@@ -94,8 +94,8 @@ check-decode:
 # the serving-density lane (docs/SERVING.md "Cache density"):
 # quantized paged-KV config validation + pool-bytes accounting, the
 # int8/fp8 decode-parity bars on the CPU mesh, the dequantizing Pallas
-# kernel (interpret mode; the on-chip case stays collectable via
-# tpu_only), the refcount/COW allocator property test, prefix-sharing
+# kernel (interpret mode; chip_smoke.py runs it on the chip), the
+# refcount/COW allocator property test, prefix-sharing
 # losslessness + record globals, the arrival-plan prefix knobs, and
 # the kv_density_ab bench-line schema + sentinel comparability.
 # ~1 min wall.

@@ -216,7 +216,7 @@ def _line_dtype(metric: str) -> str:
 
 def attribute_line(line: dict) -> dict | None:
     """Attribution for a bench JSON line from its OWN keys — the legacy
-    pathway for committed artifacts that predate stamping (BENCH_r01-05).
+    pathway for committed artifacts that predate stamping (BENCH_r05).
 
     The line states its achieved rate (``tflops_achieved`` /
     ``tops_achieved``) and how much of its time the roofline model

@@ -28,12 +28,15 @@ pay for tracing + compilation.  Three properties fall out:
    leaves have no shape/dtype-matching output is dropped (and recorded
    in the meta as ``undonated``) rather than left to XLA to warn about.
 
-3. **Warm-start re-runs.**  ``DLNB_COMPILE_CACHE_DIR`` opts into jax's
-   persistent compilation cache (size/compile-time thresholds zeroed so
+3. **Warm-start re-runs.**  The entry points (``cli.py``, ``bench.py``,
+   ``sweep.py``, ``chip_smoke.py``) call ``enable_persistent_cache``
+   before their first compile: jax's persistent compilation cache at
+   ``$JAX_COMPILATION_CACHE_DIR`` when that is set, else at one fixed
+   path inside the checkout (size/compile-time thresholds zeroed so
    every program is eligible), so a re-run of a sweep — each grid point
    a fresh process — deserializes executables instead of recompiling.
-   The config is set through one code path so the cache key's
-   compile-environment component is identical across runs.
+   The directory is part of the cache key, which is why it is never a
+   temporary name; importing the package sets nothing.
 """
 from __future__ import annotations
 
@@ -41,12 +44,16 @@ import dataclasses
 import os
 import time
 from collections.abc import Callable
+from pathlib import Path
 
 import jax
 
 from dlnetbench_tpu.metrics import spans
 
-ENV_CACHE_DIR = "DLNB_COMPILE_CACHE_DIR"
+ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
+# where the cache lives when the environment does not place it: one
+# fixed path inside the checkout (listed in .gitignore)
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 # Donation kill-switch.  Each donated program owns a PRIVATE clone of
 # its donated buffers (sibling programs must survive the donation), so
@@ -57,35 +64,35 @@ ENV_CACHE_DIR = "DLNB_COMPILE_CACHE_DIR"
 # copy-per-step behavior without touching any call site.
 ENV_NO_DONATION = "DLNB_NO_DONATION"
 
-_CACHE_CONFIGURED = False
 
-
-def enable_persistent_cache() -> str | None:
-    """Point jax's persistent compilation cache at ``$DLNB_COMPILE_CACHE_DIR``
-    (no-op when unset).  Idempotent; returns the directory in use.
+def enable_persistent_cache() -> str:
+    """Turn on jax's persistent compilation cache and return its
+    directory: ``$JAX_COMPILATION_CACHE_DIR`` where the environment
+    sets it (jax reads that variable itself, so no directory is set in
+    code), else ``DEFAULT_CACHE_DIR``.  Idempotent.
 
     Thresholds are zeroed so even fast-compiling CPU-mesh programs are
     cached — the sweep acceptance case is a 3-config CPU sweep whose
     per-point compiles are hundreds of ms, under jax's 1 s default
     minimum."""
-    global _CACHE_CONFIGURED
-    cache_dir = os.environ.get(ENV_CACHE_DIR)
-    if not cache_dir:
-        return None
-    if not _CACHE_CONFIGURED:
+    from jax.experimental.compilation_cache import compilation_cache
+
+    changed = False
+    cache_dir = os.environ.get(ENV_CACHE_DIR) or str(DEFAULT_CACHE_DIR)
+    if jax.config.jax_compilation_cache_dir != cache_dir:
+        # never true where the variable was set before jax was imported
         jax.config.update("jax_compilation_cache_dir", cache_dir)
+        changed = True
+    if jax.config.jax_persistent_cache_min_compile_time_secs != 0:
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        changed = True
+    if changed:
         # jax latches its cache-enabled decision at the FIRST compile of
-        # the process; buffer allocation (sharded_zeros) usually compiles
-        # before we get here, so force a re-evaluation under the new
+        # the process; buffer allocation usually compiles before an
+        # entry point gets here, so force a re-evaluation under the new
         # config or the whole run silently skips the cache
-        try:
-            from jax._src import compilation_cache
-            compilation_cache.reset_cache()
-        except Exception:  # private API drifted: next compile may still
-            pass           # pick the config up; never fail the build
-        _CACHE_CONFIGURED = True
+        compilation_cache.reset_cache()
     return cache_dir
 
 
@@ -115,7 +122,6 @@ class CompiledProgram:
     """
 
     def __init__(self, program: Program):
-        enable_persistent_cache()
         # the traceable python callable, kept for structural analyses
         # (metrics/profiling.py re-traces it to a jaxpr — the compiled
         # executable is opaque to make_jaxpr)
@@ -183,6 +189,11 @@ class CompiledProgram:
     def memory_analysis(self) -> dict | None:
         return self.stats.get("memory_analysis")
 
+    def as_text(self) -> str:
+        """The compiled HLO: what will run, Pallas kernels included
+        (each appears as a ``tpu_custom_call``)."""
+        return self._compiled.as_text()
+
     def __call__(self):
         outs = self._compiled(*self._args)
         if self._rebind:
@@ -229,7 +240,6 @@ class CompiledStep:
     def __init__(self, fn: Callable, example_args: tuple,
                  donate_argnums: tuple = (),
                  compiler_options: dict | None = None):
-        enable_persistent_cache()
         self.traceable = fn
         donate = (() if os.environ.get(ENV_NO_DONATION)
                   else tuple(donate_argnums))
@@ -255,6 +265,10 @@ class CompiledStep:
     @property
     def memory_analysis(self) -> dict | None:
         return self.stats.get("memory_analysis")
+
+    def as_text(self) -> str:
+        """The compiled HLO (see ``CompiledProgram.as_text``)."""
+        return self._compiled.as_text()
 
     def __call__(self, *args):
         return self._compiled(*args)
@@ -443,7 +457,7 @@ def compile_programs(programs: dict[str, Program],
         global_meta["aot"] = {
             name: {k: v for k, v in c.stats.items() if k != "compile_ms"}
             for name, c in compiled.items()}
-        cache_dir = enable_persistent_cache()
-        if cache_dir:
+        cache_dir = jax.config.jax_compilation_cache_dir
+        if cache_dir and jax.config.jax_enable_compilation_cache:
             global_meta["compile_cache_dir"] = cache_dir
     return compiled

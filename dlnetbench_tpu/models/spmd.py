@@ -23,8 +23,9 @@ math, every parallelism family the proxies replay as traffic (SURVEY.md
 
 Backward is ``jax.grad`` *through the collectives* (XLA transposes
 ppermute/psum/all_to_all), then gradients are psum'd over every mesh axis
-a parameter is replicated on.  The driver's ``dryrun_multichip`` entry
-jit-compiles and runs this step on an N-virtual-device mesh.
+a parameter is replicated on.  ``chip_smoke.py --chips 4`` runs this
+step on four chips against the single-device step; tests/test_spmd.py
+runs it on the virtual CPU mesh.
 
 r7 overlap layer (docs/PERF.md round 7): ``tp_overlap="decomposed"``
 replaces the blocking TP collectives with ppermute-pipelined collective

@@ -109,9 +109,6 @@ def flash_supported(q, k, v) -> bool:
             and hq % hkv == 0)
 
 
-_interpret = pallas_common.interpret_mode
-
-
 def _mask_causal(s, i, j, block_q: int, block_k: int):
     """Mask score block ``s`` at grid position (q block i, kv block j)."""
     qi = i * block_q + jax.lax.broadcasted_iota(
@@ -232,7 +229,7 @@ def _fwd(q, k, v, *, causal: bool, block_q: int, block_k: int):
             pltpu.VMEM((block_q, _LANES), _F32),
         ],
         compiler_params=_compiler_params(),
-        interpret=_interpret(),
+        interpret=pallas_common.interpret_mode(),
     )(qt, kt, vt)
     return _from_bsf(out, hq, dh), lse
 
@@ -474,7 +471,7 @@ def _bwd_impl(q, k, v, out, lse, do, *, causal: bool,
         out_shape=jax.ShapeDtypeStruct((b, s, hq * dh_p), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq_dq, dh_p), _F32)],
         compiler_params=_compiler_params(),
-        interpret=_interpret(),
+        interpret=pallas_common.interpret_mode(),
     )(qt, kt, vt, dot, lse, dcap)
 
     # dk/dv per q-head; inner (minor) axis walks q blocks
@@ -509,7 +506,7 @@ def _bwd_impl(q, k, v, out, lse, do, *, causal: bool,
         scratch_shapes=[pltpu.VMEM((bk_dkv, dh_p), _F32),
                         pltpu.VMEM((bk_dkv, dh_p), _F32)],
         compiler_params=_compiler_params(),
-        interpret=_interpret(),
+        interpret=pallas_common.interpret_mode(),
     )(qt, kt, vt, dot, lse, dcap)
 
     # sum the q-head group into each kv head (GQA): consecutive q heads
@@ -752,7 +749,7 @@ def _splash_fwd(q, k, v, spec, *, block_q: int, block_k: int):
             jax.ShapeDtypeStruct((b, hq, _SUBLANES, s), _F32),
         ],
         compiler_params=_compiler_params(),
-        interpret=_interpret(),
+        interpret=pallas_common.interpret_mode(),
     )(*_splash_prefetch(bm), qt, kt, vt,
       _row_i32(bm.lo, s), _row_i32(bm.hi, s))
     return _from_bsf(out, hq, dh), lse
@@ -907,7 +904,7 @@ def _splash_bwd_impl(q, k, v, out, lse, do, spec, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, s, hq * dh_p), q.dtype),
         compiler_params=_compiler_params(),
-        interpret=_interpret(),
+        interpret=pallas_common.interpret_mode(),
     )(*_splash_prefetch(bm_dq), qt, kt, vt, dot, lse, dcap,
       _row_i32(bm_dq.lo, s), _row_i32(bm_dq.hi, s))
 
@@ -959,7 +956,7 @@ def _splash_bwd_impl(q, k, v, out, lse, do, spec, *,
         out_shape=[jax.ShapeDtypeStruct((b, s, hq * dh_p), k.dtype),
                    jax.ShapeDtypeStruct((b, s, hq * dh_p), v.dtype)],
         compiler_params=_compiler_params(),
-        interpret=_interpret(),
+        interpret=pallas_common.interpret_mode(),
     )(jnp.asarray(bm_t.kv_first_q), jnp.asarray(bm_t.kv_last_q),
       jnp.asarray(bm_t.blk_lo_max), jnp.asarray(bm_t.blk_hi_min),
       qt, kt, vt, dot, lse, dcap,

@@ -40,12 +40,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dlnetbench_tpu.ops.pallas_common import (
-    F32,
-    compiler_params,
-    fit_block,
-    interpret_mode,
-)
+from dlnetbench_tpu.ops import pallas_common
+from dlnetbench_tpu.ops.pallas_common import F32, compiler_params, fit_block
 from dlnetbench_tpu.ops.quantized_matmul import (
     _FORMATS,
     _cast_q,
@@ -79,12 +75,13 @@ def _tuned_blocks(e: int, c: int, kdim: int, n: int, fmt: str | None,
         DEFAULT_BLOCKS, validate=check)
 
 
-def _grouped_kernel(counts_ref, x_ref, w_ref, sx_ref, sw_ref, out_ref,
+def _grouped_kernel(counts_ref, sx_ref, sw_ref, x_ref, w_ref, out_ref,
                     acc_ref, *, fmt: str | None, block_c: int):
     """Grid (e, ci, ni, ki); ki is the minor accumulation axis.  A
     token block wholly beyond its expert's count contributes no dot
     (its inputs were never re-DMA'd — the index map clamped to block 0)
-    and emits zeros."""
+    and emits zeros.  ``counts_ref``/``sx_ref``/``sw_ref`` are the
+    scalar-prefetched [E] per-expert counts and scales."""
     e = pl.program_id(0)
     ci = pl.program_id(1)
     ki = pl.program_id(3)
@@ -103,7 +100,7 @@ def _grouped_kernel(counts_ref, x_ref, w_ref, sx_ref, sw_ref, out_ref,
         if fmt:
             # prologue: quantize the activation tile in VMEM against
             # this EXPERT's scale — x_q never exists in HBM
-            xq = _cast_q(xf / sx_ref[0, 0], fmt)
+            xq = _cast_q(xf / sx_ref[e], fmt)
             wblk = w_ref[0]
         else:
             xq, wblk = xf, w_ref[0].astype(F32)
@@ -113,7 +110,7 @@ def _grouped_kernel(counts_ref, x_ref, w_ref, sx_ref, sw_ref, out_ref,
 
     @pl.when(ki == nk - 1)
     def _emit():
-        scale = (sx_ref[0, 0] * sw_ref[0, 0]) if fmt \
+        scale = (sx_ref[e] * sw_ref[e]) if fmt \
             else jnp.float32(1.0)
         val = acc_ref[...].astype(F32) * scale
         out_ref[0] = jnp.where(live, val, 0.0).astype(out_ref.dtype)
@@ -170,36 +167,27 @@ def grouped_matmul(x, w, *, counts=None, sx=None, sw=None,
     if counts is None:
         counts = jnp.full((e,), c, jnp.int32)
     counts = counts.astype(jnp.int32)
-    sx_a = (jnp.asarray(sx, F32).reshape(e, 1) if fmt
-            else jnp.zeros((e, 1), F32))
-    sw_a = (jnp.asarray(sw, F32).reshape(e, 1) if fmt
-            else jnp.zeros((e, 1), F32))
+    sx_a = (jnp.asarray(sx, F32).reshape(e) if fmt
+            else jnp.zeros((e,), F32))
+    sw_a = (jnp.asarray(sw, F32).reshape(e) if fmt
+            else jnp.zeros((e,), F32))
 
-    def x_index(ei, ci, ni, ki, counts_ref):
+    def x_index(ei, ci, ni, ki, counts_ref, _sx, _sw):
         # skipped blocks clamp to the expert's block 0: an already-
         # visited block, so the revisit issues no fresh DMA
         cc = jnp.where(ci * bc < counts_ref[ei], ci, 0)
         return (ei, cc, ki)
 
-    def w_index(ei, ci, ni, ki, counts_ref):
-        return (ei, ki, ni)
-
-    def s_index(ei, ci, ni, ki, counts_ref):
-        return (ei, 0)
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=3,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bc, bk), x_index),
-            pl.BlockSpec((1, bk, bn), w_index),
-            pl.BlockSpec((1, 1), s_index,
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), s_index,
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, bk, bn),
+                         lambda ei, ci, ni, ki, *_: (ei, ki, ni)),
         ],
         out_specs=pl.BlockSpec(
-            (1, bc, bn), lambda ei, ci, ni, ki, _c: (ei, ci, ni)),
+            (1, bc, bn), lambda ei, ci, ni, ki, *_: (ei, ci, ni)),
         scratch_shapes=[pltpu.VMEM((bc, bn),
                                    _FORMATS[fmt][2] if fmt else F32)],
     )
@@ -209,8 +197,8 @@ def grouped_matmul(x, w, *, counts=None, sx=None, sw=None,
         out_shape=jax.ShapeDtypeStruct((e, c, n), out_dtype or x.dtype),
         compiler_params=compiler_params(
             ("parallel", "parallel", "parallel", "arbitrary")),
-        interpret=interpret_mode(),
-    )(counts, x, w, sx_a, sw_a)
+        interpret=pallas_common.interpret_mode(),
+    )(counts, sx_a, sw_a, x, w)
     return out
 
 

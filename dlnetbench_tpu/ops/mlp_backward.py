@@ -31,11 +31,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dlnetbench_tpu.ops import pallas_common
 from dlnetbench_tpu.ops.pallas_common import (
     F32 as _F32,
     compiler_params as _compiler_params,
     fit_block,
-    interpret_mode as _interpret,
 )
 
 
@@ -94,7 +94,7 @@ def dgdu(dy, wd, g, u, *, block_m: int = 1024, block_n: int = 2048):
             jax.ShapeDtypeStruct((t, f), u.dtype),
         ],
         compiler_params=_compiler_params(("parallel", "parallel")),
-        interpret=_interpret(),
+        interpret=pallas_common.interpret_mode(),
     )(dy, wd, g, u)
 
 
@@ -145,7 +145,7 @@ def dwd(g, u, dy, *, block_f: int = 2048, block_d: int = 2048,
         scratch_shapes=[pltpu.VMEM((block_f, block_d), _F32)],
         compiler_params=_compiler_params(("parallel", "parallel",
                                           "arbitrary")),
-        interpret=_interpret(),
+        interpret=pallas_common.interpret_mode(),
     )(g, u, dy)
 
 

@@ -19,7 +19,7 @@ the quantized cache gets its own decode kernel here:
 * masking is by sequence length, exactly like the dense gather
   fallback (``kv_cache._gather_attention`` with scales), which is the
   parity reference the CPU-mesh tests lock this kernel against under
-  ``interpret=True`` — and the ``tpu_only`` case locks on real silicon.
+  ``interpret=True`` — and ``chip_smoke.py``'s kernels phase on the chip.
 
 ``q`` arrives PRE-SCALED by ``head_dim**-0.5`` (the convention every
 paged-attention impl in this repo shares).  ``pages_per_compute_block``
@@ -36,8 +36,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dlnetbench_tpu.ops.pallas_common import (F32, compiler_params,
-                                              interpret_mode)
+from dlnetbench_tpu.ops import pallas_common
+from dlnetbench_tpu.ops.pallas_common import F32, compiler_params
 
 # finite mask value (matches kv_cache.MASK_VALUE): exp(mask - m)
 # underflows to exactly 0, and a fully-masked tail block can never
@@ -45,11 +45,15 @@ from dlnetbench_tpu.ops.pallas_common import (F32, compiler_params,
 _NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
-def _kernel(q_ref, k_ref, v_ref, ks_ref, vs_ref, len_ref, o_ref,
+def _kernel(len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
             acc_ref, m_ref, l_ref, *, ppcb: int, page_size: int):
     """Grid (b, h_kv, t): t walks the gathered sequence in blocks of
     ``ppcb`` pages; accumulators carry the online softmax across t
-    (minor, "arbitrary"), emitted on the last block."""
+    (minor, "arbitrary"), emitted on the last block.  ``len_ref`` is
+    the scalar-prefetched [B] lengths; ``ks_ref``/``vs_ref`` hold this
+    sequence's [1, Hkv, Pmax] page scales in SMEM."""
+    bi = pl.program_id(0)
+    h = pl.program_id(1)
     t = pl.program_id(2)
     nt = pl.num_programs(2)
     bt = ppcb * page_size
@@ -61,21 +65,26 @@ def _kernel(q_ref, k_ref, v_ref, ks_ref, vs_ref, len_ref, o_ref,
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     # VMEM prologue: dequantize this block's page tiles against their
-    # (prefetched) per-page scales — the quantized copy never exists
-    # outside VMEM in a wider dtype
-    ks = ks_ref[0, 0]                                     # [ppcb]
-    vs = vs_ref[0, 0]
-    dh = k_ref.shape[-1]
-    kf = (k_ref[0, 0].astype(F32).reshape(ppcb, page_size, dh)
-          * ks[:, None, None]).reshape(bt, dh)
-    vf = (v_ref[0, 0].astype(F32).reshape(ppcb, page_size, dh)
-          * vs[:, None, None]).reshape(bt, dh)
+    # per-page scales — the quantized copy never exists outside VMEM in
+    # a wider dtype.  Each scale is a scalar read from SMEM, spread
+    # over its page's rows of a [bt, 1] column by a select chain.
+    row = jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0)
+
+    def scale_col(s_ref):
+        col = jnp.full((bt, 1), s_ref[0, h, t * ppcb], F32)
+        for p in range(1, ppcb):
+            col = jnp.where(row >= p * page_size,
+                            s_ref[0, h, t * ppcb + p], col)
+        return col
+
+    kf = k_ref[0, 0].astype(F32) * scale_col(ks_ref)     # [bt, Dh]
+    vf = v_ref[0, 0].astype(F32) * scale_col(vs_ref)
 
     q = q_ref[0, 0].astype(F32)                           # [G, Dh]
     s = jax.lax.dot_general(q, kf, (((1,), (1,)), ((), ())),
                             preferred_element_type=F32)   # [G, bt]
     pos = t * bt + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(pos < len_ref[0, 0], s, _NEG_INF)
+    s = jnp.where(pos < len_ref[bi], s, _NEG_INF)
 
     m_prev = m_ref[:, :1]                                 # [G, 1]
     m_cur = jnp.max(s, axis=-1, keepdims=True)
@@ -126,38 +135,37 @@ def quant_paged_attention(q, k_pages, v_pages, k_scale, v_scale,
     ksg = jnp.moveaxis(k_scale[:, page_indices], 0, 1)   # [B, Hkv, Pmax]
     vsg = jnp.moveaxis(v_scale[:, page_indices], 0, 1)
     q4 = q.reshape(b, hkv, g, dh)
-    len2 = lengths.astype(jnp.int32).reshape(b, 1)
-
     bt = ppcb * page_size
-    grid = (b, hkv, pmax // ppcb)
-    out = pl.pallas_call(
-        functools.partial(_kernel, ppcb=ppcb, page_size=page_size),
-        grid=grid,
+    smem_scales = pl.BlockSpec((1, hkv, pmax),
+                               lambda bi, h, t, _len: (bi, 0, 0),
+                               memory_space=pltpu.SMEM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, hkv, pmax // ppcb),
         in_specs=[
-            pl.BlockSpec((1, 1, g, dh), lambda bi, h, t: (bi, h, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, bt, dh), lambda bi, h, t: (bi, h, t, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, bt, dh), lambda bi, h, t: (bi, h, t, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, ppcb), lambda bi, h, t: (bi, h, t),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, ppcb), lambda bi, h, t: (bi, h, t),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1), lambda bi, h, t: (bi, 0),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, g, dh),
+                         lambda bi, h, t, _len: (bi, h, 0, 0)),
+            pl.BlockSpec((1, 1, bt, dh),
+                         lambda bi, h, t, _len: (bi, h, t, 0)),
+            pl.BlockSpec((1, 1, bt, dh),
+                         lambda bi, h, t, _len: (bi, h, t, 0)),
+            smem_scales,
+            smem_scales,
         ],
         out_specs=pl.BlockSpec((1, 1, g, dh),
-                               lambda bi, h, t: (bi, h, 0, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, dh), q.dtype),
+                               lambda bi, h, t, _len: (bi, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g, dh), F32),
             pltpu.VMEM((g, 128), F32),
             pltpu.VMEM((g, 128), F32),
         ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_kernel, ppcb=ppcb, page_size=page_size),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g, dh), q.dtype),
         compiler_params=compiler_params(
             ("parallel", "parallel", "arbitrary")),
-        interpret=interpret_mode(),
-    )(q4, kg, vg, ksg, vsg, len2)
+        interpret=pallas_common.interpret_mode(),
+    )(lengths.astype(jnp.int32), q4, kg, vg, ksg, vsg)
     return out.reshape(b, hq, dh)

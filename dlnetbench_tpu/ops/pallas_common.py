@@ -17,14 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams after 0.4.x; the
-# fields are identical.  Resolving it HERE (the one shim module) is
-# what turned the seed's 16 "Pallas-on-CPU" tier-1 failures — every
-# kernel file AttributeError-ing on the new name under jax 0.4.37 —
-# into passes (same spirit as utils/jax_compat.py for shard_map).
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 # fp32: the accumulation / epilogue dtype of every kernel (MXU
 # accumulators, online-softmax state, quantization scales)
 F32 = jnp.float32
@@ -49,7 +41,7 @@ def compiler_params(dimension_semantics,
     semantics (``"parallel"`` outer axes let Mosaic pipeline DMA across
     grid rows; accumulator-carrying minor axes must be
     ``"arbitrary"``), VMEM cap in MiB."""
-    return _CompilerParams(
+    return pltpu.CompilerParams(
         dimension_semantics=tuple(dimension_semantics),
         vmem_limit_bytes=vmem_limit_mb * 1024 * 1024)
 

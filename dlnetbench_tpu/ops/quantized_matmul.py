@@ -58,12 +58,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dlnetbench_tpu.ops.pallas_common import (
-    F32,
-    compiler_params,
-    fit_block,
-    interpret_mode,
-)
+from dlnetbench_tpu.ops import pallas_common
+from dlnetbench_tpu.ops.pallas_common import F32, compiler_params, fit_block
 
 # format table: (quantized dtype, symmetric max, MXU accumulator dtype)
 _FORMATS = {
@@ -139,7 +135,7 @@ def _fused_matmul_kernel(x_ref, wq_ref, sx_ref, sw_ref, *refs,
         preferred_element_type=acc_dtype)
 
     if collect_amax:
-        amax_ref[0, 0] = jnp.max(jnp.abs(xf))
+        amax_ref[...] = jnp.full(amax_ref.shape, jnp.max(jnp.abs(xf)), F32)
 
     @pl.when(k == nk - 1)
     def _emit():
@@ -152,6 +148,9 @@ def _fused_matmul_kernel(x_ref, wq_ref, sx_ref, sw_ref, *refs,
 # call without explicit blocks and without a tuning-DB hit runs on —
 # locked bit-identical by tests/test_tuning.py
 DEFAULT_BLOCKS = {"block_m": 1024, "block_n": 2048, "block_k": 2048}
+
+# block of the delayed-scaling amax side output: one f32 vreg tile
+_AMAX_TILE = (8, 128)
 
 
 def _tuned_blocks(t: int, kdim: int, n: int, fmt: str, xdtype) -> dict:
@@ -219,9 +218,12 @@ def fused_matmul(x, wq, sw, sx, *, fmt: str, out_dtype=None,
     out_specs = [pl.BlockSpec((bm, bn), lambda i, j, k: (i, j),
                               memory_space=pltpu.VMEM)]
     if collect_amax:
-        out_shape.append(jax.ShapeDtypeStruct((grid[0], grid[2]), F32))
-        out_specs.append(pl.BlockSpec((1, 1), lambda i, j, k: (i, k),
-                                      memory_space=pltpu.SMEM))
+        # one (8, 128) f32 tile per (i, k) block, every element the
+        # tile's amax: the smallest output block the TPU lowering takes
+        out_shape.append(jax.ShapeDtypeStruct(
+            (grid[0] * _AMAX_TILE[0], grid[2] * _AMAX_TILE[1]), F32))
+        out_specs.append(pl.BlockSpec(_AMAX_TILE, lambda i, j, k: (i, k),
+                                      memory_space=pltpu.VMEM))
     # the amax side output's (i, k) block is revisited along j, so j
     # must stay sequential when it is emitted; without it the kernel
     # keeps the dwd-style (parallel, parallel, arbitrary) semantics
@@ -245,7 +247,7 @@ def fused_matmul(x, wq, sw, sx, *, fmt: str, out_dtype=None,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
         compiler_params=compiler_params(sem),
-        interpret=interpret_mode(),
+        interpret=pallas_common.interpret_mode(),
     )(x2, wq,
       jnp.asarray(sx, F32).reshape(1, 1),
       jnp.asarray(sw, F32).reshape(1, 1))

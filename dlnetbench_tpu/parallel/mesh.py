@@ -33,10 +33,14 @@ AXIS_FLAT = "x"  # single-axis meshes (dp / fsdp proxies)
 # per point would defeat jax-internal sharding caches keyed on mesh
 # identity.  Keyed on device ids so distinct --devices subsets coexist,
 # AND on the device objects' python identity: after a backend re-init
-# (clear_backends in __graft_entry__ / test_wedge_guard) jax hands out
-# NEW device objects with the SAME ids, and a Mesh over the dead
-# backend's devices must never be served from here.
+# jax hands out NEW device objects with the SAME ids, and a Mesh over
+# the dead backend's devices must never be served from here.
 _MESH_CACHE: dict = {}
+
+# grids laid out in the order the devices were given because jax knows
+# no topology-aware assignment for them (keyed like _MESH_CACHE's device
+# part); ``describe_mesh`` stamps it into the record
+_GIVEN_ORDER: set = set()
 
 
 def _cached_mesh(shape: tuple[int, ...], axes: tuple[str, ...],
@@ -59,11 +63,15 @@ def _device_grid(shape: tuple[int, ...], devices=None) -> np.ndarray:
                          f"have {len(devices)}")
     if need < len(devices):
         devices = devices[:need]
+    # let JAX pick an ICI-friendly assignment when it knows the topology
+    from jax.experimental import mesh_utils
     try:
-        # let JAX pick an ICI-friendly assignment when it knows the topology
-        from jax.experimental import mesh_utils
         return mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception:
+    except (AssertionError, NotImplementedError, ValueError):
+        # a device set that is no cuboid of the torus (fault-shrink
+        # survivors, ``--devices 0,3``) has no such assignment: keep the
+        # order given, and let the record say so
+        _GIVEN_ORDER.add(tuple(d.id for d in devices))
         return np.asarray(devices).reshape(shape)
 
 
@@ -122,6 +130,10 @@ def describe_mesh(mesh: Mesh) -> dict:
         # numbers derived from them must never be read as fabric numbers
         "fabric": "virtual" if devs[0].platform == "cpu" else "real",
     }
+    if tuple(d.id for d in devs) in _GIVEN_ORDER:
+        # no topology-aware assignment existed for this device set:
+        # neighbours on a mesh axis need not be neighbours on the torus
+        info["device_order"] = "given"
     coords = []
     for d in devs:
         c = getattr(d, "coords", None)

@@ -46,27 +46,8 @@ def initialize(coordinator_address: str | None = None,
     if coordinator_address is None and num_processes in (None, 1) \
             and not _looks_like_tpu_pod():
         return  # plain single-process dev box: nothing to bootstrap
-    # Tolerate environments that pre-import jax and initialise a backend
-    # (e.g. a sitecustomize pinning the platform): distributed init must
-    # precede backend init.  Clearing invalidates every live array and
-    # compiled executable, so only clear when a backend actually exists —
-    # a clean process keeps its state untouched.
-    backend_live = True  # unknown internal state: clear to be safe
-    try:
-        from jax._src import xla_bridge as _xb
-        if hasattr(_xb, "_backends"):  # attribute gone = unknown -> clear
-            backend_live = bool(_xb._backends)
-    except Exception:
-        pass
-    if backend_live:
-        try:
-            from jax.extend import backend as jeb
-            jeb.clear_backends()
-        except Exception as e:
-            raise RuntimeError(
-                "a JAX backend is already initialized and could not be "
-                "cleared; call multihost.initialize() before any other "
-                "JAX use in this process") from e
+    # distributed init must precede backend init: jax raises if this
+    # process has already touched a backend
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id)

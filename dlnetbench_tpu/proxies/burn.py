@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dlnetbench_tpu.utils.timing import time_callable
+from dlnetbench_tpu.utils.timing import dispatch_fence_s, time_callable
 
 # 256x256 bf16: two MXU tiles wide — big enough to exercise the MXU,
 # small enough to live in VMEM and calibrate in milliseconds.
@@ -93,18 +93,36 @@ class BurnCalibration:
         return iters * self.ns_per_iter / 1000.0
 
 
+# the shorter calibration probe must outlast one dispatch plus fence by
+# this factor, and the probes stop growing at this many iterations
+_PROBE_OVER_DISPATCH = 4.0
+_MAX_PROBE_ITERS = 1 << 20
+
+
 def _calibrate_on_device(shape, dtype_name, device, n_lo, n_hi):
+    """ns per burn iteration from the difference of two loop lengths.
+
+    The difference cancels dispatch and fence only while both probes
+    keep the device busy longer than the host takes to dispatch and
+    fence: a probe shorter than that finishes under the host's own
+    latency and reads as the host.  So the pair grows fourfold until the
+    shorter probe outlasts ``_PROBE_OVER_DISPATCH`` dispatch floors (on
+    a v5e one iteration is about a microsecond and the floor most of a
+    millisecond: 64 and 256 iterations read 2.2x too fast there)."""
     dtype = jnp.dtype(dtype_name)
     with jax.default_device(device):
         state = jax.device_put(make_state(shape, dtype), device)
-
-        lo = jax.jit(functools.partial(burn, iters=n_lo))
-        hi = jax.jit(functools.partial(burn, iters=n_hi))
-        lo(state).block_until_ready()  # compile
-        hi(state).block_until_ready()
-
-        t_lo = min(time_callable(lo, state, reps=5))
-        t_hi = min(time_callable(hi, state, reps=5))
+        floor_s = _PROBE_OVER_DISPATCH * dispatch_fence_s()
+        while True:
+            lo = jax.jit(functools.partial(burn, iters=n_lo))
+            hi = jax.jit(functools.partial(burn, iters=n_hi))
+            lo(state).block_until_ready()  # compile
+            hi(state).block_until_ready()
+            t_lo = min(time_callable(lo, state, reps=5))
+            t_hi = min(time_callable(hi, state, reps=5))
+            if t_lo >= floor_s or n_hi >= _MAX_PROBE_ITERS:
+                break
+            n_lo, n_hi = 4 * n_lo, 4 * n_hi
         ns = (t_hi - t_lo) * 1e9 / (n_hi - n_lo)
         if ns <= 0:  # timer noise on very fast devices: widen the gap
             t_hi = min(time_callable(
@@ -116,15 +134,23 @@ def _calibrate_on_device(shape, dtype_name, device, n_lo, n_hi):
 
 _CAL_CACHE: dict = {}
 
+# part of every persisted entry's name: a calibration made by an older
+# method must not answer (bump when ``_calibrate_on_device`` changes
+# what it measures; 2 = probes grown past the dispatch floor)
+_CAL_METHOD = 2
+
+
+def _persist_name(key) -> str:
+    return ":".join(map(str, (*key, f"m{_CAL_METHOD}")))
+
 
 def _persist_path():
-    """Calibration rides in the same opt-in cache dir as compiled
-    executables (core/executor.py DLNB_COMPILE_CACHE_DIR): a warm sweep
-    re-run should skip the ~2.4 s calibration the same way it skips
-    recompiles.  Returns None when the cache is not opted into."""
-    import os
-    d = os.environ.get("DLNB_COMPILE_CACHE_DIR")
-    if not d:
+    """Calibration rides in the same cache dir as compiled executables
+    (core/executor.enable_persistent_cache): a warm sweep re-run should
+    skip the ~2.4 s calibration the same way it skips recompiles.
+    Returns None when no entry point has placed the cache."""
+    d = jax.config.jax_compilation_cache_dir
+    if not d or not jax.config.jax_enable_compilation_cache:
         return None
     from pathlib import Path
     return Path(d) / "burn_calibration.json"
@@ -136,7 +162,7 @@ def _load_persisted(path, key) -> BurnCalibration | None:
     # dict (hand edit, torn write) must fall back to measuring, not
     # crash every run until someone deletes the file
     try:
-        entry = json.loads(path.read_text())[":".join(map(str, key))]
+        entry = json.loads(path.read_text())[_persist_name(key)]
         return BurnCalibration(ns_per_iter=float(entry), shape=key[0],
                                dtype=key[1], device_kind=key[2])
     except (OSError, KeyError, TypeError, ValueError):
@@ -154,7 +180,7 @@ def _store_persisted(path, key, cal: BurnCalibration) -> None:
             data = {}
         if not isinstance(data, dict):
             data = {}
-        data[":".join(map(str, key))] = cal.ns_per_iter
+        data[_persist_name(key)] = cal.ns_per_iter
         # per-process + random tmp name: id() repeats across processes
         # (same heap layout), and two concurrent sweep points sharing a
         # tmp path could rename a torn file into place
@@ -172,8 +198,8 @@ def calibrate(shape=DEFAULT_SHAPE, dtype=DEFAULT_DTYPE,
     Differenced between two trip counts so dispatch/compile overheads cancel
     (the same discipline as the reference's warm-up skipping, reference
     cpp/utils.hpp:121-123).  Cached in-process per (shape, dtype, device
-    kind) — one ``build()`` per grid point must not re-pay it — and,
-    when ``DLNB_COMPILE_CACHE_DIR`` is set, persisted there so re-runs
+    kind) — one ``build()`` per grid point must not re-pay it — and
+    persisted beside the compile cache, where one is in use, so re-runs
     start warm."""
     device = device or jax.devices()[0]
     key = (tuple(shape), jnp.dtype(dtype).name, device.device_kind)

@@ -109,11 +109,12 @@ def build(stats: ModelStats, num_units: int, cfg: ProxyConfig,
                               with_comm=with_comm),
             mesh=mesh, in_specs=(P(), tuple(P() for _ in shards)),
             out_specs=P(), check_vma=False)
-        # donate the burn state and every parameter/gradient shard — the
-        # outputs are (state', per-unit grad shards), shape-matched, so
-        # XLA reuses the buffers instead of copying per step
+        # donate every parameter/gradient shard — the outputs are
+        # (state', per-unit grad shards), shape-matched, so XLA reuses
+        # the buffers instead of copying per step; the burn state stays
+        # undonated (proxies/dp.py says why)
         return executor.Program(fn=fn, args=(state0, tuple(shards)),
-                                donate_argnums=(0, 1))
+                                donate_argnums=(1,))
 
     # comm-only sub-schedules for per-collective timers (reference
     # fsdp.cpp:61-66 allgather / reduce_scatter timers)

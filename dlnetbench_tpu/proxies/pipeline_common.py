@@ -334,7 +334,9 @@ def build(stats: ModelStats, card: ModelCard, cfg: ProxyConfig, *,
                 outs.append(col.allreduce(col.tie(grad_b, state), AXIS_DP))
         return (state, cur, *col.fence(*outs))
 
-    zero = jnp.zeros((1,), dtype)
+    # the stand-in for a buffer this mode does not have lives on the
+    # mesh like every other argument, not on the default device
+    zero = sharded_zeros(mesh, P(), (1,), dtype)
     ne_in = ne_buf if ne_buf is not None else zero
     ex_in = ex_buf if ex_buf is not None else zero
     act2_in = act2 if act2 is not None else zero
@@ -350,12 +352,13 @@ def build(stats: ModelStats, card: ModelCard, cfg: ProxyConfig, *,
         # rebind from (schedule/mode dependent: gpipe never outputs the
         # act2 dummy, the A2A buffer comes back reshaped, the TP/grad
         # buffers only exist as outputs in their modes) and records the
-        # dropped ones in the compile meta as ``undonated``
+        # dropped ones in the compile meta as ``undonated``; the burn
+        # state (argument 0) stays undonated (proxies/dp.py says why)
         return executor.Program(
             fn=fn,
             args=(state0, act, act2_in, grad_shard, tp_buf, a2a_buf,
                   ne_in, ex_in),
-            donate_argnums=tuple(range(8)))
+            donate_argnums=tuple(range(1, 8)))
 
     # per-collective comm-only variants
     def make_var(body, *bufs):

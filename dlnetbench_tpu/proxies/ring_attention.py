@@ -89,10 +89,11 @@ def build(stats: ModelStats, card: ModelCard, cfg: ProxyConfig, *,
                               with_comm=with_comm),
             mesh=mesh, in_specs=(P(), P(), P()), out_specs=P(),
             check_vma=False)
-        # donate state/KV block/grad shard (grad is only rebindable —
-        # hence only donated — when dp > 1 produces its allreduce output)
+        # donate KV block/grad shard (grad is only rebindable — hence
+        # only donated — when dp > 1 produces its allreduce output); the
+        # burn state stays undonated (proxies/dp.py says why)
         return executor.Program(fn=fn, args=(state0, kv, grads),
-                                donate_argnums=(0, 1, 2))
+                                donate_argnums=(1, 2))
 
     # one ring pass per layer fwd + one bwd (bwd doubles compute, not
     # hops); shared by ring_body and the comm_model declaration

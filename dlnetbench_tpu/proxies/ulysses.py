@@ -90,10 +90,11 @@ def build(stats: ModelStats, card: ModelCard, cfg: ProxyConfig, *,
                               with_comm=with_comm),
             mesh=mesh, in_specs=(P(), P(), P()), out_specs=P(),
             check_vma=False)
-        # donate state/activations/grad shard (grad only donated when
-        # dp > 1 emits its allreduce output to rebind from)
+        # donate activations/grad shard (grad only donated when dp > 1
+        # emits its allreduce output to rebind from); the burn state
+        # stays undonated (proxies/dp.py says why)
         return executor.Program(fn=fn, args=(state0, acts, grads),
-                                donate_argnums=(0, 1, 2))
+                                donate_argnums=(1, 2))
 
     a2a_total = layers * 4  # 2 per layer fwd + 2 per layer bwd; shared
                             # by a2a_body and the comm_model declaration

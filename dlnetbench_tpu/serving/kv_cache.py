@@ -802,7 +802,7 @@ def sharded_paged_attention(mesh, axis: str = "kv",
             in_specs=(P(None, axis, None), P(axis, None, None, None),
                       P(axis, None, None, None), P(), P()),
             out_specs=P(None, axis, None),
-            check_rep=False)
+            check_vma=False)
 
     def qfn(q, k_pages, v_pages, k_scale, v_scale, lengths,
             page_indices):
@@ -817,4 +817,4 @@ def sharded_paged_attention(mesh, axis: str = "kv",
                   P(axis, None, None, None), P(axis, None),
                   P(axis, None), P(), P()),
         out_specs=P(None, axis, None),
-        check_rep=False)
+        check_vma=False)

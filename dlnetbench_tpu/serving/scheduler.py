@@ -449,7 +449,7 @@ class Engine:
                                      sampler=self._sampler),
                 self._prefill_example_args(),
                 donate_argnums=self._pool_argnums)
-        decode_prog = self._loop if self._loop_mode else self._decode
+        decode_prog = self.decode_program
         decode_name = "decode_loop" if self._loop_mode else "decode_step"
         self.meta["compile_ms"] = {
             "prefill_chunk": self._prefill.stats["compile_ms"]}
@@ -468,6 +468,12 @@ class Engine:
         # share one stream
         self.live = None
         self._reset_state()
+
+    @property
+    def decode_program(self):
+        """The compiled decode program this engine dispatches: the
+        fused loop, or the 1-step program."""
+        return self._loop if self._loop_mode else self._decode
 
     # ---- construction helpers ----------------------------------------
     @property
@@ -812,10 +818,10 @@ class Engine:
 
         Fence honesty: only the PROMPT-COMPLETING chunk fences (its
         ``int(nxt)`` is load-bearing — the TTFT token).  Intermediate
-        chunks return dispatch-acknowledged wall only; forcing a
-        device->host fence on each would cost a full RTT per chunk on
-        a tunnel backend for timing's sake.  On an async backend their
-        queued compute therefore completes inside a LATER fenced
+        chunks return dispatch-acknowledged wall only; fencing each
+        would stall the host once per chunk and leave the device idle
+        between chunks for timing's sake.  Dispatch is async, so their
+        queued compute completes inside a LATER fenced
         window — in separate-prefill mode that is still the admission
         phase (the final chunk's fence), but in inline mode it can be
         the next decode dispatch, which is why the bench A/B and the

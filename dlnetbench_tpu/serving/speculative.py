@@ -23,8 +23,8 @@ attention math (the Pallas ``paged_attention`` kernel is single-query
 and cannot serve K1 positions), so the parity lock is EXACT where the
 1-step engine shares that math — the CPU mesh, or ``attn_impl=
 "gather"`` on chip.  Against the on-chip Pallas 1-step path the two
-argmaxes agree to kernel-parity tolerance (the tpu_only
-pallas-vs-gather case bounds it), not bit-exactly — a near-tie in the
+argmaxes agree to kernel-parity tolerance (``chip_smoke.py``'s
+pallas-vs-gather checks bound it), not bit-exactly — a near-tie in the
 logits can diverge.  The drafter only moves the ACCEPTANCE RATE, i.e.
 throughput:
 

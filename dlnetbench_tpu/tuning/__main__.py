@@ -309,7 +309,7 @@ def _tune_tp_overlap_chunks(args):
                     chunks=cfg["chunks"]),
                 mesh=mesh, in_specs=(P(None, AXIS_TP, None), P()),
                 out_specs=P(None, AXIS_TP, None),
-                check_rep=False)(xx, ww)
+                check_vma=False)(xx, ww)
         return _chain(fn, (x, w), args.k)
     return "tp_overlap_chunks", key, cands, measure_cfg
 

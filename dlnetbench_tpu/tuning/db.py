@@ -1,9 +1,9 @@
 """TuningDB: the persistent per-(op, shape, chip) tuned-config store.
 
 JSON-lines file (``tuning_db.jsonl``) in a directory the operator points
-``DLNB_TUNING_DB_DIR`` at — deliberately the same opt-in shape as the
-PR-1 persistent compile cache (``DLNB_COMPILE_CACHE_DIR``), and meant to
-live beside it: tuning cost, like compile cost, is paid once per cache,
+``DLNB_TUNING_DB_DIR`` at — a warm-state directory like the persistent
+compile cache (``core/executor.enable_persistent_cache``), and meant
+to live beside it: tuning cost, like compile cost, is paid once per cache,
 and both directories are stamped into the bench headline so every
 artifact says what warm state produced it.
 

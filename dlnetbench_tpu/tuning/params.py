@@ -62,14 +62,11 @@ def hw_key() -> str:
     (shared with bench/attribution via ``hw_key_for_device_kind``), the
     jax backend name otherwise (``cpu`` on the virtual mesh — CPU-tuned
     records must never be consulted on a chip, and vice versa)."""
-    try:
-        import jax
+    import jax
 
-        from dlnetbench_tpu.core.hardware import hw_key_for_device_kind
-        return (hw_key_for_device_kind(jax.devices()[0].device_kind)
-                or jax.default_backend())
-    except Exception:  # pragma: no cover - backend never initialized
-        return "unknown"
+    from dlnetbench_tpu.core.hardware import hw_key_for_device_kind
+    return (hw_key_for_device_kind(jax.devices()[0].device_kind)
+            or jax.default_backend())
 
 
 def consult(op: str, key: str, default: dict, validate=None) -> dict:

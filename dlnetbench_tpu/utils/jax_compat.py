@@ -1,104 +1,15 @@
-"""Version compatibility shims for the jax API surface this repo uses.
+"""The single import point for the jax names whose home has moved
+between releases.
 
-``shard_map`` moved twice across the jax versions this repo must run on:
-
-* jax >= 0.6: top-level ``jax.shard_map`` with a ``check_vma=`` kwarg;
-* jax 0.4.x (this container ships 0.4.37): only
-  ``jax.experimental.shard_map.shard_map`` with the same knob spelled
-  ``check_rep=``.
-
-Every module imports ``shard_map`` from HERE instead of from jax, and may
-pass either ``check_vma=`` or ``check_rep=`` — the shim translates to
-whatever the underlying implementation accepts.  A guard test
-(tests/test_guard_imports.py) rejects new direct ``from jax import
-shard_map`` imports so the 9-collection-error regression this shim fixed
-cannot silently return.
+Every module imports ``shard_map`` and ``axis_size`` from HERE instead
+of from jax, so the next move is a one-file change; a guard test
+(tests/test_guard_imports.py) rejects direct ``shard_map`` imports
+elsewhere.  The installation is jax 0.9: ``jax.shard_map`` takes its
+replication check as ``check_vma=``.
 """
 from __future__ import annotations
 
-import functools
+from jax import shard_map
+from jax.lax import axis_size
 
-try:  # jax >= 0.6 spelling
-    from jax import shard_map as _shard_map_impl  # type: ignore
-    _CHECK_KWARG = "check_vma"
-except ImportError:  # jax 0.4.x spelling
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _CHECK_KWARG = "check_rep"
-
-try:  # jax >= 0.6
-    from jax.lax import axis_size
-except ImportError:  # jax 0.4.x: the classic static-psum idiom — psum of
-    # a non-tracer constant over a named axis folds to axis_size * 1 at
-    # trace time, so the result is a plain int usable in permute tables
-    def axis_size(axis_name) -> int:
-        import jax.lax
-        return jax.lax.psum(1, axis_name)
-
-
-def cpu_device_count_snapshot() -> tuple:
-    """Opaque pre-repin state for ``restore_cpu_device_count``.
-
-    jax >= 0.5 exposes the virtual CPU device count as the
-    ``jax_num_cpu_devices`` config; 0.4.x only reads
-    ``--xla_force_host_platform_device_count`` from XLA_FLAGS at FIRST
-    backend init, so there the snapshot/restore works on the env var."""
-    import os
-
-    import jax
-    if hasattr(jax.config, "jax_num_cpu_devices"):
-        return ("config", jax.config.jax_num_cpu_devices)
-    return ("env", os.environ.get("XLA_FLAGS"))
-
-
-def request_cpu_device_count(n: int) -> None:
-    """Ask the NEXT cpu backend init for ``n`` virtual devices.  Caller
-    must clear backends first and verify the count after re-init: on
-    jax 0.4.x XLA parses XLA_FLAGS once per process, so a post-init
-    change can only help processes (or backends) not yet initialized —
-    the verification is what keeps that limitation loud."""
-    import os
-    import re
-
-    import jax
-    if hasattr(jax.config, "jax_num_cpu_devices"):
-        jax.config.update("jax_num_cpu_devices", n)
-        return
-    flag = f"--xla_force_host_platform_device_count={n}"
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "--xla_force_host_platform_device_count=" in flags:
-        flags = re.sub(r"--xla_force_host_platform_device_count=\d+",
-                       flag, flags)
-    else:
-        flags = (flags + " " + flag).strip()
-    os.environ["XLA_FLAGS"] = flags
-
-
-def restore_cpu_device_count(snapshot: tuple) -> None:
-    import os
-
-    import jax
-    kind, value = snapshot
-    if kind == "config":
-        jax.config.update("jax_num_cpu_devices", value)
-    elif value is None:
-        os.environ.pop("XLA_FLAGS", None)
-    else:
-        os.environ["XLA_FLAGS"] = value
-
-
-@functools.wraps(_shard_map_impl)
-def shard_map(f, *, mesh, in_specs, out_specs,
-              check_vma=None, check_rep=None, **kwargs):
-    """``jax.shard_map`` with the replication-check kwarg translated.
-
-    ``check_vma`` and ``check_rep`` are aliases (at most one may be
-    given); whichever is passed reaches the implementation under the
-    name it understands.
-    """
-    if check_vma is not None and check_rep is not None:
-        raise TypeError("pass either check_vma or check_rep, not both")
-    check = check_vma if check_vma is not None else check_rep
-    if check is not None:
-        kwargs[_CHECK_KWARG] = check
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kwargs)
+__all__ = ["axis_size", "shard_map"]

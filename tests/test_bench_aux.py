@@ -1,6 +1,7 @@
-"""bench.py auxiliary-line guard: a failing low-precision line must
-degrade to a machine-readable skipped marker, never cost the headline
-line (the driver's tail parser reads the LAST stdout line)."""
+"""bench.py auxiliary-line guard: a failing auxiliary line fails the
+run; only the wall-clock deadline skips lines, and the headline says
+which (``incomplete``).  The driver's tail parser reads the LAST stdout
+line."""
 from __future__ import annotations
 
 import json
@@ -8,30 +9,63 @@ import json
 import pytest
 
 
-def test_aux_failure_prints_skipped_marker(capsys):
+def test_aux_failure_fails_the_run(capsys):
+    """A line that raises is not turned into a skip marker: the
+    exception reaches the caller (and the exit status)."""
     import bench
 
     def boom(*a):
         raise RuntimeError("synthetic compile pathology")
 
-    out = bench._aux("fp8 swiglu chain", boom, "card", "hw", "dev")
-    assert out is None
-    line = json.loads(capsys.readouterr().out.strip())
-    assert line["metric"] == "fp8 swiglu chain"
-    assert "synthetic compile pathology" in line["skipped"]
+    aux = bench._Aux()
+    with pytest.raises(RuntimeError, match="synthetic compile pathology"):
+        aux("fp8 swiglu chain", boom, "card", "hw", "dev")
+    assert capsys.readouterr().out == ""
+    assert aux.incomplete == []
 
 
 def test_aux_success_passes_through(capsys):
     import bench
 
-    got = bench._aux("x", lambda a: {"metric": a}, "ok")
+    aux = bench._Aux()
+    got = aux("x", lambda a: {"metric": a}, "ok")
     assert got == {"metric": "ok"}
     assert capsys.readouterr().out == ""
+    assert aux.incomplete == []
+
+
+def test_bench_device_refuses_a_silent_cpu(monkeypatch):
+    """No TPU is an error unless the CPU was asked for by name; the
+    named CPU has no HARDWARE key, so nothing is priced against a TPU
+    peak; an unlisted TPU kind is an error too."""
+    import jax
+
+    import bench
+
+    dev, hw_key = bench._bench_device()      # conftest names the CPU
+    assert dev.platform == "cpu" and hw_key is None
+    jax.config.update("jax_platforms", None)  # the backend is up: no effect
+    try:
+        with pytest.raises(SystemExit, match="found no TPU"):
+            bench._bench_device()
+    finally:
+        jax.config.update("jax_platforms", "cpu")
+
+    class Unlisted:
+        platform, device_kind = "tpu", "TPU v99"
+    monkeypatch.setattr(jax, "devices", lambda *a: [Unlisted()])
+    with pytest.raises(SystemExit, match="no entry for device kind"):
+        bench._bench_device()
+
+    class V5e:
+        platform, device_kind = "tpu", "TPU v5 lite"
+    monkeypatch.setattr(jax, "devices", lambda *a: [V5e()])
+    assert bench._bench_device()[1] == "tpu_v5e"
 
 
 def test_above_peak_readings_are_flagged():
-    """A short-chain line whose ratio exceeds 1.0 (physically
-    impossible — fence-RTT over-subtraction) must carry the upper-bound
+    """A line whose ratio exceeds 1.0 (physically impossible: the timed
+    window did not cover the credited work) must carry the upper-bound
     note; in-range lines must not."""
     import bench
 
@@ -509,25 +543,28 @@ def test_serving_decode_ab_schema_locked():
     assert "speculative_host_frac" in flip
     assert line["token_parity"] is True
     # without the A/B inputs the line stays the ISSUE 8 shape (no
-    # accidental keys) — the schema the committed BENCH_r01-05
-    # artifacts' sentinel walk expects
+    # accidental keys) — the schema the committed BENCH_r05
+    # artifact's sentinel walk expects
     base_line = bench._serving_decode_line(one, suffix=", test")
     for key in ("multi_step", "speculative", "attribution_flip",
                 "token_parity"):
         assert key not in base_line
 
 
-def test_aux_deadline_skips_instead_of_running(capsys, monkeypatch):
+def test_aux_deadline_skips_instead_of_running(capsys):
     """Past the wall-clock deadline the aux fn must not even start —
-    the headline line takes precedence over auxiliary coverage."""
+    the headline line takes precedence over auxiliary coverage — and
+    the skip is recorded, so the headline can carry ``incomplete``."""
     import bench
 
-    monkeypatch.setattr(bench, "_AUX_DEADLINE_S", 0.0)
+    aux = bench._Aux(deadline_s=-1.0)
     ran = []
-    got = bench._aux("int8 matmul", lambda: ran.append(1))
+    got = aux("int8 matmul", lambda: ran.append(1))
     assert got is None and not ran
     line = json.loads(capsys.readouterr().out.strip())
+    assert line["metric"] == "int8 matmul"
     assert "deadline" in line["skipped"]
+    assert aux.incomplete == ["int8 matmul"]
 
 
 def test_checkpoint_ab_line_schema_locked(monkeypatch, tmp_path):

@@ -3,6 +3,7 @@ calibration contract — linearity and budget mapping — must hold)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from dlnetbench_tpu.proxies import burn as burnlib
 from dlnetbench_tpu.utils.timing import time_callable
@@ -45,3 +46,24 @@ def test_burn_time_scales_linearly():
     # 4x iters => ~3x extra time over the base measurement; allow wide
     # tolerance for CI noise but reject constant-time (DCE'd) behavior
     assert t4 > t1 * 1.5, (t1, t4, ratio)
+
+
+def test_calibration_probes_outlast_the_dispatch_floor(monkeypatch):
+    """A probe shorter than one dispatch plus fence reads as the host,
+    not the device (on the v5e the 64/256-iteration pair read 2.2x too
+    fast): the pair must grow until the difference is the device's."""
+    host_s, per_iter_s = 700e-6, 1.25e-6
+
+    def fake_time(fn, state, reps=1):
+        # the device runs under the host's own latency: a short program
+        # costs the host's overhead, a long one its device time on top
+        n = fn.__wrapped__.keywords["iters"]
+        device_s = n * per_iter_s
+        hidden = min(device_s, host_s / 2)
+        return [host_s + device_s - hidden] * reps
+
+    monkeypatch.setattr(burnlib, "time_callable", fake_time)
+    monkeypatch.setattr(burnlib, "dispatch_fence_s", lambda: host_s)
+    cal = burnlib._calibrate_on_device(
+        burnlib.DEFAULT_SHAPE, "bfloat16", jax.devices()[0], 64, 256)
+    assert cal.ns_per_iter == pytest.approx(per_iter_s * 1e9, rel=1e-6)

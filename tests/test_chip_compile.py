@@ -1,0 +1,198 @@
+"""The main path's Pallas kernels compile for the chip: each is lowered
+at llama3_8b / mixtral_8x7b widths for a DESCRIBED ``v5e:2x2`` (the TPU
+compiler is installed where no TPU is attached) and must come out as a
+``tpu_custom_call``.  Interpret mode — what every other kernel test runs
+— accepts programs the TPU lowering refuses (a block that is not a
+multiple of the (8, 128) tile, too much VMEM), so these compiles are what
+guards a kernel between chip runs.  A compile that passes is not a chip
+run: ``chip_smoke.py`` executes the same kernels against their
+references.
+
+This is the only file that describes a topology, and it does so inside
+a fixture: only one process at a time may load the TPU's library, so the
+call must not run while any module is imported (every xdist worker
+imports every test file), and the compiles run in this process, not in a
+child.
+"""
+from __future__ import annotations
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+# llama3_8b widths (dlnetbench_tpu/data/models/llama3_8b.json)
+D, F, HQ, HKV, DH = 4096, 14336, 32, 8, 128
+TOKENS = 12288          # B=2 x S=6144, the bench step's token count
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+QDTYPE = {"int8": jnp.int8, "float8": jnp.float8_e4m3fn}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no TPU compiler here: nothing to ask
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent
+    cache but cannot be read back without the chip: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def for_chip(one_chip, no_persistent_cache, monkeypatch):
+    """``compile_for_chip(fn, *shapes)``: the compiled text of ``fn`` at
+    ``(shape, dtype)`` arguments on one described chip, with the kernels
+    in Mosaic mode (``pallas_common.interpret_mode`` would say "CPU"
+    here, and every kernel file reads it through the module)."""
+    from dlnetbench_tpu.ops import pallas_common
+    monkeypatch.setattr(pallas_common, "interpret_mode", lambda: False)
+
+    def compile_for_chip(fn, *shapes) -> str:
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in shapes]
+        return jax.jit(fn).lower(*args).compile().as_text()
+    return compile_for_chip
+
+
+def ops_module(name: str):
+    """``dlnetbench_tpu.ops.<name>`` the module: the package re-exports
+    a function ``flash_attention`` that shadows its submodule."""
+    return importlib.import_module(f"dlnetbench_tpu.ops.{name}")
+
+
+def kernels_in(text: str) -> int:
+    return text.count("tpu_custom_call")
+
+
+QKV = [((2, 6144, HQ, DH), BF16), ((2, 6144, HKV, DH), BF16),
+       ((2, 6144, HKV, DH), BF16)]
+QKV_LONG = [((1, 16384, HQ, DH), BF16), ((1, 16384, HKV, DH), BF16),
+            ((1, 16384, HKV, DH), BF16)]
+
+
+def grad_of(attn):
+    return jax.grad(lambda q, k, v: jnp.sum(attn(q, k, v).astype(F32)),
+                    argnums=(0, 1, 2))
+
+
+def test_flash_forward(for_chip):
+    fa = ops_module("flash_attention")
+    assert kernels_in(for_chip(fa.flash_attention, *QKV)) == 1
+
+
+def test_flash_forward_backward(for_chip):
+    fa = ops_module("flash_attention")
+    # forward, dq, dkv
+    assert kernels_in(for_chip(grad_of(fa.flash_attention), *QKV)) == 3
+
+
+@pytest.mark.parametrize("mask", ["window", "segments"])
+def test_splash_forward_backward(for_chip, mask):
+    from dlnetbench_tpu.ops.attention_mask import MaskSpec
+    fa = ops_module("flash_attention")
+    spec = (MaskSpec(window=4096) if mask == "window"
+            else MaskSpec(seg_avg=2048, seg_seed=0))
+    text = for_chip(grad_of(lambda q, k, v: fa.splash_attention(
+        q, k, v, spec)), *QKV_LONG)
+    assert kernels_in(text) == 3
+
+
+def test_mlp_backward_dgdu(for_chip):
+    mb = ops_module("mlp_backward")
+    text = for_chip(mb.dgdu, ((TOKENS, D), BF16), ((F, D), BF16),
+                    ((TOKENS, F), BF16), ((TOKENS, F), BF16))
+    assert kernels_in(text) == 1
+
+
+def test_mlp_backward_dwd(for_chip):
+    mb = ops_module("mlp_backward")
+    text = for_chip(mb.dwd, ((TOKENS, F), BF16), ((TOKENS, F), BF16),
+                    ((TOKENS, D), BF16))
+    assert kernels_in(text) == 1
+
+
+@pytest.mark.parametrize("collect_amax", [False, True],
+                         ids=["plain", "collect_amax"])
+@pytest.mark.parametrize("fmt", ["int8", "float8"])
+def test_fused_matmul(for_chip, fmt, collect_amax):
+    """With ``collect_amax`` (delayed scaling) the amax side output was
+    a (1, 1) SMEM block the lowering refused."""
+    qm = ops_module("quantized_matmul")
+    text = for_chip(
+        lambda x, w, sw, sx: qm.fused_matmul(x, w, sw, sx, fmt=fmt,
+                                             collect_amax=collect_amax),
+        ((TOKENS, D), BF16), ((D, F), QDTYPE[fmt]), ((), F32), ((), F32))
+    assert kernels_in(text) == 1
+
+
+# the serving page layout: 32 slots, 2048 pages of 16 tokens, 128 pages
+# (2048 tokens) a sequence
+SLOTS, PAGES, PAGE, PAGES_PER_SEQ = 32, 2048, 16, 128
+
+
+@pytest.mark.parametrize("fmt", ["int8", "float8"])
+def test_quant_paged_attention(for_chip, fmt):
+    """The per-page scale operand was a (1, 1, 8) VMEM block the
+    lowering refused."""
+    pq = ops_module("paged_attention_quant")
+    pool = ((HKV, PAGES, PAGE, DH), QDTYPE[fmt])
+    text = for_chip(
+        lambda q, k, v, ks, vs, n, idx: pq.quant_paged_attention(
+            q, k, v, ks, vs, n, idx, fmt=fmt, pages_per_compute_block=8),
+        ((SLOTS, HQ, DH), BF16), pool, pool, ((HKV, PAGES), F32),
+        ((HKV, PAGES), F32), ((SLOTS,), I32),
+        ((SLOTS, PAGES_PER_SEQ), I32))
+    assert kernels_in(text) == 1
+
+
+@pytest.mark.parametrize("page", [8, 16])
+def test_jax_paged_attention_at_repo_layout(for_chip, page):
+    """jax's own kernel, called as ``serving/kv_cache.py`` calls it."""
+    from dlnetbench_tpu.serving.kv_cache import paged_attention_decode
+    pool = ((HKV, PAGES, page, DH), BF16)
+    text = for_chip(
+        lambda q, k, v, n, idx: paged_attention_decode(
+            q, k, v, n, idx, impl="pallas"),
+        ((SLOTS, HQ, DH), BF16), pool, pool, ((SLOTS,), I32),
+        ((SLOTS, PAGES_PER_SEQ), I32))
+    assert kernels_in(text) == 1
+
+
+@pytest.mark.parametrize("fmt", [None, "int8"], ids=["bf16", "int8"])
+def test_grouped_matmul(for_chip, fmt):
+    """mixtral_8x7b's expert FFN (8 experts, same D and F).  The
+    per-expert scale operand was a (1, 1) SMEM block the lowering
+    refused — on the bf16 path too, which carries it unused."""
+    gm = ops_module("grouped_matmul")
+    e, c = 8, 2048
+    x, counts = ((e, c, D), BF16), ((e,), I32)
+    if fmt is None:
+        text = for_chip(
+            lambda x, w, n: gm.grouped_matmul(x, w, counts=n),
+            x, ((e, D, F), BF16), counts)
+    else:
+        text = for_chip(
+            lambda x, w, n, sx, sw: gm.grouped_matmul(
+                x, w, counts=n, sx=sx, sw=sw, fmt=fmt),
+            x, ((e, D, F), QDTYPE[fmt]), counts, ((e,), F32), ((e,), F32))
+    assert kernels_in(text) == 1

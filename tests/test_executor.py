@@ -3,6 +3,8 @@ donation + rebinding safety, chained-fence timing, and the no-compile-in-
 warmup property that keeps estimate_runs honest."""
 from __future__ import annotations
 
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -189,12 +191,27 @@ def test_run_proxy_chain_partitioning(eight_devices):
     assert calls["comp"] == 6
 
 
-def test_persistent_cache_opt_in(tmp_path, monkeypatch, eight_devices):
-    """DLNB_COMPILE_CACHE_DIR wires jax's persistent compilation cache:
-    compiling through the executor populates the directory."""
-    monkeypatch.setenv(executor.ENV_CACHE_DIR, str(tmp_path))
-    monkeypatch.setattr(executor, "_CACHE_CONFIGURED", False)
+def test_persistent_cache_placement(tmp_path, monkeypatch, eight_devices):
+    """The compile cache is placed from outside: where
+    JAX_COMPILATION_CACHE_DIR is set that directory is used (and
+    compiling through the executor populates it); where it is not, the
+    one fixed path inside the checkout — never a temporary name."""
+    knobs = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    before = {k: getattr(jax.config, k) for k in knobs}
     try:
+        # conftest turns the cache off for the session
+        jax.config.update("jax_enable_compilation_cache", True)
+        monkeypatch.delenv(executor.ENV_CACHE_DIR, raising=False)
+        repo = Path(__file__).resolve().parent.parent
+        assert executor.enable_persistent_cache() == str(repo / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(
+            repo / ".jax_cache")
+
+        monkeypatch.setenv(executor.ENV_CACHE_DIR, str(tmp_path))
+        assert executor.enable_persistent_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
         mesh = _mesh4(eight_devices)
         prog, _, _ = _carry_program(mesh)
         meta: dict = {}
@@ -204,8 +221,10 @@ def test_persistent_cache_opt_in(tmp_path, monkeypatch, eight_devices):
                    for f in tmp_path.iterdir()), \
             "compile cache dir stayed empty"
     finally:  # do not leave the global cache pointed at a dead tmpdir
-        jax.config.update("jax_compilation_cache_dir", None)
-        executor._CACHE_CONFIGURED = False
+        for k, v in before.items():
+            jax.config.update(k, v)
+        from jax.experimental.compilation_cache import compilation_cache
+        compilation_cache.reset_cache()
 
 
 def test_estimate_runs_sees_execution_only(eight_devices):

@@ -1,10 +1,9 @@
 """Tier-1-adjacent guards.
 
-1. No direct jax shard_map imports outside the compat shim: ``from jax
-   import shard_map`` only exists in jax >= 0.6, and 9 test files failed
-   COLLECTION on this toolchain (jax 0.4.x) before
-   ``utils/jax_compat.py`` — a grep guard keeps the regression from
-   coming back one import at a time.
+1. No direct jax shard_map imports outside ``utils/jax_compat.py``:
+   ``shard_map`` has moved between jax releases before, and each move
+   broke collection of every file that imported it directly — one
+   import point keeps the next move a one-file change.
 2. ``pytest --collect-only`` must report zero errors: a collection error
    silently removes an entire file's tests from the tier-1 count.
 """
@@ -41,13 +40,12 @@ def test_no_direct_shard_map_imports():
             offenders.append(rel)
     assert not offenders, (
         f"direct jax shard_map imports outside {SHIM}: {offenders} — "
-        f"import it from dlnetbench_tpu.utils.jax_compat instead "
-        f"(version-portable, translates check_vma<->check_rep)")
+        f"import it from dlnetbench_tpu.utils.jax_compat instead")
 
 
 def test_collection_is_clean():
-    """Zero collection errors — the seed shipped with 9, which silently
-    removed ~a third of the suite from every tier-1 run."""
+    """Zero collection errors — one silently removes a whole file's
+    tests from every tier-1 run."""
     proc = subprocess.run(
         [sys.executable, "-m", "pytest", "tests/", "-q", "--collect-only",
          "-p", "no:cacheprovider"],

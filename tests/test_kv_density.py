@@ -238,38 +238,6 @@ def test_quant_tuning_site_is_its_own_key():
                                impl="gather", pages_per_compute_block=3)
 
 
-@pytest.mark.tpu_only
-def test_quant_kernel_parity_on_chip():
-    """On-chip: the dequantizing Pallas kernel against the gather
-    fallback on TPU-friendly shapes (collectable everywhere,
-    auto-skipped off-TPU via the conftest hook)."""
-    from dlnetbench_tpu.ops.paged_attention_quant import \
-        quant_paged_attention
-    rng = np.random.RandomState(0)
-    hkv, pages, s, dh = 2, 32, 16, 128
-    kq = jnp.asarray(rng.randint(-127, 127, (hkv, pages, s, dh)),
-                     jnp.int8)
-    vq = jnp.asarray(rng.randint(-127, 127, (hkv, pages, s, dh)),
-                     jnp.int8)
-    ks = jnp.asarray(np.abs(rng.randn(hkv, pages)) * 0.02 + 1e-4,
-                     jnp.float32)
-    vs = jnp.asarray(np.abs(rng.randn(hkv, pages)) * 0.02 + 1e-4,
-                     jnp.float32)
-    q = jnp.asarray(rng.randn(4, 8, dh), jnp.float32) * dh**-0.5
-    lengths = jnp.asarray([40, 128, 16, 70], jnp.int32)
-    pidx = jnp.asarray(np.arange(4 * 8).reshape(4, 8) % pages,
-                       jnp.int32)
-    ref = paged_attention_decode(q, kq, vq, lengths, pidx,
-                                 k_scale=ks, v_scale=vs, fmt="int8",
-                                 impl="gather")
-    for ppcb in (1, 2, 8):
-        got = quant_paged_attention(q, kq, vq, ks, vs, lengths, pidx,
-                                    fmt="int8",
-                                    pages_per_compute_block=ppcb)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   rtol=2e-2, atol=2e-2)
-
-
 # ---------------------------------------------------------------------
 # engine end-to-end per cache dtype
 

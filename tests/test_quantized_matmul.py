@@ -4,9 +4,8 @@ reference: int8 EXACT (shared scale definition + associative int32
 accumulation), fp8 within e4m3 quantization tolerance, delayed-scaling
 state threading, and the transformer config plumbing.
 
-The on-chip paired A/B harness test at the bottom is ``tpu_only``:
-collectable on the CPU mesh, skipped there (conftest), measured on the
-real chip."""
+On the chip the same kernels run at the bench shape in
+``chip_smoke.py``'s kernels phase."""
 from __future__ import annotations
 
 import dataclasses
@@ -358,23 +357,3 @@ def test_forward_requires_qstate_when_delayed():
                                 cfg.vocab_size)
     with pytest.raises(ValueError, match="qstate"):
         tfm.forward(params, tokens, cfg)
-
-
-@pytest.mark.tpu_only
-def test_fused_ab_harness_on_chip():
-    """The paired fused-vs-composed A/B at the REAL bench shape — the
-    on-chip measurement harness behind bench.py's int8_fused_ab /
-    fp8_fused_ab lines.  Collectable everywhere; the CPU mesh skips it
-    (conftest) — interpret-mode kernels at 12288x4096x14336 would take
-    hours there and measure nothing."""
-    import bench
-    from dlnetbench_tpu.models.bench_step import bench_card
-
-    card = bench_card()
-    dev = jax.devices()[0]
-    for fmt in ("int8", "float8"):
-        line = bench._bench_quant_fused_ab(card, "tpu_v5e", dev, fmt)
-        assert line is not None
-        for key in ("value", "best", "band", "n", "composed", "fused",
-                    "fused_delayed", "ratio_fused_vs_composed"):
-            assert key in line, key

@@ -220,8 +220,8 @@ def test_paged_attention_block_parity_and_validation(eight_devices):
     satellite — the old inline ``min(pages, 8)`` hard-code): results
     are identical across 3 explicit block values (the knob sizes the
     kernel grid, never the math — the gather impl computes full
-    attention regardless, and the tpu_only case below locks the Pallas
-    kernel to the same contract), it flows through
+    attention regardless; ``chip_smoke.py``'s kernels phase holds the
+    Pallas kernel to the same contract on the chip), it flows through
     ``sharded_paged_attention``, and a non-divisor is refused loudly on
     every impl."""
     from dlnetbench_tpu.parallel.mesh import make_flat_mesh
@@ -249,49 +249,6 @@ def test_paged_attention_block_parity_and_validation(eight_devices):
     with pytest.raises(ValueError, match="does not divide"):
         paged_attention_decode(q, kp, vp, lengths, pidx, impl="gather",
                                pages_per_compute_block=4)
-
-
-@pytest.mark.tpu_only
-def test_pallas_paged_attention_block_parity():
-    """On-chip: the Pallas kernel itself across 3 block values — the
-    knob moves the grid, never the numbers."""
-    q = jax.random.normal(jax.random.key(7), (4, 8, 128), jnp.float32)
-    kp = jax.random.normal(jax.random.key(8), (2, 32, 16, 128),
-                           jnp.float32)
-    vp = jax.random.normal(jax.random.key(9), (2, 32, 16, 128),
-                           jnp.float32)
-    lengths = jnp.asarray([40, 128, 16, 70], jnp.int32)
-    pidx = jnp.asarray(np.arange(4 * 8).reshape(4, 8) % 32, jnp.int32)
-    ref = paged_attention_decode(q, kp, vp, lengths, pidx,
-                                 impl="pallas",
-                                 pages_per_compute_block=8)
-    for blk in (1, 2, 4):
-        got = paged_attention_decode(q, kp, vp, lengths, pidx,
-                                     impl="pallas",
-                                     pages_per_compute_block=blk)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   rtol=1e-5, atol=1e-5)
-
-
-@pytest.mark.tpu_only
-def test_pallas_paged_attention_matches_gather():
-    """On-chip: the Pallas paged_attention kernel against the gather
-    fallback (collectable everywhere, runs on TPU only — the
-    conftest.py tpu_only skip hook)."""
-    q = jax.random.normal(jax.random.key(7), (4, 8, 128),
-                          jnp.float32)
-    kp = jax.random.normal(jax.random.key(8), (2, 32, 16, 128),
-                           jnp.float32)
-    vp = jax.random.normal(jax.random.key(9), (2, 32, 16, 128),
-                           jnp.float32)
-    lengths = jnp.asarray([40, 128, 16, 70], jnp.int32)
-    pidx = jnp.asarray(np.arange(4 * 8).reshape(4, 8) % 32, jnp.int32)
-    ref = paged_attention_decode(q, kp, vp, lengths, pidx,
-                                 impl="gather")
-    got = paged_attention_decode(q, kp, vp, lengths, pidx,
-                                 impl="pallas")
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=2e-2, atol=2e-2)
 
 
 # ---------------------------------------------------------------------

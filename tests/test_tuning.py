@@ -185,7 +185,7 @@ def test_search_elects_min_median_and_commits_band(tmp_path):
 def test_search_prunes_band_disjoint_losers():
     """A candidate whose best-of-two samples lands strictly above the
     incumbent's whole band is cut after two rounds (never one — a
-    single draw can hit the slow tunnel mode); a band-ambiguous one
+    single draw can hit a slow mode); a band-ambiguous one
     gets its full rounds."""
     seen = []
     # fast's samples SPREAD (band [1.0, 1.2]); slow's best-of-two is
