@@ -42,6 +42,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import re
 import time
 from collections.abc import Callable
 from pathlib import Path
@@ -96,6 +97,140 @@ def enable_persistent_cache() -> str:
     return cache_dir
 
 
+# --------------------------------------------------------------------
+# From compiled HLO text to {instruction name: scope}: the join between
+# what a device trace prints first in each event's name (``%fusion.54 =
+# ...``) and the ``jax.named_scope`` the model wore where that work was
+# written (``spans.SCOPES``).
+
+_HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$")
+_HLO_OPCODE = re.compile(r"\s(fusion|dot|convolution)\(")
+_HLO_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_TRANSFORM = re.compile(r"(\w+)\((.*)\)")
+
+
+def scope_of_op_name(op_name: str, vocabulary=spans.SCOPES) -> str | None:
+    """The innermost vocabulary scope of an ``op_name`` path, with the
+    transforms peeled off (``jit(f)/transpose(jvp(attn))/dot_general``
+    -> ``attn``), so that forward and backward of a layer land together;
+    None where the path holds none."""
+    for part in reversed(op_name.split("/")):
+        # jvp(attn), transpose(jvp(attn)) are the scope transformed;
+        # jit(attn) is a function that happens to be called so
+        while (m := _HLO_TRANSFORM.fullmatch(part)) and m.group(1) != "jit":
+            part = m.group(2)
+        if part in vocabulary:
+            return part
+    return None
+
+
+def hlo_module_name(hlo_text: str) -> str:
+    m = re.match(r"HloModule\s+([\w.\-]+)", hlo_text)
+    return m.group(1) if m else ""
+
+
+def hlo_op_scopes(hlo_text: str, vocabulary=spans.SCOPES) -> dict[str, str]:
+    """{instruction name: scope} of every instruction of every
+    computation of a compiled module's text.
+
+    An instruction's scope is that of its own ``op_name``.  A fusion
+    mixes instructions of several scopes (a weight gradient with the
+    SGD update fused in, a residual add riding with the next norm), so
+    its rule is fixed here: the scope of the ``dot``/``convolution``
+    inside the computation it calls where that has one, else of that
+    computation's root (where the root carries none, being a tuple of
+    outputs or a bitcast, of the last instruction before it that does),
+    else its own.  A custom call (a Pallas kernel) keeps its own.
+    ``spans.OTHER_SCOPE`` where there is none."""
+    own: dict[str, str | None] = {}     # instruction -> scope or None
+    fusions: dict[str, str] = {}        # fusion instruction -> callee
+    matmul: dict[str, str] = {}         # computation -> its dot's scope
+    root: dict[str, str] = {}           # computation -> its root's scope
+    comp = None
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if m is None:
+            c = _HLO_COMPUTATION.match(line)
+            if c is not None:
+                comp = c.group(1)
+            continue
+        head, _, meta = line.partition(", metadata={")
+        name = _HLO_OP_NAME.search(meta)
+        scope = scope_of_op_name(name.group(1), vocabulary) if name else None
+        own[m.group(1)] = scope
+        op = _HLO_OPCODE.search(head)
+        kind = op.group(1) if op else None
+        if kind == "fusion":
+            callee = _HLO_CALLS.search(head)
+            if callee:
+                fusions[m.group(1)] = callee.group(1)
+        elif kind in ("dot", "convolution") and scope and comp is not None:
+            matmul.setdefault(comp, scope)
+        if scope and comp is not None:
+            # text order is a topological order and the root comes last
+            root[comp] = scope
+    for inst, callee in fusions.items():
+        own[inst] = matmul.get(callee) or root.get(callee) or own[inst]
+    return {k: v or spans.OTHER_SCOPE for k, v in own.items()}
+
+
+class _Compiled:
+    """What ``CompiledProgram`` and ``CompiledStep`` share: XLA's
+    analyses of the executable, its text, and the op->scope table made
+    from that text — computed when asked and kept, never at build when
+    tracing is off."""
+
+    _compiled = None
+    _op_scopes = None
+    stats: dict
+
+    # per-program cost stats as first-class attributes (not just the
+    # global_meta channel compile_programs writes): the attribution
+    # engine joins a program's OWN flops/bytes with its OWN timers —
+    # e.g. bench.py's chained microbenches, which never go through
+    # compile_programs
+    @property
+    def cost_analysis(self) -> dict | None:
+        """XLA's {flops, bytes_accessed} for THIS executable, or None
+        when the backend implements no cost analysis."""
+        return self.stats.get("cost_analysis")
+
+    @property
+    def memory_analysis(self) -> dict | None:
+        return self.stats.get("memory_analysis")
+
+    def as_text(self) -> str:
+        """The compiled HLO: what will run, Pallas kernels included
+        (each appears as a ``tpu_custom_call``)."""
+        return self._compiled.as_text()
+
+    def op_scopes(self) -> dict[str, str]:
+        """{instruction name: ``spans.SCOPES`` name or "other"} of the
+        compiled program (``hlo_op_scopes``).
+
+        Read from the executable's own text, so an executable loaded
+        from the persistent cache gives the scopes it was compiled
+        with: jax leaves ``op_name`` metadata out of the cache key, and
+        an edit that only moves a ``spans.scope`` finds the old
+        executable and the old table, with no warning.  Clear the cache
+        directory (``enable_persistent_cache``) after such an edit."""
+        if self._op_scopes is None:
+            self._op_scopes = hlo_op_scopes(self.as_text())
+        return self._op_scopes
+
+    def _register_op_scopes(self) -> None:
+        """Hand the table to the current tracer, keyed by the module's
+        name as a device trace prints it; nothing when tracing is off."""
+        tracer = spans.current()
+        if tracer is not None:
+            text = self.as_text()
+            self._op_scopes = hlo_op_scopes(text)
+            tracer.register_op_scopes(hlo_module_name(text),
+                                      self._op_scopes)
+
+
 @dataclasses.dataclass
 class Program:
     """One jittable callable plus the concrete buffers it runs on.
@@ -112,7 +247,7 @@ class Program:
     compiler_options: dict | None = None
 
 
-class CompiledProgram:
+class CompiledProgram(_Compiled):
     """A zero-arg callable around an AOT-compiled executable.
 
     Owns the argument buffers: after each call, donated arguments are
@@ -150,6 +285,7 @@ class CompiledProgram:
                 lowered = jax.jit(program.fn,
                                   donate_argnums=donate).lower(*args)
             self._compiled = lowered.compile(program.compiler_options)
+            self._register_op_scopes()
         compile_ms = (time.perf_counter() - t0) * 1e3
 
         # donation consumes the buffer, and sibling programs (full /
@@ -174,26 +310,6 @@ class CompiledProgram:
         """The program's current argument buffers (for re-tracing)."""
         return tuple(self._args)
 
-    # per-program cost stats as first-class attributes (not just the
-    # global_meta channel compile_programs writes): the attribution
-    # engine joins a program's OWN flops/bytes with its OWN timers —
-    # e.g. bench.py's chained microbenches, which never go through
-    # compile_programs
-    @property
-    def cost_analysis(self) -> dict | None:
-        """XLA's {flops, bytes_accessed} for THIS executable, or None
-        when the backend implements no cost analysis."""
-        return self.stats.get("cost_analysis")
-
-    @property
-    def memory_analysis(self) -> dict | None:
-        return self.stats.get("memory_analysis")
-
-    def as_text(self) -> str:
-        """The compiled HLO: what will run, Pallas kernels included
-        (each appears as a ``tpu_custom_call``)."""
-        return self._compiled.as_text()
-
     def __call__(self):
         outs = self._compiled(*self._args)
         if self._rebind:
@@ -216,7 +332,7 @@ class CompiledProgram:
         self._args = list(jax.tree.unflatten(self._treedef, flat_args))
 
 
-class CompiledStep:
+class CompiledStep(_Compiled):
     """An AOT-compiled callable that still takes per-call arguments.
 
     ``CompiledProgram`` owns fixed buffers and exposes a zero-arg
@@ -249,6 +365,7 @@ class CompiledStep:
             lowered = jax.jit(fn, donate_argnums=donate).lower(
                 *example_args)
             self._compiled = lowered.compile(compiler_options)
+            self._register_op_scopes()
         # abstract output leaves (shape/dtype), kept so subclasses can
         # validate structural contracts (CompiledLoop's carry check)
         # without re-tracing
@@ -257,18 +374,6 @@ class CompiledStep:
             (time.perf_counter() - t0) * 1e3, 3),
             "donated_argnums": list(donate)}
         self.stats.update(_analyses(self._compiled))
-
-    @property
-    def cost_analysis(self) -> dict | None:
-        return self.stats.get("cost_analysis")
-
-    @property
-    def memory_analysis(self) -> dict | None:
-        return self.stats.get("memory_analysis")
-
-    def as_text(self) -> str:
-        """The compiled HLO (see ``CompiledProgram.as_text``)."""
-        return self._compiled.as_text()
 
     def __call__(self, *args):
         return self._compiled(*args)

@@ -24,6 +24,17 @@ side the same artifact-grade story:
   fence), per-device tracks below, collective ops colored by kind via
   ``profiling.classify_op``.
 
+* While a ``jax.profiler`` trace runs, an enabled span is also a
+  ``jax.profiler.TraceAnnotation``: it lies on the host line of the same
+  ``.xplane.pb`` as the device's operations, on the profiler's one
+  clock, its numeric and string attrs as the event's stats.  A gap on
+  the device can then be put down to the span that covers it.
+* ``SCOPES`` is the fixed vocabulary of ``jax.named_scope`` names the
+  model step wears (``scope(name)``); ``core/executor.py`` maps each
+  compiled instruction back to one of them (``op_scopes``), and a
+  tracer keeps the tables of the steps compiled while it was enabled
+  (``Tracer.export``).
+
 The tracer is deliberately NOT a per-collective measurement channel —
 that is the decomposition harness (proxies/base.py) and the device
 trace (metrics/profiling.py).  Spans attribute *phases* of the harness
@@ -35,6 +46,24 @@ import json
 import threading
 import time
 from pathlib import Path
+
+# ---------------------------------------------------------------------
+# The model step's layers, by name.  Declared here once: the models wear
+# them through ``scope()``, the executor's op->scope table and the
+# benchmark's per-layer readers take them from here.
+SCOPES = ("embed", "attn", "mlp", "moe.router", "moe.dispatch",
+          "moe.experts", "moe.combine", "head_loss", "optimizer")
+OTHER_SCOPE = "other"   # an instruction under none of them
+
+
+def scope(name: str):
+    """``jax.named_scope`` of one vocabulary name: metadata on the
+    operations traced under it, nothing at run time."""
+    if name not in SCOPES:
+        raise ValueError(f"scope {name!r} is not in spans.SCOPES {SCOPES}")
+    import jax
+    return jax.named_scope(name)
+
 
 # ---------------------------------------------------------------------
 # Tracer core.
@@ -60,7 +89,7 @@ class _Span:
     record to its tracer on __exit__.  Exceptions propagate (the span
     still closes, marked ``error``) so a failing phase stays visible in
     the timeline instead of vanishing with its context."""
-    __slots__ = ("_tracer", "name", "attrs", "_t0", "_depth")
+    __slots__ = ("_tracer", "name", "attrs", "_t0", "_depth", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict | None):
         self._tracer = tracer
@@ -69,11 +98,18 @@ class _Span:
 
     def __enter__(self):
         self._depth = self._tracer._push(self.name)
+        # the same region on the profiler's clock (a no-op flag test
+        # while no profiler trace runs)
+        self._ann = self._tracer._annotation(
+            self.name, **{k: v for k, v in (self.attrs or {}).items()
+                          if isinstance(v, (int, float, str))})
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
+        self._ann.__exit__(exc_type, exc, tb)
         tr = self._tracer
         tr._pop()
         if exc_type is not None:
@@ -90,8 +126,13 @@ class Tracer:
     one tracer per measured run is the intended shape."""
 
     def __init__(self):
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
         self.origin = time.perf_counter()
         self.spans: list[dict] = []
+        # module name -> {instruction name: scope} of the steps compiled
+        # while this tracer was current (core/executor.py registers them)
+        self.op_scopes: dict[str, dict[str, str]] = {}
         self._lock = threading.Lock()
         self._local = threading.local()
         # tid -> stack of OPEN span names, readable from other threads:
@@ -138,6 +179,18 @@ class Tracer:
     # -- public ------------------------------------------------------
     def span(self, name: str, **attrs) -> _Span:
         return _Span(self, name, attrs or None)
+
+    def register_op_scopes(self, module: str, table: dict) -> None:
+        with self._lock:
+            self.op_scopes[module] = table
+
+    def export(self) -> dict:
+        """What a run's reader takes at its end, as plain data: the
+        finished spans and the op->scope tables."""
+        with self._lock:
+            return {"spans": [dict(s) for s in self.spans],
+                    "op_scopes": {m: dict(t)
+                                  for m, t in self.op_scopes.items()}}
 
 
 # Module-level current tracer.  ``None`` means disabled — the common

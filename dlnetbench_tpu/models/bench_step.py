@@ -80,7 +80,13 @@ def make_train_k(cfg, k: int, lr: float = 1e-3):
     ``lr`` is the SGD step: at the bench's 1e-3 the bf16 weights barely
     move (the headline measures time); a caller that wants to see the
     loss fall over the chain passes a larger one."""
+    from dlnetbench_tpu.metrics.spans import scope
     from dlnetbench_tpu.models import transformer as tfm
+
+    def sgd(p, g):
+        with scope("optimizer"):
+            return jax.tree.map(lambda a, b: a - lr * b.astype(a.dtype),
+                                p, g)
 
     if tfm.needs_qstate(cfg):
         def train_k(carry, t):
@@ -88,18 +94,14 @@ def make_train_k(cfg, k: int, lr: float = 1e-3):
                 p, qs = carry
                 (loss, new_qs), g = jax.value_and_grad(
                     tfm.loss_fn, has_aux=True)(p, t, cfg, qs)
-                p = jax.tree.map(lambda a, b: a - lr * b.astype(a.dtype),
-                                 p, g)
-                return (p, new_qs), loss
+                return (sgd(p, g), new_qs), loss
             return jax.lax.scan(body, carry, None, length=k)
         return train_k
 
     def train_k(p, t):
         def body(p, _):
             loss, g = jax.value_and_grad(tfm.loss_fn)(p, t, cfg)
-            p = jax.tree.map(lambda a, b: a - lr * b.astype(a.dtype),
-                             p, g)
-            return p, loss
+            return sgd(p, g), loss
         return jax.lax.scan(body, p, None, length=k)
     return train_k
 

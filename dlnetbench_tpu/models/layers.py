@@ -13,6 +13,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from dlnetbench_tpu.metrics.spans import scope
+
 _F32 = jnp.float32
 
 
@@ -258,10 +260,11 @@ def router_logits(x, w_router):
 def moe_router(x, w_router, top_k: int):
     """Token router: returns (weights [T, k], expert indices [T, k]).
     Softmax over the selected top-k (Mixtral convention)."""
-    logits = router_logits(x, w_router)
-    top_vals, top_idx = jax.lax.top_k(logits, top_k)
-    weights = jax.nn.softmax(top_vals, axis=-1)
-    return weights, top_idx
+    with scope("moe.router"):
+        logits = router_logits(x, w_router)
+        top_vals, top_idx = jax.lax.top_k(logits, top_k)
+        weights = jax.nn.softmax(top_vals, axis=-1)
+        return weights, top_idx
 
 
 def moe_dense(x2d, w_router, w_gate, w_up, w_down, top_k: int):
@@ -272,15 +275,20 @@ def moe_dense(x2d, w_router, w_gate, w_up, w_down, top_k: int):
     t, d = x2d.shape
     e = w_gate.shape[0]
     weights, idx = moe_router(x2d, w_router, top_k)        # [T,k], [T,k]
-    # combine[t, e] = sum_k weights[t,k] * (idx[t,k]==e)
-    combine = jnp.sum(jax.nn.one_hot(idx, e, dtype=_F32)
-                      * weights[..., None], axis=1)        # [T, E]
-    h = jnp.einsum("td,edh->teh", x2d, w_gate, preferred_element_type=_F32)
-    u = jnp.einsum("td,edh->teh", x2d, w_up, preferred_element_type=_F32)
-    h = jax.nn.silu(h) * u
-    y = jnp.einsum("teh,ehd->ted", h.astype(x2d.dtype), w_down,
-                   preferred_element_type=_F32)            # [T, E, D]
-    return jnp.einsum("ted,te->td", y, combine).astype(x2d.dtype)
+    with scope("moe.router"):
+        # combine[t, e] = sum_k weights[t,k] * (idx[t,k]==e)
+        combine = jnp.sum(jax.nn.one_hot(idx, e, dtype=_F32)
+                          * weights[..., None], axis=1)    # [T, E]
+    with scope("moe.experts"):
+        h = jnp.einsum("td,edh->teh", x2d, w_gate,
+                       preferred_element_type=_F32)
+        u = jnp.einsum("td,edh->teh", x2d, w_up,
+                       preferred_element_type=_F32)
+        h = jax.nn.silu(h) * u
+        y = jnp.einsum("teh,ehd->ted", h.astype(x2d.dtype), w_down,
+                       preferred_element_type=_F32)        # [T, E, D]
+    with scope("moe.combine"):
+        return jnp.einsum("ted,te->td", y, combine).astype(x2d.dtype)
 
 
 def moe_dispatch(x2d, w_router, num_experts: int, top_k: int,
@@ -301,21 +309,24 @@ def moe_dispatch(x2d, w_router, num_experts: int, top_k: int,
     weights, idx = moe_router(x2d, w_router, top_k)         # [T,k] each
     cap = max(1, int(capacity_factor * t * top_k / e))
 
-    onehot = jax.nn.one_hot(idx, e, dtype=_F32)             # [T, k, E]
-    gate = jnp.sum(onehot * weights[..., None], axis=1)     # [T, E]
-    mask = jnp.sum(onehot, axis=1)                          # [T, E] 0/1
-    pos = jnp.cumsum(mask, axis=0) - 1.0                    # arrival order
-    keep = mask * (pos < cap)
-    disp = jax.nn.one_hot(pos.astype(jnp.int32), cap, dtype=_F32) \
-        * keep[..., None]                                   # [T, E, C]
-    xe = jnp.einsum("tec,td->ecd", disp, x2d.astype(_F32))  # [E, C, d]
+    with scope("moe.router"):
+        onehot = jax.nn.one_hot(idx, e, dtype=_F32)         # [T, k, E]
+        gate = jnp.sum(onehot * weights[..., None], axis=1)  # [T, E]
+    with scope("moe.dispatch"):
+        mask = jnp.sum(onehot, axis=1)                      # [T, E] 0/1
+        pos = jnp.cumsum(mask, axis=0) - 1.0                # arrival order
+        keep = mask * (pos < cap)
+        disp = jax.nn.one_hot(pos.astype(jnp.int32), cap, dtype=_F32) \
+            * keep[..., None]                               # [T, E, C]
+        xe = jnp.einsum("tec,td->ecd", disp, x2d.astype(_F32))  # [E, C, d]
     return xe, disp, gate
 
 
 def moe_combine(out, disp, gate):
     """Scatter per-expert outputs [E, C, d] back to tokens [T, d] with
     the dispatch one-hots and combine weights from ``moe_dispatch``."""
-    return jnp.einsum("ecd,tec->td", out, disp * gate[..., None])
+    with scope("moe.combine"):
+        return jnp.einsum("ecd,tec->td", out, disp * gate[..., None])
 
 
 def moe_sparse(x2d, w_router, w_gate, w_up, w_down, top_k: int,
@@ -326,14 +337,16 @@ def moe_sparse(x2d, w_router, w_gate, w_up, w_down, top_k: int,
     moe_dense exactly (tests/test_models.py pins this)."""
     e = w_gate.shape[0]
     xe, disp, gate = moe_dispatch(x2d, w_router, e, top_k, capacity_factor)
-    xe = xe.astype(x2d.dtype)
-    h = jax.nn.silu(jnp.einsum("ecd,edh->ech", xe, w_gate,
-                               preferred_element_type=_F32))
-    h = h * jnp.einsum("ecd,edh->ech", xe, w_up,
-                       preferred_element_type=_F32)
-    out = jnp.einsum("ech,ehd->ecd", h.astype(x2d.dtype), w_down,
-                     preferred_element_type=_F32)           # [E, C, d]
-    return moe_combine(out, disp, gate).astype(x2d.dtype)
+    with scope("moe.experts"):
+        xe = xe.astype(x2d.dtype)
+        h = jax.nn.silu(jnp.einsum("ecd,edh->ech", xe, w_gate,
+                                   preferred_element_type=_F32))
+        h = h * jnp.einsum("ecd,edh->ech", xe, w_up,
+                           preferred_element_type=_F32)
+        out = jnp.einsum("ech,ehd->ecd", h.astype(x2d.dtype), w_down,
+                         preferred_element_type=_F32)       # [E, C, d]
+    with scope("moe.combine"):
+        return moe_combine(out, disp, gate).astype(x2d.dtype)
 
 
 def cross_entropy(logits, targets):

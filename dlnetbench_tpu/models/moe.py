@@ -42,6 +42,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from dlnetbench_tpu.metrics.spans import scope
 from dlnetbench_tpu.models import layers as L
 
 _F32 = jnp.float32
@@ -116,41 +117,44 @@ def dispatch(x2d, w_router, num_experts: int, top_k: int,
             return xe, disp, gate
         cap = group_capacity(t, top_k, e, capacity_factor)
         _, idx = L.moe_router(x2d, w_router, top_k)
-        counts = jnp.sum(jax.nn.one_hot(idx, e, dtype=_F32),
-                         axis=(0, 1))[None]          # [1, E]
-        stats = _routing_stats(x2d, w_router, counts, disp, cap)
+        with scope("moe.dispatch"):
+            counts = jnp.sum(jax.nn.one_hot(idx, e, dtype=_F32),
+                             axis=(0, 1))[None]          # [1, E]
+            stats = _routing_stats(x2d, w_router, counts, disp, cap)
         return xe, disp, gate, stats
 
     n_groups = t // g
     cap_g = group_capacity(g, top_k, e, capacity_factor)
     weights, idx = L.moe_router(x2d, w_router, top_k)
-    onehot = jax.nn.one_hot(idx, e, dtype=_F32)          # [T, k, E]
-    gate = jnp.sum(onehot * weights[..., None], axis=1)  # [T, E]
-    mask = jnp.sum(onehot, axis=1)                       # [T, E] 0/1
-    maskg = mask.reshape(n_groups, g, e)
-    if drop_seed is not None:
-        if gids is None:
-            gids = jnp.arange(t, dtype=jnp.int32)
-        prio = token_priority(drop_seed, gids).reshape(n_groups, g)
-        order = jnp.argsort(prio, axis=1)                # queue order
-        inv = jnp.argsort(order, axis=1)
-        ms = jnp.take_along_axis(maskg, order[..., None], axis=1)
-        pos_s = jnp.cumsum(ms, axis=1) - 1.0
-        pos = jnp.take_along_axis(pos_s, inv[..., None], axis=1)
-    else:
-        pos = jnp.cumsum(maskg, axis=1) - 1.0
-    keep = maskg * (pos < cap_g)                         # [G, g, E]
-    slot = pos + (jnp.arange(n_groups, dtype=_F32)
-                  * cap_g)[:, None, None]
-    c_total = n_groups * cap_g
-    disp = jax.nn.one_hot(slot.astype(jnp.int32).reshape(t, e),
-                          c_total, dtype=_F32) \
-        * keep.reshape(t, e)[..., None]                  # [T, E, C]
-    xe = jnp.einsum("tec,td->ecd", disp, x2d.astype(_F32))
-    if not with_stats:
-        return xe, disp, gate
-    counts = jnp.sum(maskg, axis=1)                      # [G, E]
-    stats = _routing_stats(x2d, w_router, counts, disp, cap_g)
+    with scope("moe.router"):
+        onehot = jax.nn.one_hot(idx, e, dtype=_F32)          # [T, k, E]
+        gate = jnp.sum(onehot * weights[..., None], axis=1)  # [T, E]
+    with scope("moe.dispatch"):
+        mask = jnp.sum(onehot, axis=1)                       # [T, E] 0/1
+        maskg = mask.reshape(n_groups, g, e)
+        if drop_seed is not None:
+            if gids is None:
+                gids = jnp.arange(t, dtype=jnp.int32)
+            prio = token_priority(drop_seed, gids).reshape(n_groups, g)
+            order = jnp.argsort(prio, axis=1)                # queue order
+            inv = jnp.argsort(order, axis=1)
+            ms = jnp.take_along_axis(maskg, order[..., None], axis=1)
+            pos_s = jnp.cumsum(ms, axis=1) - 1.0
+            pos = jnp.take_along_axis(pos_s, inv[..., None], axis=1)
+        else:
+            pos = jnp.cumsum(maskg, axis=1) - 1.0
+        keep = maskg * (pos < cap_g)                         # [G, g, E]
+        slot = pos + (jnp.arange(n_groups, dtype=_F32)
+                      * cap_g)[:, None, None]
+        c_total = n_groups * cap_g
+        disp = jax.nn.one_hot(slot.astype(jnp.int32).reshape(t, e),
+                              c_total, dtype=_F32) \
+            * keep.reshape(t, e)[..., None]                  # [T, E, C]
+        xe = jnp.einsum("tec,td->ecd", disp, x2d.astype(_F32))
+        if not with_stats:
+            return xe, disp, gate
+        counts = jnp.sum(maskg, axis=1)                      # [G, E]
+        stats = _routing_stats(x2d, w_router, counts, disp, cap_g)
     return xe, disp, gate, stats
 
 
@@ -217,27 +221,28 @@ def expert_ffn(xe, w_gate, w_up, w_down, *, impl: str = "einsum",
       optional fused int8/fp8 quantization (``quant``) and count-aware
       block skipping (``counts``).
     """
-    if impl == "grouped":
-        from dlnetbench_tpu.ops.grouped_matmul import grouped_ffn
-        return grouped_ffn(xe, w_gate, w_up, w_down, counts=counts,
-                           fmt=quant).astype(_F32)
-    if impl != "einsum":
-        raise ValueError(f"moe.expert_ffn: unknown impl {impl!r} "
-                         f"(einsum | grouped)")
-    if mlp_int8:
-        from dlnetbench_tpu.ops.int8 import int8_dot_batched
-        dt = xe.dtype
-        g = int8_dot_batched(xe, w_gate.astype(dt))
-        u = int8_dot_batched(xe, w_up.astype(dt))
-        h = jax.nn.silu(g.astype(_F32)) * u.astype(_F32)
-        out = int8_dot_batched(h.astype(dt), w_down.astype(dt))
-        return out.astype(_F32)
-    h = jax.nn.silu(jnp.einsum("ecd,edh->ech", xe, w_gate,
-                               preferred_element_type=_F32))
-    h = h * jnp.einsum("ecd,edh->ech", xe, w_up,
-                       preferred_element_type=_F32)
-    return jnp.einsum("ech,ehd->ecd", h.astype(xe.dtype), w_down,
-                      preferred_element_type=_F32)
+    with scope("moe.experts"):
+        if impl == "grouped":
+            from dlnetbench_tpu.ops.grouped_matmul import grouped_ffn
+            return grouped_ffn(xe, w_gate, w_up, w_down, counts=counts,
+                               fmt=quant).astype(_F32)
+        if impl != "einsum":
+            raise ValueError(f"moe.expert_ffn: unknown impl {impl!r} "
+                             f"(einsum | grouped)")
+        if mlp_int8:
+            from dlnetbench_tpu.ops.int8 import int8_dot_batched
+            dt = xe.dtype
+            g = int8_dot_batched(xe, w_gate.astype(dt))
+            u = int8_dot_batched(xe, w_up.astype(dt))
+            h = jax.nn.silu(g.astype(_F32)) * u.astype(_F32)
+            out = int8_dot_batched(h.astype(dt), w_down.astype(dt))
+            return out.astype(_F32)
+        h = jax.nn.silu(jnp.einsum("ecd,edh->ech", xe, w_gate,
+                                   preferred_element_type=_F32))
+        h = h * jnp.einsum("ecd,edh->ech", xe, w_up,
+                           preferred_element_type=_F32)
+        return jnp.einsum("ech,ehd->ecd", h.astype(xe.dtype), w_down,
+                          preferred_element_type=_F32)
 
 
 def moe_grouped(x2d, w_router, w_gate, w_up, w_down, top_k: int,
@@ -253,12 +258,15 @@ def moe_grouped(x2d, w_router, w_gate, w_up, w_down, top_k: int,
     out = dispatch(x2d, w_router, e, top_k, capacity_factor,
                    drop_seed=drop_seed, with_stats=True)
     xe, disp, gate, stats = out
-    counts = jnp.minimum(
-        stats["kept"],
-        jnp.float32(xe.shape[1])).astype(jnp.int32)
-    y = expert_ffn(xe.astype(x2d.dtype), w_gate, w_up, w_down,
+    with scope("moe.dispatch"):
+        counts = jnp.minimum(
+            stats["kept"],
+            jnp.float32(xe.shape[1])).astype(jnp.int32)
+        xe = xe.astype(x2d.dtype)
+    y = expert_ffn(xe, w_gate, w_up, w_down,
                    impl="grouped", quant=quant, counts=counts)
-    return L.moe_combine(y, disp, gate).astype(x2d.dtype)
+    with scope("moe.combine"):
+        return L.moe_combine(y, disp, gate).astype(x2d.dtype)
 
 
 # ------------------------------------------------------- schedule twin

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from dlnetbench_tpu import ops
 from dlnetbench_tpu.core.model_card import ModelCard
+from dlnetbench_tpu.metrics.spans import scope
 from dlnetbench_tpu.models import layers as L
 
 
@@ -331,39 +332,52 @@ def init_params(key, cfg: TransformerConfig) -> dict:
 def _block(cfg: TransformerConfig, x, lp, positions, qs_row=None):
     """One decoder block; x: [B, S, D], lp: this layer's param slice.
     ``qs_row`` is this layer's delayed-scaling amax state (delayed
-    quant only) — when given, returns ``(x, new_qs_row)``."""
-    b, s, d = x.shape
-    if cfg.gated:
-        y = L.rmsnorm(x, lp["norm1"])
-    else:
-        y = L.layernorm(x, lp["norm1"], lp["norm1_b"])
-    q = jnp.dot(y, lp["wq"]).reshape(b, s, cfg.num_heads, cfg.head_dim)
-    k = jnp.dot(y, lp["wk"]).reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
-    v = jnp.dot(y, lp["wv"]).reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
-    if not cfg.max_positions:  # RoPE family
-        q, k = L.rope(q, k, positions)
-    att = ops.attention(q, k, v, causal=True, impl=cfg.attention_impl,
-                        mask=cfg.mask_spec).reshape(b, s, d)
-    x = x + jnp.dot(att, lp["wo"])
+    quant only) — when given, returns ``(x, new_qs_row)``.
 
-    if cfg.gated:
-        y = L.rmsnorm(x, lp["norm2"])
-        if cfg.num_experts > 1:
-            if cfg.moe_impl == "dense":
-                moe = L.moe_dense
-            elif cfg.moe_impl == "grouped":
-                from dlnetbench_tpu.models.moe import moe_grouped
-                moe = functools.partial(
-                    moe_grouped,
-                    capacity_factor=cfg.moe_capacity_factor)
-            else:
-                moe = functools.partial(
-                    L.moe_sparse,
-                    capacity_factor=cfg.moe_capacity_factor)
-            y2 = moe(y.reshape(b * s, d), lp["w_router"],
-                     lp["w_gate"], lp["w_up"], lp["w_down"],
-                     cfg.top_k).reshape(b, s, d)
+    The block wears the step's scopes (``spans.SCOPES``): ``attn`` up to
+    its residual add; then ``mlp`` over norm2, the dense MLP and its
+    residual, or the four ``moe.*`` (norm2 lies in ``moe.router``, the
+    residual in ``moe.combine``)."""
+    b, s, d = x.shape
+    with scope("attn"):
+        if cfg.gated:
+            y = L.rmsnorm(x, lp["norm1"])
         else:
+            y = L.layernorm(x, lp["norm1"], lp["norm1_b"])
+        q = jnp.dot(y, lp["wq"]).reshape(b, s, cfg.num_heads, cfg.head_dim)
+        k = jnp.dot(y, lp["wk"]).reshape(b, s, cfg.num_kv_heads,
+                                         cfg.head_dim)
+        v = jnp.dot(y, lp["wv"]).reshape(b, s, cfg.num_kv_heads,
+                                         cfg.head_dim)
+        if not cfg.max_positions:  # RoPE family
+            q, k = L.rope(q, k, positions)
+        att = ops.attention(q, k, v, causal=True, impl=cfg.attention_impl,
+                            mask=cfg.mask_spec).reshape(b, s, d)
+        x = x + jnp.dot(att, lp["wo"])
+
+    if cfg.gated and cfg.num_experts > 1:
+        with scope("moe.router"):
+            y = L.rmsnorm(x, lp["norm2"])
+        if cfg.moe_impl == "dense":
+            moe = L.moe_dense
+        elif cfg.moe_impl == "grouped":
+            from dlnetbench_tpu.models.moe import moe_grouped
+            moe = functools.partial(
+                moe_grouped,
+                capacity_factor=cfg.moe_capacity_factor)
+        else:
+            moe = functools.partial(
+                L.moe_sparse,
+                capacity_factor=cfg.moe_capacity_factor)
+        y2 = moe(y.reshape(b * s, d), lp["w_router"],
+                 lp["w_gate"], lp["w_up"], lp["w_down"],
+                 cfg.top_k).reshape(b, s, d)
+        with scope("moe.combine"):
+            return x + y2
+
+    with scope("mlp"):
+        if cfg.gated:
+            y = L.rmsnorm(x, lp["norm2"])
             new_qs_row = None
             if cfg.mlp_dtype in ("float8", "int8"):
                 mlp_fn = functools.partial(
@@ -393,12 +407,13 @@ def _block(cfg: TransformerConfig, x, lp, positions, qs_row=None):
             y2 = mlp_fn(y, lp["w_gate"], lp["w_up"], lp["w_down"])
             if qs_row is not None:
                 y2, new_qs_row = y2
-    else:
-        y = L.layernorm(x, lp["norm2"], lp["norm2_b"])
-        y2 = L.gelu_mlp(y, lp["w_in"], lp["b_in"], lp["w_out"], lp["b_out"])
-    if qs_row is not None:
-        return x + y2, new_qs_row
-    return x + y2
+        else:
+            y = L.layernorm(x, lp["norm2"], lp["norm2_b"])
+            y2 = L.gelu_mlp(y, lp["w_in"], lp["b_in"], lp["w_out"],
+                            lp["b_out"])
+        if qs_row is not None:
+            return x + y2, new_qs_row
+        return x + y2
 
 
 def forward(params: dict, tokens, cfg: TransformerConfig, qstate=None):
@@ -412,11 +427,12 @@ def forward(params: dict, tokens, cfg: TransformerConfig, qstate=None):
     if delayed and qstate is None:
         raise ValueError("cfg.quant_scaling='delayed' requires the "
                          "qstate carry (models.transformer.init_qstate)")
-    x = params["embed"][tokens]
     s = tokens.shape[1]
     positions = jnp.arange(s)
-    if cfg.max_positions:
-        x = x + params["pos_embed"][positions][None]
+    with scope("embed"):
+        x = params["embed"][tokens]
+        if cfg.max_positions:
+            x = x + params["pos_embed"][positions][None]
 
     block = _block
     if cfg.remat and cfg.remat_scope == "block":
@@ -449,14 +465,16 @@ def forward(params: dict, tokens, cfg: TransformerConfig, qstate=None):
                 x = block(cfg, x, lp, positions)
         if delayed:
             new_qstate = jnp.stack(new_rows)
-    if cfg.gated:
-        x = L.rmsnorm(x, params["final_norm"])
-    else:
-        x = L.layernorm(x, params["final_norm"], params["final_norm_b"])
-    head = params["embed"].T if cfg.tied_embeddings else params["head"]
-    logits = jnp.dot(x, head,
-                     preferred_element_type=(jnp.float32 if cfg.logits_f32
-                                             else x.dtype))
+    with scope("head_loss"):
+        if cfg.gated:
+            x = L.rmsnorm(x, params["final_norm"])
+        else:
+            x = L.layernorm(x, params["final_norm"],
+                            params["final_norm_b"])
+        head = params["embed"].T if cfg.tied_embeddings else params["head"]
+        logits = jnp.dot(x, head,
+                         preferred_element_type=(
+                             jnp.float32 if cfg.logits_f32 else x.dtype))
     if delayed:
         return logits, new_qstate
     return logits
@@ -469,6 +487,8 @@ def loss_fn(params: dict, tokens, cfg: TransformerConfig, qstate=None):
     shape — the state is an aux output, not part of the loss)."""
     if needs_qstate(cfg):
         logits, new_qstate = forward(params, tokens[:, :-1], cfg, qstate)
-        return L.cross_entropy(logits, tokens[:, 1:]), new_qstate
+        with scope("head_loss"):
+            return L.cross_entropy(logits, tokens[:, 1:]), new_qstate
     logits = forward(params, tokens[:, :-1], cfg)
-    return L.cross_entropy(logits, tokens[:, 1:])
+    with scope("head_loss"):
+        return L.cross_entropy(logits, tokens[:, 1:])

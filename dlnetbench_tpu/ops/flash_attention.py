@@ -229,6 +229,7 @@ def _fwd(q, k, v, *, causal: bool, block_q: int, block_k: int):
             pltpu.VMEM((block_q, _LANES), _F32),
         ],
         compiler_params=_compiler_params(),
+        name="flash_fwd",
         interpret=pallas_common.interpret_mode(),
     )(qt, kt, vt)
     return _from_bsf(out, hq, dh), lse
@@ -471,6 +472,7 @@ def _bwd_impl(q, k, v, out, lse, do, *, causal: bool,
         out_shape=jax.ShapeDtypeStruct((b, s, hq * dh_p), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq_dq, dh_p), _F32)],
         compiler_params=_compiler_params(),
+        name="flash_bwd_dq",
         interpret=pallas_common.interpret_mode(),
     )(qt, kt, vt, dot, lse, dcap)
 
@@ -506,6 +508,7 @@ def _bwd_impl(q, k, v, out, lse, do, *, causal: bool,
         scratch_shapes=[pltpu.VMEM((bk_dkv, dh_p), _F32),
                         pltpu.VMEM((bk_dkv, dh_p), _F32)],
         compiler_params=_compiler_params(),
+        name="flash_bwd_dkv",
         interpret=pallas_common.interpret_mode(),
     )(qt, kt, vt, dot, lse, dcap)
 
@@ -749,6 +752,7 @@ def _splash_fwd(q, k, v, spec, *, block_q: int, block_k: int):
             jax.ShapeDtypeStruct((b, hq, _SUBLANES, s), _F32),
         ],
         compiler_params=_compiler_params(),
+        name="flash_fwd",
         interpret=pallas_common.interpret_mode(),
     )(*_splash_prefetch(bm), qt, kt, vt,
       _row_i32(bm.lo, s), _row_i32(bm.hi, s))
@@ -904,6 +908,7 @@ def _splash_bwd_impl(q, k, v, out, lse, do, spec, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, s, hq * dh_p), q.dtype),
         compiler_params=_compiler_params(),
+        name="flash_bwd_dq",
         interpret=pallas_common.interpret_mode(),
     )(*_splash_prefetch(bm_dq), qt, kt, vt, dot, lse, dcap,
       _row_i32(bm_dq.lo, s), _row_i32(bm_dq.hi, s))
@@ -956,6 +961,7 @@ def _splash_bwd_impl(q, k, v, out, lse, do, spec, *,
         out_shape=[jax.ShapeDtypeStruct((b, s, hq * dh_p), k.dtype),
                    jax.ShapeDtypeStruct((b, s, hq * dh_p), v.dtype)],
         compiler_params=_compiler_params(),
+        name="flash_bwd_dkv",
         interpret=pallas_common.interpret_mode(),
     )(jnp.asarray(bm_t.kv_first_q), jnp.asarray(bm_t.kv_last_q),
       jnp.asarray(bm_t.blk_lo_max), jnp.asarray(bm_t.blk_hi_min),

@@ -197,6 +197,7 @@ def grouped_matmul(x, w, *, counts=None, sx=None, sw=None,
         out_shape=jax.ShapeDtypeStruct((e, c, n), out_dtype or x.dtype),
         compiler_params=compiler_params(
             ("parallel", "parallel", "parallel", "arbitrary")),
+        name="grouped_mm",
         interpret=pallas_common.interpret_mode(),
     )(counts, sx_a, sw_a, x, w)
     return out

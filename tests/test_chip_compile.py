@@ -196,3 +196,62 @@ def test_grouped_matmul(for_chip, fmt):
                 x, w, counts=n, sx=sx, sw=sw, fmt=fmt),
             x, ((e, D, F), QDTYPE[fmt]), counts, ((e,), F32), ((e,), F32))
     assert kernels_in(text) == 1
+
+
+def kernel_instructions(text: str) -> list:
+    """The names of the Pallas custom calls, as a device trace prints
+    them first in each event's name."""
+    from dlnetbench_tpu.core import executor
+    return [m.group(1) for line in text.splitlines()
+            if "tpu_custom_call" in line
+            and (m := executor._HLO_INSTRUCTION.match(line))]
+
+
+def test_kernels_carry_their_given_names_on_the_chip(for_chip):
+    """Under one of the step's scopes, as the models call them, the
+    chip's compiler names a kernel's instruction by the ``name=`` of its
+    ``pallas_call``: no ``pallas_call.N``, ``jvp__.N`` or
+    ``transpose_jvp___.N``, which said nothing of which kernel ran.
+    (With no scope around it the transform wraps the name itself:
+    ``jvp_flash_fwd_``.)"""
+    import re
+
+    from dlnetbench_tpu.metrics import spans
+    fa, gm = ops_module("flash_attention"), ops_module("grouped_matmul")
+
+    def scoped(q, k, v):
+        with spans.scope("attn"):
+            return fa.flash_attention(q, k, v)
+    flash = kernel_instructions(for_chip(grad_of(scoped), *QKV))
+    assert sorted(re.sub(r"\.\d+$", "", n) for n in flash) == \
+        ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    grouped = kernel_instructions(for_chip(
+        lambda x, w, n: gm.grouped_matmul(x, w, counts=n),
+        ((8, 2048, D), BF16), ((8, D, F), BF16), ((8,), I32)))
+    assert [re.sub(r"\.\d+$", "", n) for n in grouped] == ["grouped_mm"]
+
+
+def test_op_scopes_of_a_program_compiled_for_the_chip(for_chip):
+    """The table made from the chip compiler's text: the three flash
+    kernels and the projection's fusion, forward and backward, under the
+    scope the function wore; nothing of it under another."""
+    from dlnetbench_tpu.core import executor
+    from dlnetbench_tpu.metrics import spans
+    fa = ops_module("flash_attention")
+
+    def attn(q, k, v, w):
+        with spans.scope("attn"):
+            out = fa.flash_attention(q, k, v)
+            return jnp.sum(jnp.dot(out.reshape(2, 6144, HQ * DH), w)
+                           .astype(F32))
+    text = for_chip(jax.grad(attn, argnums=(0, 1, 2, 3)), *QKV,
+                    ((HQ * DH, D), BF16))
+    table = executor.hlo_op_scopes(text)
+    kernels = kernel_instructions(text)
+    assert len(kernels) == 3 and {table[k] for k in kernels} == {"attn"}
+    entry = text[text.index("ENTRY"):]
+    fusions = [m.group(1) for line in entry.splitlines()
+               if " fusion(" in line
+               and (m := executor._HLO_INSTRUCTION.match(line))]
+    assert fusions and {table[f] for f in fusions} <= {"attn", "other"}
+    assert sum(table[f] == "attn" for f in fusions) >= 2

@@ -1,0 +1,228 @@
+"""The op->scope table (core/executor.py ``hlo_op_scopes``) and the
+scopes the model step wears (``spans.SCOPES``): every matmul of a tiny
+dense and a tiny grouped-MoE train step lands in a vocabulary scope,
+forward and backward of a layer together; the rule for fusions; the
+table is made when asked or when a tracer is on, never otherwise."""
+from __future__ import annotations
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from dlnetbench_tpu.core import executor
+from dlnetbench_tpu.core.model_card import ModelCard, MoEParams
+from dlnetbench_tpu.metrics import spans
+from dlnetbench_tpu.models import bench_step, transformer as tfm
+
+
+@pytest.fixture(autouse=True)
+def _clean_tracer():
+    spans.disable()
+    yield
+    spans.disable()
+
+
+def tiny_step(moe: bool, **over):
+    card = ModelCard(
+        name="tiny", embed_dim=32, num_heads=4, num_kv_heads=2, ff_dim=64,
+        seq_len=16, num_decoder_blocks=2, vocab_size=64, gated_mlp=True,
+        moe_params=MoEParams(4, 2) if moe else None)
+    if moe:
+        over.setdefault("moe_impl", "grouped")
+    cfg = bench_step.bench_cfg(card, dtype="float32", **over)
+    params = tfm.init_params(jax.random.key(0), cfg)
+    tokens = jax.random.randint(jax.random.key(1), (2, 17), 0, 64)
+    return executor.CompiledStep(
+        bench_step.make_train_k(cfg, 1, 0.1), (params, tokens),
+        donate_argnums=bench_step.DONATE_ARGNUMS)
+
+
+@pytest.fixture(scope="module")
+def steps():
+    return {"dense": tiny_step(False), "moe": tiny_step(True)}
+
+
+WORK = re.compile(r"\s(dot|convolution|custom-call)\(")
+
+
+def instructions(text, pattern=WORK):
+    """[(name, op_name)] of the instructions whose opcode matches."""
+    out = []
+    for line in text.splitlines():
+        m = executor._HLO_INSTRUCTION.match(line)
+        head, _, meta = line.partition(", metadata={")
+        if m and pattern.search(head):
+            name = executor._HLO_OP_NAME.search(meta)
+            out.append((m.group(1), name.group(1) if name else ""))
+    return out
+
+
+@pytest.mark.parametrize("kind", ["dense", "moe"])
+def test_every_matmul_and_custom_call_has_a_vocabulary_scope(steps, kind):
+    step = steps[kind]
+    table = step.op_scopes()
+    work = instructions(step.as_text())
+    assert len(work) >= 10
+    assert {table[n] for n, _ in work} <= set(spans.SCOPES)
+    want = {"attn", "head_loss"} | (
+        {"mlp"} if kind == "dense" else {"moe.router", "moe.experts"})
+    assert want <= {table[n] for n, _ in work}
+
+
+@pytest.mark.parametrize("kind", ["dense", "moe"])
+def test_table_names_the_scopes_of_the_step(steps, kind):
+    got = set(steps[kind].op_scopes().values())
+    block = ({"mlp"} if kind == "dense" else
+             {"moe.router", "moe.dispatch", "moe.experts", "moe.combine"})
+    assert {"embed", "attn", "head_loss", "optimizer"} | block <= got
+    assert got <= set(spans.SCOPES) | {spans.OTHER_SCOPE}
+    assert ("mlp" in got) == (kind == "dense")
+
+
+@pytest.mark.parametrize("kind", ["dense", "moe"])
+def test_forward_and_backward_of_a_layer_share_a_scope(steps, kind):
+    step = steps[kind]
+    table = step.op_scopes()
+    layer = "mlp" if kind == "dense" else "moe.experts"
+    for scope in ("attn", layer, "head_loss"):
+        mine = [op for n, op in instructions(step.as_text())
+                if table[n] == scope]
+        assert any(f"/jvp({scope})/" in op for op in mine), scope
+        assert any(f"transpose(jvp({scope}))" in op for op in mine), scope
+
+
+@pytest.mark.parametrize("op_name,want", [
+    ("jit(train_k)/while/body/closed_call/jvp(attn)/dot_general", "attn"),
+    ("jit(train_k)/transpose(jvp(attn))/flash_bwd_dkv/pallas_call",
+     "attn"),
+    ("jit(f)/jvp(moe.router)/inner/tanh", "moe.router"),
+    ("jit(f)/jvp(moe.combine)/moe.experts/dot_general", "moe.experts"),
+    ("jit(f)/transpose(jvp(mlp))/jvp(mlp)/checkpoint/dot_general", "mlp"),
+    ("jit(f)/optimizer/sub", "optimizer"),
+    ("jit(attn)/add", None),    # a function's name is not a scope
+    ("jit(f)/attention/add", None),
+    ("", None)])
+def test_scope_of_op_name(op_name, want):
+    assert executor.scope_of_op_name(op_name) == want
+
+
+SNIPPET = '''HloModule jit_step, is_scheduled=true
+
+%fused_computation.1 (p0: f32[8,8], p1: f32[8,8]) -> f32[8,8] {
+  %p0 = f32[8,8]{1,0} parameter(0)
+  %p1 = f32[8,8]{1,0} parameter(1)
+  %convolution.3 = f32[8,8]{1,0} convolution(%p0, %p1), dim_labels=bf_io->bf, metadata={op_name="jit(step)/transpose(jvp(moe.experts))/dot_general" stack_frame_id=1}
+  ROOT %subtract.4 = f32[8,8]{1,0} subtract(%p1, %convolution.3), metadata={op_name="jit(step)/optimizer/sub" stack_frame_id=2}
+}
+
+%fused_computation.2 (p0.1: f32[8,8]) -> (f32[8,8], f32[8]) {
+  %p0.1 = f32[8,8]{1,0} parameter(0)
+  %add.5 = f32[8,8]{1,0} add(%p0.1, %p0.1), metadata={op_name="jit(step)/jvp(attn)/add"}
+  %multiply.6 = f32[8,8]{1,0} multiply(%add.5, %add.5), metadata={op_name="jit(step)/jvp(mlp)/mul"}
+  %reduce.7 = f32[8]{0} reduce(%multiply.6, %p0.1), dimensions={1}, metadata={op_name="jit(step)/jvp(mlp)/reduce_sum"}
+  ROOT %tuple.8 = (f32[8,8]{1,0}, f32[8]{0}) tuple(%multiply.6, %reduce.7)
+}
+
+%fused_computation.3 (p0.2: f32[8,8]) -> f32[8,8] {
+  %p0.2 = f32[8,8]{1,0} parameter(0)
+  ROOT %copy.9 = f32[8,8]{0,1} copy(%p0.2)
+}
+
+ENTRY %main.10 (a: f32[8,8], b: f32[8,8]) -> f32[8,8] {
+  %a = f32[8,8]{1,0} parameter(0), metadata={op_name="a"}
+  %b = f32[8,8]{1,0} parameter(1), metadata={op_name="b"}
+  %fusion.1 = f32[8,8]{1,0} fusion(%a, %b), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(step)/optimizer/sub"}
+  %fusion.2 = (f32[8,8]{1,0}, f32[8]{0}) fusion(%fusion.1), kind=kLoop, calls=%fused_computation.2
+  %fusion.3 = f32[8,8]{0,1} fusion(%fusion.1), kind=kLoop, calls=%fused_computation.3, metadata={op_name="jit(step)/embed/transpose"}
+  %fusion.4 = f32[8,8]{0,1} fusion(%fusion.1), kind=kLoop, calls=%fused_computation.3
+  %flash_fwd.11 = f32[8,8]{1,0} custom-call(%fusion.3), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jvp(attn)/flash_fwd/pallas_call"}
+  %custom-call.12 = f32[8,8]{1,0} custom-call(%fusion.3), custom_call_target="ConcatBitcast"
+  ROOT %copy.13 = f32[8,8]{1,0} copy(%flash_fwd.11)
+}
+'''
+
+
+@pytest.mark.parametrize("instruction,want,why", [
+    ("fusion.1", "moe.experts", "the dot inside, not the root or itself"),
+    ("fusion.2", "mlp", "no dot, the root a bare tuple: the last before"),
+    ("fusion.3", "embed", "nothing inside says: its own"),
+    ("fusion.4", "other", "nothing anywhere"),
+    ("flash_fwd.11", "attn", "a custom call keeps its own"),
+    ("custom-call.12", "other", "the compiler's, no metadata"),
+    ("convolution.3", "moe.experts", "inside a fusion: its own"),
+    ("subtract.4", "optimizer", "inside a fusion: its own"),
+    ("a", "other", "a parameter")])
+def test_fusion_rule_on_a_written_module(instruction, want, why):
+    assert executor.hlo_op_scopes(SNIPPET)[instruction] == want, why
+
+
+def test_module_name_of_a_text():
+    assert executor.hlo_module_name(SNIPPET) == "jit_step"
+    assert executor.hlo_module_name("nothing") == ""
+
+
+def count_as_text(monkeypatch):
+    calls = []
+    real = executor._Compiled.as_text
+
+    def as_text(self):
+        calls.append(self)
+        return real(self)
+    monkeypatch.setattr(executor._Compiled, "as_text", as_text)
+    return calls
+
+
+def small():
+    return executor.CompiledStep(lambda x: x * 2.0, (jnp.ones((4,)),))
+
+
+def test_table_is_not_made_at_build_with_tracing_off(monkeypatch):
+    calls = count_as_text(monkeypatch)
+    step = small()
+    assert not calls and step._op_scopes is None
+    step(jnp.ones((4,)))
+    assert not calls
+    table = step.op_scopes()
+    assert table and len(calls) == 1
+    assert step.op_scopes() is table and len(calls) == 1    # kept
+
+
+def test_tracer_is_handed_the_table_inside_the_compile_span():
+    tracer = spans.enable()
+
+    def doubled(x):
+        with spans.scope("mlp"):
+            return x * 2.0
+    step = executor.CompiledStep(doubled, (jnp.ones((4,)),))
+    prog = executor.CompiledProgram(
+        executor.Program(doubled, (jnp.ones((4,)),)))
+    spans.disable()
+    assert tracer.op_scopes["jit_doubled"] == step.op_scopes() \
+        == prog.op_scopes()
+    assert "mlp" in step.op_scopes().values()
+    assert [s["attrs"]["fn"] for s in tracer.spans
+            if s["name"] == "compile"] == ["doubled", "doubled"]
+    got = tracer.export()
+    assert got["op_scopes"] == tracer.op_scopes
+    assert got["spans"] == tracer.spans and got["spans"] is not tracer.spans
+
+
+def test_a_call_opens_no_span_of_its_own():
+    """Tracing on or off, a call is the executable's and nothing else:
+    a dispatch gets its name from the caller that has a reader for it
+    (the serving engine calls a step for every decode and prefill)."""
+    step = small()
+    tracer = spans.enable()
+    out = step(jnp.ones((4,)))
+    spans.disable()
+    assert float(out[0]) == 2.0
+    assert tracer.spans == [] and tracer.op_scopes == {}
+
+
+def test_scope_refuses_a_name_outside_the_vocabulary():
+    with pytest.raises(ValueError, match="spans.SCOPES"):
+        spans.scope("attention")
+    assert len(set(spans.SCOPES)) == len(spans.SCOPES) == 9
+    assert spans.OTHER_SCOPE not in spans.SCOPES

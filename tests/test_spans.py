@@ -289,3 +289,86 @@ def test_merge_trace_out_writes_native_style_trace(tmp_path):
     # consumers only ever see X events)
     loaded = load_trace_events(trace)
     assert loaded and all(e["ph"] == "X" for e in loaded)
+
+
+# ---------------------------------------------------------------------
+# Spans on the profiler's clock (ISSUE 24).
+
+def test_enabled_span_lies_on_the_profilers_clock(tmp_path):
+    """While a profiler trace runs, an enabled span is an event on the
+    host line of the same ``.xplane.pb`` as the executed operations,
+    its numeric and string attrs the event's stats — and on the same
+    clock: the operations of the call it wraps start and end inside
+    it, with no shifting."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    f = jax.jit(lambda x: jnp.tanh(x @ x).sum())
+    x = jnp.ones((256, 256))
+    f(x).block_until_ready()
+    tracer = spans.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with spans.span("fenced", k=3, what="wait", share=0.5,
+                        argnums=[0]):
+            f(x).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    spans.disable()
+    data = ProfileData.from_file(glob.glob(
+        str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))[0])
+    events = [ev for plane in data.planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events]
+    mine = [ev for ev in events if ev.name == "fenced"]
+    assert len(mine) == 1
+    assert dict(mine[0].stats) == {"k": 3, "what": "wait", "share": 0.5}
+    begin, end = mine[0].start_ns, mine[0].start_ns + mine[0].duration_ns
+    ran = [ev for ev in events if "hlo_op" in dict(ev.stats)]
+    assert ran
+    assert all(begin <= ev.start_ns
+               and ev.start_ns + ev.duration_ns <= end for ev in ran)
+    # the tracer kept the region too, with every attr: it is timed
+    # inside the annotation
+    (rec,) = tracer.spans
+    assert rec["attrs"]["argnums"] == [0]
+    assert 0 < rec["dur_us"] * 1e3 <= mine[0].duration_ns + 1e3
+
+
+def test_span_without_a_profiler_trace_costs_no_event():
+    """No trace running: the annotation is a flag test and the span is
+    kept by the tracer as before."""
+    tracer = spans.enable()
+    with spans.span("alone", n=1):
+        pass
+    spans.disable()
+    assert [(s["name"], s["attrs"]) for s in tracer.spans] == \
+        [("alone", {"n": 1})]
+
+
+def test_disabled_path_reads_no_clock_and_makes_no_annotation(monkeypatch):
+    import time
+    monkeypatch.setattr(spans, "_Span", None)        # would raise if made
+    monkeypatch.setattr(time, "perf_counter",
+                        lambda: pytest.fail("clock read"))
+    with spans.span("off", k=1) as s:
+        assert s is spans.NULL_SPAN
+    assert spans.active_stacks() == {}
+
+
+def test_export_is_plain_data_and_a_copy():
+    tracer = spans.enable()
+    with spans.span("a", k=1):
+        pass
+    tracer.register_op_scopes("jit_f", {"fusion.1": "attn"})
+    spans.disable()
+    got = tracer.export()
+    assert json.loads(json.dumps(got)) == got
+    assert got["op_scopes"] == {"jit_f": {"fusion.1": "attn"}}
+    got["op_scopes"]["jit_f"]["fusion.1"] = "mlp"
+    got["spans"][0]["name"] = "b"
+    assert tracer.op_scopes["jit_f"]["fusion.1"] == "attn"
+    assert tracer.spans[0]["name"] == "a"
