@@ -1,5 +1,5 @@
 """Building-block layers (pure JAX, no flax): norms, RoPE, attention,
-MLPs, dense MoE routing.
+MLPs, MoE routing (dense, and capacity-based by row gathers).
 
 Conventions: parameters are plain dict pytrees; compute dtype is the
 input's dtype (bfloat16 on TPU) with float32 accumulation where precision
@@ -9,6 +9,7 @@ matters (norm statistics, softmax, router logits); matmuls request float32
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -291,19 +292,114 @@ def moe_dense(x2d, w_router, w_gate, w_up, w_down, top_k: int):
         return jnp.einsum("ted,te->td", y, combine).astype(x2d.dtype)
 
 
+class MoePlan(NamedTuple):
+    """A routing as indices: what dispatch, combine and their backward
+    passes gather rows through.
+
+    ``slot`` [T, E] int32: the token's place in expert e's buffer, -1
+    where it is not routed there or was dropped at capacity.
+    ``src`` [E, C] int32, its inverse: the token in each slot, T (one
+    past the last token) in an empty slot.
+    ``idx`` [T, k] int32: the token's experts, as the router chose."""
+    slot: jax.Array
+    src: jax.Array
+    idx: jax.Array
+
+
+def moe_plan(idx, pos, keep, cap: int) -> MoePlan:
+    """The plan of a routing.  ``pos`` [G, g, E] int32: each token's
+    place in the queue of its (group, expert); ``keep`` [G, g, E] bool:
+    routed there and under ``cap``, the slots a group has in an
+    expert's buffer (C = G * cap).  ``src`` comes from a sort of each
+    queue (the kept first, in slot order), not from a scatter: the
+    TPU's scatter serialises its updates."""
+    n_groups, g, e = pos.shape
+    t = n_groups * g
+    group = jnp.arange(n_groups, dtype=jnp.int32)[:, None, None]
+    slot = jnp.where(keep, pos + group * cap, -1).reshape(t, e)
+    key = jnp.where(keep, pos, g).transpose(0, 2, 1)        # [G, E, g]
+    token = jax.lax.broadcasted_iota(jnp.int32, key.shape, 2) + group * g
+    _, queue = jax.lax.sort_key_val(key, token, dimension=2)
+    n = min(cap, g)
+    kept = jnp.sum(keep, axis=1, dtype=jnp.int32)           # [G, E]
+    src = jnp.where(jnp.arange(n) < kept[..., None], queue[..., :n], t)
+    src = jnp.pad(src, ((0, 0), (0, 0), (0, cap - n)), constant_values=t)
+    return MoePlan(slot, src.transpose(1, 0, 2).reshape(e, n_groups * cap),
+                   idx)
+
+
+def _to_slots(x, plan: MoePlan, w=None):
+    """Token rows [T, d] into the experts' buffers [E, C, d]: slot
+    (e, c) gets the row of token ``src[e, c]``, times ``w[token, e]``
+    where ``w`` [T, E] is given; an empty slot gets zeros."""
+    xe = jnp.take(x, plan.src, axis=0, mode="fill", fill_value=0)
+    if w is None:
+        return xe
+    ws = jnp.take_along_axis(w.T, plan.src, axis=1, mode="fill",
+                             fill_value=0)                  # [E, C]
+    return xe.astype(ws.dtype) * ws[..., None]
+
+
+def _chosen(plan: MoePlan, e: int):
+    """[T, k, E] bool: expert e is the token's k-th choice."""
+    return plan.idx[..., None] == jnp.arange(e)
+
+
+def _of_choice(a, plan: MoePlan):
+    """``a`` [T, E] at each token's k experts -> [T, k]; a sum over
+    E against a one-hot, which costs less than a gather of scalars."""
+    return jnp.sum(jnp.where(_chosen(plan, a.shape[1]), a[:, None, :], 0),
+                   axis=-1)
+
+
+def _from_slots(out, plan: MoePlan):
+    """The rows [k, T, d] that a token's k experts hold for it in
+    ``out`` [E, C, d]; zeros where the token was dropped.  k leads so
+    that the gathered [k * T, d] rows need no copy to be seen as that:
+    a [T, k, d] view is another tiling on the TPU."""
+    e, c, d = out.shape
+    slot = _of_choice(plan.slot, plan)                      # [T, k]
+    row = jnp.where(slot >= 0, plan.idx * c + slot, e * c)
+    return jnp.take(out.reshape(e * c, d), row.T, axis=0, mode="fill",
+                    fill_value=0)
+
+
+@jax.custom_vjp
+def dispatch_rows(x, plan: MoePlan):
+    """``xe[e, c] = x[src[e, c]]`` in x's dtype, zeros in empty slots
+    (the grouped kernels' amax and the expert backward rely on padded
+    rows being zero).  Its transpose is a gather too, a combine with
+    weight one, so no scatter-add appears."""
+    with scope("moe.dispatch"):
+        return _to_slots(x, plan)
+
+
+def _dispatch_rows_fwd(x, plan):
+    return dispatch_rows(x, plan), plan
+
+
+def _dispatch_rows_bwd(plan, dxe):
+    with scope("moe.dispatch"):
+        dx = jnp.sum(_from_slots(dxe, plan), axis=0, dtype=_F32)
+        return dx.astype(dxe.dtype), None
+
+
+dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
 def moe_dispatch(x2d, w_router, num_experts: int, top_k: int,
                  capacity_factor: float):
     """Capacity-based token dispatch (GShard/Switch style), shared by the
     single-device sparse MoE below and the EP-sharded SPMD step
     (models/spmd.py _moe_block — identical math, with all_to_alls
     inserted around the expert compute).  Tokens land in per-expert
-    buffers of C = floor(T*k/E * capacity_factor) slots via a
-    cumsum-position one-hot; tokens beyond capacity are dropped (their
+    buffers of C = floor(T*k/E * capacity_factor) slots in arrival
+    order (a cumsum position); tokens beyond capacity are dropped (their
     combine weight is zero, the residual carries them).
 
-    Returns (xe [E, C, d] f32 expert inputs, disp [T, E, C] dispatch
-    one-hots, gate [T, E] combine weights); combine with
-    ``moe_combine``."""
+    Returns (xe [E, C, d] expert inputs in x2d's dtype, plan: the
+    ``MoePlan`` of the routing, gate [T, E] combine weights); combine
+    with ``moe_combine``."""
     t, _ = x2d.shape
     e = num_experts
     weights, idx = moe_router(x2d, w_router, top_k)         # [T,k] each
@@ -313,20 +409,45 @@ def moe_dispatch(x2d, w_router, num_experts: int, top_k: int,
         onehot = jax.nn.one_hot(idx, e, dtype=_F32)         # [T, k, E]
         gate = jnp.sum(onehot * weights[..., None], axis=1)  # [T, E]
     with scope("moe.dispatch"):
-        mask = jnp.sum(onehot, axis=1)                      # [T, E] 0/1
-        pos = jnp.cumsum(mask, axis=0) - 1.0                # arrival order
-        keep = mask * (pos < cap)
-        disp = jax.nn.one_hot(pos.astype(jnp.int32), cap, dtype=_F32) \
-            * keep[..., None]                               # [T, E, C]
-        xe = jnp.einsum("tec,td->ecd", disp, x2d.astype(_F32))  # [E, C, d]
-    return xe, disp, gate
+        routed = jnp.sum(onehot, axis=1).astype(jnp.int32)  # [T, E] 0/1
+        pos = jnp.cumsum(routed, axis=0) - 1                # arrival order
+        keep = (routed > 0) & (pos < cap)
+        plan = moe_plan(idx, pos[None], keep[None], cap)
+        xe = dispatch_rows(x2d, plan)                       # [E, C, d]
+    return xe, plan, gate
 
 
-def moe_combine(out, disp, gate):
-    """Scatter per-expert outputs [E, C, d] back to tokens [T, d] with
-    the dispatch one-hots and combine weights from ``moe_dispatch``."""
+@jax.custom_vjp
+def moe_combine(out, plan: MoePlan, gate):
+    """Per-expert outputs [E, C, d] back to tokens [T, d], in ``out``'s
+    dtype, with the plan and the combine weights of ``moe_dispatch``:
+    ``y[t] = sum_k gate[t, e_k] * out[e_k, slot[t, e_k]]`` over the
+    token's top-k, product and sum in float32; a dropped choice adds
+    nothing.  Backward by row gathers as well: the transpose is a
+    dispatch of ``dy`` with the gate as a per-slot weight, the gate's
+    gradient a row-wise dot of ``dy`` with the gathered rows."""
     with scope("moe.combine"):
-        return jnp.einsum("ecd,tec->td", out, disp * gate[..., None])
+        w = _of_choice(gate.astype(_F32), plan).T           # [k, T]
+        rows = _from_slots(out, plan).astype(_F32)
+        return jnp.sum(rows * w[..., None], axis=0).astype(out.dtype)
+
+
+def _moe_combine_fwd(out, plan, gate):
+    return moe_combine(out, plan, gate), (out, plan, gate)
+
+
+def _moe_combine_bwd(res, dy):
+    out, plan, gate = res
+    with scope("moe.combine"):
+        dout = _to_slots(dy, plan, gate.astype(_F32))
+        dw = jnp.sum(_from_slots(out, plan).astype(_F32)
+                     * dy.astype(_F32), axis=-1).T          # [T, k]
+        dgate = jnp.sum(jnp.where(_chosen(plan, gate.shape[1]),
+                                  dw[..., None], 0), axis=1)   # [T, E]
+        return dout.astype(out.dtype), None, dgate.astype(gate.dtype)
+
+
+moe_combine.defvjp(_moe_combine_fwd, _moe_combine_bwd)
 
 
 def moe_sparse(x2d, w_router, w_gate, w_up, w_down, top_k: int,
@@ -336,9 +457,8 @@ def moe_sparse(x2d, w_router, w_gate, w_up, w_down, top_k: int,
     capacity_factor >= E/top_k nothing drops and the result matches
     moe_dense exactly (tests/test_models.py pins this)."""
     e = w_gate.shape[0]
-    xe, disp, gate = moe_dispatch(x2d, w_router, e, top_k, capacity_factor)
+    xe, plan, gate = moe_dispatch(x2d, w_router, e, top_k, capacity_factor)
     with scope("moe.experts"):
-        xe = xe.astype(x2d.dtype)
         h = jax.nn.silu(jnp.einsum("ecd,edh->ech", xe, w_gate,
                                    preferred_element_type=_F32))
         h = h * jnp.einsum("ecd,edh->ech", xe, w_up,
@@ -346,7 +466,7 @@ def moe_sparse(x2d, w_router, w_gate, w_up, w_down, top_k: int,
         out = jnp.einsum("ech,ehd->ecd", h.astype(x2d.dtype), w_down,
                          preferred_element_type=_F32)       # [E, C, d]
     with scope("moe.combine"):
-        return moe_combine(out, disp, gate).astype(x2d.dtype)
+        return moe_combine(out, plan, gate).astype(x2d.dtype)
 
 
 def cross_entropy(logits, targets):

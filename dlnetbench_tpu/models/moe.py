@@ -20,6 +20,12 @@ schedule:
   single-device execution (the acceptance bar the arrival-order rule
   could never meet).  ``drop_seed=None`` + one group delegates to
   ``layers.moe_dispatch`` — bit-identical legacy behavior.
+* **The routing is a plan of indices** — ``dispatch`` returns a
+  ``layers.MoePlan`` (each token's slot in each expert's buffer, the
+  token in each slot, the token's experts), not [T, E, C] one-hots:
+  dispatch, combine and their backward passes gather rows through it
+  (``layers.dispatch_rows``, ``layers.moe_combine``), and the
+  per-expert kept counts the grouped kernels skip by are read off it.
 * **Drop closed form** — ``expected_drops`` states the capacity
   arithmetic (``sum_e,g max(0, n_ge - cap_g)``) the property tests pin
   the measured drop counts against.
@@ -87,10 +93,10 @@ def dispatch(x2d, w_router, num_experts: int, top_k: int,
              group_tokens: int = 0, gids=None, with_stats: bool = False):
     """Capacity-based token dispatch with seeded grouped token-drop.
 
-    Returns ``(xe [E, C_total, d], disp [T, E, C_total], gate [T, E])``
-    (+ ``stats`` with ``with_stats``) — the ``layers.moe_dispatch``
-    contract with the expert buffer subdivided into per-group capacity
-    blocks (``C_total = G * cap_g``).
+    Returns ``(xe [E, C_total, d], plan, gate [T, E])`` (+ ``stats``
+    with ``with_stats``) — the ``layers.moe_dispatch`` contract, the
+    plan a ``layers.MoePlan`` over an expert buffer subdivided into
+    per-group capacity blocks (``C_total = G * cap_g``).
 
     * ``group_tokens = 0`` (one group) + ``drop_seed = None`` is the
       LEGACY path — it delegates to ``layers.moe_dispatch`` outright,
@@ -111,17 +117,16 @@ def dispatch(x2d, w_router, num_experts: int, top_k: int,
         raise ValueError(f"moe.dispatch: {t} tokens not divisible by "
                          f"group_tokens={g}")
     if drop_seed is None and g == t:
-        xe, disp, gate = L.moe_dispatch(x2d, w_router, e, top_k,
+        xe, plan, gate = L.moe_dispatch(x2d, w_router, e, top_k,
                                         capacity_factor)
         if not with_stats:
-            return xe, disp, gate
+            return xe, plan, gate
         cap = group_capacity(t, top_k, e, capacity_factor)
-        _, idx = L.moe_router(x2d, w_router, top_k)
         with scope("moe.dispatch"):
-            counts = jnp.sum(jax.nn.one_hot(idx, e, dtype=_F32),
+            counts = jnp.sum(jax.nn.one_hot(plan.idx, e, dtype=jnp.int32),
                              axis=(0, 1))[None]          # [1, E]
-            stats = _routing_stats(x2d, w_router, counts, disp, cap)
-        return xe, disp, gate, stats
+            stats = _routing_stats(x2d, w_router, counts, plan, cap)
+        return xe, plan, gate, stats
 
     n_groups = t // g
     cap_g = group_capacity(g, top_k, e, capacity_factor)
@@ -130,35 +135,30 @@ def dispatch(x2d, w_router, num_experts: int, top_k: int,
         onehot = jax.nn.one_hot(idx, e, dtype=_F32)          # [T, k, E]
         gate = jnp.sum(onehot * weights[..., None], axis=1)  # [T, E]
     with scope("moe.dispatch"):
-        mask = jnp.sum(onehot, axis=1)                       # [T, E] 0/1
-        maskg = mask.reshape(n_groups, g, e)
+        routed = jnp.sum(onehot, axis=1).astype(jnp.int32)   # [T, E] 0/1
+        routed = routed.reshape(n_groups, g, e)
         if drop_seed is not None:
             if gids is None:
                 gids = jnp.arange(t, dtype=jnp.int32)
             prio = token_priority(drop_seed, gids).reshape(n_groups, g)
             order = jnp.argsort(prio, axis=1)                # queue order
             inv = jnp.argsort(order, axis=1)
-            ms = jnp.take_along_axis(maskg, order[..., None], axis=1)
-            pos_s = jnp.cumsum(ms, axis=1) - 1.0
+            rs = jnp.take_along_axis(routed, order[..., None], axis=1)
+            pos_s = jnp.cumsum(rs, axis=1) - 1
             pos = jnp.take_along_axis(pos_s, inv[..., None], axis=1)
         else:
-            pos = jnp.cumsum(maskg, axis=1) - 1.0
-        keep = maskg * (pos < cap_g)                         # [G, g, E]
-        slot = pos + (jnp.arange(n_groups, dtype=_F32)
-                      * cap_g)[:, None, None]
-        c_total = n_groups * cap_g
-        disp = jax.nn.one_hot(slot.astype(jnp.int32).reshape(t, e),
-                              c_total, dtype=_F32) \
-            * keep.reshape(t, e)[..., None]                  # [T, E, C]
-        xe = jnp.einsum("tec,td->ecd", disp, x2d.astype(_F32))
+            pos = jnp.cumsum(routed, axis=1) - 1
+        keep = (routed > 0) & (pos < cap_g)                  # [G, g, E]
+        plan = L.moe_plan(idx, pos, keep, cap_g)
+        xe = L.dispatch_rows(x2d, plan)
         if not with_stats:
-            return xe, disp, gate
-        counts = jnp.sum(maskg, axis=1)                      # [G, E]
-        stats = _routing_stats(x2d, w_router, counts, disp, cap_g)
-    return xe, disp, gate, stats
+            return xe, plan, gate
+        counts = jnp.sum(routed, axis=1)                     # [G, E]
+        stats = _routing_stats(x2d, w_router, counts, plan, cap_g)
+    return xe, plan, gate, stats
 
 
-def _routing_stats(x2d, w_router, counts, disp, cap_g: int) -> dict:
+def _routing_stats(x2d, w_router, counts, plan, cap_g: int) -> dict:
     """In-graph routing stats: routed/kept histograms, drop count (and
     its closed form — equal by construction, pinned by tests), router
     entropy of the MEAN full-softmax distribution (normalized to
@@ -168,7 +168,7 @@ def _routing_stats(x2d, w_router, counts, disp, cap_g: int) -> dict:
     p_mean = jnp.mean(probs, axis=0)                     # [E]
     entropy = -jnp.sum(p_mean * jnp.log(p_mean + 1e-12))
     routed = jnp.sum(counts, axis=0)                     # [E]
-    kept = jnp.sum(disp, axis=(0, 2))                    # [E]
+    kept = jnp.sum(plan.slot >= 0, axis=0, dtype=jnp.int32)  # [E]
     return {
         "routed": routed,
         "kept": kept,
@@ -255,18 +255,16 @@ def moe_grouped(x2d, w_router, w_gate, w_up, w_down, top_k: int,
     blocks past an expert's kept-token count are skipped, and ``quant``
     selects the fused int8/fp8 recipes."""
     e = w_gate.shape[0]
-    out = dispatch(x2d, w_router, e, top_k, capacity_factor,
-                   drop_seed=drop_seed, with_stats=True)
-    xe, disp, gate, stats = out
-    with scope("moe.dispatch"):
-        counts = jnp.minimum(
-            stats["kept"],
-            jnp.float32(xe.shape[1])).astype(jnp.int32)
-        xe = xe.astype(x2d.dtype)
+    xe, plan, gate, stats = dispatch(x2d, w_router, e, top_k,
+                                     capacity_factor, drop_seed=drop_seed,
+                                     with_stats=True)
     y = expert_ffn(xe, w_gate, w_up, w_down,
-                   impl="grouped", quant=quant, counts=counts)
+                   impl="grouped", quant=quant, counts=stats["kept"])
     with scope("moe.combine"):
-        return L.moe_combine(y, disp, gate).astype(x2d.dtype)
+        # the grouped kernels compute in x2d's dtype and expert_ffn
+        # widens what they return: combine in the kernels' dtype, so
+        # that its gathers move rows of that width, not float32's
+        return L.moe_combine(y.astype(x2d.dtype), plan, gate)
 
 
 # ------------------------------------------------------- schedule twin

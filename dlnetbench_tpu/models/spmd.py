@@ -398,8 +398,11 @@ def _moe_block(cfg: SpmdConfig, tp: int, y, lp, gids, comm_on=True,
     ``gids``: [mb, S/tp] GLOBAL token ids — the seeded drop priority's
     domain (models/moe.py), so routing is identical however the batch
     is sharded.  Routing dispatches through ``models/moe.dispatch``
-    (legacy knobs delegate to ``layers.moe_dispatch`` bit-identically);
-    the a2a pair runs blocking (``moe_a2a="monolithic"``) or as the
+    (legacy knobs delegate to ``layers.moe_dispatch`` bit-identically),
+    which gathers the local tokens' rows into ``ein`` and returns the
+    routing as a ``layers.MoePlan`` of indices; ``layers.moe_combine``
+    gathers the experts' rows back through the same plan.
+    The a2a pair runs blocking (``moe_a2a="monolithic"``) or as the
     ppermute chunk loop fused with the expert FFN
     (``"decomposed"`` — ops/moe_dispatch.a2a_expert_ffn, the
     hybrid_3d_moe dispatch/combine A2As overlapped)."""
@@ -410,7 +413,7 @@ def _moe_block(cfg: SpmdConfig, tp: int, y, lp, gids, comm_on=True,
     x2 = y.reshape(t, d)
     quant = None if cfg.moe_ffn_quant == "none" else cfg.moe_ffn_quant
     if compute_on:
-        ein, disp, gate = MoE.dispatch(
+        ein, plan, gate = MoE.dispatch(
             x2, lp["w_router"], cfg.num_experts, cfg.top_k,
             cfg.capacity_factor, drop_seed=cfg.moe_drop_seed,
             group_tokens=cfg.moe_group_tokens, gids=gids.reshape(t))
@@ -418,9 +421,9 @@ def _moe_block(cfg: SpmdConfig, tp: int, y, lp, gids, comm_on=True,
         g = cfg.moe_group_tokens or t
         c_total = (t // g) * MoE.group_capacity(
             g, cfg.top_k, cfg.num_experts, cfg.capacity_factor)
-        ein = CM.comm_stub((cfg.num_experts, c_total, d), _F32, x2,
+        ein = CM.comm_stub((cfg.num_experts, c_total, d), x2.dtype, x2,
                            lp["w_router"])
-        disp = gate = None
+        plan = gate = None
     if cfg.moe_a2a == "decomposed" and tp > 1:
         # dispatch a2a + expert FFN + combine a2a as ONE fused
         # ppermute chunk loop — each peer block's hops overlap the
@@ -455,7 +458,7 @@ def _moe_block(cfg: SpmdConfig, tp: int, y, lp, gids, comm_on=True,
                                   concat_axis=0, tiled=True) if comm_on
                    else _local_a2a(out, tp, 1, 0))
     if compute_on:
-        y2 = Lyr.moe_combine(out, disp, gate)
+        y2 = Lyr.moe_combine(out, plan, gate)
     else:
         y2 = CM.comm_stub((t, d), _F32, out)
     return y2.reshape(mb, s_loc, d).astype(y.dtype)

@@ -198,6 +198,40 @@ def test_grouped_matmul(for_chip, fmt):
     assert kernels_in(text) == 1
 
 
+def test_moe_dispatch_and_combine_at_the_cell_shapes(for_chip):
+    """The row-gather dispatch and combine with their hand-written
+    backward, at ``mixtral8x7b_train``'s shapes (T = 8192 tokens, 8
+    experts top-2, C = 2560 slots, D = 4096, bf16): the chip's compiler
+    takes the gathers and the sort; nothing of shape [T, E, C], no
+    matmul, scatter or kernel under the two scopes."""
+    import re
+
+    from dlnetbench_tpu.core import executor
+    from dlnetbench_tpu.models import layers, moe
+    t, e, k, c = 8192, 8, 2, 2560
+
+    def loss(x, w_router, scale):
+        xe, plan, gate = moe.dispatch(x, w_router, e, k, 1.25)
+        assert xe.shape == (e, c, D) and xe.dtype == BF16
+        y = layers.moe_combine(xe * scale, plan, gate)
+        return jnp.sum(y.astype(F32))
+    text = for_chip(jax.grad(loss, argnums=(0, 1, 2)), ((t, D), BF16),
+                    ((D, e), BF16), ((e, c, D), BF16))
+    assert kernels_in(text) == 0
+    assert f"[{t},{e},{c}]" not in text
+    table = executor.hlo_op_scopes(text)
+    route = {"moe.dispatch", "moe.combine"}
+    opcode = re.compile(r"\s(gather|sort|dot|convolution|scatter)\(")
+    found = {}
+    for line in text.splitlines():
+        m = executor._HLO_INSTRUCTION.match(line)
+        op = opcode.search(line.partition(", metadata=")[0])
+        if m and op and table[m.group(1)] in route:
+            found.setdefault(op.group(1), set()).add(table[m.group(1)])
+    assert found["gather"] == route and found["sort"] == {"moe.dispatch"}
+    assert not {"dot", "convolution", "scatter"} & set(found)
+
+
 def kernel_instructions(text: str) -> list:
     """The names of the Pallas custom calls, as a device trace prints
     them first in each event's name."""

@@ -29,14 +29,117 @@ def _routing_case(t=64, d=16, e=4, seed=0):
 
 
 # ------------------------------------------------------------ routing
+def _onehot_dispatch(x, wr, e, k, cf, *, drop_seed=None, group_tokens=0):
+    """The dense [T, E, C] one-hot dispatch the program ran before the
+    routing became a plan of indices, kept here as the reference the
+    gathers are compared with: ``(xe, disp, gate)``."""
+    t = x.shape[0]
+    g = group_tokens or t
+    n_groups, cap = t // g, moe.group_capacity(g, k, e, cf)
+    weights, idx = L.moe_router(x, wr, k)
+    onehot = jax.nn.one_hot(idx, e, dtype=_F32)
+    gate = jnp.sum(onehot * weights[..., None], axis=1)
+    maskg = jnp.sum(onehot, axis=1).reshape(n_groups, g, e)
+    if drop_seed is None:
+        pos = jnp.cumsum(maskg, axis=1) - 1.0
+    else:
+        prio = moe.token_priority(drop_seed, jnp.arange(t))
+        order = jnp.argsort(prio.reshape(n_groups, g), axis=1)
+        ms = jnp.take_along_axis(maskg, order[..., None], axis=1)
+        pos = jnp.take_along_axis(jnp.cumsum(ms, axis=1) - 1.0,
+                                  jnp.argsort(order, axis=1)[..., None],
+                                  axis=1)
+    keep = maskg * (pos < cap)
+    slot = pos + (jnp.arange(n_groups, dtype=_F32) * cap)[:, None, None]
+    disp = jax.nn.one_hot(slot.astype(jnp.int32).reshape(t, e),
+                          n_groups * cap, dtype=_F32) \
+        * keep.reshape(t, e)[..., None]
+    return jnp.einsum("tec,td->ecd", disp, x), disp, gate
+
+
+def _onehot_combine(out, disp, gate):
+    return jnp.einsum("ecd,tec->td", out, disp * gate[..., None])
+
+
+PATHS = {"legacy": {}, "seeded_grouped": {"drop_seed": 7,
+                                          "group_tokens": 16}}
+
+
+def _plan_as_onehot(plan, c_total):
+    """[T, E, C] 0/1 from a plan's ``slot`` (-1 matches no slot)."""
+    return (plan.slot[..., None] == jnp.arange(c_total)).astype(_F32)
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+@pytest.mark.parametrize("cf", [0.5, 1.25, 2.0], ids=["cf0.5", "cf1.25",
+                                                      "nodrop"])
+def test_plan_dispatch_and_combine_match_onehot(cf, path):
+    """Values and gradients (x, the router weight, the expert output)
+    of the gathers against the one-hot matmuls: ``xe`` exact, the rest
+    to 1e-6 of the largest reference entry."""
+    x, wr = _routing_case()
+    kw = PATHS[path]
+    xe, plan, gate = moe.dispatch(x, wr, 4, 2, cf, **kw)
+    xe0, disp, gate0 = _onehot_dispatch(x, wr, 4, 2, cf, **kw)
+    assert xe.dtype == x.dtype and jnp.all(xe == xe0)
+    assert jnp.all(gate == gate0)
+    assert jnp.all(_plan_as_onehot(plan, xe.shape[1]) == disp)
+    eo = jax.random.normal(jax.random.key(5), xe.shape, _F32)
+
+    def new(x, wr, eo):
+        xe, plan, gate = moe.dispatch(x, wr, 4, 2, cf, **kw)
+        return jnp.sum(jnp.sin(L.moe_combine(jnp.tanh(xe) * eo, plan,
+                                             gate)))
+
+    def old(x, wr, eo):
+        xe, disp, gate = _onehot_dispatch(x, wr, 4, 2, cf, **kw)
+        return jnp.sum(jnp.sin(_onehot_combine(jnp.tanh(xe) * eo, disp,
+                                               gate)))
+    got = jax.value_and_grad(new, argnums=(0, 1, 2))(x, wr, eo)
+    want = jax.value_and_grad(old, argnums=(0, 1, 2))(x, wr, eo)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        scale = max(1.0, float(jnp.max(jnp.abs(b))))
+        assert float(jnp.max(jnp.abs(a - b))) <= 1e-6 * scale
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_empty_slots_are_zero_and_dropped_tokens_get_nothing(path):
+    """At capacity factor 0.5 slots stay empty in one expert while
+    another drops: an empty slot of ``xe`` and of the combine's
+    transpose is zero; a token dropped at both its experts gets zero
+    output and zero gradient, a dropped choice zero gate gradient."""
+    x, wr = _routing_case()
+    x = x + 1.0                              # no zero row by accident
+    xe, plan, gate = moe.dispatch(x, wr, 4, 2, 0.5, **PATHS[path])
+    empty = plan.src == x.shape[0]
+    lost = jnp.all(jnp.take_along_axis(plan.slot, plan.idx, 1) < 0, 1)
+    assert bool(empty.any()) and bool(lost.any())
+    assert jnp.all(jnp.where(empty[..., None], xe, 0.0) == 0.0)
+    assert jnp.all(jnp.any(xe != 0.0, axis=-1) == ~empty)
+    out = jax.random.normal(jax.random.key(6), xe.shape, _F32)
+    y = L.moe_combine(out, plan, gate)
+    assert jnp.all(y[lost] == 0.0) and jnp.all(jnp.any(y[~lost] != 0, 1))
+
+    def loss(x, out):
+        xe, plan, gate = moe.dispatch(x, wr, 4, 2, 0.5, **PATHS[path])
+        return jnp.sum(L.moe_combine(out + xe, plan, gate) ** 2)
+    dx, dout = jax.grad(loss, argnums=(0, 1))(x, out)
+    assert jnp.all(dx[lost] == 0.0) and jnp.all(jnp.any(dx[~lost] != 0, 1))
+    assert jnp.all(jnp.where(empty[..., None], dout, 0.0) == 0.0)
+    dgate = jax.grad(lambda g: jnp.sum(L.moe_combine(out, plan, g) ** 2)
+                     )(gate)
+    assert jnp.all(jnp.where(plan.slot < 0, dgate, 0.0) == 0.0)
+    assert jnp.all((dgate != 0.0) == (plan.slot >= 0))
+
+
 def test_legacy_dispatch_bit_identical():
     """drop_seed=None + one group delegates to layers.moe_dispatch —
     the pre-ISSUE-15 harness bit for bit."""
     x, wr = _routing_case()
-    xe0, d0, g0 = L.moe_dispatch(x, wr, 4, 2, 1.25)
-    xe1, d1, g1 = moe.dispatch(x, wr, 4, 2, 1.25)
-    assert jnp.all(xe0 == xe1) and jnp.all(d0 == d1)
-    assert jnp.all(g0 == g1)
+    xe0, p0, g0 = L.moe_dispatch(x, wr, 4, 2, 1.25)
+    xe1, p1, g1 = moe.dispatch(x, wr, 4, 2, 1.25)
+    assert jnp.all(xe0 == xe1) and jnp.all(g0 == g1)
+    assert all(jnp.all(a == b) for a, b in zip(p0, p1))
 
 
 def test_seeded_routing_deterministic_and_seed_sensitive():
@@ -44,30 +147,38 @@ def test_seeded_routing_deterministic_and_seed_sensitive():
     a = moe.dispatch(x, wr, 4, 2, 0.5, drop_seed=7, group_tokens=16)
     b = moe.dispatch(x, wr, 4, 2, 0.5, drop_seed=7, group_tokens=16)
     c = moe.dispatch(x, wr, 4, 2, 0.5, drop_seed=8, group_tokens=16)
-    assert jnp.all(a[1] == b[1])            # same seed: identical
-    assert not jnp.all(a[1] == c[1])        # the seed is load-bearing
+    # same seed: identical; the seed is load-bearing
+    assert all(jnp.all(p == q) for p, q in zip(a[1], b[1]))
+    assert not jnp.all(a[1].slot == c[1].slot)
+    assert not jnp.all(a[1].src == c[1].src)
 
 
 @pytest.mark.parametrize("shards", [2, 4])
 def test_seeded_routing_shard_invariant(shards):
     """The acceptance bar: the kept/dropped set computed per shard is
     IDENTICAL to the single-device computation over the same global
-    tokens (exact one-hot equality — groups nest inside shards and
-    the priority is a pure function of (seed, global token id))."""
+    tokens (exact equality of the plan, a shard's tokens and slots
+    counted from its own start — groups nest inside shards and the
+    priority is a pure function of (seed, global token id))."""
     t, g = 64, 16
     x, wr = _routing_case(t=t)
     full = moe.dispatch(x, wr, 4, 2, 1.0, drop_seed=11, group_tokens=g,
                         gids=jnp.arange(t))
     h = t // shards
-    ch = full[1].shape[2] // shards
+    ch = full[1].src.shape[1] // shards
     for s in range(shards):
         part = moe.dispatch(x[s * h:(s + 1) * h], wr, 4, 2, 1.0,
                             drop_seed=11, group_tokens=g,
                             gids=jnp.arange(s * h, (s + 1) * h))
-        assert jnp.all(
-            full[1][s * h:(s + 1) * h, :, s * ch:(s + 1) * ch]
-            == part[1]), f"shard {s} routing differs"
+        slot = full[1].slot[s * h:(s + 1) * h]
+        src = full[1].src[:, s * ch:(s + 1) * ch]
+        assert jnp.all(jnp.where(slot >= 0, slot - s * ch, -1)
+                       == part[1].slot), f"shard {s} routing differs"
+        assert jnp.all(jnp.where(src < t, src - s * h, h)
+                       == part[1].src), f"shard {s} routing differs"
+        assert jnp.all(full[1].idx[s * h:(s + 1) * h] == part[1].idx)
         assert jnp.all(full[2][s * h:(s + 1) * h] == part[2])
+        assert jnp.all(full[0][:, s * ch:(s + 1) * ch] == part[0])
 
 
 @pytest.mark.parametrize("cf", [0.25, 0.5, 1.0, 4.0])

@@ -93,6 +93,33 @@ def test_forward_and_backward_of_a_layer_share_a_scope(steps, kind):
         assert any(f"transpose(jvp({scope}))" in op for op in mine), scope
 
 
+def test_moe_dispatch_and_combine_gather_rows_and_multiply_nothing(steps):
+    """The routing is a plan of indices: under ``moe.dispatch`` and
+    ``moe.combine``, forward and backward, no instruction is a matmul
+    (and so no fusion calls one: a fusion takes its dot's scope) or a
+    scatter, and each gathers; the step's scatters are those of the
+    embedding, the loss's target pick and the router's top-k, as
+    before; all four MoE scopes are still in the table."""
+    step = steps["moe"]
+    text, table = step.as_text(), step.op_scopes()
+    route = {"moe.dispatch", "moe.combine"}
+
+    def scopes_of(opcodes):
+        found = instructions(text, re.compile(rf"\s({opcodes})\("))
+        return [table[n] for n, _ in found]
+    assert route & set(scopes_of("dot|convolution|custom-call")) == set()
+    assert set(scopes_of("dot|convolution")) >= {"moe.router",
+                                                 "moe.experts"}
+    assert set(scopes_of("scatter")) <= {"embed", "head_loss",
+                                         "moe.router"}
+    assert route <= set(scopes_of("gather"))
+    assert route | {"moe.router", "moe.experts"} <= set(table.values())
+    for scope in route:     # the hand-written backward wears it too
+        ops = [op for n, op in instructions(text, re.compile(
+            r"\sgather\(")) if table[n] == scope]
+        assert any(f"transpose(jvp({scope}))" in op for op in ops), scope
+
+
 @pytest.mark.parametrize("op_name,want", [
     ("jit(train_k)/while/body/closed_call/jvp(attn)/dot_general", "attn"),
     ("jit(train_k)/transpose(jvp(attn))/flash_bwd_dkv/pallas_call",
