@@ -99,6 +99,14 @@ class Sizes:
     m_seq: int = 2048
     m_k: int = 2
     m_requests: int = 8
+    # hybrid: every kind of layer of models/hybrid.py once or twice
+    h_shape: dict = dataclasses.field(default_factory=lambda: dict(
+        embed_dim=256, num_heads=4, num_kv_heads=2, ff_dim=512,
+        vocab_size=512, ssm_inner=512, ssm_state=16, ssm_conv=4,
+        ssm_dt_rank=16, sliding_window=128))
+    h_seq: int = 512
+    h_k: int = 3
+    h_lr: float = 0.1
     # four chips
     c4_fsdp_model: str = "llama3_8b_16_bfloat16"
     c4_fsdp_scale: float = 0.125
@@ -129,6 +137,9 @@ TINY = Sizes(
     s_parity_requests=3, s_multi_n=4,
     m_shape=dict(_TINY_SHAPE, num_experts=4, top_k=2),
     m_seq=128, m_requests=3,
+    h_shape=dict(_TINY_SHAPE, ssm_inner=128, ssm_state=16, ssm_conv=4,
+                 ssm_dt_rank=4, sliding_window=16),
+    h_seq=128,
     c4_fsdp_scale=1e-5, c4_h3d_scale=1e-5, c4_time_scale=1e-4, c4_batch=4,
     c4_seq=128,
 )
@@ -843,6 +854,82 @@ def phase_moe(sz: Sizes) -> dict:
     }
 
 
+# ---------------------------------------------------------- phase: hybrid
+
+HYBRID_KINDS = ("mamba", "window", "mamba", "window", "mamba", "full",
+                "gmu", "cross", "gmu", "cross")
+
+
+def phase_hybrid(sz: Sizes) -> dict:
+    """The hybrid decoder (models/hybrid.py: Mamba, window, full and
+    cross differential attention, gated memory units) through the same
+    step builder and executor as ``phase_train``, with the scan and
+    attention kernels forced, against the benchmark's plain float32
+    reference of the same model on the same seeded weights."""
+    import jax
+
+    from benchmarks import reference_hybrid, weights_hybrid
+    from dlnetbench_tpu.core import executor
+    from dlnetbench_tpu.core.model_card import ModelCard
+    from dlnetbench_tpu.models import bench_step, hybrid
+
+    shape = dict(sz.h_shape)
+    card = ModelCard(name="smoke_hybrid", seq_len=sz.h_seq,
+                     num_decoder_blocks=len(HYBRID_KINDS), gated_mlp=True,
+                     tied_embeddings=True, layer_kinds=HYBRID_KINDS,
+                     differential_attention=True, **shape)
+    cfg = hybrid.HybridConfig.from_card(
+        card, dtype=sz.dtype, remat=True, attention_impl="flash",
+        scan_impl="pallas", loss_row_block=sz.h_seq // 2)
+    arch = weights_hybrid.arch_of({
+        "num_attention_heads": card.num_heads,
+        "num_key_value_heads": card.kv_heads,
+        "hidden_size": card.embed_dim, "intermediate_size": card.ff_dim,
+        "vocab_size": card.vocab_size, "layer_kinds": HYBRID_KINDS,
+        "num_hidden_layers": len(HYBRID_KINDS),
+        "sliding_window": card.sliding_window, "layer_norm_eps": 1e-5,
+        "torch_dtype": sz.dtype,
+        "assumed": {k: shape[k] for k in ("ssm_inner", "ssm_state",
+                                          "ssm_conv", "ssm_dt_rank")}})
+
+    def make_params():
+        return weights_hybrid.make_params(arch, sz.seed)
+    tokens = weights_hybrid.make_token_pool(
+        sz.seed, 1, 2, sz.h_seq + 1, card.vocab_size)[0]
+    want = reference_hybrid.sgd_steps(
+        make_params, [tokens] * sz.h_k, arch, sz.h_lr)["losses"]
+    prog = executor.CompiledProgram(executor.Program(
+        fn=bench_step.make_train_k(cfg, sz.h_k, sz.h_lr),
+        args=(make_params(), tokens),
+        donate_argnums=bench_step.DONATE_ARGNUMS))
+    kernels = prog.as_text().count("tpu_custom_call")
+    if on_tpu():
+        # two scan kernels a mamba layer and three attention kernels a
+        # call, each at least once
+        require(kernels >= 5, f"compiled hybrid step holds {kernels} "
+                              f"tpu_custom_call")
+    got = [float(v) for v in jax.block_until_ready(prog()[1])]
+    tol_first, tol_drop = 0.02, 0.35
+    require(abs(got[0] - want[0]) <= tol_first * want[0],
+            f"hybrid step: first loss {got[0]} vs float32 reference "
+            f"{want[0]} (tolerance {tol_first} relative)")
+    drop_got, drop_want = got[0] - got[-1], want[0] - want[-1]
+    require(drop_want > 0 and abs(drop_got - drop_want)
+            <= tol_drop * drop_want,
+            f"hybrid step: loss fell {drop_got} over {sz.h_k} steps, "
+            f"reference {drop_want} (tolerance {tol_drop} relative)")
+    return {"shapes": {**shape, "seq": sz.h_seq, "batch": 2,
+                       "layer_kinds": list(HYBRID_KINDS),
+                       "steps": sz.h_k, "lr": sz.h_lr},
+            "tpu_custom_calls": kernels,
+            "memory_analysis": prog.memory_analysis,
+            "losses": [round(v, 4) for v in got],
+            "float32_losses": [round(v, 4) for v in want],
+            "checks": "first loss and the loss's fall within tolerance "
+                      "of benchmarks/reference_hybrid.py; scan and "
+                      "attention kernels compiled in"}
+
+
 # ------------------------------------------------------ four-chip phases
 
 def all_device_ids() -> set:
@@ -1049,7 +1136,7 @@ def phase_kv_shard(sz: Sizes) -> dict:
 
 ONE_CHIP = (("kernels", phase_kernels), ("train", phase_train),
             ("proxy", phase_proxy), ("serve", phase_serve),
-            ("moe", phase_moe))
+            ("moe", phase_moe), ("hybrid", phase_hybrid))
 FOUR_CHIPS = (("mesh_proxies", phase_mesh_proxies), ("spmd", phase_spmd),
               ("kv_shard", phase_kv_shard))
 

@@ -51,6 +51,16 @@ class ModelCard:
     image_size: int = 0             # ViT
     patch_size: int = 0             # ViT
     num_classes: int = 0            # ViT head
+    # a per-layer pattern of mixers (models/hybrid.py KINDS: mamba,
+    # window, full, gmu, cross), one name a decoder block; () => every
+    # block is the transformer's one kind (models/transformer.py)
+    layer_kinds: tuple = ()
+    sliding_window: int = 0         # keys a "window" layer attends
+    differential_attention: bool = False  # heads paired, two softmaxes
+    ssm_inner: int = 0              # a mamba / gmu layer's channels E
+    ssm_state: int = 0              # state size N a channel
+    ssm_conv: int = 0               # depthwise causal conv width
+    ssm_dt_rank: int = 0            # rank of the step projection
 
     # ------------------------------------------------------------------ #
     @property
@@ -95,9 +105,28 @@ class ModelCard:
         n_mat = 3 if self.gated_mlp else 2
         return n_mat * self.embed_dim * self.ff_dim
 
+    def mixer_params(self, kind: str) -> int:
+        """Parameters of one ``layer_kinds`` mixer (norms left out)."""
+        d, e, n, r = (self.embed_dim, self.ssm_inner, self.ssm_state,
+                      self.ssm_dt_rank)
+        if kind == "mamba":
+            return (d * 2 * e + e * self.ssm_conv + e + e * (r + 2 * n)
+                    + r * e + e + e * n + e + e * d)
+        if kind == "gmu":
+            return 2 * d * e
+        if kind == "cross":
+            return 2 * d * d            # queries and output only
+        return self.attn_params_per_layer()
+
     def num_params(self) -> int:
         """Analytic total parameter count (biases/norms included coarsely)."""
         d = self.embed_dim
+        if self.layer_kinds:
+            per_block = self.mlp_params_per_expert() + 4 * d
+            total = sum(self.mixer_params(k) + per_block
+                        for k in self.layer_kinds) + 2 * d
+            return total + self.vocab_size * d * (
+                1 if self.tied_embeddings else 2)
         per_layer = self.attn_params_per_layer() + 2 * d  # + two norms
         if self.is_moe:
             per_layer += self.num_experts * self.mlp_params_per_expert()
@@ -138,6 +167,8 @@ def _parse_card(name: str, raw: dict) -> ModelCard:
         )
     known = {f.name for f in dataclasses.fields(ModelCard)}
     kwargs = {k: v for k, v in raw.items() if k in known and k != "moe_params"}
+    if "layer_kinds" in kwargs:
+        kwargs["layer_kinds"] = tuple(kwargs["layer_kinds"])
     return ModelCard(name=name, moe_params=moe, **kwargs)
 
 

@@ -5,7 +5,7 @@ python/download_models.py:21-36 registry, :41-109 download logic), rethought
 for this framework: what every downstream layer consumes is the
 *architecture card* (core/model_card.py), so the useful artifact of "import
 a HF model" is a card, not a cache of safetensors.  This module maps a HF
-config (``model_type`` gpt2 / llama / mistral / mixtral / vit) onto
+config (``model_type`` gpt2 / llama / mistral / mixtral / phi4flash / vit) onto
 ``ModelCard`` fields and writes the card JSON.
 
 Offline-first: hub access is attempted only when requested and is never
@@ -94,6 +94,9 @@ def card_from_hf_config(name: str, cfg: Mapping[str, Any] | Any) -> ModelCard:
             moe_params=moe,
         )
 
+    if mt == "phi4flash":
+        return _phi4flash_card(name, cfg)
+
     if mt == "vit":
         image = int(cfg["image_size"])
         patch = int(cfg["patch_size"])
@@ -112,6 +115,54 @@ def card_from_hf_config(name: str, cfg: Mapping[str, Any] | Any) -> ModelCard:
     raise ValueError(f"unsupported HF model_type {mt!r} for {name}")
 
 
+def phi4flash_layer_kinds(num_layers: int) -> tuple:
+    """The SambaY layer map (arXiv:2507.06607; ``modeling_phi4flash.py``),
+    which ``config.json`` does not state: the first half and one more
+    layer alternate mamba and window attention, the layer after that is
+    the one full-attention layer, the rest alternate gated memory units
+    and cross attention over that layer's keys and values."""
+    half = num_layers // 2
+    kinds = []
+    for li in range(num_layers):
+        if li <= half:
+            kinds.append("mamba" if li % 2 == 0 else "window")
+        elif li == half + 1:
+            kinds.append("full")
+        else:
+            kinds.append("gmu" if li % 2 == 0 else "cross")
+    return tuple(kinds)
+
+
+def _phi4flash_card(name: str, cfg: Mapping[str, Any]) -> ModelCard:
+    """``model_type: "phi4flash"``.  The state-space sizes are not in
+    the config: they are the Mamba family's defaults (E = 2 x hidden,
+    N = 16, conv 4, dt rank hidden / 16) unless the config names them
+    (``mamba_d_state``, ``mamba_d_conv``, ``mamba_expand``,
+    ``mamba_dt_rank``)."""
+    hidden = int(cfg["hidden_size"])
+    heads = int(cfg["num_attention_heads"])
+    dt_rank = cfg.get("mamba_dt_rank", "auto")
+    return ModelCard(
+        name=name,
+        embed_dim=hidden,
+        num_heads=heads,
+        num_kv_heads=int(cfg.get("num_key_value_heads") or heads),
+        ff_dim=int(cfg["intermediate_size"]),
+        seq_len=int(cfg["max_position_embeddings"]),
+        num_decoder_blocks=int(cfg["num_hidden_layers"]),
+        vocab_size=int(cfg["vocab_size"]),
+        gated_mlp=True,
+        tied_embeddings=bool(cfg.get("tie_word_embeddings", True)),
+        layer_kinds=phi4flash_layer_kinds(int(cfg["num_hidden_layers"])),
+        sliding_window=int(cfg["sliding_window"]),
+        differential_attention=True,
+        ssm_inner=int(cfg.get("mamba_expand", 2)) * hidden,
+        ssm_state=int(cfg.get("mamba_d_state", 16)),
+        ssm_conv=int(cfg.get("mamba_d_conv", 4)),
+        ssm_dt_rank=(hidden // 16 if dt_rank == "auto" else int(dt_rank)),
+    )
+
+
 def card_to_json(card: ModelCard) -> dict:
     """Card -> the on-disk JSON schema (reference models/*.json shape plus
     the rebuild's extended fields; zero/False/None fields are elided)."""
@@ -121,7 +172,7 @@ def card_to_json(card: ModelCard) -> dict:
             continue
         v = getattr(card, f.name)
         if v:
-            out[f.name] = v
+            out[f.name] = list(v) if isinstance(v, tuple) else v
     if card.moe_params is not None:
         out["moe_params"] = {
             "num_experts": card.moe_params.num_experts,

@@ -81,14 +81,20 @@ def make_train_k(cfg, k: int, lr: float = 1e-3):
     move (the headline measures time); a caller that wants to see the
     loss fall over the chain passes a larger one."""
     from dlnetbench_tpu.metrics.spans import scope
+    from dlnetbench_tpu.models import hybrid
     from dlnetbench_tpu.models import transformer as tfm
+
+    # the one step builder for every model family: a config names its
+    # model by its type
+    loss_fn = (hybrid.loss_fn if isinstance(cfg, hybrid.HybridConfig)
+               else tfm.loss_fn)
 
     def sgd(p, g):
         with scope("optimizer"):
             return jax.tree.map(lambda a, b: a - lr * b.astype(a.dtype),
                                 p, g)
 
-    if tfm.needs_qstate(cfg):
+    if loss_fn is tfm.loss_fn and tfm.needs_qstate(cfg):
         def train_k(carry, t):
             def body(carry, _):
                 p, qs = carry
@@ -100,7 +106,7 @@ def make_train_k(cfg, k: int, lr: float = 1e-3):
 
     def train_k(p, t):
         def body(p, _):
-            loss, g = jax.value_and_grad(tfm.loss_fn)(p, t, cfg)
+            loss, g = jax.value_and_grad(loss_fn)(p, t, cfg)
             return sgd(p, g), loss
         return jax.lax.scan(body, p, None, length=k)
     return train_k

@@ -44,7 +44,8 @@ def _dense_mask_np(spec: _M.MaskSpec, s: int):
     return _M.dense_mask(spec, s)
 
 
-def attention(q, k, v, causal: bool, impl: str = "auto", mask=None):
+def attention(q, k, v, causal: bool, impl: str = "auto", mask=None,
+              block_q: int | None = None, block_k: int | None = None):
     """q: [B, S, Hq, Dh], k/v: [B, S, Hkv, Dh] -> [B, S, Hq, Dh].
 
     impl: "flash" (Pallas kernel, error if unsupported shape),
@@ -57,7 +58,13 @@ def attention(q, k, v, causal: bool, impl: str = "auto", mask=None):
     (the CPU-mesh reference the sparse paths are parity-tested
     against).  The spec's ``causal`` must agree with the ``causal``
     argument — a silent disagreement would A/B two different maths.
+
+    ``block_q`` / ``block_k`` go to the Pallas kernels where they run
+    (a window narrower than the default blocks skips nothing unless the
+    blocks are as narrow); the dense paths have no blocks.
     """
+    blocks = {} if block_q is None and block_k is None else {
+        "block_q": block_q, "block_k": block_k}
     s = q.shape[1]
     if mask is not None:
         if mask.causal != causal:
@@ -73,16 +80,16 @@ def attention(q, k, v, causal: bool, impl: str = "auto", mask=None):
         return _L.attention(q, k, v, causal=causal)
     if impl == "flash":
         if mask is not None:
-            return splash_attention(q, k, v, mask)
-        return flash_attention(q, k, v, causal=causal)
+            return splash_attention(q, k, v, mask, **blocks)
+        return flash_attention(q, k, v, causal=causal, **blocks)
     if impl != "auto":
         raise ValueError(f"unknown attention impl {impl!r}")
     supported = flash_supported(q, k, v)   # raises at S>=64k w/o blocks
     if (jax.default_backend() == "tpu" and s >= _AUTO_MIN_SEQ
             and supported):
         if mask is not None:
-            return splash_attention(q, k, v, mask)
-        return flash_attention(q, k, v, causal=causal)
+            return splash_attention(q, k, v, mask, **blocks)
+        return flash_attention(q, k, v, causal=causal, **blocks)
     if s >= LONG_SEQ:
         # the dense fallback at 64k+ materializes the S^2 score matrix
         # — never a sane degradation (ISSUE 10 satellite: fail loud,

@@ -241,6 +241,38 @@ def kernel_instructions(text: str) -> list:
             and (m := executor._HLO_INSTRUCTION.match(line))]
 
 
+def test_selective_scan_forward_backward_at_the_cell_shapes(for_chip):
+    """The two scan kernels at the hybrid cell's shapes (T=8192,
+    E=5120, N=16): one forward, one backward, and no ``[T, E, N]``
+    array beside them."""
+    ss = ops_module("selective_scan")
+    t, e, n = 8192, 5120, 16
+
+    def grads(u, delta, a, b, c, d):
+        return jax.grad(lambda *x: jnp.sum(ss.selective_scan(
+            *x, "pallas").astype(F32)), argnums=(0, 1, 2, 3, 4, 5))(
+                u, delta, a, b, c, d)
+    text = for_chip(grads, ((1, t, e), BF16), ((1, t, e), F32),
+                    ((e, n), F32), ((1, t, n), BF16), ((1, t, n), BF16),
+                    ((e,), F32))
+    assert kernels_in(text) == 2
+    assert "ssm_scan_fwd" in text and "ssm_scan_bwd" in text
+    assert f"[1,{t},{e},{n}]" not in text and f"[1,{t},{n},{e}]" not in text
+
+
+def test_differential_window_attention_at_the_cell_shapes(for_chip):
+    """Window-512 attention over pairs of 64-wide heads padded to the
+    value's 128, in blocks of 512: forward, dq, dkv."""
+    from dlnetbench_tpu import ops
+    from dlnetbench_tpu.ops.attention_mask import MaskSpec
+    spec = MaskSpec(window=512)
+    text = for_chip(grad_of(lambda q, k, v: ops.attention(
+        q, k, v, causal=True, impl="flash", mask=spec, block_q=512,
+        block_k=512)), ((1, 8192, 20, 128), BF16),
+        ((1, 8192, 10, 128), BF16), ((1, 8192, 10, 128), BF16))
+    assert kernels_in(text) == 3
+
+
 def test_kernels_carry_their_given_names_on_the_chip(for_chip):
     """Under one of the step's scopes, as the models call them, the
     chip's compiler names a kernel's instruction by the ``name=`` of its

@@ -33,9 +33,19 @@ VIT_B = {"model_type": "vit", "hidden_size": 768, "num_attention_heads": 12,
          "image_size": 224, "patch_size": 16, "num_labels": 1000}
 
 
+# the catalog's config of microsoft/Phi-4-mini-flash-reasoning
+PHI4FLASH = {"model_type": "phi4flash", "hidden_size": 2560,
+             "intermediate_size": 10240, "num_attention_heads": 40,
+             "num_key_value_heads": 20, "num_hidden_layers": 32,
+             "max_position_embeddings": 262144, "sliding_window": 512,
+             "mb_per_layer": 2, "layer_norm_eps": 1e-05,
+             "tie_word_embeddings": True, "vocab_size": 200064}
+
+
 @pytest.mark.parametrize("name,cfg", [
     ("gpt2_l", GPT2_L), ("llama3_8b", LLAMA3_8B),
     ("mixtral_8x7b", MIXTRAL), ("vit_b", VIT_B),
+    ("phi4_mini_flash_reasoning", PHI4FLASH),
 ])
 def test_mapping_reproduces_committed_card(name, cfg):
     got = hf_import.card_from_hf_config(name, cfg)
@@ -73,3 +83,12 @@ def test_cli_list_and_all(tmp_path, capsys):
     # moe block survives the roundtrip as nested JSON
     raw = json.loads((tmp_path / "mixtral_8x7b.json").read_text())
     assert raw["moe_params"]["num_experts_per_tok"] == 2
+
+
+def test_phi4flash_layer_map_is_the_published_one():
+    kinds = hf_import.phi4flash_layer_kinds(32)
+    assert kinds[:17:2] == ("mamba",) * 9 and kinds[1:16:2] == ("window",) * 8
+    assert kinds[17] == "full"
+    assert kinds[18::2] == ("gmu",) * 7 and kinds[19::2] == ("cross",) * 7
+    card = hf_import.card_from_hf_config("x", PHI4FLASH)
+    assert abs(card.num_params() - 3.85e9) / 3.85e9 < 0.01
