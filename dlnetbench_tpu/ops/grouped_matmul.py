@@ -27,9 +27,16 @@ layout a first-class grid:
 
 ``grouped_ffn`` stacks three grouped matmuls into the SwiGLU expert
 FFN with a straight-through (master-dtype) custom VJP — the same
-backward recipe every quantized path in this repo uses.  All kernels
-run under ``interpret=True`` off-TPU (pallas_common), so the CPU-mesh
-tier-1 lane unit-tests them.
+backward recipe every quantized path in this repo uses.  The forward
+hands its two projections ``g = x @ w_gate`` and ``u = x @ w_up`` to
+the backward as residuals, in the dtype its kernels wrote them (the
+``layers.swiglu_fwd_res`` discipline), so the backward is the gradient
+of the function that was evaluated and computes neither again: six
+full-grid einsums, not eight.  Rows past an expert's count need no
+mask there: a skipped block's ``g`` and ``u`` are the kernel's zeros,
+which make ``h``, ``dg`` and ``du`` zero whatever ``dy`` holds.  All
+kernels run under ``interpret=True`` off-TPU (pallas_common), so the
+CPU-mesh tier-1 lane unit-tests them.
 """
 from __future__ import annotations
 
@@ -221,8 +228,11 @@ def expert_amax(x):
 
 
 def _ffn_fwd(x, w_gate, w_up, w_down, counts, fmt, blocks):
-    """The three grouped dots of the expert SwiGLU; bf16-residual
-    discipline matches ``layers.swiglu_fwd_res``.  ``blocks`` is the
+    """The three grouped dots of the expert SwiGLU -> ``(y, g, u)``:
+    the ONE body behind the primal and the VJP's forward, so the
+    rounding of what the backward reads cannot drift from what the
+    forward computed (``layers.swiglu_fwd_res``'s discipline: ``g``,
+    ``u`` stay in the kernels' output dtype).  ``blocks`` is the
     (block_c, block_n, block_k) triple (hashable — it rides a
     custom_vjp nondiff argnum)."""
     kw = dict(counts=counts,
@@ -236,33 +246,40 @@ def _ffn_fwd(x, w_gate, w_up, w_down, counts, fmt, blocks):
         h = (jax.nn.silu(g.astype(F32)) * u.astype(F32)).astype(g.dtype)
         sh = scale_from_amax(expert_amax(h), fmt)
         wdq, swd = quantize_experts(w_down, fmt)
-        return grouped_matmul(h, wdq, sx=sh, sw=swd, fmt=fmt, **kw)
+        return (grouped_matmul(h, wdq, sx=sh, sw=swd, fmt=fmt, **kw),
+                g, u)
     g = grouped_matmul(x, w_gate, **kw)
     u = grouped_matmul(x, w_up, **kw)
     h = (jax.nn.silu(g.astype(F32)) * u.astype(F32)).astype(g.dtype)
-    return grouped_matmul(h, w_down, **kw)
+    return grouped_matmul(h, w_down, **kw), g, u
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def _grouped_ffn(x, w_gate, w_up, w_down, counts, fmt, blocks):
-    return _ffn_fwd(x, w_gate, w_up, w_down, counts, fmt, blocks)
+    return _ffn_fwd(x, w_gate, w_up, w_down, counts, fmt, blocks)[0]
 
 
 def _grouped_ffn_fwd(x, w_gate, w_up, w_down, counts, fmt, blocks):
-    y = _ffn_fwd(x, w_gate, w_up, w_down, counts, fmt, blocks)
-    return y, (x, w_gate, w_up, w_down, counts)
+    y, g, u = _ffn_fwd(x, w_gate, w_up, w_down, counts, fmt, blocks)
+    return y, (x, g, u, w_gate, w_up, w_down, counts)
 
 
 def _grouped_ffn_bwd(fmt, blocks, res, dy):
     """Straight-through master-dtype backward (the recipe every
-    quantized path shares): batched einsums over the expert axis, h
-    recomputed instead of saved.  Rows beyond an expert's count carry
-    zero cotangent by construction (their combine weights are zero),
-    so no count mask is needed here."""
-    x, w_gate, w_up, w_down, counts = res
+    quantized path shares): six batched einsums over the expert axis,
+    all ``E * C`` slots, float32 accumulation.  ``g`` and ``u`` are
+    the forward's own (for every ``fmt``: the gradient is taken at the
+    activations the forward fed to ``silu(g) * u``), widened to
+    float32 for the elementwise block; only ``h`` is made again from
+    them.  No count mask is needed: in a block the forward skipped,
+    ``g`` and ``u`` are its zeros, so ``h``, ``dg`` and ``du`` vanish
+    there and those rows get zero ``dx`` whatever ``dy`` holds; in a
+    live block, rows past the count are the dispatch's zero fill and
+    carry zero cotangent (their combine weights are zero)."""
+    x, g, u, w_gate, w_up, w_down, counts = res
     xf = x.astype(F32)
-    g = jnp.einsum("ecd,edh->ech", xf, w_gate.astype(F32))
-    u = jnp.einsum("ecd,edh->ech", xf, w_up.astype(F32))
+    g = g.astype(F32)
+    u = u.astype(F32)
     sig = jax.nn.sigmoid(g)
     silu = g * sig
     h = silu * u

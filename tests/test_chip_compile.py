@@ -232,6 +232,47 @@ def test_moe_dispatch_and_combine_at_the_cell_shapes(for_chip):
     assert not {"dot", "convolution", "scatter"} & set(found)
 
 
+def test_moe_train_step_at_the_cell_shapes_fits_the_chip(for_chip, one_chip):
+    """``mixtral8x7b_train``'s whole step (one layer, 8 experts, B=2 x
+    S=4096, the cell's own files and compiler options), as the runner
+    builds it: arguments and temporaries fit the chip's 15.75 GiB, and
+    of the expert backward's ``[E, C, F]`` float32 arrays only ``dh``
+    is left: ``g`` and ``u`` are read as the forward's kernels wrote
+    them, in bf16.  ``auto`` attention asks the backend, the CPU here,
+    so the test names the kernels the chip would pick."""
+    import re
+
+    from benchmarks import harness, weights
+    from benchmarks.runners import train
+    from dlnetbench_tpu.core import executor
+    from dlnetbench_tpu.models import bench_step, moe
+    cell = harness.load_cell("mixtral8x7b_train")
+    wl, tr = cell.workload, cell.traffic
+    arch = weights.arch_of(cell.config,
+                           capacity_factor=wl["capacity_factor"])
+    cfg = train.program_config(cell, arch, {"attention_impl": "flash"})
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree.map(
+        on_chip, jax.eval_shape(lambda: weights.make_params(arch, 0)))
+    tokens = jax.ShapeDtypeStruct((tr["batch"], tr["seq_len"] + 1), I32,
+                                  sharding=one_chip)
+    step = executor.CompiledStep(
+        bench_step.make_train_k(cfg, 1, wl["lr"]), (params, tokens),
+        donate_argnums=bench_step.DONATE_ARGNUMS,
+        compiler_options=wl["compiler_options"])
+    mem = step.memory_analysis
+    assert mem["argument"] + mem["temp"] < 15.75 * 2 ** 30
+    text = step.as_text()
+    assert kernels_in(text) == 6     # three flash, three grouped_mm
+    e, f = arch["num_experts"], arch["ff_dim"]
+    c = moe.group_capacity(tr["batch"] * tr["seq_len"], arch["top_k"], e,
+                           arch["capacity_factor"])
+    wide = re.compile(rf"^\s*(?:ROOT )?\S+ = f32\[{e},{c},{f}\]", re.M)
+    assert len(wide.findall(text[text.index("ENTRY"):])) == 1
+
+
 def kernel_instructions(text: str) -> list:
     """The names of the Pallas custom calls, as a device trace prints
     them first in each event's name."""

@@ -276,22 +276,109 @@ def test_grouped_matmul_int8_exact_vs_composed():
     assert jnp.all(out == comp)
 
 
-def test_grouped_ffn_grads_match_reference():
+def _ffn_ref(x_, a, b, c, live=None):
+    """The einsum SwiGLU the grouped FFN is held to; ``live`` [E, C]
+    marks the rows the kernels compute (the rest emit zeros)."""
+    h = (jax.nn.silu(jnp.einsum("ecd,edh->ech", x_, a))
+         * jnp.einsum("ecd,edh->ech", x_, b))
+    y = jnp.einsum("ech,ehd->ecd", h, c)
+    return y if live is None else y * live[..., None]
+
+
+_GM_BLOCKS = dict(block_c=4, block_n=16, block_k=16)
+
+
+@pytest.mark.parametrize("counts", [None, (16, 5, 0, 9)],
+                         ids=["all_slots", "blocks_skipped"])
+def test_grouped_ffn_grads_match_reference(counts):
+    """All four gradients against the einsum reference.  With counts,
+    x is nonzero everywhere and the cotangent is nonzero everywhere, so
+    only the forward's own g, u (zeros in a skipped block) keep rows
+    past the count at zero dx: a recomputed g, u would not."""
     x, wg, wu, wd = _gm_case()
+    r = jax.random.normal(jax.random.key(7), x.shape, _F32)
+    cnt = live = None
+    if counts is not None:
+        cnt = jnp.array(counts, jnp.int32)
+        # a block of 4 rows is live when its first row is under the count
+        live = ((jnp.arange(16)[None, :] // 4 * 4)
+                < cnt[:, None]).astype(_F32)
 
     def loss(x_, a, b, c):
-        return jnp.sum(gm.grouped_ffn(x_, a, b, c, block_c=8,
-                                      block_n=16, block_k=16) ** 2)
+        return jnp.sum(gm.grouped_ffn(x_, a, b, c, counts=cnt,
+                                      **_GM_BLOCKS) * r)
 
     def ref(x_, a, b, c):
-        h = (jax.nn.silu(jnp.einsum("ecd,edh->ech", x_, a))
-             * jnp.einsum("ecd,edh->ech", x_, b))
-        return jnp.sum(jnp.einsum("ech,ehd->ecd", h, c) ** 2)
+        return jnp.sum(_ffn_ref(x_, a, b, c, live) * r)
 
     g1 = jax.grad(loss, argnums=(0, 1, 2, 3))(x, wg, wu, wd)
     g2 = jax.grad(ref, argnums=(0, 1, 2, 3))(x, wg, wu, wd)
     for a, b in zip(g1, g2):
         assert float(jnp.max(jnp.abs(a - b))) < 1e-5
+    if live is not None:
+        assert float(jnp.max(jnp.abs(g1[0] * (1.0 - live)[..., None]))) \
+            == 0.0
+        # ... and the mask is not why: a live block's rows get theirs
+        assert float(jnp.max(jnp.abs(g1[0][1, :4]))) > 0.0
+
+
+def _eqns_outside_kernels(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations carry
+    (einsum's jit, custom-derivative bodies), the bodies of
+    ``pallas_call`` left out."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns_outside_kernels(sub)
+
+
+def test_grouped_ffn_backward_reads_forward_g_u():
+    """The backward holds six matmuls, not eight: the forward's three
+    kernels wrote g and u, and nothing multiplies x by w_gate or w_up
+    again (the only [E, C, h] product left is dh = dy @ w_down^T)."""
+    x, wg, wu, wd = _gm_case()
+    e, c, _ = x.shape
+    h = wg.shape[2]
+
+    def loss(x_, a, b, c_):
+        return jnp.sum(gm.grouped_ffn(x_, a, b, c_, **_GM_BLOCKS) ** 2)
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3)))(
+        x, wg, wu, wd).jaxpr
+    eqns = list(_eqns_outside_kernels(jaxpr))
+    assert sum(q.primitive.name == "pallas_call" for q in eqns) == 3
+    dots = [q for q in eqns if q.primitive.name == "dot_general"]
+    assert len(dots) == 6
+    assert sum(q.outvars[0].aval.shape == (e, c, h) for q in dots) == 1
+
+
+@pytest.mark.parametrize("fmt", ["int8", "float8"])
+def test_grouped_ffn_quantized_grads_straight_through(fmt):
+    """A quantized format's backward is the master-dtype gradient at
+    the forward's own (quantized-kernel) g, u: close to the unquantized
+    reference's, to the format's rounding."""
+    x, wg, wu, wd = _gm_case(dtype=jnp.bfloat16)
+
+    def loss(x_, a, b, c):
+        y = gm.grouped_ffn(x_, a, b, c, fmt=fmt, **_GM_BLOCKS)
+        return jnp.sum(y.astype(_F32) ** 2)
+
+    def ref(x_, a, b, c):
+        return jnp.sum(_ffn_ref(x_, a, b, c) ** 2)
+
+    g1 = jax.grad(loss, argnums=(0, 1, 2, 3))(x, wg, wu, wd)
+    g2 = jax.grad(ref, argnums=(0, 1, 2, 3))(
+        *(t.astype(_F32) for t in (x, wg, wu, wd)))
+    for a, b in zip(g1, g2):
+        assert a.dtype == jnp.bfloat16
+        a = a.astype(_F32)
+        assert jnp.all(jnp.isfinite(a))
+        assert float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b)) < 0.15
 
 
 def test_grouped_ffn_fp8_runs_finite():
