@@ -369,7 +369,7 @@ def _run_bench(args, tracer) -> int:
     # on-chip (docs/PERF.md r4): split-dot custom VJP 0.9975 (neutral),
     # fused Pallas dg/du + dWd kernels 1.012 (slower), bare same-shape
     # dots 0.992 of peak in isolation — XLA's backward schedule is at
-    # the wall; mlp_backward stays "fused".
+    # the wall; the SwiGLU backward is plain autodiff.
     # The step itself is built by models/bench_step.py, SHARED with
     # examples/xla_knob_study.py so compiler-knob sweeps tune exactly
     # this program.
@@ -2105,7 +2105,7 @@ def _ab_line(metric: str, summaries_s: dict, round_times_s: dict,
 def _bench_quant_fused_ab(card, hw_key: str, dev, fmt: str) -> dict | None:
     """Paired fused-vs-composed quantized-matmul A/B at the bench shape
     (ISSUE 3 tentpole; protocol = the r4 MLP study's interleaved
-    rounds).  Three variants of the (T,D)@(D,F) up-projection chained
+    rounds).  Two variants of the (T,D)@(D,F) up-projection chained
     K deep:
 
     * ``composed`` — the shipped XLA recipe (ops/int8.py int8_dot /
@@ -2116,12 +2116,9 @@ def _bench_quant_fused_ab(card, hw_key: str, dev, fmt: str) -> dict | None:
       amax still reduced by XLA (one read of x), but quantization
       happens in the kernel prologue in VMEM and sa*sb in the
       epilogue — the quantized activation never exists in HBM.
-    * ``fused_delayed`` — the amax additionally carried through the
-      chain as state (SwitchBack/FP8-recipe delayed scaling): NO
-      amax reduction on the hot path at all.
 
     The weight-quantization pass is loop-invariant and hoisted by XLA
-    in ALL variants (weights pre-quantized once per chain), so the A/B
+    in BOTH variants (weights pre-quantized once per chain), so the A/B
     isolates exactly the per-step activation-quantization overhead."""
     import jax.numpy as jnp
 
@@ -2140,11 +2137,9 @@ def _bench_quant_fused_ab(card, hw_key: str, dev, fmt: str) -> dict | None:
     if fmt == "int8":
         from dlnetbench_tpu.ops.int8 import int8_dot as composed_dot
         fused_dot_op = qmm.int8_dot_fused
-        delayed_op = qmm.int8_dot_fused_delayed
     else:
         from dlnetbench_tpu.ops.fp8 import fp8_dot as composed_dot
         fused_dot_op = qmm.fp8_dot_fused
-        delayed_op = qmm.fp8_dot_fused_delayed
 
     tokens, d, f = BATCH * SEQ, card.embed_dim, card.ff_dim
     x = jax.random.normal(jax.random.key(11), (tokens, d), jnp.bfloat16)
@@ -2165,18 +2160,9 @@ def _bench_quant_fused_ab(card, hw_key: str, dev, fmt: str) -> dict | None:
             return jax.lax.scan(body, x0, None, length=K)[0]
         return chain
 
-    def delayed_chain(carry):
-        def body(c, _):
-            xc, am = c
-            y, am_next = delayed_op(xc, w, am)
-            return ((xc + y[:, :d] * 1e-6).astype(xc.dtype), am_next), ()
-        return jax.lax.scan(body, carry, None, length=K)[0]
-
-    amax0 = jnp.max(jnp.abs(x.astype(jnp.float32)))
     progs = {
         "composed": _compile_chain(chain_of(composed_dot), x),
         "fused": _compile_chain(chain_of(fused_dot_op), x),
-        "fused_delayed": _compile_chain(delayed_chain, (x, amax0)),
     }
     summaries, round_times = _measure_paired(progs, K)
 
@@ -2187,8 +2173,8 @@ def _bench_quant_fused_ab(card, hw_key: str, dev, fmt: str) -> dict | None:
                  + BYTES_PER_ELEMENT[peak_key] * d * f)
     line = _ab_line(
         f"{label}: fused-quantization Pallas matmul (VMEM prologue "
-        f"quantize + in-register sa*sb epilogue; fused_delayed carries "
-        f"amax as chain state) vs composed XLA recipe, paired "
+        f"quantize + in-register sa*sb epilogue) vs composed XLA "
+        f"recipe, paired "
         f"interleaved rounds, {tokens} tok D={d} F={f}, "
         f"{dev.device_kind} ({hw_key}, {peak_key} peak "
         f"{peak/1e12:.0f} T/s)",

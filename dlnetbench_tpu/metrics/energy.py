@@ -41,7 +41,7 @@ POWER_SAMPLING_RATE_MS = 5   # reference dp.cpp:67
 
 
 class TpuChipSampler:
-    """Best-effort TPU *chip* energy counter (VERDICT r5 #7).
+    """Best-effort TPU *chip* energy counter.
 
     TPU chips expose no public per-chip energy counter through JAX/PJRT
     today, but that is a probed fact, not an assumption — this sampler

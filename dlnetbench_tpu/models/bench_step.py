@@ -22,12 +22,13 @@ import jax
 
 from dlnetbench_tpu.utils.env import env_int
 
-# Shape knobs, frozen at import (the DLNB_FLASH_BWD_BLOCKS discipline):
-# the driver's headline shape by default; DLNB_BENCH_* overrides let the
-# sentinel lane (Makefile `check-bench`, tests/test_sentinel.py) run the
-# EXACT bench.py pipeline — headline compile, stat bands, --check — on a
-# tiny CPU-feasible model.  Every consumer imports these constants, so a
-# run's shape is one coherent choice, never a mix.
+# Shape knobs, frozen at import (jit's cache is not keyed on the
+# environment): the driver's headline shape by default; DLNB_BENCH_*
+# overrides let the sentinel lane (Makefile `check-bench`,
+# tests/test_sentinel.py) run the EXACT bench.py pipeline — headline
+# compile, stat bands, --check — on a tiny CPU-feasible model.  Every
+# consumer imports these constants, so a run's shape is one coherent
+# choice, never a mix.
 BATCH = env_int("DLNB_BENCH_BATCH", 2)
 SEQ = env_int("DLNB_BENCH_SEQ", 6144)
 LAYERS = env_int("DLNB_BENCH_LAYERS", 4)
@@ -69,13 +70,8 @@ def make_train_k(cfg, k: int, lr: float = 1e-3):
     """K optimizer steps chained in one program: every dispatch costs
     host latency that a real training loop, which keeps the device
     queue full, never serializes on; chaining K steps amortises it, so
-    the reading is the DEVICE's.
-
-    With ``cfg.quant_scaling == "delayed"`` the scan carry is
-    ``(params, qstate)`` — the per-layer amax state rides the chain
-    exactly as it would ride a real training loop, which is the point
-    of delayed scaling (the fresh-amax reduction is off the hot path,
-    its replacement data flows step to step).
+    the reading is the DEVICE's.  ``train_k(params, tokens)`` returns
+    ``(params, losses[k])``.
 
     ``lr`` is the SGD step: at the bench's 1e-3 the bf16 weights barely
     move (the headline measures time); a caller that wants to see the
@@ -94,16 +90,6 @@ def make_train_k(cfg, k: int, lr: float = 1e-3):
             return jax.tree.map(lambda a, b: a - lr * b.astype(a.dtype),
                                 p, g)
 
-    if loss_fn is tfm.loss_fn and tfm.needs_qstate(cfg):
-        def train_k(carry, t):
-            def body(carry, _):
-                p, qs = carry
-                (loss, new_qs), g = jax.value_and_grad(
-                    tfm.loss_fn, has_aux=True)(p, t, cfg, qs)
-                return (sgd(p, g), new_qs), loss
-            return jax.lax.scan(body, carry, None, length=k)
-        return train_k
-
     def train_k(p, t):
         def body(p, _):
             loss, g = jax.value_and_grad(loss_fn)(p, t, cfg)
@@ -114,18 +100,14 @@ def make_train_k(cfg, k: int, lr: float = 1e-3):
 
 def build(k: int = 10, *, card=None, batch: int = BATCH, lr: float = 1e-3,
           **cfg_overrides):
-    """(train_k_fn, carry, tokens, card, cfg) at the bench shape, or at
+    """(train_k_fn, params, tokens, card, cfg) at the bench shape, or at
     ``card``'s and ``batch`` where given (``chip_smoke.py`` checks the
-    same step at a small size against a float32 reference); the carry
-    is the params pytree, or ``(params, qstate)`` when the config
-    threads delayed-scaling state (both donate as argument 0)."""
+    same step at a small size against a float32 reference)."""
     from dlnetbench_tpu.models import transformer as tfm
     card = card or bench_card()
     cfg = bench_cfg(card, **cfg_overrides)
-    carry = tfm.init_params(jax.random.key(0), cfg)
-    if tfm.needs_qstate(cfg):
-        carry = (carry, tfm.init_qstate(cfg))
+    params = tfm.init_params(jax.random.key(0), cfg)
     tokens = jax.random.randint(jax.random.key(1),
                                 (batch, cfg.seq_len + 1), 0,
                                 cfg.vocab_size)
-    return make_train_k(cfg, k, lr), carry, tokens, card, cfg
+    return make_train_k(cfg, k, lr), params, tokens, card, cfg

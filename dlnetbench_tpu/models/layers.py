@@ -1,5 +1,5 @@
-"""Building-block layers (pure JAX, no flax): norms, RoPE, attention,
-MLPs, MoE routing (dense, and capacity-based by row gathers).
+"""Building-block layers (pure JAX, no flax): norms, RoPE, MLPs, MoE
+routing (dense, and capacity-based by row gathers).
 
 Conventions: parameters are plain dict pytrees; compute dtype is the
 input's dtype (bfloat16 on TPU) with float32 accumulation where precision
@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 
 from dlnetbench_tpu.metrics.spans import scope
+from dlnetbench_tpu.ops import fp8 as qf8
+from dlnetbench_tpu.ops import int8 as q8
 
 _F32 = jnp.float32
 
@@ -84,40 +86,8 @@ def rope(q, k, positions, theta=10000.0):
     return rot(q), rot(k)
 
 
-def attention(q, k, v, causal: bool, dense_mask=None):
-    """q: [B, S, Hq, Dh], k/v: [B, S, Hkv, Dh] (GQA broadcast).
-    Softmax in fp32.
-
-    ``dense_mask`` (an [S, S] bool, True = attend — built by
-    ops/attention_mask.dense_mask) replaces the causal tril when given:
-    it already encodes the causal half, so the two are never composed.
-    This is the reference path the block-sparse kernels are
-    parity-tested against — it pays the full S x S grid by design."""
-    b, s, hq, dh = q.shape
-    hkv = k.shape[2]
-    group = hq // hkv
-    q = q.reshape(b, s, hkv, group, dh)
-    scores = jnp.einsum("bqhgd,bkhd->bhgqk", q, k,
-                        preferred_element_type=_F32)
-    scores = scores / jnp.sqrt(jnp.asarray(dh, _F32))
-    if dense_mask is not None:
-        mask = jnp.asarray(dense_mask, bool)
-        scores = jnp.where(mask[None, None, None], scores, -jnp.inf)
-    elif causal:
-        mask = jnp.tril(jnp.ones((s, s), bool))
-        scores = jnp.where(mask[None, None, None], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs.astype(v.dtype), v,
-                     preferred_element_type=_F32)
-    return out.reshape(b, s, hq, dh).astype(v.dtype)
-
-
-def swiglu_fwd_res(x, w_gate, w_up, w_down):
-    """The SwiGLU forward, returning (y, residuals): the ONE place the
-    three-dot body lives — the autodiff path (swiglu), the split-dot
-    VJP below, and the Pallas VJP (ops/mlp_backward.py) all call it, so
-    the bf16 rounding discipline cannot silently diverge between the
-    variants that are A/B'd against each other.
+def swiglu(x, w_gate, w_up, w_down):
+    """SwiGLU, backward by autodiff.
 
     Rounds each projection to the compute dtype IMMEDIATELY so the
     saved residuals are bf16, not f32 (the MXU still accumulates in
@@ -129,73 +99,12 @@ def swiglu_fwd_res(x, w_gate, w_up, w_down):
     g = jnp.dot(x, w_gate, preferred_element_type=_F32).astype(x.dtype)
     u = jnp.dot(x, w_up, preferred_element_type=_F32).astype(x.dtype)
     h = (jax.nn.silu(g.astype(_F32)) * u.astype(_F32)).astype(g.dtype)
-    y = jnp.dot(h, w_down, preferred_element_type=_F32).astype(x.dtype)
-    return y, (x, g, u, w_gate, w_up, w_down)
-
-
-def swiglu(x, w_gate, w_up, w_down):
-    return swiglu_fwd_res(x, w_gate, w_up, w_down)[0]
-
-
-@jax.custom_vjp
-def swiglu_split_bwd(x, w_gate, w_up, w_down):
-    """SwiGLU whose BACKWARD is hand-structured: six pure dot_generals
-    with the silu-gradient elementwise pass isolated behind
-    optimization barriers.
-
-    Why: on v5e, XLA's autodiff backward for this block compiles to
-    generic matmul fusions measured at ~0.80 of the bf16 MXU peak
-    (docs/PERF.md r3 budget), while the same-shape PURE dots run at
-    0.99 of peak (r4 experiment).  Keeping the elementwise work out of
-    the matmuls' fusions trades a small explicit HBM round trip of the
-    [T, ff] tensors (~2 ms/layer at bench shape) for matmuls that the
-    compiler schedules at full rate (~9 ms/layer at bench shape).
-    Forward is the same three dots as ``swiglu``; residuals saved are
-    bf16 (x, g, u), matching swiglu's memory discipline.
-    """
-    return swiglu(x, w_gate, w_up, w_down)
-
-
-def _swiglu_split_fwd(x, w_gate, w_up, w_down):
-    return swiglu_fwd_res(x, w_gate, w_up, w_down)
-
-
-def _swiglu_split_bwd(res, dy):
-    x, g, u, w_gate, w_up, w_down = res
-    t_nk = (((1,), (1,)), ((), ()))   # a @ b^T  (contract both dim 1)
-    t_km = (((0,), (0,)), ((), ()))   # a^T @ b  (contract both dim 0)
-    # dh = dy @ Wd^T — a pure dot; the barrier keeps the elementwise
-    # silu-grad block below OUT of its fusion
-    dh = jax.lax.dot_general(dy, w_down, t_nk,
-                             preferred_element_type=_F32)
-    (dh,) = jax.lax.optimization_barrier((dh,))
-    gf = g.astype(_F32)
-    sig = jax.nn.sigmoid(gf)
-    silu = gf * sig
-    dg = (dh * u.astype(_F32) * (sig + silu * (1.0 - sig))).astype(g.dtype)
-    du = (dh * silu).astype(u.dtype)
-    h = (silu * u.astype(_F32)).astype(g.dtype)
-    dg, du, h = jax.lax.optimization_barrier((dg, du, h))
-    dx = (jax.lax.dot_general(dg, w_gate, t_nk,
-                              preferred_element_type=_F32)
-          + jax.lax.dot_general(du, w_up, t_nk,
-                                preferred_element_type=_F32)).astype(x.dtype)
-    dwg = jax.lax.dot_general(x, dg, t_km,
-                              preferred_element_type=_F32)
-    dwu = jax.lax.dot_general(x, du, t_km,
-                              preferred_element_type=_F32)
-    dwd = jax.lax.dot_general(h, dy, t_km,
-                              preferred_element_type=_F32)
-    return (dx, dwg.astype(w_gate.dtype), dwu.astype(w_up.dtype),
-            dwd.astype(w_down.dtype))
-
-
-swiglu_split_bwd.defvjp(_swiglu_split_fwd, _swiglu_split_bwd)
+    return jnp.dot(h, w_down, preferred_element_type=_F32).astype(x.dtype)
 
 
 def quantized_swiglu(x, w_gate, w_up, w_down, *, mlp_dtype: str,
                      quant_fusion: str = "composed",
-                     int8_backward: str = "master", amax_state=None):
+                     int8_backward: str = "master"):
     """The ONE dispatch point for the low-precision SwiGLU recipes
     (transformer._block calls this; TransformerConfig validates the
     combinations):
@@ -205,34 +114,14 @@ def quantized_swiglu(x, w_gate, w_up, w_down, *, mlp_dtype: str,
       swiglu_fp8): quantization as separate amax/rescale passes.
     * ``quant_fusion="fused"`` — the fused-quantization Pallas kernels
       (ops/quantized_matmul.py): scale application inlined into the
-      matmul prologue/epilogue.
-    * ``amax_state`` (a ``[amax_x, amax_h]`` f32 pair, fused only) —
-      delayed scaling: scales come from the PREVIOUS step's amaxes and
-      the return value is ``(y, new_state)`` instead of ``y``.
-
-    Imports are lazy (ops imports this module's sibling namespace)."""
-    if amax_state is not None and quant_fusion != "fused":
-        # mirror TransformerConfig's validation for direct callers: the
-        # carried amax is a fused-kernel side output — a composed call
-        # handing state would otherwise silently get the fused path
-        raise ValueError(
-            "quantized_swiglu: amax_state (delayed scaling) requires "
-            "quant_fusion='fused'")
+      matmul prologue/epilogue."""
     if mlp_dtype == "int8":
-        from dlnetbench_tpu.ops import int8 as q8
-        if amax_state is not None:
-            return q8.swiglu_int8_fused_delayed(x, w_gate, w_up, w_down,
-                                                amax_state)
         if quant_fusion == "fused":
             return q8.swiglu_int8_fused(x, w_gate, w_up, w_down)
         if int8_backward == "switchback":
             return q8.swiglu_int8_sb(x, w_gate, w_up, w_down)
         return q8.swiglu_int8(x, w_gate, w_up, w_down)
     if mlp_dtype == "float8":
-        from dlnetbench_tpu.ops import fp8 as qf8
-        if amax_state is not None:
-            return qf8.swiglu_fp8_fused_delayed(x, w_gate, w_up, w_down,
-                                                amax_state)
         if quant_fusion == "fused":
             return qf8.swiglu_fp8_fused(x, w_gate, w_up, w_down)
         return qf8.swiglu_fp8(x, w_gate, w_up, w_down)

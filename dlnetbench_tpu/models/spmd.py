@@ -411,7 +411,13 @@ def _moe_block(cfg: SpmdConfig, tp: int, y, lp, gids, comm_on=True,
     mb, s_loc, d = y.shape
     t = mb * s_loc
     x2 = y.reshape(t, d)
-    quant = None if cfg.moe_ffn_quant == "none" else cfg.moe_ffn_quant
+    # the shared expert-FFN dispatch point (models/moe.py) with this
+    # config's switches bound: einsum (bit-identical legacy spelling,
+    # incl. the r5 mlp_int8 recipe) or the grouped Pallas kernels
+    ffn = partial(
+        MoE.expert_ffn, impl=cfg.moe_ffn_impl,
+        quant=None if cfg.moe_ffn_quant == "none" else cfg.moe_ffn_quant,
+        mlp_int8=cfg.mlp_int8)
     if compute_on:
         ein, plan, gate = MoE.dispatch(
             x2, lp["w_router"], cfg.num_experts, cfg.top_k,
@@ -430,10 +436,8 @@ def _moe_block(cfg: SpmdConfig, tp: int, y, lp, gids, comm_on=True,
         # blocks already computing, forward and backward
         out = MD.a2a_expert_ffn(
             ein.astype(cfg.jdtype), lp["w_gate"], lp["w_up"],
-            lp["w_down"], AXIS_TP, chunks=cfg.moe_chunks,
-            fake_compute=not compute_on, fake_comm=not comm_on,
-            ffn_impl=cfg.moe_ffn_impl, quant=quant,
-            mlp_int8=cfg.mlp_int8)
+            lp["w_down"], AXIS_TP, ffn, chunks=cfg.moe_chunks,
+            fake_compute=not compute_on, fake_comm=not comm_on)
     else:
         # EP all_to_all: [E, C, d] -> [E/tp, C*tp, d] (each rank gets
         # its experts' tokens from every peer — the hybrid_3d_moe
@@ -447,12 +451,7 @@ def _moe_block(cfg: SpmdConfig, tp: int, y, lp, gids, comm_on=True,
             out = CM.comm_stub(ein.shape, _F32, ein, lp["w_gate"],
                                lp["w_up"], lp["w_down"])
         else:
-            # the shared expert-FFN dispatch point (models/moe.py):
-            # einsum (bit-identical legacy spelling, incl. the r5
-            # mlp_int8 recipe) or the grouped Pallas kernels
-            out = MoE.expert_ffn(ein, lp["w_gate"], lp["w_up"],
-                                 lp["w_down"], impl=cfg.moe_ffn_impl,
-                                 quant=quant, mlp_int8=cfg.mlp_int8)
+            out = ffn(ein, lp["w_gate"], lp["w_up"], lp["w_down"])
         if tp > 1:  # combine A2A (reverse reshard)
             out = (lax.all_to_all(out, AXIS_TP, split_axis=1,
                                   concat_axis=0, tiled=True) if comm_on

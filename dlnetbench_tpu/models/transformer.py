@@ -43,19 +43,6 @@ class TransformerConfig:
     dtype: str = "bfloat16"
     remat: bool = False      # jax.checkpoint each block: recompute activations
                              # in backward instead of storing S x S residuals
-    remat_policy: str = "full"     # "full" (recompute everything) or "dots"
-                             # (keep matmul outputs, recompute elementwise —
-                             # measured ~6% faster than full at S=2048 on v5e
-                             # for a fraction of full-remat's memory saving)
-    remat_scope: str = "block"     # "block" checkpoints the whole decoder
-                             # block; "mlp" checkpoints ONLY the gated MLP —
-                             # the [B, S, ff] g/u pre-activation saves are
-                             # the dominant residuals (roofline
-                             # train_step_bytes), and for mlp_dtype="int8"
-                             # the int32/f32 quantization intermediates
-                             # stay transient in BOTH passes (the r5
-                             # no-remat OOM source), at the price of
-                             # recomputing 3 MLP matmuls per layer
     attention_impl: str = "auto"   # ops.attention dispatch: auto | flash | xla
     attention_window: int = 0      # sliding-window attention: each token
                              # attends its W most recent tokens (itself
@@ -121,24 +108,6 @@ class TransformerConfig:
                              # sa*sb in the epilogue (the r6 attack on
                              # the fp8 chain's 0.56-of-peak and the
                              # int8 step's quantization overhead)
-    quant_scaling: str = "dynamic" # "dynamic" = fresh per-tensor amax
-                             # each call; "delayed" (fused only) = the
-                             # amax is CARRIED from the previous step
-                             # as per-layer state threaded through the
-                             # train step (init_qstate/forward's
-                             # qstate arg; SwitchBack / FP8-recipe
-                             # style), so the fresh-amax HBM reduction
-                             # leaves the hot path — scales lag one
-                             # step and saturate on overflow
-    mlp_backward: str = "fused"    # SwiGLU backward: "fused" = plain
-                             # autodiff (the r4-measured winner);
-                             # "split" = pure dots behind barriers
-                             # (layers.swiglu_split_bwd, 0.9975 paired
-                             # ratio — noise); "pallas" = fused dg/du +
-                             # dWd kernels (ops/mlp_backward.py, 1.012 —
-                             # slower).  All three measured end-to-end
-                             # on v5e; docs/PERF.md r4 records why the
-                             # XLA schedule is already at the wall
 
     def __post_init__(self):
         if self.attention_window < 0 or self.attention_seg_avg < 0:
@@ -146,17 +115,6 @@ class TransformerConfig:
                 f"attention_window={self.attention_window} / "
                 f"attention_seg_avg={self.attention_seg_avg} must be "
                 f">= 0 (0 = off)")
-        if self.remat_policy not in ("full", "dots"):
-            raise ValueError(f"unknown remat_policy {self.remat_policy!r}; "
-                             f"expected 'full' or 'dots'")
-        if self.remat_scope not in ("block", "mlp"):
-            raise ValueError(f"unknown remat_scope {self.remat_scope!r}; "
-                             f"expected 'block' or 'mlp'")
-        if self.remat_scope == "mlp" and (self.num_experts > 1
-                                          or not self.gated):
-            raise ValueError(
-                "remat_scope='mlp' covers the dense gated (SwiGLU) MLP "
-                "path only")
         if self.moe_impl not in ("dense", "sparse", "grouped"):
             raise ValueError(f"unknown moe_impl {self.moe_impl!r}; "
                              f"expected 'dense', 'sparse' or 'grouped'")
@@ -178,35 +136,15 @@ class TransformerConfig:
         if self.quant_fusion not in ("composed", "fused"):
             raise ValueError(f"unknown quant_fusion {self.quant_fusion!r}; "
                              f"expected 'composed' or 'fused'")
-        if self.quant_scaling not in ("dynamic", "delayed"):
-            raise ValueError(
-                f"unknown quant_scaling {self.quant_scaling!r}; "
-                f"expected 'dynamic' or 'delayed'")
         if self.quant_fusion == "fused" and self.mlp_dtype == "bfloat16":
             raise ValueError(
                 "quant_fusion='fused' requires mlp_dtype='float8' or "
                 "'int8' (there is nothing to quantize in bf16)")
-        if self.quant_scaling == "delayed" and self.quant_fusion != "fused":
-            raise ValueError(
-                "quant_scaling='delayed' requires quant_fusion='fused' "
-                "(the carried amax is a fused-kernel side output)")
         if self.quant_fusion == "fused" and self.int8_backward != "master":
             raise ValueError(
                 "quant_fusion='fused' covers the master-dtype "
                 "(straight-through) backward only; SwitchBack's "
                 "quantized dx dots are a composed-path recipe")
-        if self.mlp_backward not in ("split", "fused", "pallas"):
-            raise ValueError(f"unknown mlp_backward {self.mlp_backward!r}; "
-                             f"expected 'split', 'fused' or 'pallas'")
-        if self.mlp_backward != "fused" and (self.num_experts > 1
-                                             or self.mlp_dtype != "bfloat16"
-                                             or not self.gated):
-            # the MoE / fp8 / int8 / gelu branches would win the
-            # dispatch and silently measure the WRONG backward in an A/B
-            raise ValueError(
-                f"mlp_backward={self.mlp_backward!r} covers the dense "
-                f"bf16 SwiGLU path only (MoE, float8/int8 and non-gated "
-                f"MLPs dispatch elsewhere)")
 
     @classmethod
     def from_card(cls, card: ModelCard, *, seq_len: int | None = None,
@@ -249,28 +187,6 @@ class TransformerConfig:
                                    self.attention_seg_avg,
                                    self.attention_seg_seed)
 
-
-
-
-def needs_qstate(cfg: TransformerConfig) -> bool:
-    """True when the step must thread delayed-scaling amax state
-    (``init_qstate`` -> ``forward(..., qstate=...)`` ->
-    ``(out, new_qstate)``)."""
-    return cfg.quant_scaling == "delayed"
-
-
-def init_qstate(cfg: TransformerConfig):
-    """Initial delayed-scaling state: per layer ``[amax_x, amax_h]``
-    (gate/up share the x amax; down uses the h amax), f32.
-
-    Initialized to 1.0 — an order-of-magnitude guess for unit-variance
-    bf16 activations, NOT a calibration: the first step quantizes
-    against it (saturating at the format edge if it is low) and emits
-    the true amaxes, so the state self-corrects after one step (the
-    standard delayed-scaling warm-in; arXiv:2209.05433 §4)."""
-    if not needs_qstate(cfg):
-        raise ValueError("init_qstate: cfg.quant_scaling != 'delayed'")
-    return jnp.ones((cfg.num_layers, 2), jnp.float32)
 
 
 def init_params(key, cfg: TransformerConfig) -> dict:
@@ -329,10 +245,8 @@ def init_params(key, cfg: TransformerConfig) -> dict:
     return params
 
 
-def _block(cfg: TransformerConfig, x, lp, positions, qs_row=None):
+def _block(cfg: TransformerConfig, x, lp, positions):
     """One decoder block; x: [B, S, D], lp: this layer's param slice.
-    ``qs_row`` is this layer's delayed-scaling amax state (delayed
-    quant only) — when given, returns ``(x, new_qs_row)``.
 
     The block wears the step's scopes (``spans.SCOPES``): ``attn`` up to
     its residual add; then ``mlp`` over norm2, the dense MLP and its
@@ -378,55 +292,23 @@ def _block(cfg: TransformerConfig, x, lp, positions, qs_row=None):
     with scope("mlp"):
         if cfg.gated:
             y = L.rmsnorm(x, lp["norm2"])
-            new_qs_row = None
             if cfg.mlp_dtype in ("float8", "int8"):
                 mlp_fn = functools.partial(
                     L.quantized_swiglu, mlp_dtype=cfg.mlp_dtype,
                     quant_fusion=cfg.quant_fusion,
                     int8_backward=cfg.int8_backward)
-                if qs_row is not None:
-                    mlp_fn = functools.partial(mlp_fn, amax_state=qs_row)
-            elif cfg.mlp_backward == "pallas":
-                from dlnetbench_tpu.ops.mlp_backward import \
-                    swiglu_pallas_bwd
-
-                def mlp_fn(y, wg, wu, wd):
-                    return swiglu_pallas_bwd(
-                        y.reshape(b * s, d), wg, wu, wd).reshape(b, s, d)
-            elif cfg.mlp_backward == "split":
-                def mlp_fn(y, wg, wu, wd):
-                    return L.swiglu_split_bwd(
-                        y.reshape(b * s, d), wg, wu, wd).reshape(b, s, d)
             else:
                 mlp_fn = L.swiglu
-            if cfg.remat and cfg.remat_scope == "mlp":
-                # checkpoint ONLY the MLP: recompute the g/u
-                # pre-activations (and, for int8/fp8, the quantization
-                # intermediates) in backward instead of saving them
-                mlp_fn = jax.checkpoint(mlp_fn)
             y2 = mlp_fn(y, lp["w_gate"], lp["w_up"], lp["w_down"])
-            if qs_row is not None:
-                y2, new_qs_row = y2
         else:
             y = L.layernorm(x, lp["norm2"], lp["norm2_b"])
             y2 = L.gelu_mlp(y, lp["w_in"], lp["b_in"], lp["w_out"],
                             lp["b_out"])
-        if qs_row is not None:
-            return x + y2, new_qs_row
         return x + y2
 
 
-def forward(params: dict, tokens, cfg: TransformerConfig, qstate=None):
-    """tokens [B, S] int32 -> logits [B, S, V].
-
-    With ``cfg.quant_scaling == "delayed"``, ``qstate`` (the
-    ``init_qstate``-shaped [L, 2] amax carry) is REQUIRED and the
-    return value is ``(logits, new_qstate)`` — the caller threads the
-    new state into the next step."""
-    delayed = needs_qstate(cfg)
-    if delayed and qstate is None:
-        raise ValueError("cfg.quant_scaling='delayed' requires the "
-                         "qstate carry (models.transformer.init_qstate)")
+def forward(params: dict, tokens, cfg: TransformerConfig):
+    """tokens [B, S] int32 -> logits [B, S, V]."""
     s = tokens.shape[1]
     positions = jnp.arange(s)
     with scope("embed"):
@@ -435,36 +317,18 @@ def forward(params: dict, tokens, cfg: TransformerConfig, qstate=None):
             x = x + params["pos_embed"][positions][None]
 
     block = _block
-    if cfg.remat and cfg.remat_scope == "block":
-        policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-                  if cfg.remat_policy == "dots" else None)
-        block = jax.checkpoint(_block, static_argnums=(0,), policy=policy)
+    if cfg.remat:
+        block = jax.checkpoint(_block, static_argnums=(0,))
 
-    new_qstate = None
     if cfg.scan_layers:
-        if delayed:
-            def body(carry, xs):
-                lp, qs_row = xs
-                return block(cfg, carry, lp, positions, qs_row)
+        def body(carry, lp):
+            return block(cfg, carry, lp, positions), None
 
-            x, new_qstate = jax.lax.scan(body, x,
-                                         (params["layers"], qstate))
-        else:
-            def body(carry, lp):
-                return block(cfg, carry, lp, positions), None
-
-            x, _ = jax.lax.scan(body, x, params["layers"])
+        x, _ = jax.lax.scan(body, x, params["layers"])
     else:
-        new_rows = []
         for li in range(cfg.num_layers):
             lp = jax.tree.map(lambda a: a[li], params["layers"])
-            if delayed:
-                x, row = block(cfg, x, lp, positions, qstate[li])
-                new_rows.append(row)
-            else:
-                x = block(cfg, x, lp, positions)
-        if delayed:
-            new_qstate = jnp.stack(new_rows)
+            x = block(cfg, x, lp, positions)
     with scope("head_loss"):
         if cfg.gated:
             x = L.rmsnorm(x, params["final_norm"])
@@ -472,23 +336,13 @@ def forward(params: dict, tokens, cfg: TransformerConfig, qstate=None):
             x = L.layernorm(x, params["final_norm"],
                             params["final_norm_b"])
         head = params["embed"].T if cfg.tied_embeddings else params["head"]
-        logits = jnp.dot(x, head,
-                         preferred_element_type=(
-                             jnp.float32 if cfg.logits_f32 else x.dtype))
-    if delayed:
-        return logits, new_qstate
-    return logits
+        return jnp.dot(x, head,
+                       preferred_element_type=(
+                           jnp.float32 if cfg.logits_f32 else x.dtype))
 
 
-def loss_fn(params: dict, tokens, cfg: TransformerConfig, qstate=None):
-    """Next-token cross-entropy on a [B, S+1] token batch.  With
-    delayed quantization scaling the return value is
-    ``(loss, new_qstate)`` (``jax.value_and_grad(..., has_aux=True)``
-    shape — the state is an aux output, not part of the loss)."""
-    if needs_qstate(cfg):
-        logits, new_qstate = forward(params, tokens[:, :-1], cfg, qstate)
-        with scope("head_loss"):
-            return L.cross_entropy(logits, tokens[:, 1:]), new_qstate
+def loss_fn(params: dict, tokens, cfg: TransformerConfig):
+    """Next-token cross-entropy on a [B, S+1] token batch."""
     logits = forward(params, tokens[:, :-1], cfg)
     with scope("head_loss"):
         return L.cross_entropy(logits, tokens[:, 1:])

@@ -9,14 +9,13 @@ materialize the S x S score matrix in HBM.
 
 ``attention`` is the dispatcher the model families call: it routes to the
 Pallas kernel when the backend and shapes support it and otherwise falls
-back to the plain-XLA einsum implementation (models/layers.py), which is
-also the numerical reference in tests.
+back to the plain-XLA einsum implementation (ops/xla_attention.py), which
+is also the numerical reference in tests.
 """
 from __future__ import annotations
 
 import jax
 
-from dlnetbench_tpu.models import layers as _L
 from dlnetbench_tpu.ops import attention_mask as _M
 from dlnetbench_tpu.ops.flash_attention import (
     LONG_SEQ,
@@ -24,9 +23,10 @@ from dlnetbench_tpu.ops.flash_attention import (
     flash_supported,
     splash_attention,
 )
+from dlnetbench_tpu.ops.xla_attention import xla_attention
 
 __all__ = ["attention", "flash_attention", "flash_supported",
-           "splash_attention"]
+           "splash_attention", "xla_attention"]
 
 # Measured on a v5e chip (llama3_8b-shaped 4-layer train step, remat on):
 # flash loses ~2% at S=1024 (attention is a sliver of the step and the
@@ -75,9 +75,9 @@ def attention(q, k, v, causal: bool, impl: str = "auto", mask=None,
             mask = None   # the dense-causal default IS this mask
     if impl == "xla":
         if mask is not None:
-            return _L.attention(q, k, v, causal=causal,
+            return xla_attention(q, k, v, causal=causal,
                                 dense_mask=_dense_mask_np(mask, s))
-        return _L.attention(q, k, v, causal=causal)
+        return xla_attention(q, k, v, causal=causal)
     if impl == "flash":
         if mask is not None:
             return splash_attention(q, k, v, mask, **blocks)
@@ -100,6 +100,6 @@ def attention(q, k, v, causal: bool, impl: str = "auto", mask=None,
             f"materialize); use the flash/splash path on TPU or pass "
             f"impl='xla' explicitly")
     if mask is not None:
-        return _L.attention(q, k, v, causal=causal,
+        return xla_attention(q, k, v, causal=causal,
                             dense_mask=_dense_mask_np(mask, s))
-    return _L.attention(q, k, v, causal=causal)
+    return xla_attention(q, k, v, causal=causal)

@@ -30,8 +30,8 @@ attention (ops/sequence_parallel.py) and the serving prefill
   compute leg (ops/sequence_parallel.py).
 
 ``dense_mask`` builds the equivalent boolean S x S mask for the
-CPU-mesh reference path (models/layers.py applies it densely), which is
-what every parity test checks the sparse paths against.
+CPU-mesh reference path (ops/xla_attention.py applies it densely),
+which is what every parity test checks the sparse paths against.
 """
 from __future__ import annotations
 
@@ -40,7 +40,7 @@ import functools
 
 import numpy as np
 
-from dlnetbench_tpu.serving.arrivals import splitmix64
+from dlnetbench_tpu.utils.seeded import splitmix64
 
 # BlockMask verdicts
 SKIP, PARTIAL, FULL = 0, 1, 2

@@ -25,12 +25,11 @@ Design (TPU-first, not a port — the reference has no kernels at all):
 
 On non-TPU backends the same kernels run under ``interpret=True`` so the
 whole path is unit-testable on the CPU mesh (tests/test_flash_attention.py
-checks fwd+grad against the einsum reference in models/layers.py).
+checks fwd+grad against the einsum reference in ops/xla_attention.py).
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -318,101 +317,44 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-# Read ONCE at import time: the override reaches compiled code at trace
-# time, but jax's jit cache is NOT keyed on the environment — a value
-# changed between calls of an already-traced function would silently
-# keep the stale compiled block config (ADVICE r5).  Freezing the knob
-# at import makes the per-process semantics explicit; sweeps vary it by
-# launching a fresh process per value (docs/studies/flash_bwd_blocks_r5
-# already does), and a post-import change raises instead of lying.
-_BWD_BLOCKS_ENV = os.environ.get("DLNB_FLASH_BWD_BLOCKS", "")
-
-
-def _parse_bwd_blocks(env: str, bq: int, bk: int, s: int):
-    """Validate and split one knob string into ((bq_dq, bk_dq),
-    (bq_dkv, bk_dkv)); empty string = default (bq, bk) for both.
-
-    An experiment knob must fail LOUD: a malformed string or a block
-    that does not divide the sequence raises — truncated grids would
-    silently leave dq rows unwritten and drop query contributions from
-    dk/dv while the sweep records a plausible-looking time."""
-    if not env:
-        return (bq, bk), (bq, bk)
-    try:
-        a, b, c, d = (int(x) for x in env.split(","))
-    except ValueError as e:
-        raise ValueError(
-            f"DLNB_FLASH_BWD_BLOCKS={env!r}: expected 4 comma-separated "
-            f"ints (bq_dq,bk_dq,bq_dkv,bk_dkv)") from e
-    for blk in (a, b, c, d):
-        if blk <= 0 or s % blk:
-            raise ValueError(
-                f"DLNB_FLASH_BWD_BLOCKS={env!r}: block {blk} does not "
-                f"divide seq_len {s}")
-    return (a, b), (c, d)
-
-
-def _bwd_blocks_override(bq: int, bk: int, s: int):
-    """Per-kernel backward block shapes, env-overridable for on-chip
-    sweeps (docs/studies/flash_bwd_blocks_r5):
-    ``DLNB_FLASH_BWD_BLOCKS=bq_dq,bk_dq,bq_dkv,bk_dkv`` — captured at
-    IMPORT time (module constant ``_BWD_BLOCKS_ENV``), one value per
-    process.  The dq kernel (minor axis = kv blocks, accumulator
-    [bq, dh]) and the dk/dv kernel (minor axis = q blocks, accumulators
-    2x[bk, dh]) have different live sets, so their optima need not
-    coincide; default: both (bq, bk).
-
-    A value changed AFTER import raises (where a re-trace happens to
-    observe it) rather than silently keeping the stale compiled config
-    through the jit cache — the pre-freeze behavior read the LIVE env
-    at trace time, so an in-process sweep could believe it measured 4
-    configs while timing one.  The error names the frozen -> attempted
-    values so the offending sweep knows exactly which config it tried
-    to smuggle in (tests/test_tuning.py locks both properties).
-    Returns None when the env is unset (the tuning DB may then answer,
-    ``_resolve_bwd_blocks``) — env always wins for reproducibility."""
-    live = os.environ.get("DLNB_FLASH_BWD_BLOCKS", "")
-    if live != _BWD_BLOCKS_ENV:
-        raise ValueError(
-            f"DLNB_FLASH_BWD_BLOCKS changed after import "
-            f"(frozen {_BWD_BLOCKS_ENV!r} -> attempted {live!r}): the "
-            f"knob is captured at import time because jit caching is "
-            f"not keyed on it — set it before importing, or use a "
-            f"fresh process per value")
-    if not _BWD_BLOCKS_ENV:
-        return None
-    return _parse_bwd_blocks(_BWD_BLOCKS_ENV, bq, bk, s)
-
-
 def _validate_blocks(s: int, what: str):
-    """Loud validator for DB-tuned block configs: every block must be a
-    positive divisor of the sequence — a truncated grid would silently
-    drop contributions (same failure mode ``_parse_bwd_blocks`` guards
-    the env knob against)."""
+    """Loud validator for block configs that come from outside the
+    kernel's own defaults (the tuning DB, the tuner's
+    ``override_blocks``): every block must be a positive divisor of the
+    sequence — a truncated grid would silently leave dq rows unwritten
+    and drop query contributions from dk/dv while a sweep records a
+    plausible-looking time."""
     def check(cfg: dict) -> None:
         for name, blk in cfg.items():
             if not isinstance(blk, int) or blk <= 0 or s % blk:
                 raise ValueError(
-                    f"{what}: tuned block {name}={blk!r} does not "
+                    f"{what}: block {name}={blk!r} does not "
                     f"divide seq_len {s}")
     return check
 
 
+def _checked_override(override_blocks, s: int, what: str):
+    """The tuner's ``((bq_dq, bk_dq), (bq_dkv, bk_dkv))``, held to
+    ``_validate_blocks``."""
+    (bq_dq, bk_dq), (bq_dkv, bk_dkv) = override_blocks
+    _validate_blocks(s, what)({"bq_dq": bq_dq, "bk_dq": bk_dq,
+                               "bq_dkv": bq_dkv, "bk_dkv": bk_dkv})
+    return override_blocks
+
+
 def _resolve_bwd_blocks(q, k, causal: bool, bq: int, bk: int,
                         consult_db: bool = True):
-    """Backward per-kernel blocks, in override precedence order: the
-    env knob first (frozen at import, ``_bwd_blocks_override`` — a
-    sweep that sets it must measure ITS blocks whatever anything else
-    says), then — only when the caller passed no explicit blocks
-    (``consult_db``) — the tuning DB (``dlnetbench_tpu/tuning``,
-    frozen after first consult per shape key), then (bq, bk) for both
-    kernels: the caller's explicit blocks, or today's defaults, so an
-    empty DB is bit-identical to the pre-tuning harness and explicit
-    arguments are never silently overlaid by a DB hit."""
+    """Backward per-kernel blocks where the tuner gave no
+    ``override_blocks``: only when the caller passed no explicit blocks
+    (``consult_db``) the tuning DB (``dlnetbench_tpu/tuning``, frozen
+    after first consult per shape key), else (bq, bk) for both kernels:
+    the caller's explicit blocks, or the defaults, so an empty DB is
+    bit-identical to the pre-tuning harness and explicit arguments are
+    never silently overlaid by a DB hit.  The dq kernel (minor axis =
+    kv blocks, accumulator [bq, dh]) and the dk/dv kernel (minor axis =
+    q blocks, accumulators 2x[bk, dh]) have different live sets, so a
+    record holds a pair of blocks for each."""
     b, s, hq, _ = q.shape
-    env = _bwd_blocks_override(bq, bk, s)
-    if env is not None:
-        return env
     if not consult_db:
         return (bq, bk), (bq, bk)
     from dlnetbench_tpu import tuning
@@ -429,7 +371,9 @@ def _bwd_impl(q, k, v, out, lse, do, *, causal: bool,
               block_q: int, block_k: int, override_blocks=None,
               consult_db: bool = True):
     (bq_dq, bk_dq), (bq_dkv, bk_dkv) = (
-        override_blocks if override_blocks is not None
+        _checked_override(override_blocks, q.shape[1],
+                          "flash_attention backward override_blocks")
+        if override_blocks is not None
         else _resolve_bwd_blocks(q, k, causal, block_q, block_k,
                                  consult_db=consult_db))
     b, s, hq, dh = q.shape
@@ -545,7 +489,7 @@ def _from_bsf(x, h: int, dh: int):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_attention(q, k, v, causal: bool = True,
                     block_q: int | None = None, block_k: int | None = None):
-    """Blockwise attention; same contract as models/layers.py::attention.
+    """Blockwise attention; same contract as ops/xla_attention.py.
 
     q: [B, S, Hq, Dh], k/v: [B, S, Hkv, Dh] with Hq % Hkv == 0.
     """
@@ -852,7 +796,9 @@ def _splash_bwd_impl(q, k, v, out, lse, do, spec, *,
                      block_q: int, block_k: int, override_blocks=None,
                      consult_db: bool = True):
     (bq_dq, bk_dq), (bq_dkv, bk_dkv) = (
-        override_blocks if override_blocks is not None
+        _checked_override(override_blocks, q.shape[1],
+                          "splash_attention backward override_blocks")
+        if override_blocks is not None
         else _resolve_splash_bwd_blocks(q, k, spec, block_q, block_k,
                                         consult_db=consult_db))
     b, s, hq, dh = q.shape
@@ -978,14 +924,11 @@ def _splash_bwd_impl(q, k, v, out, lse, do, spec, *,
 def _resolve_splash_bwd_blocks(q, k, spec, bq: int, bk: int,
                                consult_db: bool = True):
     """Splash backward per-kernel blocks, same precedence as the dense
-    path (``_resolve_bwd_blocks``): the frozen env knob first, then —
-    only for all-default calls — the tuning DB under the MASK-labeled
-    ``splash_bwd`` key (sparsity changes the live set, so splash and
-    dense optima are distinct records), then (bq, bk) for both."""
+    path (``_resolve_bwd_blocks``): only for all-default calls the
+    tuning DB under the MASK-labeled ``splash_bwd`` key (sparsity
+    changes the live set, so splash and dense optima are distinct
+    records), else (bq, bk) for both."""
     b, s, hq, _ = q.shape
-    env = _bwd_blocks_override(bq, bk, s)
-    if env is not None:
-        return env
     if not consult_db:
         return (bq, bk), (bq, bk)
     from dlnetbench_tpu import tuning

@@ -98,24 +98,3 @@ def _swiglu_fp8_fused_fwd(x, w_gate, w_up, w_down):
 
 swiglu_fp8_fused.defvjp(_swiglu_fp8_fused_fwd, qmm.swiglu_master_bwd)
 
-
-@jax.custom_vjp
-def swiglu_fp8_fused_delayed(x, w_gate, w_up, w_down, qs):
-    """Delayed-scaling fused-SwiGLU (e4m3): ``qs`` is this layer's
-    carried ``[amax_x, amax_h]`` f32 state from the PREVIOUS step —
-    the scales come from it, so no fresh-amax HBM reduction runs on
-    the hot path; the kernel emits this step's amaxes as the state for
-    the next step (FP8-recipe delayed scaling, arXiv:2209.05433).
-    Returns ``(y, new_qs)``; the state carries no gradient."""
-    (out, new_qs), _ = qmm.swiglu_fused_delayed_fwd_res(
-        x, w_gate, w_up, w_down, qs, "float8")
-    return out, new_qs
-
-
-def _swiglu_fp8_fused_delayed_fwd(x, w_gate, w_up, w_down, qs):
-    return qmm.swiglu_fused_delayed_fwd_res(
-        x, w_gate, w_up, w_down, qs, "float8")
-
-
-swiglu_fp8_fused_delayed.defvjp(_swiglu_fp8_fused_delayed_fwd,
-                                qmm.swiglu_delayed_master_bwd)

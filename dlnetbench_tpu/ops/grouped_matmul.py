@@ -30,7 +30,7 @@ FFN with a straight-through (master-dtype) custom VJP — the same
 backward recipe every quantized path in this repo uses.  The forward
 hands its two projections ``g = x @ w_gate`` and ``u = x @ w_up`` to
 the backward as residuals, in the dtype its kernels wrote them (the
-``layers.swiglu_fwd_res`` discipline), so the backward is the gradient
+``layers.swiglu`` discipline), so the backward is the gradient
 of the function that was evaluated and computes neither again: six
 full-grid einsums, not eight.  Rows past an expert's count need no
 mask there: a skipped block's ``g`` and ``u`` are the kernel's zeros,
@@ -231,7 +231,7 @@ def _ffn_fwd(x, w_gate, w_up, w_down, counts, fmt, blocks):
     """The three grouped dots of the expert SwiGLU -> ``(y, g, u)``:
     the ONE body behind the primal and the VJP's forward, so the
     rounding of what the backward reads cannot drift from what the
-    forward computed (``layers.swiglu_fwd_res``'s discipline: ``g``,
+    forward computed (``layers.swiglu``'s discipline: ``g``,
     ``u`` stay in the kernels' output dtype).  ``blocks`` is the
     (block_c, block_n, block_k) triple (hashable — it rides a
     custom_vjp nondiff argnum)."""

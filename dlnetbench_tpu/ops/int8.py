@@ -171,29 +171,6 @@ swiglu_int8_fused.defvjp(_swiglu_int8_fused_fwd, _swiglu_int8_bwd)
 
 
 @jax.custom_vjp
-def swiglu_int8_fused_delayed(x, w_gate, w_up, w_down, qs):
-    """Delayed-scaling fused-SwiGLU (int8): ``qs`` is this layer's
-    carried ``[amax_x, amax_h]`` f32 state from the PREVIOUS step
-    (SwitchBack-style delayed scaling, arXiv:2304.13013) — no
-    fresh-amax HBM reduction on the hot path; the kernel emits this
-    step's amaxes as next-step state.  A stale scale saturates at
-    +-127 and self-corrects the following step.  Returns
-    ``(y, new_qs)``; the state carries no gradient."""
-    (out, new_qs), _ = qmm.swiglu_fused_delayed_fwd_res(
-        x, w_gate, w_up, w_down, qs, "int8")
-    return out, new_qs
-
-
-def _swiglu_int8_fused_delayed_fwd(x, w_gate, w_up, w_down, qs):
-    return qmm.swiglu_fused_delayed_fwd_res(
-        x, w_gate, w_up, w_down, qs, "int8")
-
-
-swiglu_int8_fused_delayed.defvjp(_swiglu_int8_fused_delayed_fwd,
-                                 qmm.swiglu_delayed_master_bwd)
-
-
-@jax.custom_vjp
 def swiglu_int8_sb(x, w_gate, w_up, w_down):
     """SwiGLU, int8 forward AND int8 activation-gradient (dx-side)
     backward — the SwitchBack recipe (arXiv:2304.13013 pattern: the

@@ -63,25 +63,18 @@ def _hop(x, axis_name: str, offset: int, fake_comm: bool):
     return lax.ppermute(x, axis_name, perm)
 
 
-def _ffn_block(xblk, wg, wu, wd, chunks: int, ffn_impl: str,
-               quant: str | None, mlp_int8: bool, fake: bool):
+def _ffn_block(xblk, wg, wu, wd, ffn, chunks: int, fake: bool):
     """One peer block's expert FFN ([eloc, C, d] -> [eloc, C, d] f32)
-    through the shared dispatch point (models/moe.expert_ffn);
-    ``chunks`` splits the capacity axis so each slice's MXU work can
-    interleave with in-flight permutes at finer grain."""
+    through the caller's ``ffn(x, wg, wu, wd)``; ``chunks`` splits the
+    capacity axis so each slice's MXU work can interleave with
+    in-flight permutes at finer grain."""
     if fake:
         return comm_stub(xblk.shape, _F32, xblk, wg, wu, wd)
-    from dlnetbench_tpu.models.moe import expert_ffn
-
-    def ffn(b):
-        return expert_ffn(b, wg, wu, wd, impl=ffn_impl, quant=quant,
-                          mlp_int8=mlp_int8)
-
     c = xblk.shape[1]
     if chunks <= 1 or c < 2:
-        return ffn(xblk)
+        return ffn(xblk, wg, wu, wd)
     bounds = [round(i * c / chunks) for i in range(chunks + 1)]
-    parts = [ffn(lax.slice_in_dim(xblk, lo, hi, axis=1))
+    parts = [ffn(lax.slice_in_dim(xblk, lo, hi, axis=1), wg, wu, wd)
              for lo, hi in zip(bounds, bounds[1:]) if hi > lo]
     return jnp.concatenate(parts, axis=1)
 
@@ -108,17 +101,16 @@ def _put(buf, val, idx, eloc: int):
     return lax.dynamic_update_slice_in_dim(buf, val, idx * eloc, axis=0)
 
 
-def _impl(ein, wg, wu, wd, axis_name, chunks, fk_compute, fk_comm,
-          ffn_impl, quant, mlp_int8, collect_recv: bool):
+def _impl(ein, wg, wu, wd, axis_name, expert_ffn, chunks, fk_compute,
+          fk_comm, collect_recv: bool):
     """The fused loop.  Returns ``(out, recv)``: ``out`` [E, C, d] f32
     in the monolithic combine layout (block r = rank r's experts'
     results for my tokens), ``recv`` the received dispatch blocks
     keyed by SOURCE rank (saved as the VJP residual when
     ``collect_recv``, else None)."""
     n = _axis_size(axis_name)
-    ffn = partial(_ffn_block, wg=wg, wu=wu, wd=wd, chunks=chunks,
-                  ffn_impl=ffn_impl, quant=quant, mlp_int8=mlp_int8,
-                  fake=fk_compute)
+    ffn = partial(_ffn_block, wg=wg, wu=wu, wd=wd, ffn=expert_ffn,
+                  chunks=chunks, fake=fk_compute)
     if n == 1:
         out = ffn(ein)
         return out, (ein if collect_recv else None)
@@ -150,23 +142,23 @@ def _impl(ein, wg, wu, wd, axis_name, chunks, fk_compute, fk_comm,
     return out, recv
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
-def _a2a_ffn(ein, wg, wu, wd, axis_name, chunks, fk_compute, fk_comm,
-             ffn_impl, quant, mlp_int8):
-    out, _ = _impl(ein, wg, wu, wd, axis_name, chunks, fk_compute,
-                   fk_comm, ffn_impl, quant, mlp_int8, False)
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _a2a_ffn(ein, wg, wu, wd, axis_name, expert_ffn, chunks, fk_compute,
+             fk_comm):
+    out, _ = _impl(ein, wg, wu, wd, axis_name, expert_ffn, chunks,
+                   fk_compute, fk_comm, False)
     return out
 
 
-def _a2a_ffn_fwd(ein, wg, wu, wd, axis_name, chunks, fk_compute,
-                 fk_comm, ffn_impl, quant, mlp_int8):
-    out, recv = _impl(ein, wg, wu, wd, axis_name, chunks, fk_compute,
-                      fk_comm, ffn_impl, quant, mlp_int8, True)
+def _a2a_ffn_fwd(ein, wg, wu, wd, axis_name, expert_ffn, chunks,
+                 fk_compute, fk_comm):
+    out, recv = _impl(ein, wg, wu, wd, axis_name, expert_ffn, chunks,
+                      fk_compute, fk_comm, True)
     return out, (recv, wg, wu, wd)
 
 
-def _a2a_ffn_bwd(axis_name, chunks, fk_compute, fk_comm, ffn_impl,
-                 quant, mlp_int8, res, dout):
+def _a2a_ffn_bwd(axis_name, expert_ffn, chunks, fk_compute, fk_comm, res,
+                 dout):
     """The transposed loop: combine^T carries result cotangents to the
     rank that computed them, the per-block FFN VJP runs as they land
     (forward recomputed from the saved received blocks — MoE remat),
@@ -183,9 +175,8 @@ def _a2a_ffn_bwd(axis_name, chunks, fk_compute, fk_comm, ffn_impl,
             zd = comm_stub(wd.shape, _F32, xblk, dblk)
             return dx, zg, zu, zd
         _, pull = jax.vjp(
-            lambda b, a, u_, d_: _ffn_block(b, a, u_, d_, chunks,
-                                            ffn_impl, quant, mlp_int8,
-                                            False),
+            lambda b, a, u_, d_: _ffn_block(b, a, u_, d_, expert_ffn,
+                                            chunks, False),
             xblk, wg, wu, wd)
         return pull(dblk.astype(_F32))
 
@@ -221,10 +212,9 @@ def _a2a_ffn_bwd(axis_name, chunks, fk_compute, fk_comm, ffn_impl,
 _a2a_ffn.defvjp(_a2a_ffn_fwd, _a2a_ffn_bwd)
 
 
-def a2a_expert_ffn(ein, w_gate, w_up, w_down, axis_name: str, *,
-                   chunks: int = 1, fake_compute: bool = False,
-                   fake_comm: bool = False, ffn_impl: str = "einsum",
-                   quant: str | None = None, mlp_int8: bool = False):
+def a2a_expert_ffn(ein, w_gate, w_up, w_down, axis_name: str,
+                   expert_ffn, *, chunks: int = 1,
+                   fake_compute: bool = False, fake_comm: bool = False):
     """``combine_a2a(expert_ffn(dispatch_a2a(ein)))`` as ONE fused
     ppermute chunk loop (call inside ``shard_map`` over ``axis_name``).
 
@@ -232,11 +222,12 @@ def a2a_expert_ffn(ein, w_gate, w_up, w_down, axis_name: str, *,
     the GLOBAL expert set; experts are sharded over the axis (E must
     divide by its size) and the local expert weights are [E/n, ...].
     Returns the combined [E, C, d] f32 buffer in the monolithic
-    layout.  Backward overlaps too (custom VJP).  ``ffn_impl`` /
-    ``quant`` / ``mlp_int8`` follow ``models/moe.expert_ffn``."""
+    layout.  Backward overlaps too (custom VJP).  ``expert_ffn(x, wg,
+    wu, wd)`` is the caller's expert FFN on one peer block ([E/n, c, d]
+    -> [E/n, c, d] f32), a hashable callable: the model's dispatch
+    point with its switches bound (models/spmd._moe_block)."""
     if w_gate.ndim != 3:
         raise ValueError(f"a2a_expert_ffn: expert weights must be "
                          f"[E_local, d, h], got {w_gate.shape}")
-    return _a2a_ffn(ein, w_gate, w_up, w_down, axis_name, int(chunks),
-                    bool(fake_compute), bool(fake_comm), str(ffn_impl),
-                    quant, bool(mlp_int8))
+    return _a2a_ffn(ein, w_gate, w_up, w_down, axis_name, expert_ffn,
+                    int(chunks), bool(fake_compute), bool(fake_comm))

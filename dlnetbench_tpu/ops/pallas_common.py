@@ -1,8 +1,7 @@
 """Shared shims for every Pallas TPU kernel in ops/.
 
 Before this module existed, ``_interpret()``, ``_compiler_params`` and
-the fp32 constant were copy-pasted per kernel file (``mlp_backward.py``,
-``flash_attention.py``); a fix to any of them (e.g. the interpret-mode
+the fp32 constant were copy-pasted per kernel file; a fix to any of them (e.g. the interpret-mode
 gate growing a force-override for debugging) had to be applied N times.
 Everything here is the single definition the kernel files import.
 

@@ -29,19 +29,10 @@ Weights are pre-quantized ONCE per step by the caller
 (``quantize_tensor`` — a [K, N] pass, small next to the [T, K]
 activation traffic the fusion removes).
 
-Scaling recipes, selected by the wrapper:
-
-* **dynamic (fresh)** — ``*_dot_fused``: the scale comes from a fresh
-  amax of the CURRENT activation.  One XLA reduction pass over x
-  remains, but the separate quantize-write + quantized-read passes of
-  the composed path are gone.
-* **delayed** — ``*_dot_fused_delayed``: the scale is derived from an
-  amax CARRIED from the previous step (SwitchBack / FP8-recipe style,
-  threaded through the train step as state), and the kernel emits the
-  fresh amax as a per-tile side output reduced by the wrapper — the
-  fresh-amax HBM reduction leaves the hot path entirely.  Stale-scale
-  overflow is handled the standard way: values are clamped to the
-  format's range (saturation), and the state self-corrects next step.
+The scale is dynamic (``*_dot_fused``): it comes from a fresh amax of
+the CURRENT activation.  One XLA reduction pass over x remains, but the
+separate quantize-write + quantized-read passes of the composed path
+are gone.
 
 All kernels run under ``interpret=True`` off-TPU (pallas_common), so
 the CPU-mesh tier-1 lane unit-tests them (tests/test_quantized_matmul).
@@ -83,10 +74,9 @@ def scale_from_amax(amax, fmt: str):
 
 def _cast_q(scaled, fmt: str):
     """Scaled master-dtype values -> quantized dtype, saturating at the
-    format's range (delayed scaling can hand a stale, too-small scale;
-    clamping is the standard recipe).  For a fresh scale the clamp is
-    the identity, which is what keeps the fused int8 result EXACTLY
-    equal to the composed one."""
+    format's range.  For a fresh scale the clamp is the identity, which
+    is what keeps the fused int8 result EXACTLY equal to the composed
+    one."""
     qdtype, qmax, _ = _FORMATS[fmt]
     if fmt == "int8":
         return jnp.clip(jnp.round(scaled), -qmax, qmax).astype(qdtype)
@@ -105,18 +95,10 @@ def quantize_tensor(x, fmt: str):
 
 # ------------------------------------------------------------- kernel
 
-def _fused_matmul_kernel(x_ref, wq_ref, sx_ref, sw_ref, *refs,
-                         fmt: str, collect_amax: bool):
+def _fused_matmul_kernel(x_ref, wq_ref, sx_ref, sw_ref, out_ref, acc_ref,
+                         *, fmt: str):
     """Grid (i, j, k) = (row blocks, col blocks, contraction blocks);
-    k is the minor accumulation axis.  The amax side output (delayed
-    scaling) is written on EVERY visit of its (i, k) block — the value
-    is identical for every j, and an unwritten revisit would flush
-    stale VMEM over a good value (Pallas re-emits the buffer whenever
-    the output block index changes)."""
-    if collect_amax:
-        out_ref, amax_ref, acc_ref = refs
-    else:
-        out_ref, acc_ref = refs
+    k is the minor accumulation axis."""
     k = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -134,9 +116,6 @@ def _fused_matmul_kernel(x_ref, wq_ref, sx_ref, sw_ref, *refs,
         xq, wq_ref[...], (((1,), (0,)), ((), ())),
         preferred_element_type=acc_dtype)
 
-    if collect_amax:
-        amax_ref[...] = jnp.full(amax_ref.shape, jnp.max(jnp.abs(xf)), F32)
-
     @pl.when(k == nk - 1)
     def _emit():
         # epilogue: sa*sb applied in-register to the accumulator tile
@@ -148,9 +127,6 @@ def _fused_matmul_kernel(x_ref, wq_ref, sx_ref, sw_ref, *refs,
 # call without explicit blocks and without a tuning-DB hit runs on —
 # locked bit-identical by tests/test_tuning.py
 DEFAULT_BLOCKS = {"block_m": 1024, "block_n": 2048, "block_k": 2048}
-
-# block of the delayed-scaling amax side output: one f32 vreg tile
-_AMAX_TILE = (8, 128)
 
 
 def _tuned_blocks(t: int, kdim: int, n: int, fmt: str, xdtype) -> dict:
@@ -176,15 +152,12 @@ def _tuned_blocks(t: int, kdim: int, n: int, fmt: str, xdtype) -> dict:
 
 
 def fused_matmul(x, wq, sw, sx, *, fmt: str, out_dtype=None,
-                 collect_amax: bool = False, block_m: int | None = None,
+                 block_m: int | None = None,
                  block_n: int | None = None, block_k: int | None = None):
     """[..., K] master-dtype x  @  [K, N] pre-quantized w  ->  [..., N].
 
-    ``sx`` is the PROVIDED activation scale (fresh or carried), ``sw``
-    the weight scale from ``quantize_tensor``.  With ``collect_amax``
-    the fresh amax of x rides out as a per-(row, contraction)-tile side
-    output, reduced here to one scalar — the delayed-scaling state for
-    the next step.  Returns ``y`` or ``(y, amax)``.
+    ``sx`` is the PROVIDED activation scale, ``sw`` the weight scale
+    from ``quantize_tensor``.
 
     Grid blocks: explicit arguments win; with none given the tuning DB
     is consulted per (shape, dtype, chip) key and an empty DB keeps the
@@ -214,24 +187,8 @@ def fused_matmul(x, wq, sw, sx, *, fmt: str, out_dtype=None,
     grid = (t // bm, n // bn, kdim // bk)
 
     out_dtype = out_dtype or x.dtype
-    out_shape = [jax.ShapeDtypeStruct((t, n), out_dtype)]
-    out_specs = [pl.BlockSpec((bm, bn), lambda i, j, k: (i, j),
-                              memory_space=pltpu.VMEM)]
-    if collect_amax:
-        # one (8, 128) f32 tile per (i, k) block, every element the
-        # tile's amax: the smallest output block the TPU lowering takes
-        out_shape.append(jax.ShapeDtypeStruct(
-            (grid[0] * _AMAX_TILE[0], grid[2] * _AMAX_TILE[1]), F32))
-        out_specs.append(pl.BlockSpec(_AMAX_TILE, lambda i, j, k: (i, k),
-                                      memory_space=pltpu.VMEM))
-    # the amax side output's (i, k) block is revisited along j, so j
-    # must stay sequential when it is emitted; without it the kernel
-    # keeps the dwd-style (parallel, parallel, arbitrary) semantics
-    sem = (("parallel", "arbitrary", "arbitrary") if collect_amax
-           else ("parallel", "parallel", "arbitrary"))
-    res = pl.pallas_call(
-        functools.partial(_fused_matmul_kernel, fmt=fmt,
-                          collect_amax=collect_amax),
+    (y,) = pl.pallas_call(
+        functools.partial(_fused_matmul_kernel, fmt=fmt),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k),
@@ -243,18 +200,16 @@ def fused_matmul(x, wq, sw, sx, *, fmt: str, out_dtype=None,
             pl.BlockSpec((1, 1), lambda i, j, k: (0, 0),
                          memory_space=pltpu.SMEM),
         ],
-        out_specs=out_specs,
-        out_shape=out_shape,
+        out_specs=[pl.BlockSpec((bm, bn), lambda i, j, k: (i, j),
+                                memory_space=pltpu.VMEM)],
+        out_shape=[jax.ShapeDtypeStruct((t, n), out_dtype)],
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-        compiler_params=compiler_params(sem),
+        compiler_params=compiler_params(
+            ("parallel", "parallel", "arbitrary")),
         interpret=pallas_common.interpret_mode(),
     )(x2, wq,
       jnp.asarray(sx, F32).reshape(1, 1),
       jnp.asarray(sw, F32).reshape(1, 1))
-    if collect_amax:
-        y, amax_tiles = res
-        return y.reshape(*lead, n), jnp.max(amax_tiles)
-    (y,) = res
     return y.reshape(*lead, n)
 
 
@@ -268,19 +223,6 @@ def fused_dot(x, w, fmt: str):
     sx = scale_from_amax(jnp.max(jnp.abs(x.astype(F32))), fmt)
     wq, sw = quantize_tensor(w, fmt)
     return fused_matmul(x, wq, sw, sx, fmt=fmt)
-
-
-def fused_dot_delayed(x, w, fmt: str, amax_in, *,
-                      collect_amax: bool = True):
-    """Delayed-scaling fused dot: the activation scale comes from
-    ``amax_in`` (carried state from the previous step) — NO reduction
-    over x on the hot path.  Returns ``(y, amax_out)`` when
-    ``collect_amax`` (the state for the next step), else ``y`` (a
-    second consumer of the same activation, e.g. the up projection,
-    reuses the sibling's collected amax)."""
-    sx = scale_from_amax(amax_in, fmt)
-    wq, sw = quantize_tensor(w, fmt)
-    return fused_matmul(x, wq, sw, sx, fmt=fmt, collect_amax=collect_amax)
 
 
 # ------------------------------------------- differentiable wrappers
@@ -312,19 +254,6 @@ def swiglu_fused_fwd_res(x, w_gate, w_up, w_down, fmt: str):
     h = (jax.nn.silu(g.astype(F32)) * u.astype(F32)).astype(g.dtype)
     out = fused_dot(h, w_down, fmt)
     return out, (x, g, u, w_gate, w_up, w_down)
-
-
-def swiglu_fused_delayed_fwd_res(x, w_gate, w_up, w_down, qs, fmt: str):
-    """Delayed-scaling fused-SwiGLU forward: ``qs`` is this layer's
-    carried ``[amax_x, amax_h]`` state; gate and up share the x scale
-    (one collected amax), down uses the h scale.  Returns
-    ((y, new_qs), residuals) — same residual contract as above."""
-    g, amax_x = fused_dot_delayed(x, w_gate, fmt, qs[0])
-    u = fused_dot_delayed(x, w_up, fmt, qs[0], collect_amax=False)
-    h = (jax.nn.silu(g.astype(F32)) * u.astype(F32)).astype(g.dtype)
-    out, amax_h = fused_dot_delayed(h, w_down, fmt, qs[1])
-    new_qs = jnp.stack([amax_x, amax_h])
-    return (out, new_qs), (x, g, u, w_gate, w_up, w_down)
 
 
 def swiglu_bwd_impl(res, dy, act_dot):
@@ -366,14 +295,6 @@ def swiglu_master_bwd(res, dy):
     return swiglu_bwd_impl(res, dy, jnp.matmul)
 
 
-def swiglu_delayed_master_bwd(res, cots):
-    """``swiglu_master_bwd`` for the delayed-scaling swiglus: the
-    second cotangent (the emitted amax state) is dropped and the
-    carried ``[amax_x, amax_h]`` input gets a zero gradient."""
-    dy, _d_qs = cots
-    return (*swiglu_bwd_impl(res, dy, jnp.matmul), jnp.zeros((2,), F32))
-
-
 @jax.custom_vjp
 def int8_dot_fused(x, w):
     """[..., K] x [K, N] -> [..., N]: the fused-kernel sibling of
@@ -404,39 +325,3 @@ def _fp8_dot_fused_fwd(x, w):
 
 
 fp8_dot_fused.defvjp(_fp8_dot_fused_fwd, straight_through_dot_bwd)
-
-
-def _dot_delayed_fwd(x, w, amax_in, fmt):
-    y, amax_out = fused_dot_delayed(x, w, fmt, amax_in)
-    return (y, amax_out), (x, w)
-
-
-def _dot_delayed_bwd(res, cots):
-    dy, _d_amax = cots      # the carried amax is state, not a weight
-    dx, dw = straight_through_dot_bwd(res, dy)
-    return dx, dw, jnp.zeros((), F32)
-
-
-@jax.custom_vjp
-def int8_dot_fused_delayed(x, w, amax_in):
-    """Delayed-scaling int8 dot: ``(y, amax_out)`` with the activation
-    scale taken from ``amax_in`` (previous step's state) and the fresh
-    amax emitted by the kernel for the next step.  Backward is
-    straight-through; the state carries no gradient."""
-    y, amax_out = fused_dot_delayed(x, w, "int8", amax_in)
-    return y, amax_out
-
-
-int8_dot_fused_delayed.defvjp(
-    functools.partial(_dot_delayed_fwd, fmt="int8"), _dot_delayed_bwd)
-
-
-@jax.custom_vjp
-def fp8_dot_fused_delayed(x, w, amax_in):
-    """Delayed-scaling e4m3 dot; see ``int8_dot_fused_delayed``."""
-    y, amax_out = fused_dot_delayed(x, w, "float8", amax_in)
-    return y, amax_out
-
-
-fp8_dot_fused_delayed.defvjp(
-    functools.partial(_dot_delayed_fwd, fmt="float8"), _dot_delayed_bwd)

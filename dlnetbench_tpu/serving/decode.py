@@ -49,6 +49,7 @@ from dlnetbench_tpu.serving.kv_cache import (CacheConfig,
                                              paged_attention_decode,
                                              quant_write_span,
                                              sharded_paged_attention)
+from dlnetbench_tpu.utils.seeded import Rng
 
 _F32 = jnp.float32
 
@@ -711,8 +712,7 @@ def prompt_tokens(rid: int, prompt_len: int, vocab_size: int):
     prompt length, a hidden multi-hundred-ms admission stall."""
     import numpy as np
 
-    from dlnetbench_tpu.serving.arrivals import _Rng
-    rng = _Rng((rid + 1) * 0x9E3779B9)
+    rng = Rng((rid + 1) * 0x9E3779B9)
     return np.fromiter((rng.uniform_int(0, vocab_size - 1)
                         for _ in range(prompt_len)),
                        dtype=np.int32, count=prompt_len)
@@ -728,11 +728,10 @@ def prompt_tokens_for(req, vocab_size: int):
     Without a prefix this is exactly ``prompt_tokens``."""
     import numpy as np
 
-    from dlnetbench_tpu.serving.arrivals import _Rng
     if getattr(req, "prefix_id", -1) < 0 or req.prefix_len <= 0:
         return prompt_tokens(req.rid, req.prompt_len, vocab_size)
     n_pre = min(req.prefix_len, req.prompt_len)
-    rng = _Rng((req.prefix_id + 1) * 0xC2B2AE3D)
+    rng = Rng((req.prefix_id + 1) * 0xC2B2AE3D)
     pre = np.fromiter((rng.uniform_int(0, vocab_size - 1)
                        for _ in range(n_pre)),
                       dtype=np.int32, count=n_pre)

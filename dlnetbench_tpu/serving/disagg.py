@@ -14,7 +14,7 @@ device subsets of the same harness world:
   existing ``_prefill_one`` stamp);
 * ranks ``[prefill_ranks, world)`` — a decode replica that receives
   finished sequences over the page-migration channel
-  (``ops/page_migration.py``: pages + scales contiguous in their
+  (``serving/page_migration.py``: pages + scales contiguous in their
   STORED int8/fp8 dtype, chunk-loop transfers) and decodes them to
   completion.
 
@@ -56,10 +56,10 @@ import jax
 from dlnetbench_tpu.metrics import spans
 from dlnetbench_tpu.models.transformer import (TransformerConfig,
                                                init_params)
-from dlnetbench_tpu.ops.page_migration import MigrationChannel
 from dlnetbench_tpu.serving import metrics as M
 from dlnetbench_tpu.serving import requeue
 from dlnetbench_tpu.serving.arrivals import ArrivalPlan, Request
+from dlnetbench_tpu.serving.page_migration import MigrationChannel
 from dlnetbench_tpu.serving.scheduler import Engine, ServingConfig
 
 

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from dlnetbench_tpu.models import layers as L
+from dlnetbench_tpu.utils.seeded import Rng
 
 _F32 = jnp.float32
 
@@ -45,8 +46,7 @@ def skew_bias(num_experts: int, skew: float, seed: int):
         return None
     import numpy as np
 
-    from dlnetbench_tpu.serving.arrivals import _Rng
-    rng = _Rng((seed + 1) * 0xA24BAED4)
+    rng = Rng((seed + 1) * 0xA24BAED4)
     draws = np.array([rng.u01() for _ in range(num_experts)],
                      dtype=np.float32)
     return jnp.asarray(float(skew) * draws)

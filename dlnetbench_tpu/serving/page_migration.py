@@ -44,6 +44,7 @@ import numpy as np
 
 from dlnetbench_tpu.metrics import stats
 from dlnetbench_tpu.serving.kv_cache import CacheConfig
+from dlnetbench_tpu.serving.metrics import percentile
 
 
 def bf16_equiv_page_bytes(cfg: CacheConfig) -> int:
@@ -237,7 +238,6 @@ class MigrationChannel:
         pages = sum(r.pages for r in self._sends)
         walls = [r.wall_ms for r in self._sends]
         ov = self.overlap()
-        from dlnetbench_tpu.serving.metrics import percentile
         return {
             "sends": len(self._sends),
             "pages": pages,

@@ -13,7 +13,7 @@ Policies (``ROUTING_POLICIES``):
                     index order.  No RNG draws, no load signal.
   p2c             — power-of-two-choices: draw TWO distinct active
                     replicas from the router's splitmix64 stream
-                    (serving/arrivals._Rng — the same generator every
+                    (utils/seeded.Rng — the same generator every
                     committed plan uses), route to the one with the
                     lower live load score, first draw wins ties.  The
                     classic balanced-allocations result: max load drops
@@ -44,7 +44,7 @@ evolves identically run over run.
 """
 from __future__ import annotations
 
-from dlnetbench_tpu.serving.arrivals import _Rng
+from dlnetbench_tpu.utils.seeded import Rng
 
 ROUTING_POLICIES = ("round_robin", "p2c", "prefix_affinity")
 
@@ -76,7 +76,7 @@ class Router:
         The fleet warmup drives synthetic requests through the SAME
         router; the measured run must start from the seeded origin or
         the warmup count would shift every measured draw."""
-        self._rng = _Rng(self.seed)
+        self._rng = Rng(self.seed)
         self._rr_next = 0
         self.assignments: list[tuple[int, int]] = []   # (rid, replica)
         self.counts = [0] * self.num_replicas

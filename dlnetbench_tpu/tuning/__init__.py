@@ -13,9 +13,10 @@ Three layers:
   winner committed WITH its measured band.
 * ``params`` — ``consult``: what the tunable sites call.  Disabled-by-
   default (env unset -> caller defaults, bit-identical untuned
-  behavior), frozen after first consult per key (the jit-cache hazard
-  that froze ``DLNB_FLASH_BWD_BLOCKS``), explicit/env values always
-  win, every consult logged for record provenance
+  behavior), frozen after first consult per key (jit's cache is not
+  keyed on the DB, so a later edit would time a stale config),
+  explicit values always win, every consult logged for record
+  provenance
   (``metrics/emit`` stamps ``global.tuning``).
 
 Tunable sites wired (each falls back to today's exact default on a

@@ -7,17 +7,15 @@ Contract (ISSUE 9):
   untuned behavior is bit-identical to the pre-tuning harness, which is
   what lets the tier-1 suite lock today's defaults as the contract.
 * **Frozen after first consult.**  jax's jit cache is not keyed on this
-  DB (the same ADVICE-r5 hazard that froze ``DLNB_FLASH_BWD_BLOCKS`` at
-  import): a DB edit between traces of an already-compiled function
-  would silently time a stale block config.  So the FIRST consult of a
+  DB: a DB edit between traces of an already-compiled function would
+  silently time a stale block config.  So the FIRST consult of a
   ``(op, key, hw)`` is cached for the process lifetime; later consults
   — including retraces — see the same answer even if the file changed.
-  Sweeping tuned values means a fresh process per DB state, exactly
-  like the env-knob discipline.
+  Sweeping tuned values means a fresh process per DB state.
 * **Explicit values always win.**  Sites only consult when the caller
   passed no explicit value (``block_q=None``, ``tp_overlap_chunks=None``,
-  ...); an explicit argument or env override (``DLNB_FLASH_BWD_BLOCKS``)
-  bypasses the DB entirely, for reproducibility.
+  ...); an explicit argument bypasses the DB entirely, for
+  reproducibility.
 * **Every consult is logged** (hit or miss) into a process-global map
   that ``metrics/emit`` stamps into ``global.tuning`` — a record always
   says which configs it ran under, which came from the DB, and with
@@ -77,8 +75,7 @@ def consult(op: str, key: str, default: dict, validate=None) -> dict:
     default (unknown DB keys ride along, missing ones keep their
     default).  ``validate(config)`` — if given — runs on HIT configs
     and must raise ``ValueError`` on an inapplicable one (wrong divisor
-    for this shape, ...): a tuned experiment knob fails loud, exactly
-    like ``DLNB_FLASH_BWD_BLOCKS``."""
+    for this shape, ...): a tuned experiment knob fails loud."""
     if not enabled():
         return dict(default)
     hw = hw_key()

@@ -7,7 +7,7 @@ harness's backends is one draw from a noisy distribution, not a result.
 Discipline:
 
 * **Seeded order.**  Candidates are visited in a splitmix64-shuffled
-  order (``serving/arrivals.splitmix64`` — the SAME generator the fault
+  order (``utils/seeded.splitmix64`` — the SAME generator the fault
   and arrival plans use, golden-value-matched to the native tier), so a
   search is replayable from ``(candidates, seed)`` alone and two
   processes given the same seed measure in the same order.
@@ -32,14 +32,14 @@ Discipline:
 from __future__ import annotations
 
 from dlnetbench_tpu.metrics import stats as stats_mod
-from dlnetbench_tpu.serving.arrivals import _Rng
+from dlnetbench_tpu.utils.seeded import Rng
 from dlnetbench_tpu.tuning.db import TuningDB
 
 
 def seeded_order(n: int, seed: int) -> list[int]:
     """Fisher–Yates over ``range(n)`` driven by the shared splitmix64
     stream — deterministic per seed, identical across tiers."""
-    rng = _Rng(seed)
+    rng = Rng(seed)
     order = list(range(n))
     for i in range(n - 1, 0, -1):
         j = rng.uniform_int(0, i)

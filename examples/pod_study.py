@@ -1,8 +1,8 @@
 #!/usr/bin/env python3
 """North-star pod study — every proxy workload on llama3_70b + mixtral,
 one command producing the effective-bandwidth table and the three plot
-families (SURVEY.md §7.2 step 7; reference BASELINE.md's "effective bus
-GB/s + iter time per collective").
+families (SURVEY.md §7.2 step 7: effective bus GB/s and iteration time
+per collective).
 
 The reference runs this as a SLURM grid (sbatchman) over
 dp/fsdp/hybrid_3d/hybrid_3d_moe and parses the job outputs back into

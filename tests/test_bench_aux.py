@@ -87,11 +87,8 @@ def test_ab_line_schema_locked():
     summaries = {
         "composed": {"value": 2.0, "best": 1.9, "band": [1.9, 2.2], "n": 3},
         "fused": {"value": 1.0, "best": 0.9, "band": [0.9, 1.2], "n": 3},
-        "fused_delayed": {"value": 0.8, "best": 0.7, "band": [0.7, 0.9],
-                          "n": 3},
     }
-    rounds = {"composed": [2.0, 1.9, 2.2], "fused": [1.0, 0.9, 1.2],
-              "fused_delayed": [0.8, 0.7, 0.9]}
+    rounds = {"composed": [2.0, 1.9, 2.2], "fused": [1.0, 0.9, 1.2]}
     line = bench._ab_line("int8 fused-quant A/B (test)", summaries,
                           rounds, flops_per_iter=10 ** 12,
                           roofline_s=0.5)
@@ -112,7 +109,6 @@ def test_ab_line_schema_locked():
     for key in ("value", "best", "band", "n"):
         assert key in r, key
     assert r["value"] == 0.5 and r["n"] == 3
-    assert "ratio_fused_delayed_vs_composed" in line
     assert "ratio_composed_vs_composed" not in line
     # roofline ratio rides along (and the above-peak guard applies)
     assert line["vs_baseline"] == 0.5

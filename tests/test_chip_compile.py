@@ -117,32 +117,30 @@ def test_splash_forward_backward(for_chip, mask):
     assert kernels_in(text) == 3
 
 
-def test_mlp_backward_dgdu(for_chip):
-    mb = ops_module("mlp_backward")
-    text = for_chip(mb.dgdu, ((TOKENS, D), BF16), ((F, D), BF16),
-                    ((TOKENS, F), BF16), ((TOKENS, F), BF16))
-    assert kernels_in(text) == 1
-
-
-def test_mlp_backward_dwd(for_chip):
-    mb = ops_module("mlp_backward")
-    text = for_chip(mb.dwd, ((TOKENS, F), BF16), ((TOKENS, F), BF16),
-                    ((TOKENS, D), BF16))
-    assert kernels_in(text) == 1
-
-
-@pytest.mark.parametrize("collect_amax", [False, True],
-                         ids=["plain", "collect_amax"])
 @pytest.mark.parametrize("fmt", ["int8", "float8"])
-def test_fused_matmul(for_chip, fmt, collect_amax):
-    """With ``collect_amax`` (delayed scaling) the amax side output was
-    a (1, 1) SMEM block the lowering refused."""
+def test_fused_matmul(for_chip, fmt):
     qm = ops_module("quantized_matmul")
     text = for_chip(
-        lambda x, w, sw, sx: qm.fused_matmul(x, w, sw, sx, fmt=fmt,
-                                             collect_amax=collect_amax),
+        lambda x, w, sw, sx: qm.fused_matmul(x, w, sw, sx, fmt=fmt),
         ((TOKENS, D), BF16), ((D, F), QDTYPE[fmt]), ((), F32), ((), F32))
     assert kernels_in(text) == 1
+
+
+@pytest.mark.parametrize("fmt", ["int8", "float8"])
+def test_fused_swiglu_forward_backward(for_chip, fmt):
+    """The fused-quantization SwiGLU as the train step runs it
+    (``quant_fusion="fused"``): gate, up and down through the kernel in
+    the forward, the backward in the master dtype with no kernel."""
+    from dlnetbench_tpu.models import layers
+
+    def loss(x, wg, wu, wd):
+        y = layers.quantized_swiglu(x, wg, wu, wd, mlp_dtype=fmt,
+                                    quant_fusion="fused")
+        return jnp.sum(jnp.square(y.astype(F32)))   # dy reads y
+    text = for_chip(jax.grad(loss, argnums=(0, 1, 2, 3)),
+                    ((TOKENS, D), BF16), ((D, F), BF16), ((D, F), BF16),
+                    ((F, D), BF16))
+    assert kernels_in(text) == 3
 
 
 # the serving page layout: 32 slots, 2048 pages of 16 tokens, 128 pages

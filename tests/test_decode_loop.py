@@ -223,7 +223,7 @@ def test_device_state_roundtrip_property():
     pull round-trips device_state <-> host view losslessly: the host
     mirrors after a final pull equal a pure-host reference model that
     applied the same operations."""
-    from dlnetbench_tpu.serving.arrivals import _Rng
+    from dlnetbench_tpu.utils.seeded import Rng
     slots, pmax, vocab = 4, 6, 32
     ds = DeviceDecodeState(slots, pmax, vocab=vocab)
     ref = {"state": np.zeros((D.STATE_ROWS, slots), np.int32),
@@ -257,7 +257,7 @@ def test_device_state_roundtrip_property():
                 st[D.STATE_POS, s] += 1
                 st[D.STATE_REM, s] -= 1
 
-    rng = _Rng(123)
+    rng = Rng(123)
     for _ in range(120):
         op = rng.uniform_int(0, 3)
         if op == 0:                       # admit a slot

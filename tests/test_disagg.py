@@ -21,10 +21,10 @@ import pytest
 
 from dlnetbench_tpu.metrics import telemetry
 from dlnetbench_tpu.models import transformer as tfm
-from dlnetbench_tpu.ops.page_migration import (MigrationChannel,
-                                               bf16_equiv_page_bytes)
 from dlnetbench_tpu.serving.arrivals import ArrivalPlan, Request
 from dlnetbench_tpu.serving.kv_cache import CacheConfig, device_buffers
+from dlnetbench_tpu.serving.page_migration import (MigrationChannel,
+                                                   bf16_equiv_page_bytes)
 from dlnetbench_tpu.serving.scheduler import (Engine, ServingConfig,
                                               _SlotState)
 

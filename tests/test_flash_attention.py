@@ -1,4 +1,4 @@
-"""Flash-attention kernel vs. the einsum reference (models/layers.py).
+"""Flash-attention kernel vs. the einsum reference (ops/xla_attention.py).
 
 Runs the Pallas kernels in interpret mode on the CPU mesh (conftest forces
 JAX_PLATFORMS=cpu), checking forward values and all three input gradients.
@@ -10,9 +10,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from dlnetbench_tpu.models import layers as L
 from dlnetbench_tpu import ops
-from dlnetbench_tpu.ops import flash_attention, flash_supported
+from dlnetbench_tpu.ops import flash_attention, flash_supported, xla_attention
 
 
 def _make_qkv(key, b, s, hq, hkv, dh, dtype=jnp.float32):
@@ -36,7 +35,7 @@ CASES = [
 @pytest.mark.parametrize("b,s,hq,hkv,dh,causal", CASES)
 def test_forward_matches_reference(b, s, hq, hkv, dh, causal):
     q, k, v = _make_qkv(jax.random.key(0), b, s, hq, hkv, dh)
-    want = L.attention(q, k, v, causal=causal)
+    want = xla_attention(q, k, v, causal=causal)
     got = flash_attention(q, k, v, causal, 128, 128)
     assert got.shape == want.shape and got.dtype == want.dtype
     assert jnp.max(jnp.abs(got - want)) < 2e-5
@@ -48,7 +47,7 @@ def test_gradients_match_reference(b, s, hq, hkv, dh, causal):
     cot = jax.random.normal(jax.random.key(2), q.shape, q.dtype)
 
     def loss_ref(q, k, v):
-        return jnp.sum(L.attention(q, k, v, causal=causal) * cot)
+        return jnp.sum(xla_attention(q, k, v, causal=causal) * cot)
 
     def loss_flash(q, k, v):
         return jnp.sum(flash_attention(q, k, v, causal, 128, 128) * cot)
@@ -84,7 +83,7 @@ def test_unsupported_seq_falls_back():
 def test_bf16_forward_close():
     q, k, v = _make_qkv(jax.random.key(5), 1, 256, 2, 2, 128,
                         dtype=jnp.bfloat16)
-    want = L.attention(q, k, v, causal=True).astype(jnp.float32)
+    want = xla_attention(q, k, v, causal=True).astype(jnp.float32)
     got = flash_attention(q, k, v, True, 128, 128).astype(jnp.float32)
     assert jnp.max(jnp.abs(got - want)) < 3e-2
 
@@ -105,7 +104,7 @@ MASK_SPECS = [
 
 
 def _masked_ref(q, k, v, spec):
-    return L.attention(q, k, v, causal=spec.causal,
+    return xla_attention(q, k, v, causal=spec.causal,
                        dense_mask=jnp.asarray(
                            am.dense_mask(spec, q.shape[1])))
 

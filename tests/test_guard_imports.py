@@ -6,13 +6,19 @@
    import point keeps the next move a one-file change.
 2. ``pytest --collect-only`` must report zero errors: a collection error
    silently removes an entire file's tests from the tier-1 count.
+3. The package's layers import downward only: ``utils`` -> ``core`` /
+   ``metrics`` / ``parallel`` -> ``ops`` -> ``models`` -> ``serving``.
+   What still points up is listed here with its reason.
 """
 from __future__ import annotations
 
+import ast
 import re
 import subprocess
 import sys
 from pathlib import Path
+
+import pytest
 
 
 REPO = Path(__file__).resolve().parent.parent
@@ -41,6 +47,64 @@ def test_no_direct_shard_map_imports():
     assert not offenders, (
         f"direct jax shard_map imports outside {SHIM}: {offenders} — "
         f"import it from dlnetbench_tpu.utils.jax_compat instead")
+
+
+# the lower layers, and the upper ones none of them may import
+LOWER = ("utils", "core", "metrics", "parallel", "ops", "tuning")
+UPPER = ("models", "serving", "proxies", "faults", "analysis")
+
+# (file, upper package) -> why it still points up.  Each is a debt in
+# ROADMAP.md; an entry that matches no import fails the test too.
+UPWARD_EXCEPTIONS = {
+    ("dlnetbench_tpu/tuning/__main__.py", "serving"):
+        "the tune CLI is an entry point: it times serving's paged "
+        "attention site to fill the DB",
+    ("dlnetbench_tpu/tuning/__main__.py", "models"):
+        "the tune CLI is an entry point: it times the SPMD step's "
+        "gradient-bucket knob to fill the DB",
+    ("dlnetbench_tpu/metrics/emit.py", "proxies"):
+        "the record emitter takes a ProxyResult: the type belongs "
+        "beside the record schema",
+    ("dlnetbench_tpu/metrics/emit.py", "analysis"):
+        "records are stamped with their attribution as they are emitted",
+    ("dlnetbench_tpu/metrics/merge.py", "analysis"):
+        "merged records are attributed again after the merge",
+}
+
+
+def _upward_imports(package: str) -> set:
+    """{(file, upper package)} over every import statement of the
+    package's files, at module level or inside a function."""
+    found = set()
+    for path in (REPO / "dlnetbench_tpu" / package).rglob("*.py"):
+        rel = path.relative_to(REPO).as_posix()
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.ImportFrom):
+                assert not node.level, f"{rel}:{node.lineno}: relative import"
+                names = [f"{node.module}.{a.name}" for a in node.names]
+            elif isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            else:
+                continue
+            for name in names:
+                top, _, rest = name.partition(".")
+                upper = rest.split(".")[0]
+                if top == "dlnetbench_tpu" and upper in UPPER:
+                    found.add((rel, upper))
+    return found
+
+
+@pytest.mark.parametrize("package", LOWER)
+def test_lower_layers_do_not_import_upward(package):
+    found = _upward_imports(package)
+    listed = {k for k in UPWARD_EXCEPTIONS
+              if k[0].startswith(f"dlnetbench_tpu/{package}/")}
+    assert found - listed == set(), (
+        f"{package}/ imports a layer above it: {sorted(found - listed)}; "
+        f"move the shared piece down or hand it in as an argument")
+    assert listed - found == set(), (
+        f"exceptions that match no import any more, delete them: "
+        f"{sorted(listed - found)}")
 
 
 def test_collection_is_clean():

@@ -143,37 +143,6 @@ def test_swiglu_int8_residual_contract_no_hidden_h():
                             atol=1e-3, rtol=1e-3), name
 
 
-def test_flash_bwd_blocks_override_fails_loud(monkeypatch):
-    """The sweep env knob must raise on malformed strings and
-    non-divisor blocks — a truncated grid would silently compute wrong
-    gradients while recording a plausible time.  The knob is frozen at
-    IMPORT time (jit caching is not keyed on the environment, ADVICE
-    r5), so parsing is tested through the pure parser and a post-import
-    env change must raise instead of silently reusing the stale
-    compiled config."""
-    from dlnetbench_tpu.ops.flash_attention import (
-        _bwd_blocks_override, _parse_bwd_blocks)
-
-    with pytest.raises(ValueError, match="comma-separated"):
-        _parse_bwd_blocks("1024;1024,1024,1024", 1024, 1024, 6144)
-    with pytest.raises(ValueError, match="does not divide"):
-        _parse_bwd_blocks("1280,1024,1024,1024", 1024, 1024, 6144)
-    assert _parse_bwd_blocks("2048,512,512,2048", 1024, 1024, 6144) == \
-        ((2048, 512), (512, 2048))
-    assert _parse_bwd_blocks("", 1024, 1024, 6144) == ((1024, 1024),
-                                                       (1024, 1024))
-    # the import-time freeze: a live env differing from the frozen value
-    # is a configuration error, not a silent stale-cache reuse
-    monkeypatch.setenv("DLNB_FLASH_BWD_BLOCKS", "2048,512,512,2048")
-    with pytest.raises(ValueError, match="changed after import"):
-        _bwd_blocks_override(1024, 1024, 6144)
-    monkeypatch.delenv("DLNB_FLASH_BWD_BLOCKS")
-    # empty env defers to the tuning layer (ISSUE 9): None = "the DB
-    # may answer, else the defaults" — _resolve_bwd_blocks owns that
-    # fallback now (tests/test_tuning.py covers both arms)
-    assert _bwd_blocks_override(1024, 1024, 6144) is None
-
-
 def test_swiglu_int8_switchback_grads_close_to_master():
     """The SwitchBack backward (dx-side matmuls quantized) must stay
     CLOSE to the master-dtype backward — the quantization error it
@@ -254,8 +223,8 @@ def test_int8_config_validation():
     cfg = tfm.TransformerConfig.from_card(card, seq_len=64, num_layers=2)
     with pytest.raises(ValueError, match="dense SwiGLU"):
         dataclasses.replace(cfg, mlp_dtype="int8")
-    # the custom backwards cover only the bf16 path
+    # switchback is the int8 path's own backward
     card2 = load_model_card("llama3_8b")
     cfg2 = tfm.TransformerConfig.from_card(card2, seq_len=64, num_layers=2)
-    with pytest.raises(ValueError, match="bf16 SwiGLU"):
-        dataclasses.replace(cfg2, mlp_dtype="int8", mlp_backward="pallas")
+    with pytest.raises(ValueError, match="requires mlp_dtype='int8'"):
+        dataclasses.replace(cfg2, int8_backward="switchback")

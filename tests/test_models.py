@@ -1,5 +1,7 @@
 """Real-model tests: shapes, finiteness, gradient flow, and learning on
 tiny configs (CPU)."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -175,10 +177,10 @@ def test_vit_card_guard():
         tfm.TransformerConfig.from_card(load_model_card("vit_b"))
 
 
-def test_remat_policies_agree():
-    """remat off / full / dots must give the same loss and gradients; an
-    unknown policy string is rejected at construction."""
-    from dlnetbench_tpu.models import transformer as tfm
+def test_remat_agrees_with_no_remat():
+    """``remat=True`` (each block recomputed in the backward) must give
+    the same loss and gradients as storing the activations, through
+    both layer-stack forms."""
     cfg0 = tfm.TransformerConfig(
         vocab_size=64, embed_dim=32, num_heads=4, num_kv_heads=2, ff_dim=64,
         num_layers=2, seq_len=16, gated=True, max_positions=0,
@@ -190,24 +192,55 @@ def test_remat_policies_agree():
         return jax.value_and_grad(tfm.loss_fn)(params, tokens, cfg)
 
     l0, g0 = lg(cfg0)
-    for policy in ("full", "dots"):
-        cfg = tfm.TransformerConfig(
-            **{**cfg0.__dict__, "remat": True, "remat_policy": policy})
-        l1, g1 = lg(cfg)
+    for scan_layers in (True, False):
+        l1, g1 = lg(dataclasses.replace(cfg0, remat=True,
+                                        scan_layers=scan_layers))
         assert jnp.allclose(l0, l1, rtol=1e-6)
         for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
             assert jnp.allclose(a, b, rtol=1e-5, atol=1e-6)
 
-    with pytest.raises(ValueError, match="remat_policy"):
-        tfm.TransformerConfig(**{**cfg0.__dict__, "remat_policy": "dot"})
 
-    # remat_scope="mlp" (checkpoint only the SwiGLU — the r5 int8
-    # memory knob) must also be gradient-identical; bad scope rejected
-    cfg = tfm.TransformerConfig(
-        **{**cfg0.__dict__, "remat": True, "remat_scope": "mlp"})
-    l1, g1 = lg(cfg)
-    assert jnp.allclose(l0, l1, rtol=1e-6)
-    for a, b in zip(jax.tree.leaves(g0), jax.tree.leaves(g1)):
-        assert jnp.allclose(a, b, rtol=1e-5, atol=1e-6)
-    with pytest.raises(ValueError, match="remat_scope"):
-        tfm.TransformerConfig(**{**cfg0.__dict__, "remat_scope": "layer"})
+_STEP_TINY = dict(vocab_size=128, embed_dim=32, num_heads=4, num_kv_heads=2,
+                  ff_dim=64, num_layers=2, seq_len=16, gated=True,
+                  max_positions=0)
+_STEP_CONFIGS = {
+    "dense_bf16": dict(),
+    "dense_gelu": dict(gated=False, max_positions=32),
+    "dense_int8_composed": dict(mlp_dtype="int8"),
+    "dense_int8_fused": dict(mlp_dtype="int8", quant_fusion="fused"),
+    "dense_float8_composed": dict(mlp_dtype="float8"),
+    "moe_sparse": dict(num_experts=4, top_k=2, moe_impl="sparse"),
+    "moe_grouped": dict(num_experts=4, top_k=2, moe_impl="grouped"),
+}
+
+
+@pytest.mark.parametrize("name", [*_STEP_CONFIGS, "hybrid"])
+def test_step_builder_has_one_contract(name):
+    """``make_train_k(cfg, k, lr)`` is ``train_k(params, tokens) ->
+    (params', losses[k])`` for every model family and MLP recipe: the
+    state that comes back has the tree the caller handed in (both
+    benchmark runners feed it to the next call, donated), and the
+    losses are finite."""
+    from dlnetbench_tpu.models import bench_step, hybrid
+    if name == "hybrid":
+        cfg = hybrid.HybridConfig(
+            vocab_size=128, embed_dim=32, num_heads=4, num_kv_heads=2,
+            ff_dim=64, seq_len=16, ssm_inner=64, ssm_dt_rank=2,
+            attention_window=8,
+            layer_kinds=("mamba", "window", "full", "gmu", "cross"))
+        params = hybrid.init_params(jax.random.key(0), cfg)
+    else:
+        cfg = tfm.TransformerConfig(**{**_STEP_TINY,
+                                       **_STEP_CONFIGS[name]})
+        params = tfm.init_params(jax.random.key(0), cfg)
+    tokens = jax.random.randint(jax.random.key(1), (2, cfg.seq_len + 1),
+                                0, cfg.vocab_size)
+    train_k = jax.jit(bench_step.make_train_k(cfg, 2, 1e-2),
+                      donate_argnums=bench_step.DONATE_ARGNUMS)
+    shapes = jax.tree.map(lambda a: (a.shape, a.dtype), params)
+    out = train_k(params, tokens)
+    assert isinstance(out, tuple) and len(out) == 2
+    new_params, losses = out
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), new_params) == shapes
+    assert losses.shape == (2,)
+    assert bool(jnp.all(jnp.isfinite(losses)))

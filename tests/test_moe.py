@@ -494,7 +494,7 @@ def test_a2a_expert_ffn_matches_monolithic(eight_devices):
                               concat_axis=0, tiled=True)
 
     def deco(e_, a, b, c):
-        return a2a_expert_ffn(e_, a, b, c, "tp",
+        return a2a_expert_ffn(e_, a, b, c, "tp", moe.expert_ffn,
                               chunks=2).astype(e_.dtype)
 
     out_m = np.asarray(_shardmap_ffn(mono, mesh)(ein, wg, wu, wd))
@@ -529,7 +529,8 @@ def test_a2a_expert_ffn_backward_matches(eight_devices):
                               concat_axis=0, tiled=True)
 
     def deco(e_, a, b, c):
-        return a2a_expert_ffn(e_, a, b, c, "tp").astype(e_.dtype)
+        return a2a_expert_ffn(e_, a, b, c, "tp",
+                              moe.expert_ffn).astype(e_.dtype)
 
     for a, b in zip(grads_of(mono), grads_of(deco)):
         assert np.abs(a - b).max() < 1e-5
@@ -542,12 +543,13 @@ def test_a2a_expert_ffn_fake_legs(eight_devices):
     from dlnetbench_tpu.ops.moe_dispatch import a2a_expert_ffn
     mesh, ein, wg, wu, wd = _a2a_case()
     full = _shardmap_ffn(
-        lambda e_, a, b, c: a2a_expert_ffn(e_, a, b, c, "tp")
+        lambda e_, a, b, c: a2a_expert_ffn(e_, a, b, c, "tp",
+                                           moe.expert_ffn)
         .astype(e_.dtype), mesh)(ein, wg, wu, wd)
     for kw in ({"fake_compute": True}, {"fake_comm": True}):
         out = _shardmap_ffn(
             lambda e_, a, b, c, _kw=kw: a2a_expert_ffn(
-                e_, a, b, c, "tp", **_kw).astype(e_.dtype),
+                e_, a, b, c, "tp", moe.expert_ffn, **_kw).astype(e_.dtype),
             mesh)(ein, wg, wu, wd)
         assert out.shape == full.shape
         assert np.all(np.isfinite(np.asarray(out)))
@@ -557,7 +559,8 @@ def test_a2a_expert_ffn_rejects_flat_weights():
     from dlnetbench_tpu.ops.moe_dispatch import a2a_expert_ffn
     with pytest.raises(ValueError, match="E_local"):
         a2a_expert_ffn(jnp.zeros((4, 2, 8)), jnp.zeros((8, 16)),
-                       jnp.zeros((8, 16)), jnp.zeros((16, 8)), "tp")
+                       jnp.zeros((8, 16)), jnp.zeros((16, 8)), "tp",
+                       moe.expert_ffn)
 
 
 # --------------------------------------------------------- SPMD step

@@ -1,14 +1,12 @@
 """Fused-quantization Pallas matmuls (ops/quantized_matmul.py, ISSUE 3
 tentpole) — interpret-mode unit tests against the composed XLA
 reference: int8 EXACT (shared scale definition + associative int32
-accumulation), fp8 within e4m3 quantization tolerance, delayed-scaling
-state threading, and the transformer config plumbing.
+accumulation), fp8 within e4m3 quantization tolerance, and the
+transformer config plumbing.
 
 On the chip the same kernels run at the bench shape in
 ``chip_smoke.py``'s kernels phase."""
 from __future__ import annotations
-
-import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +18,6 @@ from dlnetbench_tpu.ops.int8 import (
     int8_dot,
     swiglu_int8,
     swiglu_int8_fused,
-    swiglu_int8_fused_delayed,
 )
 
 _F32 = jnp.float32
@@ -107,45 +104,6 @@ def test_fused_dot_straight_through_grads_match_composed():
             assert jnp.array_equal(a, b)
 
 
-def test_delayed_dot_state_threading():
-    """The delayed-scaling contract: (1) with amax_in = the TRUE amax,
-    the result equals fresh scaling exactly (int8); (2) amax_out is
-    the true amax of the CURRENT activation (the next step's state);
-    (3) a stale, too-small amax saturates instead of overflowing; (4)
-    the carried state gets a zero gradient."""
-    x = _rand(11, (64, 32))
-    w = _rand(12, (32, 48), 0.1)
-    true_amax = jnp.max(jnp.abs(x.astype(_F32)))
-
-    y, amax_out = qmm.int8_dot_fused_delayed(x, w, true_amax)
-    assert jnp.array_equal(y, int8_dot(x, w))
-    assert jnp.array_equal(amax_out, true_amax)
-
-    y_stale, amax_out2 = qmm.int8_dot_fused_delayed(x, w, true_amax * 0.1)
-    assert bool(jnp.all(jnp.isfinite(y_stale.astype(_F32))))
-    # the emitted state is the fresh amax regardless of the stale scale
-    assert jnp.array_equal(amax_out2, true_amax)
-
-    def loss(x, w, amax):
-        y, _ = qmm.int8_dot_fused_delayed(x, w, amax)
-        return jnp.sum(y.astype(_F32))
-
-    gx, gw, gamax = jax.grad(loss, argnums=(0, 1, 2))(x, w, true_amax)
-    assert float(jnp.sum(jnp.abs(gamax))) == 0.0
-    gx_ref, gw_ref = jax.grad(
-        lambda x, w: jnp.sum(int8_dot(x, w).astype(_F32)),
-        argnums=(0, 1))(x, w)
-    assert jnp.array_equal(gx, gx_ref) and jnp.array_equal(gw, gw_ref)
-
-    # fp8 delayed: same contract, quantization-tolerance equality
-    yf, am = qmm.fp8_dot_fused_delayed(x, w, true_amax)
-    assert jnp.array_equal(am, true_amax)
-    ref = fp8_dot(x, w).astype(_F32)
-    rel = (jnp.linalg.norm(yf.astype(_F32) - ref)
-           / jnp.maximum(jnp.linalg.norm(ref), 1e-9))
-    assert rel < 1e-2
-
-
 def test_swiglu_fused_matches_composed():
     x = _rand(13, (48, 32))
     wg = _rand(14, (32, 40), 0.1)
@@ -188,50 +146,6 @@ def test_swiglu_fused_residual_contract():
         assert n_tf == 2, (fn.__name__, n_tf)
 
 
-def test_swiglu_fused_delayed_state_and_grads():
-    """Layer-level delayed scaling: with the TRUE amaxes as incoming
-    state the output is exactly the fresh-scaling fused result, the
-    emitted state is [amax_x, amax_h] of THIS step, and gradients match
-    the master backward; the state slot gets zero gradient."""
-    x = _rand(22, (48, 32))
-    wg = _rand(23, (32, 40), 0.1)
-    wu = _rand(24, (32, 40), 0.1)
-    wd = _rand(25, (40, 32), 0.1)
-
-    # true amaxes of this step's activations
-    amax_x = jnp.max(jnp.abs(x.astype(_F32)))
-    g = int8_dot(x, wg)
-    u = int8_dot(x, wu)
-    h = (jax.nn.silu(g.astype(_F32)) * u.astype(_F32)).astype(g.dtype)
-    amax_h = jnp.max(jnp.abs(h.astype(_F32)))
-    qs = jnp.stack([amax_x, amax_h])
-
-    y, new_qs = swiglu_int8_fused_delayed(x, wg, wu, wd, qs)
-    assert jnp.array_equal(y, swiglu_int8_fused(x, wg, wu, wd))
-    assert jnp.allclose(new_qs, qs)
-
-    cot = _rand(26, (48, 32))
-
-    def loss_delayed(x, wg, wu, wd, qs):
-        y, _ = swiglu_int8_fused_delayed(x, wg, wu, wd, qs)
-        return jnp.sum(y.astype(_F32) * cot.astype(_F32))
-
-    def loss_master(*a):
-        return jnp.sum(swiglu_int8(*a).astype(_F32) * cot.astype(_F32))
-
-    gd = jax.grad(loss_delayed, argnums=(0, 1, 2, 3, 4))(x, wg, wu, wd, qs)
-    gm = jax.grad(loss_master, argnums=(0, 1, 2, 3))(x, wg, wu, wd)
-    for a, b, name in zip(gd[:4], gm, ("dx", "dwg", "dwu", "dwd")):
-        assert jnp.array_equal(a, b), name
-    assert float(jnp.sum(jnp.abs(gd[4]))) == 0.0
-
-    # a cold (ones) state still produces finite output and the emitted
-    # state converges to the truth in one step — the warm-in contract
-    y2, qs2 = swiglu_int8_fused_delayed(x, wg, wu, wd, jnp.ones(2, _F32))
-    assert bool(jnp.all(jnp.isfinite(y2.astype(_F32))))
-    assert jnp.array_equal(qs2[0], amax_x)
-
-
 def test_quantize_tensor_shared_with_composed_paths():
     """ops/int8.py and ops/fp8.py _quantize must BE the shared
     definition — this is what makes the fused-vs-composed int8 A/B an
@@ -259,76 +173,43 @@ _TINY = dict(vocab_size=128, embed_dim=32, num_heads=4, num_kv_heads=2,
              max_positions=0)
 
 
-def test_quantized_swiglu_dispatcher_guards_delayed_state():
-    """The layers-level dispatcher must mirror the config validation
-    for direct callers: handing delayed state to a composed-configured
-    call is an error, not a silent reroute to the fused kernel."""
-    from dlnetbench_tpu.models import layers as L
-    x = _rand(40, (8, 16))
-    w = _rand(41, (16, 24), 0.1)
-    wd = _rand(42, (24, 16), 0.1)
-    with pytest.raises(ValueError, match="requires quant_fusion='fused'"):
-        L.quantized_swiglu(x, w, w, wd, mlp_dtype="int8",
-                           quant_fusion="composed",
-                           amax_state=jnp.ones(2, _F32))
-
-
 def test_transformer_quant_config_validation():
     from dlnetbench_tpu.models import transformer as tfm
     with pytest.raises(ValueError, match="quant_fusion"):
         tfm.TransformerConfig(**_TINY, mlp_dtype="int8",
                               quant_fusion="pallas")
-    with pytest.raises(ValueError, match="quant_scaling"):
-        tfm.TransformerConfig(**_TINY, mlp_dtype="int8",
-                              quant_fusion="fused", quant_scaling="stale")
     with pytest.raises(ValueError, match="nothing to quantize"):
         tfm.TransformerConfig(**_TINY, quant_fusion="fused")
-    with pytest.raises(ValueError, match="requires quant_fusion='fused'"):
-        tfm.TransformerConfig(**_TINY, mlp_dtype="int8",
-                              quant_scaling="delayed")
     with pytest.raises(ValueError, match="master-dtype"):
         tfm.TransformerConfig(**_TINY, mlp_dtype="int8",
                               quant_fusion="fused",
                               int8_backward="switchback")
-    # legal combos
-    cfg = tfm.TransformerConfig(**_TINY, mlp_dtype="float8",
-                                quant_fusion="fused",
-                                quant_scaling="delayed")
-    assert tfm.needs_qstate(cfg)
-    with pytest.raises(ValueError, match="delayed"):
-        tfm.init_qstate(tfm.TransformerConfig(**_TINY))
+    # a legal combination
+    tfm.TransformerConfig(**_TINY, mlp_dtype="float8", quant_fusion="fused")
 
 
 @pytest.mark.parametrize("mlp_dtype", ["int8", "float8"])
 @pytest.mark.parametrize("scan_layers", [True, False])
-def test_transformer_fused_delayed_trains(mlp_dtype, scan_layers):
-    """The full vertical: delayed-scaling fused MLPs inside a train
-    step, state threaded through both layer-stack codepaths (scan and
-    unrolled), loss finite, grads flowing, state moving off init."""
+def test_transformer_fused_trains(mlp_dtype, scan_layers):
+    """The full vertical: fused-quantization MLPs inside a train step,
+    through both layer-stack codepaths (scan and unrolled), loss finite
+    and falling on a repeated batch, grads flowing."""
     from dlnetbench_tpu.models import transformer as tfm
     cfg = tfm.TransformerConfig(**_TINY, mlp_dtype=mlp_dtype,
                                 quant_fusion="fused",
-                                quant_scaling="delayed",
                                 scan_layers=scan_layers)
     params = tfm.init_params(jax.random.key(0), cfg)
-    qs = tfm.init_qstate(cfg)
     tokens = jax.random.randint(jax.random.key(1), (2, cfg.seq_len + 1),
                                 0, cfg.vocab_size)
-    step = jax.jit(lambda p, t, q: jax.value_and_grad(
-        tfm.loss_fn, has_aux=True)(p, t, cfg, q))
-    (loss, new_qs), g = step(params, tokens, qs)
+    step = jax.jit(lambda p, t: jax.value_and_grad(tfm.loss_fn)(p, t, cfg))
+    loss, g = step(params, tokens)
     assert jnp.isfinite(loss)
-    assert new_qs.shape == (cfg.num_layers, 2)
-    assert bool(jnp.any(new_qs != qs)), "delayed state never updated"
     gmax = jnp.max(jnp.abs(g["layers"]["w_gate"].astype(_F32)))
     assert gmax > 0
-    # second step with the threaded state: still finite, state stable
-    # (same batch -> same amaxes up to the one-step param update)
-    (loss2, qs3), _ = step(jax.tree.map(
-        lambda a, b: a - 1e-3 * b.astype(a.dtype), params, g),
-        tokens, new_qs)
-    assert jnp.isfinite(loss2)
-    assert bool(jnp.all(jnp.isfinite(qs3)))
+    # a second step on the same batch after one SGD update
+    loss2, _ = step(jax.tree.map(
+        lambda a, b: a - 0.5 * b.astype(a.dtype), params, g), tokens)
+    assert jnp.isfinite(loss2) and float(loss2) < float(loss)
 
 
 def test_transformer_fused_dynamic_matches_composed_int8():
@@ -345,15 +226,3 @@ def test_transformer_fused_dynamic_matches_composed_int8():
     loss_f = jax.jit(lambda p, t: tfm.loss_fn(p, t, cfg_f))(params, tokens)
     loss_c = jax.jit(lambda p, t: tfm.loss_fn(p, t, cfg_c))(params, tokens)
     assert float(loss_f) == float(loss_c)
-
-
-def test_forward_requires_qstate_when_delayed():
-    from dlnetbench_tpu.models import transformer as tfm
-    cfg = tfm.TransformerConfig(**_TINY, mlp_dtype="int8",
-                                quant_fusion="fused",
-                                quant_scaling="delayed")
-    params = tfm.init_params(jax.random.key(0), cfg)
-    tokens = jax.random.randint(jax.random.key(1), (1, cfg.seq_len), 0,
-                                cfg.vocab_size)
-    with pytest.raises(ValueError, match="qstate"):
-        tfm.forward(params, tokens, cfg)

@@ -15,7 +15,7 @@ from jax import lax
 from dlnetbench_tpu.utils.jax_compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from dlnetbench_tpu.models import layers as L
+from dlnetbench_tpu.ops import xla_attention
 from dlnetbench_tpu.ops.sequence_parallel import (
     ring_attention,
     ulysses_attention,
@@ -54,7 +54,7 @@ CASES = [
 def test_ring_matches_full(n, b, s, hq, hkv, dh, causal):
     mesh = _mesh(n)
     q, k, v = _qkv(jax.random.key(0), b, s, hq, hkv, dh)
-    want = L.attention(q, k, v, causal=causal)
+    want = xla_attention(q, k, v, causal=causal)
     fn = _sharded(functools.partial(ring_attention, axis_name=AXIS,
                                     causal=causal), mesh)
     got = fn(q, k, v)
@@ -65,7 +65,7 @@ def test_ring_matches_full(n, b, s, hq, hkv, dh, causal):
 def test_ulysses_matches_full(n, b, s, hq, hkv, dh, causal):
     mesh = _mesh(n)
     q, k, v = _qkv(jax.random.key(1), b, s, hq, hkv, dh)
-    want = L.attention(q, k, v, causal=causal)
+    want = xla_attention(q, k, v, causal=causal)
     fn = _sharded(functools.partial(ulysses_attention, axis_name=AXIS,
                                     causal=causal, impl="xla"), mesh)
     got = fn(q, k, v)
@@ -79,7 +79,7 @@ def test_ring_gradients_match_full():
     cot = jax.random.normal(jax.random.key(3), q.shape, q.dtype)
 
     def ref_loss(q, k, v):
-        return jnp.sum(L.attention(q, k, v, causal=True) * cot)
+        return jnp.sum(xla_attention(q, k, v, causal=True) * cot)
 
     spec = P(None, AXIS, None, None)
 
@@ -121,7 +121,7 @@ def test_masked_ring_matches_dense_reference(spec):
     n, b, s, hq, hkv, dh = 4, 2, 64, 4, 2, 16
     mesh = _mesh(n)
     q, k, v = _qkv(jax.random.key(6), b, s, hq, hkv, dh)
-    want = L.attention(q, k, v, causal=spec.causal,
+    want = xla_attention(q, k, v, causal=spec.causal,
                        dense_mask=jnp.asarray(am.dense_mask(spec, s)))
     fn = _sharded(functools.partial(ring_attention, axis_name=AXIS,
                                     causal=spec.causal, spec=spec), mesh)
@@ -145,7 +145,7 @@ def test_causal_fast_path_gates_future_hops():
     n, b, s, hq, hkv, dh = 4, 1, 64, 4, 2, 16
     mesh = _mesh(n)
     q, k, v = _qkv(jax.random.key(7), b, s, hq, hkv, dh)
-    want = L.attention(q, k, v, causal=True)
+    want = xla_attention(q, k, v, causal=True)
     fn = _sharded(functools.partial(ring_attention, axis_name=AXIS,
                                     causal=True), mesh)
     assert jnp.max(jnp.abs(fn(q, k, v) - want)) < 2e-5
@@ -161,7 +161,7 @@ def test_masked_ring_gradients_match_dense_reference():
     dm = jnp.asarray(am.dense_mask(spec, s))
 
     def ref_loss(q, k, v):
-        return jnp.sum(L.attention(q, k, v, causal=True,
+        return jnp.sum(xla_attention(q, k, v, causal=True,
                                    dense_mask=dm) * cot)
 
     sspec = P(None, AXIS, None, None)
@@ -187,7 +187,7 @@ def test_masked_ulysses_matches_dense_reference():
     n, b, s, hq, hkv, dh = 4, 2, 64, 4, 4, 16
     mesh = _mesh(n)
     q, k, v = _qkv(jax.random.key(10), b, s, hq, hkv, dh)
-    want = L.attention(q, k, v, causal=True,
+    want = xla_attention(q, k, v, causal=True,
                        dense_mask=jnp.asarray(am.dense_mask(spec, s)))
     fn = _sharded(functools.partial(ulysses_attention, axis_name=AXIS,
                                     causal=True, impl="xla", spec=spec),
@@ -233,7 +233,7 @@ def test_ulysses_gradients_match_full():
     cot = jax.random.normal(jax.random.key(5), q.shape, q.dtype)
 
     def ref_loss(q, k, v):
-        return jnp.sum(L.attention(q, k, v, causal=True) * cot)
+        return jnp.sum(xla_attention(q, k, v, causal=True) * cot)
 
     spec = P(None, AXIS, None, None)
 

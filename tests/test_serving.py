@@ -13,12 +13,13 @@ import numpy as np
 import pytest
 
 from dlnetbench_tpu.models import transformer as tfm
-from dlnetbench_tpu.serving.arrivals import ArrivalPlan, splitmix64
+from dlnetbench_tpu.serving.arrivals import ArrivalPlan
 from dlnetbench_tpu.serving.kv_cache import (CacheConfig, CacheOOM,
                                              PagedKVCache,
                                              device_buffers,
                                              paged_attention_decode,
                                              sharded_paged_attention)
+from dlnetbench_tpu.utils.seeded import Rng, splitmix64
 
 DATA = Path(__file__).parent / "data"
 
@@ -112,6 +113,20 @@ def test_splitmix64_matches_native_constants():
     v2, _ = splitmix64(s)
     assert v1 == 0xE220A8397B1DCDAF
     assert v2 == 0x6E789E6AA1B965F4
+
+
+def test_seeded_rng_stream_is_pinned():
+    """The draws every plan, prompt, router and tuner order is made of
+    (``utils/seeded.Rng``): golden values from the generator as it was
+    in ``serving/arrivals.py``, so moving or editing it cannot shift a
+    committed plan's stream."""
+    rng = Rng(7)
+    assert rng.u01() == 0.3898297483912715
+    assert rng.uniform_int(3, 11) == 9
+    assert rng.expovariate(2.5) == 0.9240883913492564
+    assert rng.uniform_int(5, 5) == 5          # no draw spent
+    assert rng.state == 0xDAA66D2C7DDF7446
+    assert Rng(-1).state == (1 << 64) - 1      # seeds wrap to 64 bits
 
 
 def test_replay_plan_samples_trace_verbatim():

@@ -16,8 +16,9 @@ Covers, per the issue's satellite checklist:
 * the ``python -m dlnetbench_tpu.tuning tune`` CLI end to end on a
   tiny CPU shape (2 candidates, seconds — the ``make check-tuning``
   lane);
-* the ``DLNB_FLASH_BWD_BLOCKS`` freeze check, directly (it was only
-  exercised indirectly before), with the old -> new values named.
+* the flash backward's block resolution: the tuner's
+  ``override_blocks`` held to the sequence, the DB consulted for an
+  all-default call.
 """
 from __future__ import annotations
 
@@ -399,7 +400,7 @@ def test_flash_blocks_empty_db_bit_identical(monkeypatch, tmp_path):
 
 def test_flash_tuned_blocks_must_divide_seq(monkeypatch, tmp_path):
     """An inapplicable DB block config fails LOUD at the flash site
-    (the truncated-grid hazard the env knob already guards)."""
+    (a truncated grid would silently drop contributions)."""
     import importlib
     fa = importlib.import_module("dlnetbench_tpu.ops.flash_attention")
 
@@ -694,47 +695,46 @@ def test_bench_tuned_ab_end_to_end_tiny(monkeypatch):
     assert is_ms_line(line)
 
 
-# ------------------------------- DLNB_FLASH_BWD_BLOCKS freeze, direct
+# ------------------------------- the flash backward's block resolution
 
-def test_flash_bwd_env_freeze_direct(monkeypatch):
-    """The post-import mutation check, exercised DIRECTLY: a changed
-    env raises, and the message names the frozen -> attempted values
-    (ISSUE 9 satellite)."""
+@pytest.mark.parametrize("kernel", ["flash", "splash"])
+def test_flash_bwd_override_blocks_must_divide_the_sequence(kernel):
+    """The tuner's ``override_blocks`` fail LOUD on a block that does
+    not divide the sequence: a truncated grid would leave dq rows
+    unwritten and drop query contributions from dk/dv while the sweep
+    records a plausible-looking time.  Blocks that divide it run."""
     import importlib
     fa = importlib.import_module("dlnetbench_tpu.ops.flash_attention")
+    from dlnetbench_tpu.ops.attention_mask import MaskSpec
 
-    assert fa._BWD_BLOCKS_ENV == ""  # tier-1 lane imports without it
-    monkeypatch.setenv("DLNB_FLASH_BWD_BLOCKS", "128,128,128,128")
-    with pytest.raises(ValueError) as e:
-        fa._bwd_blocks_override(256, 256, 1024)
-    msg = str(e.value)
-    assert "changed after import" in msg
-    assert "frozen ''" in msg and "'128,128,128,128'" in msg
-
-
-def test_flash_bwd_env_wins_over_db(monkeypatch, tmp_path):
-    """Env override beats the tuning DB (reproducibility: a sweep that
-    sets the env must measure the env's blocks, whatever the DB says).
-    Simulated by freezing a module-level env value the way an on-import
-    capture would."""
-    import importlib
-    fa = importlib.import_module("dlnetbench_tpu.ops.flash_attention")
-
-    root = _enable(monkeypatch, tmp_path)
     q = jax.random.normal(jax.random.key(0), (1, 256, 2, 128),
                           jnp.float32)
-    key = tuning.params.flash_bwd_key(1, 256, 2, 2, 128, True, q.dtype)
-    TuningDB(root).put("flash_bwd", key, tuning.hw_key(),
-                       {"bq_dq": 64, "bk_dq": 64,
-                        "bq_dkv": 64, "bk_dkv": 64})
-    monkeypatch.setenv("DLNB_FLASH_BWD_BLOCKS", "128,128,128,128")
-    monkeypatch.setattr(fa, "_BWD_BLOCKS_ENV", "128,128,128,128")
-    blocks = fa._resolve_bwd_blocks(q, q, True, 256, 256)
-    assert blocks == ((128, 128), (128, 128))   # env, not the DB's 64s
-    assert tuning.provenance() is None          # the DB was never asked
+    if kernel == "flash":
+        out, lse = fa._fwd(q, q, q, causal=True, block_q=128, block_k=128)
+
+        def bwd(blocks):
+            return fa._bwd_impl(q, q, q, out, lse, q, causal=True,
+                                block_q=128, block_k=128,
+                                override_blocks=blocks)
+    else:
+        spec = MaskSpec(causal=True, window=64)
+        out, lse = fa._splash_fwd(q, q, q, spec, block_q=128, block_k=128)
+
+        def bwd(blocks):
+            return fa._splash_bwd_impl(q, q, q, out, lse, q, spec,
+                                       block_q=128, block_k=128,
+                                       override_blocks=blocks)
+    for bad in (((96, 128), (128, 128)), ((128, 128), (128, 0))):
+        with pytest.raises(ValueError, match="does not divide"):
+            bwd(bad)
+    want = bwd(((128, 128), (128, 128)))
+    got = bwd(((64, 128), (128, 64)))
+    for a, b in zip(got, want):
+        assert a.shape == q.shape
+        assert jnp.allclose(a, b, atol=1e-4, rtol=1e-4)
 
 
-def test_flash_bwd_db_consulted_without_env(monkeypatch, tmp_path):
+def test_flash_bwd_db_consulted_for_default_call(monkeypatch, tmp_path):
     import importlib
     fa = importlib.import_module("dlnetbench_tpu.ops.flash_attention")
 
