@@ -78,9 +78,10 @@ class HybridConfig:
     attention_impl: str = "auto"    # ops.attention: auto | flash | xla
     scan_impl: str = "auto"         # ops.selective_scan: auto|pallas|xla
     loss_row_block: int = 0         # head and loss in blocks of this
-                                    # many rows, each recomputed in the
-                                    # backward, so that [T, V] logits
-                                    # never lie whole in HBM; 0 = whole
+                                    # many rows, each block's gradients
+                                    # taken with its loss, so that
+                                    # [T, V] logits never lie whole in
+                                    # HBM; 0 = whole
 
     def __post_init__(self):
         kinds = tuple(self.layer_kinds)
@@ -386,23 +387,19 @@ def forward(params: dict, tokens, cfg: HybridConfig):
     return x
 
 
-def _head(cfg, params, x):
-    x = _norm(cfg, x, params["final_norm"], params["final_norm_b"])
-    return jnp.dot(x, params["embed"].T)
-
-
 def loss_fn(params: dict, tokens, cfg: HybridConfig):
-    """Next-token cross-entropy on a [B, S+1] token batch."""
+    """Next-token cross-entropy on a [B, S+1] token batch.  Where the
+    rows are a multiple of ``cfg.loss_row_block`` the tied head and the
+    loss run block by block and each block leaves its gradients behind
+    (``layers.blocked_head_cross_entropy``); the final norm is taken
+    over all rows at once either way."""
     x = forward(params, tokens[:, :-1], cfg)
     targets = tokens[:, 1:]
     with scope("head_loss"):
+        x = _norm(cfg, x, params["final_norm"], params["final_norm_b"])
         rows, block = x.shape[0] * x.shape[1], cfg.loss_row_block
         if not block or rows <= block or rows % block:
-            return L.cross_entropy(_head(cfg, params, x), targets)
-        xb = x.reshape(rows // block, block, x.shape[-1])
-        tb = targets.reshape(rows // block, block)
-
-        @jax.checkpoint
-        def part(xt):
-            return L.cross_entropy(_head(cfg, params, xt[0]), xt[1])
-        return jnp.mean(jax.lax.map(part, (xb, tb)))
+            return L.cross_entropy(jnp.dot(x, params["embed"].T), targets)
+        return L.blocked_head_cross_entropy(
+            x.reshape(rows, -1), params["embed"], targets.reshape(rows),
+            block)

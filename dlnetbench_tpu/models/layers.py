@@ -366,3 +366,56 @@ def cross_entropy(logits, targets):
     lse = jax.scipy.special.logsumexp(logits.astype(_F32), axis=-1)
     tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
     return jnp.mean(lse - tgt.astype(_F32))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def blocked_head_cross_entropy(x, table, targets, row_block: int):
+    """``cross_entropy(x @ table.T, targets)`` for ``x`` [rows, D], a
+    tied ``table`` [V, D] and ``targets`` [rows], ``row_block`` rows at
+    a time, so that [rows, V] logits never lie whole in HBM; ``rows``
+    is a multiple of ``row_block``.
+
+    The loss is the last thing a step computes, so a block's logits
+    gradient, (softmax - onehot) / rows, is formed in the loop
+    iteration that formed the logits, and the block's ``dx`` and its
+    share of ``dtable`` are taken while the logits are still there:
+    three matmuls a block, where autodiff of checkpointed blocks makes
+    every block's logits a second time.  A caller that takes no
+    gradient compiles to the logits and the loss alone."""
+    return _blocked_head_fwd(x, table, targets, row_block)[0]
+
+
+def _blocked_head_fwd(x, table, targets, row_block):
+    rows, d = x.shape
+    blocks = rows // row_block
+
+    def block(dtable, xt):
+        xb, tb = xt
+        logits = jnp.dot(xb, table.T)                       # [R, V]
+        z = logits.astype(_F32)
+        lse = jax.scipy.special.logsumexp(z, axis=-1)
+        tgt = jnp.take_along_axis(logits, tb[:, None], axis=-1)[:, 0]
+        onehot = jnp.arange(table.shape[0]) == tb[:, None]
+        dlogits = ((jnp.exp(z - lse[:, None]) - onehot)
+                   / rows).astype(logits.dtype)
+        dtable = dtable + jnp.dot(dlogits.T, xb,
+                                  preferred_element_type=_F32)
+        return (dtable.astype(table.dtype),
+                (jnp.mean(lse - tgt.astype(_F32)), jnp.dot(dlogits, table)))
+
+    with scope("head_loss"):
+        dtable, (losses, dx) = jax.lax.scan(
+            block, jnp.zeros_like(table),
+            (x.reshape(blocks, row_block, d),
+             targets.reshape(blocks, row_block)))
+        return jnp.mean(losses), (dx.reshape(rows, d), dtable)
+
+
+def _blocked_head_bwd(row_block, res, g):
+    dx, dtable = res
+    with scope("head_loss"):
+        return ((g * dx).astype(dx.dtype),
+                (g * dtable).astype(dtable.dtype), None)
+
+
+blocked_head_cross_entropy.defvjp(_blocked_head_fwd, _blocked_head_bwd)

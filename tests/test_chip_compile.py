@@ -271,6 +271,83 @@ def test_moe_train_step_at_the_cell_shapes_fits_the_chip(for_chip, one_chip):
     assert len(wide.findall(text[text.index("ENTRY"):])) == 1
 
 
+def hlo_computations(text: str) -> dict:
+    """{computation name: its lines} of a compiled module's text."""
+    import re
+    comps, lines = {}, None
+    for line in text.splitlines():
+        if m := re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line):
+            lines = comps.setdefault(m.group(1), [])
+        elif lines is not None:
+            lines.append(line.partition(", metadata=")[0])
+    return comps
+
+
+def test_blocked_head_and_loss_at_the_cell_shapes(one_chip,
+                                                  no_persistent_cache):
+    """``phi4miniflash_train_s8k``'s head and loss alone (8192 rows of
+    2560 in blocks of 2048 against the tied 200064-row table, bf16),
+    forward and backward: one loop whose body holds three matmuls
+    against the table (logits, dx, the table's gradient) and none
+    outside it, where the checkpointed form it replaced, written out
+    here, ran four a block (the logits twice); no more memory than that
+    form; every operation under ``head_loss``."""
+    import re
+
+    from dlnetbench_tpu.core import executor
+    from dlnetbench_tpu.models import layers
+    rows, d, v, block = 8192, 2560, 200064, 2048
+
+    def fused(x, table, targets):
+        return layers.blocked_head_cross_entropy(x, table, targets, block)
+
+    def checkpointed(x, table, targets):
+        part = jax.checkpoint(lambda xt: layers.cross_entropy(
+            jnp.dot(xt[0], table.T), xt[1]))
+        return jnp.mean(jax.lax.map(part, (
+            x.reshape(-1, block, d), targets.reshape(-1, block))))
+
+    def compiled(fn):
+        args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in
+                (((rows, d), BF16), ((v, d), BF16), ((rows,), I32))]
+        exe = jax.jit(jax.value_and_grad(fn, argnums=(0, 1))) \
+            .lower(*args).compile()
+        mem = exe.memory_analysis()
+        return (exe.as_text(),
+                mem.argument_size_in_bytes + mem.temp_size_in_bytes)
+
+    def matmuls(comps, name):
+        """Matmuls of a computation and of the fusions it calls (every
+        matmul of this program has the table's 200064 on one side)."""
+        return sum(
+            bool(re.search(r" (dot|convolution)\(", line))
+            + sum(matmuls(comps, c)
+                  for c in re.findall(r"calls=%([\w.\-]+)", line))
+            for line in comps[name])
+
+    def loops(text):
+        """The matmuls of each loop body, those of the entry outside
+        the loops, and the lines of entry and bodies."""
+        comps = hlo_computations(text)
+        bodies = re.findall(r" while\(.*body=%([\w.\-]+)", text)
+        entry = re.search(r"^ENTRY %([\w.\-]+)", text, re.M).group(1)
+        return (sorted(matmuls(comps, b) for b in bodies),
+                matmuls(comps, entry),
+                [line for c in (entry, *bodies) for line in comps[c]])
+
+    text, size = compiled(fused)
+    was_text, was_size = compiled(checkpointed)
+    assert loops(was_text)[:2] == ([1, 3], 0)
+    per_loop, outside, lines = loops(text)
+    assert (per_loop, outside) == ([3], 0)
+    assert size < was_size + 0.4e9
+    table = executor.hlo_op_scopes(text)
+    fusions = [m.group(1) for line in lines if " fusion(" in line
+               and (m := executor._HLO_INSTRUCTION.match(line))]
+    assert len(fusions) >= 3
+    assert {table[f] for f in fusions} == {"head_loss"}
+
+
 def kernel_instructions(text: str) -> list:
     """The names of the Pallas custom calls, as a device trace prints
     them first in each event's name."""

@@ -12,6 +12,7 @@ from benchmarks import weights_hybrid
 from dlnetbench_tpu import ops
 from dlnetbench_tpu.core.model_card import load_model_card
 from dlnetbench_tpu.models import bench_step, hybrid
+from dlnetbench_tpu.models import layers as L
 
 KINDS = ("mamba", "window", "mamba", "window", "mamba", "full", "gmu",
          "cross", "gmu", "cross")
@@ -235,3 +236,73 @@ def test_card_states_the_pattern_and_config_follows_it():
         hybrid.HybridConfig.from_card(card, layer_kinds=("window", "cross"))
     with pytest.raises(ValueError, match="states no layer_kinds"):
         hybrid.HybridConfig.from_card(load_model_card("minerva_7b"))
+
+
+def head_case(dtype, rows=256, d=64, v=512, seed=0):
+    kx, kt, ky = jax.random.split(jax.random.key(seed), 3)
+    x = jax.random.normal(kx, (rows, d), jnp.float32)
+    table = jax.random.normal(kt, (v, d), jnp.float32) / math.sqrt(d)
+    targets = jax.random.randint(ky, (rows,), 0, v)
+    return x.astype(dtype), table.astype(dtype), targets
+
+
+def whole_head(x, table, targets):
+    return L.cross_entropy(jnp.dot(x, table.T), targets)
+
+
+def checkpointed_head(x, table, targets, block):
+    """The blocked head as it was before the fused form: every block's
+    logits made again in the backward."""
+    xb = x.reshape(-1, block, x.shape[-1])
+    part = jax.checkpoint(lambda xt: whole_head(xt[0], table, xt[1]))
+    return jnp.mean(jax.lax.map(part, (xb, targets.reshape(-1, block))))
+
+
+def rel(got, want):
+    return float(jnp.linalg.norm(got.astype(jnp.float32) - want)
+                 / jnp.linalg.norm(want))
+
+
+@pytest.mark.parametrize("blocks", [1, 2, 4])
+def test_blocked_head_loss_and_gradients_against_whole_logits(blocks):
+    x, table, targets = head_case(jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want, (wx, wt) = jax.value_and_grad(whole_head, (0, 1))(
+            x, table, targets)
+        loss, (gx, gt) = jax.jit(jax.value_and_grad(
+            lambda a, b: L.blocked_head_cross_entropy(
+                a, b, targets, x.shape[0] // blocks), (0, 1)))(x, table)
+    assert abs(float(loss) - float(want)) < 1e-5 * float(want)
+    assert rel(gx, wx) < 1e-5 and rel(gt, wt) < 1e-5
+
+
+@pytest.mark.parametrize("cotangent", [3.0, -0.5])
+def test_blocked_head_scales_its_gradients_by_the_cotangent(cotangent):
+    x, table, targets = head_case(jnp.float32)
+
+    def grads(scale):
+        return jax.grad(lambda a, b: scale * L.blocked_head_cross_entropy(
+            a, b, targets, 64), (0, 1))(x, table)
+    for got, one in zip(grads(cotangent), grads(1.0)):
+        assert rel(got, cotangent * one) < 1e-6
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_blocked_head_in_bf16_is_no_further_from_float32_than_before(seed):
+    """Same operands, same blocks: the logits' gradient is rounded to
+    bf16 once where autodiff rounded the softmax and then added the
+    target's -1/rows in bf16, and a block's share of the table's
+    gradient joins the carry in float32: the table's gradient comes
+    closer to the float32 one, x's stays where it was."""
+    x, table, targets = head_case(jnp.bfloat16, seed=seed)
+    with jax.default_matmul_precision("highest"):
+        want = jax.grad(whole_head, (0, 1))(
+            x.astype(jnp.float32), table.astype(jnp.float32), targets)
+    fused = jax.grad(lambda a, b: L.blocked_head_cross_entropy(
+        a, b, targets, 64), (0, 1))(x, table)
+    before = jax.grad(lambda a, b: checkpointed_head(a, b, targets, 64),
+                      (0, 1))(x, table)
+    (gx, gt), (ox, ot), (wx, wt) = fused, before, want
+    assert gx.dtype == gt.dtype == jnp.bfloat16
+    assert rel(gt, wt) < rel(ot, wt)
+    assert rel(gx, wx) < rel(ox, wx) * 1.02
