@@ -107,6 +107,13 @@ class Sizes:
     h_seq: int = 512
     h_k: int = 3
     h_lr: float = 0.1
+    # latent_moe: the published head widths (192-lane scores beside
+    # 128-lane values), half the experts held
+    l_shape: dict = dataclasses.field(default_factory=lambda: dict(
+        hidden_size=256, num_attention_heads=2, intermediate_size=512,
+        moe_intermediate_size=128, kv_lora_rank=128, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, vocab_size=512))
+    l_seq: int = 512
     # four chips
     c4_fsdp_model: str = "llama3_8b_16_bfloat16"
     c4_fsdp_scale: float = 0.125
@@ -140,6 +147,11 @@ TINY = Sizes(
     h_shape=dict(_TINY_SHAPE, ssm_inner=128, ssm_state=16, ssm_conv=4,
                  ssm_dt_rank=4, sliding_window=16),
     h_seq=128,
+    l_shape=dict(hidden_size=64, num_attention_heads=4,
+                 intermediate_size=128, moe_intermediate_size=32,
+                 kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                 v_head_dim=16, vocab_size=256),
+    l_seq=128,
     c4_fsdp_scale=1e-5, c4_h3d_scale=1e-5, c4_time_scale=1e-4, c4_batch=4,
     c4_seq=128,
 )
@@ -907,6 +919,93 @@ def phase_hybrid(sz: Sizes) -> dict:
                       "attention kernels compiled in"}
 
 
+# ------------------------------------------------------ phase: latent_moe
+
+def phase_latent_moe(sz: Sizes) -> dict:
+    """The latent-attention expert decoder (models/hybrid.py: ``mla``
+    layers, a dense layer and then routed experts beside a shared one,
+    4 of the router's 8 experts held) through the same step builder and
+    executor as ``phase_train``, with the attention kernels forced at
+    their two widths, against the benchmark's plain float32 reference of
+    the same model and the same share on the same seeded weights."""
+    import jax
+
+    from benchmarks import reference_latent_moe, weights_latent_moe
+    from benchmarks.runners import train_latent_moe
+    from dlnetbench_tpu.core import executor
+    from dlnetbench_tpu.models import bench_step
+
+    config = {
+        **sz.l_shape, "num_hidden_layers": 3, "first_k_dense_replace": 1,
+        "n_routed_experts": 4, "published": {"n_routed_experts": 8},
+        "num_experts_per_tok": 3, "n_shared_experts": 2,
+        "routed_scaling_factor": 2.446, "rms_norm_eps": 1e-5,
+        "rope_theta": 800000, "q_lora_rank": None, "n_group": 1,
+        "topk_group": 1, "rope_scaling": None, "moe_layer_freq": 1,
+        "scoring_func": "sigmoid", "norm_topk_prob": True,
+        "tie_word_embeddings": False, "attention_bias": False,
+        "torch_dtype": sz.dtype,
+        "assumed": {"first_held_expert": 2, "router_bias_scale": 0.1}}
+    arch = weights_latent_moe.arch_of(config)
+    slots = 2 * sz.l_seq        # every row of the batch: no bound to reach
+    cfg = train_latent_moe.config_of(
+        arch, sz.l_seq, slots, remat=True, attention_impl="flash",
+        loss_row_block=sz.l_seq)
+
+    def make_params():
+        return weights_latent_moe.make_params(arch, sz.seed)
+    tokens = weights_latent_moe.make_token_pool(
+        sz.seed, 1, 2, sz.l_seq + 1, arch["vocab_size"])[0]
+    want = reference_latent_moe.sgd_steps(
+        make_params, [tokens] * sz.h_k, arch, sz.h_lr)
+    prog = executor.CompiledProgram(executor.Program(
+        fn=bench_step.make_train_k(cfg, sz.h_k, sz.h_lr),
+        args=(make_params(), tokens),
+        donate_argnums=bench_step.DONATE_ARGNUMS))
+    kernels = prog.as_text().count("tpu_custom_call")
+    if on_tpu():
+        # three attention kernels a layer, three grouped matmuls an
+        # expert layer, each at least once
+        require(kernels >= 6, f"compiled latent-attention step holds "
+                              f"{kernels} tpu_custom_call")
+    _, (losses, routing) = jax.block_until_ready(prog())
+    got = [float(v) for v in losses]
+    require(int(routing["past_bound"].sum()) == 0
+            and int(routing["routed"][0]) > 0,
+            f"latent-attention step: routing {routing}")
+    gap = train_latent_moe.selection_gap(
+        jax.device_get(routing["choices"][0]), want["chosen"])
+    tol_first, tol_drop, tol_gap = 0.02, 0.35, 0.05
+    require(abs(got[0] - want["losses"][0])
+            <= tol_first * want["losses"][0],
+            f"latent-attention step: first loss {got[0]} vs float32 "
+            f"reference {want['losses'][0]} (tolerance {tol_first})")
+    drop_got = got[0] - got[-1]
+    drop_want = want["losses"][0] - want["losses"][-1]
+    require(drop_want > 0 and abs(drop_got - drop_want)
+            <= tol_drop * drop_want,
+            f"latent-attention step: loss fell {drop_got} over "
+            f"{sz.h_k} steps, reference {drop_want} (tolerance "
+            f"{tol_drop} relative)")
+    require(gap <= tol_gap, f"latent-attention step: {gap} of the "
+                            f"(token, choice) pairs differ from the "
+                            f"reference's (tolerance {tol_gap})")
+    return {"shapes": {**sz.l_shape, "seq": sz.l_seq, "batch": 2,
+                       "experts": 8, "held": [2, 4], "top_k": 3,
+                       "slots": slots, "steps": sz.h_k, "lr": sz.h_lr},
+            "tpu_custom_calls": kernels,
+            "memory_analysis": prog.memory_analysis,
+            "losses": [round(v, 4) for v in got],
+            "float32_losses": [round(v, 4) for v in want["losses"]],
+            "rows_routed_to_held": int(routing["routed"][0]),
+            "selection_gap": gap,
+            "checks": "first loss, the loss's fall and the selections "
+                      "within tolerance of "
+                      "benchmarks/reference_latent_moe.py; no row past "
+                      "the bound; attention and expert kernels compiled "
+                      "in"}
+
+
 # ------------------------------------------------------ four-chip phases
 
 def all_device_ids() -> set:
@@ -1113,7 +1212,8 @@ def phase_kv_shard(sz: Sizes) -> dict:
 
 ONE_CHIP = (("kernels", phase_kernels), ("train", phase_train),
             ("proxy", phase_proxy), ("serve", phase_serve),
-            ("moe", phase_moe), ("hybrid", phase_hybrid))
+            ("moe", phase_moe), ("hybrid", phase_hybrid),
+            ("latent_moe", phase_latent_moe))
 FOUR_CHIPS = (("mesh_proxies", phase_mesh_proxies), ("spmd", phase_spmd),
               ("kv_shard", phase_kv_shard))
 

@@ -30,6 +30,14 @@ _CARD_DIR = Path(__file__).resolve().parent.parent / "data" / "models"
 class MoEParams:
     num_experts: int
     num_experts_per_tok: int
+    # --- extended fields (defaults are the Mixtral family's) ---
+    scoring: str = "softmax"        # the gate: softmax over the top-k
+                                    # logits, or "sigmoid" scores with a
+                                    # selection bias, normalised, scaled
+    routed_scale: float = 1.0       # times the normalised weights
+    shared_experts: int = 0         # experts every token passes through
+    expert_ff_dim: int = 0          # an expert's width; 0 => ff_dim
+    first_dense_layers: int = 0     # leading layers with a dense FFN
 
 
 @dataclasses.dataclass(frozen=True)
@@ -61,6 +69,17 @@ class ModelCard:
     ssm_state: int = 0              # state size N a channel
     ssm_conv: int = 0               # depthwise causal conv width
     ssm_dt_rank: int = 0            # rank of the step projection
+    # latent attention (an "mla" layer, models/hybrid.py): keys and
+    # values expanded from one low-rank row a token, score heads of
+    # qk_nope + qk_rope lanes beside value heads of v_head_dim
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_theta: float = 0.0         # 0 => layers.rope's default
+    rms_norm: bool = False          # a layer_kinds model's norm: RMSNorm
+                                    # without bias (else LayerNorm)
+    norm_eps: float = 0.0           # 0 => the model family's default
 
     # ------------------------------------------------------------------ #
     @property
@@ -101,9 +120,14 @@ class ModelCard:
         d, dkv = self.embed_dim, self.kv_dim
         return d * d + 2 * d * dkv + d * d  # Wq, Wk, Wv, Wo
 
-    def mlp_params_per_expert(self) -> int:
+    def mlp_params_per_expert(self, width: int = 0) -> int:
+        """One MLP of ``width`` (the dense ``ff_dim`` by default)."""
         n_mat = 3 if self.gated_mlp else 2
-        return n_mat * self.embed_dim * self.ff_dim
+        return n_mat * self.embed_dim * (width or self.ff_dim)
+
+    def routed_expert_params(self) -> int:
+        moe = self.moe_params
+        return self.mlp_params_per_expert(moe.expert_ff_dim)
 
     def mixer_params(self, kind: str) -> int:
         """Parameters of one ``layer_kinds`` mixer (norms left out)."""
@@ -116,15 +140,33 @@ class ModelCard:
             return 2 * d * e
         if kind == "cross":
             return 2 * d * d            # queries and output only
+        if kind == "mla":
+            h, r = self.num_heads, self.kv_lora_rank
+            qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+            return (d * h * qk + d * (r + self.qk_rope_head_dim) + r
+                    + r * h * (self.qk_nope_head_dim + self.v_head_dim)
+                    + h * self.v_head_dim * d)
         return self.attn_params_per_layer()
+
+    def ffn_params(self, li: int) -> int:
+        """Parameters of layer ``li``'s FFN in a ``layer_kinds`` model:
+        dense, or routed and shared experts with their router."""
+        d, moe = self.embed_dim, self.moe_params
+        if moe is None or li < moe.first_dense_layers:
+            return self.mlp_params_per_expert()
+        experts = moe.num_experts + moe.shared_experts
+        bias = moe.num_experts if moe.scoring == "sigmoid" else 0
+        return (experts * self.routed_expert_params()
+                + d * moe.num_experts + bias)
 
     def num_params(self) -> int:
         """Analytic total parameter count (biases/norms included coarsely)."""
         d = self.embed_dim
         if self.layer_kinds:
-            per_block = self.mlp_params_per_expert() + 4 * d
-            total = sum(self.mixer_params(k) + per_block
-                        for k in self.layer_kinds) + 2 * d
+            norms = 2 * d if self.rms_norm else 4 * d
+            total = sum(self.mixer_params(k) + self.ffn_params(li) + norms
+                        for li, k in enumerate(self.layer_kinds)) \
+                + norms // 2
             return total + self.vocab_size * d * (
                 1 if self.tied_embeddings else 2)
         per_layer = self.attn_params_per_layer() + 2 * d  # + two norms
@@ -153,18 +195,16 @@ class ModelCard:
         ``Non_Expert_size:0`` convention."""
         if not self.is_moe:
             return 0
-        return self.num_params() - self.num_layers * self.num_experts * \
-            self.mlp_params_per_expert()
+        layers = self.num_layers - self.moe_params.first_dense_layers
+        return self.num_params() - layers * self.num_experts * \
+            self.routed_expert_params()
 
 
 # ---------------------------------------------------------------------- #
 def _parse_card(name: str, raw: dict) -> ModelCard:
     moe = None
     if "moe_params" in raw:
-        moe = MoEParams(
-            num_experts=int(raw["moe_params"]["num_experts"]),
-            num_experts_per_tok=int(raw["moe_params"]["num_experts_per_tok"]),
-        )
+        moe = MoEParams(**raw["moe_params"])
     known = {f.name for f in dataclasses.fields(ModelCard)}
     kwargs = {k: v for k, v in raw.items() if k in known and k != "moe_params"}
     if "layer_kinds" in kwargs:

@@ -5,7 +5,8 @@ python/download_models.py:21-36 registry, :41-109 download logic), rethought
 for this framework: what every downstream layer consumes is the
 *architecture card* (core/model_card.py), so the useful artifact of "import
 a HF model" is a card, not a cache of safetensors.  This module maps a HF
-config (``model_type`` gpt2 / llama / mistral / mixtral / phi4flash / vit) onto
+config (``model_type`` gpt2 / llama / mistral / mixtral / phi4flash /
+deepseek_v3 as Kimi-VL and Moonlight state it / vit) onto
 ``ModelCard`` fields and writes the card JSON.
 
 Offline-first: hub access is attempted only when requested and is never
@@ -56,7 +57,11 @@ def card_from_hf_config(name: str, cfg: Mapping[str, Any] | Any) -> ModelCard:
     """
     if hasattr(cfg, "to_dict"):
         cfg = cfg.to_dict()
+    if cfg.get("model_type") == "kimi_vl":   # the language model's part
+        cfg = cfg["text_config"]
     mt = cfg.get("model_type", "")
+    if mt == "deepseek_v3" or (not mt and "kv_lora_rank" in cfg):
+        return _latent_moe_card(name, cfg)
 
     if mt == "gpt2":
         n_embd = int(cfg["n_embd"])
@@ -163,6 +168,55 @@ def _phi4flash_card(name: str, cfg: Mapping[str, Any]) -> ModelCard:
     )
 
 
+def _latent_moe_card(name: str, cfg: Mapping[str, Any]) -> ModelCard:
+    """``model_type: "deepseek_v3"`` as Kimi-VL-A3B and Moonlight state
+    it (a ``config.json`` whose language model has ``kv_lora_rank``):
+    latent attention in every layer, ``first_k_dense_replace`` dense
+    layers and then routed experts beside shared ones, a sigmoid gate
+    with a selection bias.  Refused, because no layer here computes
+    them: a low-rank query (``q_lora_rank``), grouped selection
+    (``n_group`` > 1), scaled RoPE and an expert layer every other
+    layer (``moe_layer_freq`` > 1)."""
+    unsupported = {k: cfg.get(k) for k, ok in (
+        ("q_lora_rank", (None,)), ("n_group", (None, 1)),
+        ("topk_group", (None, 1)), ("rope_scaling", (None,)),
+        ("moe_layer_freq", (None, 1))) if cfg.get(k) not in ok}
+    if unsupported:
+        raise ValueError(f"{name}: latent-attention import has no "
+                         f"{unsupported}")
+    layers = int(cfg["num_hidden_layers"])
+    return ModelCard(
+        name=name,
+        embed_dim=int(cfg["hidden_size"]),
+        num_heads=int(cfg["num_attention_heads"]),
+        num_kv_heads=int(cfg.get("num_key_value_heads")
+                         or cfg["num_attention_heads"]),
+        ff_dim=int(cfg["intermediate_size"]),
+        seq_len=int(cfg["max_position_embeddings"]),
+        num_decoder_blocks=layers,
+        vocab_size=int(cfg["vocab_size"]),
+        gated_mlp=True,
+        tied_embeddings=bool(cfg.get("tie_word_embeddings", False)),
+        layer_kinds=("mla",) * layers,
+        kv_lora_rank=int(cfg["kv_lora_rank"]),
+        qk_nope_head_dim=int(cfg["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(cfg["qk_rope_head_dim"]),
+        v_head_dim=int(cfg["v_head_dim"]),
+        rope_theta=float(cfg.get("rope_theta", 10000.0)),
+        rms_norm=True,
+        norm_eps=float(cfg.get("rms_norm_eps", 1e-6)),
+        moe_params=MoEParams(
+            num_experts=int(cfg["n_routed_experts"]),
+            num_experts_per_tok=int(cfg["num_experts_per_tok"]),
+            scoring=str(cfg.get("scoring_func", "softmax")),
+            routed_scale=float(cfg.get("routed_scaling_factor", 1.0)),
+            shared_experts=int(cfg.get("n_shared_experts") or 0),
+            expert_ff_dim=int(cfg["moe_intermediate_size"]),
+            first_dense_layers=int(cfg.get("first_k_dense_replace", 0)),
+        ),
+    )
+
+
 def card_to_json(card: ModelCard) -> dict:
     """Card -> the on-disk JSON schema (reference models/*.json shape plus
     the rebuild's extended fields; zero/False/None fields are elided)."""
@@ -174,10 +228,13 @@ def card_to_json(card: ModelCard) -> dict:
         if v:
             out[f.name] = list(v) if isinstance(v, tuple) else v
     if card.moe_params is not None:
+        defaults = MoEParams(0, 0)
         out["moe_params"] = {
-            "num_experts": card.moe_params.num_experts,
-            "num_experts_per_tok": card.moe_params.num_experts_per_tok,
-        }
+            f.name: getattr(card.moe_params, f.name)
+            for f in dataclasses.fields(MoEParams)
+            if f.name in ("num_experts", "num_experts_per_tok")
+            or getattr(card.moe_params, f.name)
+            != getattr(defaults, f.name)}
     return out
 
 

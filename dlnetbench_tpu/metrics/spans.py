@@ -52,8 +52,8 @@ from pathlib import Path
 # them through ``scope()``, the executor's op->scope table and the
 # benchmark's per-layer readers take them from here.
 SCOPES = ("embed", "attn", "mlp", "moe.router", "moe.dispatch",
-          "moe.experts", "moe.combine", "ssm", "ssm.scan", "gmu",
-          "head_loss", "optimizer")
+          "moe.experts", "moe.combine", "moe.shared", "ssm", "ssm.scan",
+          "gmu", "head_loss", "optimizer")
 OTHER_SCOPE = "other"   # an instruction under none of them
 
 

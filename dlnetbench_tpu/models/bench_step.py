@@ -71,7 +71,10 @@ def make_train_k(cfg, k: int, lr: float = 1e-3):
     host latency that a real training loop, which keeps the device
     queue full, never serializes on; chaining K steps amortises it, so
     the reading is the DEVICE's.  ``train_k(params, tokens)`` returns
-    ``(params, losses[k])``.
+    ``(params, losses[k])``; for a model with expert layers
+    ``(params, (losses[k], routing))``, each entry of ``routing``
+    (``models/hybrid.ROUTING``: three counters and the selections)
+    with a leading [k].
 
     ``lr`` is the SGD step: at the bench's 1e-3 the bf16 weights barely
     move (the headline measures time); a caller that wants to see the
@@ -82,7 +85,9 @@ def make_train_k(cfg, k: int, lr: float = 1e-3):
 
     # the one step builder for every model family: a config names its
     # model by its type
-    loss_fn = (hybrid.loss_fn if isinstance(cfg, hybrid.HybridConfig)
+    counts = isinstance(cfg, hybrid.HybridConfig) and cfg.has_experts
+    loss_fn = (hybrid.loss_and_routing if counts
+               else hybrid.loss_fn if isinstance(cfg, hybrid.HybridConfig)
                else tfm.loss_fn)
 
     def sgd(p, g):
@@ -92,7 +97,8 @@ def make_train_k(cfg, k: int, lr: float = 1e-3):
 
     def train_k(p, t):
         def body(p, _):
-            loss, g = jax.value_and_grad(loss_fn)(p, t, cfg)
+            loss, g = jax.value_and_grad(loss_fn, has_aux=counts)(
+                p, t, cfg)
             return sgd(p, g), loss
         return jax.lax.scan(body, p, None, length=k)
     return train_k

@@ -1,8 +1,12 @@
-"""Hybrid decoder with a per-layer pattern of mixers (the SambaY family,
-arXiv:2507.06607): Mamba, sliding-window, full and cross attention and
-gated memory units in one stack, differential attention
-(arXiv:2410.05258) in every attention layer, LayerNorm with bias around a
-SwiGLU MLP, no positional encoding, the embedding tied to the head.
+"""Decoder with a per-layer pattern of mixers and of FFNs.  Two families
+run through it: the SambaY hybrid (arXiv:2507.06607: Mamba,
+sliding-window, full and cross attention and gated memory units in one
+stack, differential attention (arXiv:2410.05258) in every attention
+layer, LayerNorm with bias around a SwiGLU MLP, no positional encoding,
+the embedding tied to the head) and the latent-attention expert models
+of the DeepSeek-V3 family as Kimi-VL-A3B and Moonlight state them (an
+``mla`` mixer in every layer, RMSNorm, a leading dense layer and then
+routed experts beside shared ones, an untied head).
 
 ``HybridConfig.layer_kinds`` names each layer's mixer, one of ``KINDS``:
 
@@ -15,10 +19,22 @@ SwiGLU MLP, no positional encoding, the embedding tied to the head.
 * ``gmu``    — gated memory unit: ``(m * silu(y W1)) W2``.
 * ``cross``  — attention with queries of its own over the ``full``
   layer's keys and values (causal), so their gradients sum back there.
+* ``mla``    — latent attention: keys without position and values are
+  expanded from one normed low-rank row a token, one RoPE key head is
+  shared by all heads, and the scores run over ``qk_nope + qk_rope``
+  lanes beside values of ``v_head_dim`` (``ops.attention`` with two
+  widths); plain softmax, no differential pairing.
 
-Every layer is ``x += mixer(LN(x)); x += SwiGLU(LN(x))``.  Parameters are
-stacked by kind (``block`` holds what every layer has: both norms and
-the MLP), layers are unrolled as the bench recipe unrolls them, and
+Every layer is ``x += mixer(norm(x)); x += ffn(norm(x))``.
+``ffn_kinds`` names each layer's FFN: ``dense`` (SwiGLU at ``ff_dim``)
+or ``moe``: ``sum_i w_i expert_i(y)`` over the token's top-k of
+``num_experts`` routed experts, of which this chip holds
+``held_experts`` (``models/moe.moe_held``: the router scores and
+selects over all of them, no token is dropped), plus one shared SwiGLU
+at ``shared_ff_dim`` that every token passes through.  Parameters are
+stacked by kind (``block`` holds both norms of every layer and the MLP
+of every dense one; ``moe`` the expert layers' router, routed and shared
+experts), layers are unrolled as the bench recipe unrolls them, and
 ``remat`` checkpoints each layer.
 
 Differential attention runs over the kernels ``ops.attention`` already
@@ -41,20 +57,29 @@ from dlnetbench_tpu import ops
 from dlnetbench_tpu.core.model_card import ModelCard
 from dlnetbench_tpu.metrics.spans import scope
 from dlnetbench_tpu.models import layers as L
+from dlnetbench_tpu.models.moe import moe_held
 from dlnetbench_tpu.ops.attention_mask import MaskSpec
 from dlnetbench_tpu.ops.selective_scan import selective_scan
 
 _F32 = jnp.float32
-KINDS = ("mamba", "window", "full", "gmu", "cross")
+KINDS = ("mamba", "window", "full", "gmu", "cross", "mla")
+FFN_KINDS = ("dense", "moe")
 # which stack of parameters a layer's mixer reads
 GROUP_OF = {"mamba": "mamba", "window": "attn", "full": "attn",
-            "gmu": "gmu", "cross": "cross"}
+            "gmu": "gmu", "cross": "cross", "mla": "mla"}
+# what a step with expert layers returns beside its loss
+# (``models/moe.moe_held``): three counters over its expert layers (the
+# rows routed to held experts and the rows past the bound summed, the
+# largest load of one expert) and every layer's selection
+COUNTERS = ("routed", "max_load", "past_bound")
+ROUTING = (*COUNTERS, "choices")
 # leaves kept in float32 whatever the model's dtype (the family's
 # convention: the recurrence's own parameters, lambdas and norms)
 F32_LEAVES = frozenset({
     "norm1", "norm1_b", "norm2", "norm2_b", "final_norm", "final_norm_b",
     "a_log", "d_skip", "b_dt", "conv_b", "sub_norm",
-    "lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2"})
+    "lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2",
+    "kv_norm", "router_bias"})
 _SPLASH_BLOCKS = (2048, 1024, 512, 256, 128)
 
 
@@ -82,14 +107,58 @@ class HybridConfig:
                                     # taken with its loss, so that
                                     # [T, V] logits never lie whole in
                                     # HBM; 0 = whole
+    rms_norm: bool = False          # RMSNorm without bias, else LayerNorm
+    tied_head: bool = True          # the head is the embedding table
+    # latent attention ("mla" layers)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_theta: float = 10000.0
+    # the FFN of each layer; () = every layer dense
+    ffn_kinds: tuple = ()
+    num_experts: int = 0            # the router's outputs
+    top_k: int = 0
+    expert_ff_dim: int = 0
+    shared_ff_dim: int = 0          # 0 = no shared expert
+    router_scoring: str = "softmax"  # layers.moe_router's gate
+    routed_scale: float = 1.0
+    held_experts: tuple = ()        # (first, count) of the routed experts
+                                    # this chip holds; () = all of them
+    moe_slots: int = 0              # rows a held expert's buffer has: a
+                                    # bound the load is not to reach
 
     def __post_init__(self):
         kinds = tuple(self.layer_kinds)
         object.__setattr__(self, "layer_kinds", kinds)
         if not kinds or set(kinds) - set(KINDS):
             raise ValueError(f"layer_kinds {kinds} must name {KINDS}")
-        if self.num_heads % 2 or self.num_kv_heads % 2 \
-                or self.num_heads % self.num_kv_heads:
+        ffn = tuple(self.ffn_kinds) or ("dense",) * len(kinds)
+        object.__setattr__(self, "ffn_kinds", ffn)
+        if len(ffn) != len(kinds) or set(ffn) - set(FFN_KINDS):
+            raise ValueError(f"ffn_kinds {ffn} must name {FFN_KINDS}, "
+                             f"one a layer")
+        if "moe" in ffn:
+            held = tuple(self.held_experts) or (0, self.num_experts)
+            object.__setattr__(self, "held_experts", held)
+            if not (0 <= held[0] and held[1] > 0
+                    and sum(held) <= self.num_experts
+                    and 0 < self.top_k <= self.num_experts
+                    and self.moe_slots > 0 and self.expert_ff_dim > 0):
+                raise ValueError(
+                    f"expert layers need num_experts, top_k, "
+                    f"expert_ff_dim, moe_slots and held_experts "
+                    f"{held} within the {self.num_experts} experts")
+        if "mla" in kinds and not (self.kv_lora_rank
+                                   and self.qk_nope_head_dim
+                                   and self.qk_rope_head_dim % 2 == 0
+                                   and self.v_head_dim):
+            raise ValueError("mla layers need kv_lora_rank, "
+                             "qk_nope_head_dim, an even qk_rope_head_dim "
+                             "and v_head_dim")
+        if set(kinds) & {"window", "full", "cross"} and (
+                self.num_heads % 2 or self.num_kv_heads % 2
+                or self.num_heads % self.num_kv_heads):
             raise ValueError("differential attention pairs adjacent "
                              "heads: head counts must be even")
         first = {k: kinds.index(k) for k in set(kinds)}
@@ -109,14 +178,36 @@ class HybridConfig:
         if not card.layer_kinds:
             raise ValueError(f"{card.name} states no layer_kinds; use "
                              f"models.transformer")
+        kinds = tuple(layer_kinds or card.layer_kinds)
+        stated = {"rms_norm": card.rms_norm,
+                  "tied_head": card.tied_embeddings,
+                  "kv_lora_rank": card.kv_lora_rank,
+                  "qk_nope_head_dim": card.qk_nope_head_dim,
+                  "qk_rope_head_dim": card.qk_rope_head_dim,
+                  "v_head_dim": card.v_head_dim}
+        if card.rope_theta:
+            stated["rope_theta"] = card.rope_theta
+        if card.norm_eps:
+            stated["norm_eps"] = card.norm_eps
+        if (moe := card.moe_params) is not None:
+            width = moe.expert_ff_dim or card.ff_dim
+            stated.update(
+                ffn_kinds=tuple(
+                    "dense" if li < moe.first_dense_layers else "moe"
+                    for li in range(len(kinds))),
+                num_experts=moe.num_experts,
+                top_k=moe.num_experts_per_tok, expert_ff_dim=width,
+                shared_ff_dim=moe.shared_experts * width,
+                router_scoring=moe.scoring,
+                routed_scale=moe.routed_scale)
         return cls(vocab_size=card.vocab_size, embed_dim=card.embed_dim,
                    num_heads=card.num_heads, num_kv_heads=card.kv_heads,
-                   ff_dim=card.ff_dim,
-                   layer_kinds=tuple(layer_kinds or card.layer_kinds),
+                   ff_dim=card.ff_dim, layer_kinds=kinds,
                    seq_len=seq_len or card.seq_len,
                    ssm_inner=card.ssm_inner, ssm_state=card.ssm_state,
                    ssm_conv=card.ssm_conv, ssm_dt_rank=card.ssm_dt_rank,
-                   attention_window=card.sliding_window, **over)
+                   attention_window=card.sliding_window,
+                   **{**stated, **over})
 
     @property
     def num_layers(self) -> int:
@@ -145,8 +236,17 @@ class HybridConfig:
         return sum(1 for k in self.layer_kinds[:li]
                    if GROUP_OF[k] == group)
 
+    def index_in_ffn(self, li: int) -> int:
+        """Layer ``li``'s place among the layers with its kind of FFN
+        (``li`` itself where every layer is dense)."""
+        return self.ffn_kinds[:li].count(self.ffn_kinds[li])
+
+    @property
+    def has_experts(self) -> bool:
+        return "moe" in self.ffn_kinds
+
     def group_sizes(self) -> dict:
-        out = {g: 0 for g in ("mamba", "attn", "gmu", "cross")}
+        out = {g: 0 for g in ("mamba", "attn", "gmu", "cross", "mla")}
         for k in self.layer_kinds:
             out[GROUP_OF[k]] += 1
         return out
@@ -170,18 +270,39 @@ def param_shapes(cfg: HybridConfig) -> dict:
     nl = cfg.num_layers
     sizes = cfg.group_sizes()
     s_d = 1.0 / math.sqrt(d)
+    nd = cfg.ffn_kinds.count("dense")
     out = {
-        "embed": ((v, d), s_d),      # tied: the table is the head too
         "final_norm": ((d,), "ones"),
-        "final_norm_b": ((d,), "zeros"),
         "block/norm1": ((nl, d), "ones"),
-        "block/norm1_b": ((nl, d), "zeros"),
         "block/norm2": ((nl, d), "ones"),
-        "block/norm2_b": ((nl, d), "zeros"),
-        "block/w_gate": ((nl, d, f), s_d),
-        "block/w_up": ((nl, d, f), s_d),
-        "block/w_down": ((nl, f, d), 1.0 / math.sqrt(f)),
     }
+    if cfg.tied_head:
+        out["embed"] = ((v, d), s_d)  # tied: the table is the head too
+    else:
+        out.update({"embed": ((v, d), 1.0), "head": ((v, d), s_d)})
+    if not cfg.rms_norm:
+        out.update({"final_norm_b": ((d,), "zeros"),
+                    "block/norm1_b": ((nl, d), "zeros"),
+                    "block/norm2_b": ((nl, d), "zeros")})
+    if nd:
+        out.update({"block/w_gate": ((nd, d, f), s_d),
+                    "block/w_up": ((nd, d, f), s_d),
+                    "block/w_down": ((nd, f, d), 1.0 / math.sqrt(f))})
+    if (m := nl - nd):
+        x, fe, fs = cfg.num_experts, cfg.expert_ff_dim, cfg.shared_ff_dim
+        held = cfg.held_experts[1]
+        out.update({
+            "moe/w_router": ((m, d, x), s_d),
+            "moe/w_gate": ((m, held, d, fe), s_d),
+            "moe/w_up": ((m, held, d, fe), s_d),
+            "moe/w_down": ((m, held, fe, d), 1.0 / math.sqrt(fe))})
+        if cfg.router_scoring == "sigmoid":
+            out["moe/router_bias"] = ((m, x), "zeros")
+        if fs:
+            out.update({
+                "moe/ws_gate": ((m, d, fs), s_d),
+                "moe/ws_up": ((m, d, fs), s_d),
+                "moe/ws_down": ((m, fs, d), 1.0 / math.sqrt(fs))})
     if (m := sizes["mamba"]):
         out.update({
             "mamba/w_in": ((m, d, 2 * e), s_d),
@@ -210,6 +331,16 @@ def param_shapes(cfg: HybridConfig) -> dict:
     if (m := sizes["gmu"]):
         out.update({"gmu/w1": ((m, d, e), s_d),
                     "gmu/w2": ((m, e, d), 1.0 / math.sqrt(e))})
+    if (m := sizes["mla"]):
+        h, r = cfg.num_heads, cfg.kv_lora_rank
+        dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+        out.update({
+            "mla/wq": ((m, d, h * (dn + dr)), s_d),
+            "mla/w_kva": ((m, d, r + dr), s_d),
+            "mla/kv_norm": ((m, r), "ones"),
+            "mla/w_kvb": ((m, r, h * (dn + dv)), 1.0 / math.sqrt(r)),
+            "mla/wo": ((m, h * dv, d), 1.0 / math.sqrt(h * dv))})
     return out
 
 
@@ -246,8 +377,13 @@ def init_params(key, cfg: HybridConfig) -> dict:
 
 # ------------------------------------------------------------- mixers
 
-def _norm(cfg, x, w, b):
-    return L.layernorm(x, w, b, cfg.norm_eps).astype(x.dtype)
+def _norm(cfg, x, p, name: str):
+    """The model's norm with the weight ``p[name]`` (and, for LayerNorm,
+    the bias ``p[name + "_b"]``)."""
+    if cfg.rms_norm:
+        return L.rmsnorm(x, p[name], cfg.norm_eps).astype(x.dtype)
+    return L.layernorm(x, p[name], p[name + "_b"],
+                       cfg.norm_eps).astype(x.dtype)
 
 
 def _silu(x):
@@ -336,70 +472,150 @@ def diff_attention(cfg: HybridConfig, y, p, kv, li: int, window: bool):
     return jnp.dot(o.reshape(b, s, hq * dh), p["wo"])
 
 
-def _layer(cfg: HybridConfig, li: int, x, bp, mp, memory, kv):
-    """Layer ``li``: returns (x, handed) where ``handed`` is the memory
-    (the memory layer), (k1, k2, V) (the full layer) or None."""
+def mla_mixer(cfg: HybridConfig, y, p):
+    """Latent attention.  ``ckv = y W_kva`` is a token's low-rank row
+    and its one RoPE key head; ``k_nope`` and ``v`` of every head are
+    expanded from the normed row; the scores run over ``[nope | rope]``
+    lanes, scaled by their width, the values keep theirs."""
+    b, s, _ = y.shape
+    h, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    q = jnp.dot(y, p["wq"]).reshape(b, s, h, dn + dr)
+    ckv = jnp.dot(y, p["w_kva"])
+    c = L.rmsnorm(ckv[..., :r], p["kv_norm"], cfg.norm_eps).astype(y.dtype)
+    kv = jnp.dot(c, p["w_kvb"]).reshape(b, s, h, dn + dv)
+    q_rope, k_rope = L.rope(q[..., dn:], ckv[..., None, r:],
+                            jnp.arange(s), cfg.rope_theta)
+    q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :dn], jnp.broadcast_to(k_rope, (b, s, h, dr))], axis=-1)
+    o = ops.attention(q, k, kv[..., dn:], causal=True,
+                      impl=cfg.attention_impl)
+    return jnp.dot(o.reshape(b, s, h * dv), p["wo"])
+
+
+def expert_ffn(cfg: HybridConfig, x, norm, fp):
+    """``x + routed(y) + shared(y)``, ``y = norm(x)``, and the layer's
+    routing (``moe_held``'s).  The norm lies in ``moe.router`` and the
+    residual in ``moe.combine``, as in ``transformer._block``."""
+    b, s, d = x.shape
+    with scope("moe.router"):
+        y = _norm(cfg, x, norm, "norm2")
+    routed, routing = moe_held(
+        y.reshape(b * s, d), fp["w_router"], fp["w_gate"], fp["w_up"],
+        fp["w_down"], cfg.top_k, held=cfg.held_experts,
+        slots=cfg.moe_slots, scoring=cfg.router_scoring,
+        bias=fp.get("router_bias"), scale=cfg.routed_scale)
+    out = routed.reshape(b, s, d)
+    if cfg.shared_ff_dim:
+        with scope("moe.shared"):
+            out = out + L.swiglu(y, fp["ws_gate"], fp["ws_up"],
+                                 fp["ws_down"])
+    with scope("moe.combine"):
+        return x + out, routing
+
+
+def _layer(cfg: HybridConfig, li: int, x, bp, mp, fp, memory, kv):
+    """Layer ``li``: ``bp`` its norms, ``mp`` its mixer's weights,
+    ``fp`` its FFN's.  Returns (x, handed, routing): ``handed`` is the
+    memory (the memory layer), (k1, k2, V) (the full layer) or None;
+    ``routing`` an expert layer's, else None."""
     kind = cfg.layer_kinds[li]
     handed = None
     if kind == "mamba":
         with scope("ssm"):
-            out, s = mamba_mixer(cfg, _norm(cfg, x, bp["norm1"],
-                                            bp["norm1_b"]), mp)
+            out, s = mamba_mixer(cfg, _norm(cfg, x, bp, "norm1"), mp)
             x = x + out
         if li == cfg.memory_layer:
             handed = s
     elif kind == "gmu":
         with scope("gmu"):
-            x = x + gmu_mixer(_norm(cfg, x, bp["norm1"], bp["norm1_b"]),
-                              memory, mp)
+            x = x + gmu_mixer(_norm(cfg, x, bp, "norm1"), memory, mp)
+    elif kind == "mla":
+        with scope("attn"):
+            x = x + mla_mixer(cfg, _norm(cfg, x, bp, "norm1"), mp)
     else:
         with scope("attn"):
-            y = _norm(cfg, x, bp["norm1"], bp["norm1_b"])
+            y = _norm(cfg, x, bp, "norm1")
             if kind != "cross":
                 kv = project_kv(cfg, y, mp)
             if kind == "full":
                 handed = kv
             x = x + diff_attention(cfg, y, mp, kv, li, kind == "window")
+    if cfg.ffn_kinds[li] == "moe":
+        x, routing = expert_ffn(cfg, x, bp, fp)
+        return x, handed, routing
     with scope("mlp"):
-        y = _norm(cfg, x, bp["norm2"], bp["norm2_b"])
-        x = x + L.swiglu(y, bp["w_gate"], bp["w_up"], bp["w_down"])
-    return x, handed
+        y = _norm(cfg, x, bp, "norm2")
+        x = x + L.swiglu(y, fp["w_gate"], fp["w_up"], fp["w_down"])
+    return x, handed, None
 
 
-def forward(params: dict, tokens, cfg: HybridConfig):
-    """tokens [B, S] -> the last layer's output [B, S, D] (before the
-    final norm; ``loss_fn`` goes on from it)."""
+_MLP = ("w_gate", "w_up", "w_down")
+
+
+def _forward(params: dict, tokens, cfg: HybridConfig):
+    """tokens [B, S] -> (the last layer's output [B, S, D] before the
+    final norm, which ``loss_and_routing`` goes on from; the expert
+    layers' ``ROUTING``, ``choices`` stacked [expert layers, T, k], or
+    {} without expert layers)."""
     with scope("embed"):
         x = params["embed"][tokens]
     layer = _layer
     if cfg.remat:
         layer = jax.checkpoint(_layer, static_argnums=(0, 1))
     memory = kv = None
+    routed = []
     for li, kind in enumerate(cfg.layer_kinds):
-        gi = cfg.index_in_group(li)
-        bp = jax.tree.map(lambda a: a[li], params["block"])
+        gi, fi = cfg.index_in_group(li), cfg.index_in_ffn(li)
+        block = params["block"]
+        bp = {k: a[li] for k, a in block.items() if k not in _MLP}
         mp = jax.tree.map(lambda a: a[gi], params[GROUP_OF[kind]])
-        x, handed = layer(cfg, li, x, bp, mp, memory, kv)
+        if cfg.ffn_kinds[li] == "moe":
+            fp = jax.tree.map(lambda a: a[fi], params["moe"])
+        else:
+            fp = {k: block[k][fi] for k in _MLP}
+        x, handed, routing = layer(cfg, li, x, bp, mp, fp, memory, kv)
         if kind == "mamba" and handed is not None:
             memory = handed
         elif kind == "full":
             kv = handed
-    return x
+        if routing is not None:
+            routed.append(routing)
+    if not routed:
+        return x, {}
+    stacked = {k: jnp.stack([r[k] for r in routed]) for k in ROUTING}
+    return x, {**stacked, "routed": jnp.sum(stacked["routed"]),
+               "max_load": jnp.max(stacked["max_load"]),
+               "past_bound": jnp.sum(stacked["past_bound"])}
+
+
+def forward(params: dict, tokens, cfg: HybridConfig):
+    """tokens [B, S] -> the last layer's output [B, S, D] (before the
+    final norm)."""
+    return _forward(params, tokens, cfg)[0]
+
+
+def loss_and_routing(params: dict, tokens, cfg: HybridConfig):
+    """(next-token cross-entropy on a [B, S+1] token batch, the expert
+    layers' ``ROUTING``).  Where the rows are a multiple of
+    ``cfg.loss_row_block`` the head and the loss run block by block and
+    each block leaves its gradients behind
+    (``layers.blocked_head_cross_entropy``); the final norm is taken
+    over all rows at once either way.  The head is the embedding table
+    (``tied_head``) or a [V, D] table of its own."""
+    x, routing = _forward(params, tokens[:, :-1], cfg)
+    targets = tokens[:, 1:]
+    table = params["embed" if cfg.tied_head else "head"]
+    with scope("head_loss"):
+        x = _norm(cfg, x, params, "final_norm")
+        rows, block = x.shape[0] * x.shape[1], cfg.loss_row_block
+        if not block or rows <= block or rows % block:
+            return L.cross_entropy(jnp.dot(x, table.T), targets), routing
+        return L.blocked_head_cross_entropy(
+            x.reshape(rows, -1), table, targets.reshape(rows),
+            block), routing
 
 
 def loss_fn(params: dict, tokens, cfg: HybridConfig):
-    """Next-token cross-entropy on a [B, S+1] token batch.  Where the
-    rows are a multiple of ``cfg.loss_row_block`` the tied head and the
-    loss run block by block and each block leaves its gradients behind
-    (``layers.blocked_head_cross_entropy``); the final norm is taken
-    over all rows at once either way."""
-    x = forward(params, tokens[:, :-1], cfg)
-    targets = tokens[:, 1:]
-    with scope("head_loss"):
-        x = _norm(cfg, x, params["final_norm"], params["final_norm_b"])
-        rows, block = x.shape[0] * x.shape[1], cfg.loss_row_block
-        if not block or rows <= block or rows % block:
-            return L.cross_entropy(jnp.dot(x, params["embed"].T), targets)
-        return L.blocked_head_cross_entropy(
-            x.reshape(rows, -1), params["embed"], targets.reshape(rows),
-            block)
+    return loss_and_routing(params, tokens, cfg)[0]

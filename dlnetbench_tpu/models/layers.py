@@ -147,14 +147,34 @@ def router_logits(x, w_router):
     return jnp.dot(x.astype(_F32), w_router.astype(_F32))
 
 
-def moe_router(x, w_router, top_k: int):
-    """Token router: returns (weights [T, k], expert indices [T, k]).
-    Softmax over the selected top-k (Mixtral convention)."""
+def moe_router(x, w_router, top_k: int, *, scoring: str = "softmax",
+               bias=None, scale: float = 1.0):
+    """Token router: returns (weights [T, k], expert indices [T, k]),
+    scores and selection in float32.  ``scoring`` is the gate the
+    model's card states (``MoEParams.scoring``):
+
+    * ``"softmax"``: the top-k of the logits, softmax over the selected
+      (Mixtral convention).
+    * ``"sigmoid"``: scores ``s = sigmoid(logits)``; the top-k of
+      ``s + bias``, ``bias`` [E] a per-expert correction that steers
+      the selection only and has no gradient; the weights are ``s``
+      itself at the selected, divided by their sum and times ``scale``
+      (the DeepSeek-V3 family's gate without groups)."""
     with scope("moe.router"):
         logits = router_logits(x, w_router)
-        top_vals, top_idx = jax.lax.top_k(logits, top_k)
-        weights = jax.nn.softmax(top_vals, axis=-1)
-        return weights, top_idx
+        if scoring == "softmax":
+            top_vals, top_idx = jax.lax.top_k(logits, top_k)
+            return jax.nn.softmax(top_vals, axis=-1), top_idx
+        if scoring != "sigmoid":
+            raise ValueError(f"moe_router: unknown scoring {scoring!r} "
+                             f"(softmax | sigmoid)")
+        s = jax.nn.sigmoid(logits)
+        choose = s if bias is None else \
+            s + jax.lax.stop_gradient(bias.astype(_F32))
+        _, top_idx = jax.lax.top_k(choose, top_k)
+        w = jnp.take_along_axis(s, top_idx, axis=-1)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return w * scale, top_idx
 
 
 def moe_dense(x2d, w_router, w_gate, w_up, w_down, top_k: int):
@@ -189,7 +209,8 @@ class MoePlan(NamedTuple):
     where it is not routed there or was dropped at capacity.
     ``src`` [E, C] int32, its inverse: the token in each slot, T (one
     past the last token) in an empty slot.
-    ``idx`` [T, k] int32: the token's experts, as the router chose."""
+    ``idx`` [T, k] int32: the token's experts, as the router chose;
+    E (one past the last) for a choice this plan does not carry."""
     slot: jax.Array
     src: jax.Array
     idx: jax.Array
@@ -293,17 +314,49 @@ def moe_dispatch(x2d, w_router, num_experts: int, top_k: int,
     e = num_experts
     weights, idx = moe_router(x2d, w_router, top_k)         # [T,k] each
     cap = max(1, int(capacity_factor * t * top_k / e))
-
-    with scope("moe.router"):
-        onehot = jax.nn.one_hot(idx, e, dtype=_F32)         # [T, k, E]
-        gate = jnp.sum(onehot * weights[..., None], axis=1)  # [T, E]
-    with scope("moe.dispatch"):
-        routed = jnp.sum(onehot, axis=1).astype(jnp.int32)  # [T, E] 0/1
-        pos = jnp.cumsum(routed, axis=0) - 1                # arrival order
-        keep = (routed > 0) & (pos < cap)
-        plan = moe_plan(idx, pos[None], keep[None], cap)
-        xe = dispatch_rows(x2d, plan)                       # [E, C, d]
+    xe, plan, gate, _ = _dispatch_choices(x2d, weights, idx, e, cap)
     return xe, plan, gate
+
+
+def _dispatch_choices(x2d, weights, idx, n: int, slots: int):
+    """The one dispatch body: the choices ``idx`` [T, k] among ``n``
+    experts (``n`` itself names no expert) with their ``weights``, into
+    ``slots`` rows an expert in arrival order; a choice past ``slots``
+    is left out.  Returns ``(xe [n, slots, d], plan, gate [T, n],
+    load [n])``, ``load`` the rows routed to each, kept or not."""
+    with scope("moe.router"):
+        onehot = jax.nn.one_hot(idx, n, dtype=_F32)         # [T, k, n]
+        gate = jnp.sum(onehot * weights[..., None], axis=1)  # [T, n]
+    with scope("moe.dispatch"):
+        routed = jnp.sum(onehot, axis=1).astype(jnp.int32)  # [T, n] 0/1
+        pos = jnp.cumsum(routed, axis=0) - 1                # arrival order
+        keep = (routed > 0) & (pos < slots)
+        plan = moe_plan(idx, pos[None], keep[None], slots)
+        xe = dispatch_rows(x2d, plan)                       # [n, slots, d]
+        load = jnp.sum(routed, axis=0)
+    return xe, plan, gate, load
+
+
+def moe_dispatch_held(x2d, weights, idx, held: tuple, slots: int):
+    """Dispatch of a routing ``(weights, idx)`` [T, k] over ALL the
+    router's experts to the ``held = (first, count)`` of them that live
+    here, ``slots`` rows an expert, in arrival order.  No capacity rule
+    drops a row: ``slots`` is a bound the load is not to reach, and a
+    row past it is left out AND shows in ``load``, from which the
+    caller (``moe.moe_held``) counts it and fails the step.  A choice
+    whose expert is not held is neither dispatched nor combined: in the
+    plan it carries the index ``count``, one past the held, which
+    ``_chosen`` matches to no expert and ``_from_slots`` sends to the
+    zero fill row.
+
+    Returns ``(xe [count, slots, d], plan, gate [T, count], load)``:
+    the ``moe_dispatch`` contract over the held experts, and ``load``
+    [count] int32, the rows routed to each (kept or not)."""
+    first, n = held
+    with scope("moe.router"):
+        local = idx - first
+        local = jnp.where((local >= 0) & (local < n), local, n)
+    return _dispatch_choices(x2d, weights, local, n, slots)
 
 
 @jax.custom_vjp

@@ -267,6 +267,37 @@ def moe_grouped(x2d, w_router, w_gate, w_up, w_down, top_k: int,
         return L.moe_combine(y.astype(x2d.dtype), plan, gate)
 
 
+def moe_held(x2d, w_router, w_gate, w_up, w_down, top_k: int, *,
+             held: tuple, slots: int, scoring: str = "softmax",
+             bias=None, scale: float = 1.0):
+    """The routed experts' part of an expert layer on a chip that holds
+    ``held = (first, count)`` of the router's experts (``w_gate`` /
+    ``w_up`` / ``w_down`` are stacked over those ``count``): the router
+    scores all of them and takes its top-k over all of them, the rows
+    whose expert lives here go through the grouped kernels, and the
+    result is what those experts add.  No token is dropped: ``slots``
+    bounds an expert's rows and a row past it is counted.
+
+    Returns ``(y [T, d], routing)``; ``routing`` holds int32 scalars,
+    ``routed`` rows routed to held experts, ``max_load`` the largest
+    load of one of them, ``past_bound`` rows left out at ``slots``
+    (a step that reads one has not computed the layer), and
+    ``choices`` [T, k], the router's selection over all its experts."""
+    weights, idx = L.moe_router(x2d, w_router, top_k, scoring=scoring,
+                                bias=bias, scale=scale)
+    xe, plan, gate, load = L.moe_dispatch_held(x2d, weights, idx, held,
+                                               slots)
+    with scope("moe.dispatch"):
+        stats = _routing_stats(x2d, w_router, load[None], plan, slots)
+        routing = {"routed": jnp.sum(stats["routed"]),
+                   "max_load": jnp.max(stats["routed"]),
+                   "past_bound": stats["dropped"], "choices": idx}
+    y = expert_ffn(xe, w_gate, w_up, w_down, impl="grouped",
+                   counts=stats["kept"])
+    with scope("moe.combine"):
+        return L.moe_combine(y.astype(x2d.dtype), plan, gate), routing
+
+
 # ------------------------------------------------------- schedule twin
 def a2a_elems_per_rank(tokens_per_mb: int, top_k: int, embed_dim: int,
                        ep: int) -> int:

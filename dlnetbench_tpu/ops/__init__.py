@@ -22,6 +22,7 @@ from dlnetbench_tpu.ops.flash_attention import (
     flash_attention,
     flash_supported,
     splash_attention,
+    splash_supported,
 )
 from dlnetbench_tpu.ops.xla_attention import xla_attention
 
@@ -46,7 +47,10 @@ def _dense_mask_np(spec: _M.MaskSpec, s: int):
 
 def attention(q, k, v, causal: bool, impl: str = "auto", mask=None,
               block_q: int | None = None, block_k: int | None = None):
-    """q: [B, S, Hq, Dh], k/v: [B, S, Hkv, Dh] -> [B, S, Hq, Dh].
+    """q: [B, S, Hq, Dh], k: [B, S, Hkv, Dh], v: [B, S, Hkv, Dv] ->
+    [B, S, Hq, Dv].  Dv is Dh everywhere but in latent attention, whose
+    scores run over a wider head than its values; the masked
+    (block-sparse) kernels keep one width.
 
     impl: "flash" (Pallas kernel, error if unsupported shape),
     "xla" (einsum reference), or "auto" (flash on TPU when the shape
@@ -84,7 +88,8 @@ def attention(q, k, v, causal: bool, impl: str = "auto", mask=None,
         return flash_attention(q, k, v, causal=causal, **blocks)
     if impl != "auto":
         raise ValueError(f"unknown attention impl {impl!r}")
-    supported = flash_supported(q, k, v)   # raises at S>=64k w/o blocks
+    supported = (flash_supported if mask is None
+                 else splash_supported)(q, k, v)  # raises at S>=64k w/o blocks
     if (jax.default_backend() == "tpu" and s >= _AUTO_MIN_SEQ
             and supported):
         if mask is not None:

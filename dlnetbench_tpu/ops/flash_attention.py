@@ -22,6 +22,13 @@ Design (TPU-first, not a port — the reference has no kernels at all):
 * Head dims that are not lane-aligned (e.g. gpt2's 64) are zero-padded
   to 128 in the wrapper; padding columns contribute nothing to scores and
   are sliced off the outputs, so numerics are unchanged.
+* The dense kernels take a query/key width that differs from the value
+  width (latent attention: scores over 192, values of 128): each is
+  padded to its own multiple of the lane tile (``_padded``), the scores
+  contract the one and ``P V`` the other, so the values are never
+  widened to the keys' width.  On the MXU a contraction runs in passes
+  of 128, so 192 padded to 256 costs the two passes that 192 costs.
+  The splash kernels keep one width.
 
 On non-TPU backends the same kernels run under ``interpret=True`` so the
 whole path is unit-testable on the CPU mesh (tests/test_flash_attention.py
@@ -41,6 +48,7 @@ from dlnetbench_tpu.ops import pallas_common
 
 _F32 = pallas_common.F32
 _LANES = pallas_common.LANES  # TPU lane width; head dim padded to this
+_MAX_HEAD_DIM = 2 * _LANES   # widest head the dense kernels take
 _SUBLANES = 8                # fp32 sublane tile: row vectors (lse, D) are
                              # stored (B, H, 8, S) so blocks are (8, block_q)
 _NEG_INF = -1e30             # finite "-inf": keeps masked rows NaN-free
@@ -101,11 +109,16 @@ def _pick_block(seq_len: int, candidates=_BLOCK_CANDIDATES) -> int | None:
 def flash_supported(q, k, v) -> bool:
     """Shape gate for the "auto" dispatcher: sequence divisible into
     lane-aligned blocks and a head dim we can pad to one lane tile."""
-    del v
     b, s, hq, dh = q.shape
     hkv = k.shape[2]
-    return (_pick_block(s) is not None and dh <= _LANES
-            and hq % hkv == 0)
+    return (_pick_block(s) is not None and hq % hkv == 0
+            and max(dh, v.shape[3]) <= _MAX_HEAD_DIM)
+
+
+def splash_supported(q, k, v) -> bool:
+    """The block-sparse kernels keep one head width of at most a lane
+    tile."""
+    return flash_supported(q, k, v) and q.shape[3] == v.shape[3] <= _LANES
 
 
 def _mask_causal(s, i, j, block_q: int, block_k: int):
@@ -179,14 +192,14 @@ def _fwd(q, k, v, *, causal: bool, block_q: int, block_k: int):
     group = hq // hkv
     scale = 1.0 / (dh ** 0.5)    # scale by the REAL head dim, pre-padding
 
-    dh_p = _LANES
+    dh_p, dv_p = _padded(dh), _padded(v.shape[3])
     # head-flattened [B, S, H*dh_p]: a free reshape when Dh == lane width,
     # so the kernel reads activations in their native [B, S, ...] layout —
     # the [B,H,S,D] variant cost a physical 33 MB transpose per tensor per
     # layer per direction (~1.1 ms each on v5e, measured)
     qt = _to_bsf(q, dh_p)        # [B, S, Hq*dh_p]
     kt = _to_bsf(k, dh_p)
-    vt = _to_bsf(v, dh_p)
+    vt = _to_bsf(v, dv_p)
 
     nq, nk = s // block_q, s // block_k
     grid = (b, hq, nq, nk)
@@ -198,8 +211,9 @@ def _fwd(q, k, v, *, causal: bool, block_q: int, block_k: int):
             j = jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
         return (bi, j, h // group)
 
-    kv_spec = pl.BlockSpec((1, block_k, dh_p), kv_index,
-                           memory_space=pltpu.VMEM)
+    def kv_spec(width):
+        return pl.BlockSpec((1, block_k, width), kv_index,
+                            memory_space=pltpu.VMEM)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
@@ -208,10 +222,10 @@ def _fwd(q, k, v, *, causal: bool, block_q: int, block_k: int):
             pl.BlockSpec((1, block_q, dh_p),
                          lambda bi, h, i, j: (bi, i, h),
                          memory_space=pltpu.VMEM),
-            kv_spec, kv_spec,
+            kv_spec(dh_p), kv_spec(dv_p),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, dh_p),
+            pl.BlockSpec((1, block_q, dv_p),
                          lambda bi, h, i, j: (bi, i, h),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, 1, _SUBLANES, block_q),
@@ -219,11 +233,11 @@ def _fwd(q, k, v, *, causal: bool, block_q: int, block_k: int):
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, s, hq * dh_p), q.dtype),
+            jax.ShapeDtypeStruct((b, s, hq * dv_p), q.dtype),
             jax.ShapeDtypeStruct((b, hq, _SUBLANES, s), _F32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, dh_p), _F32),
+            pltpu.VMEM((block_q, dv_p), _F32),
             pltpu.VMEM((block_q, _LANES), _F32),
             pltpu.VMEM((block_q, _LANES), _F32),
         ],
@@ -231,7 +245,7 @@ def _fwd(q, k, v, *, causal: bool, block_q: int, block_k: int):
         name="flash_fwd",
         interpret=pallas_common.interpret_mode(),
     )(qt, kt, vt)
-    return _from_bsf(out, hq, dh), lse
+    return _from_bsf(out, hq, v.shape[3]), lse
 
 
 # ------------------------------------------------------------------ bwd
@@ -380,15 +394,15 @@ def _bwd_impl(q, k, v, out, lse, do, *, causal: bool,
     hkv = k.shape[2]
     group = hq // hkv
     scale = 1.0 / (dh ** 0.5)
-    dh_p = _LANES
+    dv = v.shape[3]
+    dh_p, dv_p = _padded(dh), _padded(dv)
 
-    qt, kt, vt = (_to_bsf(x, dh_p) for x in (q, k, v))
-    dot = _to_bsf(do, dh_p)
-    ot = _to_bsf(out, dh_p)
+    qt, kt = _to_bsf(q, dh_p), _to_bsf(k, dh_p)
+    vt, dot, ot = (_to_bsf(x, dv_p) for x in (v, do, out))
     # D_i = rowsum(dO * O): cheap elementwise, plain XLA; only the tiny
     # [B, S, Hq] result is transposed to the kernel's row-vector layout
     dcap = jnp.sum((dot.astype(_F32) * ot.astype(_F32))
-                   .reshape(b, s, hq, dh_p), axis=-1)     # [B, S, Hq]
+                   .reshape(b, s, hq, dv_p), axis=-1)     # [B, S, Hq]
     dcap = jnp.broadcast_to(jnp.swapaxes(dcap, 1, 2)[:, :, None, :],
                             (b, hq, _SUBLANES, s))        # sublane-replicated
 
@@ -399,11 +413,14 @@ def _bwd_impl(q, k, v, out, lse, do, *, causal: bool,
             j = jnp.minimum(j, (i * bq_dq + bq_dq - 1) // bk_dq)
         return (bi, j, h // group)
 
-    q_spec = pl.BlockSpec((1, bq_dq, dh_p),
-                          lambda bi, h, i, j: (bi, i, h),
-                          memory_space=pltpu.VMEM)
-    kv_spec = pl.BlockSpec((1, bk_dq, dh_p), kv_index,
-                           memory_space=pltpu.VMEM)
+    def q_spec(width):
+        return pl.BlockSpec((1, bq_dq, width),
+                            lambda bi, h, i, j: (bi, i, h),
+                            memory_space=pltpu.VMEM)
+
+    def kv_spec(width):
+        return pl.BlockSpec((1, bk_dq, width), kv_index,
+                            memory_space=pltpu.VMEM)
     row_spec = pl.BlockSpec((1, 1, _SUBLANES, bq_dq),
                             lambda bi, h, i, j: (bi, h, 0, i),
                             memory_space=pltpu.VMEM)
@@ -411,8 +428,9 @@ def _bwd_impl(q, k, v, out, lse, do, *, causal: bool,
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           block_q=bq_dq, block_k=bk_dq),
         grid=(b, hq, nq, nk),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
+        in_specs=[q_spec(dh_p), kv_spec(dh_p), kv_spec(dv_p),
+                  q_spec(dv_p), row_spec, row_spec],
+        out_specs=q_spec(dh_p),
         out_shape=jax.ShapeDtypeStruct((b, s, hq * dh_p), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq_dq, dh_p), _F32)],
         compiler_params=_compiler_params(),
@@ -428,13 +446,19 @@ def _bwd_impl(q, k, v, out, lse, do, *, causal: bool,
             i = jnp.maximum(i, (j * bk_dkv) // bq_dkv)
         return i
 
-    q_spec_t = pl.BlockSpec((1, bq_dkv, dh_p),
-                            lambda bi, h, j, i: (bi, qi_index(bi, h, j, i), h),
+    def q_spec_t(width):
+        return pl.BlockSpec(
+            (1, bq_dkv, width),
+            lambda bi, h, j, i: (bi, qi_index(bi, h, j, i), h),
+            memory_space=pltpu.VMEM)
+
+    def kv_spec_t(width):
+        return pl.BlockSpec((1, bk_dkv, width),
+                            lambda bi, h, j, i: (bi, j, h // group),
                             memory_space=pltpu.VMEM)
-    kv_spec_t = pl.BlockSpec((1, bk_dkv, dh_p),
-                             lambda bi, h, j, i: (bi, j, h // group),
-                             memory_space=pltpu.VMEM)
-    kv_out_t = pl.BlockSpec((1, bk_dkv, dh_p),
+
+    def kv_out_t(width):
+        return pl.BlockSpec((1, bk_dkv, width),
                             lambda bi, h, j, i: (bi, j, h),
                             memory_space=pltpu.VMEM)
     row_spec_t = pl.BlockSpec((1, 1, _SUBLANES, bq_dkv),
@@ -444,13 +468,13 @@ def _bwd_impl(q, k, v, out, lse, do, *, causal: bool,
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           block_q=bq_dkv, block_k=bk_dkv),
         grid=(b, hq, nk_t, nq_t),
-        in_specs=[q_spec_t, kv_spec_t, kv_spec_t, q_spec_t,
-                  row_spec_t, row_spec_t],
-        out_specs=[kv_out_t, kv_out_t],
+        in_specs=[q_spec_t(dh_p), kv_spec_t(dh_p), kv_spec_t(dv_p),
+                  q_spec_t(dv_p), row_spec_t, row_spec_t],
+        out_specs=[kv_out_t(dh_p), kv_out_t(dv_p)],
         out_shape=[jax.ShapeDtypeStruct((b, s, hq * dh_p), k.dtype),
-                   jax.ShapeDtypeStruct((b, s, hq * dh_p), v.dtype)],
+                   jax.ShapeDtypeStruct((b, s, hq * dv_p), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk_dkv, dh_p), _F32),
-                        pltpu.VMEM((bk_dkv, dh_p), _F32)],
+                        pltpu.VMEM((bk_dkv, dv_p), _F32)],
         compiler_params=_compiler_params(),
         name="flash_bwd_dkv",
         interpret=pallas_common.interpret_mode(),
@@ -459,13 +483,19 @@ def _bwd_impl(q, k, v, out, lse, do, *, causal: bool,
     # sum the q-head group into each kv head (GQA): consecutive q heads
     # share a kv head, so the flattened head axis folds as [Hkv, group]
     dk = dk_h.reshape(b, s, hkv, group, dh_p).sum(axis=3)
-    dv = dv_h.reshape(b, s, hkv, group, dh_p).sum(axis=3)
+    dv_sum = dv_h.reshape(b, s, hkv, group, dv_p).sum(axis=3)
     return (_from_bsf(dq, hq, dh),
             dk[..., :dh].astype(k.dtype),
-            dv[..., :dh].astype(v.dtype))
+            dv_sum[..., :dv].astype(v.dtype))
 
 
 # ------------------------------------------------------- layout helpers
+
+def _padded(width: int) -> int:
+    """A head's width as the kernels block it: the next multiple of the
+    lane tile."""
+    return -(-width // _LANES) * _LANES
+
 
 def _to_bsf(x, dh_p: int):
     """[B, S, H, Dh] -> [B, S, H*dh_p]: zero-pad the head dim to one lane
@@ -491,7 +521,8 @@ def flash_attention(q, k, v, causal: bool = True,
                     block_q: int | None = None, block_k: int | None = None):
     """Blockwise attention; same contract as ops/xla_attention.py.
 
-    q: [B, S, Hq, Dh], k/v: [B, S, Hkv, Dh] with Hq % Hkv == 0.
+    q: [B, S, Hq, Dh], k: [B, S, Hkv, Dh], v: [B, S, Hkv, Dv] with
+    Hq % Hkv == 0; Dv may differ from Dh (-> [B, S, Hq, Dv]).
     """
     out, _ = _flash_fwd(q, k, v, causal, block_q, block_k)
     return out
@@ -501,10 +532,11 @@ def _resolve_blocks(q, k, block_q, block_k,
                     candidates=_BLOCK_CANDIDATES):
     s, dh = q.shape[1], q.shape[3]
     hq, hkv = q.shape[2], k.shape[2]
-    if hq % hkv or dh > _LANES:
+    if hq % hkv or dh > _MAX_HEAD_DIM:
         raise ValueError(
             f"flash_attention: unsupported shape (Hq={hq} % Hkv={hkv} != 0 "
-            f"or head dim {dh} > {_LANES}); use ops.attention(..., impl='auto')")
+            f"or head dim {dh} > {_MAX_HEAD_DIM}); use "
+            f"ops.attention(..., impl='auto')")
     bq = block_q or _pick_block(s, candidates)
     bk = block_k or _pick_block(s, candidates)
     if bq is None or bk is None or s % bq or s % bk:
@@ -954,6 +986,11 @@ def splash_attention(q, k, v, spec, block_q: int | None = None,
 
 
 def _splash_vjp_fwd(q, k, v, spec, block_q, block_k):
+    if not q.shape[3] == v.shape[3] <= _LANES:
+        raise ValueError(
+            f"splash_attention: one head width of at most {_LANES} "
+            f"(got {q.shape[3]} and {v.shape[3]}); the dense kernels "
+            f"take two")
     bq, bk = _resolve_blocks(q, k, block_q, block_k,
                              candidates=_BLOCK_CANDIDATES_FWD)
     if block_q is None and block_k is None:

@@ -12,8 +12,8 @@ _F32 = jnp.float32
 
 
 def xla_attention(q, k, v, causal: bool, dense_mask=None):
-    """q: [B, S, Hq, Dh], k/v: [B, S, Hkv, Dh] (GQA broadcast).
-    Softmax in fp32.
+    """q: [B, S, Hq, Dh], k: [B, S, Hkv, Dh], v: [B, S, Hkv, Dv] (GQA
+    broadcast; Dv may differ from Dh).  Softmax in fp32.
 
     ``dense_mask`` (an [S, S] bool, True = attend — built by
     ops/attention_mask.dense_mask) replaces the causal tril when given:
@@ -36,4 +36,4 @@ def xla_attention(q, k, v, causal: bool, dense_mask=None):
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs.astype(v.dtype), v,
                      preferred_element_type=_F32)
-    return out.reshape(b, s, hq, dh).astype(v.dtype)
+    return out.reshape(b, s, hq, v.shape[3]).astype(v.dtype)

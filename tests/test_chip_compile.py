@@ -106,6 +106,47 @@ def test_flash_forward_backward(for_chip):
     assert kernels_in(for_chip(grad_of(fa.flash_attention), *QKV)) == 3
 
 
+def test_flash_at_latent_attention_widths(for_chip):
+    """``kimivl_a3b_train_s8k``'s attention (B=2, S=8192, 16 heads,
+    scores over 128 + 64 lanes, values of 128): the three kernels at the
+    default blocks; the 192 lie padded to 256 (a block's last dimension
+    is a multiple of the 128-lane tile), the values stay 128 wide."""
+    import re
+
+    from dlnetbench_tpu.metrics import spans
+    fa = ops_module("flash_attention")
+
+    def scoped(q, k, v):
+        with spans.scope("attn"):
+            return fa.flash_attention(q, k, v)
+    text = for_chip(grad_of(scoped),
+                    ((2, 8192, 16, 192), BF16), ((2, 8192, 16, 192), BF16),
+                    ((2, 8192, 16, 128), BF16))
+    calls = {re.sub(r"\.\d+$", "", m.group(1)): line
+             for line in text.splitlines() if "tpu_custom_call" in line
+             and (m := re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", line))}
+    assert sorted(calls) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    scores, values = "bf16[2,8192,4096]", "bf16[2,8192,2048]"
+
+    def outputs(name):
+        return calls[name].partition(" custom-call(")[0]
+
+    def operands(name):
+        return calls[name].partition("operand_layout_constraints={")[2] \
+            .partition("}}")[0]
+    assert values in outputs("flash_fwd")
+    assert scores not in outputs("flash_fwd")
+    # q and k at the scores' width, v at its own
+    assert (operands("flash_fwd").count(scores),
+            operands("flash_fwd").count(values)) == (2, 1)
+    assert scores in outputs("flash_bwd_dkv")        # dk wide ...
+    assert values in outputs("flash_bwd_dkv")        # ... dv narrow
+    assert values not in outputs("flash_bwd_dq")
+    # q, k | v, dO
+    assert (operands("flash_bwd_dq").count(scores),
+            operands("flash_bwd_dq").count(values)) == (2, 2)
+
+
 @pytest.mark.parametrize("mask", ["window", "segments"])
 def test_splash_forward_backward(for_chip, mask):
     from dlnetbench_tpu.ops.attention_mask import MaskSpec
@@ -269,6 +310,46 @@ def test_moe_train_step_at_the_cell_shapes_fits_the_chip(for_chip, one_chip):
                            arch["capacity_factor"])
     wide = re.compile(rf"^\s*(?:ROOT )?\S+ = f32\[{e},{c},{f}\]", re.M)
     assert len(wide.findall(text[text.index("ENTRY"):])) == 1
+
+
+def test_latent_moe_train_step_at_the_cell_shapes_fits_the_chip(one_chip,
+                                                                no_persistent_cache,
+                                                                monkeypatch):
+    """``kimivl_a3b_train_s8k``'s whole step (the cell's own files and
+    compiler options, as the runner builds it): the depth rule of the
+    configuration file, twice the arguments plus the temporaries at or
+    under 13.0 GB by the chip compiler's count; four attention kernels
+    a layer (the forward twice: each layer is recomputed) and six
+    grouped matmuls an expert layer; the step's outputs carry the
+    routing."""
+    from benchmarks import harness, weights_latent_moe as weights
+    from benchmarks.runners import train_latent_moe
+    from dlnetbench_tpu.core import executor
+    from dlnetbench_tpu.models import bench_step
+    from dlnetbench_tpu.ops import pallas_common
+    monkeypatch.setattr(pallas_common, "interpret_mode", lambda: False)
+    cell = harness.load_cell("kimivl_a3b_train_s8k")
+    wl, tr = cell.workload, cell.traffic
+    arch = weights.arch_of(cell.config)
+    cfg = train_latent_moe.program_config(cell, arch,
+                                          {"attention_impl": "flash"})
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree.map(
+        on_chip, jax.eval_shape(lambda: weights.make_params(arch, 0)))
+    tokens = jax.ShapeDtypeStruct((tr["batch"], tr["seq_len"] + 1), I32,
+                                  sharding=one_chip)
+    step = executor.CompiledStep(
+        bench_step.make_train_k(cfg, 1, wl["lr"]), (params, tokens),
+        donate_argnums=bench_step.DONATE_ARGNUMS,
+        compiler_options=wl["compiler_options"])
+    mem = step.memory_analysis
+    assert 2 * mem["argument"] + mem["temp"] <= 13.0e9
+    assert mem["alias"] > 0.99 * mem["argument"]   # the state is donated
+    layers = arch["num_layers"]
+    experts = weights.expert_layers(arch)
+    assert kernels_in(step.as_text()) == 4 * layers + 6 * experts
 
 
 def hlo_computations(text: str) -> dict:

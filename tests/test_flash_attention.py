@@ -58,6 +58,51 @@ def test_gradients_match_reference(b, s, hq, hkv, dh, causal):
         assert jnp.max(jnp.abs(a - b_)) < 5e-4
 
 
+@pytest.mark.parametrize("hq,hkv,dqk,dv", [
+    (2, 2, 192, 128),     # latent attention: 128 + 64 lanes of scores
+    (4, 2, 192, 128),     # ... grouped
+    (2, 2, 24, 16),       # both under one lane tile, still two widths
+    (2, 2, 128, 256)])    # values wider than the scores
+def test_two_widths_forward_and_gradients_match_reference(hq, hkv, dqk,
+                                                          dv):
+    """Scores over ``dqk`` lanes beside values of ``dv``: the kernels
+    pad each to its own multiple of 128 and scale by the scores' real
+    width."""
+    kq, kk, kv, kc = jax.random.split(jax.random.key(3), 4)
+    q = jax.random.normal(kq, (1, 256, hq, dqk))
+    k = jax.random.normal(kk, (1, 256, hkv, dqk))
+    v = jax.random.normal(kv, (1, 256, hkv, dv))
+    cot = jax.random.normal(kc, (1, 256, hq, dv))
+    want = xla_attention(q, k, v, causal=True)
+    got = flash_attention(q, k, v, True, 128, 128)
+    assert got.shape == want.shape == (1, 256, hq, dv)
+    assert jnp.max(jnp.abs(got - want)) < 2e-5
+    g_ref = jax.grad(lambda *a: jnp.sum(
+        xla_attention(*a, causal=True) * cot), argnums=(0, 1, 2))(q, k, v)
+    g_fl = jax.grad(lambda *a: jnp.sum(
+        flash_attention(*a, True, 128, 128) * cot),
+        argnums=(0, 1, 2))(q, k, v)
+    for a, b_ in zip(g_ref, g_fl):
+        assert a.shape == b_.shape
+        assert jnp.max(jnp.abs(a - b_)) < 5e-4
+
+
+def test_two_widths_go_to_the_dense_kernels_only():
+    from dlnetbench_tpu.ops import attention
+    from dlnetbench_tpu.ops.attention_mask import MaskSpec
+    from dlnetbench_tpu.ops.flash_attention import (splash_attention,
+                                                    splash_supported)
+    q = jnp.zeros((1, 256, 2, 192))
+    v = jnp.zeros((1, 256, 2, 128))
+    assert flash_supported(q, q, v) and not splash_supported(q, q, v)
+    assert not flash_supported(jnp.zeros((1, 256, 2, 320)), q, v)
+    with pytest.raises(ValueError, match="one head width"):
+        splash_attention(q, q, v, MaskSpec(window=64))
+    # a masked call at two widths has the dense-masked reference
+    out = attention(q, q, v, causal=True, mask=MaskSpec(window=64))
+    assert out.shape == (1, 256, 2, 128)
+
+
 def test_dispatcher_and_support_gate():
     q, k, v = _make_qkv(jax.random.key(3), 1, 256, 2, 2, 128)
     assert flash_supported(q, k, v)

@@ -42,10 +42,32 @@ PHI4FLASH = {"model_type": "phi4flash", "hidden_size": 2560,
              "tie_word_embeddings": True, "vocab_size": 200064}
 
 
+# the catalog's config of moonshotai/Kimi-VL-A3B-Instruct: the language
+# model's settings, with no model_type of their own
+KIMI_VL = {"vocab_size": 163840, "max_position_embeddings": 131072,
+           "hidden_size": 2048, "intermediate_size": 11264,
+           "moe_intermediate_size": 1408, "num_hidden_layers": 27,
+           "num_attention_heads": 16, "n_shared_experts": 2,
+           "n_routed_experts": 64, "ep_size": 1,
+           "routed_scaling_factor": 2.446, "kv_lora_rank": 512,
+           "q_lora_rank": None, "qk_rope_head_dim": 64, "v_head_dim": 128,
+           "qk_nope_head_dim": 128, "topk_method": "noaux_tc",
+           "n_group": 1, "topk_group": 1, "num_experts_per_tok": 6,
+           "moe_layer_freq": 1, "first_k_dense_replace": 1,
+           "norm_topk_prob": True, "scoring_func": "sigmoid",
+           "seq_aux": True, "num_key_value_heads": 16,
+           "hidden_act": "silu", "rms_norm_eps": 1e-05,
+           "rope_theta": 800000, "rope_scaling": None,
+           "attention_bias": False, "tie_word_embeddings": False}
+
+
 @pytest.mark.parametrize("name,cfg", [
     ("gpt2_l", GPT2_L), ("llama3_8b", LLAMA3_8B),
     ("mixtral_8x7b", MIXTRAL), ("vit_b", VIT_B),
     ("phi4_mini_flash_reasoning", PHI4FLASH),
+    ("kimi_vl_a3b", KIMI_VL),
+    ("kimi_vl_a3b", {"model_type": "kimi_vl", "text_config": {
+        **KIMI_VL, "model_type": "deepseek_v3"}}),
 ])
 def test_mapping_reproduces_committed_card(name, cfg):
     got = hf_import.card_from_hf_config(name, cfg)
@@ -92,3 +114,33 @@ def test_phi4flash_layer_map_is_the_published_one():
     assert kinds[18::2] == ("gmu",) * 7 and kinds[19::2] == ("cross",) * 7
     card = hf_import.card_from_hf_config("x", PHI4FLASH)
     assert abs(card.num_params() - 3.85e9) / 3.85e9 < 0.01
+
+
+def test_latent_moe_card_states_the_gate_and_the_shared_experts():
+    card = hf_import.card_from_hf_config("kimi_vl_a3b", KIMI_VL)
+    moe = card.moe_params
+    assert (moe.num_experts, moe.num_experts_per_tok, moe.scoring,
+            moe.routed_scale, moe.shared_experts, moe.expert_ff_dim,
+            moe.first_dense_layers) == (64, 6, "sigmoid", 2.446, 2, 1408, 1)
+    assert card.layer_kinds == ("mla",) * 27 and card.rms_norm
+    assert (card.kv_lora_rank, card.qk_nope_head_dim,
+            card.qk_rope_head_dim, card.v_head_dim) == (512, 128, 64, 128)
+    assert not card.tied_embeddings and card.rope_theta == 800000.0
+    # 13.76 M of attention and 584.8 M of experts a layer: 15.96 B
+    assert card.mixer_params("mla") == 13763072
+    assert card.ffn_params(0) == 3 * 2048 * 11264
+    assert card.ffn_params(1) == 66 * 3 * 2048 * 1408 + 2048 * 64 + 64
+    assert card.num_params() == pytest.approx(15.96e9, rel=1e-3)
+    json_card = hf_import.card_to_json(card)
+    assert json_card["moe_params"]["scoring"] == "sigmoid"
+    # Mixtral's card keeps its two keys
+    assert hf_import.card_to_json(load_model_card("mixtral_8x7b"))[
+        "moe_params"] == {"num_experts": 8, "num_experts_per_tok": 2}
+
+
+@pytest.mark.parametrize("key,value", [
+    ("q_lora_rank", 1536), ("n_group", 8), ("moe_layer_freq", 2),
+    ("rope_scaling", {"type": "yarn", "factor": 40})])
+def test_latent_moe_import_refuses_what_no_layer_computes(key, value):
+    with pytest.raises(ValueError, match=key):
+        hf_import.card_from_hf_config("x", {**KIMI_VL, key: value})

@@ -137,7 +137,7 @@ def test_gmu_reads_the_last_mamba_layers_scan_output(case):
         bp = jax.tree.map(lambda a: a[li], params["block"])
         mp = jax.tree.map(lambda a: a[cfg.index_in_group(li)],
                           params[hybrid.GROUP_OF[KINDS[li]]])
-        x, handed = hybrid._layer(cfg, li, x, bp, mp, None, None)
+        x, handed, _ = hybrid._layer(cfg, li, x, bp, mp, bp, None, None)
     assert jnp.allclose(seen["memory"], handed, rtol=1e-5, atol=1e-6)
 
 
@@ -229,7 +229,7 @@ def test_card_states_the_pattern_and_config_follows_it():
     assert cfg.memory_layer == 16 and cfg.layer_kinds[17] == "full"
     assert (cfg.head_dim, cfg.dt_rank, cfg.ssm_inner) == (64, 160, 5120)
     assert cfg.group_sizes() == {"mamba": 9, "attn": 9, "gmu": 7,
-                                 "cross": 7}
+                                 "cross": 7, "mla": 0}
     with pytest.raises(ValueError, match="a mamba layer before"):
         hybrid.HybridConfig.from_card(card, layer_kinds=("gmu", "mamba"))
     with pytest.raises(ValueError, match="exactly one full"):

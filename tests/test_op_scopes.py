@@ -251,5 +251,5 @@ def test_a_call_opens_no_span_of_its_own():
 def test_scope_refuses_a_name_outside_the_vocabulary():
     with pytest.raises(ValueError, match="spans.SCOPES"):
         spans.scope("attention")
-    assert len(set(spans.SCOPES)) == len(spans.SCOPES) == 12
+    assert len(set(spans.SCOPES)) == len(spans.SCOPES) == 13
     assert spans.OTHER_SCOPE not in spans.SCOPES
