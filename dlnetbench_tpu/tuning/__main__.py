@@ -350,35 +350,55 @@ def _tune_grad_bucket_layers(args):
 
 def _tune_grouped_ffn(args):
     """Grouped expert-FFN grid blocks (ops/grouped_matmul.py, ISSUE
-    15): the per-expert dispatch-buffer SwiGLU measured at
-    (--experts x --capacity x --d x --ff) with optional fused
-    quantization (--fmt rides in the key via
-    ``params.grouped_ffn_key`` — bf16 optima never answer int8/fp8
-    consults)."""
+    15), one matmul at a time: the consult is keyed by a matmul's own
+    shape (``params.grouped_ffn_key`` over experts x capacity x K x N),
+    so the search measures ``[E, C, d] @ [E, d, n]``, the gate and up
+    projection of (--experts x --capacity x --d x --n); the down
+    projection is the same search with --d and --n swapped.  --fmt
+    rides in the key (bf16 optima never answer int8/fp8 consults).
+    The default candidates start from the shape's own ``tile_plan``:
+    whole dimensions and lane-multiple divisors for each row block,
+    beside the powers of two searched before."""
     import jax
     import jax.numpy as jnp
 
     from dlnetbench_tpu.ops import grouped_matmul as gm
+    from dlnetbench_tpu.ops import pallas_common
 
     e, c, d, h = args.experts, args.capacity, args.d, args.n
     fmt = None if args.fmt == "none" else args.fmt
     dt = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32
     x = jax.random.normal(jax.random.key(0), (e, c, d), dt)
-    wg = jax.random.normal(jax.random.key(1), (e, d, h), dt) * 0.02
-    wu = jax.random.normal(jax.random.key(2), (e, d, h), dt) * 0.02
-    wd = jax.random.normal(jax.random.key(3), (e, h, d), dt) * 0.02
+    w = jax.random.normal(jax.random.key(1), (e, d, h), dt) * 0.02
+    scales = {}
+    if fmt:
+        w, sw = gm.quantize_experts(w, fmt)
+        scales = dict(sx=gm.scale_from_amax(gm.expert_amax(x), fmt),
+                      sw=sw, fmt=fmt)
     key = tparams.grouped_ffn_key(e, c, d, h, fmt or "none", x.dtype)
-    cands = _parse_candidates(args.candidates, 3,
-                              ("block_c", "block_n", "block_k")) or [
-        {"block_c": bc, "block_n": bn, "block_k": bk}
-        for bc in (512, 256, 128) for bn in (1024, 512)
-        for bk in (512, 256)]
+    names = ("block_c", "block_n", "block_k")
+    cands = _parse_candidates(args.candidates, 3, names)
+    if cands is None:
+        limit = pallas_common.DEFAULT_VMEM_LIMIT_MB * 2 ** 20
+        rows = (512, 256, 128)
+        grid = [tuple(gm.tile_plan(c, d, h, x.dtype.itemsize,
+                                   quantized=fmt is not None).values())]
+        grid += [(bc, bn, bk) for bc in rows
+                 for bn in gm.blocks_of(h, pallas_common.LANES)[:3]
+                 for bk in gm.blocks_of(d, pallas_common.LANES)[:2]]
+        grid += [(bc, bn, bk) for bc in rows for bn in (1024, 512)
+                 for bk in (512, 256)]
+        # as the kernel will run them, each once, those that fit
+        fitted = dict.fromkeys(
+            (pallas_common.fit_block(c, bc), pallas_common.fit_block(h, bn),
+             pallas_common.fit_block(d, bk)) for bc, bn, bk in grid)
+        cands = [dict(zip(names, blk)) for blk in fitted
+                 if gm.tile_bytes(*blk, x.dtype.itemsize,
+                                   fmt is not None) <= limit]
 
     def measure_cfg(cfg):
-        return _chain(lambda xx: gm.grouped_ffn(
-            xx, wg, wu, wd, fmt=fmt, block_c=cfg["block_c"],
-            block_n=cfg["block_n"], block_k=cfg["block_k"]),
-            (x,), args.k)
+        return _chain(lambda xx: gm.grouped_matmul(xx, w, **scales, **cfg),
+                      (x,), args.k)
     return "grouped_ffn", key, cands, measure_cfg
 
 

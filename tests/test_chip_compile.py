@@ -237,6 +237,36 @@ def test_grouped_matmul(for_chip, fmt):
     assert kernels_in(text) == 1
 
 
+# the four grouped matmuls the benchmark's cells run (E, C, K, N), bf16
+CELL_GROUPED = {
+    "kimi_gate_up": (16, 4096, 2048, 1408),
+    "kimi_down": (16, 4096, 1408, 2048),
+    "mixtral_gate_up": (8, 2560, 4096, 14336),
+    "mixtral_down": (8, 2560, 14336, 4096),
+}
+
+
+@pytest.mark.parametrize("name", CELL_GROUPED)
+def test_grouped_matmul_at_the_cells_shapes(for_chip, name):
+    """Each under the tiles its own shape plans (1408 = 11 x 128 whole,
+    a 14336-deep contraction in one block): the chip's compiler takes
+    them inside the kernel family's VMEM limit, and the instruction is
+    still ``grouped_mm.N`` with the ``s32[E]`` counts first, which is
+    how the two roofline readers find it."""
+    import re
+    gm = ops_module("grouped_matmul")
+    e, c, k, n = CELL_GROUPED[name]
+    text = for_chip(lambda x, w, cnt: gm.grouped_matmul(x, w, counts=cnt),
+                    ((e, c, k), BF16), ((e, k, n), BF16), ((e,), I32))
+    assert kernels_in(text) == 1
+    call, = [line for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    first = re.search(rf"%grouped_mm\.\d+ = bf16\[{e},{c},{n}\]\S* "
+                      rf"custom-call\((%[\w.\-]+)", call).group(1)
+    # the text names operands without their types (a trace prints them)
+    assert re.search(rf"{re.escape(first)} = s32\[{e}\]", text)
+
+
 def test_moe_dispatch_and_combine_at_the_cell_shapes(for_chip):
     """The row-gather dispatch and combine with their hand-written
     backward, at ``mixtral8x7b_train``'s shapes (T = 8192 tokens, 8
@@ -305,6 +335,8 @@ def test_moe_train_step_at_the_cell_shapes_fits_the_chip(for_chip, one_chip):
     assert mem["argument"] + mem["temp"] < 15.75 * 2 ** 30
     text = step.as_text()
     assert kernels_in(text) == 6     # three flash, three grouped_mm
+    assert sum(k.startswith("grouped_mm.")
+               for k in kernel_instructions(text)) == 3
     e, f = arch["num_experts"], arch["ff_dim"]
     c = moe.group_capacity(tr["batch"] * tr["seq_len"], arch["top_k"], e,
                            arch["capacity_factor"])
@@ -349,7 +381,10 @@ def test_latent_moe_train_step_at_the_cell_shapes_fits_the_chip(one_chip,
     assert mem["alias"] > 0.99 * mem["argument"]   # the state is donated
     layers = arch["num_layers"]
     experts = weights.expert_layers(arch)
-    assert kernels_in(step.as_text()) == 4 * layers + 6 * experts
+    text = step.as_text()
+    assert kernels_in(text) == 4 * layers + 6 * experts
+    assert sum(k.startswith("grouped_mm.")
+               for k in kernel_instructions(text)) == 6 * experts == 30
 
 
 def hlo_computations(text: str) -> dict:

@@ -233,29 +233,91 @@ def _gm_case(e=4, c=16, d=32, h=48, dtype=_F32):
     return x, wg, wu, wd
 
 
-def test_grouped_matmul_matches_einsum():
-    x, wg, _, _ = _gm_case()
+# widths that are an odd multiple of the lane tile, scaled down for the
+# interpreter: 88 = 11 x 8 stands for 1408 = 11 x 128.  Each case is
+# (c, d, h, explicit blocks): a block left out is the plan's, which at
+# these sizes is the whole dimension.
+_TILINGS = {
+    "pow2": (16, 32, 48, dict(block_c=8, block_n=16, block_k=16)),
+    "n88_whole_k": (16, 32, 88, dict(block_c=8, block_n=8)),
+    "n88_rows_inside": (16, 32, 88, dict(block_c=4, block_n=8)),
+    "n88_nk4": (16, 32, 88, dict(block_c=4, block_n=8, block_k=8)),
+    "k88_nk11": (16, 88, 32, dict(block_c=4, block_n=16, block_k=8)),
+    "k88_whole": (16, 88, 32, dict(block_c=4)),
+    "plan": (16, 88, 32, {}),
+}
+
+
+def _grid_of(c, d, h, blocks):
+    """(bc, bn, bk, rows inside columns) the kernel runs a case on."""
+    plan = gm.tile_plan(c, d, h, 4)
+    bc, bn, bk = (blocks.get(k, plan[k])
+                  for k in ("block_c", "block_n", "block_k"))
+    return bc, bn, bk, gm.n_outer(c, d, h, bc, bn, bk)
+
+
+@pytest.mark.parametrize("tiling", _TILINGS)
+def test_grouped_matmul_matches_einsum(tiling):
+    c, d, h, blocks = _TILINGS[tiling]
+    x, wg, _, _ = _gm_case(c=c, d=d, h=h)
     ref = jnp.einsum("ecd,edh->ech", x, wg)
-    out = gm.grouped_matmul(x, wg, block_c=8, block_n=16, block_k=16)
+    out = gm.grouped_matmul(x, wg, **blocks)
     assert float(jnp.max(jnp.abs(out - ref))) < 1e-5
+    # the cases cover both grid orders, nk == 1 and nk > 1
+    _, _, bk, inside = _grid_of(c, d, h, blocks)
+    assert inside == (tiling == "n88_rows_inside")
+    assert (bk == d) == (tiling in ("n88_whole_k", "n88_rows_inside",
+                                    "k88_whole", "plan"))
 
 
-def test_grouped_matmul_counts_skip():
-    """Blocks past an expert's count emit zeros; live rows match the
-    dense reference."""
-    x, wg, _, _ = _gm_case()
+# counts that end inside a block, at a block's edge and at 0, the empty
+# experts first, between and last
+_COUNTS = {"mixed": (16, 5, 0, 9), "edges": (8, 4, 16, 12),
+           "empty_first_last": (0, 7, 0, 0), "all_empty": (0, 0, 0, 0)}
+
+
+@pytest.mark.parametrize("counts", _COUNTS)
+@pytest.mark.parametrize("tiling", [t for t in _TILINGS if t != "plan"])
+def test_grouped_matmul_counts_skip(tiling, counts):
+    """Blocks past an expert's count emit exact zeros; live rows match
+    the dense reference."""
+    c, d, h, blocks = _TILINGS[tiling]
+    x, wg, _, _ = _gm_case(c=c, d=d, h=h)
     ref = jnp.einsum("ecd,edh->ech", x, wg)
-    counts = jnp.array([16, 5, 0, 9], jnp.int32)
-    out = gm.grouped_matmul(x, wg, counts=counts, block_c=4,
-                            block_n=16, block_k=16)
+    cnt = jnp.array(_COUNTS[counts], jnp.int32)
+    out = gm.grouped_matmul(x, wg, counts=cnt, **blocks)
+    bc = blocks["block_c"]
     for e in range(4):
-        n = int(counts[e])
-        nb = min(-(-n // 4) * 4 if n else 0, 16)
+        n = int(cnt[e])
+        nb = -(-n // bc) * bc
         if n:
             assert float(jnp.max(jnp.abs(out[e, :n] - ref[e, :n]))) \
                 < 1e-5
-        if nb < 16:
-            assert float(jnp.max(jnp.abs(out[e, nb:]))) == 0.0
+        assert not jnp.any(out[e, nb:])
+
+
+@pytest.mark.parametrize("tiling", ["n88_whole_k", "n88_nk4"])
+def test_grouped_matmul_bf16_as_stored_equals_float32_copies(tiling):
+    """bf16 operands go to the dot as they are stored; the kernel used
+    to widen both to float32 first.  A float32 copy of a bf16 number is
+    that number, so the products are the same and only the order of
+    the float32 sums can differ."""
+    c, d, h, blocks = _TILINGS[tiling]
+    x, wg, _, _ = _gm_case(c=c, d=d, h=h, dtype=jnp.bfloat16)
+    cnt = jnp.array(_COUNTS["mixed"], jnp.int32)
+    out = gm.grouped_matmul(x, wg, counts=cnt, out_dtype=_F32, **blocks)
+    live = (jnp.arange(c)[None, :] // blocks["block_c"]
+            * blocks["block_c"] < cnt[:, None])[..., None]
+    was = jnp.einsum("ecd,edh->ech", x.astype(_F32), wg.astype(_F32),
+                     precision="highest") * live
+    assert out.dtype == _F32
+    assert float(jnp.max(jnp.abs(out - was))) < 1e-5
+    # and in the stored dtype: the old result to bf16's rounding
+    out16 = gm.grouped_matmul(x, wg, counts=cnt, **blocks)
+    assert out16.dtype == jnp.bfloat16
+    assert float(jnp.max(jnp.abs(out16.astype(_F32)
+                                 - was.astype(jnp.bfloat16).astype(_F32)))) \
+        <= 2 ** -8 * float(jnp.max(jnp.abs(was)))
 
 
 def test_grouped_matmul_int8_exact_vs_composed():
@@ -288,14 +350,20 @@ def _ffn_ref(x_, a, b, c, live=None):
 _GM_BLOCKS = dict(block_c=4, block_n=16, block_k=16)
 
 
-@pytest.mark.parametrize("counts", [None, (16, 5, 0, 9)],
-                         ids=["all_slots", "blocks_skipped"])
-def test_grouped_ffn_grads_match_reference(counts):
-    """All four gradients against the einsum reference.  With counts,
+@pytest.mark.parametrize("blocks", [
+    _GM_BLOCKS, dict(block_c=4, block_n=8, block_k=8), dict(block_c=4)],
+    ids=["pow2", "nk_gt_1", "whole_k"])
+@pytest.mark.parametrize("h", [48, 88])
+@pytest.mark.parametrize("counts", [None, (16, 5, 0, 9), (8, 0, 0, 4)],
+                         ids=["all_slots", "blocks_skipped", "at_edges"])
+def test_grouped_ffn_grads_match_reference(counts, h, blocks):
+    """All four gradients against the einsum reference, at a width
+    that is a power of two times 16 and at 88 = 11 x 8 (so gate/up
+    have the odd multiple in N and down has it in K).  With counts,
     x is nonzero everywhere and the cotangent is nonzero everywhere, so
     only the forward's own g, u (zeros in a skipped block) keep rows
     past the count at zero dx: a recomputed g, u would not."""
-    x, wg, wu, wd = _gm_case()
+    x, wg, wu, wd = _gm_case(h=h)
     r = jax.random.normal(jax.random.key(7), x.shape, _F32)
     cnt = live = None
     if counts is not None:
@@ -306,7 +374,7 @@ def test_grouped_ffn_grads_match_reference(counts):
 
     def loss(x_, a, b, c):
         return jnp.sum(gm.grouped_ffn(x_, a, b, c, counts=cnt,
-                                      **_GM_BLOCKS) * r)
+                                      **blocks) * r)
 
     def ref(x_, a, b, c):
         return jnp.sum(_ffn_ref(x_, a, b, c, live) * r)
@@ -319,7 +387,7 @@ def test_grouped_ffn_grads_match_reference(counts):
         assert float(jnp.max(jnp.abs(g1[0] * (1.0 - live)[..., None]))) \
             == 0.0
         # ... and the mask is not why: a live block's rows get theirs
-        assert float(jnp.max(jnp.abs(g1[0][1, :4]))) > 0.0
+        assert float(jnp.max(jnp.abs(g1[0][0, :4]))) > 0.0
 
 
 def _eqns_outside_kernels(jaxpr):
@@ -400,15 +468,17 @@ def test_grouped_blocks_validated():
 
 @pytest.mark.tuning
 def test_grouped_ffn_empty_db_bit_identity(tmp_path, monkeypatch):
-    """The ISSUE-9 consult contract on the new site: with no DB the
-    consult path is BIT-identical to explicit DEFAULT_BLOCKS, and a
-    committed record is consulted (frozen after first consult)."""
+    """The ISSUE-9 consult contract on this site: with no DB the
+    consult path is BIT-identical to the explicit blocks that each
+    matmul's own ``tile_plan`` returns, and a committed record is
+    consulted (frozen after first consult)."""
     from dlnetbench_tpu import tuning
     x, wg, wu, wd = _gm_case(e=2, c=8, d=16, h=16)
     tuning.reset(clear_env=True)
     try:
         y_off = gm.grouped_ffn(x, wg, wu, wd)
-        y_exp = gm.grouped_ffn(x, wg, wu, wd, **gm.DEFAULT_BLOCKS)
+        # d == h: gate, up and down are one shape and share one plan
+        y_exp = gm.grouped_ffn(x, wg, wu, wd, **gm.tile_plan(8, 16, 16, 4))
         assert jnp.all(y_off == y_exp)
         assert tuning.provenance() is None  # disabled: logs nothing
         # now a DB with a record for THIS key must hit
@@ -430,6 +500,114 @@ def test_grouped_ffn_empty_db_bit_identity(tmp_path, monkeypatch):
         assert float(jnp.max(jnp.abs(y_tuned - y_off))) < 1e-5
     finally:
         tuning.reset(clear_env=True)
+
+
+# ------------------------------------------------- the tile plan alone
+# the four matmuls the benchmark's cells run, bf16: (E, C, K, N), counts
+# as a window of the cell reads them (one expert emptied, to walk the
+# steps that look past it), and the most bytes a call may move (inputs
+# fetched plus output written).  The frozen triple (512, 1024, 1024)
+# halved to divisors walked 3.7 GB of inputs for the first: 2816 steps
+# of a 1 MiB activation tile and a 256 KiB weight block each.
+_KIMI_ROWS = (1536, 1210, 1922, 1405, 2344, 0, 1660, 1490, 1380, 1777,
+              1033, 1605, 1850, 1290, 1512, 1462)
+_MIXTRAL_ROWS = (2560, 2560, 1800, 1700, 2204, 2560, 1500, 1500)
+_CELL_MATMULS = {
+    "kimi_gate_up": (16, 4096, 2048, 1408, _KIMI_ROWS, 0.5e9),
+    "kimi_down": (16, 4096, 1408, 2048, _KIMI_ROWS, 0.5e9),
+    "mixtral_gate_up": (8, 2560, 4096, 14336, _MIXTRAL_ROWS, 2.8e9),
+    "mixtral_down": (8, 2560, 14336, 4096, _MIXTRAL_ROWS, 3.5e9),
+}
+
+
+def _walk(e, c, kdim, n, counts):
+    """The grid of the planned kernel, step by step, with its index
+    maps as plain functions: ``[(live, x block, w block)]`` and the
+    blocks ``(bc, bn, bk)``."""
+    plan = gm.tile_plan(c, kdim, n, 2)
+    bc, bn, bk = plan["block_c"], plan["block_n"], plan["block_k"]
+    nc, nn, nk = c // bc, n // bn, kdim // bk
+    inside = gm.n_outer(c, kdim, n, bc, bn, bk)
+    cnt = np.asarray(counts, np.int32)
+    te, tc = (np.asarray(t) for t in gm.hold_table(jnp.asarray(cnt), bc))
+    x_index, w_index, _ = gm.index_maps(bc, nn, nk, inside)
+    steps = []
+    for idx in np.ndindex(*((e, nn, nc, nk) if inside else (e, nc, nn, nk))):
+        ci = idx[2] if inside else idx[1]
+        steps.append((bool(ci * bc < cnt[idx[0]]),
+                      tuple(int(v) for v in x_index(*idx, cnt, te, tc)),
+                      tuple(int(v) for v in w_index(*idx, cnt, te, tc)),
+                      idx))
+    return steps, (bc, bn, bk), inside
+
+
+@pytest.mark.parametrize("name", _CELL_MATMULS)
+def test_tile_plan_of_the_cells_matmuls(name):
+    """From shapes alone: the blocks divide, a step's tiles fit the
+    budget (and so the Mosaic limit), the contraction is whole and no
+    output tile is narrower than its dimension allows; walking the
+    grid, a live step names its own blocks, a step past the count
+    names the next live step's (so consecutive ones name the same and
+    the pipeline fetches nothing of their own), and the bytes a call
+    moves stay under the case's figure."""
+    from dlnetbench_tpu.ops import pallas_common
+    e, c, kdim, n, counts, most = _CELL_MATMULS[name]
+    steps, (bc, bn, bk), inside = _walk(e, c, kdim, n, counts)
+    assert c % bc == 0 and n % bn == 0 and kdim % bk == 0
+    assert gm.tile_bytes(bc, bn, bk, 2) <= gm.VMEM_BUDGET \
+        < pallas_common.DEFAULT_VMEM_LIMIT_MB * 2 ** 20
+    assert bc == gm.ROW_BLOCK and bk == kdim
+    if name.startswith("kimi"):
+        # 1408 = 11 x 128 whole, never 128: an expert's weight resident
+        assert bn == n and not inside
+    else:
+        # a weight block stays while the expert's rows pass
+        assert bn % 128 == 0 and bn >= 1024 and inside
+    needed_x = {x for live, x, _, _ in steps if live}
+    needed_w = {w for live, _, w, _ in steps if live}
+    fetched, held = 0, (None, None)
+    for i, (live, x, w, idx) in enumerate(steps):
+        if live:
+            ci, ni = (idx[2], idx[1]) if inside else (idx[1], idx[2])
+            assert x == (idx[0], ci, idx[3]) and w == (idx[0], idx[3], ni)
+        else:
+            # whatever it names, a live step needs
+            assert x in needed_x and w in needed_w
+            if i and not steps[i - 1][0]:
+                assert (x, w) == steps[i - 1][1:3]
+            nxt = next((s for s in steps[i + 1:] if s[0]), None)
+            if nxt is not None:
+                assert (x, w) == nxt[1:3]
+        fetched += (x != held[0]) * bc * bk * 2 + (w != held[1]) * bk * bn * 2
+        held = (x, w)
+    assert fetched + e * c * n * 2 < most
+    # each input block comes once for every run of steps that use it
+    rows = sum(-(-r // bc) * bc for r in counts)
+    if name.startswith("kimi"):
+        assert fetched == rows * kdim * 2 + sum(
+            r > 0 for r in counts) * kdim * n * 2
+
+
+def test_tile_plan_takes_a_dimension_whole_or_by_lane_multiples():
+    """Never by halving: 1408 is 1408 when it fits and 11 x 128-lane
+    blocks' divisor when it does not; a budget nothing fits gives the
+    smallest tiles there are, not an error."""
+    assert gm.blocks_of(1408, 128) == [1408, 128]
+    assert gm.blocks_of(14336, 128)[:4] == [14336, 7168, 3584, 2048]
+    assert gm.blocks_of(88, 128) == [88]
+    whole = gm.tile_plan(4096, 2048, 1408, 2)
+    assert (whole["block_n"], whole["block_k"]) == (1408, 2048)
+    tight = gm.tile_plan(4096, 2048, 1408, 2, budget=8 * 2 ** 20)
+    assert tight["block_n"] == 1408 and tight["block_k"] < 2048
+    assert 2048 % tight["block_k"] == 0 and tight["block_k"] % 128 == 0
+    least = gm.tile_plan(4096, 2048, 1408, 2, budget=1)
+    assert (least["block_n"], least["block_k"]) == (128, 128)
+    # rows: a divisor that is a multiple of the dtype's sublane tile
+    assert gm.tile_plan(2560, 4096, 14336, 2)["block_c"] == 256
+    assert gm.tile_plan(24, 32, 48, 4)["block_c"] == 24
+    # the quantizing prologue's float32 copy of the rows counts too
+    assert gm.tile_bytes(512, 512, 14336, 2, quantized=True) \
+        == gm.tile_bytes(512, 512, 14336, 2) + 512 * 14336 * 4
 
 
 def test_moe_grouped_matches_sparse_lossless():
