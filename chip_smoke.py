@@ -114,6 +114,16 @@ class Sizes:
         moe_intermediate_size=128, kv_lora_rank=128, qk_nope_head_dim=128,
         qk_rope_head_dim=64, v_head_dim=128, vocab_size=512))
     l_seq: int = 512
+    # linear_moe: the published lanes a head (128 of the rule's keys and
+    # values, 256 of the gated attention's with 64 rotated), one period
+    # of two layers, half the experts held
+    q_shape: dict = dataclasses.field(default_factory=lambda: dict(
+        hidden_size=256, num_attention_heads=2, num_key_value_heads=1,
+        head_dim=256, intermediate_size=512, moe_intermediate_size=128,
+        shared_expert_intermediate_size=128, linear_num_key_heads=2,
+        linear_num_value_heads=4, linear_key_head_dim=128,
+        linear_value_head_dim=128, vocab_size=512))
+    q_seq: int = 1024
     # four chips
     c4_fsdp_model: str = "llama3_8b_16_bfloat16"
     c4_fsdp_scale: float = 0.125
@@ -152,6 +162,13 @@ TINY = Sizes(
                  kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
                  v_head_dim=16, vocab_size=256),
     l_seq=128,
+    q_shape=dict(hidden_size=64, num_attention_heads=4,
+                 num_key_value_heads=2, head_dim=32, intermediate_size=128,
+                 moe_intermediate_size=32,
+                 shared_expert_intermediate_size=32, linear_num_key_heads=2,
+                 linear_num_value_heads=4, linear_key_head_dim=16,
+                 linear_value_head_dim=16, vocab_size=256),
+    q_seq=128,
     c4_fsdp_scale=1e-5, c4_h3d_scale=1e-5, c4_time_scale=1e-4, c4_batch=4,
     c4_seq=128,
 )
@@ -919,6 +936,39 @@ def phase_hybrid(sz: Sizes) -> dict:
                       "attention kernels compiled in"}
 
 
+def expert_step_checks(what: str, prog, want: dict, steps: int):
+    """Run an expert decoder's chained train steps once and hold them to
+    the float32 reference's: no row past the bound, the first loss, the
+    loss's fall over ``steps`` and the first step's selections within
+    tolerance.  Returns (losses, routing, the selections' gap)."""
+    import jax
+
+    from benchmarks.runners import train_latent_moe
+    _, (losses, routing) = jax.block_until_ready(prog())
+    got = [float(v) for v in losses]
+    require(int(routing["past_bound"].sum()) == 0
+            and int(routing["routed"][0]) > 0,
+            f"{what} step: routing {routing}")
+    gap = train_latent_moe.selection_gap(
+        jax.device_get(routing["choices"][0]), want["chosen"])
+    tol_first, tol_drop, tol_gap = 0.02, 0.35, 0.05
+    require(abs(got[0] - want["losses"][0])
+            <= tol_first * want["losses"][0],
+            f"{what} step: first loss {got[0]} vs float32 "
+            f"reference {want['losses'][0]} (tolerance {tol_first})")
+    drop_got = got[0] - got[-1]
+    drop_want = want["losses"][0] - want["losses"][-1]
+    require(drop_want > 0 and abs(drop_got - drop_want)
+            <= tol_drop * drop_want,
+            f"{what} step: loss fell {drop_got} over "
+            f"{steps} steps, reference {drop_want} (tolerance "
+            f"{tol_drop} relative)")
+    require(gap <= tol_gap, f"{what} step: {gap} of the "
+                            f"(token, choice) pairs differ from the "
+                            f"reference's (tolerance {tol_gap})")
+    return got, routing, gap
+
+
 # ------------------------------------------------------ phase: latent_moe
 
 def phase_latent_moe(sz: Sizes) -> dict:
@@ -968,28 +1018,7 @@ def phase_latent_moe(sz: Sizes) -> dict:
         # expert layer, each at least once
         require(kernels >= 6, f"compiled latent-attention step holds "
                               f"{kernels} tpu_custom_call")
-    _, (losses, routing) = jax.block_until_ready(prog())
-    got = [float(v) for v in losses]
-    require(int(routing["past_bound"].sum()) == 0
-            and int(routing["routed"][0]) > 0,
-            f"latent-attention step: routing {routing}")
-    gap = train_latent_moe.selection_gap(
-        jax.device_get(routing["choices"][0]), want["chosen"])
-    tol_first, tol_drop, tol_gap = 0.02, 0.35, 0.05
-    require(abs(got[0] - want["losses"][0])
-            <= tol_first * want["losses"][0],
-            f"latent-attention step: first loss {got[0]} vs float32 "
-            f"reference {want['losses'][0]} (tolerance {tol_first})")
-    drop_got = got[0] - got[-1]
-    drop_want = want["losses"][0] - want["losses"][-1]
-    require(drop_want > 0 and abs(drop_got - drop_want)
-            <= tol_drop * drop_want,
-            f"latent-attention step: loss fell {drop_got} over "
-            f"{sz.h_k} steps, reference {drop_want} (tolerance "
-            f"{tol_drop} relative)")
-    require(gap <= tol_gap, f"latent-attention step: {gap} of the "
-                            f"(token, choice) pairs differ from the "
-                            f"reference's (tolerance {tol_gap})")
+    got, routing, gap = expert_step_checks("latent-attention", prog, want, sz.h_k)
     return {"shapes": {**sz.l_shape, "seq": sz.l_seq, "batch": 2,
                        "experts": 8, "held": [2, 4], "top_k": 3,
                        "slots": slots, "steps": sz.h_k, "lr": sz.h_lr},
@@ -1004,6 +1033,76 @@ def phase_latent_moe(sz: Sizes) -> dict:
                       "benchmarks/reference_latent_moe.py; no row past "
                       "the bound; attention and expert kernels compiled "
                       "in"}
+
+
+# ------------------------------------------------------ phase: linear_moe
+
+def phase_linear_moe(sz: Sizes) -> dict:
+    """The linear-attention expert decoder (models/hybrid.py: a ``gdn``
+    and a ``gated`` layer, routed experts beside a gated shared one, 4
+    of the router's 8 experts held) through the same step builder and
+    executor as ``phase_train``, the rule's Pallas sweep and the
+    attention kernels forced, against the benchmark's plain float32
+    reference (the token recurrence) on the same seeded weights."""
+    import jax
+
+    from benchmarks import reference_linear_moe, weights_linear_moe
+    from benchmarks.runners import train_linear_moe
+    from dlnetbench_tpu.core import executor
+    from dlnetbench_tpu.models import bench_step
+
+    config = {
+        **sz.q_shape, "num_hidden_layers": 2, "full_attention_interval": 2,
+        "num_experts": 4, "published": {"num_experts": 8},
+        "num_experts_per_tok": 3, "linear_conv_kernel_dim": 4,
+        "partial_rotary_factor": 0.25, "rope_theta": 10000000,
+        "rms_norm_eps": 1e-6, "mlp_only_layers": [],
+        "decoder_sparse_step": 1, "norm_topk_prob": True,
+        "rope_scaling": None, "use_sliding_window": False,
+        "tie_word_embeddings": False, "hidden_act": "silu",
+        "torch_dtype": sz.dtype,
+        "assumed": {"first_held_expert": 2, "decay_max": 16.0}}
+    arch = weights_linear_moe.arch_of(config)
+    slots = 2 * sz.q_seq        # every row of the batch: no bound to reach
+    cfg = train_linear_moe.config_of(
+        arch, sz.q_seq, slots, remat=True, attention_impl="flash",
+        rule_impl="pallas", loss_row_block=sz.q_seq)
+
+    def make_params():
+        return weights_linear_moe.make_params(arch, sz.seed)
+    tokens = weights_linear_moe.make_token_pool(
+        sz.seed, 1, 2, sz.q_seq + 1, arch["vocab_size"])[0]
+    want = reference_linear_moe.sgd_steps(
+        make_params, [tokens] * sz.h_k, arch, sz.h_lr)
+    prog = executor.CompiledProgram(executor.Program(
+        fn=bench_step.make_train_k(cfg, sz.h_k, sz.h_lr),
+        args=(make_params(), tokens),
+        donate_argnums=bench_step.DONATE_ARGNUMS))
+    text = prog.as_text()
+    kernels = text.count("tpu_custom_call")
+    if on_tpu():
+        # the rule's two sweeps, three attention kernels, three grouped
+        # matmuls a layer, each at least once
+        require(kernels >= 8 and "gdr_fwd" in text and "gdr_bwd" in text,
+                f"compiled linear-attention step holds {kernels} "
+                f"tpu_custom_call")
+    got, routing, gap = expert_step_checks("linear-attention", prog, want, sz.h_k)
+    return {"shapes": {**sz.q_shape, "seq": sz.q_seq, "batch": 2,
+                       "layers": list(arch["layer_kinds"]), "experts": 8,
+                       "held": [2, 4], "top_k": 3, "slots": slots,
+                       "steps": sz.h_k, "lr": sz.h_lr},
+            "tpu_custom_calls": kernels,
+            "memory_analysis": prog.memory_analysis,
+            "losses": [round(v, 4) for v in got],
+            "float32_losses": [round(v, 4) for v in want["losses"]],
+            "rows_routed_to_held": int(routing["routed"][0]),
+            "selection_gap": gap,
+            "checks": "first loss, the loss's fall and the selections "
+                      "within tolerance of "
+                      "benchmarks/reference_linear_moe.py (the token "
+                      "recurrence); no row past the bound; the rule's, "
+                      "the attention's and the experts' kernels "
+                      "compiled in"}
 
 
 # ------------------------------------------------------ four-chip phases
@@ -1213,7 +1312,8 @@ def phase_kv_shard(sz: Sizes) -> dict:
 ONE_CHIP = (("kernels", phase_kernels), ("train", phase_train),
             ("proxy", phase_proxy), ("serve", phase_serve),
             ("moe", phase_moe), ("hybrid", phase_hybrid),
-            ("latent_moe", phase_latent_moe))
+            ("latent_moe", phase_latent_moe),
+            ("linear_moe", phase_linear_moe))
 FOUR_CHIPS = (("mesh_proxies", phase_mesh_proxies), ("spmd", phase_spmd),
               ("kv_shard", phase_kv_shard))
 

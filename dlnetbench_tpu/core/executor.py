@@ -107,6 +107,8 @@ _HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
 _HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$")
 _HLO_OPCODE = re.compile(r"\s(fusion|dot|convolution)\(")
 _HLO_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_HLO_LOOP = re.compile(r"\swhile\(.*\bcondition=%?([\w.\-]+), "
+                       r"body=%?([\w.\-]+)")
 _HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
 _HLO_TRANSFORM = re.compile(r"(\w+)\((.*)\)")
 
@@ -142,12 +144,18 @@ def hlo_op_scopes(hlo_text: str, vocabulary=spans.SCOPES) -> dict[str, str]:
     inside the computation it calls where that has one, else of that
     computation's root (where the root carries none, being a tuple of
     outputs or a bitcast, of the last instruction before it that does),
-    else its own.  A custom call (a Pallas kernel) keeps its own.
+    else its own.  A custom call (a Pallas kernel) keeps its own.  What
+    still has none inside a loop's body or condition (the copies and
+    slices the compiler adds to prefetch a loop's operands carry no
+    ``op_name``) takes the loop's: the trace holds the loop's event
+    and its body's, and time inside a loop belongs to one scope.
     ``spans.OTHER_SCOPE`` where there is none."""
     own: dict[str, str | None] = {}     # instruction -> scope or None
     fusions: dict[str, str] = {}        # fusion instruction -> callee
     matmul: dict[str, str] = {}         # computation -> its dot's scope
     root: dict[str, str] = {}           # computation -> its root's scope
+    where: dict[str, str] = {}          # instruction -> its computation
+    loop_of: dict[str, str] = {}        # body or condition -> its while
     comp = None
     for line in hlo_text.splitlines():
         m = _HLO_INSTRUCTION.match(line)
@@ -160,6 +168,10 @@ def hlo_op_scopes(hlo_text: str, vocabulary=spans.SCOPES) -> dict[str, str]:
         name = _HLO_OP_NAME.search(meta)
         scope = scope_of_op_name(name.group(1), vocabulary) if name else None
         own[m.group(1)] = scope
+        where[m.group(1)] = comp
+        loop = _HLO_LOOP.search(head)
+        if loop:
+            loop_of.update(dict.fromkeys(loop.groups(), m.group(1)))
         op = _HLO_OPCODE.search(head)
         kind = op.group(1) if op else None
         if kind == "fusion":
@@ -173,7 +185,14 @@ def hlo_op_scopes(hlo_text: str, vocabulary=spans.SCOPES) -> dict[str, str]:
             root[comp] = scope
     for inst, callee in fusions.items():
         own[inst] = matmul.get(callee) or root.get(callee) or own[inst]
-    return {k: v or spans.OTHER_SCOPE for k, v in own.items()}
+
+    def scoped(inst):
+        """``inst``'s scope, or that of the innermost loop around it
+        that has one."""
+        while inst is not None and own[inst] is None:
+            inst = loop_of.get(where[inst])
+        return own[inst] if inst is not None else None
+    return {k: scoped(k) or spans.OTHER_SCOPE for k in own}
 
 
 class _Compiled:

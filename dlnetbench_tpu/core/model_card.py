@@ -36,6 +36,7 @@ class MoEParams:
                                     # selection bias, normalised, scaled
     routed_scale: float = 1.0       # times the normalised weights
     shared_experts: int = 0         # experts every token passes through
+    shared_gate: bool = False       # ... times sigmoid(y w) a token
     expert_ff_dim: int = 0          # an expert's width; 0 => ff_dim
     first_dense_layers: int = 0     # leading layers with a dense FFN
 
@@ -60,7 +61,7 @@ class ModelCard:
     patch_size: int = 0             # ViT
     num_classes: int = 0            # ViT head
     # a per-layer pattern of mixers (models/hybrid.py KINDS: mamba,
-    # window, full, gmu, cross), one name a decoder block; () => every
+    # window, full, gmu, cross, mla, gdn, gated), one name a decoder block; () => every
     # block is the transformer's one kind (models/transformer.py)
     layer_kinds: tuple = ()
     sliding_window: int = 0         # keys a "window" layer attends
@@ -80,6 +81,17 @@ class ModelCard:
     rms_norm: bool = False          # a layer_kinds model's norm: RMSNorm
                                     # without bias (else LayerNorm)
     norm_eps: float = 0.0           # 0 => the model family's default
+    norm_plus_one: bool = False     # the RMSNorm scales by 1 + w
+    # gated softmax attention (a "gated" layer): heads of attn_head_dim
+    # lanes (0 => embed_dim / num_heads), RoPE on the first rope_dim
+    attn_head_dim: int = 0
+    rope_dim: int = 0
+    # linear attention with a matrix state (a "gdn" layer)
+    linear_key_heads: int = 0
+    linear_value_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_conv: int = 0            # depthwise causal conv width
 
     # ------------------------------------------------------------------ #
     @property
@@ -140,6 +152,17 @@ class ModelCard:
             return 2 * d * e
         if kind == "cross":
             return 2 * d * d            # queries and output only
+        if kind == "gdn":
+            hv = self.linear_value_heads
+            qk = self.linear_key_heads * self.linear_key_dim
+            vz = hv * self.linear_value_dim
+            return (d * (2 * qk + 2 * vz) + d * 2 * hv
+                    + self.linear_conv * (2 * qk + vz) + 2 * hv
+                    + self.linear_value_dim + vz * d)
+        if kind == "gated":
+            dh = self.attn_head_dim or self.head_dim
+            dq, dkv = self.num_heads * dh, self.kv_heads * dh
+            return d * 2 * dq + 2 * d * dkv + 2 * dh + dq * d
         if kind == "mla":
             h, r = self.num_heads, self.kv_lora_rank
             qk = self.qk_nope_head_dim + self.qk_rope_head_dim
@@ -156,6 +179,7 @@ class ModelCard:
             return self.mlp_params_per_expert()
         experts = moe.num_experts + moe.shared_experts
         bias = moe.num_experts if moe.scoring == "sigmoid" else 0
+        bias += d if moe.shared_gate else 0
         return (experts * self.routed_expert_params()
                 + d * moe.num_experts + bias)
 

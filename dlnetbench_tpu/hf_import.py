@@ -6,7 +6,7 @@ for this framework: what every downstream layer consumes is the
 *architecture card* (core/model_card.py), so the useful artifact of "import
 a HF model" is a card, not a cache of safetensors.  This module maps a HF
 config (``model_type`` gpt2 / llama / mistral / mixtral / phi4flash /
-deepseek_v3 as Kimi-VL and Moonlight state it / vit) onto
+deepseek_v3 as Kimi-VL and Moonlight state it / qwen3_next / vit) onto
 ``ModelCard`` fields and writes the card JSON.
 
 Offline-first: hub access is attempted only when requested and is never
@@ -101,6 +101,9 @@ def card_from_hf_config(name: str, cfg: Mapping[str, Any] | Any) -> ModelCard:
 
     if mt == "phi4flash":
         return _phi4flash_card(name, cfg)
+
+    if mt == "qwen3_next":
+        return _linear_moe_card(name, cfg)
 
     if mt == "vit":
         image = int(cfg["image_size"])
@@ -213,6 +216,78 @@ def _latent_moe_card(name: str, cfg: Mapping[str, Any]) -> ModelCard:
             shared_experts=int(cfg.get("n_shared_experts") or 0),
             expert_ff_dim=int(cfg["moe_intermediate_size"]),
             first_dense_layers=int(cfg.get("first_k_dense_replace", 0)),
+        ),
+    )
+
+
+def linear_layer_kinds(num_layers: int, full_interval: int) -> tuple:
+    """``layer_types`` as the qwen3_next configuration derives it when
+    ``config.json`` leaves it out: every ``full_interval``-th layer is
+    gated softmax attention, the others Gated DeltaNet."""
+    return tuple("gated" if (i + 1) % full_interval == 0 else "gdn"
+                 for i in range(num_layers))
+
+
+def _linear_moe_card(name: str, cfg: Mapping[str, Any]) -> ModelCard:
+    """``model_type: "qwen3_next"``: Gated DeltaNet layers with one
+    gated softmax attention layer a period, a zero-centred RMSNorm,
+    softmax-routed experts in every layer beside a shared expert behind
+    a sigmoid gate.  Refused, because no layer here computes them: a
+    dense FFN in some layers (``mlp_only_layers``, ``decoder_sparse_step``
+    > 1), unnormalised top-k weights, scaled RoPE, a sliding window, a
+    shared expert whose width is no multiple of a routed one's.  The
+    multi-token-prediction module is not in ``config.json``'s keys read
+    here and is not built."""
+    width = int(cfg["moe_intermediate_size"])
+    shared = int(cfg.get("shared_expert_intermediate_size") or 0)
+    unsupported = {k: cfg.get(k) for k, ok in (
+        ("mlp_only_layers", (None, [], ())),
+        ("decoder_sparse_step", (None, 1)),
+        ("norm_topk_prob", (True,)), ("rope_scaling", (None,)),
+        ("use_sliding_window", (None, False)),
+        ("attention_bias", (None, False))) if cfg.get(k) not in ok}
+    if shared % width:
+        unsupported["shared_expert_intermediate_size"] = shared
+    if unsupported:
+        raise ValueError(f"{name}: linear-attention import has no "
+                         f"{unsupported}")
+    layers = int(cfg["num_hidden_layers"])
+    heads = int(cfg["num_attention_heads"])
+    head_dim = int(cfg.get("head_dim") or cfg["hidden_size"] // heads)
+    kinds = tuple(
+        {"linear_attention": "gdn", "full_attention": "gated"}[t]
+        for t in cfg["layer_types"]) if cfg.get("layer_types") else \
+        linear_layer_kinds(layers, int(cfg.get("full_attention_interval", 4)))
+    return ModelCard(
+        name=name,
+        embed_dim=int(cfg["hidden_size"]),
+        num_heads=heads,
+        num_kv_heads=int(cfg.get("num_key_value_heads") or heads),
+        ff_dim=int(cfg["intermediate_size"]),
+        seq_len=int(cfg["max_position_embeddings"]),
+        num_decoder_blocks=layers,
+        vocab_size=int(cfg["vocab_size"]),
+        gated_mlp=True,
+        tied_embeddings=bool(cfg.get("tie_word_embeddings", False)),
+        layer_kinds=kinds,
+        attn_head_dim=head_dim,
+        rope_dim=int(head_dim * float(cfg.get("partial_rotary_factor", 1.0))),
+        rope_theta=float(cfg.get("rope_theta", 10000.0)),
+        rms_norm=True,
+        norm_plus_one=True,
+        norm_eps=float(cfg.get("rms_norm_eps", 1e-6)),
+        linear_key_heads=int(cfg["linear_num_key_heads"]),
+        linear_value_heads=int(cfg["linear_num_value_heads"]),
+        linear_key_dim=int(cfg["linear_key_head_dim"]),
+        linear_value_dim=int(cfg["linear_value_head_dim"]),
+        linear_conv=int(cfg["linear_conv_kernel_dim"]),
+        moe_params=MoEParams(
+            num_experts=int(cfg["num_experts"]),
+            num_experts_per_tok=int(cfg["num_experts_per_tok"]),
+            scoring="softmax",
+            shared_experts=shared // width,
+            shared_gate=bool(shared),
+            expert_ff_dim=width,
         ),
     )
 

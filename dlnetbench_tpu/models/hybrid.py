@@ -3,10 +3,13 @@ run through it: the SambaY hybrid (arXiv:2507.06607: Mamba,
 sliding-window, full and cross attention and gated memory units in one
 stack, differential attention (arXiv:2410.05258) in every attention
 layer, LayerNorm with bias around a SwiGLU MLP, no positional encoding,
-the embedding tied to the head) and the latent-attention expert models
+the embedding tied to the head), the latent-attention expert models
 of the DeepSeek-V3 family as Kimi-VL-A3B and Moonlight state them (an
 ``mla`` mixer in every layer, RMSNorm, a leading dense layer and then
-routed experts beside shared ones, an untied head).
+routed experts beside shared ones, an untied head) and the
+linear-attention expert models of the Qwen3-Next family (``gdn`` layers
+with one ``gated`` layer a period, a zero-centred RMSNorm, routed
+experts beside a shared one behind a sigmoid gate).
 
 ``HybridConfig.layer_kinds`` names each layer's mixer, one of ``KINDS``:
 
@@ -24,6 +27,18 @@ routed experts beside shared ones, an untied head).
   shared by all heads, and the scores run over ``qk_nope + qk_rope``
   lanes beside values of ``v_head_dim`` (``ops.attention`` with two
   widths); plain softmax, no differential pairing.
+* ``gdn``    — Gated DeltaNet linear attention (arXiv:2412.06464): one
+  projection to queries, keys, values and an output gate, a depthwise
+  causal convolution and SiLU over the first three, queries and keys
+  normed to unit length a head, a matrix state a value head decayed by
+  ``exp(-exp(a_log) softplus(a + dt_bias))`` and corrected by a delta
+  of strength ``sigmoid(b)`` a token (``ops.gated_delta_rule``), an
+  RMSNorm over each head's output times ``silu`` of the gate.
+* ``gated``  — softmax attention with an output gate: the query
+  projection carries a gate of the head's width beside each head's
+  query, queries and keys are normed a head, RoPE turns the first
+  ``rope_dim`` lanes, and ``sigmoid(gate)`` multiplies the heads'
+  output before the output projection.
 
 Every layer is ``x += mixer(norm(x)); x += ffn(norm(x))``.
 ``ffn_kinds`` names each layer's FFN: ``dense`` (SwiGLU at ``ff_dim``)
@@ -31,7 +46,8 @@ or ``moe``: ``sum_i w_i expert_i(y)`` over the token's top-k of
 ``num_experts`` routed experts, of which this chip holds
 ``held_experts`` (``models/moe.moe_held``: the router scores and
 selects over all of them, no token is dropped), plus one shared SwiGLU
-at ``shared_ff_dim`` that every token passes through.  Parameters are
+at ``shared_ff_dim`` that every token passes through (times
+``sigmoid(y w_sg)`` a token where ``shared_gate``).  Parameters are
 stacked by kind (``block`` holds both norms of every layer and the MLP
 of every dense one; ``moe`` the expert layers' router, routed and shared
 experts), layers are unrolled as the bench recipe unrolls them, and
@@ -59,14 +75,16 @@ from dlnetbench_tpu.metrics.spans import scope
 from dlnetbench_tpu.models import layers as L
 from dlnetbench_tpu.models.moe import moe_held
 from dlnetbench_tpu.ops.attention_mask import MaskSpec
+from dlnetbench_tpu.ops.gated_delta_rule import gated_delta_rule
 from dlnetbench_tpu.ops.selective_scan import selective_scan
 
 _F32 = jnp.float32
-KINDS = ("mamba", "window", "full", "gmu", "cross", "mla")
+KINDS = ("mamba", "window", "full", "gmu", "cross", "mla", "gdn", "gated")
 FFN_KINDS = ("dense", "moe")
 # which stack of parameters a layer's mixer reads
 GROUP_OF = {"mamba": "mamba", "window": "attn", "full": "attn",
-            "gmu": "gmu", "cross": "cross", "mla": "mla"}
+            "gmu": "gmu", "cross": "cross", "mla": "mla", "gdn": "gdn",
+            "gated": "gated"}
 # what a step with expert layers returns beside its loss
 # (``models/moe.moe_held``): three counters over its expert layers (the
 # rows routed to held experts and the rows past the bound summed, the
@@ -79,7 +97,7 @@ F32_LEAVES = frozenset({
     "norm1", "norm1_b", "norm2", "norm2_b", "final_norm", "final_norm_b",
     "a_log", "d_skip", "b_dt", "conv_b", "sub_norm",
     "lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2",
-    "kv_norm", "router_bias"})
+    "kv_norm", "router_bias", "dt_bias", "o_norm", "q_norm", "k_norm"})
 _SPLASH_BLOCKS = (2048, 1024, 512, 256, 128)
 
 
@@ -108,6 +126,8 @@ class HybridConfig:
                                     # [T, V] logits never lie whole in
                                     # HBM; 0 = whole
     rms_norm: bool = False          # RMSNorm without bias, else LayerNorm
+    norm_plus_one: bool = False     # the RMSNorm scales by 1 + w (w zero
+                                    # at the start), not by w
     tied_head: bool = True          # the head is the embedding table
     # latent attention ("mla" layers)
     kv_lora_rank: int = 0
@@ -115,12 +135,22 @@ class HybridConfig:
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
     rope_theta: float = 10000.0
+    # gated attention ("gated" layers) and linear attention ("gdn")
+    attn_head_dim: int = 0          # 0 = embed_dim / num_heads
+    rope_dim: int = 0               # leading lanes of a head RoPE turns
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 0            # lanes of a key head
+    gdn_value_dim: int = 0          # lanes of a value head
+    gdn_conv: int = 4               # taps of the depthwise causal conv
+    rule_impl: str = "auto"         # ops.gated_delta_rule: auto|pallas|xla
     # the FFN of each layer; () = every layer dense
     ffn_kinds: tuple = ()
     num_experts: int = 0            # the router's outputs
     top_k: int = 0
     expert_ff_dim: int = 0
     shared_ff_dim: int = 0          # 0 = no shared expert
+    shared_gate: bool = False       # the shared expert times sigmoid(y w)
     router_scoring: str = "softmax"  # layers.moe_router's gate
     routed_scale: float = 1.0
     held_experts: tuple = ()        # (first, count) of the routed experts
@@ -156,6 +186,19 @@ class HybridConfig:
             raise ValueError("mla layers need kv_lora_rank, "
                              "qk_nope_head_dim, an even qk_rope_head_dim "
                              "and v_head_dim")
+        if "gdn" in kinds and not (
+                self.gdn_key_heads and self.gdn_key_dim
+                and self.gdn_value_dim and self.gdn_value_heads
+                and self.gdn_value_heads % self.gdn_key_heads == 0):
+            raise ValueError("gdn layers need gdn_key_heads dividing "
+                             "gdn_value_heads, gdn_key_dim and "
+                             "gdn_value_dim")
+        if "gated" in kinds and (self.num_heads % self.num_kv_heads
+                                 or self.rope_dim % 2
+                                 or self.rope_dim > self.head_dim):
+            raise ValueError("gated layers need num_kv_heads dividing "
+                             "num_heads and an even rope_dim within the "
+                             "head")
         if set(kinds) & {"window", "full", "cross"} and (
                 self.num_heads % 2 or self.num_kv_heads % 2
                 or self.num_heads % self.num_kv_heads):
@@ -180,7 +223,14 @@ class HybridConfig:
                              f"models.transformer")
         kinds = tuple(layer_kinds or card.layer_kinds)
         stated = {"rms_norm": card.rms_norm,
+                  "norm_plus_one": card.norm_plus_one,
                   "tied_head": card.tied_embeddings,
+                  "attn_head_dim": card.attn_head_dim,
+                  "rope_dim": card.rope_dim,
+                  "gdn_key_heads": card.linear_key_heads,
+                  "gdn_value_heads": card.linear_value_heads,
+                  "gdn_key_dim": card.linear_key_dim,
+                  "gdn_value_dim": card.linear_value_dim,
                   "kv_lora_rank": card.kv_lora_rank,
                   "qk_nope_head_dim": card.qk_nope_head_dim,
                   "qk_rope_head_dim": card.qk_rope_head_dim,
@@ -189,6 +239,8 @@ class HybridConfig:
             stated["rope_theta"] = card.rope_theta
         if card.norm_eps:
             stated["norm_eps"] = card.norm_eps
+        if card.linear_conv:
+            stated["gdn_conv"] = card.linear_conv
         if (moe := card.moe_params) is not None:
             width = moe.expert_ff_dim or card.ff_dim
             stated.update(
@@ -198,6 +250,7 @@ class HybridConfig:
                 num_experts=moe.num_experts,
                 top_k=moe.num_experts_per_tok, expert_ff_dim=width,
                 shared_ff_dim=moe.shared_experts * width,
+                shared_gate=moe.shared_gate,
                 router_scoring=moe.scoring,
                 routed_scale=moe.routed_scale)
         return cls(vocab_size=card.vocab_size, embed_dim=card.embed_dim,
@@ -215,7 +268,7 @@ class HybridConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.embed_dim // self.num_heads
+        return self.attn_head_dim or self.embed_dim // self.num_heads
 
     @property
     def dt_rank(self) -> int:
@@ -246,7 +299,7 @@ class HybridConfig:
         return "moe" in self.ffn_kinds
 
     def group_sizes(self) -> dict:
-        out = {g: 0 for g in ("mamba", "attn", "gmu", "cross", "mla")}
+        out = {g: 0 for g in set(GROUP_OF.values())}
         for k in self.layer_kinds:
             out[GROUP_OF[k]] += 1
         return out
@@ -262,7 +315,8 @@ def param_shapes(cfg: HybridConfig) -> dict:
     """{"group/leaf" or "leaf": (shape, init)}: the layout both
     ``init_params`` and the benchmark's seeded weights follow.  ``init``
     is a scale for normal draws, or one of "ones", "zeros", "a_log",
-    "b_dt"."""
+    "b_dt", "decay_log".  A norm that scales by ``1 + w`` starts at
+    zero."""
     d, f, v = cfg.embed_dim, cfg.ff_dim, cfg.vocab_size
     e, n, r, w = cfg.ssm_inner, cfg.ssm_state, cfg.dt_rank, cfg.ssm_conv
     dh = cfg.head_dim
@@ -271,10 +325,11 @@ def param_shapes(cfg: HybridConfig) -> dict:
     sizes = cfg.group_sizes()
     s_d = 1.0 / math.sqrt(d)
     nd = cfg.ffn_kinds.count("dense")
+    unit = "zeros" if cfg.norm_plus_one else "ones"
     out = {
-        "final_norm": ((d,), "ones"),
-        "block/norm1": ((nl, d), "ones"),
-        "block/norm2": ((nl, d), "ones"),
+        "final_norm": ((d,), unit),
+        "block/norm1": ((nl, d), unit),
+        "block/norm2": ((nl, d), unit),
     }
     if cfg.tied_head:
         out["embed"] = ((v, d), s_d)  # tied: the table is the head too
@@ -303,6 +358,8 @@ def param_shapes(cfg: HybridConfig) -> dict:
                 "moe/ws_gate": ((m, d, fs), s_d),
                 "moe/ws_up": ((m, d, fs), s_d),
                 "moe/ws_down": ((m, fs, d), 1.0 / math.sqrt(fs))})
+            if cfg.shared_gate:
+                out["moe/ws_sig"] = ((m, d), s_d)
     if (m := sizes["mamba"]):
         out.update({
             "mamba/w_in": ((m, d, 2 * e), s_d),
@@ -341,13 +398,35 @@ def param_shapes(cfg: HybridConfig) -> dict:
             "mla/kv_norm": ((m, r), "ones"),
             "mla/w_kvb": ((m, r, h * (dn + dv)), 1.0 / math.sqrt(r)),
             "mla/wo": ((m, h * dv, d), 1.0 / math.sqrt(h * dv))})
+    if (m := sizes["gdn"]):
+        hv, w = cfg.gdn_value_heads, cfg.gdn_conv
+        qk = cfg.gdn_key_heads * cfg.gdn_key_dim
+        vz = hv * cfg.gdn_value_dim
+        out.update({
+            "gdn/w_qkvz": ((m, d, 2 * qk + 2 * vz), s_d),
+            "gdn/w_ba": ((m, d, 2 * hv), s_d),
+            "gdn/conv_w": ((m, w, 2 * qk + vz), 1.0 / math.sqrt(w)),
+            "gdn/a_log": ((m, hv), "decay_log"),
+            "gdn/dt_bias": ((m, hv), "ones"),
+            "gdn/o_norm": ((m, cfg.gdn_value_dim), "ones"),
+            "gdn/w_out": ((m, vz, d), 1.0 / math.sqrt(vz))})
+    if (m := sizes["gated"]):
+        h, hkv = cfg.num_heads, cfg.num_kv_heads
+        out.update({
+            "gated/wq": ((m, d, 2 * h * dh), s_d),
+            "gated/wk": ((m, d, hkv * dh), s_d),
+            "gated/wv": ((m, d, hkv * dh), s_d),
+            "gated/q_norm": ((m, dh), unit),
+            "gated/k_norm": ((m, dh), unit),
+            "gated/wo": ((m, h * dh, d), 1.0 / math.sqrt(h * dh))})
     return out
 
 
 def init_leaf(key, name: str, shape, init, dtype):
     """One leaf of ``param_shapes``.  ``a_log`` is log(1..N) a channel,
     ``b_dt`` the inverse softplus of a step drawn log-uniform in
-    [1e-3, 1e-1]; the float32 leaves stay float32."""
+    [1e-3, 1e-1], ``decay_log`` the log of a draw uniform in (0, 16);
+    the float32 leaves stay float32."""
     dt = _F32 if name.rsplit("/", 1)[-1] in F32_LEAVES else dtype
     if init == "ones":
         return jnp.ones(shape, dt)
@@ -357,6 +436,9 @@ def init_leaf(key, name: str, shape, init, dtype):
         return jnp.broadcast_to(
             jnp.log(jnp.arange(1, shape[-1] + 1, dtype=_F32)), shape
         ).astype(dt)
+    if init == "decay_log":
+        return jnp.log(jax.random.uniform(key, shape, _F32, 1e-3, 16.0)
+                       ).astype(dt)
     if init == "b_dt":
         step = jnp.exp(jax.random.uniform(
             key, shape, _F32, math.log(1e-3), math.log(1e-1)))
@@ -377,11 +459,17 @@ def init_params(key, cfg: HybridConfig) -> dict:
 
 # ------------------------------------------------------------- mixers
 
+def _norm_scale(cfg, w):
+    """What an RMSNorm multiplies by: its weight, or one more."""
+    return 1.0 + w if cfg.norm_plus_one else w
+
+
 def _norm(cfg, x, p, name: str):
     """The model's norm with the weight ``p[name]`` (and, for LayerNorm,
     the bias ``p[name + "_b"]``)."""
     if cfg.rms_norm:
-        return L.rmsnorm(x, p[name], cfg.norm_eps).astype(x.dtype)
+        return L.rmsnorm(x, _norm_scale(cfg, p[name]),
+                         cfg.norm_eps).astype(x.dtype)
     return L.layernorm(x, p[name], p[name + "_b"],
                        cfg.norm_eps).astype(x.dtype)
 
@@ -390,15 +478,47 @@ def _silu(x):
     return jax.nn.silu(x.astype(_F32)).astype(x.dtype)
 
 
-def _causal_conv(u, w, b):
+def _causal_conv(u, w, b=None):
     """Depthwise causal convolution along time: u [B, S, E], w [K, E]
-    (tap K-1 is the current step), b [E]."""
+    (tap K-1 is the current step), b [E] or None."""
     k, s = w.shape[0], u.shape[1]
     up = jnp.pad(u, ((0, 0), (k - 1, 0), (0, 0))).astype(_F32)
-    out = b.astype(_F32)
+    out = 0.0 if b is None else b.astype(_F32)
     for i in range(k):
         out = out + up[:, i:i + s] * w[i].astype(_F32)
     return out.astype(u.dtype)
+
+
+@jax.custom_vjp
+def _conv_silu(u, w):
+    """``silu(causal conv(u))`` without bias, u [B, S, E], w [K, E].
+    The backward keeps ``u`` alone and makes the convolution again, so
+    that no float32 copy of [B, S, E] lives from the forward to it."""
+    return _silu(_causal_conv(u, w))
+
+
+def _conv_silu_fwd(u, w):
+    return _conv_silu(u, w), (u, w)
+
+
+def _conv_silu_bwd(res, dy):
+    u, w = res
+    k, s = w.shape[0], u.shape[1]
+    with scope("linattn"):
+        up = jnp.pad(u, ((0, 0), (k - 1, 0), (0, 0)))
+        wf = w.astype(_F32)
+        c = sum(up[:, i:i + s].astype(_F32) * wf[i] for i in range(k))
+        sig = jax.nn.sigmoid(c)
+        dc = dy.astype(_F32) * sig * (1.0 + c * (1.0 - sig))
+        dw = jnp.stack([jnp.sum(up[:, i:i + s].astype(_F32) * dc, (0, 1))
+                        for i in range(k)])
+        # tap i of step t reads u[t - (k - 1 - i)]
+        dcp = jnp.pad(dc, ((0, 0), (0, k - 1), (0, 0)))
+        du = sum(dcp[:, k - 1 - i:k - 1 - i + s] * wf[i] for i in range(k))
+        return du.astype(u.dtype), dw.astype(w.dtype)
+
+
+_conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
 
 
 def mamba_mixer(cfg: HybridConfig, y, p):
@@ -494,6 +614,59 @@ def mla_mixer(cfg: HybridConfig, y, p):
     return jnp.dot(o.reshape(b, s, h * dv), p["wo"])
 
 
+def _unit_heads(t, eps: float):
+    """Each head of t [..., H, d] over its length, in float32."""
+    t = t.astype(_F32)
+    return t * jax.lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + eps)
+
+
+def gdn_mixer(cfg: HybridConfig, y, p):
+    """Gated DeltaNet.  Key head ``h // (value heads / key heads)``
+    serves value head ``h``; the decay and the delta's strength are a
+    value head's, in float32."""
+    b, s, _ = y.shape
+    hk, hv = cfg.gdn_key_heads, cfg.gdn_value_heads
+    dk, dv = cfg.gdn_key_dim, cfg.gdn_value_dim
+    nqk, nv = hk * dk, hv * dv
+    qkvz = jnp.dot(y, p["w_qkvz"])
+    ba = jnp.dot(y, p["w_ba"], preferred_element_type=_F32)
+    qkv = _conv_silu(qkvz[..., :2 * nqk + nv], p["conv_w"])
+    q = _unit_heads(qkv[..., :nqk].reshape(b, s, hk, dk), 1e-6) \
+        * (1.0 / math.sqrt(dk))
+    k = _unit_heads(qkv[..., nqk:2 * nqk].reshape(b, s, hk, dk), 1e-6)
+    q, k = (jnp.repeat(t.astype(y.dtype), hv // hk, axis=2)
+            for t in (q, k))
+    v = qkv[..., 2 * nqk:].reshape(b, s, hv, dv)
+    beta = jax.nn.sigmoid(ba[..., :hv])
+    g = -jnp.exp(p["a_log"]) * jax.nn.softplus(ba[..., hv:] + p["dt_bias"])
+    with scope("linattn.rule"):
+        o = gated_delta_rule(q, k, v, g, beta, cfg.rule_impl)
+    o = L.rmsnorm(o, p["o_norm"], cfg.norm_eps).astype(y.dtype)
+    z = qkvz[..., 2 * nqk + nv:].reshape(b, s, hv, dv)
+    return jnp.dot((o * _silu(z)).reshape(b, s, nv), p["w_out"])
+
+
+def gated_mixer(cfg: HybridConfig, y, p):
+    """Softmax attention with an output gate; grouped keys and values."""
+    b, s, _ = y.shape
+    h, hkv, dh, dr = (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+                      cfg.rope_dim or cfg.head_dim)
+    qg = jnp.dot(y, p["wq"]).reshape(b, s, h, 2 * dh)
+    k = jnp.dot(y, p["wk"]).reshape(b, s, hkv, dh)
+    v = jnp.dot(y, p["wv"]).reshape(b, s, hkv, dh)
+    q = L.rmsnorm(qg[..., :dh], _norm_scale(cfg, p["q_norm"]),
+                  cfg.norm_eps).astype(y.dtype)
+    k = L.rmsnorm(k, _norm_scale(cfg, p["k_norm"]),
+                  cfg.norm_eps).astype(y.dtype)
+    q_rope, k_rope = L.rope(q[..., :dr], k[..., :dr], jnp.arange(s),
+                            cfg.rope_theta)
+    q = jnp.concatenate([q_rope, q[..., dr:]], axis=-1)
+    k = jnp.concatenate([k_rope, k[..., dr:]], axis=-1)
+    o = ops.attention(q, k, v, causal=True, impl=cfg.attention_impl)
+    gate = jax.nn.sigmoid(qg[..., dh:].astype(_F32)).astype(y.dtype)
+    return jnp.dot((o * gate).reshape(b, s, h * dh), p["wo"])
+
+
 def expert_ffn(cfg: HybridConfig, x, norm, fp):
     """``x + routed(y) + shared(y)``, ``y = norm(x)``, and the layer's
     routing (``moe_held``'s).  The norm lies in ``moe.router`` and the
@@ -509,8 +682,12 @@ def expert_ffn(cfg: HybridConfig, x, norm, fp):
     out = routed.reshape(b, s, d)
     if cfg.shared_ff_dim:
         with scope("moe.shared"):
-            out = out + L.swiglu(y, fp["ws_gate"], fp["ws_up"],
-                                 fp["ws_down"])
+            shared = L.swiglu(y, fp["ws_gate"], fp["ws_up"], fp["ws_down"])
+            if cfg.shared_gate:
+                shared = shared * jax.nn.sigmoid(jnp.dot(
+                    y, fp["ws_sig"], preferred_element_type=_F32)
+                )[..., None].astype(y.dtype)
+            out = out + shared
     with scope("moe.combine"):
         return x + out, routing
 
@@ -534,6 +711,12 @@ def _layer(cfg: HybridConfig, li: int, x, bp, mp, fp, memory, kv):
     elif kind == "mla":
         with scope("attn"):
             x = x + mla_mixer(cfg, _norm(cfg, x, bp, "norm1"), mp)
+    elif kind == "gated":
+        with scope("attn"):
+            x = x + gated_mixer(cfg, _norm(cfg, x, bp, "norm1"), mp)
+    elif kind == "gdn":
+        with scope("linattn"):
+            x = x + gdn_mixer(cfg, _norm(cfg, x, bp, "norm1"), mp)
     else:
         with scope("attn"):
             y = _norm(cfg, x, bp, "norm1")

@@ -387,6 +387,87 @@ def test_latent_moe_train_step_at_the_cell_shapes_fits_the_chip(one_chip,
                for k in kernel_instructions(text)) == 6 * experts == 30
 
 
+def test_linear_moe_train_step_at_the_cell_shapes_compiles_for_the_chip(
+        one_chip, no_persistent_cache, monkeypatch):
+    """``qwen3next_a3b_train_s16k``'s step as the runner builds it (the
+    cell's own files, widths, sequence, bound and compiler options, the
+    sweeps that "auto" takes on the chip), cut to one layer of each kind
+    so that it compiles in a minute: the kernels by name (the rule's
+    forward sweep twice a linear layer and its backward once, four
+    attention kernels a full layer, six grouped matmuls a layer), the
+    state donated, and the temporaries, which a linear layer's backward
+    sets, under what let 32 held experts keep the 13.0 GB rule (the
+    whole step's count is in the configuration file)."""
+    import dataclasses
+    import re
+
+    from benchmarks import harness, weights_linear_moe as weights
+    from benchmarks.runners import train_linear_moe
+    from dlnetbench_tpu.core import executor
+    from dlnetbench_tpu.models import bench_step
+    from dlnetbench_tpu.ops import pallas_common
+    monkeypatch.setattr(pallas_common, "interpret_mode", lambda: False)
+    cell = harness.load_cell("qwen3next_a3b_train_s16k")
+    cell = dataclasses.replace(cell, config={
+        **cell.config, "num_hidden_layers": 2,
+        "full_attention_interval": 2})
+    wl, tr = cell.workload, cell.traffic
+    arch = weights.arch_of(cell.config)
+    assert arch["layer_kinds"] == ("gdn", "gated")
+    cfg = train_linear_moe.program_config(
+        cell, arch, {"attention_impl": "flash", "rule_impl": "pallas"})
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree.map(
+        on_chip, jax.eval_shape(lambda: weights.make_params(arch, 0)))
+    tokens = jax.ShapeDtypeStruct((tr["batch"], tr["seq_len"] + 1), I32,
+                                  sharding=one_chip)
+    step = executor.CompiledStep(
+        bench_step.make_train_k(cfg, 1, wl["lr"]), (params, tokens),
+        donate_argnums=bench_step.DONATE_ARGNUMS,
+        compiler_options=wl["compiler_options"])
+    mem = step.memory_analysis
+    assert mem["alias"] > 0.99 * mem["argument"]   # the state is donated
+    assert mem["temp"] <= 5.8e9        # 5.52 GB read, PR 32
+    names = kernel_instructions(step.as_text())
+    assert sorted(re.sub(r"\.\d+$", "", k) for k in names) == sorted(
+        ["gdr_fwd"] * 2 + ["gdr_bwd"] + ["flash_fwd"] * 2
+        + ["flash_bwd_dq", "flash_bwd_dkv"] + ["grouped_mm"] * 12)
+
+
+def test_gated_delta_rule_sweeps_at_the_cell_shapes(for_chip):
+    """The rule at the linear-attention cell's shapes (T=16384, 32
+    heads of 128 x 128), Pallas sweeps: the two kernels under their
+    names, the kept states in the inputs' dtype, and no state a token."""
+    import re
+    gdr = ops_module("gated_delta_rule")
+    t, h, d = 16384, 32, 128
+
+    def grads(q, k, v, g, beta):
+        return jax.grad(lambda *x: jnp.sum(gdr.gated_delta_rule(
+            *x, "pallas").astype(F32)), argnums=(0, 1, 2, 3, 4))(
+                q, k, v, g, beta)
+    qkv = ((1, t, h, d), BF16)
+    text = for_chip(grads, qkv, qkv, qkv, ((1, t, h), F32),
+                    ((1, t, h), F32))
+    names = {re.sub(r"\.\d+$", "", n) for n in kernel_instructions(text)}
+    assert names == {"gdr_fwd", "gdr_bwd"}
+    hb = gdr._head_block(t, h)
+    assert hb < h and f"bf16[{hb},{t // gdr.CHUNK},{d},{d}]" in text
+    assert f"[1,{t},{h},{d},{d}]" not in text
+
+
+def test_flash_at_gated_attention_widths(for_chip):
+    """16 query heads over 2 key/value heads of 256 lanes at S=16384,
+    twice the longest sequence another cell runs: forward, dq, dkv."""
+    from dlnetbench_tpu import ops
+    text = for_chip(grad_of(lambda q, k, v: ops.attention(
+        q, k, v, causal=True, impl="flash")), ((1, 16384, 16, 256), BF16),
+        ((1, 16384, 2, 256), BF16), ((1, 16384, 2, 256), BF16))
+    assert kernels_in(text) == 3
+
+
 def hlo_computations(text: str) -> dict:
     """{computation name: its lines} of a compiled module's text."""
     import re

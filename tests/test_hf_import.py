@@ -61,6 +61,24 @@ KIMI_VL = {"vocab_size": 163840, "max_position_embeddings": 131072,
            "attention_bias": False, "tie_word_embeddings": False}
 
 
+# the catalog's config of Qwen/Qwen3-Next-80B-A3B-Instruct
+QWEN3_NEXT = {"decoder_sparse_step": 1, "full_attention_interval": 4,
+              "head_dim": 256, "hidden_act": "silu", "hidden_size": 2048,
+              "intermediate_size": 5120, "linear_conv_kernel_dim": 4,
+              "linear_key_head_dim": 128, "linear_num_key_heads": 16,
+              "linear_num_value_heads": 32, "linear_value_head_dim": 128,
+              "max_position_embeddings": 262144, "mlp_only_layers": [],
+              "model_type": "qwen3_next", "moe_intermediate_size": 512,
+              "norm_topk_prob": True, "num_attention_heads": 16,
+              "num_experts": 512, "num_experts_per_tok": 10,
+              "num_hidden_layers": 48, "num_key_value_heads": 2,
+              "partial_rotary_factor": 0.25, "rms_norm_eps": 1e-06,
+              "rope_scaling": None, "rope_theta": 10000000,
+              "shared_expert_intermediate_size": 512,
+              "tie_word_embeddings": False, "use_sliding_window": False,
+              "vocab_size": 151936}
+
+
 @pytest.mark.parametrize("name,cfg", [
     ("gpt2_l", GPT2_L), ("llama3_8b", LLAMA3_8B),
     ("mixtral_8x7b", MIXTRAL), ("vit_b", VIT_B),
@@ -68,6 +86,9 @@ KIMI_VL = {"vocab_size": 163840, "max_position_embeddings": 131072,
     ("kimi_vl_a3b", KIMI_VL),
     ("kimi_vl_a3b", {"model_type": "kimi_vl", "text_config": {
         **KIMI_VL, "model_type": "deepseek_v3"}}),
+    ("qwen3_next_80b_a3b", QWEN3_NEXT),
+    ("qwen3_next_80b_a3b", {**QWEN3_NEXT, "layer_types": (
+        ["linear_attention"] * 3 + ["full_attention"]) * 12}),
 ])
 def test_mapping_reproduces_committed_card(name, cfg):
     got = hf_import.card_from_hf_config(name, cfg)
@@ -144,3 +165,35 @@ def test_latent_moe_card_states_the_gate_and_the_shared_experts():
 def test_latent_moe_import_refuses_what_no_layer_computes(key, value):
     with pytest.raises(ValueError, match=key):
         hf_import.card_from_hf_config("x", {**KIMI_VL, key: value})
+
+
+def test_linear_moe_card_states_the_layers_the_gate_and_the_lanes():
+    """``layer_types`` derived from ``full_attention_interval`` where
+    the file leaves them out; the head's own width, the rotated lanes,
+    the zero-centred norm and the shared expert's gate on the card."""
+    card = hf_import.card_from_hf_config("qwen3_next_80b_a3b", QWEN3_NEXT)
+    assert card.layer_kinds == ("gdn", "gdn", "gdn", "gated") * 12
+    assert hf_import.linear_layer_kinds(6, 3) \
+        == ("gdn", "gdn", "gated") * 2
+    moe = card.moe_params
+    assert (moe.num_experts, moe.num_experts_per_tok, moe.scoring,
+            moe.shared_experts, moe.shared_gate, moe.expert_ff_dim,
+            moe.first_dense_layers) == (512, 10, "softmax", 1, True, 512, 0)
+    assert (card.attn_head_dim, card.rope_dim, card.kv_heads) == (256, 64, 2)
+    assert (card.linear_key_heads, card.linear_value_heads,
+            card.linear_key_dim, card.linear_value_dim, card.linear_conv) \
+        == (16, 32, 128, 128, 4)
+    assert card.rms_norm and card.norm_plus_one and card.norm_eps == 1e-6
+    assert not card.tied_embeddings and card.rope_theta == 1e7
+    # the issue's table: 33.72 M a linear mixer, 27.26 M the gated
+    # attention, 4.20 M + 512 x 3.146 M an expert layer: 79.7 B
+    assert card.mixer_params("gdn") == pytest.approx(33.72e6, rel=1e-3)
+    assert card.mixer_params("gated") == pytest.approx(27.26e6, rel=1e-3)
+    assert card.ffn_params(0) == 513 * 3 * 2048 * 512 + 2048 * 512 + 2048
+    assert card.num_params() == pytest.approx(79.7e9, rel=1e-3)
+    for key, value in (("mlp_only_layers", [3]), ("norm_topk_prob", False),
+                       ("decoder_sparse_step", 2),
+                       ("shared_expert_intermediate_size", 700),
+                       ("rope_scaling", {"type": "yarn"})):
+        with pytest.raises(ValueError, match="linear-attention import"):
+            hf_import.card_from_hf_config("x", {**QWEN3_NEXT, key: value})

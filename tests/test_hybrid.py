@@ -229,7 +229,8 @@ def test_card_states_the_pattern_and_config_follows_it():
     assert cfg.memory_layer == 16 and cfg.layer_kinds[17] == "full"
     assert (cfg.head_dim, cfg.dt_rank, cfg.ssm_inner) == (64, 160, 5120)
     assert cfg.group_sizes() == {"mamba": 9, "attn": 9, "gmu": 7,
-                                 "cross": 7, "mla": 0}
+                                 "cross": 7, "mla": 0, "gdn": 0,
+                                 "gated": 0}
     with pytest.raises(ValueError, match="a mamba layer before"):
         hybrid.HybridConfig.from_card(card, layer_kinds=("gmu", "mamba"))
     with pytest.raises(ValueError, match="exactly one full"):

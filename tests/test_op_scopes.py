@@ -248,8 +248,99 @@ def test_a_call_opens_no_span_of_its_own():
     assert tracer.spans == [] and tracer.op_scopes == {}
 
 
+def test_an_unscoped_instruction_inside_a_loop_takes_the_loops_scope():
+    """The compiler's own prefetch copies inside a loop's body carry no
+    ``op_name``: they take the scope of the innermost loop around them
+    that has one, so that a loop's time lies under one scope; outside
+    a loop they stay ``other``."""
+    text = """HloModule jit_f
+
+%body.1 (p: (s32[], f32[4])) -> (s32[], f32[4]) {
+  %p = (s32[], f32[4]) parameter(0)
+  %copy-start.3 = (f32[4], f32[4], u32[]) copy-start(f32[4] %x)
+  %copy-done.3 = f32[4] copy-done(%copy-start.3)
+  %mul.1 = f32[4] multiply(%copy-done.3, %copy-done.3), metadata={op_name="jit(f)/linattn/linattn.rule/mul"}
+  %inner.2 = (s32[], f32[4]) while(%p), condition=%cond.2, body=%body.2
+}
+
+%body.2 (p: (s32[], f32[4])) -> (s32[], f32[4]) {
+  %slice-done.5 = f32[4] slice-done(%s)
+  %add.7 = f32[4] add(%a, %b), metadata={op_name="jit(f)/attn/add"}
+}
+
+%cond.1 (p: (s32[], f32[4])) -> pred[] {
+  %compare.9 = pred[] compare(%a, %b), direction=LT
+}
+
+ENTRY %main (x: f32[4]) -> f32[4] {
+  %copy.1 = f32[4] copy(%x)
+  %while.8 = (s32[], f32[4]) while(%t), condition=%cond.1, body=%body.1, metadata={op_name="jit(f)/linattn/linattn.rule/while"}
+}
+"""
+    table = executor.hlo_op_scopes(text)
+    assert table["while.8"] == table["mul.1"] == "linattn.rule"
+    assert table["copy-start.3"] == table["copy-done.3"] == "linattn.rule"
+    assert table["compare.9"] == "linattn.rule"        # the condition's
+    # a loop with no scope of its own, inside one that has: the outer's
+    assert table["inner.2"] == table["slice-done.5"] == "linattn.rule"
+    assert table["add.7"] == "attn"                    # its own stays
+    assert table["copy.1"] == spans.OTHER_SCOPE        # outside any loop
+
+
+_LOOP_ATTRS = re.compile(r", condition=%?[\w.\-]+, body=%?[\w.\-]+")
+
+
+@pytest.mark.parametrize("runner,cls,name", [
+    ("train_hybrid", "HybridCell", "phi4miniflash_train_s8k"),
+    ("train_latent_moe", "LatentMoeCell", "kimivl_a3b_train_s8k"),
+    ("train_linear_moe", "LinearMoeCell", "qwen3next_a3b_train_s16k")])
+def test_the_loop_rule_moves_only_what_had_no_scope(runner, cls, name):
+    """The three ``models/hybrid.py`` cells' steps at their rehearsal
+    sizes, the table with the loop rule against the table without it
+    (the same text with the loops' ``condition=`` and ``body=`` taken
+    off, which is what ``hlo_op_scopes`` read before it knew loops):
+    an instruction that had a scope keeps it; one that moves had none,
+    lies inside a loop, and takes the scope the loop (or a loop around
+    it) had already, so the union of a scope's device time, which holds
+    the loop's own event, holds what it held."""
+    import importlib
+
+    from benchmarks import harness
+    module = importlib.import_module(f"benchmarks.runners.{runner}")
+    cell = harness.rehearsal(harness.load_cell(name))
+    text = getattr(module, cls)(cell, 5, lambda o: None).step.as_text()
+    new = executor.hlo_op_scopes(text)
+    old = executor.hlo_op_scopes(_LOOP_ATTRS.sub("", text))
+    assert set(old) == set(new)
+    moved = {k for k in new if new[k] != old[k]}
+    assert moved and {old[k] for k in moved} == {spans.OTHER_SCOPE}
+    # every loop of the step, by the computations it runs
+    loops, where, comp = {}, {}, None
+    for line in text.splitlines():
+        m = executor._HLO_INSTRUCTION.match(line)
+        if not m:
+            c = executor._HLO_COMPUTATION.match(line)
+            comp = c.group(1) if c else comp
+            continue
+        where[m.group(1)] = comp
+        loop = executor._HLO_LOOP.search(line)
+        if loop:
+            loops.update(dict.fromkeys(loop.groups(), m.group(1)))
+    assert len(set(loops.values())) >= 2
+    for k in moved:
+        around, scopes = loops.get(where[k]), []
+        while around is not None:
+            scopes.append(old[around])
+            around = loops.get(where[around])
+        scoped = [s for s in scopes if s != spans.OTHER_SCOPE]
+        assert scoped and new[k] == scoped[0], (k, scopes)
+    # and the loops themselves stay where they were
+    assert all(new[w] == old[w] or old[w] == spans.OTHER_SCOPE
+               for w in loops.values())
+
+
 def test_scope_refuses_a_name_outside_the_vocabulary():
     with pytest.raises(ValueError, match="spans.SCOPES"):
         spans.scope("attention")
-    assert len(set(spans.SCOPES)) == len(spans.SCOPES) == 13
+    assert len(set(spans.SCOPES)) == len(spans.SCOPES) == 15
     assert spans.OTHER_SCOPE not in spans.SCOPES
