@@ -393,11 +393,13 @@ def test_linear_moe_train_step_at_the_cell_shapes_compiles_for_the_chip(
     cell's own files, widths, sequence, bound and compiler options, the
     sweeps that "auto" takes on the chip), cut to one layer of each kind
     so that it compiles in a minute: the kernels by name (the rule's
-    forward sweep twice a linear layer and its backward once, four
-    attention kernels a full layer, six grouped matmuls a layer), the
-    state donated, and the temporaries, which a linear layer's backward
-    sets, under what let 32 held experts keep the 13.0 GB rule (the
-    whole step's count is in the configuration file)."""
+    forward kernel twice a linear layer and its backward once, four
+    attention kernels a full layer, six grouped matmuls a layer), no
+    loop but the head's (none around the rule: all heads go through one
+    call), no chunk matrix but what the rule's kernels write, the state
+    donated, and the temporaries under what let 32 held experts keep
+    the 13.0 GB rule (the whole step's count is in the configuration
+    file)."""
     import dataclasses
     import re
 
@@ -429,33 +431,88 @@ def test_linear_moe_train_step_at_the_cell_shapes_compiles_for_the_chip(
         compiler_options=wl["compiler_options"])
     mem = step.memory_analysis
     assert mem["alias"] > 0.99 * mem["argument"]   # the state is donated
-    assert mem["temp"] <= 5.8e9        # 5.52 GB read, PR 32
-    names = kernel_instructions(step.as_text())
+    assert mem["temp"] <= 5.5e9        # 5.39 GB read, PR 33 (5.52, PR 32)
+    text = step.as_text()
+    names = kernel_instructions(text)
     assert sorted(re.sub(r"\.\d+$", "", k) for k in names) == sorted(
         ["gdr_fwd"] * 2 + ["gdr_bwd"] + ["flash_fwd"] * 2
         + ["flash_bwd_dq", "flash_bwd_dkv"] + ["grouped_mm"] * 12)
+    table = executor.hlo_op_scopes(text)
+    loops = [m.group(1) for line in text.splitlines() if " while(" in line
+             and (m := executor._HLO_INSTRUCTION.match(line))]
+    assert [table[w] for w in loops] == ["head_loss"]
+    made = chunk_arrays(text, cfg.gdn_value_heads, tr["seq_len"] // 128)
+    assert set().union(*made.values()) <= {"get-tuple-element", "bitcast"}
+
+
+def chunk_arrays(text: str, h: int, nc: int) -> dict:
+    """{shape: opcodes of the instructions that make it} for every
+    array ``[1, h, nc, ...]`` of five dimensions: a matrix a head and
+    chunk."""
+    import re
+    made = {}
+    shape = re.compile(r"\s*(?:ROOT )?%[\w.\-]+ = (\w+\[1," + f"{h},{nc}"
+                       + r",\d+,\d+\])\S* ([\w\-]+)\(")
+    for line in text.splitlines():
+        if m := shape.match(line):
+            made.setdefault(m.group(1), set()).add(m.group(2))
+    return made
 
 
 def test_gated_delta_rule_sweeps_at_the_cell_shapes(for_chip):
     """The rule at the linear-attention cell's shapes (T=16384, 32
-    heads of 128 x 128), Pallas sweeps: the two kernels under their
-    names, the kept states in the inputs' dtype, and no state a token."""
+    heads of 128 x 128), Pallas: the two kernels under their names and
+    no loop around them; of a chunk's matrices only what the kernels
+    themselves write crosses HBM (the kept states in the inputs' dtype
+    and ``X`` in float32: no ``w``, ``u``, ``qg``, ``kr``, ``kg`` of
+    XLA's making), and no state a token."""
     import re
+
+    from dlnetbench_tpu.metrics import spans
     gdr = ops_module("gated_delta_rule")
     t, h, d = 16384, 32, 128
 
+    def rule(*x):
+        with spans.scope("linattn.rule"):     # as hybrid.gdn_mixer does
+            return jnp.sum(gdr.gated_delta_rule(*x, "pallas").astype(F32))
+
     def grads(q, k, v, g, beta):
-        return jax.grad(lambda *x: jnp.sum(gdr.gated_delta_rule(
-            *x, "pallas").astype(F32)), argnums=(0, 1, 2, 3, 4))(
-                q, k, v, g, beta)
+        return jax.grad(rule, argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
     qkv = ((1, t, h, d), BF16)
     text = for_chip(grads, qkv, qkv, qkv, ((1, t, h), F32),
                     ((1, t, h), F32))
-    names = {re.sub(r"\.\d+$", "", n) for n in kernel_instructions(text)}
-    assert names == {"gdr_fwd", "gdr_bwd"}
-    hb = gdr._head_block(t, h)
-    assert hb < h and f"bf16[{hb},{t // gdr.CHUNK},{d},{d}]" in text
+    names = sorted(re.sub(r"\.\d+$", "", n)
+                   for n in kernel_instructions(text))
+    assert names == ["gdr_bwd", "gdr_fwd"]
+    assert " while(" not in text
+    c, hb = gdr.tile_plan(t, h, d, d, 2)
+    assert (c, hb) == (128, 8)
+    made = chunk_arrays(text, h, t // c)
+    assert set(made) == {f"bf16[1,{h},{t // c},{d},{d}]",
+                         f"f32[1,{h},{t // c},{c},{c}]"}
+    assert set().union(*made.values()) <= {"get-tuple-element", "bitcast"}
     assert f"[1,{t},{h},{d},{d}]" not in text
+
+
+@pytest.mark.parametrize("d,h,budget,hb", [
+    (64, 8, 6 << 20, 2), (64, 8, 20 << 20, 8), (16, 8, 20 << 20, 8),
+    (256, 4, 1 << 20, 1)])
+def test_gated_delta_rule_head_groups_fill_lane_tiles(for_chip, monkeypatch,
+                                                      d, h, budget, hb):
+    """Heads narrower and wider than the 128-lane tile: the groups
+    ``tile_plan`` allows (whole tiles of a token block, or every head)
+    are blocks the TPU lowering takes, forward and backward; interpret
+    mode takes any."""
+    gdr = ops_module("gated_delta_rule")
+    monkeypatch.setattr(gdr, "_VMEM_BUDGET", budget)
+    t = 1024
+    assert gdr.tile_plan(t, h, d, d, 2) == (128, hb)
+    qkv = ((1, t, h, d), BF16)
+    text = for_chip(
+        jax.grad(lambda *x: jnp.sum(gdr.gated_delta_rule(
+            *x, "pallas").astype(F32)), argnums=(0, 1, 2, 3, 4)),
+        qkv, qkv, qkv, ((1, t, h), F32), ((1, t, h), F32))
+    assert kernels_in(text) == 2
 
 
 def test_flash_at_gated_attention_widths(for_chip):

@@ -89,7 +89,7 @@ def rule_inputs(seed=0, b=1, t=100, h=3, dk=16, dv=16):
     q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) / np.sqrt(dk)
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
     v = jax.random.normal(ks[2], (b, t, h, dv))
-    rate = jnp.array([40.0, 0.01, 1.0])[:h]
+    rate = jnp.resize(jnp.array([40.0, 0.01, 1.0, 0.3]), h)
     g = -rate * (0.5 + jax.random.uniform(ks[3], (b, t, h)))
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, t, h)))
     return (q, k, v, g, beta), jax.random.normal(ks[5], (b, t, h, dv))
@@ -141,12 +141,136 @@ def test_rule_keeps_its_given_dtype_and_refuses_an_unknown_impl():
         gdr.gated_delta_rule(q, k, v, g, beta, "triton")
 
 
-def test_inverse_by_blocks_is_the_inverse():
-    a = jnp.tril(jax.random.normal(jax.random.key(0), (3, 64, 64)), -1) * 0.2
+@pytest.mark.parametrize("c,atol", [
+    (8, 1e-6), (16, 2e-6), (32, 1e-5), (64, 2e-5), (128, 2e-4)])
+def test_inverse_of_a_whole_tile_is_the_inverse(c, atol):
+    """``_inv_unit_lower`` on whole tiles (diagonal blocks of 16 as a
+    product of powers, then the blocks' own product), every product in
+    float32: times ``I + a`` it is ``I``, and it is what a triangular
+    solve gives."""
+    a = jnp.tril(jax.random.normal(jax.random.key(0), (c, c)), -1) * 0.2
     x = gdr._inv_unit_lower(a)
+    np.testing.assert_allclose(np.asarray(x @ (jnp.eye(c) + a)),
+                               np.eye(c), atol=atol)
     np.testing.assert_allclose(
-        np.asarray(x @ (jnp.eye(64) + a)), np.eye(64)[None].repeat(3, 0),
-        atol=2e-5)
+        np.asarray(x), np.asarray(jax.scipy.linalg.solve_triangular(
+            jnp.eye(c) + a, jnp.eye(c), lower=True, unit_diagonal=True)),
+        atol=atol)
+
+
+def test_float32_products_of_a_chunk_are_float32_whatever_the_inputs():
+    """The inverse's products and ``dA = -X^T dX X^T`` run at
+    ``highest`` for every dtype of the inputs (bfloat16 inputs round
+    ``T`` and the operands, not ``X``): no ``dot_general`` between
+    float32 operands in either function of a chunk runs at the
+    default precision, which on the MXU is one bfloat16 pass."""
+    (q, k, v, g, beta), ct = rule_inputs(2, t=64, h=1)
+    half = [a[0, :, 0].astype(jnp.bfloat16) for a in (q, k, v)]
+    cum, bt = jnp.cumsum(g[0, :, 0])[:, None], beta[0, :, 0][:, None]
+    small = (cum, cum.T, bt, bt.T)
+    s = jnp.zeros((16, 16))
+    fwd = jax.make_jaxpr(gdr._chunk_fwd)(*half, *small, s)
+    x = jnp.eye(64)
+    bwd = jax.make_jaxpr(gdr._chunk_bwd)(
+        *half, *small, x, s.astype(jnp.bfloat16),
+        ct[0, :, 0].astype(jnp.bfloat16), s)
+    for jaxpr, products in ((fwd, 10), (bwd, 2)):
+        dots = [e for e in jaxpr.eqns if e.primitive.name == "dot_general"]
+        exact = [e for e in dots
+                 if all(v.aval.dtype == jnp.float32 for v in e.invars)]
+        assert len(exact) == products < len(dots)
+        assert all(e.params["precision"] is not None and "HIGHEST" in str(
+            e.params["precision"]) for e in exact)
+
+
+def rule_grads(args, ct, *rest):
+    got, vjp = jax.vjp(lambda *a: gdr.gated_delta_rule(*a, *rest), *args)
+    return (got, *vjp(ct))
+
+
+@pytest.fixture(scope="module")
+def recurrence_8():
+    """Eight heads of 64 lanes (two of them fast, two slow), 150
+    tokens: the token recurrence's output and gradients."""
+    args, ct = rule_inputs(4, t=150, h=8, dk=64, dv=64)
+    out, vjp = jax.vjp(gdr.reference_rule, *args)
+    return args, ct, (out, *vjp(ct))
+
+
+@pytest.mark.parametrize("groups", [1, 2, 4])
+def test_head_groups_of_a_grid_step_do_not_meet(recurrence_8, groups,
+                                                 monkeypatch):
+    """Eight heads as one group, as two and as four grid rows of the
+    same kernels (the budget a step may fill is the test's to set; a
+    group fills whole 128-lane tiles, so two heads of 64 lanes are the
+    fewest): the recurrence's output and gradients each time."""
+    args, ct, want = recurrence_8
+    t, h, dk = args[0].shape[1:]
+    for budget in range(1 << 16, 1 << 25, 1 << 16):
+        monkeypatch.setattr(gdr, "_VMEM_BUDGET", budget)
+        if gdr.tile_plan(t, h, dk, dk, 4)[1] == h // groups:
+            break
+    assert gdr.tile_plan(t, h, dk, dk, 4) == (128, h // groups)
+    for name, a, w in zip("o q k v g beta".split(),
+                          rule_grads(args, ct, "pallas"), want):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(w), rtol=1e-4,
+            atol=1e-5 * float(jnp.abs(w).max()), err_msg=name)
+
+
+@pytest.fixture(scope="module")
+def recurrence_4():
+    """Four heads (the fast one, the slow one, two between), 150
+    tokens: the token recurrence's output and gradients."""
+    args, ct = rule_inputs(3, t=150, h=4)
+    out, vjp = jax.vjp(gdr.reference_rule, *args)
+    return args, ct, (out, *vjp(ct))
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_chunks_of_64_and_of_128_give_the_same_gradients(recurrence_4,
+                                                          impl):
+    """The chunk is where the state is kept and how large ``I + A`` is,
+    not what is computed: 150 tokens as three chunks of 64 and as two of
+    128, and as the shapes' own choice, which is what a call that names
+    none gets."""
+    args, ct, want = recurrence_4
+    by_64, by_128, own = (rule_grads(args, ct, impl, c)
+                          for c in (64, 128, None))
+    assert gdr.tile_plan(*args[0].shape[1:], 16, 4)[0] == 128
+    for name, a, b, c, w in zip("o q k v g beta".split(), by_64, by_128,
+                                own, want):
+        scale = float(jnp.abs(w).max())
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                                   atol=1e-5 * scale, err_msg=name)
+        np.testing.assert_allclose(np.asarray(b), np.asarray(w), rtol=1e-4,
+                                   atol=1e-5 * scale, err_msg=name)
+        np.testing.assert_array_equal(np.asarray(c), np.asarray(b), name)
+
+
+@pytest.mark.parametrize("shape,itemsize,want", [
+    ((16384, 32, 128, 128), 2, (128, 8)),   # the linear-attention cell
+    ((16384, 32, 128, 128), 4, (128, 4)),   # the same in float32
+    ((80, 2, 16, 16), 4, (64, 2)),          # the model test's
+    ((48, 4, 16, 16), 4, (64, 4)),          # short of one chunk
+    ((4096, 6, 128, 128), 2, (128, 6)),     # heads no power of two
+    ((4096, 14, 128, 128), 2, (128, 7)),
+    ((16384, 32, 64, 64), 2, (128, 8)),     # heads of half a lane tile
+    ((16384, 32, 64, 128), 4, (128, 8)),
+    ((16384, 24, 16, 16), 4, (128, 8)),     # eight heads fill a tile
+    ((16384, 6, 64, 64), 4, (128, 6)),
+    ((16384, 7, 16, 16), 4, (128, 7)),      # no divisor does: all heads
+])
+def test_tiles_are_a_function_of_the_shapes(shape, itemsize, want):
+    """``(C, hb)``: the largest chunk the sequence fills, the most
+    heads whose step fits the budget, always a divisor of the heads
+    and, short of all of them, whole 128-lane tiles of a token block
+    (the TPU lowering takes no other last dimension, and interpret
+    mode would not say); nothing but the shapes is read."""
+    assert gdr.tile_plan(*shape, itemsize) == want
+    t, h, dk, dv = shape
+    assert h % want[1] == 0
+    assert want[1] == h or want[1] * dk % 128 == 0 == want[1] * dv % 128
 
 
 # ----------------------------------------------------------- the model
