@@ -8,7 +8,9 @@ pay for tracing + compilation.  Three properties fall out:
 1. **Compilation can never leak into measurement.**  Each program is
    lowered and compiled ahead of time (``jit(fn).lower(...).compile()``)
    at *build* time, with the wall cost recorded as ``compile_ms`` in the
-   bundle's ``global_meta`` — so ``warmup_times_us`` (and therefore
+   bundle's ``global_meta`` (and split by phase, with the cache's
+   verdict and the code's size, in the build's record: ``builds()``) —
+   so ``warmup_times_us`` (and therefore
    ``estimate_runs``, the reference's ``-m`` min-exectime logic) see
    only execution.  The compiled executable also yields XLA's
    ``cost_analysis`` (FLOPs / bytes accessed — cross-checkable against
@@ -43,6 +45,7 @@ from __future__ import annotations
 import dataclasses
 import os
 import re
+import threading
 import time
 from collections.abc import Callable
 from pathlib import Path
@@ -50,6 +53,7 @@ from pathlib import Path
 import jax
 
 from dlnetbench_tpu.metrics import spans
+from dlnetbench_tpu.utils.timing import process_age_s
 
 ENV_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"
 # where the cache lives when the environment does not place it: one
@@ -195,15 +199,150 @@ def hlo_op_scopes(hlo_text: str, vocabulary=spans.SCOPES) -> dict[str, str]:
     return {k: scoped(k) or spans.OTHER_SCOPE for k in own}
 
 
+# --------------------------------------------------------------------
+# The build record: what one build cost and where, kept whether or not a
+# tracer is on (a build happens once; the step's path pays nothing).
+
+_BUILDS: list[dict] = []        # every build of this process, in order
+_BUILDING = threading.local()   # .record: the build whose executable
+#                                 this thread is making right now
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
+_DURATION_EVENTS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval_s",
+    "/jax/core/compile/backend_compile_duration": "backend_compile_s"}
+_listening = False
+
+
+def builds() -> tuple[dict, ...]:
+    """The record of every program this process has built through the
+    executor, in build order (``_Compiled._build`` says what one
+    holds); each is also its program's ``stats["build"]``."""
+    return tuple(_BUILDS)
+
+
+def _on_event(event: str, **_) -> None:
+    record = getattr(_BUILDING, "record", None)
+    if record is not None and event in _CACHE_EVENTS:
+        record["cache"] = _CACHE_EVENTS[event]
+
+
+def _on_duration(event: str, duration: float, **_) -> None:
+    record = getattr(_BUILDING, "record", None)
+    if record is not None and event in _DURATION_EVENTS:
+        record[_DURATION_EVENTS[event]] += duration
+
+
+def _listen() -> None:
+    """jax's monitoring events reach the build in progress through one
+    pair of listeners, installed at the process's first build."""
+    global _listening
+    if not _listening:
+        jax.monitoring.register_event_listener(_on_event)
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _listening = True
+
+
 class _Compiled:
-    """What ``CompiledProgram`` and ``CompiledStep`` share: XLA's
-    analyses of the executable, its text, and the op->scope table made
-    from that text — computed when asked and kept, never at build when
-    tracing is off."""
+    """What ``CompiledProgram`` and ``CompiledStep`` share: the build
+    and its record, XLA's analyses of the executable, its text, and the
+    op->scope table made from that text — computed when asked and kept,
+    never at build when tracing is off."""
 
     _compiled = None
     _op_scopes = None
     stats: dict
+
+    def _build(self, fn: Callable, args, donate: tuple,
+               compiler_options: dict | None, plan=None):
+        """Trace, lower and compile ``fn`` ahead of time; returns the
+        lowering and the argnums donated.  ``plan(lowered)`` may drop
+        donations, and the function is then lowered again without them.
+
+        Fills ``stats`` and, in it and in ``builds()``, the build's
+        record: ``fn``, ``module`` (the name a device trace prints),
+        ``began_at_s`` (the process's age when the build began),
+        ``trace_s`` (Python to jaxpr), ``lower_s`` (jaxpr to StableHLO,
+        the Pallas kernels' Mosaic lowering included; a second lowering
+        adds into both), ``executable_s`` (``lowered.compile`` whole:
+        the key, then the persistent cache's retrieval or the backend's
+        compile and the cache's write), ``cache`` (``"hit"``,
+        ``"miss"`` or ``"off"`` for this build) with
+        ``cache_retrieval_s`` and ``backend_compile_s`` as jax's own
+        events give them, ``code_bytes`` (the executable's generated
+        code, None where the backend does not say), ``op_scopes_s``
+        (the executable's text and the op->scope table from it: what
+        tracing itself costs a build, 0.0 with no tracer) and
+        ``analyses_s`` (XLA's cost analysis, after the build).  With a
+        tracer on, the ``compile`` span wears the phases, the cache's
+        verdict and the code's size as attrs: a train run's spans are
+        its builds and nothing else, so a build's inside is no span."""
+        clock = time.perf_counter
+        name = getattr(fn, "__name__", type(fn).__name__)
+        record = {"fn": name, "module": "", "began_at_s": process_age_s(),
+                  "trace_s": 0.0, "lower_s": 0.0, "executable_s": 0.0,
+                  "cache": "off", "cache_retrieval_s": 0.0,
+                  "backend_compile_s": 0.0, "code_bytes": None,
+                  "op_scopes_s": 0.0, "analyses_s": 0.0}
+        _listen()
+
+        def lower(donate):
+            jitted = jax.jit(fn, donate_argnums=donate)
+            t0 = clock()
+            traced = jitted.trace(*args)
+            t1 = clock()
+            lowered = traced.lower()
+            record["trace_s"] += t1 - t0
+            record["lower_s"] += clock() - t1
+            return lowered
+
+        began = clock()
+        with spans.span("compile", fn=name) as whole:
+            # one trace covers both lowering and donation planning: the
+            # plan needs only output shapes/dtypes, which
+            # ``lowered.out_info`` already carries — a separate
+            # eval_shape pass would re-trace every program (tracing
+            # these unrolled pipeline bodies costs as much as compiling
+            # them warm)
+            lowered = lower(donate)
+            if plan is not None and (kept := plan(lowered)) != donate:
+                # some requested donations have no output to rebind from
+                # (mode/schedule-dependent dummies): re-lower with only
+                # the kept set — the dropped buffers must NOT be
+                # invalidated
+                donate = kept
+                lowered = lower(donate)
+            record["module"] = lowered.compiler_ir().operation.attributes[
+                "sym_name"].value
+            t0 = clock()
+            _BUILDING.record = record
+            try:
+                self._compiled = lowered.compile(compiler_options)
+            finally:
+                _BUILDING.record = None
+            record["executable_s"] = clock() - t0
+            if record["cache"] == "hit":
+                # jax times the retrieval under its compile event too
+                record["backend_compile_s"] = 0.0
+            memory = _memory_analysis(self._compiled)
+            record["code_bytes"] = memory.get("generated_code")
+            if spans.is_enabled():
+                t0 = clock()
+                self._register_op_scopes()
+                record["op_scopes_s"] = clock() - t0
+                whole.attrs.update({k: record[k] for k in (
+                    "cache", "code_bytes", "trace_s", "lower_s",
+                    "executable_s", "op_scopes_s")})
+        t0 = clock()
+        self.stats = {"compile_ms": round((t0 - began) * 1e3, 3),
+                      "donated_argnums": list(donate), "build": record}
+        if cost := _cost_analysis(self._compiled):
+            self.stats["cost_analysis"] = cost
+        if memory:
+            self.stats["memory_analysis"] = memory
+        record["analyses_s"] = clock() - t0
+        _BUILDS.append(record)
+        return lowered, donate
 
     # per-program cost stats as first-class attributes (not just the
     # global_meta channel compile_programs writes): the attribution
@@ -241,13 +380,11 @@ class _Compiled:
 
     def _register_op_scopes(self) -> None:
         """Hand the table to the current tracer, keyed by the module's
-        name as a device trace prints it; nothing when tracing is off."""
-        tracer = spans.current()
-        if tracer is not None:
-            text = self.as_text()
-            self._op_scopes = hlo_op_scopes(text)
-            tracer.register_op_scopes(hlo_module_name(text),
-                                      self._op_scopes)
+        name as a device trace prints it."""
+        text = self.as_text()
+        self._op_scopes = hlo_op_scopes(text)
+        spans.current().register_op_scopes(hlo_module_name(text),
+                                           self._op_scopes)
 
 
 @dataclasses.dataclass
@@ -284,28 +421,15 @@ class CompiledProgram(_Compiled):
         requested = (() if os.environ.get(ENV_NO_DONATION)
                      else tuple(program.donate_argnums))
 
-        t0 = time.perf_counter()
-        # one trace covers both lowering and donation planning: the
-        # rebind map needs only output shapes/dtypes, which
-        # ``lowered.out_info`` already carries — a separate eval_shape
-        # pass would re-trace every program (tracing these unrolled
-        # pipeline bodies costs as much as compiling them warm)
-        with spans.span("compile", fn=getattr(program.fn, "__name__",
-                                              type(program.fn).__name__)):
-            lowered = jax.jit(program.fn,
-                              donate_argnums=requested).lower(*args)
-            donate, self._rebind, undonated = _plan_donation(
+        undonated: list = []
+
+        def plan(lowered):
+            donate, self._rebind, undonated[:] = _plan_donation(
                 jax.tree.leaves(lowered.out_info), args, requested)
-            if donate != requested:
-                # some requested donations have no output to rebind from
-                # (mode/schedule-dependent dummies): re-lower with only
-                # the kept set — the dropped buffers must NOT be
-                # invalidated
-                lowered = jax.jit(program.fn,
-                                  donate_argnums=donate).lower(*args)
-            self._compiled = lowered.compile(program.compiler_options)
-            self._register_op_scopes()
-        compile_ms = (time.perf_counter() - t0) * 1e3
+            return donate
+
+        _, donate = self._build(program.fn, args, requested,
+                                program.compiler_options, plan)
 
         # donation consumes the buffer, and sibling programs (full /
         # compute / comm share the proxy's buffers) must stay callable:
@@ -318,11 +442,8 @@ class CompiledProgram(_Compiled):
         self._args = args
         self._treedef = jax.tree.structure(tuple(args))
 
-        self.stats = {"compile_ms": round(compile_ms, 3),
-                      "donated_argnums": list(donate)}
         if undonated:
             self.stats["undonated"] = undonated
-        self.stats.update(_analyses(self._compiled))
 
     @property
     def example_args(self) -> tuple:
@@ -378,21 +499,12 @@ class CompiledStep(_Compiled):
         self.traceable = fn
         donate = (() if os.environ.get(ENV_NO_DONATION)
                   else tuple(donate_argnums))
-        t0 = time.perf_counter()
-        with spans.span("compile", fn=getattr(fn, "__name__",
-                                              type(fn).__name__)):
-            lowered = jax.jit(fn, donate_argnums=donate).lower(
-                *example_args)
-            self._compiled = lowered.compile(compiler_options)
-            self._register_op_scopes()
+        lowered, _ = self._build(fn, example_args, donate,
+                                 compiler_options)
         # abstract output leaves (shape/dtype), kept so subclasses can
         # validate structural contracts (CompiledLoop's carry check)
         # without re-tracing
         self.out_info = lowered.out_info
-        self.stats = {"compile_ms": round(
-            (time.perf_counter() - t0) * 1e3, 3),
-            "donated_argnums": list(donate)}
-        self.stats.update(_analyses(self._compiled))
 
     def __call__(self, *args):
         return self._compiled(*args)
@@ -536,34 +648,36 @@ def _plan_donation(out_leaves, args, donate_argnums):
     return tuple(keep), rebind, dropped
 
 
-def _analyses(compiled) -> dict:
-    """Flatten XLA's per-executable analyses into JSON-ready dicts; an
+def _cost_analysis(compiled) -> dict:
+    """XLA's {flops, bytes_accessed} of an executable, JSON-ready; an
     analysis a backend doesn't implement is simply absent, never fatal."""
-    out = {}
+    cost = {}
     try:
         ca = compiled.cost_analysis()
         props = ca[0] if isinstance(ca, (list, tuple)) and ca else ca
         if isinstance(props, dict):
-            cost = {}
             if "flops" in props:
                 cost["flops"] = float(props["flops"])
             ba = [float(v) for k, v in props.items()
                   if k.startswith("bytes accessed")]
             if ba:
                 cost["bytes_accessed"] = max(ba)
-            if cost:
-                out["cost_analysis"] = cost
     except Exception:
         pass
+    return cost
+
+
+def _memory_analysis(compiled) -> dict:
+    """XLA's byte counts of an executable (arguments, outputs,
+    temporaries, aliased, generated code), absent as above."""
     try:
         ma = compiled.memory_analysis()
-        out["memory_analysis"] = {
-            k: int(getattr(ma, f"{k}_size_in_bytes"))
-            for k in ("argument", "output", "temp", "alias")
-            if hasattr(ma, f"{k}_size_in_bytes")}
+        return {k: int(getattr(ma, f"{k}_size_in_bytes"))
+                for k in ("argument", "output", "temp", "alias",
+                          "generated_code")
+                if hasattr(ma, f"{k}_size_in_bytes")}
     except Exception:
-        pass
-    return out
+        return {}
 
 
 def compile_programs(programs: dict[str, Program],

@@ -15,6 +15,7 @@ share cites.
 """
 from __future__ import annotations
 
+import os
 import statistics
 import time
 
@@ -24,6 +25,21 @@ import jax.numpy as jnp
 from dlnetbench_tpu.metrics import spans
 
 _DISPATCH_FENCE_S: float | None = None
+_IMPORTED_AT = time.perf_counter()
+
+
+def process_age_s() -> float:
+    """Seconds since this process started, by the kernel's clock: what
+    the interpreter, the imports and the device's bring-up took is in
+    it.  Since this module's import where ``/proc`` cannot say."""
+    try:
+        with open("/proc/self/stat") as f:
+            start_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        return uptime - start_ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return time.perf_counter() - _IMPORTED_AT
 
 
 def dispatch_fence_s() -> float:

@@ -249,3 +249,161 @@ def test_estimate_runs_sees_execution_only(eight_devices):
     assert warm[0] < steady + 0.5 * compile_us, (
         f"warmup[0]={warm[0]:.0f}us vs steady {steady:.0f}us and "
         f"compile {compile_us:.0f}us — compilation leaked into warmup")
+
+
+# ---------------------------------------------------------------------
+# The build record (``stats["build"]``, ``executor.builds()``) and the
+# same phases as attrs of the ``compile`` span.
+
+RECORD_KEYS = {"fn", "module", "began_at_s", "trace_s", "lower_s",
+               "executable_s", "cache", "cache_retrieval_s",
+               "backend_compile_s", "code_bytes", "op_scopes_s",
+               "analyses_s"}
+PHASES = ("trace", "lower", "executable", "op_scopes")
+KINDS = ("program", "step", "loop")
+
+
+def _carried():
+    """A fresh function object a call: jax keeps what it traced and
+    compiled for a function it has seen."""
+    def carried(s, g):
+        s = jnp.tanh(s @ s)
+        return s, jnp.sum(g) + jnp.sum(s)
+    return carried
+
+
+def _build(kind, eight_devices):
+    if kind == "program":
+        prog, _, _ = _carry_program(_mesh4(eight_devices))
+        return executor.CompiledProgram(prog)
+    args = (jnp.full((16, 16), 0.1), jnp.ones((32,)))
+    if kind == "step":
+        return executor.CompiledStep(_carried(), args, donate_argnums=(0,))
+    return executor.CompiledLoop(_carried(), args, carry_argnums=(0,))
+
+
+def _close(a_s, b_s):
+    return abs(a_s - b_s) <= max(0.05 * b_s, 0.020)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_build_record_with_no_tracer(kind, eight_devices):
+    from dlnetbench_tpu.metrics import spans
+    from dlnetbench_tpu.utils.timing import process_age_s
+    assert not spans.is_enabled()
+    before = executor.builds()
+    built = _build(kind, eight_devices)
+    rec = built.stats["build"]
+    assert executor.builds() == (*before, rec)      # the log, in order
+    assert set(rec) == RECORD_KEYS
+    assert rec["module"] == executor.hlo_module_name(built.as_text())
+    assert rec["module"] == "jit_" + rec["fn"]
+    assert 0 < rec["began_at_s"] <= process_age_s()
+    assert min(rec["trace_s"], rec["lower_s"], rec["executable_s"]) > 0
+    assert rec["op_scopes_s"] == 0.0                # no tracer, no table
+    assert rec["cache"] == "off"                    # conftest: cache off
+    assert rec["cache_retrieval_s"] == 0.0
+    assert 0 < rec["backend_compile_s"] <= rec["executable_s"]
+    assert rec["code_bytes"] == \
+        built.memory_analysis["generated_code"] >= 0
+    phases = sum(rec[f"{p}_s"] for p in PHASES)
+    assert _close(phases, built.stats["compile_ms"] * 1e-3)
+    import json
+    json.dumps(rec)                                 # the emitter's channel
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_compile_span_wears_the_build_record(kind, eight_devices):
+    """With a tracer on the build is still one span, ``compile`` (a
+    train run's spans are its builds and nothing else: the benchmark's
+    tests hold that), and its attrs carry the record's phases, the
+    cache's verdict and the code's size; the table's cost is counted."""
+    from dlnetbench_tpu.metrics import spans
+    tracer = spans.enable()
+    try:
+        built = _build(kind, eight_devices)
+    finally:
+        spans.disable()
+    rec = built.stats["build"]
+    (whole,) = [s for s in tracer.spans if s["name"] == "compile"]
+    assert {s["name"] for s in tracer.spans} <= {"compile", "donate-clone"}
+    assert whole["attrs"] == {
+        "fn": rec["fn"], **{k: rec[k] for k in (
+            "cache", "code_bytes", "trace_s", "lower_s", "executable_s",
+            "op_scopes_s")}}
+    assert rec["op_scopes_s"] > 0
+    assert "jit_" + rec["fn"] in tracer.op_scopes
+    phases = sum(rec[f"{p}_s"] for p in PHASES)
+    assert _close(phases, whole["dur_us"] * 1e-6)
+    assert _close(phases, built.stats["compile_ms"] * 1e-3)
+
+
+def test_a_second_lowering_adds_into_the_same_fields(monkeypatch):
+    """``CompiledProgram`` lowers again when a donation is dropped:
+    both tracings and both lowerings are in the one record."""
+    import types
+    clock = iter(range(100))
+    monkeypatch.setattr(executor, "time", types.SimpleNamespace(
+        perf_counter=lambda: float(next(clock))))
+    x = jnp.ones((8,))
+    built = executor.CompiledProgram(executor.Program(
+        fn=lambda v: jnp.sum(v), args=(x,), donate_argnums=(0,)))
+    assert built.stats["undonated"] == [0]
+    rec = built.stats["build"]
+    # a tick a clock read: began, (t0, t1, end) a lowering, twice, then
+    # the executable's pair
+    assert (rec["trace_s"], rec["lower_s"], rec["executable_s"]) == \
+        (2.0, 2.0, 1.0)
+    assert built.stats["compile_ms"] == 9000.0
+
+
+def test_build_record_says_what_the_cache_did(tmp_path, monkeypatch):
+    """miss, then hit (no backend compile), then off: the verdict is
+    this build's own, from jax's events while its executable is made."""
+    from jax.experimental.compilation_cache import compilation_cache
+    knobs = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    before = {k: getattr(jax.config, k) for k in knobs}
+    args = (jnp.full((16, 16), 0.1), jnp.ones((32,)))
+    try:
+        jax.config.update("jax_enable_compilation_cache", True)
+        monkeypatch.setenv(executor.ENV_CACHE_DIR, str(tmp_path))
+        executor.enable_persistent_cache()
+        first = executor.CompiledStep(_carried(), args).stats["build"]
+        second = executor.CompiledStep(_carried(), args).stats["build"]
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        third = executor.CompiledStep(_carried(), args).stats["build"]
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+    assert (first["cache"], second["cache"], third["cache"]) == \
+        ("miss", "hit", "off")
+    assert first["backend_compile_s"] > 0 == first["cache_retrieval_s"]
+    assert second["backend_compile_s"] == 0.0
+    assert 0 < second["cache_retrieval_s"] <= second["executable_s"]
+    assert third["backend_compile_s"] > 0 == third["cache_retrieval_s"]
+
+
+def test_compile_programs_ships_the_build_record(eight_devices):
+    prog, _, _ = _carry_program(_mesh4(eight_devices))
+    meta: dict = {}
+    compiled = executor.compile_programs({"full": prog}, meta)
+    assert meta["aot"]["full"]["build"] is compiled["full"].stats["build"]
+    assert meta["aot"]["full"]["build"] in executor.builds()
+    assert "compile_ms" not in meta["aot"]["full"]
+
+
+def test_process_age_is_the_kernels_and_grows():
+    import time
+
+    from dlnetbench_tpu.utils.timing import process_age_s
+    a = process_age_s()
+    time.sleep(0.03)
+    b = process_age_s()
+    # older than this module's import of jax, younger than the machine
+    assert 0 < a < b
+    with open("/proc/uptime") as f:
+        assert b <= float(f.read().split()[0])
