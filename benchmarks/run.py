@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import math
 import sys
 from pathlib import Path
 
@@ -92,9 +93,13 @@ def main(argv=None, keep_trace: bool = False) -> int:
         outcome["device"] = device
         outcome["cache"] = dict(cache.counts)
         correct = True
+        compared = {}
         for name, value, limit, where in outcome["checks"]:
             ok = value <= limit
             correct &= ok
+            # a gap that is not finite as a word: the line stays JSON
+            compared[name] = {"value": value if math.isfinite(value)
+                              else repr(value), "limit": limit}
             log({"line": "compared", "name": name, "value": value,
                  "limit": limit, "at": where, "ok": ok})
         log({"line": "compile_cache", **cache.counts})
@@ -119,6 +124,13 @@ def main(argv=None, keep_trace: bool = False) -> int:
                          for k, v in outcome["end_to_end"].items()
                          if k in units},
                 device=device)
+        # each number compared beside its limit: last on the result's
+        # line and the last lines of standard error, which is what a
+        # driver's record keeps of a run that is not correct
+        result["compared"] = compared
+        for name, row in compared.items():
+            print(f"compared {name} {row['value']!r} limit "
+                  f"{row['limit']!r}", file=sys.stderr)
     except harness.BenchError as e:
         print(f"benchmarks/run.py: {e}", file=sys.stderr)
         return 1

@@ -40,6 +40,10 @@ def program_config(cell, arch, extra: dict | None = None):
 
 
 class TrainCell:
+    # the weights a fixed horizon starts over from, and the program that
+    # puts them back (``horizon``); a subclass's ``__init__`` sets neither
+    kept = restore = None
+
     def __init__(self, cell: harness.Cell, seed: int, log,
                  program_over: dict | None = None):
         import jax
@@ -99,6 +103,30 @@ class TrainCell:
                 "delta_norms": {k: float(v)
                                 for k, v in delta_norms.items()}}
 
+    def horizon(self) -> int:
+        """The workload's ``cycle_steps`` (its ``cycle_why`` says why a
+        cell fixes its training horizon), 0 without the key.  With it,
+        on the first call, which set-up makes: a device copy of the
+        weights as they stand, after the checked steps, and the compiled
+        program that writes a fresh copy of them over the trained ones.
+        Those are donated and the kept ones pass a barrier, so XLA's one
+        copy a leaf lands in the trained weights' buffers: the peak
+        rises by the kept copy and the program has no temporaries
+        (``jnp.copy`` there compiles to two copies of each large leaf
+        through 1.16 GB of them; a ``trained`` that jit prunes as unused
+        donates nothing and the output is a third copy)."""
+        cycle = int(self.cell.workload.get("cycle_steps", 0))
+        if cycle and self.restore is None:
+            import jax
+            import jax.numpy as jnp
+            self.kept = jax.block_until_ready(jax.jit(
+                lambda p: jax.tree.map(jnp.copy, p))(self.params))
+            self.restore = jax.jit(
+                lambda trained, kept: jax.lax.optimization_barrier(kept),
+                donate_argnums=0, keep_unused=True,
+            ).lower(self.params, self.kept).compile()
+        return cycle
+
     def window(self, seconds: float, tracer: harness.TraceWindow) -> dict:
         """Steps for ``seconds``, ``steps_in_flight`` of them queued on
         the device at a time, as a training loop runs: the host waits
@@ -106,8 +134,13 @@ class TrainCell:
         so a notice of completion that comes late (PERF.md, stalls)
         idles nothing.  With 1 in flight every step is fenced before
         the next.  New steps are offered until those queued are due to
-        end at ``seconds``; the window closes when the last has ended."""
+        end at ``seconds``; the window closes when the last has ended.
+        Under a fixed horizon every ``cycle_steps``-th step is followed
+        by the restore, queued behind it like a step: the window trains
+        the same steps from the checked weights over and over, however
+        many of them fit."""
         import jax
+        cycle = self.horizon()
         clock, t0 = time.perf_counter, time.perf_counter()
         pending, ends, losses, dispatch = collections.deque(), [], [], []
         trace_after = self.cell.workload.get("trace_after_s", 2.0)
@@ -133,6 +166,8 @@ class TrainCell:
                 t_call = clock()
                 pending.append(self.call())
                 dispatch.append(clock() - t_call)
+            if cycle and len(dispatch) % cycle == 0:
+                self.params = self.restore(self.params, self.kept)
             if len(pending) >= self.in_flight:
                 with tracer.annotate("bench_wait"):
                     jax.block_until_ready(pending[0])
@@ -144,10 +179,11 @@ class TrainCell:
             tracer.maybe_stop()
         tracer.maybe_stop(force=True)
         return {"step_ends_s": ends, "dispatch_s": dispatch,
-                "losses": [float(v[0]) for v in losses]}
+                "losses": [float(v[0]) for v in losses],
+                "restores": len(dispatch) // cycle if cycle else 0}
 
     def free(self):
-        self.params = self.step = None
+        self.params = self.step = self.kept = self.restore = None
         gc.collect()
 
     def reference_steps(self, precision: str = "float32") -> dict:
@@ -184,6 +220,7 @@ def run(ctx) -> dict:
                 "tpu_custom_calls": tc.kernels,
                 "memory_analysis": tc.step.memory_analysis})
     got = tc.first_steps()
+    tc.horizon()        # what a fixed horizon keeps and compiles is set-up
     ctx["log"]({"line": "set-up", "first_losses": got["losses"]})
     setup_s = harness.process_age_s()
     before = harness.host_pressure()

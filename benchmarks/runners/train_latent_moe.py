@@ -182,6 +182,7 @@ def run(ctx) -> dict:
                 "tpu_custom_calls": tc.kernels,
                 "memory_analysis": tc.step.memory_analysis})
     got = tc.first_steps()
+    tc.horizon()        # what a fixed horizon keeps and compiles is set-up
     ctx["log"]({"line": "set-up", "first_losses": got["losses"]})
     setup_s = harness.process_age_s()
     before = harness.host_pressure()

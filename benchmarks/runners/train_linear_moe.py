@@ -163,6 +163,7 @@ def run(ctx) -> dict:
                 "tpu_custom_calls": tc.kernels,
                 "memory_analysis": tc.step.memory_analysis})
     got = tc.first_steps()
+    cycle = tc.horizon()    # what a fixed horizon keeps and compiles is set-up
     ctx["log"]({"line": "set-up", "first_losses": got["losses"]})
     setup_s = harness.process_age_s()
     before = harness.host_pressure()
@@ -210,6 +211,16 @@ def run(ctx) -> dict:
                 "moe_max_load": max(counted["max_load"]),
                 "moe_max_load_first_step": counted["max_load"][0],
                 "moe_max_load_last_step": counted["max_load"][-1],
+                # under a fixed horizon every later cycle repeats the
+                # first: the two largest loads are one number, and every
+                # loss and counter is that of the step a cycle earlier
+                "cycle_steps": cycle,
+                "restores": win["restores"],
+                "moe_max_load_first_cycle": max(
+                    counted["max_load"][:cycle or None]),
+                "cycles_repeat": bool(cycle) and all(
+                    v[cycle:] == v[:-cycle]
+                    for v in (win["losses"], *counted.values())),
                 "moe_routed_rows_median":
                     stats.percentile(counted["routed"], 50),
                 "moe_rows_past_bound": sum(counted["past_bound"]),

@@ -100,6 +100,32 @@ def test_cell_resolves_by_name(cell):
     assert {m["moves"] for m in c.per_layer} <= names
 
 
+@pytest.mark.parametrize("cell", CELLS)
+def test_fixed_horizon_is_whole_turns_of_the_feed_inside_the_bounds_reading(
+        cell):
+    """Wherever ``cycle_steps`` stands, a cycle is a whole number of
+    turns of the feed, so that ``feed()``'s count of steps meets the same
+    batches in the same order in every cycle, and no longer than the
+    timed steps over which ``moe_slots_from`` says the bound on an
+    expert's rows was read; its ``cycle_why`` stands beside it.  A cell
+    without the key has neither, nor a rehearsal of one."""
+    c = harness.load_cell(cell)
+    small = harness.rehearsal(c)
+    if "cycle_steps" not in c.workload:
+        assert "cycle_why" not in c.workload
+        assert "cycle_steps" not in small.workload
+        return
+    for sized in (c, small):
+        cycle = sized.workload["cycle_steps"]
+        assert isinstance(cycle, int) and cycle > 0
+        assert cycle % sized.traffic["pool_batches"] == 0
+    read_over = re.search(r"(\d+) timed steps", c.workload["moe_slots_from"])
+    assert c.workload["cycle_steps"] <= int(read_over.group(1)) == 51
+    why = c.workload["cycle_why"]
+    assert f"{c.workload['cycle_steps']} " in why and "pool_batches" in why
+    assert small.workload["cycle_steps"] < c.workload["cycle_steps"]
+
+
 @pytest.mark.parametrize("metric", MANIFEST["per_layer"],
                          ids=lambda m: m["name"])
 def test_layer_metric_file_and_reader(metric):

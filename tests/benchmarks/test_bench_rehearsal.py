@@ -51,6 +51,29 @@ def test_rehearsal_end_to_end(cell, capsys):
     assert compared and all(g["value"] <= g["limit"] for g in compared)
 
 
+def test_numbers_compared_stand_last_on_the_line_and_on_stderr(capsys):
+    """Each number compared beside its limit, as the last key of the
+    result's line and as the last lines of standard error before the
+    rehearsal's own refusal (a driver's record of a run that is not
+    correct keeps the end of each)."""
+    run.main(["--workload", "minerva7b_train", "--seed", "9", "--seconds",
+              "0.5", "--trace", "0", "--rehearse-cpu", "1"])
+    captured = capsys.readouterr()
+    got = [json.loads(ln) for ln in captured.out.splitlines()
+           if ln.startswith("{")]
+    result = got[-1]        # the log's line: the result and its age
+    assert list(result)[-2:] == ["compared", "t"]
+    rows = {g["name"]: {"value": g["value"], "limit": g["limit"]}
+            for g in got if g["line"] == "compared"}
+    assert result["compared"] == rows and set(rows) == {
+        "loss_gap", "grad_norm_gap", "delta_norm_gap"}
+    err = captured.err.splitlines()
+    assert err[-1].startswith("benchmarks/run.py: rehearsal")
+    assert err[-1 - len(rows):-1] == [
+        f"compared {k} {v['value']!r} limit {v['limit']!r}"
+        for k, v in rows.items()]
+
+
 @pytest.mark.parametrize("cell", TRAIN)
 def test_train_control_int8_reference_is_not_correct(cell):
     rows = train.readings(harness.rehearsal(harness.load_cell(cell)), 7,
@@ -158,6 +181,51 @@ def test_window_fills_the_queue_again_behind_a_late_notice(monkeypatch):
     assert ends[13] - ends[10] < 0.002       # known at once
     assert ends[-1] >= 0.29                  # the window kept its length
     assert win["losses"] == [float(i) for i in range(len(calls))]
+
+
+@pytest.mark.parametrize("in_flight", [1, 3, 8])
+def test_restore_is_queued_right_behind_every_cycle_th_step(in_flight,
+                                                            monkeypatch):
+    """The window's loop on a step that only counts: the restore is
+    dispatched straight after the step that ends a cycle, before the
+    host waits for any older step, it is given the trained weights and
+    the kept ones, what it returns is what the next step trains, and it
+    is no step: not timed, not counted."""
+    import dataclasses
+
+    import jax
+    tc = object.__new__(train.TrainCell)
+    cell = harness.rehearsal(harness.load_cell("minerva7b_train"))
+    tc.cell = dataclasses.replace(
+        cell, workload={**cell.workload, "cycle_steps": 5})
+    tc.in_flight, tc.params, tc.kept = in_flight, "seeded", "kept"
+    events, done = [], set()
+
+    def call():
+        events.append(("step", tc.params))
+        tc.params = f"trained {len(events)}"
+        return FakeLoss(sum(e[0] == "step" for e in events) - 1, done)
+
+    def restore(trained, kept):
+        events.append(("restore", trained, kept))
+        return "fresh"
+    tc.call, tc.restore = call, restore
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: (events.append(("wait",)), done.add(x.i)))
+    win = tc.window(0.05, harness.TraceWindow(False, 0.0))
+    steps = [i for i, e in enumerate(events) if e[0] == "step"]
+    assert len(steps) >= 15 and win["restores"] == len(steps) // 5 \
+        == sum(e[0] == "restore" for e in events)
+    assert win["losses"] == [float(i) for i in range(len(steps))]
+    assert len(win["step_ends_s"]) == len(win["dispatch_s"]) == len(steps)
+    for n, at in enumerate(steps, 1):
+        after = events[at + 1] if at + 1 < len(events) else ("end",)
+        if n % 5 == 0:
+            assert after == ("restore", f"trained {at + 1}", "kept")
+            if n < len(steps):
+                assert events[steps[n]] == ("step", "fresh")
+        else:
+            assert after[0] != "restore"
 
 
 @pytest.mark.parametrize("experts", [1, 4])
