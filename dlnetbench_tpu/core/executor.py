@@ -113,6 +113,9 @@ _HLO_OPCODE = re.compile(r"\s(fusion|dot|convolution)\(")
 _HLO_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
 _HLO_LOOP = re.compile(r"\swhile\(.*\bcondition=%?([\w.\-]+), "
                        r"body=%?([\w.\-]+)")
+_HLO_BRANCHES = re.compile(
+    r"\sconditional\(.*\b(?:branch_computations=\{([^}]*)\}"
+    r"|true_computation=(\S+), false_computation=(\S+))")
 _HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
 _HLO_TRANSFORM = re.compile(r"(\w+)\((.*)\)")
 
@@ -152,14 +155,19 @@ def hlo_op_scopes(hlo_text: str, vocabulary=spans.SCOPES) -> dict[str, str]:
     still has none inside a loop's body or condition (the copies and
     slices the compiler adds to prefetch a loop's operands carry no
     ``op_name``) takes the loop's: the trace holds the loop's event
-    and its body's, and time inside a loop belongs to one scope.
+    and its body's, and time inside a loop belongs to one scope.  A
+    conditional is read as a loop is, its branches as the body; one
+    that the compiler rebuilt (an operation moved into or out of every
+    branch) has lost its ``op_name`` and takes its branches' scope.
     ``spans.OTHER_SCOPE`` where there is none."""
     own: dict[str, str | None] = {}     # instruction -> scope or None
     fusions: dict[str, str] = {}        # fusion instruction -> callee
     matmul: dict[str, str] = {}         # computation -> its dot's scope
     root: dict[str, str] = {}           # computation -> its root's scope
     where: dict[str, str] = {}          # instruction -> its computation
-    loop_of: dict[str, str] = {}        # body or condition -> its while
+    loop_of: dict[str, str] = {}        # body or condition -> its while,
+    #                                     a branch -> its conditional
+    branches: dict[str, list] = {}      # conditional -> its branches
     comp = None
     for line in hlo_text.splitlines():
         m = _HLO_INSTRUCTION.match(line)
@@ -176,6 +184,12 @@ def hlo_op_scopes(hlo_text: str, vocabulary=spans.SCOPES) -> dict[str, str]:
         loop = _HLO_LOOP.search(head)
         if loop:
             loop_of.update(dict.fromkeys(loop.groups(), m.group(1)))
+        cond = _HLO_BRANCHES.search(head)
+        if cond:
+            names = re.findall(r"[\w.\-]+", " ".join(filter(None,
+                                                            cond.groups())))
+            branches[m.group(1)] = names
+            loop_of.update(dict.fromkeys(names, m.group(1)))
         op = _HLO_OPCODE.search(head)
         kind = op.group(1) if op else None
         if kind == "fusion":
@@ -189,6 +203,9 @@ def hlo_op_scopes(hlo_text: str, vocabulary=spans.SCOPES) -> dict[str, str]:
             root[comp] = scope
     for inst, callee in fusions.items():
         own[inst] = matmul.get(callee) or root.get(callee) or own[inst]
+    for inst, names in branches.items():
+        own[inst] = own[inst] or next(
+            (root[b] for b in names if b in root), None)
 
     def scoped(inst):
         """``inst``'s scope, or that of the innermost loop around it

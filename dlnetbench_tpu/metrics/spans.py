@@ -98,7 +98,7 @@ class _Span:
         self.attrs = attrs
 
     def __enter__(self):
-        self._depth = self._tracer._push(self.name)
+        self._depth = self._tracer._push(self)
         # the same region on the profiler's clock (a no-op flag test
         # while no profiler trace runs)
         self._ann = self._tracer._annotation(
@@ -135,23 +135,20 @@ class Tracer:
         # while this tracer was current (core/executor.py registers them)
         self.op_scopes: dict[str, dict[str, str]] = {}
         self._lock = threading.Lock()
-        self._local = threading.local()
-        # tid -> stack of OPEN span names, readable from other threads:
-        # the watchdog's stall handler fires on a Timer thread and must
-        # see where the measuring thread currently is (the span stack is
+        # tid -> stack of OPEN spans, readable from other threads: the
+        # watchdog's stall handler fires on a Timer thread and must see
+        # where the measuring thread currently is (the span stack is
         # the postmortem breadcrumb the stall message dumps)
-        self._active: dict[int, list[str]] = {}
+        self._active: dict[int, list[_Span]] = {}
 
     # -- called by _Span --------------------------------------------
-    def _push(self, name: str) -> int:
-        depth = getattr(self._local, "depth", 0)
-        self._local.depth = depth + 1
+    def _push(self, span: _Span) -> int:
         with self._lock:
-            self._active.setdefault(threading.get_ident(), []).append(name)
-        return depth
+            stack = self._active.setdefault(threading.get_ident(), [])
+            stack.append(span)
+            return len(stack) - 1
 
     def _pop(self) -> None:
-        self._local.depth = getattr(self._local, "depth", 1) - 1
         with self._lock:
             stack = self._active.get(threading.get_ident())
             if stack:
@@ -160,7 +157,7 @@ class Tracer:
     def active_stacks(self) -> dict[int, list[str]]:
         """Snapshot of every thread's open-span stack (outermost first)."""
         with self._lock:
-            return {tid: list(stack)
+            return {tid: [span.name for span in stack]
                     for tid, stack in self._active.items() if stack}
 
     def _record(self, name: str, t0: float, t1: float, depth: int,
@@ -180,6 +177,22 @@ class Tracer:
     # -- public ------------------------------------------------------
     def span(self, name: str, **attrs) -> _Span:
         return _Span(self, name, attrs or None)
+
+    def mark(self, name: str, **attrs) -> None:
+        """A fact with no duration (a choice made while a step is
+        traced): one more entry of the list ``name`` in the attrs of
+        the innermost span open on this thread, a build's ``compile``
+        as a rule; a span of that name where none is open."""
+        with self._lock:
+            stack = self._active.get(threading.get_ident())
+            if stack:
+                span = stack[-1]
+                if span.attrs is None:
+                    span.attrs = {}
+                span.attrs.setdefault(name, []).append(attrs)
+                return
+        now = time.perf_counter()
+        self._record(name, now, now, 0, attrs)
 
     def register_op_scopes(self, module: str, table: dict) -> None:
         with self._lock:
@@ -230,6 +243,13 @@ def span(name: str, **attrs):
     if t is None:
         return NULL_SPAN
     return t.span(name, **attrs)
+
+
+def mark(name: str, **attrs) -> None:
+    """``Tracer.mark`` on the process tracer; free when there is none."""
+    t = _TRACER
+    if t is not None:
+        t.mark(name, **attrs)
 
 
 def active_stacks() -> dict[int, list[str]]:

@@ -14,7 +14,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from dlnetbench_tpu.metrics.spans import scope
+from dlnetbench_tpu.metrics.spans import mark, scope
 from dlnetbench_tpu.ops import fp8 as qf8
 from dlnetbench_tpu.ops import int8 as q8
 
@@ -203,17 +203,55 @@ def moe_dense(x2d, w_router, w_gate, w_up, w_down, top_k: int):
 
 class MoePlan(NamedTuple):
     """A routing as indices: what dispatch, combine and their backward
-    passes gather rows through.
+    passes move rows through.
 
     ``slot`` [T, E] int32: the token's place in expert e's buffer, -1
     where it is not routed there or was dropped at capacity.
     ``src`` [E, C] int32, its inverse: the token in each slot, T (one
     past the last token) in an empty slot.
     ``idx`` [T, k] int32: the token's experts, as the router chose;
-    E (one past the last) for a choice this plan does not carry."""
+    E (one past the last) for a choice this plan does not carry.
+
+    The plan has two sides.  Tokens into slots (dispatch, the combine's
+    transpose) always go by ``src``: E * C rows.  Slots back into
+    tokens (combine, the dispatch's transpose, the gate's gradient) go
+    by whichever side is the smaller, which ``_plan_side`` reads off
+    these shapes: the k * T (token, choice) pairs through ``idx`` and
+    ``slot``, or the E * C slots through ``src`` again."""
     slot: jax.Array
     src: jax.Array
     idx: jax.Array
+
+
+# The slot side sums the first quarter, half or all of the slots, the
+# least that holds the rows routed here.  Read on the chip alone,
+# [32, 1536, 2048] into [16384, 2048] at a fill of 21 % and
+# [16, 4096, 2048] at 38 % (PERF.md section 6, PR 39): the sum costs
+# 1.3 ms and 66 ns a slot it visits, so all 49 152 read 4.5 ms and a
+# quarter 2.1, against 7.5 on the pair side; of 65 536 the half reads
+# 3.5 against 4.7.  Finer steps win nothing there (sixteenths 2.1 and
+# 3.2) and every step is a copy of the gather and the scatter in the
+# step's code, some 2.3 MB at these shapes, in each of a step's twelve
+# sites.  A loop over chunks of the held slots read worse than one
+# step: every scatter into [T, d] costs 0.8 ms before its first row.
+_SLOT_SHARES = (4, 2, 1)
+
+
+def _plan_side(plan: MoePlan, site: str) -> str:
+    """The side of the plan that the token-side pass ``site`` moves
+    rows through, from the plan's static shapes alone: ``"slots"``
+    where the experts' buffers hold fewer rows than the routing has
+    (token, choice) pairs, E * C < k * T, a chip that holds a share of
+    the router's experts; ``"pairs"`` where every expert is here and a
+    capacity factor of one or more makes the buffers the larger side.
+    The choice is a fact of the traced program, so it is marked once
+    for each traced site (``spans.mark``: on the build's ``compile``
+    span under a tracer, nothing without one)."""
+    (t, e), c, k = plan.slot.shape, plan.src.shape[1], plan.idx.shape[1]
+    side = "slots" if e * c < k * t else "pairs"
+    mark("moe.plan_side", site=site, side=side, pairs=k * t,
+         rows=e * c if side == "slots" else k * t)
+    return side
 
 
 def moe_plan(idx, pos, keep, cap: int) -> MoePlan:
@@ -221,8 +259,10 @@ def moe_plan(idx, pos, keep, cap: int) -> MoePlan:
     place in the queue of its (group, expert); ``keep`` [G, g, E] bool:
     routed there and under ``cap``, the slots a group has in an
     expert's buffer (C = G * cap).  ``src`` comes from a sort of each
-    queue (the kept first, in slot order), not from a scatter: the
-    TPU's scatter serialises its updates."""
+    queue (the kept first, in slot order), not from a scatter of
+    scalars: the TPU's scatter takes its updates one after another, a
+    price that rows of 8 KB carry (``_sum_by_token``) and indices do
+    not."""
     n_groups, g, e = pos.shape
     t = n_groups * g
     group = jnp.arange(n_groups, dtype=jnp.int32)[:, None, None]
@@ -245,9 +285,15 @@ def _to_slots(x, plan: MoePlan, w=None):
     xe = jnp.take(x, plan.src, axis=0, mode="fill", fill_value=0)
     if w is None:
         return xe
-    ws = jnp.take_along_axis(w.T, plan.src, axis=1, mode="fill",
-                             fill_value=0)                  # [E, C]
+    ws = _slot_weights(w, plan)
     return xe.astype(ws.dtype) * ws[..., None]
+
+
+def _slot_weights(w, plan: MoePlan):
+    """``w`` [T, E] at each slot's (token, expert) -> [E, C]; zero in
+    an empty slot."""
+    return jnp.take_along_axis(w.T, plan.src, axis=1, mode="fill",
+                               fill_value=0)
 
 
 def _chosen(plan: MoePlan, e: int):
@@ -268,18 +314,61 @@ def _from_slots(out, plan: MoePlan):
     that the gathered [k * T, d] rows need no copy to be seen as that:
     a [T, k, d] view is another tiling on the TPU."""
     e, c, d = out.shape
-    slot = _of_choice(plan.slot, plan)                      # [T, k]
-    row = jnp.where(slot >= 0, plan.idx * c + slot, e * c)
+    row = _slot_of_choice(plan)
     return jnp.take(out.reshape(e * c, d), row.T, axis=0, mode="fill",
                     fill_value=0)
+
+
+def _slot_of_choice(plan: MoePlan):
+    """[T, k] int32: the slot, counted through all the buffers, that
+    holds the token's k-th choice; E * C, one past the last, where
+    none does."""
+    e, c = plan.src.shape
+    slot = _of_choice(plan.slot, plan)                      # [T, k]
+    return jnp.where(slot >= 0, plan.idx * c + slot, e * c)
+
+
+def _sum_by_token(rows, plan: MoePlan, w=None):
+    """Slot rows [E, C, d] summed into their tokens' rows [T, d], in
+    float32: row (e, c), times ``w[src[e, c], e]`` where ``w`` [T, E]
+    is given, is added to token ``src[e, c]``; an empty slot adds
+    nothing.  The slot side of ``_from_slots`` and a sum over k.
+
+    The slots are sorted by token, which puts the held ones first
+    (an empty slot names token T), and one gather and one scatter-add
+    in that order take the least share of them (``_SLOT_SHARES``) that
+    holds every held one: what moves is the rows the plan holds, not
+    the room it has for them.  The TPU applies a scatter's updates
+    in their order, so the same inputs give the same bits."""
+    e, c, d = rows.shape
+    t, n = plan.slot.shape[0], e * c
+    src = plan.src.reshape(n)
+    token, order = jax.lax.sort_key_val(src, jnp.arange(n, dtype=jnp.int32))
+    rows = rows.reshape(n, d)
+    ws = None if w is None else _slot_weights(w, plan).reshape(n)
+
+    def first(size):
+        def summed():
+            r = jnp.take(rows, order[:size], axis=0).astype(_F32)
+            if ws is not None:
+                r = r * jnp.take(ws, order[:size])[:, None]
+            return jnp.zeros((t, d), _F32).at[token[:size]].add(
+                r, mode="drop", indices_are_sorted=True)
+        return summed
+    sizes = sorted({-(-n // share) for share in _SLOT_SHARES})
+    held = jnp.sum(src < t, dtype=jnp.int32)
+    return jax.lax.switch(jnp.sum(held > jnp.asarray(sizes[:-1])),
+                          [first(size) for size in sizes])
 
 
 @jax.custom_vjp
 def dispatch_rows(x, plan: MoePlan):
     """``xe[e, c] = x[src[e, c]]`` in x's dtype, zeros in empty slots
     (the grouped kernels' amax and the expert backward rely on padded
-    rows being zero).  Its transpose is a gather too, a combine with
-    weight one, so no scatter-add appears."""
+    rows being zero): a gather of E * C rows, whichever side the plan
+    has.  Its transpose is a combine with weight one and takes the
+    plan's smaller side (``_plan_side``): a gather of the k * T pairs'
+    rows and a sum over k, or the sum of the slots' rows by token."""
     with scope("moe.dispatch"):
         return _to_slots(x, plan)
 
@@ -290,7 +379,10 @@ def _dispatch_rows_fwd(x, plan):
 
 def _dispatch_rows_bwd(plan, dxe):
     with scope("moe.dispatch"):
-        dx = jnp.sum(_from_slots(dxe, plan), axis=0, dtype=_F32)
+        if _plan_side(plan, "dispatch.bwd") == "slots":
+            dx = _sum_by_token(dxe, plan)
+        else:
+            dx = jnp.sum(_from_slots(dxe, plan), axis=0, dtype=_F32)
         return dx.astype(dxe.dtype), None
 
 
@@ -347,7 +439,11 @@ def moe_dispatch_held(x2d, weights, idx, held: tuple, slots: int):
     whose expert is not held is neither dispatched nor combined: in the
     plan it carries the index ``count``, one past the held, which
     ``_chosen`` matches to no expert and ``_from_slots`` sends to the
-    zero fill row.
+    zero fill row.  Where ``count * slots`` is less than the T * k
+    pairs, a chip with a share of the experts, most pairs are such
+    choices: combine and both backward passes then move rows through
+    the plan's slot side (``_plan_side``), the held rows and not one
+    for every pair.
 
     Returns ``(xe [count, slots, d], plan, gate [T, count], load)``:
     the ``moe_dispatch`` contract over the held experts, and ``load``
@@ -365,10 +461,17 @@ def moe_combine(out, plan: MoePlan, gate):
     dtype, with the plan and the combine weights of ``moe_dispatch``:
     ``y[t] = sum_k gate[t, e_k] * out[e_k, slot[t, e_k]]`` over the
     token's top-k, product and sum in float32; a dropped choice adds
-    nothing.  Backward by row gathers as well: the transpose is a
-    dispatch of ``dy`` with the gate as a per-slot weight, the gate's
-    gradient a row-wise dot of ``dy`` with the gathered rows."""
+    nothing.  On the plan's pair side (``_plan_side``) the k * T rows
+    are gathered and summed over k; on its slot side the E * C slot
+    rows, times their slot's gate, are summed by token.  The transpose
+    is a dispatch of ``dy`` with the gate as a per-slot weight on
+    either side; the gate's gradient is the dot of ``dy[t]`` with the
+    expert's row for t, taken a pair over the gathered rows or a slot
+    over the rows where they lie and read back as E * C scalars."""
     with scope("moe.combine"):
+        if _plan_side(plan, "combine") == "slots":
+            return _sum_by_token(out, plan,
+                                 gate.astype(_F32)).astype(out.dtype)
         w = _of_choice(gate.astype(_F32), plan).T           # [k, T]
         rows = _from_slots(out, plan).astype(_F32)
         return jnp.sum(rows * w[..., None], axis=0).astype(out.dtype)
@@ -381,9 +484,16 @@ def _moe_combine_fwd(out, plan, gate):
 def _moe_combine_bwd(res, dy):
     out, plan, gate = res
     with scope("moe.combine"):
-        dout = _to_slots(dy, plan, gate.astype(_F32))
-        dw = jnp.sum(_from_slots(out, plan).astype(_F32)
-                     * dy.astype(_F32), axis=-1).T          # [T, k]
+        if _plan_side(plan, "combine.bwd") == "slots":
+            dys = _to_slots(dy, plan).astype(_F32)          # [E, C, d]
+            dout = dys * _slot_weights(gate.astype(_F32), plan)[..., None]
+            dot = jnp.sum(out.astype(_F32) * dys, axis=-1)  # [E, C]
+            dw = jnp.take(dot.reshape(-1), _slot_of_choice(plan),
+                          mode="fill", fill_value=0)        # [T, k]
+        else:
+            dout = _to_slots(dy, plan, gate.astype(_F32))
+            dw = jnp.sum(_from_slots(out, plan).astype(_F32)
+                         * dy.astype(_F32), axis=-1).T      # [T, k]
         dgate = jnp.sum(jnp.where(_chosen(plan, gate.shape[1]),
                                   dw[..., None], 0), axis=1)   # [T, E]
         return dout.astype(out.dtype), None, dgate.astype(gate.dtype)

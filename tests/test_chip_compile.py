@@ -267,15 +267,30 @@ def test_grouped_matmul_at_the_cells_shapes(for_chip, name):
     assert re.search(rf"{re.escape(first)} = s32\[{e}\]", text)
 
 
+def scopes_by_opcode(text: str, opcodes: str, keep) -> dict:
+    """{opcode: the scopes its instructions lie under}, over the
+    instructions of ``text`` whose opcode is one of ``opcodes`` (a
+    regex alternation) and that ``keep(line, scope)`` takes."""
+    import re
+
+    from dlnetbench_tpu.core import executor
+    table = executor.hlo_op_scopes(text)
+    opcode = re.compile(rf"\s({opcodes})\(")
+    found = {}
+    for line in text.splitlines():
+        m = executor._HLO_INSTRUCTION.match(line)
+        op = opcode.search(line.partition(", metadata=")[0])
+        if m and op and keep(line, table[m.group(1)]):
+            found.setdefault(op.group(1), set()).add(table[m.group(1)])
+    return found
+
+
 def test_moe_dispatch_and_combine_at_the_cell_shapes(for_chip):
     """The row-gather dispatch and combine with their hand-written
     backward, at ``mixtral8x7b_train``'s shapes (T = 8192 tokens, 8
     experts top-2, C = 2560 slots, D = 4096, bf16): the chip's compiler
     takes the gathers and the sort; nothing of shape [T, E, C], no
     matmul, scatter or kernel under the two scopes."""
-    import re
-
-    from dlnetbench_tpu.core import executor
     from dlnetbench_tpu.models import layers, moe
     t, e, k, c = 8192, 8, 2, 2560
 
@@ -288,17 +303,49 @@ def test_moe_dispatch_and_combine_at_the_cell_shapes(for_chip):
                     ((D, e), BF16), ((e, c, D), BF16))
     assert kernels_in(text) == 0
     assert f"[{t},{e},{c}]" not in text
-    table = executor.hlo_op_scopes(text)
     route = {"moe.dispatch", "moe.combine"}
-    opcode = re.compile(r"\s(gather|sort|dot|convolution|scatter)\(")
-    found = {}
-    for line in text.splitlines():
-        m = executor._HLO_INSTRUCTION.match(line)
-        op = opcode.search(line.partition(", metadata=")[0])
-        if m and op and table[m.group(1)] in route:
-            found.setdefault(op.group(1), set()).add(table[m.group(1)])
+    found = scopes_by_opcode(text, "gather|sort|dot|convolution|scatter",
+                             lambda line, scope: scope in route)
     assert found["gather"] == route and found["sort"] == {"moe.dispatch"}
     assert not {"dot", "convolution", "scatter"} & set(found)
+
+
+# the held layers of the two cells whose chip holds a share of the
+# experts: (T, k, E held, C, D)
+CELL_HELD = {"qwen3next_a3b_train_s16k": (16384, 10, 32, 1536, 2048),
+             "kimivl_a3b_train_s8k": (16384, 6, 16, 4096, 2048)}
+
+
+@pytest.mark.parametrize("cell", CELL_HELD)
+def test_held_routing_at_the_cell_shapes_moves_no_pair_rows(for_chip, cell):
+    """Dispatch and combine of a layer whose chip holds a share of the
+    router's experts, with their hand-written backward, bf16: the
+    plan's slot side (E * C < k * T).  The chip's compiler takes the
+    sort, the steps' gathers and scatter-adds; nothing is as long as
+    the k * T pairs, every gather and scatter lies under the two
+    scopes, no matmul or kernel does."""
+    import re
+
+    from dlnetbench_tpu.models import layers
+    t, k, e, c, d = CELL_HELD[cell]
+
+    def loss(x, weights, scale, idx):
+        xe, plan, gate, _ = layers.moe_dispatch_held(x, weights, idx,
+                                                     (2 * e, e), c)
+        assert layers._plan_side(plan, "combine") == "slots"
+        y = layers.moe_combine(xe * scale, plan, gate)
+        return jnp.sum(jnp.sin(y.astype(F32)))     # y and dy both live
+    text = for_chip(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                    ((t, d), BF16), ((t, k), F32), ((e, c, d), BF16),
+                    ((t, k), I32))
+    assert kernels_in(text) == 0
+    assert not re.search(rf"\[{k},{t},{d}\]|\[{k * t},{d}\]|"
+                         rf"\[{t},{k},{d}\]", text)
+    found = scopes_by_opcode(
+        text, "gather|scatter|dot|convolution",
+        lambda line, scope: re.search(rf"\[\d+,{d}\]", line))
+    assert found == {"gather": {"moe.dispatch", "moe.combine"},
+                     "scatter": {"moe.dispatch", "moe.combine"}}
 
 
 def test_moe_train_step_at_the_cell_shapes_fits_the_chip(for_chip, one_chip):

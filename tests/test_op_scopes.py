@@ -287,6 +287,47 @@ ENTRY %main (x: f32[4]) -> f32[4] {
     assert table["copy.1"] == spans.OTHER_SCOPE        # outside any loop
 
 
+def test_a_conditional_is_read_as_a_loop_and_takes_its_branches_scope():
+    """A conditional that the compiler rebuilt (an operation moved into
+    or out of every branch) carries no ``op_name``: it takes its
+    branches' scope, and what has none inside a branch takes the
+    conditional's; one with a scope of its own keeps it."""
+    text = """HloModule jit_f
+
+%region_1.1 (p: (f32[4])) -> (f32[4]) {
+  %copy.2 = f32[4] copy(%x)
+  %fusion.5 = f32[4] fusion(%copy.2), kind=kCustom, calls=%scatter_comp, metadata={op_name="jit(f)/transpose(jvp(moe.dispatch))/scatter-add"}
+  ROOT %tuple.1 = (f32[4]) tuple(%fusion.5)
+}
+
+%region_2.2 (p: (f32[4])) -> (f32[4]) {
+  %copy.3 = f32[4] copy(%x)
+  %fusion.6 = f32[4] fusion(%copy.3), kind=kCustom, calls=%scatter_comp, metadata={op_name="jit(f)/transpose(jvp(moe.dispatch))/scatter-add"}
+  ROOT %tuple.2 = (f32[4]) tuple(%fusion.6)
+}
+
+%then.3 (p: (f32[4])) -> (f32[4]) {
+  %copy.4 = f32[4] copy(%x)
+  ROOT %add.9 = f32[4] add(%copy.4, %copy.4), metadata={op_name="jit(f)/attn/add"}
+}
+
+ENTRY %main (x: f32[4]) -> f32[4] {
+  %conditional.4 = (f32[4]) conditional(%i, %t.1, %t.2), branch_computations={%region_1.1, %region_2.2}, backend_config={"flag_configs":[]}
+  %cond.7 = (f32[4]) conditional(%i, %t.1, %t.2), branch_computations={%region_1.1, %region_2.2}, metadata={op_name="jit(f)/jvp(moe.combine)/cond"}
+  %conditional.8 = f32[4] conditional(%b, %x, %x), true_computation=%then.3, false_computation=%then.3
+  %copy.1 = f32[4] copy(%x)
+}
+"""
+    table = executor.hlo_op_scopes(text)
+    assert table["conditional.4"] == "moe.dispatch"    # its branches'
+    assert table["cond.7"] == "moe.combine"            # its own stays
+    assert table["conditional.8"] == table["copy.4"] == "attn"
+    assert table["fusion.5"] == "moe.dispatch"
+    # a branch belongs to the conditional named last, as a body would
+    assert table["copy.2"] == table["copy.3"] == "moe.combine"
+    assert table["copy.1"] == spans.OTHER_SCOPE
+
+
 _LOOP_ATTRS = re.compile(r", condition=%?[\w.\-]+, body=%?[\w.\-]+")
 
 
