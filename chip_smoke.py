@@ -124,6 +124,12 @@ class Sizes:
         linear_num_value_heads=4, linear_key_head_dim=128,
         linear_value_head_dim=128, vocab_size=512))
     q_seq: int = 1024
+    # conv_moe: the published 64 lanes a head, one dense conv layer and
+    # the period [attention, conv, conv, conv], every expert held
+    v_shape: dict = dataclasses.field(default_factory=lambda: dict(
+        hidden_size=256, num_attention_heads=4, num_key_value_heads=1,
+        intermediate_size=512, moe_intermediate_size=128, vocab_size=512))
+    v_seq: int = 1024
     # four chips
     c4_fsdp_model: str = "llama3_8b_16_bfloat16"
     c4_fsdp_scale: float = 0.125
@@ -169,6 +175,10 @@ TINY = Sizes(
                  linear_num_value_heads=4, linear_key_head_dim=16,
                  linear_value_head_dim=16, vocab_size=256),
     q_seq=128,
+    v_shape=dict(hidden_size=64, num_attention_heads=4,
+                 num_key_value_heads=2, intermediate_size=128,
+                 moe_intermediate_size=32, vocab_size=256),
+    v_seq=128,
     c4_fsdp_scale=1e-5, c4_h3d_scale=1e-5, c4_time_scale=1e-4, c4_batch=4,
     c4_seq=128,
 )
@@ -1105,6 +1115,69 @@ def phase_linear_moe(sz: Sizes) -> dict:
                       "compiled in"}
 
 
+# -------------------------------------------------------- phase: conv_moe
+
+def phase_conv_moe(sz: Sizes) -> dict:
+    """The short-convolution expert decoder (models/hybrid.py: ``conv``
+    layers and a ``gated`` layer without a gate, a leading dense layer
+    and then all 8 of the router's experts held, the head tied) through
+    the same step builder and executor as ``phase_train``, the attention
+    kernels forced at 64 lanes, against the benchmark's plain float32
+    reference on the same seeded weights."""
+    from benchmarks import reference_conv_moe, weights_conv_moe
+    from benchmarks.runners import train_conv_moe
+    from dlnetbench_tpu.core import executor
+    from dlnetbench_tpu.models import bench_step
+
+    kinds = ["conv", "full_attention", "conv", "conv", "conv"]
+    config = {
+        **sz.v_shape, "num_hidden_layers": 5, "num_dense_layers": 1,
+        "layer_types": kinds, "num_experts": 8, "num_experts_per_tok": 4,
+        "conv_L_cache": 3, "conv_bias": False, "norm_eps": 1e-5,
+        "norm_topk_prob": True, "use_expert_bias": True,
+        "routed_scaling_factor": 1, "rope_theta": 1000000,
+        "torch_dtype": sz.dtype,
+        "assumed": {"first_held_expert": 0, "router_bias_scale": 0.01}}
+    arch = weights_conv_moe.arch_of(config)
+    slots = 2 * sz.v_seq        # every row of the batch: no bound to reach
+    cfg = train_conv_moe.config_of(
+        arch, sz.v_seq, slots, remat=True, attention_impl="flash",
+        loss_row_block=sz.v_seq)
+
+    def make_params():
+        return weights_conv_moe.make_params(arch, sz.seed)
+    tokens = weights_conv_moe.make_token_pool(
+        sz.seed, 1, 2, sz.v_seq + 1, arch["vocab_size"])[0]
+    want = reference_conv_moe.sgd_steps(
+        make_params, [tokens] * sz.h_k, arch, sz.h_lr)
+    prog = executor.CompiledProgram(executor.Program(
+        fn=bench_step.make_train_k(cfg, sz.h_k, sz.h_lr),
+        args=(make_params(), tokens),
+        donate_argnums=bench_step.DONATE_ARGNUMS))
+    kernels = prog.as_text().count("tpu_custom_call")
+    if on_tpu():
+        # three attention kernels, three grouped matmuls an expert
+        # layer, each at least once
+        require(kernels >= 6, f"compiled short-convolution step holds "
+                              f"{kernels} tpu_custom_call")
+    got, routing, gap = expert_step_checks("short-convolution", prog, want,
+                                           sz.h_k)
+    return {"shapes": {**sz.v_shape, "seq": sz.v_seq, "batch": 2,
+                       "layers": list(arch["layer_kinds"]), "experts": 8,
+                       "held": [0, 8], "top_k": 4, "slots": slots,
+                       "steps": sz.h_k, "lr": sz.h_lr},
+            "tpu_custom_calls": kernels,
+            "memory_analysis": prog.memory_analysis,
+            "losses": [round(v, 4) for v in got],
+            "float32_losses": [round(v, 4) for v in want["losses"]],
+            "rows_routed_to_held": int(routing["routed"][0]),
+            "selection_gap": gap,
+            "checks": "first loss, the loss's fall and the selections "
+                      "within tolerance of "
+                      "benchmarks/reference_conv_moe.py; no row past the "
+                      "bound; attention and expert kernels compiled in"}
+
+
 # ------------------------------------------------------ four-chip phases
 
 def all_device_ids() -> set:
@@ -1313,7 +1386,8 @@ ONE_CHIP = (("kernels", phase_kernels), ("train", phase_train),
             ("proxy", phase_proxy), ("serve", phase_serve),
             ("moe", phase_moe), ("hybrid", phase_hybrid),
             ("latent_moe", phase_latent_moe),
-            ("linear_moe", phase_linear_moe))
+            ("linear_moe", phase_linear_moe),
+            ("conv_moe", phase_conv_moe))
 FOUR_CHIPS = (("mesh_proxies", phase_mesh_proxies), ("spmd", phase_spmd),
               ("kv_shard", phase_kv_shard))
 

@@ -61,7 +61,7 @@ class ModelCard:
     patch_size: int = 0             # ViT
     num_classes: int = 0            # ViT head
     # a per-layer pattern of mixers (models/hybrid.py KINDS: mamba,
-    # window, full, gmu, cross, mla, gdn, gated), one name a decoder block; () => every
+    # window, full, gmu, cross, mla, gdn, gated, conv), one name a decoder block; () => every
     # block is the transformer's one kind (models/transformer.py)
     layer_kinds: tuple = ()
     sliding_window: int = 0         # keys a "window" layer attends
@@ -86,12 +86,18 @@ class ModelCard:
     # lanes (0 => embed_dim / num_heads), RoPE on the first rope_dim
     attn_head_dim: int = 0
     rope_dim: int = 0
+    attn_output_gate: bool = True   # false: the heads' output ungated,
+                                    # the query projection without lanes
+                                    # for a gate
     # linear attention with a matrix state (a "gdn" layer)
     linear_key_heads: int = 0
     linear_value_heads: int = 0
     linear_key_dim: int = 0
     linear_value_dim: int = 0
     linear_conv: int = 0            # depthwise causal conv width
+    # gated short convolution (a "conv" layer): taps of its depthwise
+    # causal conv over the model's width
+    short_conv: int = 0
 
     # ------------------------------------------------------------------ #
     @property
@@ -162,7 +168,10 @@ class ModelCard:
         if kind == "gated":
             dh = self.attn_head_dim or self.head_dim
             dq, dkv = self.num_heads * dh, self.kv_heads * dh
-            return d * 2 * dq + 2 * d * dkv + 2 * dh + dq * d
+            return (d * (1 + self.attn_output_gate) * dq + 2 * d * dkv
+                    + 2 * dh + dq * d)
+        if kind == "conv":
+            return d * 3 * d + self.short_conv * d + d * d
         if kind == "mla":
             h, r = self.num_heads, self.kv_lora_rank
             qk = self.qk_nope_head_dim + self.qk_rope_head_dim

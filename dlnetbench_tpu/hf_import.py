@@ -6,7 +6,8 @@ for this framework: what every downstream layer consumes is the
 *architecture card* (core/model_card.py), so the useful artifact of "import
 a HF model" is a card, not a cache of safetensors.  This module maps a HF
 config (``model_type`` gpt2 / llama / mistral / mixtral / phi4flash /
-deepseek_v3 as Kimi-VL and Moonlight state it / qwen3_next / vit) onto
+deepseek_v3 as Kimi-VL and Moonlight state it / qwen3_next / lfm2_moe /
+vit) onto
 ``ModelCard`` fields and writes the card JSON.
 
 Offline-first: hub access is attempted only when requested and is never
@@ -104,6 +105,9 @@ def card_from_hf_config(name: str, cfg: Mapping[str, Any] | Any) -> ModelCard:
 
     if mt == "qwen3_next":
         return _linear_moe_card(name, cfg)
+
+    if mt == "lfm2_moe":
+        return _conv_moe_card(name, cfg)
 
     if mt == "vit":
         image = int(cfg["image_size"])
@@ -292,15 +296,62 @@ def _linear_moe_card(name: str, cfg: Mapping[str, Any]) -> ModelCard:
     )
 
 
+def _conv_moe_card(name: str, cfg: Mapping[str, Any]) -> ModelCard:
+    """``model_type: "lfm2_moe"``: gated short convolutions with
+    grouped-query softmax attention (a norm a head, RoPE on every lane,
+    no output gate) where ``layer_types`` says ``full_attention``,
+    ``num_dense_layers`` leading dense FFNs and then sigmoid-routed
+    experts with a selection bias and no shared expert, the head tied
+    to the embedding.  Refused, because no layer here computes them: a
+    bias in the convolution, unnormalised top-k weights, a router
+    without its selection bias, scaled RoPE."""
+    unsupported = {k: cfg.get(k) for k, ok in (
+        ("conv_bias", (None, False)), ("norm_topk_prob", (True,)),
+        ("use_expert_bias", (True,)), ("rope_scaling", (None,)))
+        if cfg.get(k) not in ok}
+    if unsupported:
+        raise ValueError(f"{name}: short-convolution import has no "
+                         f"{unsupported}")
+    heads = int(cfg["num_attention_heads"])
+    kinds = tuple({"conv": "conv", "full_attention": "gated"}[t]
+                  for t in cfg["layer_types"])
+    return ModelCard(
+        name=name,
+        embed_dim=int(cfg["hidden_size"]),
+        num_heads=heads,
+        num_kv_heads=int(cfg.get("num_key_value_heads") or heads),
+        ff_dim=int(cfg["intermediate_size"]),
+        seq_len=int(cfg["max_position_embeddings"]),
+        num_decoder_blocks=int(cfg["num_hidden_layers"]),
+        vocab_size=int(cfg["vocab_size"]),
+        gated_mlp=True,
+        tied_embeddings=bool(cfg.get("tie_embedding", True)),
+        layer_kinds=kinds,
+        attn_output_gate=False,
+        rope_theta=float(cfg.get("rope_theta", 10000.0)),
+        rms_norm=True,
+        norm_eps=float(cfg.get("norm_eps", 1e-5)),
+        short_conv=int(cfg["conv_L_cache"]),
+        moe_params=MoEParams(
+            num_experts=int(cfg["num_experts"]),
+            num_experts_per_tok=int(cfg["num_experts_per_tok"]),
+            scoring="sigmoid",
+            routed_scale=float(cfg.get("routed_scaling_factor", 1.0)),
+            expert_ff_dim=int(cfg["moe_intermediate_size"]),
+            first_dense_layers=int(cfg.get("num_dense_layers", 0)),
+        ),
+    )
+
+
 def card_to_json(card: ModelCard) -> dict:
     """Card -> the on-disk JSON schema (reference models/*.json shape plus
-    the rebuild's extended fields; zero/False/None fields are elided)."""
+    the rebuild's extended fields; fields at their default are elided)."""
     out: dict[str, Any] = {}
     for f in dataclasses.fields(ModelCard):
         if f.name in ("name", "moe_params"):
             continue
         v = getattr(card, f.name)
-        if v:
+        if v != f.default:
             out[f.name] = list(v) if isinstance(v, tuple) else v
     if card.moe_params is not None:
         defaults = MoEParams(0, 0)

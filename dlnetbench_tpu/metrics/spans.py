@@ -53,7 +53,8 @@ from pathlib import Path
 # benchmark's per-layer readers take them from here.
 SCOPES = ("embed", "attn", "mlp", "moe.router", "moe.dispatch",
           "moe.experts", "moe.combine", "moe.shared", "ssm", "ssm.scan",
-          "gmu", "linattn", "linattn.rule", "head_loss", "optimizer")
+          "gmu", "linattn", "linattn.rule", "conv", "conv.gate", "head_loss",
+          "optimizer")
 OTHER_SCOPE = "other"   # an instruction under none of them
 
 

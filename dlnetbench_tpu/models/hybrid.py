@@ -6,10 +6,14 @@ layer, LayerNorm with bias around a SwiGLU MLP, no positional encoding,
 the embedding tied to the head), the latent-attention expert models
 of the DeepSeek-V3 family as Kimi-VL-A3B and Moonlight state them (an
 ``mla`` mixer in every layer, RMSNorm, a leading dense layer and then
-routed experts beside shared ones, an untied head) and the
+routed experts beside shared ones, an untied head), the
 linear-attention expert models of the Qwen3-Next family (``gdn`` layers
 with one ``gated`` layer a period, a zero-centred RMSNorm, routed
-experts beside a shared one behind a sigmoid gate).
+experts beside a shared one behind a sigmoid gate) and the
+short-convolution expert models of the LFM2 family (``conv`` layers
+with a ``gated`` layer without its gate every third or fourth, leading
+dense layers that are ``conv`` layers, sigmoid-routed experts and no
+shared one, the head tied under RMSNorm).
 
 ``HybridConfig.layer_kinds`` names each layer's mixer, one of ``KINDS``:
 
@@ -38,7 +42,14 @@ experts beside a shared one behind a sigmoid gate).
   projection carries a gate of the head's width beside each head's
   query, queries and keys are normed a head, RoPE turns the first
   ``rope_dim`` lanes, and ``sigmoid(gate)`` multiplies the heads'
-  output before the output projection.
+  output before the output projection.  Where the card states no gate
+  (``attn_gate`` false) the projection carries the queries alone and
+  the heads' output goes to the output projection as it is: grouped
+  softmax attention with a norm a head.
+* ``conv``   — gated short convolution: one projection to three streams
+  ``[b | c | u]`` of the model's width, ``c * conv(b * u)`` with a
+  depthwise causal convolution of ``short_conv`` taps over time, no
+  activation and no bias, and an output projection.
 
 Every layer is ``x += mixer(norm(x)); x += ffn(norm(x))``.
 ``ffn_kinds`` names each layer's FFN: ``dense`` (SwiGLU at ``ff_dim``)
@@ -79,12 +90,13 @@ from dlnetbench_tpu.ops.gated_delta_rule import gated_delta_rule
 from dlnetbench_tpu.ops.selective_scan import selective_scan
 
 _F32 = jnp.float32
-KINDS = ("mamba", "window", "full", "gmu", "cross", "mla", "gdn", "gated")
+KINDS = ("mamba", "window", "full", "gmu", "cross", "mla", "gdn", "gated",
+         "conv")
 FFN_KINDS = ("dense", "moe")
 # which stack of parameters a layer's mixer reads
 GROUP_OF = {"mamba": "mamba", "window": "attn", "full": "attn",
             "gmu": "gmu", "cross": "cross", "mla": "mla", "gdn": "gdn",
-            "gated": "gated"}
+            "gated": "gated", "conv": "conv"}
 # what a step with expert layers returns beside its loss
 # (``models/moe.moe_held``): three counters over its expert layers (the
 # rows routed to held experts and the rows past the bound summed, the
@@ -143,6 +155,8 @@ class HybridConfig:
     gdn_key_dim: int = 0            # lanes of a key head
     gdn_value_dim: int = 0          # lanes of a value head
     gdn_conv: int = 4               # taps of the depthwise causal conv
+    attn_gate: bool = True          # a "gated" layer's output gate
+    short_conv: int = 3             # taps of a "conv" layer's convolution
     rule_impl: str = "auto"         # ops.gated_delta_rule: auto|pallas|xla
     # the FFN of each layer; () = every layer dense
     ffn_kinds: tuple = ()
@@ -226,6 +240,7 @@ class HybridConfig:
                   "norm_plus_one": card.norm_plus_one,
                   "tied_head": card.tied_embeddings,
                   "attn_head_dim": card.attn_head_dim,
+                  "attn_gate": card.attn_output_gate,
                   "rope_dim": card.rope_dim,
                   "gdn_key_heads": card.linear_key_heads,
                   "gdn_value_heads": card.linear_value_heads,
@@ -241,6 +256,8 @@ class HybridConfig:
             stated["norm_eps"] = card.norm_eps
         if card.linear_conv:
             stated["gdn_conv"] = card.linear_conv
+        if card.short_conv:
+            stated["short_conv"] = card.short_conv
         if (moe := card.moe_params) is not None:
             width = moe.expert_ff_dim or card.ff_dim
             stated.update(
@@ -413,12 +430,18 @@ def param_shapes(cfg: HybridConfig) -> dict:
     if (m := sizes["gated"]):
         h, hkv = cfg.num_heads, cfg.num_kv_heads
         out.update({
-            "gated/wq": ((m, d, 2 * h * dh), s_d),
+            "gated/wq": ((m, d, (1 + cfg.attn_gate) * h * dh), s_d),
             "gated/wk": ((m, d, hkv * dh), s_d),
             "gated/wv": ((m, d, hkv * dh), s_d),
             "gated/q_norm": ((m, dh), unit),
             "gated/k_norm": ((m, dh), unit),
             "gated/wo": ((m, h * dh, d), 1.0 / math.sqrt(h * dh))})
+    if (m := sizes["conv"]):
+        out.update({
+            "conv/w_in": ((m, d, 3 * d), s_d),
+            "conv/conv_w": ((m, cfg.short_conv, d),
+                            1.0 / math.sqrt(cfg.short_conv)),
+            "conv/w_out": ((m, d, d), s_d)})
     return out
 
 
@@ -519,6 +542,52 @@ def _conv_silu_bwd(res, dy):
 
 
 _conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+
+
+@jax.custom_vjp
+def _gated_conv(bcu, w):
+    """``c * conv(b * u)`` of the three streams ``bcu = [b | c | u]``
+    [B, S, 3E] with the taps w [K, E]: a causal depthwise convolution,
+    no activation and no bias.  The backward keeps ``bcu`` as the
+    projection wrote it and makes the two products and the convolution
+    again, so that no float32 copy of [B, S, E] lives from the forward
+    to it."""
+    b, c, u = (t.astype(_F32) for t in jnp.split(bcu, 3, axis=-1))
+    return (c * _causal_conv(b * u, w)).astype(bcu.dtype)
+
+
+def _gated_conv_fwd(bcu, w):
+    return _gated_conv(bcu, w), (bcu, w)
+
+
+def _gated_conv_bwd(res, dy):
+    bcu, w = res
+    k, s = w.shape[0], bcu.shape[1]
+    with scope("conv.gate"):
+        b, c, u = (t.astype(_F32) for t in jnp.split(bcu, 3, axis=-1))
+        wf, dy, z = w.astype(_F32), dy.astype(_F32), b * u
+        dh = dy * c
+        zp = jnp.pad(z, ((0, 0), (k - 1, 0), (0, 0)))
+        dw = jnp.stack([jnp.sum(zp[:, i:i + s] * dh, (0, 1))
+                        for i in range(k)])
+        # tap i of step t reads z[t - (k - 1 - i)]
+        dhp = jnp.pad(dh, ((0, 0), (0, k - 1), (0, 0)))
+        dz = sum(dhp[:, k - 1 - i:k - 1 - i + s] * wf[i] for i in range(k))
+        dbcu = jnp.concatenate(
+            [dz * u, dy * _causal_conv(z, w), dz * b], axis=-1)
+        return dbcu.astype(bcu.dtype), dw.astype(w.dtype)
+
+
+_gated_conv.defvjp(_gated_conv_fwd, _gated_conv_bwd)
+
+
+def conv_mixer(y, p):
+    """Gated short convolution: ``(c * conv(b * u)) W_out`` with
+    ``[b | c | u] = y W_in``."""
+    bcu = jnp.dot(y, p["w_in"])
+    with scope("conv.gate"):
+        g = _gated_conv(bcu, p["conv_w"])
+    return jnp.dot(g, p["w_out"])
 
 
 def mamba_mixer(cfg: HybridConfig, y, p):
@@ -647,11 +716,12 @@ def gdn_mixer(cfg: HybridConfig, y, p):
 
 
 def gated_mixer(cfg: HybridConfig, y, p):
-    """Softmax attention with an output gate; grouped keys and values."""
+    """Softmax attention with grouped keys and values, a norm a head on
+    queries and keys, and an output gate where the card states one."""
     b, s, _ = y.shape
     h, hkv, dh, dr = (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
                       cfg.rope_dim or cfg.head_dim)
-    qg = jnp.dot(y, p["wq"]).reshape(b, s, h, 2 * dh)
+    qg = jnp.dot(y, p["wq"]).reshape(b, s, h, (1 + cfg.attn_gate) * dh)
     k = jnp.dot(y, p["wk"]).reshape(b, s, hkv, dh)
     v = jnp.dot(y, p["wv"]).reshape(b, s, hkv, dh)
     q = L.rmsnorm(qg[..., :dh], _norm_scale(cfg, p["q_norm"]),
@@ -663,8 +733,9 @@ def gated_mixer(cfg: HybridConfig, y, p):
     q = jnp.concatenate([q_rope, q[..., dr:]], axis=-1)
     k = jnp.concatenate([k_rope, k[..., dr:]], axis=-1)
     o = ops.attention(q, k, v, causal=True, impl=cfg.attention_impl)
-    gate = jax.nn.sigmoid(qg[..., dh:].astype(_F32)).astype(y.dtype)
-    return jnp.dot((o * gate).reshape(b, s, h * dh), p["wo"])
+    if cfg.attn_gate:
+        o = o * jax.nn.sigmoid(qg[..., dh:].astype(_F32)).astype(y.dtype)
+    return jnp.dot(o.reshape(b, s, h * dh), p["wo"])
 
 
 def expert_ffn(cfg: HybridConfig, x, norm, fp):
@@ -717,6 +788,9 @@ def _layer(cfg: HybridConfig, li: int, x, bp, mp, fp, memory, kv):
     elif kind == "gdn":
         with scope("linattn"):
             x = x + gdn_mixer(cfg, _norm(cfg, x, bp, "norm1"), mp)
+    elif kind == "conv":
+        with scope("conv"):
+            x = x + conv_mixer(_norm(cfg, x, bp, "norm1"), mp)
     else:
         with scope("attn"):
             y = _norm(cfg, x, bp, "norm1")

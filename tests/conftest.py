@@ -65,11 +65,31 @@ def native_bin():
 _KEYED_BY_KIND = ("test_scope_dump_reads_through_the_harness",
                   "test_scope_dump_fails_the_run_on_a_program_without_scopes",
                   "test_run_with_the_programs_tracer_at_rehearsal_sizes")
-_NOT_IN_THE_TABLE = ("kimivl_a3b_train_s8k", "qwen3next_a3b_train_s16k")
+_NOT_IN_THE_TABLE = ("kimivl_a3b_train_s8k", "qwen3next_a3b_train_s16k",
+                     "lfm2_8b_a1b_train_s8k")
+
+
+# One case of tests/benchmarks/test_bench_qwen3next.py holds its cell to
+# be the manifest's last (``workloads[-1]``, ``per_layer[-12:]``, the last
+# name of ``train_tokens_per_s``'s list); the driver refuses a PR whose
+# new entries stand anywhere but at the end of their lists, and that file
+# is the benchmark's.  With a cell appended the case cannot pass, so it
+# is skipped here, and tests/benchmarks/test_bench_lfm2.py holds the same
+# entries by name
+# (``test_the_linear_attention_cells_entries_are_what_they_were``) until a
+# ``benchmark`` PR keys the case by name and takes this out.
+_PINS_THE_MANIFESTS_TAIL = (
+    "test_bench_qwen3next.py::"
+    "test_manifest_gains_the_cell_and_changes_nothing_else")
 
 
 def pytest_collection_modifyitems(config, items):
     for item in items:
+        if item.nodeid.endswith(_PINS_THE_MANIFESTS_TAIL):
+            item.add_marker(pytest.mark.skip(
+                reason="pins the manifest's tail, where the driver wants "
+                       "a new cell's entries; held by name in "
+                       "test_bench_lfm2.py"))
         if "test_bench_scopes.py" not in item.nodeid:
             continue
         if any(item.name == f"{fn}[{cell}]" for fn in _KEYED_BY_KIND

@@ -492,6 +492,58 @@ def test_linear_moe_train_step_at_the_cell_shapes_compiles_for_the_chip(
     assert set().union(*made.values()) <= {"get-tuple-element", "bitcast"}
 
 
+def test_conv_moe_train_step_at_the_cell_shapes_fits_the_chip(
+        one_chip, no_persistent_cache, monkeypatch):
+    """``lfm2_8b_a1b_train_s8k``'s whole step (the cell's own files and
+    compiler options, as the runner builds it): the rule of the
+    configuration file, twice the arguments plus the temporaries at or
+    under 14.0 GB by the chip compiler's count with all 32 experts of
+    every layer held; four attention kernels for the one attention
+    layer at 64 lanes (the forward twice: each layer is recomputed) and
+    six grouped matmuls an expert layer; the gated convolution under
+    its own scope, forward and backward; the state donated."""
+    from benchmarks import harness, weights_conv_moe as weights
+    from benchmarks.runners import train_conv_moe
+    from dlnetbench_tpu.core import executor
+    from dlnetbench_tpu.models import bench_step
+    from dlnetbench_tpu.ops import pallas_common
+    monkeypatch.setattr(pallas_common, "interpret_mode", lambda: False)
+    cell = harness.load_cell("lfm2_8b_a1b_train_s8k")
+    wl, tr = cell.workload, cell.traffic
+    arch = weights.arch_of(cell.config)
+    assert arch["held"] == (0, 32) and arch["head_dim"] == 64
+    cfg = train_conv_moe.program_config(cell, arch,
+                                        {"attention_impl": "flash"})
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree.map(
+        on_chip, jax.eval_shape(lambda: weights.make_params(arch, 0)))
+    tokens = jax.ShapeDtypeStruct((tr["batch"], tr["seq_len"] + 1), I32,
+                                  sharding=one_chip)
+    step = executor.CompiledStep(
+        bench_step.make_train_k(cfg, 1, wl["lr"]), (params, tokens),
+        donate_argnums=bench_step.DONATE_ARGNUMS,
+        compiler_options=wl["compiler_options"])
+    mem = step.memory_analysis
+    assert 2 * mem["argument"] + mem["temp"] <= 14.0e9
+    assert mem["alias"] > 0.99 * mem["argument"]   # the state is donated
+    text = step.as_text()
+    names = [re_sub_number(k) for k in kernel_instructions(text)]
+    experts = weights.expert_layers(arch)
+    assert sorted(names) == sorted(
+        ["flash_fwd"] * 2 + ["flash_bwd_dq", "flash_bwd_dkv"]
+        + ["grouped_mm"] * 6 * experts) and experts == 4
+    scopes = set(executor.hlo_op_scopes(text).values())
+    assert {"conv", "conv.gate", "attn", "mlp", "moe.experts",
+            "head_loss"} <= scopes
+
+
+def re_sub_number(name: str) -> str:
+    import re
+    return re.sub(r"\.\d+$", "", name)
+
+
 def chunk_arrays(text: str, h: int, nc: int) -> dict:
     """{shape: opcodes of the instructions that make it} for every
     array ``[1, h, nc, ...]`` of five dimensions: a matrix a head and

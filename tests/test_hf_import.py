@@ -77,6 +77,20 @@ QWEN3_NEXT = {"decoder_sparse_step": 1, "full_attention_interval": 4,
               "shared_expert_intermediate_size": 512,
               "tie_word_embeddings": False, "use_sliding_window": False,
               "vocab_size": 151936}
+# the catalog row's config of LFM2-8B-A1B
+LFM2_MOE = {
+    "conv_L_cache": 3, "conv_bias": False, "hidden_size": 2048,
+    "intermediate_size": 7168,
+    "layer_types": (["conv"] * 2 + (["full_attention"] + ["conv"] * 3) * 4
+                    + ["full_attention", "conv", "conv", "full_attention",
+                       "conv", "conv"]),
+    "max_position_embeddings": 128000, "model_type": "lfm2_moe",
+    "moe_intermediate_size": 1792, "norm_eps": 1e-05,
+    "norm_topk_prob": True, "num_attention_heads": 32,
+    "num_dense_layers": 2, "num_experts": 32, "num_experts_per_tok": 4,
+    "num_hidden_layers": 24, "num_key_value_heads": 8,
+    "rope_theta": 1000000, "routed_scaling_factor": 1,
+    "use_expert_bias": True, "vocab_size": 65536}
 
 
 @pytest.mark.parametrize("name,cfg", [
@@ -89,6 +103,7 @@ QWEN3_NEXT = {"decoder_sparse_step": 1, "full_attention_interval": 4,
     ("qwen3_next_80b_a3b", QWEN3_NEXT),
     ("qwen3_next_80b_a3b", {**QWEN3_NEXT, "layer_types": (
         ["linear_attention"] * 3 + ["full_attention"]) * 12}),
+    ("lfm2_8b_a1b", LFM2_MOE),
 ])
 def test_mapping_reproduces_committed_card(name, cfg):
     got = hf_import.card_from_hf_config(name, cfg)
@@ -197,3 +212,40 @@ def test_linear_moe_card_states_the_layers_the_gate_and_the_lanes():
                        ("rope_scaling", {"type": "yarn"})):
         with pytest.raises(ValueError, match="linear-attention import"):
             hf_import.card_from_hf_config("x", {**QWEN3_NEXT, key: value})
+
+
+def test_conv_moe_card_states_the_mixers_the_gate_and_the_tie():
+    """``layer_types`` to mixer kinds, ``num_dense_layers`` to the
+    leading dense FFNs, the attention's missing output gate, the conv's
+    taps and the tie on the card; the card written out and read back is
+    the card (a field at false where the default is true is kept)."""
+    card = hf_import.card_from_hf_config("lfm2_8b_a1b", LFM2_MOE)
+    attention = [2, 6, 10, 14, 18, 21]
+    assert [i for i, k in enumerate(card.layer_kinds) if k == "gated"] \
+        == attention
+    assert set(card.layer_kinds) == {"conv", "gated"}
+    moe = card.moe_params
+    assert (moe.num_experts, moe.num_experts_per_tok, moe.scoring,
+            moe.routed_scale, moe.shared_experts, moe.expert_ff_dim,
+            moe.first_dense_layers) == (32, 4, "sigmoid", 1.0, 0, 1792, 2)
+    assert (card.head_dim, card.attn_head_dim, card.rope_dim,
+            card.kv_heads, card.short_conv) == (64, 0, 0, 8, 3)
+    assert not card.attn_output_gate and card.tied_embeddings
+    assert card.rms_norm and not card.norm_plus_one
+    assert card.norm_eps == 1e-5 and card.rope_theta == 1e6
+    # the issue's table: 16.78 M a conv mixer, 10.49 M the attention,
+    # 44.04 M the dense FFN, 352.39 M an expert layer; 8.34 B tied
+    assert card.mixer_params("conv") == pytest.approx(16.78e6, rel=1e-3)
+    assert card.mixer_params("gated") == pytest.approx(10.49e6, rel=1e-3)
+    assert card.ffn_params(1) == 3 * 2048 * 7168
+    assert card.ffn_params(2) == 32 * 3 * 2048 * 1792 + 2048 * 32 + 32
+    assert card.num_params() == pytest.approx(8.34e9, rel=1e-3)
+    raw = hf_import.card_to_json(card)
+    assert raw["attn_output_gate"] is False and raw["short_conv"] == 3
+    assert "attn_output_gate" not in hf_import.card_to_json(
+        load_model_card("qwen3_next_80b_a3b"))
+    for key, value in (("conv_bias", True), ("norm_topk_prob", False),
+                       ("use_expert_bias", False),
+                       ("rope_scaling", {"type": "yarn"})):
+        with pytest.raises(ValueError, match="short-convolution import"):
+            hf_import.card_from_hf_config("x", {**LFM2_MOE, key: value})

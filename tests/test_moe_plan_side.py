@@ -244,13 +244,17 @@ def test_pair_side_traces_to_the_parents_program(name):
     ("qwen3next_a3b_train_s16k", (16384, 10, 32, 1536)),
     ("kimivl_a3b_train_s8k", (16384, 6, 16, 4096)),
     ("mixtral8x7b_train", (8192, 2, 8, 2560)),
+    # a whole expert layer: every pair's expert is here, at any bound at
+    # or over the even router's 1024 rows
+    ("lfm2_8b_a1b_train_s8k", (8192, 4, 32, 1024)),
+    ("lfm2_8b_a1b_train_s8k", (8192, 4, 32, 3072)),
 ])
 def test_the_rule_at_the_cells_shapes(name, shape):
     t, k, e, c = shape
     plan = L.MoePlan(jax.ShapeDtypeStruct((t, e), jnp.int32),
                      jax.ShapeDtypeStruct((e, c), jnp.int32),
                      jax.ShapeDtypeStruct((t, k), jnp.int32))
-    want = "pairs" if name.startswith("mixtral") else "slots"
+    want = "pairs" if name.startswith(("mixtral", "lfm2")) else "slots"
     assert L._plan_side(plan, "combine") == want
 
 

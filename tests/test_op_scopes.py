@@ -128,6 +128,12 @@ def test_moe_dispatch_and_combine_gather_rows_and_multiply_nothing(steps):
     ("jit(f)/jvp(moe.combine)/moe.experts/dot_general", "moe.experts"),
     ("jit(f)/transpose(jvp(mlp))/jvp(mlp)/checkpoint/dot_general", "mlp"),
     ("jit(f)/optimizer/sub", "optimizer"),
+    # the gated convolution's backward, which wears the inner scope once
+    # more, and its layer's recomputed projections
+    ("jit(f)/transpose(jvp(jvp()))/checkpoint/conv/conv.gate/conv.gate/mul",
+     "conv.gate"),
+    ("jit(f)/transpose(jvp(jvp()))/checkpoint/rematted_computation/conv/"
+     "dot_general", "conv"),
     ("jit(attn)/add", None),    # a function's name is not a scope
     ("jit(f)/attention/add", None),
     ("", None)])
@@ -334,9 +340,10 @@ _LOOP_ATTRS = re.compile(r", condition=%?[\w.\-]+, body=%?[\w.\-]+")
 @pytest.mark.parametrize("runner,cls,name", [
     ("train_hybrid", "HybridCell", "phi4miniflash_train_s8k"),
     ("train_latent_moe", "LatentMoeCell", "kimivl_a3b_train_s8k"),
-    ("train_linear_moe", "LinearMoeCell", "qwen3next_a3b_train_s16k")])
+    ("train_linear_moe", "LinearMoeCell", "qwen3next_a3b_train_s16k"),
+    ("train_conv_moe", "ConvMoeCell", "lfm2_8b_a1b_train_s8k")])
 def test_the_loop_rule_moves_only_what_had_no_scope(runner, cls, name):
-    """The three ``models/hybrid.py`` cells' steps at their rehearsal
+    """The four ``models/hybrid.py`` cells' steps at their rehearsal
     sizes, the table with the loop rule against the table without it
     (the same text with the loops' ``condition=`` and ``body=`` taken
     off, which is what ``hlo_op_scopes`` read before it knew loops):
@@ -383,5 +390,5 @@ def test_the_loop_rule_moves_only_what_had_no_scope(runner, cls, name):
 def test_scope_refuses_a_name_outside_the_vocabulary():
     with pytest.raises(ValueError, match="spans.SCOPES"):
         spans.scope("attention")
-    assert len(set(spans.SCOPES)) == len(spans.SCOPES) == 15
+    assert len(set(spans.SCOPES)) == len(spans.SCOPES) == 17
     assert spans.OTHER_SCOPE not in spans.SCOPES
