@@ -210,7 +210,7 @@ def stats_globals(stats, *, num_experts: int, top_k: int,
 # -------------------------------------------------- grouped expert FFN
 def expert_ffn(xe, w_gate, w_up, w_down, *, impl: str = "einsum",
                quant: str | None = None, counts=None,
-               mlp_int8: bool = False):
+               mlp_int8: bool = False, backward: str = "einsum"):
     """The expert-FFN dispatch point shared by the single-device MoE
     below and the EP-sharded SPMD path: ``xe`` [E, C, d] dispatch
     buffers -> [E, C, d].
@@ -219,13 +219,15 @@ def expert_ffn(xe, w_gate, w_up, w_down, *, impl: str = "einsum",
       spelling; ``mlp_int8`` keeps the r5 int8_dot_batched recipe).
     * ``impl="grouped"`` — the Pallas grouped-matmul kernels with
       optional fused int8/fp8 quantization (``quant``) and count-aware
-      block skipping (``counts``).
+      block skipping (``counts``); ``backward`` is ``grouped_ffn``'s
+      (``"counted"``: the backward skips the row blocks the forward
+      skipped).
     """
     with scope("moe.experts"):
         if impl == "grouped":
             from dlnetbench_tpu.ops.grouped_matmul import grouped_ffn
             return grouped_ffn(xe, w_gate, w_up, w_down, counts=counts,
-                               fmt=quant).astype(_F32)
+                               fmt=quant, backward=backward).astype(_F32)
         if impl != "einsum":
             raise ValueError(f"moe.expert_ffn: unknown impl {impl!r} "
                              f"(einsum | grouped)")
@@ -276,7 +278,12 @@ def moe_held(x2d, w_router, w_gate, w_up, w_down, top_k: int, *,
     scores all of them and takes its top-k over all of them, the rows
     whose expert lives here go through the grouped kernels, and the
     result is what those experts add.  No token is dropped: ``slots``
-    bounds an expert's rows and a row past it is counted.
+    bounds an expert's rows and a row past it is counted.  Because the
+    buffer is a bound and not a capacity it is loose by design, most
+    of its slots the dispatch's zeros, so the experts take the counted
+    backward (``grouped_ffn(backward="counted")``: kernels over the row
+    blocks the forward multiplied); ``moe_grouped``, whose capacity
+    drops rows to stay nearly full, keeps the einsums over every slot.
 
     Returns ``(y [T, d], routing)``; ``routing`` holds int32 scalars,
     ``routed`` rows routed to held experts, ``max_load`` the largest
@@ -293,7 +300,7 @@ def moe_held(x2d, w_router, w_gate, w_up, w_down, top_k: int, *,
                    "max_load": jnp.max(stats["routed"]),
                    "past_bound": stats["dropped"], "choices": idx}
     y = expert_ffn(xe, w_gate, w_up, w_down, impl="grouped",
-                   counts=stats["kept"])
+                   counts=stats["kept"], backward="counted")
     with scope("moe.combine"):
         return L.moe_combine(y.astype(x2d.dtype), plan, gate), routing
 

@@ -51,11 +51,39 @@ hands its two projections ``g = x @ w_gate`` and ``u = x @ w_up`` to
 the backward as residuals, in the dtype its kernels wrote them (the
 ``layers.swiglu`` discipline), so the backward is the gradient
 of the function that was evaluated and computes neither again: six
-full-grid einsums, not eight.  Rows past an expert's count need no
-mask there: a skipped block's ``g`` and ``u`` are the kernel's zeros,
-which make ``h``, ``dg`` and ``du`` zero whatever ``dy`` holds.  All
-kernels run under ``interpret=True`` off-TPU (pallas_common), so the
-CPU-mesh tier-1 lane unit-tests them.
+products, not eight.  Which backward runs is the caller's word on
+what its buffer is (``backward=``):
+
+* ``"einsum"`` (the default; ``moe.moe_grouped``, the quantized
+  ``fmt`` recipes, ``counts=None`` under the EP-sharded
+  ``a2a_expert_ffn``): six full-grid XLA einsums over all ``E * C``
+  slots (``_grouped_ffn_bwd``).  Right where the buffer is a
+  CAPACITY: rows are dropped to fit it, so it is nearly full (80 % in
+  the Mixtral cell) and XLA fuses the weight gradients with the
+  optimizer's update of a one-layer stack.  Rows past an expert's
+  count need no mask there: a skipped block's ``g`` and ``u`` are the
+  kernel's zeros, which make ``h``, ``dg`` and ``du`` zero whatever
+  ``dy`` holds.
+* ``"counted"`` (``moe.moe_held``): four kernels that multiply exactly
+  the row blocks the forward multiplied (``_grouped_ffn_counted_bwd``,
+  a second ``custom_vjp`` over the same ``_ffn_fwd``).  Right where
+  the buffer is a BOUND: nothing may be dropped, so it is loose by
+  design (21-50 % full in the three ``hybrid.py`` cells) and most of
+  what the einsums multiply is the dispatch's zeros.  The row side
+  (``dh``; ``dx`` as one float32 sum of two products) reuses the
+  forward's tile plan and index maps with the weight contracted on
+  its last dimension as stored (``grouped_mm_bwd_dh``, whose epilogue
+  makes ``h``, ``dg``, ``du`` from the float32 ``dh`` tile, each
+  rounded once; ``grouped_mm_bwd_dx``); the contraction side
+  (``grouped_mm_bwd_dw``: dW_down; dW_gate and dW_up in one call)
+  walks an expert's row blocks as the last, ``arbitrary`` grid axis
+  into a float32 sum of the whole ``[K, N]`` gradient, a block past
+  the count naming the last live block (``last_live``).  None of the
+  three names is ``grouped_mm``: the forward family's roofline
+  metrics find their kernels by that name and count only them.
+
+All kernels run under ``interpret=True`` off-TPU (pallas_common), so
+the CPU-mesh tier-1 lane unit-tests them.
 """
 from __future__ import annotations
 
@@ -66,6 +94,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dlnetbench_tpu.metrics import spans
 from dlnetbench_tpu.ops import pallas_common
 from dlnetbench_tpu.ops.pallas_common import (
     F32,
@@ -95,13 +124,17 @@ _BLOCK_NAMES = ("block_c", "block_n", "block_k")
 
 
 def tile_bytes(bc: int, bn: int, bk: int, itemsize: int,
-                quantized: bool = False) -> int:
+                quantized: bool = False, *, pairs: int = 1,
+                row_tiles: int = 1) -> int:
     """VMEM one grid step asks for: the activation tile, the weight
     block and the output tile, each twice (the pipeline fetches the
     next while this one multiplies), and the float32 product; the
-    quantizing prologue widens the activation tile to float32 first."""
-    return (2 * (bc * bk + bk * bn + bc * bn) * itemsize + bc * bn * 4
-            + (bc * bk * 4 if quantized else 0))
+    quantizing prologue widens the activation tile to float32 first.
+    The backward's row-side kernels sum ``pairs`` products a step and
+    move ``row_tiles`` tiles of the output's shape (the SwiGLU
+    epilogue reads two and writes three)."""
+    return (2 * (pairs * (bc * bk + bk * bn) + row_tiles * bc * bn)
+            * itemsize + bc * bn * 4 + (bc * bk * 4 if quantized else 0))
 
 
 def blocks_of(dim: int, unit: int) -> list:
@@ -128,7 +161,9 @@ def n_outer(c: int, kdim: int, n: int, bc: int, bn: int, bk: int) -> bool:
 
 
 def tile_plan(c: int, kdim: int, n: int, itemsize: int, *,
-              quantized: bool = False, budget: int = VMEM_BUDGET) -> dict:
+              quantized: bool = False, budget: int = VMEM_BUDGET,
+              block_c: int | None = None, pairs: int = 1,
+              row_tiles: int = 1) -> dict:
     """The grid blocks of ``[C, K] @ [K, N]`` an expert, from the
     matmul's own shape: a dimension is taken whole or by a divisor that
     is a multiple of the 128-lane tile (rows: of the dtype's sublane
@@ -137,14 +172,17 @@ def tile_plan(c: int, kdim: int, n: int, itemsize: int, *,
     (no accumulator pass, and one operand stays put across the inner
     grid axis), then the widest output tile: the whole of N keeps an
     expert's weight resident across its row blocks.  ``n_outer`` then
-    orders the grid for these blocks."""
+    orders the grid for these blocks.  ``block_c`` fixes the row block
+    (the backward takes the forward's); ``pairs`` and ``row_tiles`` are
+    ``tile_bytes``'s."""
     rows = blocks_of(c, 8 * max(1, 4 // itemsize))
-    bc = next((b for b in rows if b <= ROW_BLOCK), rows[-1])
+    bc = block_c or next((b for b in rows if b <= ROW_BLOCK), rows[-1])
     cands = [(bn, bk) for bk in blocks_of(kdim, LANES)
              for bn in blocks_of(n, LANES)]
 
     def need(p):
-        return tile_bytes(bc, *p, itemsize, quantized)
+        return tile_bytes(bc, *p, itemsize, quantized, pairs=pairs,
+                          row_tiles=row_tiles)
     fits = [p for p in cands if need(p) <= budget] or [min(cands, key=need)]
     bn, bk = max(fits, key=lambda p: (p[0] >= min(n, _MIN_BLOCK_N),
                                       p[1], p[0]))
@@ -172,6 +210,25 @@ def _tuned_blocks(e: int, c: int, kdim: int, n: int, fmt: str | None,
                   quantized=fmt is not None), validate=check)
 
 
+def _fit_blocks(e: int, c: int, kdim: int, n: int, fmt: str | None,
+                xdtype, blocks) -> tuple:
+    """``(bc, bn, bk)`` of one grouped matmul from its (block_c,
+    block_n, block_k) arguments: explicit ones win (one left out is
+    the plan's); with none given the tuning DB is consulted; each is
+    then shrunk to a divisor of its dimension."""
+    given = {name: blk for name, blk in zip(_BLOCK_NAMES, blocks)
+             if blk is not None}
+    for name, blk in given.items():
+        if not isinstance(blk, int) or blk <= 0:
+            raise ValueError(f"grouped_matmul: {name}={blk!r} must "
+                             f"be a positive int")
+    planned = {**(tile_plan(c, kdim, n, jnp.dtype(xdtype).itemsize,
+                            quantized=fmt is not None) if given
+                  else _tuned_blocks(e, c, kdim, n, fmt, xdtype)), **given}
+    return tuple(fit_block(dim, planned[name])
+                 for dim, name in zip((c, n, kdim), _BLOCK_NAMES))
+
+
 def hold_table(counts, block_c: int):
     """``(te, tc)`` [E] int32: the expert and the row block that the
     steps past expert ``e``'s count name.  They look ahead: row block 0
@@ -191,20 +248,14 @@ def hold_table(counts, block_c: int):
             jnp.where(ahead < e, 0, jnp.maximum(live_blocks[back] - 1, 0)))
 
 
-def index_maps(bc: int, nn: int, nk: int, n_out: bool):
-    """The three block index maps ``(x, w, out)`` over the grid
-    ``(e, ci, ni, ki)`` (``(e, ni, ci, ki)`` when ``n_out``), each
-    taking the grid indices and then the prefetched ``counts, te, tc``
-    (``hold_table``).  A step past its expert's count names the input
-    blocks of the next live step (of the last one, when none follows):
-    the pipeline fetches a block only when its index changes, so such a
-    step moves no input byte that a live step does not need, and the
-    one fetch a run of them starts is the next live step's own, begun
-    while the last live step still multiplies.  Its output block is
-    its own (the kernel writes its zeros)."""
+def named_step(bc: int, nn: int, nk: int, n_out: bool):
+    """``named(ei, a, b, ki, counts, te, tc)`` -> the (expert, row
+    block, column block, contraction block) whose input blocks a step
+    of the grid ``(e, ci, ni, ki)`` (``(e, ni, ci, ki)`` when
+    ``n_out``) names: its own when its row block is live, else the
+    next live step's (of the last one, when none follows;
+    ``hold_table``)."""
     def named(ei, a, b, ki, counts, te, tc):
-        """(expert, row block, column block, contraction block) whose
-        input blocks this step names."""
         ci, ni = (b, a) if n_out else (a, b)
         live = ci * bc < counts[ei]
         ahead = te[ei] > ei
@@ -216,6 +267,21 @@ def index_maps(bc: int, nn: int, nk: int, n_out: bool):
                 jnp.where(live, ni, jnp.where(
                     again, ni + 1, jnp.where(ahead, 0, nn - 1))),
                 jnp.where(live, ki, jnp.where(again | ahead, 0, nk - 1)))
+    return named
+
+
+def index_maps(bc: int, nn: int, nk: int, n_out: bool):
+    """The three block index maps ``(x, w, out)`` over the grid
+    ``(e, ci, ni, ki)`` (``(e, ni, ci, ki)`` when ``n_out``), each
+    taking the grid indices and then the prefetched ``counts, te, tc``
+    (``hold_table``).  A step past its expert's count names the input
+    blocks of the next live step (of the last one, when none follows):
+    the pipeline fetches a block only when its index changes, so such a
+    step moves no input byte that a live step does not need, and the
+    one fetch a run of them starts is the next live step's own, begun
+    while the last live step still multiplies.  Its output block is
+    its own (the kernel writes its zeros)."""
+    named = named_step(bc, nn, nk, n_out)
 
     def x_index(ei, a, b, ki, counts, te, tc, *_):
         e_, c_, _n, k_ = named(ei, a, b, ki, counts, te, tc)
@@ -322,19 +388,8 @@ def grouped_matmul(x, w, *, counts=None, sx=None, sw=None,
         if sx is None or sw is None:
             raise ValueError("grouped_matmul: fmt set but sx/sw "
                              "per-expert scales missing")
-    given = {name: blk for name, blk in
-             zip(_BLOCK_NAMES, (block_c, block_n, block_k))
-             if blk is not None}
-    for name, blk in given.items():
-        if not isinstance(blk, int) or blk <= 0:
-            raise ValueError(f"grouped_matmul: {name}={blk!r} must "
-                             f"be a positive int")
-    blocks = {**(tile_plan(c, kdim, n, x.dtype.itemsize,
-                           quantized=fmt is not None) if given
-                 else _tuned_blocks(e, c, kdim, n, fmt, x.dtype)), **given}
-    bc = fit_block(c, blocks["block_c"])
-    bn = fit_block(n, blocks["block_n"])
-    bk = fit_block(kdim, blocks["block_k"])
+    bc, bn, bk = _fit_blocks(e, c, kdim, n, fmt, x.dtype,
+                             (block_c, block_n, block_k))
     nc, nn, nk = c // bc, n // bn, kdim // bk
     n_out = n_outer(c, kdim, n, bc, bn, bk)
 
@@ -462,21 +517,353 @@ def _grouped_ffn_bwd(fmt, blocks, res, dy):
 _grouped_ffn.defvjp(_grouped_ffn_fwd, _grouped_ffn_bwd)
 
 
+# ------------------------------------------- the counted backward
+def _as_one_dtype(a, b):
+    """Two tiles of one product in one dtype, as stored when they
+    agree."""
+    if a.dtype == b.dtype:
+        return a, b
+    both = jnp.promote_types(a.dtype, b.dtype)
+    return a.astype(both), b.astype(both)
+
+
+def _swiglu_bwd_tiles(dh, g, u, dtype):
+    """``(h, dg, du)`` of ``h = silu(g) * u`` from the float32 tile
+    ``dh`` and the forward's ``g``, ``u``: float32 arithmetic, each
+    rounded once to ``dtype``."""
+    g, u = g.astype(F32), u.astype(F32)
+    sig = jax.nn.sigmoid(g)
+    silu = g * sig
+    return ((silu * u).astype(dtype),
+            (dh * u * (sig + silu * (1.0 - sig))).astype(dtype),
+            (dh * silu).astype(dtype))
+
+
+def _bwd_rows_kernel(counts_ref, _te, _tc, *refs, pairs: int,
+                     swiglu: bool, block_c: int, n_out: bool, nk: int):
+    """One grid step of the backward's row side: the sum over ``pairs``
+    of ``x_p [bc, bk] @ w_p [bn, bk]^T`` (the weight as the forward
+    stores it, contracted on its last dimension), float32, rounded
+    once.  With ``swiglu`` the float32 sum is ``dh`` and the step
+    writes ``h``, ``dg``, ``du`` from it and the forward's ``g``,
+    ``u`` tiles instead.  A row block past its expert's count
+    multiplies nothing and writes zeros, as in the forward."""
+    xs, ws = refs[:pairs], refs[pairs:2 * pairs]
+    rest = refs[2 * pairs:]
+    gu, rest = (rest[:2], rest[2:]) if swiglu else ((), rest)
+    outs, acc = (rest[:3], rest[3:]) if swiglu else (rest[:1], rest[1:])
+    e = pl.program_id(0)
+    ci = pl.program_id(2 if n_out else 1)
+    live = ci * block_c < counts_ref[e]
+
+    def product():
+        total = None
+        for x_ref, w_ref in zip(xs, ws):
+            xblk, wblk = _as_one_dtype(x_ref[0], w_ref[0])
+            part = jax.lax.dot_general(
+                xblk, wblk, (((1,), (1,)), ((), ())),
+                preferred_element_type=F32)
+            total = part if total is None else total + part
+        return total
+
+    def emit(val):
+        if swiglu:
+            tiles = _swiglu_bwd_tiles(val, gu[0][0], gu[1][0],
+                                      outs[0].dtype)
+            for out_ref, tile in zip(outs, tiles):
+                out_ref[0] = tile
+        else:
+            outs[0][0] = val.astype(outs[0].dtype)
+
+    def zeros():
+        for out_ref in outs:
+            out_ref[0] = jnp.zeros_like(out_ref[0])
+
+    if nk == 1:
+        pl.when(live)(lambda: emit(product()))
+        pl.when(jnp.logical_not(live))(zeros)
+        return
+
+    acc_ref, = acc
+    ki = pl.program_id(3)
+    last = ki == nk - 1
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(live)
+    def _dot():
+        acc_ref[...] += product()
+
+    pl.when(last & live)(lambda: emit(acc_ref[...]))
+    pl.when(last & jnp.logical_not(live))(zeros)
+
+
+def _bwd_rows(xs, ws, counts, blocks, *, swiglu=None, name: str):
+    """The backward's row side as one kernel: ``sum_p xs[p] [E, C, K] @
+    ws[p] [E, N, K]^T -> [E, C, N]`` in ``xs[0]``'s dtype, row blocks
+    past ``counts`` skipped on the forward's tile plan and index maps
+    (``tile_plan``, ``index_maps``).  ``swiglu = (g, u)`` [E, C, N]:
+    the float32 sum is ``dh`` and the result is ``(h, dg, du)`` in
+    ``g``'s dtype.  ``blocks = (bc, block_n, block_k)``: the forward's
+    row block and the caller's explicit blocks, if any."""
+    e, c, kdim = xs[0].shape
+    n = ws[0].shape[1]
+    pairs = len(xs)
+    bc, given_n, given_k = blocks
+    plan = tile_plan(c, kdim, n, xs[0].dtype.itemsize, block_c=bc,
+                     pairs=pairs, row_tiles=5 if swiglu else 1)
+    bn = fit_block(n, given_n or plan["block_n"])
+    bk = fit_block(kdim, given_k or plan["block_k"])
+    nc, nn, nk = c // bc, n // bn, kdim // bk
+    n_out = n_outer(c, kdim, n, bc, bn, bk)
+    te, tc = hold_table(counts, bc)
+    named = named_step(bc, nn, nk, n_out)
+
+    def x_index(*idx):
+        e_, c_, _n, k_ = named(*idx)
+        return e_, c_, k_
+
+    def w_index(*idx):
+        e_, _c, n_, k_ = named(*idx)
+        return e_, n_, k_
+
+    def tile_index(*idx):
+        # g and u of a step past the count: the next live step's, as
+        # its x and w (the step reads neither)
+        e_, c_, n_, _k = named(*idx)
+        return e_, c_, n_
+
+    def out_index(ei, a, b, ki, *_):
+        return (ei, b, a) if n_out else (ei, a, b)
+
+    out_spec = pl.BlockSpec((1, bc, bn), out_index)
+    tile = jax.ShapeDtypeStruct((e, c, n),
+                                swiglu[0].dtype if swiglu else xs[0].dtype)
+    return pl.pallas_call(
+        functools.partial(_bwd_rows_kernel, pairs=pairs,
+                          swiglu=swiglu is not None, block_c=bc,
+                          n_out=n_out, nk=nk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(e, nn, nc, nk) if n_out else (e, nc, nn, nk),
+            in_specs=([pl.BlockSpec((1, bc, bk), x_index)] * pairs
+                      + [pl.BlockSpec((1, bn, bk), w_index)] * pairs
+                      + [pl.BlockSpec((1, bc, bn), tile_index)]
+                      * (2 if swiglu else 0)),
+            out_specs=[out_spec] * 3 if swiglu else out_spec,
+            scratch_shapes=([pltpu.VMEM((bc, bn), F32)] if nk > 1
+                            else []),
+        ),
+        out_shape=[tile] * 3 if swiglu else tile,
+        compiler_params=compiler_params(
+            ("parallel", "parallel", "parallel", "arbitrary")),
+        name=name,
+        interpret=pallas_common.interpret_mode(),
+    )(counts, te, tc, *xs, *ws, *(swiglu or ()))
+
+
+def last_live(counts, block_c: int):
+    """``(be, bl)`` [E] int32: the expert and the row block that the
+    contraction-side steps past expert ``e``'s count name, the last
+    live row block at or before ``e`` (``hold_table``'s idea, looking
+    back only: the block is in VMEM already, so such a step fetches
+    nothing; block 0 of expert 0 when no expert up to ``e`` has a
+    row)."""
+    live_blocks = (counts + block_c - 1) // block_c
+    idx = jnp.arange(counts.shape[0], dtype=jnp.int32)
+    back = jax.lax.cummax(jnp.where(live_blocks > 0, idx, 0))
+    return back, jnp.maximum(live_blocks[back] - 1, 0)
+
+
+def dw_tile_plan(bc: int, kdim: int, n: int, itemsize: int, *,
+                 outs: int = 1, budget: int = VMEM_BUDGET) -> dict:
+    """The output tile ``(block_k, block_n)`` of ``[C, K]^T @ [C, N]``
+    an expert, summed over row blocks of ``bc``: a dimension whole or
+    by a lane-multiple divisor, the largest tile that fits ``budget``
+    (the whole of ``[K, N]`` reads every row block once; a tile of it
+    reads one operand again for each block of the other's dimension).
+    A step holds the ``[bc, bk]`` tile and ``outs`` tiles ``[bc, bn]``
+    twice, and for each output its float32 sum and the tile twice."""
+    def need(p):
+        bk, bn = p
+        return (2 * bc * (bk + outs * bn) * itemsize
+                + outs * bk * bn * (4 + 2 * itemsize))
+    cands = [(bk, bn) for bk in blocks_of(kdim, LANES)
+             for bn in blocks_of(n, LANES)]
+    fits = [p for p in cands if need(p) <= budget] or [min(cands, key=need)]
+    bk, bn = max(fits, key=lambda p: (p[0] * p[1], p[1]))
+    return {"block_k": bk, "block_n": bn}
+
+
+def _bwd_dw_kernel(counts_ref, _be, _bl, a_ref, *refs, outs: int,
+                   block_c: int):
+    """One grid step of the backward's contraction side: row block
+    ``ci`` of ``a [bc, bk]^T @ b_j [bc, bn]`` added to output ``j``'s
+    float32 sum, which the last row block writes in the weight's
+    dtype.  A block past the expert's count adds nothing (and fetched
+    nothing: ``last_live``), so an expert with no row writes zeros."""
+    b_refs, out_refs, accs = (refs[:outs], refs[outs:2 * outs],
+                              refs[2 * outs:])
+    ci = pl.program_id(3)
+    live = ci * block_c < counts_ref[pl.program_id(0)]
+
+    @pl.when(ci == 0)
+    def _init():
+        for acc_ref in accs:
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(live)
+    def _dot():
+        for b_ref, acc_ref in zip(b_refs, accs):
+            ablk, bblk = _as_one_dtype(a_ref[0], b_ref[0])
+            acc_ref[...] += jax.lax.dot_general(
+                ablk, bblk, (((0,), (0,)), ((), ())),
+                preferred_element_type=F32)
+
+    @pl.when(ci == pl.num_programs(3) - 1)
+    def _emit():
+        for out_ref, acc_ref in zip(out_refs, accs):
+            out_ref[0] = acc_ref[...].astype(out_ref.dtype)
+
+
+def _bwd_dw(a, bs, counts, blocks, out_dtypes, *, name: str):
+    """The backward's contraction side as one kernel: ``a [E, C, K]^T
+    @ bs[j] [E, C, N] -> [E, K, N]`` for each ``j``, the ragged
+    dimension contracted: the row-block axis is the grid's last
+    (``arbitrary``), a block past ``counts`` issues no product and
+    names the last live block's inputs.  ``blocks = (bc, block_k,
+    block_n)`` as in ``_bwd_rows``."""
+    e, c, kdim = a.shape
+    n = bs[0].shape[2]
+    outs = len(bs)
+    bc, given_k, given_n = blocks
+    plan = dw_tile_plan(bc, kdim, n, a.dtype.itemsize, outs=outs)
+    bk = fit_block(kdim, given_k or plan["block_k"])
+    bn = fit_block(n, given_n or plan["block_n"])
+    be, bl = last_live(counts, bc)
+
+    def rows(ei, ci, counts, be, bl):
+        live = ci * bc < counts[ei]
+        return jnp.where(live, ei, be[ei]), jnp.where(live, ci, bl[ei])
+
+    def a_index(ei, ki, ni, ci, *pre):
+        e_, c_ = rows(ei, ci, *pre)
+        return e_, c_, ki
+
+    def b_index(ei, ki, ni, ci, *pre):
+        e_, c_ = rows(ei, ci, *pre)
+        return e_, c_, ni
+
+    return pl.pallas_call(
+        functools.partial(_bwd_dw_kernel, outs=outs, block_c=bc),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(e, kdim // bk, n // bn, c // bc),
+            in_specs=([pl.BlockSpec((1, bc, bk), a_index)]
+                      + [pl.BlockSpec((1, bc, bn), b_index)] * outs),
+            out_specs=[pl.BlockSpec(
+                (1, bk, bn), lambda ei, ki, ni, ci, *_: (ei, ki, ni))]
+            * outs,
+            scratch_shapes=[pltpu.VMEM((bk, bn), F32)] * outs,
+        ),
+        out_shape=[jax.ShapeDtypeStruct((e, kdim, n), dt)
+                   for dt in out_dtypes],
+        compiler_params=compiler_params(
+            ("parallel", "parallel", "parallel", "arbitrary")),
+        name=name,
+        interpret=pallas_common.interpret_mode(),
+    )(counts, be, bl, a, *bs)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _grouped_ffn_counted(x, w_gate, w_up, w_down, counts, blocks):
+    return _ffn_fwd(x, w_gate, w_up, w_down, counts, None, blocks)[0]
+
+
+def _grouped_ffn_counted_fwd(x, w_gate, w_up, w_down, counts, blocks):
+    return _grouped_ffn_fwd(x, w_gate, w_up, w_down, counts, None, blocks)
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _counted_bwd(blocks, x, g, u, w_gate, w_up, w_down, counts, dy):
+    """The backward that multiplies the row blocks the forward
+    multiplied: the six products of ``_grouped_ffn_bwd`` as four
+    kernels keyed by the forward's counts and its row block.  ``dh =
+    dy @ w_down^T`` stays float32 inside its kernel, whose epilogue
+    writes ``h``, ``dg``, ``du`` (each rounded once to the stored
+    dtype, which is what the MXU's default precision does to the
+    einsums' float32 operands); ``dx`` is the float32 sum of its two
+    products, rounded once; the weight gradients sum live row blocks
+    only.  A block is live here if and only if it was live in the
+    forward: a live block's rows past the count are multiplied as the
+    einsums multiply them, and a skipped block's ``g``, ``u`` are the
+    forward's zeros, which add nothing to any sum.  A ``jit`` of its
+    own, so that the expert layers of one shape in a step trace and
+    lower these kernels once and call them (XLA inlines the calls)."""
+    e, c, d = x.shape
+    _, block_n, block_k = blocks
+    # the row block the forward's kernels skipped by
+    bc = _fit_blocks(e, c, d, w_gate.shape[2], None, x.dtype, blocks)[0]
+    cnt = counts.astype(jnp.int32)
+    h, dg, du = _bwd_rows((dy,), (w_down,), cnt, (bc, block_n, block_k),
+                          swiglu=(g, u), name="grouped_mm_bwd_dh")
+    dx = _bwd_rows((dg, du), (w_gate, w_up), cnt, (bc, block_n, block_k),
+                   name="grouped_mm_bwd_dx")
+    dwd, = _bwd_dw(h, (dy,), cnt, (bc, block_k, block_n),
+                   (w_down.dtype,), name="grouped_mm_bwd_dw")
+    dwg, dwu = _bwd_dw(x, (dg, du), cnt, (bc, block_k, block_n),
+                       (w_gate.dtype, w_up.dtype),
+                       name="grouped_mm_bwd_dw")
+    return dx.astype(x.dtype), dwg, dwu, dwd, jnp.zeros_like(counts)
+
+
+def _grouped_ffn_counted_bwd(blocks, res, dy):
+    return _counted_bwd(blocks, *res, dy)
+
+
+_grouped_ffn_counted.defvjp(_grouped_ffn_counted_fwd,
+                            _grouped_ffn_counted_bwd)
+
+
 def grouped_ffn(x, w_gate, w_up, w_down, *, counts=None,
                 fmt: str | None = None, block_c: int | None = None,
-                block_n: int | None = None, block_k: int | None = None):
+                block_n: int | None = None, block_k: int | None = None,
+                backward: str = "einsum"):
     """The grouped expert SwiGLU: ``x`` [E, C, d] dispatch buffers,
     weights [E, d, h] / [E, h, d] stacked per expert -> [E, C, d].
 
     ``counts`` enables the gather/scatter block skipping, ``fmt``
     selects the fused-quantization recipes (per-expert dynamic scales,
     straight-through backward).  Block shapes are a tuning-DB site
-    (op ``grouped_ffn``); ``None`` consults, explicit ints win."""
+    (op ``grouped_ffn``); ``None`` consults, explicit ints win.
+
+    ``backward``: ``"einsum"``, six XLA einsums over every slot, or
+    ``"counted"``, kernels over the row blocks the forward multiplied
+    (the caller's word on what its buffer is: a bound that is loose by
+    design, so that most of its slots are empty; it needs ``counts``
+    and has no quantized form).  Each traced call leaves a mark
+    ``moe.experts_bwd`` (``spans.mark``: ``path``, ``slots`` = E * C,
+    ``row_block``) on the build's ``compile`` span under a tracer."""
     if fmt is not None and fmt not in _FORMATS:
         raise ValueError(f"grouped_ffn: unknown fmt {fmt!r}; one of "
                          f"{tuple(_FORMATS)} or None")
-    e, c, _ = x.shape
+    if backward not in ("einsum", "counted"):
+        raise ValueError(f"grouped_ffn: unknown backward {backward!r} "
+                         f"(einsum | counted)")
+    if backward == "counted" and (counts is None or fmt is not None):
+        raise ValueError("grouped_ffn: the counted backward needs counts "
+                         "and has no quantized form")
+    e, c, d = x.shape
+    blocks = (block_c, block_n, block_k)
+    if spans.is_enabled():
+        spans.mark("moe.experts_bwd", path=backward, slots=e * c,
+                   row_block=_fit_blocks(e, c, d, w_gate.shape[2], fmt,
+                                         x.dtype, blocks)[0])
     counts_f = (jnp.full((e,), float(c), F32) if counts is None
                 else counts.astype(F32))
-    return _grouped_ffn(x, w_gate, w_up, w_down, counts_f, fmt,
-                        (block_c, block_n, block_k))
+    if backward == "counted":
+        return _grouped_ffn_counted(x, w_gate, w_up, w_down, counts_f,
+                                    blocks)
+    return _grouped_ffn(x, w_gate, w_up, w_down, counts_f, fmt, blocks)

@@ -267,6 +267,45 @@ def test_grouped_matmul_at_the_cells_shapes(for_chip, name):
     assert re.search(rf"{re.escape(first)} = s32\[{e}\]", text)
 
 
+# the kernels of ``moe_held``'s counted backward, by name: one dh (with
+# the SwiGLU epilogue) and one dx a layer, and two contraction-side
+# calls (dW_down; dW_gate with dW_up)
+EXPERTS_BWD = ("grouped_mm_bwd_dh", "grouped_mm_bwd_dx", "grouped_mm_bwd_dw")
+CELL_HELD_FFN = {          # (E, C, d, F), bf16
+    "lfm2": (32, 2048, 2048, 1792),
+    "kimi": (16, 4096, 2048, 1408),
+    "qwen": (32, 1536, 2048, 512),
+}
+
+
+@pytest.mark.parametrize("cell", CELL_HELD_FFN)
+def test_counted_expert_backward_at_the_cells_shapes(for_chip, cell):
+    """The four kernels of ``grouped_ffn(backward="counted")`` under
+    the tiles their own shapes plan (an expert's whole weight a step on
+    the row side, its whole gradient in VMEM on the contraction side,
+    both gate and up at once): the chip's compiler takes them inside
+    the family's VMEM limit beside the forward's two, and nothing of
+    ``[E, C, .]`` is multiplied outside a kernel."""
+    import re
+
+    from dlnetbench_tpu.metrics import spans
+    gm = ops_module("grouped_matmul")
+    e, c, d, f = CELL_HELD_FFN[cell]
+
+    def loss(x, wg, wu, wd, cnt):
+        with spans.scope("moe.experts"):
+            y = gm.grouped_ffn(x, wg, wu, wd, counts=cnt,
+                               backward="counted")
+        return jnp.sum(y.astype(F32))
+    text = for_chip(jax.grad(loss, argnums=(0, 1, 2, 3)),
+                    ((e, c, d), BF16), ((e, d, f), BF16), ((e, d, f), BF16),
+                    ((e, f, d), BF16), ((e,), I32))
+    names = [re.sub(r"\.\d+$", "", k) for k in kernel_instructions(text)]
+    assert sorted(names) == sorted(
+        ["grouped_mm"] * 2 + [*EXPERTS_BWD, "grouped_mm_bwd_dw"])
+    assert not re.search(rf" (dot|convolution)\(", text)
+
+
 def scopes_by_opcode(text: str, opcodes: str, keep) -> dict:
     """{opcode: the scopes its instructions lie under}, over the
     instructions of ``text`` whose opcode is one of ``opcodes`` (a
@@ -398,9 +437,9 @@ def test_latent_moe_train_step_at_the_cell_shapes_fits_the_chip(one_chip,
     compiler options, as the runner builds it): the depth rule of the
     configuration file, twice the arguments plus the temporaries at or
     under 13.0 GB by the chip compiler's count; four attention kernels
-    a layer (the forward twice: each layer is recomputed) and six
-    grouped matmuls an expert layer; the step's outputs carry the
-    routing."""
+    a layer (the forward twice: each layer is recomputed), six
+    grouped matmuls an expert layer and the four kernels of its
+    counted backward; the step's outputs carry the routing."""
     from benchmarks import harness, weights_latent_moe as weights
     from benchmarks.runners import train_latent_moe
     from dlnetbench_tpu.core import executor
@@ -429,9 +468,11 @@ def test_latent_moe_train_step_at_the_cell_shapes_fits_the_chip(one_chip,
     layers = arch["num_layers"]
     experts = weights.expert_layers(arch)
     text = step.as_text()
-    assert kernels_in(text) == 4 * layers + 6 * experts
-    assert sum(k.startswith("grouped_mm.")
-               for k in kernel_instructions(text)) == 6 * experts == 30
+    assert kernels_in(text) == 4 * layers + 10 * experts
+    names = [re_sub_number(k) for k in kernel_instructions(text)]
+    assert names.count("grouped_mm") == 6 * experts == 30
+    assert [names.count(k) for k in EXPERTS_BWD] == [experts] * 2 \
+        + [2 * experts]
 
 
 def test_linear_moe_train_step_at_the_cell_shapes_compiles_for_the_chip(
@@ -441,7 +482,8 @@ def test_linear_moe_train_step_at_the_cell_shapes_compiles_for_the_chip(
     sweeps that "auto" takes on the chip), cut to one layer of each kind
     so that it compiles in a minute: the kernels by name (the rule's
     forward kernel twice a linear layer and its backward once, four
-    attention kernels a full layer, six grouped matmuls a layer), no
+    attention kernels a full layer, six grouped matmuls a layer and the
+    four kernels of its counted backward), no
     loop but the head's (none around the rule: all heads go through one
     call), no chunk matrix but what the rule's kernels write, the state
     donated, and the temporaries under what let 32 held experts keep
@@ -483,7 +525,8 @@ def test_linear_moe_train_step_at_the_cell_shapes_compiles_for_the_chip(
     names = kernel_instructions(text)
     assert sorted(re.sub(r"\.\d+$", "", k) for k in names) == sorted(
         ["gdr_fwd"] * 2 + ["gdr_bwd"] + ["flash_fwd"] * 2
-        + ["flash_bwd_dq", "flash_bwd_dkv"] + ["grouped_mm"] * 12)
+        + ["flash_bwd_dq", "flash_bwd_dkv"] + ["grouped_mm"] * 12
+        + [*EXPERTS_BWD, "grouped_mm_bwd_dw"] * 2)
     table = executor.hlo_op_scopes(text)
     loops = [m.group(1) for line in text.splitlines() if " while(" in line
              and (m := executor._HLO_INSTRUCTION.match(line))]
@@ -499,8 +542,10 @@ def test_conv_moe_train_step_at_the_cell_shapes_fits_the_chip(
     configuration file, twice the arguments plus the temporaries at or
     under 14.0 GB by the chip compiler's count with all 32 experts of
     every layer held; four attention kernels for the one attention
-    layer at 64 lanes (the forward twice: each layer is recomputed) and
-    six grouped matmuls an expert layer; the gated convolution under
+    layer at 64 lanes (the forward twice: each layer is recomputed),
+    six grouped matmuls an expert layer and the four kernels of its
+    counted backward (no ``[E, C, F]`` float32 array is left in the
+    step: ``dh`` stays inside its kernel); the gated convolution under
     its own scope, forward and backward; the state donated."""
     from benchmarks import harness, weights_conv_moe as weights
     from benchmarks.runners import train_conv_moe
@@ -533,7 +578,11 @@ def test_conv_moe_train_step_at_the_cell_shapes_fits_the_chip(
     experts = weights.expert_layers(arch)
     assert sorted(names) == sorted(
         ["flash_fwd"] * 2 + ["flash_bwd_dq", "flash_bwd_dkv"]
-        + ["grouped_mm"] * 6 * experts) and experts == 4
+        + ["grouped_mm"] * 6 * experts
+        + [*EXPERTS_BWD, "grouped_mm_bwd_dw"] * experts) and experts == 4
+    import re
+    assert not re.findall(r"^\s*(?:ROOT )?\S+ = f32\[(?:1,)?32,2048,1792\]",
+                          text[text.index("ENTRY"):], re.M)
     scopes = set(executor.hlo_op_scopes(text).values())
     assert {"conv", "conv.gate", "attn", "mlp", "moe.experts",
             "head_loss"} <= scopes

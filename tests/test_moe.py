@@ -425,6 +425,216 @@ def test_grouped_ffn_backward_reads_forward_g_u():
     assert sum(q.outvars[0].aval.shape == (e, c, h) for q in dots) == 1
 
 
+# ---- the counted backward (ISSUE 41) -----------------------------------
+# an expert with no row first, between and last; one row; a count inside
+# a block; at a block's edge; a full buffer; every expert empty
+_BWD_COUNTS = {"blocks_skipped": (16, 5, 0, 9), "at_edges": (8, 0, 0, 4),
+               "one_row": (0, 1, 13, 0), "full_buffer": (16, 16, 16, 16),
+               "all_empty": (0, 0, 0, 0)}
+_BWD_KERNELS = ["grouped_mm_bwd_dh", "grouped_mm_bwd_dx",
+                "grouped_mm_bwd_dw", "grouped_mm_bwd_dw"]
+
+
+@pytest.mark.parametrize("blocks", [
+    _GM_BLOCKS, dict(block_c=4, block_n=8, block_k=8), dict(block_c=4)],
+    ids=["pow2", "nk_gt_1", "whole_k"])
+@pytest.mark.parametrize("h", [48, 88])
+@pytest.mark.parametrize("counts", _BWD_COUNTS)
+def test_counted_backward_matches_reference_and_einsums(counts, h, blocks):
+    """``backward="counted"``: all four gradients against the einsum
+    reference and against the einsum backward, with x and the
+    cotangent nonzero past every count.  A block is live in the
+    backward if and only if it was live in the forward: rows of a live
+    block past the count get their gradient, rows of a skipped block
+    exact zeros, and the weight gradient of an expert with no row is
+    exactly zero."""
+    x, wg, wu, wd = _gm_case(h=h)
+    r = jax.random.normal(jax.random.key(7), x.shape, _F32)
+    cnt = jnp.array(_BWD_COUNTS[counts], jnp.int32)
+    live = ((jnp.arange(16)[None, :] // 4 * 4) < cnt[:, None]).astype(_F32)
+
+    def loss(backward):
+        return lambda x_, a, b, c: jnp.sum(gm.grouped_ffn(
+            x_, a, b, c, counts=cnt, backward=backward, **blocks) * r)
+
+    def ref(x_, a, b, c):
+        return jnp.sum(_ffn_ref(x_, a, b, c, live) * r)
+
+    got = jax.grad(loss("counted"), argnums=(0, 1, 2, 3))(x, wg, wu, wd)
+    was = jax.grad(loss("einsum"), argnums=(0, 1, 2, 3))(x, wg, wu, wd)
+    want = jax.grad(ref, argnums=(0, 1, 2, 3))(x, wg, wu, wd)
+    for a, b, c in zip(got, was, want):
+        assert float(jnp.max(jnp.abs(a - c))) < 1e-5
+        assert float(jnp.max(jnp.abs(a - b))) < 1e-5
+    assert not jnp.any(got[0] * (1.0 - live)[..., None])
+    for e in range(4):
+        if int(cnt[e]) == 0:
+            assert not any(jnp.any(dw[e]) for dw in got[1:])
+        elif int(cnt[e]) % 4:
+            # rows past the count in a live block are multiplied
+            assert jnp.any(got[0][e, int(cnt[e])])
+
+
+def test_counted_backward_rounds_the_sum_of_the_dx_products_once():
+    """bf16 operands holding small whole numbers, so that every float32
+    sum is exact whatever its order: the row-side kernel over both
+    products equals the float32 sum of the two einsums rounded once,
+    to the last bit, and not the sum of two rounded products."""
+    e, c, d, h = 4, 16, 32, 88
+    bf = jnp.bfloat16
+    ks = jax.random.split(jax.random.key(11), 4)
+    dg, du = (jax.random.randint(k, (e, c, h), -9, 10).astype(bf)
+              for k in ks[:2])
+    wg, wu = (jax.random.randint(k, (e, d, h), -9, 10).astype(bf)
+              for k in ks[2:])
+    cnt = jnp.array((16, 5, 0, 9), jnp.int32)
+    live = ((jnp.arange(c)[None, :] // 4 * 4) < cnt[:, None])[..., None]
+    dx = gm._bwd_rows((dg, du), (wg, wu), cnt, (4, 8, 8),
+                      name="grouped_mm_bwd_dx")
+    parts = [jnp.einsum("ech,edh->ecd", t.astype(_F32), w.astype(_F32),
+                        precision="highest") * live
+             for t, w in ((dg, wg), (du, wu))]
+    once = (parts[0] + parts[1]).astype(bf)
+    twice = parts[0].astype(bf) + parts[1].astype(bf)
+    assert dx.dtype == bf and jnp.all(dx == once)
+    assert jnp.any(once != twice)
+
+
+def test_counted_backward_in_bf16_is_the_einsum_backward_to_rounding():
+    """bf16 as the cells run it: ``h``, ``dg``, ``du`` are rounded to
+    bf16 on their way to a kernel, which is what the MXU's default
+    precision does to the einsums' float32 operands on the chip (there
+    the two paths read 2e-5 apart, PERF.md section 6, PR 41); the CPU
+    multiplies the einsums' float32 operands as they are, so here the
+    gap is that one rounding."""
+    x, wg, wu, wd = _gm_case(dtype=jnp.bfloat16)
+    cnt = jnp.array((16, 5, 0, 9), jnp.int32)
+
+    def loss(backward):
+        return lambda x_, a, b, c: jnp.sum(gm.grouped_ffn(
+            x_, a, b, c, counts=cnt, backward=backward,
+            **_GM_BLOCKS).astype(_F32) ** 2)
+
+    got = jax.grad(loss("counted"), argnums=(0, 1, 2, 3))(x, wg, wu, wd)
+    was = jax.grad(loss("einsum"), argnums=(0, 1, 2, 3))(x, wg, wu, wd)
+    for a, b in zip(got, was):
+        assert a.dtype == b.dtype == jnp.bfloat16
+        a, b = a.astype(_F32), b.astype(_F32)
+        assert float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b)) < 2 ** -7
+
+
+def test_counted_backward_refuses_what_it_cannot_count():
+    x, wg, wu, wd = _gm_case()
+    with pytest.raises(ValueError, match="counts"):
+        gm.grouped_ffn(x, wg, wu, wd, backward="counted")
+    with pytest.raises(ValueError, match="quantized"):
+        gm.grouped_ffn(x, wg, wu, wd, counts=jnp.full((4,), 16), fmt="int8",
+                       backward="counted")
+    with pytest.raises(ValueError, match="backward"):
+        gm.grouped_ffn(x, wg, wu, wd, backward="ragged")
+
+
+def _held_case(t=32, d=16, e=4, f=24):
+    ks = jax.random.split(jax.random.key(5), 5)
+    return (jax.random.normal(ks[0], (t, d), _F32),
+            jax.random.normal(ks[1], (d, e), _F32),
+            jax.random.normal(ks[2], (e, d, f), _F32) * 0.1,
+            jax.random.normal(ks[3], (e, d, f), _F32) * 0.1,
+            jax.random.normal(ks[4], (e, f, d), _F32) * 0.1)
+
+
+_EXPERT_LAYERS = {
+    "moe_held": (lambda *a: moe.moe_held(*a, 2, held=(0, 4), slots=32)[0],
+                 "counted", 4 * 32),
+    "moe_grouped": (lambda *a: moe.moe_grouped(*a, 2, 1.25), "einsum",
+                    4 * moe.group_capacity(32, 2, 4, 1.25)),
+}
+
+
+def _eqns_under(jaxpr, scope: str, inside: bool = False):
+    """The equations outside kernels whose name stack holds ``scope``,
+    or that lie in a jaxpr carried by one that does (a ``jit`` of its
+    own starts a name stack of its own)."""
+    for eqn in jaxpr.eqns:
+        here = inside or scope in str(eqn.source_info.name_stack)
+        if here:
+            yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns_under(sub, scope, here)
+
+
+def _expert_eqns(layer):
+    """(kernel names, dot_generals outside kernels) under the scope
+    ``moe.experts`` in the gradient's jaxpr of an expert layer."""
+    args = _held_case()
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(layer(*a) ** 2), argnums=(0, 1, 2, 3, 4)))(
+            *args).jaxpr
+    eqns = list(_eqns_under(jaxpr, "moe.experts"))
+    return ([q.params["name"] for q in eqns
+             if q.primitive.name == "pallas_call"],
+            [q for q in eqns if q.primitive.name == "dot_general"])
+
+
+def test_moe_held_backward_is_kernels_by_name():
+    """The layer whose buffer is a bound takes the counted backward:
+    its gradient holds the forward's three kernels and the backward's
+    four by their names, and nothing multiplies an ``[E, C, .]`` array
+    outside a kernel under ``moe.experts``."""
+    kernels, dots = _expert_eqns(_EXPERT_LAYERS["moe_held"][0])
+    assert kernels == ["grouped_mm"] * 3 + _BWD_KERNELS
+    assert dots == []
+
+
+@pytest.mark.parametrize("path", ["moe_grouped", "int8"])
+def test_capacity_and_quantized_paths_keep_the_einsum_backward(path):
+    """``moe_grouped`` (a capacity: rows are dropped to fit) and the
+    quantized recipes keep ``_grouped_ffn_bwd``: three kernels, all the
+    forward's, and six einsums."""
+    if path == "int8":
+        x, wg, wu, wd = _gm_case(dtype=jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(gm.grouped_ffn(
+            *a, fmt="int8", **_GM_BLOCKS).astype(_F32) ** 2),
+            argnums=(0, 1, 2, 3)))(x, wg, wu, wd).jaxpr
+        eqns = list(_eqns_outside_kernels(jaxpr))
+        kernels = [q.params["name"] for q in eqns
+                   if q.primitive.name == "pallas_call"]
+        dots = [q for q in eqns if q.primitive.name == "dot_general"]
+    else:
+        kernels, dots = _expert_eqns(_EXPERT_LAYERS["moe_grouped"][0])
+    assert kernels == ["grouped_mm"] * 3
+    assert len(dots) == 6
+
+
+@pytest.mark.parametrize("name", sorted(_EXPERT_LAYERS))
+def test_a_traced_expert_layer_marks_its_backward(name):
+    """One ``moe.experts_bwd`` mark a traced site, on the open span
+    (a build's ``compile``), and none without a tracer."""
+    from dlnetbench_tpu.metrics import spans
+    layer, path, slots = _EXPERT_LAYERS[name]
+    args = _held_case()
+
+    def trace():
+        jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(layer(*a) ** 2)))(*args)
+    trace()
+    assert spans.current() is None
+    tracer = spans.enable()
+    try:
+        with spans.span("compile", fn="layer"):
+            trace()
+    finally:
+        spans.disable()
+    build, = tracer.export()["spans"]
+    assert build["attrs"]["moe.experts_bwd"] == [
+        {"path": path, "slots": slots,
+         "row_block": gm.tile_plan(slots // 4, 16, 24, 4)["block_c"]}]
+
+
 @pytest.mark.parametrize("fmt", ["int8", "float8"])
 def test_grouped_ffn_quantized_grads_straight_through(fmt):
     """A quantized format's backward is the master-dtype gradient at
@@ -608,6 +818,50 @@ def test_tile_plan_takes_a_dimension_whole_or_by_lane_multiples():
     # the quantizing prologue's float32 copy of the rows counts too
     assert gm.tile_bytes(512, 512, 14336, 2, quantized=True) \
         == gm.tile_bytes(512, 512, 14336, 2) + 512 * 14336 * 4
+
+
+# the contraction side of the three held cells' expert layers, bf16:
+# (E, C, K, N, outputs a call, rows an expert as a window reads them)
+_CELL_DW = {
+    "lfm2_down": (32, 2048, 1792, 2048, 1, (1024,) * 30 + (0, 2048)),
+    "lfm2_gate_up": (32, 2048, 2048, 1792, 2, (1024,) * 30 + (0, 2048)),
+    "kimi_gate_up": (16, 4096, 2048, 1408, 2, _KIMI_ROWS),
+    "qwen_gate_up": (32, 1536, 2048, 512, 2, (0, 0) + (322,) * 29 + (0,)),
+}
+
+
+@pytest.mark.parametrize("name", _CELL_DW)
+def test_dw_tile_plan_of_the_cells_keeps_a_whole_gradient(name):
+    """An expert's whole ``[K, N]`` gradient (both of gate and up) fits
+    a step, so every live row block is fetched once and a step past
+    the count names the blocks the step before it named: it fetches
+    nothing, and an expert with no row nothing either."""
+    e, c, kdim, n, outs, counts = _CELL_DW[name]
+    bc = gm.tile_plan(c, kdim, n, 2)["block_c"]
+    plan = gm.dw_tile_plan(bc, kdim, n, 2, outs=outs)
+    assert plan == {"block_k": kdim, "block_n": n}
+    cnt = np.asarray(counts, np.int32)
+    be, bl = (np.asarray(t) for t in gm.last_live(jnp.asarray(cnt), bc))
+    fetched, before = 0, None
+    for ei in range(e):
+        for ci in range(c // bc):
+            live = ci * bc < cnt[ei]
+            block = (ei, ci) if live else (int(be[ei]), int(bl[ei]))
+            fetched += block != before
+            assert live or block == before or before is None
+            before = block
+    assert fetched == int((-(-cnt // bc)).sum()) + (cnt[0] == 0)
+
+
+def test_dw_tile_plan_splits_by_lane_multiples_under_a_tight_budget():
+    whole = gm.dw_tile_plan(256, 2048, 1792, 2, outs=2)
+    assert whole == {"block_k": 2048, "block_n": 1792}
+    tight = gm.dw_tile_plan(256, 2048, 1792, 2, outs=2, budget=24 * 2 ** 20)
+    bk, bn = tight["block_k"], tight["block_n"]
+    assert (bk, bn) != (2048, 1792)
+    assert 2048 % bk == 0 and 1792 % bn == 0 and bk % 128 == bn % 128 == 0
+    least = gm.dw_tile_plan(256, 2048, 1792, 2, budget=1)
+    assert least == {"block_k": 128, "block_n": 128}
 
 
 def test_moe_grouped_matches_sparse_lossless():
