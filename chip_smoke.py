@@ -130,6 +130,13 @@ class Sizes:
         hidden_size=256, num_attention_heads=4, num_key_value_heads=1,
         intermediate_size=512, moe_intermediate_size=128, vocab_size=512))
     v_seq: int = 1024
+    # swa_moe: the published 128 lanes a head in groups of seven, one
+    # period [full, window, window, window], a quarter of the experts held
+    w_shape: dict = dataclasses.field(default_factory=lambda: dict(
+        hidden_size=256, num_attention_heads=7, num_key_value_heads=1,
+        head_dim=128, sliding_window_size=256, moe_ffn_hidden_size=128,
+        vocab_size=512))
+    w_seq: int = 1024
     # four chips
     c4_fsdp_model: str = "llama3_8b_16_bfloat16"
     c4_fsdp_scale: float = 0.125
@@ -179,6 +186,10 @@ TINY = Sizes(
                  num_key_value_heads=2, intermediate_size=128,
                  moe_intermediate_size=32, vocab_size=256),
     v_seq=128,
+    w_shape=dict(hidden_size=64, num_attention_heads=7,
+                 num_key_value_heads=1, head_dim=16, sliding_window_size=32,
+                 moe_ffn_hidden_size=32, vocab_size=256),
+    w_seq=128,
     c4_fsdp_scale=1e-5, c4_h3d_scale=1e-5, c4_time_scale=1e-4, c4_batch=4,
     c4_seq=128,
 )
@@ -1178,6 +1189,71 @@ def phase_conv_moe(sz: Sizes) -> dict:
                       "bound; attention and expert kernels compiled in"}
 
 
+# --------------------------------------------------------- phase: swa_moe
+
+def phase_swa_moe(sz: Sizes) -> dict:
+    """The window-and-full attention expert decoder (models/hybrid.py:
+    a ``nope`` layer and three ``swa`` layers, seven query heads over one
+    key/value head, the router on the layer's input, ReLU-gated experts
+    of which 2 of the router's 8 are held, the head untied) through the
+    same step builder and executor as ``phase_train``, the dense and the
+    block-sparse attention kernels forced, against the benchmark's plain
+    float32 reference on the same seeded weights."""
+    from benchmarks import reference_swa_moe, weights_swa_moe
+    from benchmarks.runners import train_swa_moe
+    from dlnetbench_tpu.core import executor
+    from dlnetbench_tpu.models import bench_step
+
+    layout = [0, 1, 1, 1]
+    config = {
+        **sz.w_shape, "num_hidden_layers": 4, "rope_layout": layout,
+        "sliding_window_layout": layout, "moe_num_primary_experts": 2,
+        "published": {"moe_num_primary_experts": 8},
+        "moe_num_active_primary_experts": 3,
+        "moe_primary_router_apply_softmax": True, "norm_topk_prob": True,
+        "rms_norm_eps": 1e-6, "rope_theta": 1500000, "rope_scaling": None,
+        "tie_word_embeddings": False, "torch_dtype": sz.dtype,
+        "assumed": {"first_held_expert": 2}}
+    arch = weights_swa_moe.arch_of(config)
+    slots = 2 * sz.w_seq        # every row of the batch: no bound to reach
+    cfg = train_swa_moe.config_of(
+        arch, sz.w_seq, slots, remat=True, attention_impl="flash",
+        loss_row_block=sz.w_seq)
+
+    def make_params():
+        return weights_swa_moe.make_params(arch, sz.seed)
+    tokens = weights_swa_moe.make_token_pool(
+        sz.seed, 1, 2, sz.w_seq + 1, arch["vocab_size"])[0]
+    want = reference_swa_moe.sgd_steps(
+        make_params, [tokens] * sz.h_k, arch, sz.h_lr)
+    prog = executor.CompiledProgram(executor.Program(
+        fn=bench_step.make_train_k(cfg, sz.h_k, sz.h_lr),
+        args=(make_params(), tokens),
+        donate_argnums=bench_step.DONATE_ARGNUMS))
+    kernels = prog.as_text().count("tpu_custom_call")
+    if on_tpu():
+        # three attention kernels of either kind, three grouped matmuls
+        # and the counted backward's four a layer, each at least once
+        require(kernels >= 13, f"compiled window-and-full step holds "
+                               f"{kernels} tpu_custom_call")
+    got, routing, gap = expert_step_checks("window-and-full", prog, want,
+                                           sz.h_k)
+    return {"shapes": {**sz.w_shape, "seq": sz.w_seq, "batch": 2,
+                       "layers": list(arch["layer_kinds"]), "experts": 8,
+                       "held": list(arch["held"]), "top_k": 3,
+                       "slots": slots, "steps": sz.h_k, "lr": sz.h_lr},
+            "tpu_custom_calls": kernels,
+            "memory_analysis": prog.memory_analysis,
+            "losses": [round(v, 4) for v in got],
+            "float32_losses": [round(v, 4) for v in want["losses"]],
+            "rows_routed_to_held": int(routing["routed"][0]),
+            "selection_gap": gap,
+            "checks": "first loss, the loss's fall and the selections "
+                      "within tolerance of "
+                      "benchmarks/reference_swa_moe.py; no row past the "
+                      "bound; window, full and expert kernels compiled in"}
+
+
 # ------------------------------------------------------ four-chip phases
 
 def all_device_ids() -> set:
@@ -1387,7 +1463,8 @@ ONE_CHIP = (("kernels", phase_kernels), ("train", phase_train),
             ("moe", phase_moe), ("hybrid", phase_hybrid),
             ("latent_moe", phase_latent_moe),
             ("linear_moe", phase_linear_moe),
-            ("conv_moe", phase_conv_moe))
+            ("conv_moe", phase_conv_moe),
+            ("swa_moe", phase_swa_moe))
 FOUR_CHIPS = (("mesh_proxies", phase_mesh_proxies), ("spmd", phase_spmd),
               ("kv_shard", phase_kv_shard))
 

@@ -39,6 +39,11 @@ class MoEParams:
     shared_gate: bool = False       # ... times sigmoid(y w) a token
     expert_ff_dim: int = 0          # an expert's width; 0 => ff_dim
     first_dense_layers: int = 0     # leading layers with a dense FFN
+    early_router: bool = False      # the router reads the layer's normed
+                                    # input, the tensor attention reads,
+                                    # not the experts' (the normed stream
+                                    # after attention)
+    activation: str = "silu"        # the experts' gate: silu | relu
 
 
 @dataclasses.dataclass(frozen=True)
@@ -61,10 +66,11 @@ class ModelCard:
     patch_size: int = 0             # ViT
     num_classes: int = 0            # ViT head
     # a per-layer pattern of mixers (models/hybrid.py KINDS: mamba,
-    # window, full, gmu, cross, mla, gdn, gated, conv), one name a decoder block; () => every
+    # window, full, gmu, cross, mla, gdn, gated, conv, swa, nope), one
+    # name a decoder block; () => every
     # block is the transformer's one kind (models/transformer.py)
     layer_kinds: tuple = ()
-    sliding_window: int = 0         # keys a "window" layer attends
+    sliding_window: int = 0         # keys a "window" or "swa" layer attends
     differential_attention: bool = False  # heads paired, two softmaxes
     ssm_inner: int = 0              # a mamba / gmu layer's channels E
     ssm_state: int = 0              # state size N a channel
@@ -89,6 +95,10 @@ class ModelCard:
     attn_output_gate: bool = True   # false: the heads' output ungated,
                                     # the query projection without lanes
                                     # for a gate
+    attn_head_norm: bool = True     # false: queries and keys not normed
+                                    # a head ("swa" and "nope" layers are
+                                    # "gated" ones with a window and RoPE,
+                                    # or neither, of their own)
     # linear attention with a matrix state (a "gdn" layer)
     linear_key_heads: int = 0
     linear_value_heads: int = 0
@@ -165,11 +175,11 @@ class ModelCard:
             return (d * (2 * qk + 2 * vz) + d * 2 * hv
                     + self.linear_conv * (2 * qk + vz) + 2 * hv
                     + self.linear_value_dim + vz * d)
-        if kind == "gated":
+        if kind in ("gated", "swa", "nope"):
             dh = self.attn_head_dim or self.head_dim
             dq, dkv = self.num_heads * dh, self.kv_heads * dh
             return (d * (1 + self.attn_output_gate) * dq + 2 * d * dkv
-                    + 2 * dh + dq * d)
+                    + 2 * dh * self.attn_head_norm + dq * d)
         if kind == "conv":
             return d * 3 * d + self.short_conv * d + d * d
         if kind == "mla":

@@ -7,7 +7,7 @@ for this framework: what every downstream layer consumes is the
 a HF model" is a card, not a cache of safetensors.  This module maps a HF
 config (``model_type`` gpt2 / llama / mistral / mixtral / phi4flash /
 deepseek_v3 as Kimi-VL and Moonlight state it / qwen3_next / lfm2_moe /
-vit) onto
+smallthinker / vit) onto
 ``ModelCard`` fields and writes the card JSON.
 
 Offline-first: hub access is attempted only when requested and is never
@@ -108,6 +108,9 @@ def card_from_hf_config(name: str, cfg: Mapping[str, Any] | Any) -> ModelCard:
 
     if mt == "lfm2_moe":
         return _conv_moe_card(name, cfg)
+
+    if mt == "smallthinker" or (not mt and "moe_num_primary_experts" in cfg):
+        return _swa_moe_card(name, cfg)
 
     if mt == "vit":
         image = int(cfg["image_size"])
@@ -339,6 +342,72 @@ def _conv_moe_card(name: str, cfg: Mapping[str, Any]) -> ModelCard:
             routed_scale=float(cfg.get("routed_scaling_factor", 1.0)),
             expert_ff_dim=int(cfg["moe_intermediate_size"]),
             first_dense_layers=int(cfg.get("num_dense_layers", 0)),
+        ),
+    )
+
+
+def _swa_moe_card(name: str, cfg: Mapping[str, Any]) -> ModelCard:
+    """``model_type: "smallthinker"`` (or a ``config.json`` with its
+    ``moe_num_primary_experts``): grouped-query softmax attention with
+    no norm a head whose window and RoPE are a layer's
+    (``sliding_window_layout`` and ``rope_layout``, 1 a layer with a
+    window of ``sliding_window_size`` keys and RoPE, 0 a layer that
+    sees every earlier key and has no position), a router that reads
+    the layer's normed input before attention (softmax over the top-k
+    logits), ReLU-gated experts in every layer and no shared one, an
+    untied head.  Refused, because no layer here computes them: a layer
+    whose two layouts differ (a window without RoPE, or RoPE over the
+    whole sequence), a router without its softmax, unnormalised top-k
+    weights, scaled RoPE."""
+    unsupported = {k: cfg.get(k) for k, ok in (
+        ("moe_primary_router_apply_softmax", (True,)),
+        ("norm_topk_prob", (True,)), ("rope_scaling", (None,)))
+        if cfg.get(k) not in ok}
+    if unsupported:
+        raise ValueError(f"{name}: window-and-full import has no "
+                         f"{unsupported}")
+    layers = int(cfg["num_hidden_layers"])
+    window, turned = (list(cfg[k]) for k in ("sliding_window_layout",
+                                             "rope_layout"))
+    if not len(window) == len(turned) == layers:
+        raise ValueError(f"{name}: {len(window)} sliding_window_layout and "
+                         f"{len(turned)} rope_layout entries for {layers} "
+                         f"layers")
+    differ = [li for li, (w, r) in enumerate(zip(window, turned))
+              if bool(w) != bool(r)]
+    if differ:
+        raise ValueError(f"{name}: layer {differ[0]} has "
+                         f"sliding_window_layout {window[differ[0]]} and "
+                         f"rope_layout {turned[differ[0]]}; no layer here "
+                         f"has a window without RoPE or RoPE without one")
+    heads = int(cfg["num_attention_heads"])
+    return ModelCard(
+        name=name,
+        embed_dim=int(cfg["hidden_size"]),
+        num_heads=heads,
+        num_kv_heads=int(cfg.get("num_key_value_heads") or heads),
+        ff_dim=int(cfg["moe_ffn_hidden_size"]),
+        seq_len=int(cfg["max_position_embeddings"]),
+        num_decoder_blocks=layers,
+        vocab_size=int(cfg["vocab_size"]),
+        gated_mlp=True,
+        tied_embeddings=bool(cfg.get("tie_word_embeddings", False)),
+        layer_kinds=tuple("swa" if w else "nope" for w in window),
+        sliding_window=int(cfg["sliding_window_size"]),
+        attn_head_dim=int(cfg.get("head_dim")
+                          or cfg["hidden_size"] // heads),
+        attn_output_gate=False,
+        attn_head_norm=False,
+        rope_theta=float(cfg.get("rope_theta", 10000.0)),
+        rms_norm=True,
+        norm_eps=float(cfg.get("rms_norm_eps", 1e-6)),
+        moe_params=MoEParams(
+            num_experts=int(cfg["moe_num_primary_experts"]),
+            num_experts_per_tok=int(cfg["moe_num_active_primary_experts"]),
+            scoring="softmax",
+            expert_ff_dim=int(cfg["moe_ffn_hidden_size"]),
+            early_router=True,
+            activation="relu",
         ),
     )
 

@@ -13,7 +13,11 @@ experts beside a shared one behind a sigmoid gate) and the
 short-convolution expert models of the LFM2 family (``conv`` layers
 with a ``gated`` layer without its gate every third or fourth, leading
 dense layers that are ``conv`` layers, sigmoid-routed experts and no
-shared one, the head tied under RMSNorm).
+shared one, the head tied under RMSNorm) and the window-and-full
+attention expert models of the SmallThinker family (``swa`` layers with
+a ``nope`` layer every fourth, no norm a head, a router that reads the
+layer's input before attention, ReLU-gated experts and no shared one,
+an untied head under RMSNorm).
 
 ``HybridConfig.layer_kinds`` names each layer's mixer, one of ``KINDS``:
 
@@ -46,6 +50,10 @@ shared one, the head tied under RMSNorm).
   (``attn_gate`` false) the projection carries the queries alone and
   the heads' output goes to the output projection as it is: grouped
   softmax attention with a norm a head.
+* ``swa`` / ``nope`` — the ``gated`` layer's grouped softmax attention
+  (its weights, its function) with the layer's own mask and positions:
+  ``swa`` sees the last ``attention_window`` keys and RoPE turns its
+  heads, ``nope`` sees every earlier key and has no position at all.
 * ``conv``   — gated short convolution: one projection to three streams
   ``[b | c | u]`` of the model's width, ``c * conv(b * u)`` with a
   depthwise causal convolution of ``short_conv`` taps over time, no
@@ -58,7 +66,10 @@ or ``moe``: ``sum_i w_i expert_i(y)`` over the token's top-k of
 ``held_experts`` (``models/moe.moe_held``: the router scores and
 selects over all of them, no token is dropped), plus one shared SwiGLU
 at ``shared_ff_dim`` that every token passes through (times
-``sigmoid(y w_sg)`` a token where ``shared_gate``).  Parameters are
+``sigmoid(y w_sg)`` a token where ``shared_gate``).  Where
+``early_router`` the router reads the mixer's normed input, the tensor
+attention reads, and the experts the normed stream after the mixer;
+``expert_activation`` is the experts' gate's (``silu`` | ``relu``).  Parameters are
 stacked by kind (``block`` holds both norms of every layer and the MLP
 of every dense one; ``moe`` the expert layers' router, routed and shared
 experts), layers are unrolled as the bench recipe unrolls them, and
@@ -74,6 +85,7 @@ dtype), so the kernels' ``1 / sqrt(2 * head_dim)`` is the published
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 
@@ -91,12 +103,16 @@ from dlnetbench_tpu.ops.selective_scan import selective_scan
 
 _F32 = jnp.float32
 KINDS = ("mamba", "window", "full", "gmu", "cross", "mla", "gdn", "gated",
-         "conv")
+         "conv", "swa", "nope")
 FFN_KINDS = ("dense", "moe")
 # which stack of parameters a layer's mixer reads
 GROUP_OF = {"mamba": "mamba", "window": "attn", "full": "attn",
             "gmu": "gmu", "cross": "cross", "mla": "mla", "gdn": "gdn",
-            "gated": "gated", "conv": "conv"}
+            "gated": "gated", "conv": "conv", "swa": "gated",
+            "nope": "gated"}
+# the inner scope of a "swa" or "nope" layer's kernel call
+# (``spans.SCOPES``); a "gated" layer's wears ``attn`` alone
+_ATTN_SCOPE = {"swa": "attn.window", "nope": "attn.full"}
 # what a step with expert layers returns beside its loss
 # (``models/moe.moe_held``): three counters over its expert layers (the
 # rows routed to held experts and the rows past the bound summed, the
@@ -156,6 +172,7 @@ class HybridConfig:
     gdn_value_dim: int = 0          # lanes of a value head
     gdn_conv: int = 4               # taps of the depthwise causal conv
     attn_gate: bool = True          # a "gated" layer's output gate
+    head_norm: bool = True          # ... and its norm a head on q and k
     short_conv: int = 3             # taps of a "conv" layer's convolution
     rule_impl: str = "auto"         # ops.gated_delta_rule: auto|pallas|xla
     # the FFN of each layer; () = every layer dense
@@ -166,6 +183,8 @@ class HybridConfig:
     shared_ff_dim: int = 0          # 0 = no shared expert
     shared_gate: bool = False       # the shared expert times sigmoid(y w)
     router_scoring: str = "softmax"  # layers.moe_router's gate
+    early_router: bool = False      # the router reads the mixer's input
+    expert_activation: str = "silu"  # the experts' gate: silu | relu
     routed_scale: float = 1.0
     held_experts: tuple = ()        # (first, count) of the routed experts
                                     # this chip holds; () = all of them
@@ -207,10 +226,17 @@ class HybridConfig:
             raise ValueError("gdn layers need gdn_key_heads dividing "
                              "gdn_value_heads, gdn_key_dim and "
                              "gdn_value_dim")
-        if "gated" in kinds and (self.num_heads % self.num_kv_heads
+        if self.early_router and any(
+                f == "moe" and GROUP_OF[k] != "gated"
+                for k, f in zip(kinds, ffn)):
+            raise ValueError("early_router: only a gated, swa or nope "
+                             "layer hands its router the mixer's input")
+        if set(kinds) & {"gated", "swa", "nope"} and (
+                self.num_heads % self.num_kv_heads
                                  or self.rope_dim % 2
                                  or self.rope_dim > self.head_dim):
-            raise ValueError("gated layers need num_kv_heads dividing "
+            raise ValueError("gated, swa and nope layers need "
+                             "num_kv_heads dividing "
                              "num_heads and an even rope_dim within the "
                              "head")
         if set(kinds) & {"window", "full", "cross"} and (
@@ -241,6 +267,7 @@ class HybridConfig:
                   "tied_head": card.tied_embeddings,
                   "attn_head_dim": card.attn_head_dim,
                   "attn_gate": card.attn_output_gate,
+                  "head_norm": card.attn_head_norm,
                   "rope_dim": card.rope_dim,
                   "gdn_key_heads": card.linear_key_heads,
                   "gdn_value_heads": card.linear_value_heads,
@@ -269,7 +296,9 @@ class HybridConfig:
                 shared_ff_dim=moe.shared_experts * width,
                 shared_gate=moe.shared_gate,
                 router_scoring=moe.scoring,
-                routed_scale=moe.routed_scale)
+                routed_scale=moe.routed_scale,
+                early_router=moe.early_router,
+                expert_activation=moe.activation)
         return cls(vocab_size=card.vocab_size, embed_dim=card.embed_dim,
                    num_heads=card.num_heads, num_kv_heads=card.kv_heads,
                    ff_dim=card.ff_dim, layer_kinds=kinds,
@@ -433,9 +462,10 @@ def param_shapes(cfg: HybridConfig) -> dict:
             "gated/wq": ((m, d, (1 + cfg.attn_gate) * h * dh), s_d),
             "gated/wk": ((m, d, hkv * dh), s_d),
             "gated/wv": ((m, d, hkv * dh), s_d),
-            "gated/q_norm": ((m, dh), unit),
-            "gated/k_norm": ((m, dh), unit),
             "gated/wo": ((m, h * dh, d), 1.0 / math.sqrt(h * dh))})
+        if cfg.head_norm:
+            out.update({"gated/q_norm": ((m, dh), unit),
+                        "gated/k_norm": ((m, dh), unit)})
     if (m := sizes["conv"]):
         out.update({
             "conv/w_in": ((m, d, 3 * d), s_d),
@@ -715,32 +745,49 @@ def gdn_mixer(cfg: HybridConfig, y, p):
     return jnp.dot((o * _silu(z)).reshape(b, s, nv), p["w_out"])
 
 
-def gated_mixer(cfg: HybridConfig, y, p):
-    """Softmax attention with grouped keys and values, a norm a head on
-    queries and keys, and an output gate where the card states one."""
+def gated_mixer(cfg: HybridConfig, y, p, kind: str = "gated"):
+    """Softmax attention with grouped keys and values; a norm a head on
+    queries and keys and an output gate where the card states them.
+    The layer's ``kind`` gives its mask and its positions: ``gated``
+    and ``nope`` see every earlier key, ``swa`` the last
+    ``attention_window`` (the block-sparse kernels at
+    ``_splash_block``'s blocks); RoPE turns the first ``rope_dim``
+    lanes of ``gated`` and ``swa`` heads and none of ``nope``'s."""
     b, s, _ = y.shape
     h, hkv, dh, dr = (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
                       cfg.rope_dim or cfg.head_dim)
     qg = jnp.dot(y, p["wq"]).reshape(b, s, h, (1 + cfg.attn_gate) * dh)
+    q = qg[..., :dh]
     k = jnp.dot(y, p["wk"]).reshape(b, s, hkv, dh)
     v = jnp.dot(y, p["wv"]).reshape(b, s, hkv, dh)
-    q = L.rmsnorm(qg[..., :dh], _norm_scale(cfg, p["q_norm"]),
-                  cfg.norm_eps).astype(y.dtype)
-    k = L.rmsnorm(k, _norm_scale(cfg, p["k_norm"]),
-                  cfg.norm_eps).astype(y.dtype)
-    q_rope, k_rope = L.rope(q[..., :dr], k[..., :dr], jnp.arange(s),
-                            cfg.rope_theta)
-    q = jnp.concatenate([q_rope, q[..., dr:]], axis=-1)
-    k = jnp.concatenate([k_rope, k[..., dr:]], axis=-1)
-    o = ops.attention(q, k, v, causal=True, impl=cfg.attention_impl)
+    if cfg.head_norm:
+        q = L.rmsnorm(q, _norm_scale(cfg, p["q_norm"]),
+                      cfg.norm_eps).astype(y.dtype)
+        k = L.rmsnorm(k, _norm_scale(cfg, p["k_norm"]),
+                      cfg.norm_eps).astype(y.dtype)
+    inner = _ATTN_SCOPE.get(kind)
+    with scope(inner) if inner else contextlib.nullcontext():
+        if kind != "nope":
+            q_rope, k_rope = L.rope(q[..., :dr], k[..., :dr], jnp.arange(s),
+                                    cfg.rope_theta)
+            q = jnp.concatenate([q_rope, q[..., dr:]], axis=-1)
+            k = jnp.concatenate([k_rope, k[..., dr:]], axis=-1)
+        mask, block = None, None
+        if kind == "swa":
+            mask = MaskSpec(causal=True, window=cfg.attention_window)
+            block = _splash_block(cfg, s)
+        o = ops.attention(q, k, v, causal=True, impl=cfg.attention_impl,
+                          mask=mask, block_q=block, block_k=block)
     if cfg.attn_gate:
         o = o * jax.nn.sigmoid(qg[..., dh:].astype(_F32)).astype(y.dtype)
     return jnp.dot(o.reshape(b, s, h * dh), p["wo"])
 
 
-def expert_ffn(cfg: HybridConfig, x, norm, fp):
+def expert_ffn(cfg: HybridConfig, x, norm, fp, router_in=None):
     """``x + routed(y) + shared(y)``, ``y = norm(x)``, and the layer's
-    routing (``moe_held``'s).  The norm lies in ``moe.router`` and the
+    routing (``moe_held``'s).  The router reads ``router_in`` [B, S, D]
+    where the layer hands one (``early_router``: the mixer's normed
+    input), else ``y``.  The norm lies in ``moe.router`` and the
     residual in ``moe.combine``, as in ``transformer._block``."""
     b, s, d = x.shape
     with scope("moe.router"):
@@ -749,7 +796,10 @@ def expert_ffn(cfg: HybridConfig, x, norm, fp):
         y.reshape(b * s, d), fp["w_router"], fp["w_gate"], fp["w_up"],
         fp["w_down"], cfg.top_k, held=cfg.held_experts,
         slots=cfg.moe_slots, scoring=cfg.router_scoring,
-        bias=fp.get("router_bias"), scale=cfg.routed_scale)
+        bias=fp.get("router_bias"), scale=cfg.routed_scale,
+        router_x=(None if router_in is None
+                  else router_in.reshape(b * s, d)),
+        activation=cfg.expert_activation)
     out = routed.reshape(b, s, d)
     if cfg.shared_ff_dim:
         with scope("moe.shared"):
@@ -769,7 +819,7 @@ def _layer(cfg: HybridConfig, li: int, x, bp, mp, fp, memory, kv):
     memory (the memory layer), (k1, k2, V) (the full layer) or None;
     ``routing`` an expert layer's, else None."""
     kind = cfg.layer_kinds[li]
-    handed = None
+    handed = router_in = None
     if kind == "mamba":
         with scope("ssm"):
             out, s = mamba_mixer(cfg, _norm(cfg, x, bp, "norm1"), mp)
@@ -782,9 +832,16 @@ def _layer(cfg: HybridConfig, li: int, x, bp, mp, fp, memory, kv):
     elif kind == "mla":
         with scope("attn"):
             x = x + mla_mixer(cfg, _norm(cfg, x, bp, "norm1"), mp)
-    elif kind == "gated":
+    elif GROUP_OF[kind] == "gated":
         with scope("attn"):
-            x = x + gated_mixer(cfg, _norm(cfg, x, bp, "norm1"), mp)
+            y = _norm(cfg, x, bp, "norm1")
+            # a "gated" layer keeps the call of three arguments that
+            # the benchmark's planted fault wraps
+            # (benchmarks/runners/train_conv_moe._no_qk_norm)
+            x = x + (gated_mixer(cfg, y, mp) if kind == "gated"
+                     else gated_mixer(cfg, y, mp, kind))
+        if cfg.early_router:
+            router_in = y
     elif kind == "gdn":
         with scope("linattn"):
             x = x + gdn_mixer(cfg, _norm(cfg, x, bp, "norm1"), mp)
@@ -800,7 +857,7 @@ def _layer(cfg: HybridConfig, li: int, x, bp, mp, fp, memory, kv):
                 handed = kv
             x = x + diff_attention(cfg, y, mp, kv, li, kind == "window")
     if cfg.ffn_kinds[li] == "moe":
-        x, routing = expert_ffn(cfg, x, bp, fp)
+        x, routing = expert_ffn(cfg, x, bp, fp, router_in)
         return x, handed, routing
     with scope("mlp"):
         y = _norm(cfg, x, bp, "norm2")

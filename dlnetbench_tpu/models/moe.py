@@ -210,7 +210,8 @@ def stats_globals(stats, *, num_experts: int, top_k: int,
 # -------------------------------------------------- grouped expert FFN
 def expert_ffn(xe, w_gate, w_up, w_down, *, impl: str = "einsum",
                quant: str | None = None, counts=None,
-               mlp_int8: bool = False, backward: str = "einsum"):
+               mlp_int8: bool = False, backward: str = "einsum",
+               activation: str = "silu"):
     """The expert-FFN dispatch point shared by the single-device MoE
     below and the EP-sharded SPMD path: ``xe`` [E, C, d] dispatch
     buffers -> [E, C, d].
@@ -221,16 +222,21 @@ def expert_ffn(xe, w_gate, w_up, w_down, *, impl: str = "einsum",
       optional fused int8/fp8 quantization (``quant``) and count-aware
       block skipping (``counts``); ``backward`` is ``grouped_ffn``'s
       (``"counted"``: the backward skips the row blocks the forward
-      skipped).
+      skipped) and ``activation`` the gate's (``grouped_ffn``'s: ``silu``
+      or ``relu``; the einsum path is the SwiGLU only).
     """
     with scope("moe.experts"):
         if impl == "grouped":
             from dlnetbench_tpu.ops.grouped_matmul import grouped_ffn
             return grouped_ffn(xe, w_gate, w_up, w_down, counts=counts,
-                               fmt=quant, backward=backward).astype(_F32)
+                               fmt=quant, backward=backward,
+                               activation=activation).astype(_F32)
         if impl != "einsum":
             raise ValueError(f"moe.expert_ffn: unknown impl {impl!r} "
                              f"(einsum | grouped)")
+        if activation != "silu":
+            raise ValueError(f"moe.expert_ffn: the einsum path has the "
+                             f"SwiGLU only, not {activation!r}")
         if mlp_int8:
             from dlnetbench_tpu.ops.int8 import int8_dot_batched
             dt = xe.dtype
@@ -271,7 +277,8 @@ def moe_grouped(x2d, w_router, w_gate, w_up, w_down, top_k: int,
 
 def moe_held(x2d, w_router, w_gate, w_up, w_down, top_k: int, *,
              held: tuple, slots: int, scoring: str = "softmax",
-             bias=None, scale: float = 1.0):
+             bias=None, scale: float = 1.0, router_x=None,
+             activation: str = "silu"):
     """The routed experts' part of an expert layer on a chip that holds
     ``held = (first, count)`` of the router's experts (``w_gate`` /
     ``w_up`` / ``w_down`` are stacked over those ``count``): the router
@@ -284,23 +291,29 @@ def moe_held(x2d, w_router, w_gate, w_up, w_down, top_k: int, *,
     backward (``grouped_ffn(backward="counted")``: kernels over the row
     blocks the forward multiplied); ``moe_grouped``, whose capacity
     drops rows to stay nearly full, keeps the einsums over every slot.
+    ``router_x`` [T, d] is what the router reads where that is another
+    tensor than the experts' ``x2d`` (a router placed before attention
+    reads the layer's normed input); ``activation`` the experts' gate's
+    (``grouped_ffn``).
 
     Returns ``(y [T, d], routing)``; ``routing`` holds int32 scalars,
     ``routed`` rows routed to held experts, ``max_load`` the largest
     load of one of them, ``past_bound`` rows left out at ``slots``
     (a step that reads one has not computed the layer), and
     ``choices`` [T, k], the router's selection over all its experts."""
-    weights, idx = L.moe_router(x2d, w_router, top_k, scoring=scoring,
+    routed_on = x2d if router_x is None else router_x
+    weights, idx = L.moe_router(routed_on, w_router, top_k, scoring=scoring,
                                 bias=bias, scale=scale)
     xe, plan, gate, load = L.moe_dispatch_held(x2d, weights, idx, held,
                                                slots)
     with scope("moe.dispatch"):
-        stats = _routing_stats(x2d, w_router, load[None], plan, slots)
+        stats = _routing_stats(routed_on, w_router, load[None], plan, slots)
         routing = {"routed": jnp.sum(stats["routed"]),
                    "max_load": jnp.max(stats["routed"]),
                    "past_bound": stats["dropped"], "choices": idx}
     y = expert_ffn(xe, w_gate, w_up, w_down, impl="grouped",
-                   counts=stats["kept"], backward="counted")
+                   counts=stats["kept"], backward="counted",
+                   activation=activation)
     with scope("moe.combine"):
         return L.moe_combine(y.astype(x2d.dtype), plan, gate), routing
 

@@ -443,8 +443,24 @@ def expert_amax(x):
     return jnp.max(jnp.abs(x.astype(F32)), axis=(1, 2))
 
 
-def _ffn_fwd(x, w_gate, w_up, w_down, counts, fmt, blocks):
-    """The three grouped dots of the expert SwiGLU -> ``(y, g, u)``:
+ACTIVATIONS = ("silu", "relu")
+
+
+def gate_act(g, act: str):
+    """``(a(g), a'(g))`` of the gate's activation, the float32 ``g``
+    in: ``silu`` (SwiGLU) or ``relu`` (``max(g, 0)``, slope ``[g > 0]``).
+    The forward, the einsum backward and the counted backward's tiles
+    all take the activation and its slope from here."""
+    if act == "relu":
+        pos = g > 0
+        return jnp.where(pos, g, 0.0), jnp.where(pos, 1.0, 0.0)
+    sig = jax.nn.sigmoid(g)
+    silu = g * sig
+    return silu, sig + silu * (1.0 - sig)
+
+
+def _ffn_fwd(x, w_gate, w_up, w_down, counts, fmt, blocks, act="silu"):
+    """The three grouped dots of the gated expert FFN -> ``(y, g, u)``:
     the ONE body behind the primal and the VJP's forward, so the
     rounding of what the backward reads cannot drift from what the
     forward computed (``layers.swiglu``'s discipline: ``g``,
@@ -459,33 +475,34 @@ def _ffn_fwd(x, w_gate, w_up, w_down, counts, fmt, blocks):
         wuq, swu = quantize_experts(w_up, fmt)
         g = grouped_matmul(x, wgq, sx=sx, sw=swg, fmt=fmt, **kw)
         u = grouped_matmul(x, wuq, sx=sx, sw=swu, fmt=fmt, **kw)
-        h = (jax.nn.silu(g.astype(F32)) * u.astype(F32)).astype(g.dtype)
+        h = (gate_act(g.astype(F32), act)[0]
+             * u.astype(F32)).astype(g.dtype)
         sh = scale_from_amax(expert_amax(h), fmt)
         wdq, swd = quantize_experts(w_down, fmt)
         return (grouped_matmul(h, wdq, sx=sh, sw=swd, fmt=fmt, **kw),
                 g, u)
     g = grouped_matmul(x, w_gate, **kw)
     u = grouped_matmul(x, w_up, **kw)
-    h = (jax.nn.silu(g.astype(F32)) * u.astype(F32)).astype(g.dtype)
+    h = (gate_act(g.astype(F32), act)[0] * u.astype(F32)).astype(g.dtype)
     return grouped_matmul(h, w_down, **kw), g, u
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _grouped_ffn(x, w_gate, w_up, w_down, counts, fmt, blocks):
-    return _ffn_fwd(x, w_gate, w_up, w_down, counts, fmt, blocks)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _grouped_ffn(x, w_gate, w_up, w_down, counts, fmt, blocks, act):
+    return _ffn_fwd(x, w_gate, w_up, w_down, counts, fmt, blocks, act)[0]
 
 
-def _grouped_ffn_fwd(x, w_gate, w_up, w_down, counts, fmt, blocks):
-    y, g, u = _ffn_fwd(x, w_gate, w_up, w_down, counts, fmt, blocks)
+def _grouped_ffn_fwd(x, w_gate, w_up, w_down, counts, fmt, blocks, act):
+    y, g, u = _ffn_fwd(x, w_gate, w_up, w_down, counts, fmt, blocks, act)
     return y, (x, g, u, w_gate, w_up, w_down, counts)
 
 
-def _grouped_ffn_bwd(fmt, blocks, res, dy):
+def _grouped_ffn_bwd(fmt, blocks, act, res, dy):
     """Straight-through master-dtype backward (the recipe every
     quantized path shares): six batched einsums over the expert axis,
     all ``E * C`` slots, float32 accumulation.  ``g`` and ``u`` are
     the forward's own (for every ``fmt``: the gradient is taken at the
-    activations the forward fed to ``silu(g) * u``), widened to
+    activations the forward fed to ``act(g) * u``), widened to
     float32 for the elementwise block; only ``h`` is made again from
     them.  No count mask is needed: in a block the forward skipped,
     ``g`` and ``u`` are its zeros, so ``h``, ``dg`` and ``du`` vanish
@@ -496,14 +513,13 @@ def _grouped_ffn_bwd(fmt, blocks, res, dy):
     xf = x.astype(F32)
     g = g.astype(F32)
     u = u.astype(F32)
-    sig = jax.nn.sigmoid(g)
-    silu = g * sig
-    h = silu * u
+    a, slope = gate_act(g, act)
+    h = a * u
     dyf = dy.astype(F32)
     dh = jnp.einsum("ecd,ehd->ech", dyf, w_down.astype(F32))
     dwd = jnp.einsum("ech,ecd->ehd", h, dyf).astype(w_down.dtype)
-    dg = dh * u * (sig + silu * (1.0 - sig))
-    du = dh * silu
+    dg = dh * u * slope
+    du = dh * a
     dx = (jnp.einsum("ech,edh->ecd", dg, w_gate.astype(F32))
           + jnp.einsum("ech,edh->ecd", du, w_up.astype(F32)))
     dwg = jnp.einsum("ecd,ech->edh", xf, dg).astype(w_gate.dtype)
@@ -527,20 +543,19 @@ def _as_one_dtype(a, b):
     return a.astype(both), b.astype(both)
 
 
-def _swiglu_bwd_tiles(dh, g, u, dtype):
-    """``(h, dg, du)`` of ``h = silu(g) * u`` from the float32 tile
+def _swiglu_bwd_tiles(dh, g, u, dtype, act: str = "silu"):
+    """``(h, dg, du)`` of ``h = act(g) * u`` from the float32 tile
     ``dh`` and the forward's ``g``, ``u``: float32 arithmetic, each
     rounded once to ``dtype``."""
     g, u = g.astype(F32), u.astype(F32)
-    sig = jax.nn.sigmoid(g)
-    silu = g * sig
-    return ((silu * u).astype(dtype),
-            (dh * u * (sig + silu * (1.0 - sig))).astype(dtype),
-            (dh * silu).astype(dtype))
+    a, slope = gate_act(g, act)
+    return ((a * u).astype(dtype), (dh * u * slope).astype(dtype),
+            (dh * a).astype(dtype))
 
 
 def _bwd_rows_kernel(counts_ref, _te, _tc, *refs, pairs: int,
-                     swiglu: bool, block_c: int, n_out: bool, nk: int):
+                     swiglu: bool, block_c: int, n_out: bool, nk: int,
+                     act: str = "silu"):
     """One grid step of the backward's row side: the sum over ``pairs``
     of ``x_p [bc, bk] @ w_p [bn, bk]^T`` (the weight as the forward
     stores it, contracted on its last dimension), float32, rounded
@@ -569,7 +584,7 @@ def _bwd_rows_kernel(counts_ref, _te, _tc, *refs, pairs: int,
     def emit(val):
         if swiglu:
             tiles = _swiglu_bwd_tiles(val, gu[0][0], gu[1][0],
-                                      outs[0].dtype)
+                                      outs[0].dtype, act)
             for out_ref, tile in zip(outs, tiles):
                 out_ref[0] = tile
         else:
@@ -600,14 +615,16 @@ def _bwd_rows_kernel(counts_ref, _te, _tc, *refs, pairs: int,
     pl.when(last & jnp.logical_not(live))(zeros)
 
 
-def _bwd_rows(xs, ws, counts, blocks, *, swiglu=None, name: str):
+def _bwd_rows(xs, ws, counts, blocks, *, swiglu=None, name: str,
+              act: str = "silu"):
     """The backward's row side as one kernel: ``sum_p xs[p] [E, C, K] @
     ws[p] [E, N, K]^T -> [E, C, N]`` in ``xs[0]``'s dtype, row blocks
     past ``counts`` skipped on the forward's tile plan and index maps
     (``tile_plan``, ``index_maps``).  ``swiglu = (g, u)`` [E, C, N]:
     the float32 sum is ``dh`` and the result is ``(h, dg, du)`` in
-    ``g``'s dtype.  ``blocks = (bc, block_n, block_k)``: the forward's
-    row block and the caller's explicit blocks, if any."""
+    ``g``'s dtype, ``act`` the gate's activation.  ``blocks = (bc,
+    block_n, block_k)``: the forward's row block and the caller's
+    explicit blocks, if any."""
     e, c, kdim = xs[0].shape
     n = ws[0].shape[1]
     pairs = len(xs)
@@ -644,7 +661,7 @@ def _bwd_rows(xs, ws, counts, blocks, *, swiglu=None, name: str):
     return pl.pallas_call(
         functools.partial(_bwd_rows_kernel, pairs=pairs,
                           swiglu=swiglu is not None, block_c=bc,
-                          n_out=n_out, nk=nk),
+                          n_out=n_out, nk=nk, act=act),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(e, nn, nc, nk) if n_out else (e, nc, nn, nk),
@@ -777,17 +794,18 @@ def _bwd_dw(a, bs, counts, blocks, out_dtypes, *, name: str):
     )(counts, be, bl, a, *bs)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _grouped_ffn_counted(x, w_gate, w_up, w_down, counts, blocks):
-    return _ffn_fwd(x, w_gate, w_up, w_down, counts, None, blocks)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _grouped_ffn_counted(x, w_gate, w_up, w_down, counts, blocks, act):
+    return _ffn_fwd(x, w_gate, w_up, w_down, counts, None, blocks, act)[0]
 
 
-def _grouped_ffn_counted_fwd(x, w_gate, w_up, w_down, counts, blocks):
-    return _grouped_ffn_fwd(x, w_gate, w_up, w_down, counts, None, blocks)
+def _grouped_ffn_counted_fwd(x, w_gate, w_up, w_down, counts, blocks, act):
+    return _grouped_ffn_fwd(x, w_gate, w_up, w_down, counts, None, blocks,
+                            act)
 
 
-@functools.partial(jax.jit, static_argnums=(0,))
-def _counted_bwd(blocks, x, g, u, w_gate, w_up, w_down, counts, dy):
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _counted_bwd(blocks, act, x, g, u, w_gate, w_up, w_down, counts, dy):
     """The backward that multiplies the row blocks the forward
     multiplied: the six products of ``_grouped_ffn_bwd`` as four
     kernels keyed by the forward's counts and its row block.  ``dh =
@@ -808,7 +826,7 @@ def _counted_bwd(blocks, x, g, u, w_gate, w_up, w_down, counts, dy):
     bc = _fit_blocks(e, c, d, w_gate.shape[2], None, x.dtype, blocks)[0]
     cnt = counts.astype(jnp.int32)
     h, dg, du = _bwd_rows((dy,), (w_down,), cnt, (bc, block_n, block_k),
-                          swiglu=(g, u), name="grouped_mm_bwd_dh")
+                          swiglu=(g, u), name="grouped_mm_bwd_dh", act=act)
     dx = _bwd_rows((dg, du), (w_gate, w_up), cnt, (bc, block_n, block_k),
                    name="grouped_mm_bwd_dx")
     dwd, = _bwd_dw(h, (dy,), cnt, (bc, block_k, block_n),
@@ -819,8 +837,8 @@ def _counted_bwd(blocks, x, g, u, w_gate, w_up, w_down, counts, dy):
     return dx.astype(x.dtype), dwg, dwu, dwd, jnp.zeros_like(counts)
 
 
-def _grouped_ffn_counted_bwd(blocks, res, dy):
-    return _counted_bwd(blocks, *res, dy)
+def _grouped_ffn_counted_bwd(blocks, act, res, dy):
+    return _counted_bwd(blocks, act, *res, dy)
 
 
 _grouped_ffn_counted.defvjp(_grouped_ffn_counted_fwd,
@@ -830,9 +848,13 @@ _grouped_ffn_counted.defvjp(_grouped_ffn_counted_fwd,
 def grouped_ffn(x, w_gate, w_up, w_down, *, counts=None,
                 fmt: str | None = None, block_c: int | None = None,
                 block_n: int | None = None, block_k: int | None = None,
-                backward: str = "einsum"):
-    """The grouped expert SwiGLU: ``x`` [E, C, d] dispatch buffers,
-    weights [E, d, h] / [E, h, d] stacked per expert -> [E, C, d].
+                backward: str = "einsum", activation: str = "silu"):
+    """The grouped gated expert FFN ``(act(x W_gate) * x W_up) W_down``:
+    ``x`` [E, C, d] dispatch buffers, weights [E, d, h] / [E, h, d]
+    stacked per expert -> [E, C, d].  ``activation`` is the gate's, a
+    static word the model's card states (``ACTIVATIONS``: ``silu``, the
+    SwiGLU, or ``relu``), the same in the forward, its recomputation
+    and either backward (``gate_act``).
 
     ``counts`` enables the gather/scatter block skipping, ``fmt``
     selects the fused-quantization recipes (per-expert dynamic scales,
@@ -855,6 +877,9 @@ def grouped_ffn(x, w_gate, w_up, w_down, *, counts=None,
     if backward == "counted" and (counts is None or fmt is not None):
         raise ValueError("grouped_ffn: the counted backward needs counts "
                          "and has no quantized form")
+    if activation not in ACTIVATIONS:
+        raise ValueError(f"grouped_ffn: unknown activation {activation!r} "
+                         f"{ACTIVATIONS}")
     e, c, d = x.shape
     blocks = (block_c, block_n, block_k)
     if spans.is_enabled():
@@ -865,5 +890,6 @@ def grouped_ffn(x, w_gate, w_up, w_down, *, counts=None,
                 else counts.astype(F32))
     if backward == "counted":
         return _grouped_ffn_counted(x, w_gate, w_up, w_down, counts_f,
-                                    blocks)
-    return _grouped_ffn(x, w_gate, w_up, w_down, counts_f, fmt, blocks)
+                                    blocks, activation)
+    return _grouped_ffn(x, w_gate, w_up, w_down, counts_f, fmt, blocks,
+                        activation)

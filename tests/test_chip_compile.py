@@ -275,6 +275,7 @@ CELL_HELD_FFN = {          # (E, C, d, F), bf16
     "lfm2": (32, 2048, 2048, 1792),
     "kimi": (16, 4096, 2048, 1408),
     "qwen": (32, 1536, 2048, 512),
+    "smallthinker": (16, 12032, 2560, 768),
 }
 
 
@@ -586,6 +587,66 @@ def test_conv_moe_train_step_at_the_cell_shapes_fits_the_chip(
     scopes = set(executor.hlo_op_scopes(text).values())
     assert {"conv", "conv.gate", "attn", "mlp", "moe.experts",
             "head_loss"} <= scopes
+
+
+def test_swa_moe_train_step_at_the_cell_shapes_fits_the_chip(
+        one_chip, no_persistent_cache, monkeypatch):
+    """``smallthinker_21b_a3b_train_s16k``'s whole step (the cell's own
+    files and compiler options, as the runner builds it): the rule of
+    the configuration file, twice the arguments plus the temporaries at
+    or under 14.0 GB by the chip compiler's count with 16 of 64 experts
+    held in eight layers; four attention kernels a layer at 28 query
+    heads over 4 (the forward twice: each layer is recomputed), the
+    window layers' block-sparse at blocks of 2048 and the full layers'
+    dense under one set of names and two scopes; six grouped matmuls a
+    layer at width 768 and the four kernels of its counted backward; the
+    router's logits read the layer's input; the state donated."""
+    from benchmarks import harness, weights_swa_moe as weights
+    from benchmarks.runners import train_swa_moe
+    from dlnetbench_tpu.core import executor
+    from dlnetbench_tpu.models import bench_step, hybrid
+    from dlnetbench_tpu.ops import pallas_common
+    monkeypatch.setattr(pallas_common, "interpret_mode", lambda: False)
+    cell = harness.load_cell("smallthinker_21b_a3b_train_s16k")
+    wl, tr = cell.workload, cell.traffic
+    arch = weights.arch_of(cell.config)
+    assert arch["held"] == (0, 16) and arch["head_dim"] == 128
+    cfg = train_swa_moe.program_config(cell, arch,
+                                       {"attention_impl": "flash"})
+    assert hybrid._splash_block(cfg, tr["seq_len"]) == 2048
+    assert (cfg.num_heads, cfg.num_kv_heads, cfg.attention_window) \
+        == (28, 4, 4096)
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree.map(
+        on_chip, jax.eval_shape(lambda: weights.make_params(arch, 0)))
+    tokens = jax.ShapeDtypeStruct((tr["batch"], tr["seq_len"] + 1), I32,
+                                  sharding=one_chip)
+    step = executor.CompiledStep(
+        bench_step.make_train_k(cfg, 1, wl["lr"]), (params, tokens),
+        donate_argnums=bench_step.DONATE_ARGNUMS,
+        compiler_options=wl["compiler_options"])
+    mem = step.memory_analysis
+    assert 2 * mem["argument"] + mem["temp"] <= 14.0e9
+    assert mem["alias"] > 0.99 * mem["argument"]   # the state is donated
+    text = step.as_text()
+    names = [re_sub_number(k) for k in kernel_instructions(text)]
+    layers = arch["num_layers"]
+    assert sorted(names) == sorted(
+        (["flash_fwd"] * 2 + ["flash_bwd_dq", "flash_bwd_dkv"]
+         + ["grouped_mm"] * 6 + [*EXPERTS_BWD, "grouped_mm_bwd_dw"])
+        * layers) and layers == 8
+    table = executor.hlo_op_scopes(text)
+    by_scope = {}
+    for inst, scope in table.items():
+        if re_sub_number(inst) in ("flash_fwd", "flash_bwd_dq",
+                                   "flash_bwd_dkv"):
+            by_scope.setdefault(scope, []).append(re_sub_number(inst))
+    assert {k: len(v) for k, v in by_scope.items()} \
+        == {"attn.window": 6 * 4, "attn.full": 2 * 4}
+    assert {"attn", "moe.router", "moe.dispatch", "moe.experts",
+            "moe.combine", "head_loss"} <= set(table.values())
 
 
 def re_sub_number(name: str) -> str:

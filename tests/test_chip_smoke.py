@@ -40,7 +40,7 @@ def _lines(capsys) -> list[dict]:
 
 @pytest.mark.parametrize("chips,phases", [
     (1, ("kernels", "train", "proxy", "serve", "moe", "hybrid",
-         "latent_moe", "linear_moe", "conv_moe")),
+         "latent_moe", "linear_moe", "conv_moe", "swa_moe")),
     (4, ("mesh_proxies", "spmd", "kv_shard")),
 ], ids=["one_chip", "four_chips"])
 def test_phases_pass_at_tiny_size_and_exit_nonzero_off_tpu(

@@ -249,3 +249,68 @@ def test_conv_moe_card_states_the_mixers_the_gate_and_the_tie():
                        ("rope_scaling", {"type": "yarn"})):
         with pytest.raises(ValueError, match="short-convolution import"):
             hf_import.card_from_hf_config("x", {**LFM2_MOE, key: value})
+
+
+def _smallthinker_row():
+    """The keys of the catalog's row for SmallThinker-21BA3B-Instruct
+    (``config.json`` as published)."""
+    layout = [0, 1, 1, 1] * 13
+    return {
+        "head_dim": 128, "hidden_size": 2560,
+        "max_position_embeddings": 16384,
+        "model_name": "smallthinker_21b_instruct",
+        "moe_ffn_hidden_size": 768, "moe_num_active_primary_experts": 6,
+        "moe_num_primary_experts": 64,
+        "moe_primary_router_apply_softmax": True, "norm_topk_prob": True,
+        "num_attention_heads": 28, "num_hidden_layers": 52,
+        "num_key_value_heads": 4, "rms_norm_eps": 1e-06,
+        "rope_layout": list(layout), "rope_scaling": None,
+        "rope_theta": 1500000, "sliding_window_layout": list(layout),
+        "sliding_window_size": 4096, "tie_word_embeddings": False,
+        "vocab_size": 151936}
+
+
+def test_swa_moe_card_states_the_layers_the_router_and_the_activation():
+    card = hf_import.card_from_hf_config("smallthinker_21b_a3b",
+                                         _smallthinker_row())
+    assert card == load_model_card("smallthinker_21b_a3b")
+    assert card == hf_import.card_from_hf_config(
+        "smallthinker_21b_a3b",
+        {**_smallthinker_row(), "model_type": "smallthinker"})
+    kinds = card.layer_kinds
+    assert len(kinds) == 52 and kinds[:5] == ("nope", "swa", "swa", "swa",
+                                              "nope")
+    assert [i for i, k in enumerate(kinds) if k == "nope"] \
+        == list(range(0, 52, 4))
+    assert (card.embed_dim, card.num_heads, card.kv_heads,
+            card.attn_head_dim, card.sliding_window, card.rope_theta,
+            card.norm_eps, card.vocab_size, card.seq_len) == (
+        2560, 28, 4, 128, 4096, 1.5e6, 1e-6, 151936, 16384)
+    assert not card.attn_output_gate and not card.attn_head_norm
+    assert card.rms_norm and not card.tied_embeddings
+    moe = card.moe_params
+    assert (moe.num_experts, moe.num_experts_per_tok, moe.expert_ff_dim,
+            moe.scoring, moe.shared_experts, moe.first_dense_layers,
+            moe.early_router, moe.activation) == (
+        64, 6, 768, "softmax", 0, 0, True, "relu")
+    # the published "21B" and "A3B"
+    assert card.num_params() == pytest.approx(21.507e9, rel=1e-4)
+    assert hf_import.card_to_json(card)["moe_params"] == {
+        "num_experts": 64, "num_experts_per_tok": 6, "expert_ff_dim": 768,
+        "early_router": True, "activation": "relu"}
+
+
+@pytest.mark.parametrize("over,match", [
+    ({"rope_layout": [0, 1, 0, 1] + [0, 1, 1, 1] * 12}, "layer 2 has"),
+    ({"sliding_window_layout": [1] + [1, 1, 1] + [0, 1, 1, 1] * 12},
+     "layer 0 has"),
+    ({"rope_layout": [0, 1, 1, 1]}, "52 sliding_window_layout and 4"),
+    ({"moe_primary_router_apply_softmax": False}, "apply_softmax"),
+    ({"norm_topk_prob": False}, "norm_topk_prob"),
+    ({"rope_scaling": {"type": "yarn"}}, "rope_scaling")])
+def test_swa_moe_import_refuses_what_no_layer_computes(over, match):
+    """Layouts that differ in a layer (a window without RoPE, RoPE over
+    the whole sequence) are refused with the layer's number; so are a
+    router without its softmax, unnormalised weights and scaled RoPE."""
+    with pytest.raises(ValueError, match=match):
+        hf_import.card_from_hf_config("x", {**_smallthinker_row(), **over})
