@@ -14,11 +14,16 @@ Design (TPU-first, not a port — the reference has no kernels at all):
   masked in-register.
 * GQA maps q head ``h`` to kv head ``h // group`` purely in the
   ``BlockSpec`` index maps — no materialized KV broadcast.
-* Backward is the standard flash-attention recomputation split into a
-  dq kernel (grid minor axis = kv blocks) and a dk/dv kernel (grid minor
-  axis = q blocks), both reusing the saved logsumexp; dk/dv are produced
-  per q-head and group-summed by the wrapper, which keeps every output
-  block written by exactly one grid lane.
+* Backward is the standard flash-attention recomputation from the
+  saved logsumexp, in the dk/dv kernel (grid minor axis = q blocks):
+  dk/dv are produced per q-head and group-summed by the wrapper, which
+  keeps every output block written by exactly one grid lane.  The same
+  kernel accumulates dq from the ``ds`` it holds, one query head's whole
+  dq in a float32 VMEM scratch that leaves through an output block of
+  the head's size, so the scores, exp and dP are computed once
+  (``_dq_resident``).  Where a head's dq does not fit its share of the
+  VMEM limit, a second kernel (grid minor axis = kv blocks) recomputes
+  them for dq alone.
 * Head dims that are not lane-aligned (e.g. gpt2's 64) are zero-padded
   to 128 in the wrapper; padding columns contribute nothing to scores and
   are sliced off the outputs, so numerics are unchanged.
@@ -43,6 +48,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from dlnetbench_tpu.metrics import spans
 from dlnetbench_tpu.ops import attention_mask as amask
 from dlnetbench_tpu.ops import pallas_common
 
@@ -69,19 +75,28 @@ _BLOCK_CANDIDATES_BWD = (1024, 512, 256, 128)
 _BLOCK_CANDIDATES = _BLOCK_CANDIDATES_BWD   # shape gate: the common subset
 
 
-def _compiler_params():
-    """Mosaic params shared by all three kernels: the minor grid axis
+_VMEM_LIMIT_MB = 64
+# the share of it that a head's resident dq may take in the dk/dv
+# kernel (``_dq_resident``); the score tiles have the rest
+_DQ_RESIDENT_SHARE = 0.5
+
+
+def _compiler_params(dq_resident: bool = False):
+    """Mosaic params shared by all the kernels: the minor grid axis
     carries the online-softmax / accumulator scratch (sequential); the
     outer (batch, head, row-block) axes are independent — declaring them
     ``parallel`` lets Mosaic pipeline DMA across grid rows instead of
     treating the whole grid as one sequential chain (measured: the 2048
-    forward blocks are ~1.7x slower without it).  The VMEM cap stays at
+    forward blocks are ~1.7x slower without it).  With a head's dq
+    resident the row-block axis carries that accumulator too and is
+    sequential; batch and head stay independent.  The VMEM cap stays at
     64 MiB (tighter than the matmul-family default — these kernels hold
     more live blocks per lane) so 2048-wide blocks keep double-buffering
     headroom on v5e/v5p (128 MiB physical VMEM)."""
     return pallas_common.compiler_params(
-        ("parallel", "parallel", "parallel", "arbitrary"),
-        vmem_limit_mb=64)
+        ("parallel", "parallel",
+         "arbitrary" if dq_resident else "parallel", "arbitrary"),
+        vmem_limit_mb=_VMEM_LIMIT_MB)
 
 
 # At and beyond this length the dense-attention fallback materializes a
@@ -287,9 +302,48 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref, dq_ref,
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
-                dk_ref, dv_ref, dk_acc, dv_acc,
-                *, scale: float, causal: bool, block_q: int, block_k: int):
+def _dkv_refs(refs):
+    """The dk/dv kernels' outputs and scratch as Pallas hands them:
+    (dk, dv, dk_acc, dv_acc), or with a head's dq resident
+    (dk, dv, dq, dk_acc, dv_acc, dq_acc).  Always six, ``None`` where
+    the dq kernel does that work."""
+    if len(refs) == 4:
+        dk_ref, dv_ref, dk_acc, dv_acc = refs
+        return dk_ref, dv_ref, None, dk_acc, dv_acc, None
+    return refs
+
+
+def _dq_resident_init(dq_acc, j, i):
+    """Zeros at the head's first grid step.  Keyed, like the emit, on
+    the grid's indices and not on ``work``: under a mask a head's last
+    step may visit nothing, and a query block that no key block visits
+    leaves zeros."""
+    @pl.when((j == 0) & (i == 0))
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+
+def _dq_resident_emit(dq_ref, dq_acc, j, i):
+    """The one write of the head's output block, after the step of the
+    head's last grid step."""
+    @pl.when((j == pl.num_programs(2) - 1) & (i == pl.num_programs(3) - 1))
+    def _emit():
+        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+
+
+def _dq_resident_add(dq_acc, ds, k, i, block_q: int):
+    """``dq[q block i] += ds k``: the product the dq kernel ends with,
+    on the ``ds`` the dk/dv kernel holds.  Key blocks arrive in
+    ascending order (the outer grid axis), so the float32 sum has the
+    dq kernel's order."""
+    rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+    dq_acc[rows, :] += jax.lax.dot_general(
+        ds, k, (((1,), (0,)), ((), ())), preferred_element_type=_F32)
+
+
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref, *refs,
+                scale: float, causal: bool, block_q: int, block_k: int):
+    dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc = _dkv_refs(refs)
     j = pl.program_id(2)      # kv block (outer)
     i = pl.program_id(3)      # q block (inner / minor)
     nq = pl.num_programs(3)
@@ -302,6 +356,9 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    if dq_acc is not None:
+        _dq_resident_init(dq_acc, j, i)
 
     @pl.when(work)
     def _step():
@@ -320,15 +377,20 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
         dp = jax.lax.dot_general(
             do, v_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=_F32)                  # [bq, bk]
-        ds = p * (dp - dcap_ref[0, 0, 0][:, None]) * scale
+        ds = (p * (dp - dcap_ref[0, 0, 0][:, None]) * scale).astype(q.dtype)
         dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=_F32)                  # [bk, dh]
+        if dq_acc is not None:
+            _dq_resident_add(dq_acc, ds, k, i, block_q)
 
     @pl.when(i == nq - 1)
     def _emit():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+    if dq_acc is not None:
+        _dq_resident_emit(dq_ref, dq_acc, j, i)
 
 
 def _validate_blocks(s: int, what: str):
@@ -381,6 +443,88 @@ def _resolve_bwd_blocks(q, k, causal: bool, bq: int, bk: int,
     return ((cfg["bq_dq"], cfg["bk_dq"]), (cfg["bq_dkv"], cfg["bk_dkv"]))
 
 
+def _dq_resident(q, dh_p: int, bq: int, bk: int, fit: bool):
+    """Whether the dk/dv kernel also produces dq, and the blocks it then
+    runs at, from ``q.shape`` and the blocks alone: ``(fused, bq, bk)``.
+    It does where one query head's whole dq, the float32 accumulator
+    ``[S, dh_p]`` and the output block in ``q``'s dtype (twice: Pallas
+    double-buffers it), fits ``_DQ_RESIDENT_SHARE`` of the kernels' VMEM
+    limit.  Past that (at bf16 and 128 lanes, S of 64k and more) the dq
+    kernel recomputes the scores for dq alone, at its own pair of
+    blocks.  The score tiles have to fit beside it: where the four
+    float32 tiles of a step (s, p, dP, dS) at the caller's blocks and
+    the resident dq pass the limit together, the blocks halve until they
+    do (``fit``; blocks of 2048, which a caller sized for the forward:
+    measured on the v5e at S=16384, 28 heads of 128, window 4096, the
+    kernel took 63.0 ms at 2048 x 2048 beside 16 MiB of dq and 16.3 at
+    1024 x 1024, where dkv + dq at 2048 took 14.5 + 11.3).  The tuner's
+    ``override_blocks`` go as they are.  The choice is a fact of the
+    traced program, marked once for each traced site (``spans.mark``: on
+    the build's ``compile`` span under a tracer, nothing without
+    one)."""
+    limit = _VMEM_LIMIT_MB * 2 ** 20
+    resident = q.shape[1] * dh_p * (4 + 2 * q.dtype.itemsize)
+    fused = resident <= _DQ_RESIDENT_SHARE * limit
+    while (fused and fit and max(bq, bk) > _LANES
+           and resident + 4 * bq * bk * 4 > limit):
+        bq, bk = max(bq // 2, _LANES), max(bk // 2, _LANES)
+    spans.mark("flash.bwd", fused=fused, dq_resident_bytes=resident,
+               block_q=bq, block_k=bk)
+    return fused, bq, bk
+
+
+def _dq_resident_parts(q, dh_p: int, fused: bool):
+    """What the dk/dv call gains with a head's dq resident, each a list
+    to append (empty where it is not): the output block (the head's
+    whole dq, its index fixed over both block axes so it is written back
+    once a head), its shape, the accumulator."""
+    if not fused:
+        return [], [], []
+    b, s, hq, _ = q.shape
+    return ([pl.BlockSpec((1, s, dh_p), lambda bi, h, *_: (bi, 0, h))],
+            [jax.ShapeDtypeStruct((b, s, hq * dh_p), q.dtype)],
+            [pltpu.VMEM((s, dh_p), _F32)])
+
+
+def _dq_call(qt, kt, vt, dot, lse, dcap, *, scale: float, causal: bool,
+             group: int, block_q: int, block_k: int):
+    """dq from a kernel of its own (grid minor axis = kv blocks), where a
+    head's dq is not resident in the dk/dv kernel (``_dq_resident``)."""
+    b, s, _ = qt.shape
+    hq = lse.shape[1]
+    dh_p, dv_p = qt.shape[2] // hq, dot.shape[2] // hq
+
+    def kv_index(bi, h, i, j):
+        if causal:  # no DMA for fully-masked KV blocks (see _fwd)
+            j = jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
+        return (bi, j, h // group)
+
+    def q_spec(width):
+        return pl.BlockSpec((1, block_q, width),
+                            lambda bi, h, i, j: (bi, i, h),
+                            memory_space=pltpu.VMEM)
+
+    def kv_spec(width):
+        return pl.BlockSpec((1, block_k, width), kv_index,
+                            memory_space=pltpu.VMEM)
+    row_spec = pl.BlockSpec((1, 1, _SUBLANES, block_q),
+                            lambda bi, h, i, j: (bi, h, 0, i),
+                            memory_space=pltpu.VMEM)
+    return pl.pallas_call(
+        functools.partial(_dq_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k),
+        grid=(b, hq, s // block_q, s // block_k),
+        in_specs=[q_spec(dh_p), kv_spec(dh_p), kv_spec(dv_p),
+                  q_spec(dv_p), row_spec, row_spec],
+        out_specs=q_spec(dh_p),
+        out_shape=jax.ShapeDtypeStruct((b, s, hq * dh_p), qt.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, dh_p), _F32)],
+        compiler_params=_compiler_params(),
+        name="flash_bwd_dq",
+        interpret=pallas_common.interpret_mode(),
+    )(qt, kt, vt, dot, lse, dcap)
+
+
 def _bwd_impl(q, k, v, out, lse, do, *, causal: bool,
               block_q: int, block_k: int, override_blocks=None,
               consult_db: bool = True):
@@ -396,6 +540,8 @@ def _bwd_impl(q, k, v, out, lse, do, *, causal: bool,
     scale = 1.0 / (dh ** 0.5)
     dv = v.shape[3]
     dh_p, dv_p = _padded(dh), _padded(dv)
+    fused, bq_dkv, bk_dkv = _dq_resident(q, dh_p, bq_dkv, bk_dkv,
+                                         fit=override_blocks is None)
 
     qt, kt = _to_bsf(q, dh_p), _to_bsf(k, dh_p)
     vt, dot, ot = (_to_bsf(x, dv_p) for x in (v, do, out))
@@ -405,38 +551,6 @@ def _bwd_impl(q, k, v, out, lse, do, *, causal: bool,
                    .reshape(b, s, hq, dv_p), axis=-1)     # [B, S, Hq]
     dcap = jnp.broadcast_to(jnp.swapaxes(dcap, 1, 2)[:, :, None, :],
                             (b, hq, _SUBLANES, s))        # sublane-replicated
-
-    nq, nk = s // bq_dq, s // bk_dq
-
-    def kv_index(bi, h, i, j):
-        if causal:  # no DMA for fully-masked KV blocks (see _fwd)
-            j = jnp.minimum(j, (i * bq_dq + bq_dq - 1) // bk_dq)
-        return (bi, j, h // group)
-
-    def q_spec(width):
-        return pl.BlockSpec((1, bq_dq, width),
-                            lambda bi, h, i, j: (bi, i, h),
-                            memory_space=pltpu.VMEM)
-
-    def kv_spec(width):
-        return pl.BlockSpec((1, bk_dq, width), kv_index,
-                            memory_space=pltpu.VMEM)
-    row_spec = pl.BlockSpec((1, 1, _SUBLANES, bq_dq),
-                            lambda bi, h, i, j: (bi, h, 0, i),
-                            memory_space=pltpu.VMEM)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          block_q=bq_dq, block_k=bk_dq),
-        grid=(b, hq, nq, nk),
-        in_specs=[q_spec(dh_p), kv_spec(dh_p), kv_spec(dv_p),
-                  q_spec(dv_p), row_spec, row_spec],
-        out_specs=q_spec(dh_p),
-        out_shape=jax.ShapeDtypeStruct((b, s, hq * dh_p), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq_dq, dh_p), _F32)],
-        compiler_params=_compiler_params(),
-        name="flash_bwd_dq",
-        interpret=pallas_common.interpret_mode(),
-    )(qt, kt, vt, dot, lse, dcap)
 
     # dk/dv per q-head; inner (minor) axis walks q blocks
     nq_t, nk_t = s // bq_dkv, s // bk_dkv
@@ -464,21 +578,26 @@ def _bwd_impl(q, k, v, out, lse, do, *, causal: bool,
     row_spec_t = pl.BlockSpec((1, 1, _SUBLANES, bq_dkv),
                               lambda bi, h, j, i: (bi, h, 0, qi_index(bi, h, j, i)),
                               memory_space=pltpu.VMEM)
-    dk_h, dv_h = pl.pallas_call(
+    dq_spec, dq_shape, dq_scratch = _dq_resident_parts(q, dh_p, fused)
+    dk_h, dv_h, *dq_res = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           block_q=bq_dkv, block_k=bk_dkv),
         grid=(b, hq, nk_t, nq_t),
         in_specs=[q_spec_t(dh_p), kv_spec_t(dh_p), kv_spec_t(dv_p),
                   q_spec_t(dv_p), row_spec_t, row_spec_t],
-        out_specs=[kv_out_t(dh_p), kv_out_t(dv_p)],
+        out_specs=[kv_out_t(dh_p), kv_out_t(dv_p)] + dq_spec,
         out_shape=[jax.ShapeDtypeStruct((b, s, hq * dh_p), k.dtype),
-                   jax.ShapeDtypeStruct((b, s, hq * dv_p), v.dtype)],
+                   jax.ShapeDtypeStruct((b, s, hq * dv_p), v.dtype)]
+        + dq_shape,
         scratch_shapes=[pltpu.VMEM((bk_dkv, dh_p), _F32),
-                        pltpu.VMEM((bk_dkv, dv_p), _F32)],
-        compiler_params=_compiler_params(),
+                        pltpu.VMEM((bk_dkv, dv_p), _F32)] + dq_scratch,
+        compiler_params=_compiler_params(dq_resident=fused),
         name="flash_bwd_dkv",
         interpret=pallas_common.interpret_mode(),
     )(qt, kt, vt, dot, lse, dcap)
+    dq = dq_res[0] if fused else _dq_call(
+        qt, kt, vt, dot, lse, dcap, scale=scale, causal=causal, group=group,
+        block_q=bq_dq, block_k=bk_dq)
 
     # sum the q-head group into each kv head (GQA): consecutive q heads
     # share a kv head, so the flattened head axis folds as [Hkv, group]
@@ -778,8 +897,9 @@ def _splash_dq_kernel(first_ref, last_ref, lomax_ref, himin_ref,
 
 def _splash_dkv_kernel(firsti_ref, lasti_ref, lomax_ref, himin_ref,
                        q_ref, k_ref, v_ref, do_ref, lse_ref, dcap_ref,
-                       lo_ref, hi_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-                       *, scale: float, block_q: int, block_k: int):
+                       lo_ref, hi_ref, *refs,
+                       scale: float, block_q: int, block_k: int):
+    dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc = _dkv_refs(refs)
     j = pl.program_id(2)      # kv block (outer)
     i = pl.program_id(3)      # q block (inner / minor)
     fi, li = firsti_ref[j], lasti_ref[j]
@@ -788,6 +908,9 @@ def _splash_dkv_kernel(firsti_ref, lasti_ref, lomax_ref, himin_ref,
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    if dq_acc is not None:
+        _dq_resident_init(dq_acc, j, i)
 
     work = (i >= fi) & (i <= li)
     full = ((lomax_ref[i] <= j * block_k)
@@ -810,10 +933,12 @@ def _splash_dkv_kernel(firsti_ref, lasti_ref, lomax_ref, himin_ref,
         dp = jax.lax.dot_general(
             do, v_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=_F32)
-        ds = p * (dp - dcap_ref[0, 0, 0][:, None]) * scale
+        ds = (p * (dp - dcap_ref[0, 0, 0][:, None]) * scale).astype(q.dtype)
         dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=_F32)
+        if dq_acc is not None:
+            _dq_resident_add(dq_acc, ds, k, i, block_q)
 
     pl.when(work & full)(lambda: _step(False))
     pl.when(work & ~full)(lambda: _step(True))
@@ -823,33 +948,18 @@ def _splash_dkv_kernel(firsti_ref, lasti_ref, lomax_ref, himin_ref,
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
+    if dq_acc is not None:
+        _dq_resident_emit(dq_ref, dq_acc, j, i)
 
-def _splash_bwd_impl(q, k, v, out, lse, do, spec, *,
-                     block_q: int, block_k: int, override_blocks=None,
-                     consult_db: bool = True):
-    (bq_dq, bk_dq), (bq_dkv, bk_dkv) = (
-        _checked_override(override_blocks, q.shape[1],
-                          "splash_attention backward override_blocks")
-        if override_blocks is not None
-        else _resolve_splash_bwd_blocks(q, k, spec, block_q, block_k,
-                                        consult_db=consult_db))
-    b, s, hq, dh = q.shape
-    hkv = k.shape[2]
-    group = hq // hkv
-    scale = 1.0 / (dh ** 0.5)
+
+def _splash_dq_call(qt, kt, vt, dot, lse, dcap, spec, *, scale: float,
+                    group: int, block_q: int, block_k: int):
+    """The masked ``_dq_call``: per-q-block visit ranges at ITS block
+    shape."""
+    b, s, _ = qt.shape
+    hq = lse.shape[1]
     dh_p = _LANES
-
-    qt, kt, vt = (_to_bsf(x, dh_p) for x in (q, k, v))
-    dot = _to_bsf(do, dh_p)
-    ot = _to_bsf(out, dh_p)
-    dcap = jnp.sum((dot.astype(_F32) * ot.astype(_F32))
-                   .reshape(b, s, hq, dh_p), axis=-1)
-    dcap = jnp.broadcast_to(jnp.swapaxes(dcap, 1, 2)[:, :, None, :],
-                            (b, hq, _SUBLANES, s))
-
-    # dq kernel: per-q-block visit ranges at ITS block shape
-    bm_dq = amask.block_mask(spec, s, bq_dq, bk_dq)
-    nq, nk = s // bq_dq, s // bk_dq
+    bm_dq = amask.block_mask(spec, s, block_q, block_k)
 
     def kv_index(bi, h, i, j, first_ref, last_ref, *_r):
         j = jnp.clip(j, first_ref[i], last_ref[i])
@@ -866,30 +976,56 @@ def _splash_bwd_impl(q, k, v, out, lse, do, spec, *,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(b, hq, nq, nk),
+        grid=(b, hq, s // block_q, s // block_k),
         in_specs=[
-            pl.BlockSpec((1, bq_dq, dh_p), q_index),
-            pl.BlockSpec((1, bk_dq, dh_p), kv_index),
-            pl.BlockSpec((1, bk_dq, dh_p), kv_index),
-            pl.BlockSpec((1, bq_dq, dh_p), q_index),
-            pl.BlockSpec((1, 1, _SUBLANES, bq_dq), row_index),
-            pl.BlockSpec((1, 1, _SUBLANES, bq_dq), row_index),
-            pl.BlockSpec((_SUBLANES, bq_dq), mrow_index),
-            pl.BlockSpec((_SUBLANES, bq_dq), mrow_index),
+            pl.BlockSpec((1, block_q, dh_p), q_index),
+            pl.BlockSpec((1, block_k, dh_p), kv_index),
+            pl.BlockSpec((1, block_k, dh_p), kv_index),
+            pl.BlockSpec((1, block_q, dh_p), q_index),
+            pl.BlockSpec((1, 1, _SUBLANES, block_q), row_index),
+            pl.BlockSpec((1, 1, _SUBLANES, block_q), row_index),
+            pl.BlockSpec((_SUBLANES, block_q), mrow_index),
+            pl.BlockSpec((_SUBLANES, block_q), mrow_index),
         ],
-        out_specs=pl.BlockSpec((1, bq_dq, dh_p), q_index),
-        scratch_shapes=[pltpu.VMEM((bq_dq, dh_p), _F32)],
+        out_specs=pl.BlockSpec((1, block_q, dh_p), q_index),
+        scratch_shapes=[pltpu.VMEM((block_q, dh_p), _F32)],
     )
-    dq = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_splash_dq_kernel, scale=scale,
-                          block_q=bq_dq, block_k=bk_dq),
+                          block_q=block_q, block_k=block_k),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, s, hq * dh_p), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, s, hq * dh_p), qt.dtype),
         compiler_params=_compiler_params(),
         name="flash_bwd_dq",
         interpret=pallas_common.interpret_mode(),
     )(*_splash_prefetch(bm_dq), qt, kt, vt, dot, lse, dcap,
       _row_i32(bm_dq.lo, s), _row_i32(bm_dq.hi, s))
+
+
+def _splash_bwd_impl(q, k, v, out, lse, do, spec, *,
+                     block_q: int, block_k: int, override_blocks=None,
+                     consult_db: bool = True):
+    (bq_dq, bk_dq), (bq_dkv, bk_dkv) = (
+        _checked_override(override_blocks, q.shape[1],
+                          "splash_attention backward override_blocks")
+        if override_blocks is not None
+        else _resolve_splash_bwd_blocks(q, k, spec, block_q, block_k,
+                                        consult_db=consult_db))
+    b, s, hq, dh = q.shape
+    hkv = k.shape[2]
+    group = hq // hkv
+    scale = 1.0 / (dh ** 0.5)
+    dh_p = _LANES
+    fused, bq_dkv, bk_dkv = _dq_resident(q, dh_p, bq_dkv, bk_dkv,
+                                         fit=override_blocks is None)
+
+    qt, kt, vt = (_to_bsf(x, dh_p) for x in (q, k, v))
+    dot = _to_bsf(do, dh_p)
+    ot = _to_bsf(out, dh_p)
+    dcap = jnp.sum((dot.astype(_F32) * ot.astype(_F32))
+                   .reshape(b, s, hq, dh_p), axis=-1)
+    dcap = jnp.broadcast_to(jnp.swapaxes(dcap, 1, 2)[:, :, None, :],
+                            (b, hq, _SUBLANES, s))
 
     # dk/dv kernel: transposed visit ranges (per-kv-block q range) at
     # its own block shape; the minor grid axis walks q blocks
@@ -914,6 +1050,7 @@ def _splash_bwd_impl(q, k, v, out, lse, do, spec, *,
     def mrow_index_t(bi, h, j, i, firsti_ref, lasti_ref, *_r):
         return (0, i_clamped(j, i, firsti_ref, lasti_ref))
 
+    dq_spec, dq_shape, dq_scratch = _dq_resident_parts(q, dh_p, fused)
     grid_spec_t = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(b, hq, nk_t, nq_t),
@@ -928,23 +1065,27 @@ def _splash_bwd_impl(q, k, v, out, lse, do, spec, *,
             pl.BlockSpec((_SUBLANES, bq_dkv), mrow_index_t),
         ],
         out_specs=[pl.BlockSpec((1, bk_dkv, dh_p), kv_out_t),
-                   pl.BlockSpec((1, bk_dkv, dh_p), kv_out_t)],
+                   pl.BlockSpec((1, bk_dkv, dh_p), kv_out_t)] + dq_spec,
         scratch_shapes=[pltpu.VMEM((bk_dkv, dh_p), _F32),
-                        pltpu.VMEM((bk_dkv, dh_p), _F32)],
+                        pltpu.VMEM((bk_dkv, dh_p), _F32)] + dq_scratch,
     )
-    dk_h, dv_h = pl.pallas_call(
+    dk_h, dv_h, *dq_res = pl.pallas_call(
         functools.partial(_splash_dkv_kernel, scale=scale,
                           block_q=bq_dkv, block_k=bk_dkv),
         grid_spec=grid_spec_t,
         out_shape=[jax.ShapeDtypeStruct((b, s, hq * dh_p), k.dtype),
-                   jax.ShapeDtypeStruct((b, s, hq * dh_p), v.dtype)],
-        compiler_params=_compiler_params(),
+                   jax.ShapeDtypeStruct((b, s, hq * dh_p), v.dtype)]
+        + dq_shape,
+        compiler_params=_compiler_params(dq_resident=fused),
         name="flash_bwd_dkv",
         interpret=pallas_common.interpret_mode(),
     )(jnp.asarray(bm_t.kv_first_q), jnp.asarray(bm_t.kv_last_q),
       jnp.asarray(bm_t.blk_lo_max), jnp.asarray(bm_t.blk_hi_min),
       qt, kt, vt, dot, lse, dcap,
       _row_i32(bm_t.lo, s), _row_i32(bm_t.hi, s))
+    dq = dq_res[0] if fused else _splash_dq_call(
+        qt, kt, vt, dot, lse, dcap, spec, scale=scale, group=group,
+        block_q=bq_dq, block_k=bk_dq)
 
     dk = dk_h.reshape(b, s, hkv, group, dh_p).sum(axis=3)
     dv = dv_h.reshape(b, s, hkv, group, dh_p).sum(axis=3)

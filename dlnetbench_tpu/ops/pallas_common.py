@@ -23,7 +23,7 @@ F32 = jnp.float32
 # Default Mosaic VMEM cap for the matmul-family kernels: raised above
 # the 16 MiB default so 1-2k-wide blocks keep double-buffering headroom
 # on v5e/v5p (128 MiB physical VMEM).  flash_attention uses a tighter
-# 64 MiB cap (its three kernels hold more live blocks per lane).
+# 64 MiB cap (its kernels hold more live blocks per lane).
 DEFAULT_VMEM_LIMIT_MB = 100
 
 

@@ -149,6 +149,11 @@ def _tune_flash(args, direction: str):
     do = jax.random.normal(jax.random.key(3), q.shape, dt)
 
     def measure_cfg(cfg):
+        """The backward at the record's two pairs of blocks.  Where a
+        head's dq is resident in the dk/dv kernel (``fa._dq_resident``:
+        from the shape alone) the dkv pair is that one kernel's and the
+        dq pair is unused; it keeps its meaning, and the record its
+        four keys, for the shapes past the rule."""
         blocks = ((cfg["bq_dq"], cfg["bk_dq"]),
                   (cfg["bq_dkv"], cfg["bk_dkv"]))
         return _chain(lambda *a: fa._bwd_impl(
@@ -205,6 +210,8 @@ def _tune_splash(args, direction: str):
     do = jax.random.normal(jax.random.key(3), q.shape, dt)
 
     def measure_cfg(cfg):
+        """As ``_tune_flash``'s: the dq pair is unused where a head's
+        dq is resident in the dk/dv kernel."""
         blocks = ((cfg["bq_dq"], cfg["bk_dq"]),
                   (cfg["bq_dkv"], cfg["bk_dkv"]))
         return _chain(lambda *a: fa._splash_bwd_impl(

@@ -17,6 +17,7 @@ child.
 from __future__ import annotations
 
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -102,15 +103,16 @@ def test_flash_forward(for_chip):
 
 def test_flash_forward_backward(for_chip):
     fa = ops_module("flash_attention")
-    # forward, dq, dkv
-    assert kernels_in(for_chip(grad_of(fa.flash_attention), *QKV)) == 3
+    # forward, and the dk/dv kernel with a head's dq resident
+    assert kernels_in(for_chip(grad_of(fa.flash_attention), *QKV)) == 2
 
 
 def test_flash_at_latent_attention_widths(for_chip):
     """``kimivl_a3b_train_s8k``'s attention (B=2, S=8192, 16 heads,
-    scores over 128 + 64 lanes, values of 128): the three kernels at the
+    scores over 128 + 64 lanes, values of 128): the two kernels at the
     default blocks; the 192 lie padded to 256 (a block's last dimension
-    is a multiple of the 128-lane tile), the values stay 128 wide."""
+    is a multiple of the 128-lane tile), the values stay 128 wide; dq
+    leaves the dk/dv kernel at the scores' width."""
     import re
 
     from dlnetbench_tpu.metrics import spans
@@ -125,7 +127,7 @@ def test_flash_at_latent_attention_widths(for_chip):
     calls = {re.sub(r"\.\d+$", "", m.group(1)): line
              for line in text.splitlines() if "tpu_custom_call" in line
              and (m := re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", line))}
-    assert sorted(calls) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    assert sorted(calls) == ["flash_bwd_dkv", "flash_fwd"]
     scores, values = "bf16[2,8192,4096]", "bf16[2,8192,2048]"
 
     def outputs(name):
@@ -139,12 +141,12 @@ def test_flash_at_latent_attention_widths(for_chip):
     # q and k at the scores' width, v at its own
     assert (operands("flash_fwd").count(scores),
             operands("flash_fwd").count(values)) == (2, 1)
-    assert scores in outputs("flash_bwd_dkv")        # dk wide ...
-    assert values in outputs("flash_bwd_dkv")        # ... dv narrow
-    assert values not in outputs("flash_bwd_dq")
+    # dk and dq wide, dv narrow
+    assert (outputs("flash_bwd_dkv").count(scores),
+            outputs("flash_bwd_dkv").count(values)) == (2, 1)
     # q, k | v, dO
-    assert (operands("flash_bwd_dq").count(scores),
-            operands("flash_bwd_dq").count(values)) == (2, 2)
+    assert (operands("flash_bwd_dkv").count(scores),
+            operands("flash_bwd_dkv").count(values)) == (2, 2)
 
 
 @pytest.mark.parametrize("mask", ["window", "segments"])
@@ -155,7 +157,59 @@ def test_splash_forward_backward(for_chip, mask):
             else MaskSpec(seg_avg=2048, seg_seed=0))
     text = for_chip(grad_of(lambda q, k, v: fa.splash_attention(
         q, k, v, spec)), *QKV_LONG)
-    assert kernels_in(text) == 3
+    assert kernels_in(text) == 2
+
+
+# the seven cells' attention, as their models call ``ops.attention``:
+# q's shape, key/value heads, value lanes, window, explicit blocks
+CELL_ATTENTION = {
+    "minerva7b_train": ((2, 6144, 32, 128), 8, 128, None, None),
+    "mixtral8x7b_train": ((2, 4096, 32, 128), 8, 128, None, None),
+    "phi4miniflash_train_s8k.window": ((1, 8192, 20, 128), 10, 128, 512,
+                                       512),
+    "phi4miniflash_train_s8k.full": ((1, 8192, 20, 128), 10, 128, None,
+                                     None),
+    "kimivl_a3b_train_s8k": ((2, 8192, 16, 192), 16, 128, None, None),
+    "qwen3next_a3b_train_s16k": ((1, 16384, 16, 256), 2, 256, None, None),
+    "lfm2_8b_a1b_train_s8k": ((1, 8192, 32, 64), 8, 64, None, None),
+    "smallthinker_21b_a3b_train_s16k.window": ((1, 16384, 28, 128), 4, 128,
+                                               4096, 2048),
+    "smallthinker_21b_a3b_train_s16k.full": ((1, 16384, 28, 128), 4, 128,
+                                             None, None),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_ATTENTION))
+def test_dkv_with_a_resident_dq_at_the_cells_shapes(for_chip, cell):
+    """The dk/dv kernel with one query head's dq in VMEM (a float32
+    accumulator ``[S, dh_p]`` and the output block twice: 16 + 16 MiB
+    at Qwen's 16384 x 256, beside the score tiles under the 64 MiB
+    limit) compiles for the chip at every cell's shape, dense and
+    block-sparse: no dq kernel is left, and dq is the dk/dv kernel's
+    third output at q's padded width."""
+    from dlnetbench_tpu import ops
+    from dlnetbench_tpu.metrics import spans
+    from dlnetbench_tpu.ops.attention_mask import MaskSpec
+    (b, s, hq, dh), hkv, dv, window, block = CELL_ATTENTION[cell]
+    mask = MaskSpec(causal=True, window=window) if window else None
+
+    def scoped(q, k, v):        # as the models call it: the kernels'
+        with spans.scope("attn"):       # instructions keep their names
+            return ops.attention(q, k, v, causal=True, impl="flash",
+                                 mask=mask, block_q=block, block_k=block)
+    text = for_chip(grad_of(scoped), ((b, s, hq, dh), BF16),
+                    ((b, s, hkv, dh), BF16), ((b, s, hkv, dv), BF16))
+    calls = {re_sub_number(m.group(1)): line
+             for line in text.splitlines() if "tpu_custom_call" in line
+             and (m := re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", line))}
+    assert sorted(calls) == ["flash_bwd_dkv", "flash_fwd"]
+    dh_p, dv_p = -(-dh // 128) * 128, -(-dv // 128) * 128
+    outputs = calls["flash_bwd_dkv"].partition(" custom-call(")[0]
+    wide, narrow = f"bf16[{b},{s},{hq * dh_p}]", f"bf16[{b},{s},{hq * dv_p}]"
+    # dk and dq at the scores' width, dv at the values'
+    assert outputs.count("bf16[") == 3
+    assert (outputs.count(wide), outputs.count(narrow)) == (
+        (3, 3) if dh_p == dv_p else (2, 1))
 
 
 @pytest.mark.parametrize("fmt", ["int8", "float8"])
@@ -421,7 +475,7 @@ def test_moe_train_step_at_the_cell_shapes_fits_the_chip(for_chip, one_chip):
     mem = step.memory_analysis
     assert mem["argument"] + mem["temp"] < 15.75 * 2 ** 30
     text = step.as_text()
-    assert kernels_in(text) == 6     # three flash, three grouped_mm
+    assert kernels_in(text) == 5     # two flash, three grouped_mm
     assert sum(k.startswith("grouped_mm.")
                for k in kernel_instructions(text)) == 3
     e, f = arch["num_experts"], arch["ff_dim"]
@@ -437,8 +491,9 @@ def test_latent_moe_train_step_at_the_cell_shapes_fits_the_chip(one_chip,
     """``kimivl_a3b_train_s8k``'s whole step (the cell's own files and
     compiler options, as the runner builds it): the depth rule of the
     configuration file, twice the arguments plus the temporaries at or
-    under 13.0 GB by the chip compiler's count; four attention kernels
-    a layer (the forward twice: each layer is recomputed), six
+    under 13.0 GB by the chip compiler's count; three attention kernels
+    a layer (the forward twice: each layer is recomputed; dq from the
+    dk/dv kernel), six
     grouped matmuls an expert layer and the four kernels of its
     counted backward; the step's outputs carry the routing."""
     from benchmarks import harness, weights_latent_moe as weights
@@ -469,7 +524,7 @@ def test_latent_moe_train_step_at_the_cell_shapes_fits_the_chip(one_chip,
     layers = arch["num_layers"]
     experts = weights.expert_layers(arch)
     text = step.as_text()
-    assert kernels_in(text) == 4 * layers + 10 * experts
+    assert kernels_in(text) == 3 * layers + 10 * experts
     names = [re_sub_number(k) for k in kernel_instructions(text)]
     assert names.count("grouped_mm") == 6 * experts == 30
     assert [names.count(k) for k in EXPERTS_BWD] == [experts] * 2 \
@@ -526,7 +581,7 @@ def test_linear_moe_train_step_at_the_cell_shapes_compiles_for_the_chip(
     names = kernel_instructions(text)
     assert sorted(re.sub(r"\.\d+$", "", k) for k in names) == sorted(
         ["gdr_fwd"] * 2 + ["gdr_bwd"] + ["flash_fwd"] * 2
-        + ["flash_bwd_dq", "flash_bwd_dkv"] + ["grouped_mm"] * 12
+        + ["flash_bwd_dkv"] + ["grouped_mm"] * 12
         + [*EXPERTS_BWD, "grouped_mm_bwd_dw"] * 2)
     table = executor.hlo_op_scopes(text)
     loops = [m.group(1) for line in text.splitlines() if " while(" in line
@@ -578,7 +633,7 @@ def test_conv_moe_train_step_at_the_cell_shapes_fits_the_chip(
     names = [re_sub_number(k) for k in kernel_instructions(text)]
     experts = weights.expert_layers(arch)
     assert sorted(names) == sorted(
-        ["flash_fwd"] * 2 + ["flash_bwd_dq", "flash_bwd_dkv"]
+        ["flash_fwd"] * 2 + ["flash_bwd_dkv"]
         + ["grouped_mm"] * 6 * experts
         + [*EXPERTS_BWD, "grouped_mm_bwd_dw"] * experts) and experts == 4
     import re
@@ -634,7 +689,7 @@ def test_swa_moe_train_step_at_the_cell_shapes_fits_the_chip(
     names = [re_sub_number(k) for k in kernel_instructions(text)]
     layers = arch["num_layers"]
     assert sorted(names) == sorted(
-        (["flash_fwd"] * 2 + ["flash_bwd_dq", "flash_bwd_dkv"]
+        (["flash_fwd"] * 2 + ["flash_bwd_dkv"]
          + ["grouped_mm"] * 6 + [*EXPERTS_BWD, "grouped_mm_bwd_dw"])
         * layers) and layers == 8
     table = executor.hlo_op_scopes(text)
@@ -644,7 +699,7 @@ def test_swa_moe_train_step_at_the_cell_shapes_fits_the_chip(
                                    "flash_bwd_dkv"):
             by_scope.setdefault(scope, []).append(re_sub_number(inst))
     assert {k: len(v) for k, v in by_scope.items()} \
-        == {"attn.window": 6 * 4, "attn.full": 2 * 4}
+        == {"attn.window": 6 * 3, "attn.full": 2 * 3}
     assert {"attn", "moe.router", "moe.dispatch", "moe.experts",
             "moe.combine", "head_loss"} <= set(table.values())
 
@@ -726,12 +781,13 @@ def test_gated_delta_rule_head_groups_fill_lane_tiles(for_chip, monkeypatch,
 
 def test_flash_at_gated_attention_widths(for_chip):
     """16 query heads over 2 key/value heads of 256 lanes at S=16384,
-    twice the longest sequence another cell runs: forward, dq, dkv."""
+    twice the longest sequence another cell runs: forward, and dkv with
+    a head's dq resident, 16 + 16 MiB of it."""
     from dlnetbench_tpu import ops
     text = for_chip(grad_of(lambda q, k, v: ops.attention(
         q, k, v, causal=True, impl="flash")), ((1, 16384, 16, 256), BF16),
         ((1, 16384, 2, 256), BF16), ((1, 16384, 2, 256), BF16))
-    assert kernels_in(text) == 3
+    assert kernels_in(text) == 2
 
 
 def hlo_computations(text: str) -> dict:
@@ -841,7 +897,7 @@ def test_selective_scan_forward_backward_at_the_cell_shapes(for_chip):
 
 def test_differential_window_attention_at_the_cell_shapes(for_chip):
     """Window-512 attention over pairs of 64-wide heads padded to the
-    value's 128, in blocks of 512: forward, dq, dkv."""
+    value's 128, in blocks of 512: forward, and dkv with dq."""
     from dlnetbench_tpu import ops
     from dlnetbench_tpu.ops.attention_mask import MaskSpec
     spec = MaskSpec(window=512)
@@ -849,7 +905,7 @@ def test_differential_window_attention_at_the_cell_shapes(for_chip):
         q, k, v, causal=True, impl="flash", mask=spec, block_q=512,
         block_k=512)), ((1, 8192, 20, 128), BF16),
         ((1, 8192, 10, 128), BF16), ((1, 8192, 10, 128), BF16))
-    assert kernels_in(text) == 3
+    assert kernels_in(text) == 2
 
 
 def test_kernels_carry_their_given_names_on_the_chip(for_chip):
@@ -869,7 +925,7 @@ def test_kernels_carry_their_given_names_on_the_chip(for_chip):
             return fa.flash_attention(q, k, v)
     flash = kernel_instructions(for_chip(grad_of(scoped), *QKV))
     assert sorted(re.sub(r"\.\d+$", "", n) for n in flash) == \
-        ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+        ["flash_bwd_dkv", "flash_fwd"]
     grouped = kernel_instructions(for_chip(
         lambda x, w, n: gm.grouped_matmul(x, w, counts=n),
         ((8, 2048, D), BF16), ((8, D, F), BF16), ((8,), I32)))
@@ -877,9 +933,11 @@ def test_kernels_carry_their_given_names_on_the_chip(for_chip):
 
 
 def test_op_scopes_of_a_program_compiled_for_the_chip(for_chip):
-    """The table made from the chip compiler's text: the three flash
-    kernels and the projection's fusion, forward and backward, under the
-    scope the function wore; nothing of it under another."""
+    """The table made from the chip compiler's text: the two flash
+    kernels (a step holds no ``flash_bwd_dq`` where a head's dq is
+    resident in the dk/dv kernel) and the projection's fusion, forward
+    and backward, under the scope the function wore; nothing of it
+    under another."""
     from dlnetbench_tpu.core import executor
     from dlnetbench_tpu.metrics import spans
     fa = ops_module("flash_attention")
@@ -893,7 +951,9 @@ def test_op_scopes_of_a_program_compiled_for_the_chip(for_chip):
                     ((HQ * DH, D), BF16))
     table = executor.hlo_op_scopes(text)
     kernels = kernel_instructions(text)
-    assert len(kernels) == 3 and {table[k] for k in kernels} == {"attn"}
+    assert sorted(re_sub_number(k) for k in kernels) == \
+        ["flash_bwd_dkv", "flash_fwd"]
+    assert {table[k] for k in kernels} == {"attn"}
     entry = text[text.index("ENTRY"):]
     fusions = [m.group(1) for line in entry.splitlines()
                if " fusion(" in line

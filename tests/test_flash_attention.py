@@ -6,6 +6,9 @@ The einsum implementation is the ground truth; tolerances are fp32-tight.
 """
 from __future__ import annotations
 
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -263,3 +266,231 @@ def test_fit_block_refuses_sub_lane_grid_on_long_dim():
         pallas_common.fit_block(64 * 1024 + 1, 2048)
     # short dims keep the soft degradation
     assert pallas_common.fit_block(100, 64) == 4
+
+
+# ------------------------------------------------ a head's dq resident
+# The dk/dv kernel also produces dq where one query head's dq fits its
+# share of the VMEM limit (``_dq_resident``); the dq kernel stays for
+# the sizes past it.  Both paths on the same inputs: the rule's share
+# set to nothing is the two-kernel path, as a size past the rule takes
+# it.
+
+import importlib  # noqa: E402
+
+from dlnetbench_tpu.metrics import spans  # noqa: E402
+
+fa = importlib.import_module("dlnetbench_tpu.ops.flash_attention")
+
+# tests/test_attention_mask.py's SPECS[1], [2] and [3]: a window, a
+# causal document mask, a document mask that looks both ways
+WINDOW = am.MaskSpec(causal=True, window=24)
+DOCS = am.MaskSpec(causal=True, seg_avg=20, seg_seed=3)
+DOCS_BOTH_WAYS = am.MaskSpec(causal=False, seg_avg=16, seg_seed=1)
+
+RESIDENT_CASES = {
+    # id: (b, s, hq, hkv, dqk, dv, causal or a MaskSpec,
+    #      the dq kernel's (bq, bk), the dk/dv kernel's (bq, bk))
+    "causal": (2, 256, 2, 2, 128, 128, True, (64, 64), (64, 64)),
+    "non_causal": (1, 256, 2, 2, 128, 128, False, (64, 64), (64, 64)),
+    "window": (2, 256, 2, 1, 128, 128, WINDOW, (64, 64), (64, 64)),
+    "documents": (1, 256, 2, 2, 128, 128, DOCS, (64, 64), (64, 64)),
+    "documents_both_ways": (1, 256, 2, 2, 128, 128, DOCS_BOTH_WAYS,
+                            (64, 64), (64, 64)),
+    "28_over_4": (1, 128, 28, 4, 128, 128, True, (64, 64), (64, 64)),
+    "14_over_2_window": (1, 128, 14, 2, 128, 128, WINDOW,
+                         (64, 64), (64, 64)),
+    "dh64_padded": (1, 256, 4, 1, 64, 64, True, (128, 128), (128, 128)),
+    "dh64_padded_window": (1, 256, 4, 2, 64, 64, WINDOW,
+                           (64, 64), (64, 64)),
+    "scores192_values128": (1, 256, 4, 4, 192, 128, True,
+                            (128, 128), (128, 128)),
+    "scores192_values128_grouped": (1, 256, 4, 2, 192, 128, False,
+                                    (64, 64), (64, 64)),
+    "bq_over_bk": (1, 256, 2, 2, 128, 128, True, (128, 64), (128, 64)),
+    "bk_over_bq": (1, 256, 2, 1, 128, 128, True, (64, 128), (64, 128)),
+    "bk_over_bq_documents": (1, 256, 2, 2, 128, 128, DOCS,
+                             (64, 128), (64, 128)),
+    # the two kernels at different pairs: the sums' order differs
+    "other_blocks": (1, 256, 2, 2, 128, 128, True, (128, 128), (64, 128)),
+    "other_blocks_window": (1, 256, 2, 2, 128, 128, WINDOW,
+                            (128, 64), (64, 64)),
+}
+
+
+def _backward(case, monkeypatch=None):
+    """dq, dk, dv of ``_bwd_impl`` / ``_splash_bwd_impl`` on the case's
+    seeded inputs and the ``flash.bwd`` marks of the trace; with
+    ``monkeypatch`` the rule's share of the VMEM limit is nothing."""
+    b, s, hq, hkv, dqk, dv, mask, dq_blocks, dkv_blocks = case
+    kq, kk, kv, kd = jax.random.split(jax.random.key(21), 4)
+    q = jax.random.normal(kq, (b, s, hq, dqk), jnp.float32)
+    k = jax.random.normal(kk, (b, s, hkv, dqk), jnp.float32)
+    v = jax.random.normal(kv, (b, s, hkv, dv), jnp.float32)
+    do = jax.random.normal(kd, (b, s, hq, dv), jnp.float32)
+    bq, bk = dkv_blocks
+    kw = dict(block_q=bq, block_k=bk)
+    if isinstance(mask, bool):
+        out, lse = fa._fwd(q, k, v, causal=mask, **kw)
+        run = functools.partial(fa._bwd_impl, causal=mask)
+    else:
+        out, lse = fa._splash_fwd(q, k, v, mask, **kw)
+        run = lambda *a, **o: fa._splash_bwd_impl(*a, mask, **o)  # noqa: E731
+    if monkeypatch is not None:
+        monkeypatch.setattr(fa, "_DQ_RESIDENT_SHARE", 0.0)
+    tracer = spans.enable()
+    try:
+        grads = run(q, k, v, out, lse, do, **kw,
+                    override_blocks=(dq_blocks, dkv_blocks))
+    finally:
+        spans.disable()
+    marks = [s_["attrs"] for s_ in tracer.export()["spans"]
+             if s_["name"] == "flash.bwd"]
+    return grads, marks, (q, k, v, do, mask)
+
+
+@longcontext
+@pytest.mark.parametrize("name", sorted(RESIDENT_CASES))
+def test_resident_dq_matches_the_two_kernels(name, monkeypatch):
+    """The dk/dv kernel with a head's dq resident against dkv + dq: dq,
+    dk and dv bit-equal where both run the same blocks (key blocks
+    arrive in the dq kernel's order), else within this file's
+    tolerance; each path marks itself once."""
+    case = RESIDENT_CASES[name]
+    b, s, hq, hkv, dqk, dv, mask, dq_blocks, dkv_blocks = case
+    one, one_marks, _ = _backward(case)
+    two, two_marks, _ = _backward(case, monkeypatch)
+    mark = {"dq_resident_bytes": s * fa._padded(dqk) * (4 + 2 * 4),
+            "block_q": dkv_blocks[0], "block_k": dkv_blocks[1]}
+    assert one_marks == [{"fused": True, **mark}]
+    assert two_marks == [{"fused": False, **mark}]
+    for a, c, what in zip(one, two, ("dq", "dk", "dv")):
+        assert a.shape == c.shape and a.dtype == c.dtype, what
+        if dq_blocks == dkv_blocks or what != "dq":
+            assert jnp.array_equal(a, c), what
+        else:
+            assert jnp.max(jnp.abs(a - c)) < 1e-4, what
+
+
+@longcontext
+@pytest.mark.parametrize("name", ["causal", "window", "documents_both_ways",
+                                  "scores192_values128", "bk_over_bq"])
+def test_resident_dq_matches_reference(name):
+    """And against the einsum reference under the same mask."""
+    (dq, dk, dv), _, (q, k, v, do, mask) = _backward(RESIDENT_CASES[name])
+
+    def ref(q, k, v):
+        if isinstance(mask, bool):
+            return xla_attention(q, k, v, causal=mask)
+        return _masked_ref(q, k, v, mask)
+    _, vjp = jax.vjp(ref, q, k, v)
+    for a, c in zip(vjp(do), (dq, dk, dv)):
+        assert jnp.max(jnp.abs(a - c)) < 5e-4
+
+
+# q's shape and dtype at the seven cells' attention layers (B, S, Hq, dh)
+CELL_Q = {
+    "minerva7b_train": ((2, 6144, 32, 128), 3 << 20),
+    "mixtral8x7b_train": ((2, 4096, 32, 128), 2 << 20),
+    "phi4miniflash_train_s8k": ((1, 8192, 20, 128), 4 << 20),
+    "kimivl_a3b_train_s8k": ((2, 8192, 16, 192), 8 << 20),
+    "qwen3next_a3b_train_s16k": ((1, 16384, 16, 256), 16 << 20),
+    "lfm2_8b_a1b_train_s8k": ((1, 8192, 32, 64), 4 << 20),
+    "smallthinker_21b_a3b_train_s16k": ((1, 16384, 28, 128), 8 << 20),
+}
+
+
+def _rule(shape, dtype=jnp.bfloat16, blocks=(1024, 1024), fit=True):
+    """``_dq_resident`` at a shape, with what it marked: (fused, the
+    dk/dv kernel's blocks, the resident bytes)."""
+    q = jax.ShapeDtypeStruct(shape, dtype)
+    tracer = spans.enable()
+    try:
+        fused, bq, bk = fa._dq_resident(q, fa._padded(shape[3]), *blocks,
+                                        fit=fit)
+    finally:
+        spans.disable()
+    mark, = [s["attrs"] for s in tracer.export()["spans"]]
+    assert (mark["fused"], mark["block_q"], mark["block_k"]) == (fused, bq,
+                                                                 bk)
+    return fused, (bq, bk), mark["dq_resident_bytes"]
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_Q))
+def test_a_head_of_dq_is_resident_at_every_cells_shape(cell):
+    """The float32 accumulator and the bf16 output block twice: each
+    half of what the rule counts, all seven under half of 64 MiB
+    (Qwen's 16 + 16 MiB is the rule's edge), at the backward's default
+    blocks as they are."""
+    shape, half = CELL_Q[cell]
+    assert _rule(shape) == (True, (1024, 1024), 2 * half)
+
+
+@pytest.mark.parametrize("shape,dtype,fused", [
+    ((1, 32768, 8, 128), jnp.bfloat16, True),       # 16 + 16 MiB
+    ((1, 65536, 8, 128), jnp.bfloat16, False),      # 32 + 32
+    ((1, 131072, 8, 64), jnp.bfloat16, False),
+    ((1, 32768, 8, 256), jnp.bfloat16, False),
+    ((1, 16384, 8, 256), jnp.float32, False),       # 16 + 2 x 16
+])
+def test_past_the_vmem_rule_the_two_kernels_stay(shape, dtype, fused):
+    """From ``q.shape`` and its dtype alone; the long-context paths
+    (a head's dq of 32 MB and more) keep dkv + dq, and the traced
+    program holds the dq kernel there."""
+    assert _rule(shape, dtype)[:2] == (fused, (1024, 1024))
+    if shape[1] > 32768:
+        return      # tracing a 64k-long grid is the slow part, once is enough
+    b, s, hq, dh = shape
+    args = [jax.ShapeDtypeStruct(x, dtype) for x in (
+        shape, shape, shape, shape)] + [
+        jax.ShapeDtypeStruct((b, hq, 8, s), jnp.float32),
+        jax.ShapeDtypeStruct(shape, dtype)]
+    text = str(jax.make_jaxpr(functools.partial(
+        fa._bwd_impl, causal=True, block_q=1024, block_k=1024,
+        consult_db=False))(*args))
+    assert ("flash_bwd_dq" in text) == (not fused)
+    assert "flash_bwd_dkv" in text
+
+
+@pytest.mark.parametrize("shape,blocks,fit,want", [
+    # SmallThinker's window layers: the model's 2048 x 2048 (64 MiB of
+    # float32 score tiles) beside 16 MiB of dq pass the 64 MiB limit
+    ((1, 16384, 28, 128), (2048, 2048), True, (1024, 1024)),
+    ((1, 16384, 28, 128), (2048, 1024), True, (2048, 1024)),   # 32 + 16
+    ((1, 16384, 28, 128), (2048, 2048), False, (2048, 2048)),  # the tuner's
+    ((2, 4096, 32, 128), (2048, 2048), True, (1024, 1024)),    # 64 + 4
+    ((1, 16384, 16, 256), (1024, 1024), True, (1024, 1024)),   # 16 + 32
+    ((1, 32768, 8, 128), (2048, 1024), True, (2048, 1024)),    # 32 + 32
+])
+def test_blocks_halve_until_the_score_tiles_fit_beside_dq(shape, blocks, fit,
+                                                          want):
+    assert _rule(shape, blocks=blocks, fit=fit)[:2] == (True, want)
+
+
+def test_past_the_rule_the_callers_blocks_stand():
+    assert _rule((1, 65536, 8, 128), blocks=(2048, 2048))[:2] == \
+        (False, (2048, 2048))
+
+
+def test_the_window_layers_backward_runs_at_the_fitted_blocks():
+    """``smallthinker_21b_a3b_train_s16k``'s window layers hand
+    ``ops.attention`` blocks of 2048 for both directions: the forward
+    and the backward's grid as traced."""
+    from dlnetbench_tpu import ops
+    shape, kv = (1, 16384, 28, 128), (1, 16384, 4, 128)
+    spec = am.MaskSpec(causal=True, window=4096)
+    text = str(jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(ops.attention(
+        q, k, v, causal=True, impl="flash", mask=spec, block_q=2048,
+        block_k=2048).astype(jnp.float32)), argnums=(0, 1, 2)))(
+        *[jax.ShapeDtypeStruct(x, jnp.bfloat16) for x in (shape, kv, kv)]))
+    grids = {name: grid for grid, name in re.findall(
+        r"grid=\((.*?)\).*?name=(flash_\w+)", text, re.S)}
+    assert grids == {"flash_fwd": "1, 28, 8, 8",
+                     "flash_bwd_dkv": "1, 28, 16, 16"}
+
+
+def test_resident_dq_without_a_tracer_marks_nothing():
+    assert not spans.is_enabled()
+    assert fa._dq_resident(jax.ShapeDtypeStruct((1, 256, 2, 128),
+                                                jnp.float32), 128, 64, 64,
+                           fit=True) == (True, 64, 64)
+    assert spans.current() is None

@@ -5,12 +5,16 @@ forward and backward of a layer together; the rule for fusions; the
 table is made when asked or when a tracer is on, never otherwise."""
 from __future__ import annotations
 
+import importlib
+import json
 import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from benchmarks import harness, scope_dump
+from benchmarks.readers import scope_ms
 from dlnetbench_tpu.core import executor
 from dlnetbench_tpu.core.model_card import ModelCard, MoEParams
 from dlnetbench_tpu.metrics import spans
@@ -396,3 +400,64 @@ def test_scope_refuses_a_name_outside_the_vocabulary():
         spans.scope("attention")
     assert len(set(spans.SCOPES)) == len(spans.SCOPES) == 19
     assert spans.OTHER_SCOPE not in spans.SCOPES
+
+
+# ---------------------------------------- a step with no dq kernel
+# Where a head's dq is resident in the dk/dv kernel (PR 43) a step holds
+# no ``flash_bwd_dq`` instruction.  The benchmark's readers and fixtures
+# (``benchmarks/scope_fixture_*.json``) stay as they are; the same
+# step without that kernel is derived here.
+
+def read(metric, c):
+    s = harness.load_json(harness.HERE / "layer_metrics" / f"{metric}.json")
+    return importlib.import_module(
+        f"benchmarks.readers.{s['reader']}").read(c, s["params"])
+
+
+ATTENTION_FIXTURES = {
+    # fixture: (its attention scopes, its roofline metrics)
+    "scope_fixture_latent_moe.json": (("attn",), ("mla_flash_roofline",)),
+    "scope_fixture_linear_moe.json": (("attn",), ("gated_flash_roofline",)),
+    "scope_fixture_conv_moe.json": (("attn",), ("gqa64_flash_roofline",)),
+    "scope_fixture_swa_moe.json": (("attn.window", "attn.full"),
+                                   ("swa_window_roofline",
+                                    "swa_full_roofline")),
+}
+
+
+def fixture_ctx(fix, keep=lambda inst: True):
+    """A reader's ``ctx`` on a cell's fixture, of the instructions that
+    ``keep`` takes, in the trace and in the program's table alike."""
+    program_trace = json.loads(json.dumps(fix["program_trace"]))
+    for name, table in program_trace["op_scopes"].items():
+        program_trace["op_scopes"][name] = {
+            inst: scope for inst, scope in table.items() if keep(inst)}
+    ops = [tuple(e) for e in fix["ops"]
+           if keep(scope_ms.instruction(e[0]) or "")]
+    return {"record": {**json.loads(json.dumps(fix["record"])),
+                       "program_trace": program_trace},
+            "devices": [{"ops": ops,
+                         "modules": [tuple(e) for e in fix["modules"]]}],
+            "window": tuple(fix["window"]), "peaks": fix["peaks"]}
+
+
+@pytest.mark.parametrize("name", sorted(ATTENTION_FIXTURES))
+def test_a_step_with_no_dq_kernel_keeps_its_attention_time_under_attn(name):
+    fix = harness.load_json(harness.HERE / name)
+    scopes, rooflines = ATTENTION_FIXTURES[name]
+    whole = fixture_ctx(fix)
+    fused = fixture_ctx(fix, lambda inst: not inst.startswith("flash_bwd_dq"))
+    assert len(fused["devices"][0]["ops"]) < len(whole["devices"][0]["ops"])
+    before, after = scope_dump.by_scope(whole), scope_dump.by_scope(fused)
+    assert "unknown" not in after and set(after) == set(before)
+    dq = {e[0] for e in whole["devices"][0]["ops"]} \
+        - {e[0] for e in fused["devices"][0]["ops"]}
+    dq_ms = scope_dump.by_op(whole, lambda n: n in dq)[True]
+    # the attention scopes lose the dq kernel's time, the others nothing
+    assert sum(before[s] - after[s] for s in scopes) == pytest.approx(dq_ms)
+    assert all(before[s] - after[s] > 0 for s in scopes)
+    assert {s: after[s] for s in after if s not in scopes} == \
+        {s: pytest.approx(before[s]) for s in before if s not in scopes}
+    # the same work over less time: every share rises, none past 100 %
+    for metric in rooflines:
+        assert read(metric, whole) < read(metric, fused) < 100.0

@@ -734,6 +734,85 @@ def test_flash_bwd_override_blocks_must_divide_the_sequence(kernel):
         assert jnp.allclose(a, b, atol=1e-4, rtol=1e-4)
 
 
+@pytest.mark.parametrize("kernel", ["flash", "splash"])
+def test_flash_bwd_dq_pair_is_unused_where_dq_is_resident(kernel,
+                                                          monkeypatch):
+    """``override_blocks`` and the records keep their two pairs.  Where
+    a head's dq is resident in the dk/dv kernel the dq pair changes
+    nothing, not a bit and not an instruction; past the rule (its share
+    set to nothing here) the dq kernel runs at that pair."""
+    import functools
+    import importlib
+    fa = importlib.import_module("dlnetbench_tpu.ops.flash_attention")
+    from dlnetbench_tpu.ops.attention_mask import MaskSpec
+
+    q = jax.random.normal(jax.random.key(0), (1, 256, 2, 128),
+                          jnp.float32)
+    if kernel == "flash":
+        out, lse = fa._fwd(q, q, q, causal=True, block_q=128, block_k=128)
+        bwd = functools.partial(fa._bwd_impl, q, q, q, out, lse, q,
+                                causal=True, block_q=128, block_k=128)
+    else:
+        spec = MaskSpec(causal=True, window=64)
+        out, lse = fa._splash_fwd(q, q, q, spec, block_q=128, block_k=128)
+        bwd = functools.partial(fa._splash_bwd_impl, q, q, q, out, lse, q,
+                                spec, block_q=128, block_k=128)
+
+    def program(dq_pair):
+        return str(jax.make_jaxpr(lambda: bwd(
+            override_blocks=(dq_pair, (128, 64))))())
+    want = bwd(override_blocks=((128, 64), (128, 64)))
+    got = bwd(override_blocks=((64, 128), (128, 64)))
+    assert all(jnp.array_equal(a, b) for a, b in zip(got, want))
+    assert program((64, 128)) == program((128, 64))
+    assert "flash_bwd_dq" not in program((64, 128))
+    monkeypatch.setattr(fa, "_DQ_RESIDENT_SHARE", 0.0)
+    assert "flash_bwd_dq" in program((64, 128))
+    assert program((64, 128)) != program((128, 64))
+    two = bwd(override_blocks=((64, 128), (128, 64)))
+    assert all(jnp.allclose(a, b, atol=1e-4, rtol=1e-4)
+               for a, b in zip(two, want))
+
+
+@pytest.mark.parametrize("op", ["flash_bwd", "splash_bwd"])
+def test_tune_cli_backward_records_keep_their_four_keys(op, monkeypatch,
+                                                        tmp_path, capsys):
+    """The tuner's backward search still measures and commits
+    ``bq_dq``, ``bk_dq``, ``bq_dkv``, ``bk_dkv``, and the real site
+    reads the record back as its two pairs."""
+    import importlib
+
+    from dlnetbench_tpu.ops.attention_mask import MaskSpec
+    from dlnetbench_tpu.tuning.__main__ import main as tuning_main
+
+    fa = importlib.import_module("dlnetbench_tpu.ops.flash_attention")
+    root = tmp_path / "tdb"
+    rc = tuning_main([
+        "tune", "--op", op, "--db", str(root), "--batch", "1",
+        "--seq", "256", "--heads", "2", "--kv_heads", "2",
+        "--head_dim", "128", "--dtype", "float32", "--window", "64",
+        "--candidates", "64,128,128,64;128,128,128,128",
+        "--rounds", "1", "-k", "1",
+    ])
+    assert rc == 0
+    committed = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert committed["op"] == op
+    assert set(committed["config"]) == {"bq_dq", "bk_dq", "bq_dkv",
+                                        "bk_dkv"}
+    monkeypatch.setenv(tuning.ENV_DB_DIR, str(root))
+    tuning.reset()
+    q = jax.random.normal(jax.random.key(0), (1, 256, 2, 128),
+                          jnp.float32)
+    cfg = committed["config"]
+    pairs = ((cfg["bq_dq"], cfg["bk_dq"]), (cfg["bq_dkv"], cfg["bk_dkv"]))
+    if op == "flash_bwd":
+        assert fa._resolve_bwd_blocks(q, q, True, 256, 256) == pairs
+    else:
+        assert fa._resolve_splash_bwd_blocks(
+            q, q, MaskSpec(causal=True, window=64), 256, 256) == pairs
+    assert tuning.provenance()["hits"] == 1
+
+
 def test_flash_bwd_db_consulted_for_default_call(monkeypatch, tmp_path):
     import importlib
     fa = importlib.import_module("dlnetbench_tpu.ops.flash_attention")
