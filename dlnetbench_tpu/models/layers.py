@@ -17,6 +17,11 @@ import jax.numpy as jnp
 from dlnetbench_tpu.metrics.spans import mark, scope
 from dlnetbench_tpu.ops import fp8 as qf8
 from dlnetbench_tpu.ops import int8 as q8
+from dlnetbench_tpu.ops.grouped_matmul import (
+    owner_of,
+    packed_first,
+    packed_rows,
+)
 
 _F32 = jnp.float32
 
@@ -201,6 +206,22 @@ def moe_dense(x2d, w_router, w_gate, w_up, w_down, top_k: int):
         return jnp.einsum("ted,te->td", y, combine).astype(x2d.dtype)
 
 
+class PackedRows(NamedTuple):
+    """A packed layout of a plan's expert rows (``MoePlan.packed``):
+    one buffer of R rows (``packed_room``) in which expert e's kept
+    rows lie in arrival order from row ``start[e]`` on, the next
+    row-block boundary past the expert before it.
+
+    ``src`` [1, R] int32: the token in each row, T in an empty one
+    (past an expert's count within its last block, or past the live
+    prefix); one group of R rows, which is how the kernels see the
+    buffer (``grouped_matmul``'s packed form).
+    ``start`` [E] int32 (``grouped_matmul.packed_first`` in rows, which
+    the kernels read off the same counts)."""
+    src: jax.Array
+    start: jax.Array
+
+
 class MoePlan(NamedTuple):
     """A routing as indices: what dispatch, combine and their backward
     passes move rows through.
@@ -217,10 +238,36 @@ class MoePlan(NamedTuple):
     tokens (combine, the dispatch's transpose, the gate's gradient) go
     by whichever side is the smaller, which ``_plan_side`` reads off
     these shapes: the k * T (token, choice) pairs through ``idx`` and
-    ``slot``, or the E * C slots through ``src`` again."""
+    ``slot``, or the E * C slots through ``src`` again.
+
+    And two layouts of the experts' rows.  Padded, ``[E, C, d]``: room
+    for C rows of every expert; what a capacity fills (``moe_grouped``,
+    the SPMD step) and what a bound smaller than the pairs leaves
+    (Kimi, Qwen: the slot side).  Packed (``packed`` set, by
+    ``moe_dispatch_held`` alone), ``[1, R, d]``: where C is a bound so
+    loose that E * C exceeds what all the pairs can fill
+    (``packed_room``; LFM2, SmallThinker), every pass above goes
+    through ``packed`` instead, the same rows in the same order from
+    the same ``slot`` and ``src``, which stay as they are (the pair
+    side reads ``slot``; ``packed.src`` is gathered from ``src``)."""
     slot: jax.Array
     src: jax.Array
     idx: jax.Array
+    packed: PackedRows | None = None
+
+
+def packed_room(pairs: int, e: int, c: int, row_block: int | None):
+    """R, the rows of the packed buffer that takes the place of
+    ``[E, C]`` slots, or None where the layout stays padded: a fact of
+    static shapes.  ``row_block`` is the kernels' (None: a caller that
+    has no kernels to lay rows out for); all E experts together hold at
+    most the ``pairs`` rows of the routing, each from a row-block
+    boundary, so R = ``packed_rows`` always suffices, and the layer
+    packs where that is less than the E * C the bound reserves."""
+    if row_block is None or c % row_block:
+        return None
+    room = packed_rows(pairs, e, row_block)
+    return room if room < e * c else None
 
 
 # The slot side sums the first quarter, half or all of the slots, the
@@ -246,11 +293,16 @@ def _plan_side(plan: MoePlan, site: str) -> str:
     capacity factor of one or more makes the buffers the larger side.
     The choice is a fact of the traced program, so it is marked once
     for each traced site (``spans.mark``: on the build's ``compile``
-    span under a tracer, nothing without one)."""
+    span under a tracer, nothing without one), with the plan's
+    ``layout`` (``padded`` | ``packed``) and the ``room`` its buffer
+    has, E * C or R rows; a packed plan is always on the pair side
+    (R holds every pair, and it packs only where E * C exceeds R)."""
     (t, e), c, k = plan.slot.shape, plan.src.shape[1], plan.idx.shape[1]
     side = "slots" if e * c < k * t else "pairs"
     mark("moe.plan_side", site=site, side=side, pairs=k * t,
-         rows=e * c if side == "slots" else k * t)
+         rows=e * c if side == "slots" else k * t,
+         layout="padded" if plan.packed is None else "packed",
+         room=e * c if plan.packed is None else plan.packed.src.shape[1])
     return side
 
 
@@ -279,10 +331,12 @@ def moe_plan(idx, pos, keep, cap: int) -> MoePlan:
 
 
 def _to_slots(x, plan: MoePlan, w=None):
-    """Token rows [T, d] into the experts' buffers [E, C, d]: slot
-    (e, c) gets the row of token ``src[e, c]``, times ``w[token, e]``
-    where ``w`` [T, E] is given; an empty slot gets zeros."""
-    xe = jnp.take(x, plan.src, axis=0, mode="fill", fill_value=0)
+    """Token rows [T, d] into the experts' buffers [E, C, d] ([1, R, d]
+    where the plan is packed): slot (e, c) gets the row of token
+    ``src[e, c]``, times ``w[token, e]`` where ``w`` [T, E] is given;
+    an empty slot gets zeros."""
+    src = plan.src if plan.packed is None else plan.packed.src
+    xe = jnp.take(x, src, axis=0, mode="fill", fill_value=0)
     if w is None:
         return xe
     ws = _slot_weights(w, plan)
@@ -290,10 +344,16 @@ def _to_slots(x, plan: MoePlan, w=None):
 
 
 def _slot_weights(w, plan: MoePlan):
-    """``w`` [T, E] at each slot's (token, expert) -> [E, C]; zero in
-    an empty slot."""
-    return jnp.take_along_axis(w.T, plan.src, axis=1, mode="fill",
-                               fill_value=0)
+    """``w`` [T, E] at each slot's (token, expert) -> [E, C] ([1, R]
+    where the plan is packed); zero in an empty slot."""
+    if plan.packed is None:
+        return jnp.take_along_axis(w.T, plan.src, axis=1, mode="fill",
+                                   fill_value=0)
+    src, start = plan.packed
+    owner = owner_of(jnp.arange(src.shape[1], dtype=jnp.int32), start[1:])
+    # an empty row names token T: past the last weight, so the fill
+    return jnp.take(w.reshape(-1), src * w.shape[1] + owner, mode="fill",
+                    fill_value=0)
 
 
 def _chosen(plan: MoePlan, e: int):
@@ -310,22 +370,28 @@ def _of_choice(a, plan: MoePlan):
 
 def _from_slots(out, plan: MoePlan):
     """The rows [k, T, d] that a token's k experts hold for it in
-    ``out`` [E, C, d]; zeros where the token was dropped.  k leads so
-    that the gathered [k * T, d] rows need no copy to be seen as that:
-    a [T, k, d] view is another tiling on the TPU."""
-    e, c, d = out.shape
+    ``out`` [E, C, d] ([1, R, d] where the plan is packed); zeros where
+    the token was dropped.  k leads so that the gathered [k * T, d]
+    rows need no copy to be seen as that: a [T, k, d] view is another
+    tiling on the TPU."""
     row = _slot_of_choice(plan)
-    return jnp.take(out.reshape(e * c, d), row.T, axis=0, mode="fill",
-                    fill_value=0)
+    return jnp.take(out.reshape(-1, out.shape[-1]), row.T, axis=0,
+                    mode="fill", fill_value=0)
 
 
 def _slot_of_choice(plan: MoePlan):
     """[T, k] int32: the slot, counted through all the buffers, that
-    holds the token's k-th choice; E * C, one past the last, where
-    none does."""
+    holds the token's k-th choice (the row of the packed buffer where
+    the plan is packed); E * C (R), one past the last, where none
+    does."""
     e, c = plan.src.shape
-    slot = _of_choice(plan.slot, plan)                      # [T, k]
-    return jnp.where(slot >= 0, plan.idx * c + slot, e * c)
+    if plan.packed is None:
+        slot = _of_choice(plan.slot, plan)                  # [T, k]
+        return jnp.where(slot >= 0, plan.idx * c + slot, e * c)
+    src, start = plan.packed
+    row = _of_choice(jnp.where(plan.slot >= 0, plan.slot + start, -1), plan)
+    # a choice that is not held matches no expert and sums to 0
+    return jnp.where((row >= 0) & (plan.idx < e), row, src.shape[1])
 
 
 def _sum_by_token(rows, plan: MoePlan, w=None):
@@ -366,7 +432,9 @@ def dispatch_rows(x, plan: MoePlan):
     """``xe[e, c] = x[src[e, c]]`` in x's dtype, zeros in empty slots
     (the grouped kernels' amax and the expert backward rely on padded
     rows being zero): a gather of E * C rows, whichever side the plan
-    has.  Its transpose is a combine with weight one and takes the
+    has (of the R rows ``xe[0, r] = x[packed.src[0, r]]`` where the
+    plan is packed).  Its transpose is a combine with weight one and
+    takes the
     plan's smaller side (``_plan_side``): a gather of the k * T pairs'
     rows and a sum over k, or the sum of the slots' rows by token."""
     with scope("moe.dispatch"):
@@ -410,12 +478,16 @@ def moe_dispatch(x2d, w_router, num_experts: int, top_k: int,
     return xe, plan, gate
 
 
-def _dispatch_choices(x2d, weights, idx, n: int, slots: int):
+def _dispatch_choices(x2d, weights, idx, n: int, slots: int,
+                      row_block: int | None = None):
     """The one dispatch body: the choices ``idx`` [T, k] among ``n``
     experts (``n`` itself names no expert) with their ``weights``, into
     ``slots`` rows an expert in arrival order; a choice past ``slots``
     is left out.  Returns ``(xe [n, slots, d], plan, gate [T, n],
-    load [n])``, ``load`` the rows routed to each, kept or not."""
+    load [n])``, ``load`` the rows routed to each, kept or not.  With
+    the kernels' ``row_block`` given, the rows are packed where that
+    takes less room (``packed_room``): ``xe`` is then ``[1, R, d]``
+    and the plan carries the layout."""
     with scope("moe.router"):
         onehot = jax.nn.one_hot(idx, n, dtype=_F32)         # [T, k, n]
         gate = jnp.sum(onehot * weights[..., None], axis=1)  # [T, n]
@@ -424,12 +496,37 @@ def _dispatch_choices(x2d, weights, idx, n: int, slots: int):
         pos = jnp.cumsum(routed, axis=0) - 1                # arrival order
         keep = (routed > 0) & (pos < slots)
         plan = moe_plan(idx, pos[None], keep[None], slots)
+        room = packed_room(idx.size, n, slots, row_block)
+        if room is not None:
+            plan = plan._replace(packed=_pack(
+                plan, jnp.sum(keep, axis=0, dtype=jnp.int32), room,
+                row_block))
         xe = dispatch_rows(x2d, plan)                       # [n, slots, d]
         load = jnp.sum(routed, axis=0)
     return xe, plan, gate, load
 
 
-def moe_dispatch_held(x2d, weights, idx, held: tuple, slots: int):
+def _pack(plan: MoePlan, kept, room: int, row_block: int) -> PackedRows:
+    """The packed layout of ``plan``'s rows in a buffer of ``room``
+    rows: ``kept`` [E] rows an expert, each expert's from the next
+    multiple of ``row_block`` (``packed_first``).  A row block of the
+    buffer mirrors a row block of its owner's slots, so the source map
+    is a gather of ``src`` by blocks, indices and not rows; a block
+    past the live prefix mirrors none and names token T throughout."""
+    (t, e), c = plan.slot.shape, plan.src.shape[1]
+    first = packed_first(kept, row_block)                   # [E + 1]
+    blk = jnp.arange(room // row_block, dtype=jnp.int32)
+    owner = owner_of(blk, first[1:])            # E past the live prefix
+    # its owner's first block: the last boundary at or before it
+    base = jnp.max(jnp.where(first <= blk[:, None], first, 0), axis=1)
+    mirrored = owner * (c // row_block) + blk - base
+    src = jnp.take(plan.src.reshape(e * c // row_block, row_block),
+                   mirrored, axis=0, mode="fill", fill_value=t)
+    return PackedRows(src.reshape(1, room), first[:-1] * row_block)
+
+
+def moe_dispatch_held(x2d, weights, idx, held: tuple, slots: int,
+                      row_block: int | None = None):
     """Dispatch of a routing ``(weights, idx)`` [T, k] over ALL the
     router's experts to the ``held = (first, count)`` of them that live
     here, ``slots`` rows an expert, in arrival order.  No capacity rule
@@ -443,22 +540,32 @@ def moe_dispatch_held(x2d, weights, idx, held: tuple, slots: int):
     pairs, a chip with a share of the experts, most pairs are such
     choices: combine and both backward passes then move rows through
     the plan's slot side (``_plan_side``), the held rows and not one
-    for every pair.
+    for every pair.  Where it is more than all the pairs can fill,
+    ``packed_room(T * k, count, slots, row_block)`` rows, a bound so
+    loose that most of its slots can never hold a row, and the caller
+    names the ``row_block`` of the kernels that will read the buffer
+    (``moe.moe_held`` does; a caller without one keeps ``[count,
+    slots, d]``), the rows are packed: one buffer ``[1, R, d]``, each
+    expert's rows from a row-block boundary, the bound enforced and
+    counted as before, and dispatch, combine and their transposes fill,
+    gather and weigh R rows, not ``count * slots``.
 
-    Returns ``(xe [count, slots, d], plan, gate [T, count], load)``:
-    the ``moe_dispatch`` contract over the held experts, and ``load``
-    [count] int32, the rows routed to each (kept or not)."""
+    Returns ``(xe [count, slots, d] or [1, R, d], plan, gate [T, count],
+    load)``: the ``moe_dispatch`` contract over the held experts, and
+    ``load`` [count] int32, the rows routed to each (kept or not)."""
     first, n = held
     with scope("moe.router"):
         local = idx - first
         local = jnp.where((local >= 0) & (local < n), local, n)
-    return _dispatch_choices(x2d, weights, local, n, slots)
+    return _dispatch_choices(x2d, weights, local, n, slots, row_block)
 
 
 @jax.custom_vjp
 def moe_combine(out, plan: MoePlan, gate):
-    """Per-expert outputs [E, C, d] back to tokens [T, d], in ``out``'s
-    dtype, with the plan and the combine weights of ``moe_dispatch``:
+    """Per-expert outputs [E, C, d] ([1, R, d] where the plan is packed:
+    the same rows, found at ``packed.start[e] + slot``) back to tokens
+    [T, d], in ``out``'s dtype, with the plan and the combine weights of
+    ``moe_dispatch``:
     ``y[t] = sum_k gate[t, e_k] * out[e_k, slot[t, e_k]]`` over the
     token's top-k, product and sum in float32; a dropped choice adds
     nothing.  On the plan's pair side (``_plan_side``) the k * T rows

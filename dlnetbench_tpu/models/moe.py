@@ -211,7 +211,7 @@ def stats_globals(stats, *, num_experts: int, top_k: int,
 def expert_ffn(xe, w_gate, w_up, w_down, *, impl: str = "einsum",
                quant: str | None = None, counts=None,
                mlp_int8: bool = False, backward: str = "einsum",
-               activation: str = "silu"):
+               activation: str = "silu", bound: int | None = None):
     """The expert-FFN dispatch point shared by the single-device MoE
     below and the EP-sharded SPMD path: ``xe`` [E, C, d] dispatch
     buffers -> [E, C, d].
@@ -223,14 +223,17 @@ def expert_ffn(xe, w_gate, w_up, w_down, *, impl: str = "einsum",
       block skipping (``counts``); ``backward`` is ``grouped_ffn``'s
       (``"counted"``: the backward skips the row blocks the forward
       skipped) and ``activation`` the gate's (``grouped_ffn``'s: ``silu``
-      or ``relu``; the einsum path is the SwiGLU only).
+      or ``relu``; the einsum path is the SwiGLU only); ``bound`` = C
+      says that ``xe`` is a packed buffer ``[1, R, d]`` (``grouped_ffn``'s:
+      the grouped kernels with the counted backward alone).
     """
     with scope("moe.experts"):
         if impl == "grouped":
             from dlnetbench_tpu.ops.grouped_matmul import grouped_ffn
             return grouped_ffn(xe, w_gate, w_up, w_down, counts=counts,
                                fmt=quant, backward=backward,
-                               activation=activation).astype(_F32)
+                               activation=activation,
+                               bound=bound).astype(_F32)
         if impl != "einsum":
             raise ValueError(f"moe.expert_ffn: unknown impl {impl!r} "
                              f"(einsum | grouped)")
@@ -291,6 +294,19 @@ def moe_held(x2d, w_router, w_gate, w_up, w_down, top_k: int, *,
     backward (``grouped_ffn(backward="counted")``: kernels over the row
     blocks the forward multiplied); ``moe_grouped``, whose capacity
     drops rows to stay nearly full, keeps the einsums over every slot.
+    Which layout the rows take is read off the static shapes here
+    (``layers.packed_room`` under the kernels' ``row_block``), as the
+    plan's side is: where ``count * slots`` exceeds the R rows that
+    all k * T pairs can fill when each expert's rows start at a
+    row-block boundary (a whole layer, LFM2; a loose bound,
+    SmallThinker), ONE packed buffer ``[1, R, d]`` goes through
+    dispatch, the kernels (``grouped_ffn(bound=slots)``) and combine,
+    and the fills, gathers and row blocks are R's, not the bound's;
+    where the
+    bound leaves fewer slots than pairs (Kimi, Qwen) the buffer stays
+    ``[count, slots, d]`` and the plan takes its slot side.  The two
+    layouts hold the same rows in the same blocks: equal to the bit,
+    ``past_bound`` included (``tests/test_moe_packed.py``).
     ``router_x`` [T, d] is what the router reads where that is another
     tensor than the experts' ``x2d`` (a router placed before attention
     reads the layer's normed input); ``activation`` the experts' gate's
@@ -301,11 +317,14 @@ def moe_held(x2d, w_router, w_gate, w_up, w_down, top_k: int, *,
     load of one of them, ``past_bound`` rows left out at ``slots``
     (a step that reads one has not computed the layer), and
     ``choices`` [T, k], the router's selection over all its experts."""
+    from dlnetbench_tpu.ops.grouped_matmul import row_block
     routed_on = x2d if router_x is None else router_x
     weights, idx = L.moe_router(routed_on, w_router, top_k, scoring=scoring,
                                 bias=bias, scale=scale)
-    xe, plan, gate, load = L.moe_dispatch_held(x2d, weights, idx, held,
-                                               slots)
+    e, d, f = w_gate.shape
+    xe, plan, gate, load = L.moe_dispatch_held(
+        x2d, weights, idx, held, slots,
+        row_block=row_block(e, slots, d, f, x2d.dtype))
     with scope("moe.dispatch"):
         stats = _routing_stats(routed_on, w_router, load[None], plan, slots)
         routing = {"routed": jnp.sum(stats["routed"]),
@@ -313,7 +332,8 @@ def moe_held(x2d, w_router, w_gate, w_up, w_down, top_k: int, *,
                    "past_bound": stats["dropped"], "choices": idx}
     y = expert_ffn(xe, w_gate, w_up, w_down, impl="grouped",
                    counts=stats["kept"], backward="counted",
-                   activation=activation)
+                   activation=activation,
+                   bound=None if plan.packed is None else slots)
     with scope("moe.combine"):
         return L.moe_combine(y.astype(x2d.dtype), plan, gate), routing
 

@@ -82,6 +82,26 @@ what its buffer is (``backward=``):
   three names is ``grouped_mm``: the forward family's roofline
   metrics find their kernels by that name and count only them.
 
+Two layouts of the experts' rows run through the same kernel bodies,
+tiles and names.  PADDED, ``[E, C, K]``: room for C rows of every
+expert, the grid's row axis over each expert's C / bc blocks; what a
+capacity fills (``moe.moe_grouped``, the SPMD step) and what
+``moe.moe_held`` keeps where its bound leaves fewer slots than the
+routing has pairs (Kimi, Qwen).  PACKED, ``[1, R, K]`` (``bound=`` C on
+``grouped_matmul`` and ``grouped_ffn``): where a bound is so loose
+that ``E * C`` exceeds the ``R = packed_rows(k * T, E, bc)`` rows all
+the pairs can fill (LFM2, SmallThinker), ``moe_held`` hands over one
+buffer in which expert e's rows start at row block
+``packed_first(counts, bc)[e]``.  The row-side calls then walk the
+buffer's R / bc blocks as one group whose live rows are a prefix, a
+block multiplied by the weight of the expert that owns it
+(``packed_tables``: consecutive blocks of one expert keep its weight
+resident), the blocks past the prefix dead as above; the contraction
+side walks expert e's at most C / bc blocks from its first.  What
+differs is the grid's row axis, the index maps and the prefetched
+tables: a row block's products are the padded form's to the bit, and
+an expert's blocks add up in the same order.
+
 All kernels run under ``interpret=True`` off-TPU (pallas_common), so
 the CPU-mesh tier-1 lane unit-tests them.
 """
@@ -229,6 +249,56 @@ def _fit_blocks(e: int, c: int, kdim: int, n: int, fmt: str | None,
                  for dim, name in zip((c, n, kdim), _BLOCK_NAMES))
 
 
+def row_block(e: int, c: int, d: int, f: int, xdtype,
+              blocks=(None, None, None)) -> int:
+    """The row block of an expert FFN ``[E, C, d] -> f -> d``: the
+    granule its forward kernels skip by, its counted backward walks
+    and a packed buffer is laid out in (the gate projection's)."""
+    return _fit_blocks(e, c, d, f, None, xdtype, blocks)[0]
+
+
+def packed_rows(pairs: int, e: int, block_c: int) -> int:
+    """R, the rows of a packed buffer: what ``pairs`` rows over ``e``
+    experts can fill at the very most when each expert's rows start at
+    a multiple of ``block_c``, itself a whole number of row blocks."""
+    return -(-(pairs + e * (block_c - 1)) // block_c) * block_c
+
+
+def packed_first(counts, block_c: int):
+    """[E + 1] int32, THE packed layout, in row blocks: the block at
+    which each expert's rows start, the next one past the expert before
+    it (``first[0] = 0``), and last the end of the live prefix; expert
+    e's rows lie from row ``first[e] * block_c`` on.  The plan that
+    fills a packed buffer (``layers._pack``) and the kernels that walk
+    it both read the layout off the counts through this."""
+    blocks = jax.lax.div(counts.astype(jnp.int32) + (block_c - 1), block_c)
+    return jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                            jnp.cumsum(blocks, dtype=jnp.int32)])
+
+
+def owner_of(at, ends):
+    """For each position of ``at`` (ascending from 0) the segment it
+    lies in, where segment i ends before ``ends[i]`` (ascending, a
+    segment may be empty): the number of ends at or before it, so a
+    position past them all reads ``len(ends)``."""
+    return jnp.sum(at[:, None] >= ends[None, :], axis=1, dtype=jnp.int32)
+
+
+def packed_tables(counts, block_c: int, n_blocks: int):
+    """The prefetched tables of a row-side call over a packed buffer
+    of ``n_blocks`` row blocks, which the grid walks as ONE group whose
+    live rows are a prefix: ``(rows, te, tc, owner)``, the first three
+    that group's ``counts`` and ``hold_table`` (a dead step looks back
+    to the last live block), and ``owner`` [n_blocks] int32, the expert
+    whose rows a block holds and whose weight multiplies it (any
+    expert's for a dead block, which no step names)."""
+    first = packed_first(counts, block_c)
+    live = first[-1:]
+    owner = owner_of(jnp.arange(n_blocks, dtype=jnp.int32), first[1:-1])
+    return (live * block_c, jnp.zeros((1,), jnp.int32),
+            jnp.maximum(live - 1, 0), owner)
+
+
 def hold_table(counts, block_c: int):
     """``(te, tc)`` [E] int32: the expert and the row block that the
     steps past expert ``e``'s count name.  They look ahead: row block 0
@@ -270,7 +340,8 @@ def named_step(bc: int, nn: int, nk: int, n_out: bool):
     return named
 
 
-def index_maps(bc: int, nn: int, nk: int, n_out: bool):
+def index_maps(bc: int, nn: int, nk: int, n_out: bool,
+               packed: bool = False):
     """The three block index maps ``(x, w, out)`` over the grid
     ``(e, ci, ni, ki)`` (``(e, ni, ci, ki)`` when ``n_out``), each
     taking the grid indices and then the prefetched ``counts, te, tc``
@@ -280,20 +351,32 @@ def index_maps(bc: int, nn: int, nk: int, n_out: bool):
     step moves no input byte that a live step does not need, and the
     one fetch a run of them starts is the next live step's own, begun
     while the last live step still multiplies.  Its output block is
-    its own (the kernel writes its zeros)."""
+    its own (the kernel writes its zeros).  ``packed``: the rows are
+    one group (``packed_tables``) and the weight is the one of the
+    expert that owns the named row block, the fourth prefetched
+    table."""
     named = named_step(bc, nn, nk, n_out)
 
     def x_index(ei, a, b, ki, counts, te, tc, *_):
         e_, c_, _n, k_ = named(ei, a, b, ki, counts, te, tc)
         return e_, c_, k_
 
-    def w_index(ei, a, b, ki, counts, te, tc, *_):
-        e_, _c, n_, k_ = named(ei, a, b, ki, counts, te, tc)
-        return e_, k_, n_
+    def w_index(ei, a, b, ki, counts, te, tc, *rest):
+        e_, c_, n_, k_ = named(ei, a, b, ki, counts, te, tc)
+        return rest[0][c_] if packed else e_, k_, n_
 
     def out_index(ei, a, b, ki, *_):
         return (ei, b, a) if n_out else (ei, a, b)
     return x_index, w_index, out_index
+
+
+def _owner_is_the_maps(kernel):
+    """The body of a packed row-side call: ``kernel`` without the
+    fourth prefetched table (``packed_tables``' ``owner``), which only
+    the index maps read."""
+    def body(counts_ref, te_ref, tc_ref, _owner, *refs):
+        return kernel(counts_ref, te_ref, tc_ref, *refs)
+    return body
 
 
 def _grouped_kernel(counts_ref, _te, _tc, sx_ref, sw_ref, x_ref, w_ref,
@@ -358,7 +441,8 @@ def grouped_matmul(x, w, *, counts=None, sx=None, sw=None,
                    fmt: str | None = None, out_dtype=None,
                    block_c: int | None = None,
                    block_n: int | None = None,
-                   block_k: int | None = None):
+                   block_k: int | None = None,
+                   bound: int | None = None):
     """``[E, C, K] @ [E, K, N] -> [E, C, N]`` per-expert matmul.
 
     ``counts`` ([E] int32, optional): valid tokens per expert — token
@@ -366,6 +450,15 @@ def grouped_matmul(x, w, *, counts=None, sx=None, sw=None,
     DMA, zero output).  ``None`` computes every block (the dense
     capacity-buffer contract: padded rows are zeros and produce
     zeros).
+
+    Packed form (``bound`` = C given): ``x`` is ``[1, R, K]``, one
+    group of rows, expert e's ``counts[e]`` (at most C) lying from row
+    ``packed_first(counts, bc)[e] * bc`` on, -> ``[1, R, N]``.  The
+    tiles are those of ``[E, C, K]``, so a row block's product is the
+    padded form's to the bit; the grid's row axis runs over the R / bc
+    blocks of the buffer, each multiplied by its owner's weight
+    (``packed_tables``), the blocks past the live prefix skipped as
+    above.
 
     Quantized form (``fmt`` = "int8" | "float8"): ``w`` must be
     PRE-QUANTIZED per expert ([E, K, N] in the quantized dtype), with
@@ -376,8 +469,10 @@ def grouped_matmul(x, w, *, counts=None, sx=None, sw=None,
     with none given the tuning DB is consulted (op ``grouped_ffn``)
     and an empty DB gives this shape's ``tile_plan``.  The grid's
     order follows from the blocks (``n_outer``)."""
-    e, c, kdim = x.shape
-    if w.shape[0] != e or w.shape[1] != kdim:
+    packed = bound is not None
+    e, (groups, rows, kdim) = w.shape[0], x.shape
+    c = bound if packed else rows
+    if w.shape[1] != kdim or groups != (1 if packed else e):
         raise ValueError(f"grouped_matmul: shape mismatch "
                          f"x{x.shape} @ w{w.shape}")
     n = w.shape[2]
@@ -388,25 +483,38 @@ def grouped_matmul(x, w, *, counts=None, sx=None, sw=None,
         if sx is None or sw is None:
             raise ValueError("grouped_matmul: fmt set but sx/sw "
                              "per-expert scales missing")
+    if packed and (counts is None or fmt is not None):
+        raise ValueError("grouped_matmul: the packed form needs counts "
+                         "and has no quantized form")
     bc, bn, bk = _fit_blocks(e, c, kdim, n, fmt, x.dtype,
                              (block_c, block_n, block_k))
-    nc, nn, nk = c // bc, n // bn, kdim // bk
+    nn, nk = n // bn, kdim // bk
     n_out = n_outer(c, kdim, n, bc, bn, bk)
 
     if counts is None:
         counts = jnp.full((e,), c, jnp.int32)
     counts = counts.astype(jnp.int32)
-    te, tc = hold_table(counts, bc)
     sx_a = (jnp.asarray(sx, F32).reshape(e) if fmt
             else jnp.zeros((e,), F32))
     sw_a = (jnp.asarray(sw, F32).reshape(e) if fmt
             else jnp.zeros((e,), F32))
+    kernel = functools.partial(_grouped_kernel, fmt=fmt, block_c=bc,
+                               n_out=n_out)
+    if rows % bc:
+        raise ValueError(f"grouped_matmul: {rows} rows are no whole "
+                         f"number of {bc}-row blocks")
+    nc = rows // bc
+    if packed:
+        tables = packed_tables(counts, bc, nc)
+        kernel = _owner_is_the_maps(kernel)
+    else:
+        tables = (counts, *hold_table(counts, bc))
 
-    x_index, w_index, out_index = index_maps(bc, nn, nk, n_out)
+    x_index, w_index, out_index = index_maps(bc, nn, nk, n_out, packed)
     acc_dtype = _FORMATS[fmt][2] if fmt else F32
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(e, nn, nc, nk) if n_out else (e, nc, nn, nk),
+        num_scalar_prefetch=len(tables) + 2,
+        grid=(groups, nn, nc, nk) if n_out else (groups, nc, nn, nk),
         in_specs=[pl.BlockSpec((1, bc, bk), x_index),
                   pl.BlockSpec((1, bk, bn), w_index)],
         out_specs=pl.BlockSpec((1, bc, bn), out_index),
@@ -414,15 +522,15 @@ def grouped_matmul(x, w, *, counts=None, sx=None, sw=None,
                         else []),
     )
     out = pl.pallas_call(
-        functools.partial(_grouped_kernel, fmt=fmt, block_c=bc,
-                          n_out=n_out),
+        kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((e, c, n), out_dtype or x.dtype),
+        out_shape=jax.ShapeDtypeStruct((groups, rows, n),
+                                       out_dtype or x.dtype),
         compiler_params=compiler_params(
             ("parallel", "parallel", "parallel", "arbitrary")),
         name="grouped_mm",
         interpret=pallas_common.interpret_mode(),
-    )(counts, te, tc, sx_a, sw_a, x, w)
+    )(*tables, sx_a, sw_a, x, w)
     return out
 
 
@@ -459,16 +567,21 @@ def gate_act(g, act: str):
     return silu, sig + silu * (1.0 - sig)
 
 
-def _ffn_fwd(x, w_gate, w_up, w_down, counts, fmt, blocks, act="silu"):
+def _ffn_fwd(x, w_gate, w_up, w_down, counts, fmt, blocks, act="silu",
+             bound=None):
     """The three grouped dots of the gated expert FFN -> ``(y, g, u)``:
     the ONE body behind the primal and the VJP's forward, so the
     rounding of what the backward reads cannot drift from what the
     forward computed (``layers.swiglu``'s discipline: ``g``,
     ``u`` stay in the kernels' output dtype).  ``blocks`` is the
     (block_c, block_n, block_k) triple (hashable — it rides a
-    custom_vjp nondiff argnum)."""
-    kw = dict(counts=counts,
+    custom_vjp nondiff argnum), ``bound`` ``grouped_matmul``'s (a
+    packed ``x``)."""
+    kw = dict(counts=counts, bound=bound,
               **dict(zip(("block_c", "block_n", "block_k"), blocks)))
+    if bound is not None:       # one layout under all three products
+        kw["block_c"] = row_block(w_gate.shape[0], bound, x.shape[-1],
+                                  w_gate.shape[2], x.dtype, blocks)
     if fmt:
         sx = scale_from_amax(expert_amax(x), fmt)
         wgq, swg = quantize_experts(w_gate, fmt)
@@ -616,7 +729,7 @@ def _bwd_rows_kernel(counts_ref, _te, _tc, *refs, pairs: int,
 
 
 def _bwd_rows(xs, ws, counts, blocks, *, swiglu=None, name: str,
-              act: str = "silu"):
+              act: str = "silu", bound: int | None = None):
     """The backward's row side as one kernel: ``sum_p xs[p] [E, C, K] @
     ws[p] [E, N, K]^T -> [E, C, N]`` in ``xs[0]``'s dtype, row blocks
     past ``counts`` skipped on the forward's tile plan and index maps
@@ -624,47 +737,57 @@ def _bwd_rows(xs, ws, counts, blocks, *, swiglu=None, name: str,
     the float32 sum is ``dh`` and the result is ``(h, dg, du)`` in
     ``g``'s dtype, ``act`` the gate's activation.  ``blocks = (bc,
     block_n, block_k)``: the forward's row block and the caller's
-    explicit blocks, if any."""
-    e, c, kdim = xs[0].shape
-    n = ws[0].shape[1]
+    explicit blocks, if any.  ``bound`` = C: the packed form, as
+    ``grouped_matmul``'s (``xs``, ``g``, ``u`` and the result
+    ``[1, R, .]``)."""
+    packed = bound is not None
+    _, n, kdim = ws[0].shape
+    groups, rows, _ = xs[0].shape
+    c = bound if packed else rows
     pairs = len(xs)
     bc, given_n, given_k = blocks
     plan = tile_plan(c, kdim, n, xs[0].dtype.itemsize, block_c=bc,
                      pairs=pairs, row_tiles=5 if swiglu else 1)
     bn = fit_block(n, given_n or plan["block_n"])
     bk = fit_block(kdim, given_k or plan["block_k"])
-    nc, nn, nk = c // bc, n // bn, kdim // bk
+    nn, nk = n // bn, kdim // bk
     n_out = n_outer(c, kdim, n, bc, bn, bk)
-    te, tc = hold_table(counts, bc)
+    kernel = functools.partial(_bwd_rows_kernel, pairs=pairs,
+                               swiglu=swiglu is not None, block_c=bc,
+                               n_out=n_out, nk=nk, act=act)
+    nc = rows // bc
+    if packed:
+        tables = packed_tables(counts, bc, nc)
+        kernel = _owner_is_the_maps(kernel)
+    else:
+        tables = (counts, *hold_table(counts, bc))
     named = named_step(bc, nn, nk, n_out)
 
     def x_index(*idx):
-        e_, c_, _n, k_ = named(*idx)
+        e_, c_, _n, k_ = named(*idx[:7])
         return e_, c_, k_
 
     def w_index(*idx):
-        e_, _c, n_, k_ = named(*idx)
-        return e_, n_, k_
+        e_, c_, n_, k_ = named(*idx[:7])
+        return idx[7][c_] if packed else e_, n_, k_
 
     def tile_index(*idx):
         # g and u of a step past the count: the next live step's, as
         # its x and w (the step reads neither)
-        e_, c_, n_, _k = named(*idx)
+        e_, c_, n_, _k = named(*idx[:7])
         return e_, c_, n_
 
     def out_index(ei, a, b, ki, *_):
         return (ei, b, a) if n_out else (ei, a, b)
 
     out_spec = pl.BlockSpec((1, bc, bn), out_index)
-    tile = jax.ShapeDtypeStruct((e, c, n),
+    tile = jax.ShapeDtypeStruct((groups, rows, n),
                                 swiglu[0].dtype if swiglu else xs[0].dtype)
     return pl.pallas_call(
-        functools.partial(_bwd_rows_kernel, pairs=pairs,
-                          swiglu=swiglu is not None, block_c=bc,
-                          n_out=n_out, nk=nk, act=act),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(e, nn, nc, nk) if n_out else (e, nc, nn, nk),
+            num_scalar_prefetch=len(tables),
+            grid=(groups, nn, nc, nk) if n_out else (groups, nc, nn, nk),
             in_specs=([pl.BlockSpec((1, bc, bk), x_index)] * pairs
                       + [pl.BlockSpec((1, bn, bk), w_index)] * pairs
                       + [pl.BlockSpec((1, bc, bn), tile_index)]
@@ -678,7 +801,7 @@ def _bwd_rows(xs, ws, counts, blocks, *, swiglu=None, name: str,
             ("parallel", "parallel", "parallel", "arbitrary")),
         name=name,
         interpret=pallas_common.interpret_mode(),
-    )(counts, te, tc, *xs, *ws, *(swiglu or ()))
+    )(*tables, *xs, *ws, *(swiglu or ()))
 
 
 def last_live(counts, block_c: int):
@@ -745,25 +868,41 @@ def _bwd_dw_kernel(counts_ref, _be, _bl, a_ref, *refs, outs: int,
             out_ref[0] = acc_ref[...].astype(out_ref.dtype)
 
 
-def _bwd_dw(a, bs, counts, blocks, out_dtypes, *, name: str):
+def _bwd_dw(a, bs, counts, blocks, out_dtypes, *, name: str,
+            bound: int | None = None):
     """The backward's contraction side as one kernel: ``a [E, C, K]^T
     @ bs[j] [E, C, N] -> [E, K, N]`` for each ``j``, the ragged
     dimension contracted: the row-block axis is the grid's last
     (``arbitrary``), a block past ``counts`` issues no product and
     names the last live block's inputs.  ``blocks = (bc, block_k,
-    block_n)`` as in ``_bwd_rows``."""
-    e, c, kdim = a.shape
-    n = bs[0].shape[2]
+    block_n)`` as in ``_bwd_rows``.  ``bound`` = C: the packed form
+    (``a``, ``bs`` ``[1, R, .]``), in which expert e's at most C / bc
+    row blocks are walked from its first in the buffer
+    (``packed_first``); the prefetched tables are then the counts, each
+    expert's first block and the last live block at or before it, all
+    counted through the buffer."""
+    packed = bound is not None
+    e, c = counts.shape[0], bound if packed else a.shape[1]
+    kdim, n = a.shape[-1], bs[0].shape[-1]
     outs = len(bs)
     bc, given_k, given_n = blocks
     plan = dw_tile_plan(bc, kdim, n, a.dtype.itemsize, outs=outs)
     bk = fit_block(kdim, given_k or plan["block_k"])
     bn = fit_block(n, given_n or plan["block_n"])
-    be, bl = last_live(counts, bc)
+    if packed:
+        first = packed_first(counts, bc)
+        # the blocks run on through the buffer, so the last live one
+        # at or before e is the largest so far
+        tables = (counts, first[:-1], jax.lax.cummax(jnp.where(
+            first[1:] > first[:-1], first[1:] - 1, 0)))
+    else:
+        tables = (counts, *last_live(counts, bc))
 
-    def rows(ei, ci, counts, be, bl):
+    def rows(ei, ci, counts, t1, t2):
         live = ci * bc < counts[ei]
-        return jnp.where(live, ei, be[ei]), jnp.where(live, ci, bl[ei])
+        if packed:
+            return 0, jnp.where(live, t1[ei] + ci, t2[ei])
+        return jnp.where(live, ei, t1[ei]), jnp.where(live, ci, t2[ei])
 
     def a_index(ei, ki, ni, ci, *pre):
         e_, c_ = rows(ei, ci, *pre)
@@ -791,21 +930,26 @@ def _bwd_dw(a, bs, counts, blocks, out_dtypes, *, name: str):
             ("parallel", "parallel", "parallel", "arbitrary")),
         name=name,
         interpret=pallas_common.interpret_mode(),
-    )(counts, be, bl, a, *bs)
+    )(*tables, a, *bs)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _grouped_ffn_counted(x, w_gate, w_up, w_down, counts, blocks, act):
-    return _ffn_fwd(x, w_gate, w_up, w_down, counts, None, blocks, act)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _grouped_ffn_counted(x, w_gate, w_up, w_down, counts, blocks, act,
+                         bound):
+    return _ffn_fwd(x, w_gate, w_up, w_down, counts, None, blocks, act,
+                    bound)[0]
 
 
-def _grouped_ffn_counted_fwd(x, w_gate, w_up, w_down, counts, blocks, act):
-    return _grouped_ffn_fwd(x, w_gate, w_up, w_down, counts, None, blocks,
-                            act)
+def _grouped_ffn_counted_fwd(x, w_gate, w_up, w_down, counts, blocks, act,
+                             bound):
+    y, g, u = _ffn_fwd(x, w_gate, w_up, w_down, counts, None, blocks, act,
+                       bound)
+    return y, (x, g, u, w_gate, w_up, w_down, counts)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def _counted_bwd(blocks, act, x, g, u, w_gate, w_up, w_down, counts, dy):
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _counted_bwd(blocks, act, bound, x, g, u, w_gate, w_up, w_down, counts,
+                 dy):
     """The backward that multiplies the row blocks the forward
     multiplied: the six products of ``_grouped_ffn_bwd`` as four
     kernels keyed by the forward's counts and its row block.  ``dh =
@@ -819,26 +963,30 @@ def _counted_bwd(blocks, act, x, g, u, w_gate, w_up, w_down, counts, dy):
     einsums multiply them, and a skipped block's ``g``, ``u`` are the
     forward's zeros, which add nothing to any sum.  A ``jit`` of its
     own, so that the expert layers of one shape in a step trace and
-    lower these kernels once and call them (XLA inlines the calls)."""
-    e, c, d = x.shape
+    lower these kernels once and call them (XLA inlines the calls).
+    ``bound`` = C: ``x``, ``g``, ``u``, ``dy`` are packed ``[1, R, .]``
+    (``grouped_matmul``), the same row blocks where they lie there."""
+    e, d, f = w_gate.shape
     _, block_n, block_k = blocks
     # the row block the forward's kernels skipped by
-    bc = _fit_blocks(e, c, d, w_gate.shape[2], None, x.dtype, blocks)[0]
+    bc = row_block(e, x.shape[1] if bound is None else bound, d, f,
+                   x.dtype, blocks)
     cnt = counts.astype(jnp.int32)
     h, dg, du = _bwd_rows((dy,), (w_down,), cnt, (bc, block_n, block_k),
-                          swiglu=(g, u), name="grouped_mm_bwd_dh", act=act)
+                          swiglu=(g, u), name="grouped_mm_bwd_dh", act=act,
+                          bound=bound)
     dx = _bwd_rows((dg, du), (w_gate, w_up), cnt, (bc, block_n, block_k),
-                   name="grouped_mm_bwd_dx")
+                   name="grouped_mm_bwd_dx", bound=bound)
     dwd, = _bwd_dw(h, (dy,), cnt, (bc, block_k, block_n),
-                   (w_down.dtype,), name="grouped_mm_bwd_dw")
+                   (w_down.dtype,), name="grouped_mm_bwd_dw", bound=bound)
     dwg, dwu = _bwd_dw(x, (dg, du), cnt, (bc, block_k, block_n),
                        (w_gate.dtype, w_up.dtype),
-                       name="grouped_mm_bwd_dw")
+                       name="grouped_mm_bwd_dw", bound=bound)
     return dx.astype(x.dtype), dwg, dwu, dwd, jnp.zeros_like(counts)
 
 
-def _grouped_ffn_counted_bwd(blocks, act, res, dy):
-    return _counted_bwd(blocks, act, *res, dy)
+def _grouped_ffn_counted_bwd(blocks, act, bound, res, dy):
+    return _counted_bwd(blocks, act, bound, *res, dy)
 
 
 _grouped_ffn_counted.defvjp(_grouped_ffn_counted_fwd,
@@ -848,7 +996,8 @@ _grouped_ffn_counted.defvjp(_grouped_ffn_counted_fwd,
 def grouped_ffn(x, w_gate, w_up, w_down, *, counts=None,
                 fmt: str | None = None, block_c: int | None = None,
                 block_n: int | None = None, block_k: int | None = None,
-                backward: str = "einsum", activation: str = "silu"):
+                backward: str = "einsum", activation: str = "silu",
+                bound: int | None = None):
     """The grouped gated expert FFN ``(act(x W_gate) * x W_up) W_down``:
     ``x`` [E, C, d] dispatch buffers, weights [E, d, h] / [E, h, d]
     stacked per expert -> [E, C, d].  ``activation`` is the gate's, a
@@ -865,9 +1014,13 @@ def grouped_ffn(x, w_gate, w_up, w_down, *, counts=None,
     ``"counted"``, kernels over the row blocks the forward multiplied
     (the caller's word on what its buffer is: a bound that is loose by
     design, so that most of its slots are empty; it needs ``counts``
-    and has no quantized form).  Each traced call leaves a mark
-    ``moe.experts_bwd`` (``spans.mark``: ``path``, ``slots`` = E * C,
-    ``row_block``) on the build's ``compile`` span under a tracer."""
+    and has no quantized form).  ``bound`` = C: ``x`` is a packed
+    buffer ``[1, R, d]`` -> ``[1, R, d]`` (``grouped_matmul``'s packed
+    form: each expert's rows from a row-block boundary, at most C of
+    them), which only the counted backward takes.  Each traced call leaves a
+    mark ``moe.experts_bwd`` (``spans.mark``: ``path``, ``slots`` =
+    the buffer's rows, E * C or R, ``row_block``) on the build's
+    ``compile`` span under a tracer."""
     if fmt is not None and fmt not in _FORMATS:
         raise ValueError(f"grouped_ffn: unknown fmt {fmt!r}; one of "
                          f"{tuple(_FORMATS)} or None")
@@ -880,16 +1033,21 @@ def grouped_ffn(x, w_gate, w_up, w_down, *, counts=None,
     if activation not in ACTIVATIONS:
         raise ValueError(f"grouped_ffn: unknown activation {activation!r} "
                          f"{ACTIVATIONS}")
-    e, c, d = x.shape
+    if bound is not None and backward != "counted":
+        raise ValueError("grouped_ffn: a packed buffer (bound=) takes "
+                         "the counted backward only")
+    e, d = w_gate.shape[:2]
+    c = x.shape[1] if bound is None else bound
     blocks = (block_c, block_n, block_k)
     if spans.is_enabled():
-        spans.mark("moe.experts_bwd", path=backward, slots=e * c,
+        spans.mark("moe.experts_bwd", path=backward,
+                   slots=x.shape[0] * x.shape[1],
                    row_block=_fit_blocks(e, c, d, w_gate.shape[2], fmt,
                                          x.dtype, blocks)[0])
     counts_f = (jnp.full((e,), float(c), F32) if counts is None
                 else counts.astype(F32))
     if backward == "counted":
         return _grouped_ffn_counted(x, w_gate, w_up, w_down, counts_f,
-                                    blocks, activation)
+                                    blocks, activation, bound)
     return _grouped_ffn(x, w_gate, w_up, w_down, counts_f, fmt, blocks,
                         activation)

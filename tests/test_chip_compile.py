@@ -361,6 +361,45 @@ def test_counted_expert_backward_at_the_cells_shapes(for_chip, cell):
     assert not re.search(rf" (dot|convolution)\(", text)
 
 
+# the two cells whose bound reserves more slots than their pairs can
+# fill, so that ``moe_held`` packs the rows: (R, E, C, d, F), bf16
+CELL_PACKED_FFN = {
+    "smallthinker": (102400, 16, 12032, 2560, 768),
+    "lfm2": (40960, 32, 2048, 2048, 1792),
+}
+
+
+@pytest.mark.parametrize("cell", CELL_PACKED_FFN)
+def test_packed_expert_ffn_at_the_cells_shapes(for_chip, cell):
+    """The packed forms of the forward kernel and of the counted
+    backward's three (``grouped_ffn(bound=C)``: one buffer ``[1, R, d]``,
+    the row axis of the grid over its R / 256 blocks, each block's
+    weight found through a prefetched table of owners): the chip's
+    compiler takes them under the names the padded forms carry, and
+    nothing as large as ``[E, C, .]`` is left."""
+    from dlnetbench_tpu.metrics import spans
+    from dlnetbench_tpu.models import layers
+    gm = ops_module("grouped_matmul")
+    r, e, c, d, f = CELL_PACKED_FFN[cell]
+    pairs = {"smallthinker": 6 * 16384, "lfm2": 4 * 8192}[cell]
+    assert layers.packed_room(pairs, e, c,
+                              gm.row_block(e, c, d, f, BF16)) == r
+
+    def loss(x, wg, wu, wd, cnt):
+        with spans.scope("moe.experts"):
+            y = gm.grouped_ffn(x, wg, wu, wd, counts=cnt,
+                               backward="counted", bound=c)
+        return jnp.sum(y.astype(F32))
+    text = for_chip(jax.grad(loss, argnums=(0, 1, 2, 3)),
+                    ((1, r, d), BF16), ((e, d, f), BF16), ((e, d, f), BF16),
+                    ((e, f, d), BF16), ((e,), I32))
+    names = [re_sub_number(k) for k in kernel_instructions(text)]
+    assert sorted(names) == sorted(
+        ["grouped_mm"] * 2 + [*EXPERTS_BWD, "grouped_mm_bwd_dw"])
+    assert not re.search(r" (dot|convolution)\(", text)
+    assert f"[1,{r},{f}]" in text and f"[{e},{c},{d}]" not in text
+
+
 def scopes_by_opcode(text: str, opcodes: str, keep) -> dict:
     """{opcode: the scopes its instructions lie under}, over the
     instructions of ``text`` whose opcode is one of ``opcodes`` (a

@@ -268,6 +268,9 @@ def _trace_a_layer(name):
 
 @pytest.mark.parametrize("name", sorted(HELD))
 def test_a_traced_build_marks_each_sites_side(name):
+    """These layers call ``moe_dispatch_held`` and ``moe_combine``
+    themselves and name no row block of any kernels, so every one keeps
+    the padded layout, whatever its shapes: ``room`` is its E * C."""
     t, k, _, (_, count), slots = HELD[name]
     side = SIDE[name]
     rows = count * slots if side == "slots" else k * t
@@ -280,12 +283,45 @@ def test_a_traced_build_marks_each_sites_side(name):
         spans.disable()
     build, *alone = tracer.export()["spans"]
     assert build["name"] == "compile" and build["attrs"]["fn"] == "layer"
-    want = [{"site": site, "side": side, "rows": rows, "pairs": k * t}
+    want = [{"site": site, "side": side, "rows": rows, "pairs": k * t,
+             "layout": "padded", "room": count * slots}
             for site in ("combine", "combine.bwd", "dispatch.bwd")]
     assert build["attrs"]["moe.plan_side"] == want
     assert [s["name"] for s in alone] == ["moe.plan_side"] * 3
     assert [s["attrs"] for s in alone] == want
     assert all(s["dur_us"] == 0 and s["depth"] == 0 for s in alone)
+
+
+@pytest.mark.parametrize("row_block,layout,room", [
+    (None, "padded", 8 * 20), (4, "packed", 152), (20, "padded", 8 * 20)])
+def test_the_mark_says_which_layout_and_how_much_room(row_block, layout,
+                                                      room):
+    """The whole layer of ``mixtral_like`` (8 x 20 slots for 128 pairs)
+    as a bound: with the kernels' row block named, 4 rows, the pairs
+    and 8 x 3 rows of slack fit 152 rows and the site is packed; at a
+    row block of 20 they would need 280, more than the slots, and with
+    none named nothing packs.  ``side`` and ``rows`` are the pairs'
+    in all three."""
+    t, k, _, held, slots = HELD["mixtral_like"]
+    x, weights, idx, _ = routing_case("mixtral_like")
+
+    def loss(x, weights):
+        xe, plan, gate, _ = L.moe_dispatch_held(x, weights, idx, held, slots,
+                                                row_block=row_block)
+        assert xe.shape == ((1, room, 16) if layout == "packed"
+                            else (held[1], slots, 16))
+        return jnp.sum(jnp.sin(L.moe_combine(jnp.tanh(xe), plan, gate)))
+    tracer = spans.enable()
+    try:
+        with spans.span("compile", fn="layer"):
+            jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(x, weights)
+    finally:
+        spans.disable()
+    build, = tracer.export()["spans"]
+    assert build["attrs"]["moe.plan_side"] == [
+        {"site": site, "side": "pairs", "rows": k * t, "pairs": k * t,
+         "layout": layout, "room": room}
+        for site in ("combine", "combine.bwd", "dispatch.bwd")]
 
 
 def test_no_tracer_no_mark():
