@@ -318,7 +318,7 @@ def load_record(path: Path) -> tuple[dict, object]:
 
 def phase_kernels(sz: Sizes) -> dict:
     """Every Pallas kernel family once, against its XLA reference: the
-    kernels compile for the chip in tests/test_chip_compile.py, and
+    kernels compile for the chip in tests/test_chip_compile_*.py, and
     here they execute and agree."""
     import jax
     import jax.numpy as jnp

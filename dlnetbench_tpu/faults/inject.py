@@ -76,8 +76,9 @@ class FaultInjector:
     """
 
     def __init__(self, plan: FaultPlan, world: int | None = None,
-                 rank: int | None = None):
+                 rank: int | None = None, sleep=time.sleep):
         self.plan = plan
+        self.sleep = sleep  # seconds; a test's own clock advances here
         self.world = world  # needed to name a partition's far side
         self.rank = rank    # None = controller plays every rank
         self.iteration = 0
@@ -154,5 +155,5 @@ class FaultInjector:
 
     def _sleep(self, us: float) -> None:
         if us > 0:
-            time.sleep(us / 1e6)
+            self.sleep(us / 1e6)
             self.injected_delay_us += us

@@ -139,7 +139,9 @@ def _chain_sizes(runs: int, k: int) -> list[int]:
 
 
 def run_proxy(name: str, bundle: StepBundle, cfg: ProxyConfig,
-              energy_sampler=None) -> ProxyResult:
+              energy_sampler=None, clock=time.perf_counter) -> ProxyResult:
+    # ``clock`` reads every timer of the result (a test hands in one it
+    # advances itself)
     # fault injection (faults/inject.py): wrap the FULL step so the
     # injected sleeps land inside every timed window and crash triggers
     # count warmup + measured invocations, matching the native tier
@@ -159,7 +161,8 @@ def run_proxy(name: str, bundle: StepBundle, cfg: ProxyConfig,
     # only — compile time can no longer pollute estimate_runs through
     # the warmup mean the way a first-call jit compile did.
     with spans.span("warmup", proxy=name, reps=max(cfg.warmup, 1)):
-        warmup_s = time_callable(full_step, reps=max(cfg.warmup, 1))
+        warmup_s = time_callable(full_step, reps=max(cfg.warmup, 1),
+                                 clock=clock)
     if wd is not None:
         wd.beat("warmup")
     if telemetry.is_enabled():
@@ -235,10 +238,10 @@ def run_proxy(name: str, bundle: StepBundle, cfg: ProxyConfig,
             inj0 = injector.injected_delay_us if injector is not None else 0.0
             if wd is not None:
                 with wd:
-                    t_full = time_chain(full_step, k=k)
+                    t_full = time_chain(full_step, k=k, clock=clock)
                 wd.beat(f"chain_{ci}")
             else:
-                t_full = time_chain(full_step, k=k)
+                t_full = time_chain(full_step, k=k, clock=clock)
             if injector is not None:
                 # injected latency attributable to this chain, per
                 # iteration — lets analyses subtract the scripted delay
@@ -250,7 +253,7 @@ def run_proxy(name: str, bundle: StepBundle, cfg: ProxyConfig,
                                     energy_sampler.read_joules() - e0) / k)
             full_s.append(t_full)
             if measure_compute:
-                comp_s.append(time_chain(bundle.compute, k=k))
+                comp_s.append(time_chain(bundle.compute, k=k, clock=clock))
             if telemetry.is_enabled():
                 # one ring sample per fenced chain: the measured
                 # per-iteration wall plus the axes the flight dump
@@ -289,7 +292,8 @@ def run_proxy(name: str, bundle: StepBundle, cfg: ProxyConfig,
     if cfg.measure_comm_only and bundle.comm is not None:
         with spans.span("timed", proxy=name, variant="comm"):
             time_callable(bundle.comm, reps=1)  # warm
-            comm_s = [time_chain(bundle.comm, k=k) for k in chains]
+            comm_s = [time_chain(bundle.comm, k=k, clock=clock)
+                      for k in chains]
         timers["comm_time"] = [t * 1e6 for t in comm_s]
         if measure_compute:
             # measured comm–compute overlap per chain (the A/B
@@ -307,7 +311,7 @@ def run_proxy(name: str, bundle: StepBundle, cfg: ProxyConfig,
         for vname, vfn in bundle.variants.items():
             with spans.span("timed", proxy=name, variant=vname):
                 time_callable(vfn, reps=1)  # warm
-                v_s = [time_chain(vfn, k=k) for k in chains]
+                v_s = [time_chain(vfn, k=k, clock=clock) for k in chains]
             timers[f"{vname}_time"] = [t * 1e6 for t in v_s]
 
     if wd is not None:

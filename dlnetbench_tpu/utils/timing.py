@@ -71,18 +71,21 @@ def _fence(res, k: int) -> None:
         jax.block_until_ready(res)
 
 
-def time_callable(fn, *args, reps: int = 1, **kwargs) -> list[float]:
+def time_callable(fn, *args, reps: int = 1, clock=time.perf_counter,
+                  **kwargs) -> list[float]:
     """Run ``fn(*args)`` ``reps`` times, fencing each run; returns seconds
-    per run.  Caller is responsible for warmup (compilation)."""
+    per run by ``clock``.  Caller is responsible for warmup
+    (compilation)."""
     out = []
     for _ in range(reps):
-        t0 = time.perf_counter()
+        t0 = clock()
         _fence(fn(*args, **kwargs), k=1)
-        out.append(time.perf_counter() - t0)
+        out.append(clock() - t0)
     return out
 
 
-def time_chain(fn, *args, k: int = 1, **kwargs) -> float:
+def time_chain(fn, *args, k: int = 1, clock=time.perf_counter,
+               **kwargs) -> float:
     """Run ``fn(*args)`` ``k`` times back-to-back with ONE fence after the
     last call; returns per-iteration seconds (elapsed / k).
 
@@ -96,8 +99,8 @@ def time_chain(fn, *args, k: int = 1, **kwargs) -> float:
     sequence and the chain elapsed time is k honest iterations.  Caller
     is responsible for warmup (compilation)."""
     if k <= 1:
-        return time_callable(fn, *args, **kwargs)[0]
-    t0 = time.perf_counter()
+        return time_callable(fn, *args, clock=clock, **kwargs)[0]
+    t0 = clock()
     res = None
     for _ in range(k):
         res = fn(*args, **kwargs)
@@ -105,7 +108,7 @@ def time_chain(fn, *args, k: int = 1, **kwargs) -> float:
     # merged timeline shows the host blocked-on-device tail distinct
     # from the dispatch burst
     _fence(res, k=k)
-    return (time.perf_counter() - t0) / k
+    return (clock() - t0) / k
 
 
 def median_us(samples_s: list[float]) -> float:

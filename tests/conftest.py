@@ -5,25 +5,75 @@ that the whole suite runs on a laptop).
 
 Both variables are read at first backend init, which has not happened yet
 at conftest time.  The chip is reached through ``chip_smoke.py``, never
-through pytest; the TPU compiler is reached by ``tests/test_chip_compile.py``
-alone, from inside its own fixtures.
+through pytest; the TPU compiler is reached by the
+``tests/test_chip_compile_*.py`` files alone, through the fixtures of
+``tests/chip_compile_support.py`` (registered below).  Several xdist
+workers run those files at once, and the TPU's library lets more than
+one process load it only under ``ALLOW_MULTIPLE_LIBTPU_LOAD``.
 """
 import os
+import shutil
+import tempfile
 
 os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (_flags + " --xla_force_host_platform_device_count=8").strip()
 
+# One compile cache for the session, under the system's temporary
+# directory: made by the process that starts the session, found through
+# the environment by the xdist workers and by every subprocess a test
+# spawns (jax and ``executor.enable_persistent_cache`` both read the
+# variable), removed when the session ends.  The cells' rehearsal steps
+# are built again by case after case and file after file of
+# tests/benchmarks/, each a new function object that jax's in-memory
+# caches miss: with this they compile once a session (CHANGES.md, PR 46:
+# that directory's 203 s on six workers became 139 s).  Nothing is
+# written into the checkout, and nothing outlives the session.
+_OWNS_CACHE = "PYTEST_XDIST_WORKER" not in os.environ
+if _OWNS_CACHE:
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = tempfile.mkdtemp(
+        prefix="dlnb-tier1-cache-")
+
 import jax  # noqa: E402
 
-# entry points called in-process (cli.main, sweep) place the persistent
-# compile cache inside the checkout; the suite leaves the tree clean and
-# every test compiling for itself, so the cache stays off here (the cache
-# tests turn it on around themselves)
-jax.config.update("jax_enable_compilation_cache", False)
+# programs that compile in under half a second are not worth a file
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 import pytest  # noqa: E402
+
+pytest_plugins = ("chip_compile_support",)
+
+
+def pytest_unconfigure(config):
+    if _OWNS_CACHE:
+        shutil.rmtree(os.environ["JAX_COMPILATION_CACHE_DIR"],
+                      ignore_errors=True)
+
+
+class OwnedClock:
+    """A clock that only the test moves, with the two names of ``time``
+    that the program's seams take (``run_proxy(clock=...)``,
+    ``FaultInjector(sleep=...)``): ``sleep`` advances it and nothing
+    else does, so a tier-1 assertion on a duration is one on counts
+    and holds however loaded the host is.  ``time`` itself fits where
+    the ``slow`` lane wants the wall."""
+
+    def __init__(self):
+        self.now = 0.0
+
+    def perf_counter(self) -> float:
+        return self.now
+
+    def sleep(self, seconds: float) -> None:
+        self.now += seconds
+
+
+@pytest.fixture
+def owned_clock():
+    return OwnedClock()
 
 
 @pytest.fixture(scope="session")

@@ -1,0 +1,43 @@
+"""``qwen3next_a3b_train_s16k``'s train step, one layer of each kind,
+compiles for the chip (see ``chip_compile_support.cell_step``)."""
+from __future__ import annotations
+
+from chip_compile_support import (
+    EXPERTS_BWD, cell_program, cell_step, chunk_arrays,
+    kernel_instructions, re_sub_number)
+
+
+def test_linear_moe_train_step_at_the_cell_shapes_compiles_for_the_chip(
+        one_chip, no_persistent_cache):
+    """``qwen3next_a3b_train_s16k``'s step as the runner builds it (the
+    cell's own files, widths, sequence, bound and compiler options, the
+    sweeps that "auto" takes on the chip), cut to one layer of each kind
+    so that it compiles in a minute: the kernels by name (the rule's
+    forward kernel twice a linear layer and its backward once, four
+    attention kernels a full layer, six grouped matmuls a layer and the
+    four kernels of its counted backward), no
+    loop but the head's (none around the rule: all heads go through one
+    call), no chunk matrix but what the rule's kernels write, the state
+    donated, and the temporaries under what let 32 held experts keep
+    the 13.0 GB rule (the whole step's count is in the configuration
+    file)."""
+    from dlnetbench_tpu.core import executor
+    step, cell, arch = cell_step("qwen3next_a3b_train_s16k", one_chip)
+    assert arch["layer_kinds"] == ("gdn", "gated")
+    cfg = cell_program("qwen3next_a3b_train_s16k")[2]
+    mem = step.memory_analysis
+    assert mem["alias"] > 0.99 * mem["argument"]   # the state is donated
+    assert mem["temp"] <= 5.5e9        # 5.39 GB read, PR 33 (5.52, PR 32)
+    text = step.as_text()
+    names = kernel_instructions(text)
+    assert sorted(re_sub_number(k) for k in names) == sorted(
+        ["gdr_fwd"] * 2 + ["gdr_bwd"] + ["flash_fwd"] * 2
+        + ["flash_bwd_dkv"] + ["grouped_mm"] * 12
+        + [*EXPERTS_BWD, "grouped_mm_bwd_dw"] * 2)
+    table = executor.hlo_op_scopes(text)
+    loops = [m.group(1) for line in text.splitlines() if " while(" in line
+             and (m := executor._HLO_INSTRUCTION.match(line))]
+    assert [table[w] for w in loops] == ["head_loss"]
+    made = chunk_arrays(text, cfg.gdn_value_heads,
+                        cell.traffic["seq_len"] // 128)
+    assert set().union(*made.values()) <= {"get-tuple-element", "bitcast"}

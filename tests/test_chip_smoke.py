@@ -38,15 +38,15 @@ def _lines(capsys) -> list[dict]:
     return [json.loads(ln) for ln in out.splitlines() if ln.startswith("{")]
 
 
-@pytest.mark.parametrize("chips,phases", [
-    (1, ("kernels", "train", "proxy", "serve", "moe", "hybrid",
-         "latent_moe", "linear_moe", "conv_moe", "swa_moe")),
-    (4, ("mesh_proxies", "spmd", "kv_shard")),
-], ids=["one_chip", "four_chips"])
-def test_phases_pass_at_tiny_size_and_exit_nonzero_off_tpu(
-        smoke_out, capsys, eight_devices, chips, phases):
-    argv = ["--tiny"] + (["--chips", "4"] if chips == 4 else [])
-    rc = smoke_out.main(argv)
+# the one-chip phases in two cases, the second in
+# tests/test_chip_smoke_hybrids.py: all ten in one case were the suite's
+# longest (227 s of a worker under ``--dist loadfile``)
+MAIN_PATHS = ("kernels", "train", "proxy", "serve", "moe")
+HYBRIDS = ("hybrid", "latent_moe", "linear_moe", "conv_moe", "swa_moe")
+
+
+def check_tiny_phases(smoke_out, capsys, argv, phases):
+    rc = smoke_out.main(["--tiny", *argv])
     lines = _lines(capsys)
     assert rc != 0, "there is no chip here: the smoke must not pass"
     by_phase = {ln["phase"]: ln for ln in lines if "phase" in ln}
@@ -61,6 +61,17 @@ def test_phases_pass_at_tiny_size_and_exit_nonzero_off_tpu(
     # no result line: nothing printed says a TPU ran this
     assert all("phase" in ln for ln in lines)
     assert '"platform": "tpu"' not in json.dumps(lines)
+
+
+@pytest.mark.parametrize("argv,phases", [
+    (["--phases", ",".join(MAIN_PATHS)], MAIN_PATHS),
+    (["--chips", "4"], ("mesh_proxies", "spmd", "kv_shard")),
+], ids=["one_chip", "four_chips"])
+def test_phases_pass_at_tiny_size_and_exit_nonzero_off_tpu(
+        smoke_out, capsys, eight_devices, argv, phases):
+    check_tiny_phases(smoke_out, capsys, argv, phases)
+    # the two one-chip cases leave no phase out
+    assert tuple(n for n, _ in smoke_out.ONE_CHIP) == MAIN_PATHS + HYBRIDS
 
 
 def test_full_size_run_refuses_to_start_off_tpu(smoke, capsys):

@@ -40,8 +40,12 @@ def _run_value_and_grads(fn, mesh, in_specs, out_specs, x, w):
     """One trace for forward + backward of a shard_map'd decomposed op."""
     sm = shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                    check_vma=False)
-    out, vjp = jax.vjp(sm, x, w)
-    return out, vjp(jnp.sin(out))
+
+    @jax.jit        # eagerly, a shard_map runs primitive by primitive
+    def both(x, w):
+        out, vjp = jax.vjp(sm, x, w)
+        return out, vjp(jnp.sin(out))
+    return both(x, w)
 
 
 @pytest.mark.parametrize("n,chunks", [(2, 1), (4, 2)])
@@ -94,11 +98,11 @@ def test_odd_output_width_unidirectional_fallback(eight_devices):
     a = jax.random.normal(jax.random.key(4), (MB, 4 * S_LOC, D),
                           jnp.float32)
     w = jax.random.normal(jax.random.key(5), (D, 1), jnp.float32)
-    out = shard_map(
+    out = jax.jit(shard_map(
         lambda x_, y_: CM.matmul_reduce_scatter(x_, y_, "r",
                                                 scatter_axis=1),
         mesh=mesh, in_specs=(P(None, None, "r"), P("r", None)),
-        out_specs=P(None, "r", None), check_vma=False)(a, w)
+        out_specs=P(None, "r", None), check_vma=False))(a, w)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(jnp.dot(a, w)),
                                rtol=1e-5, atol=1e-6)
@@ -113,11 +117,11 @@ def test_ab_legs_keep_shapes(eight_devices):
                           jnp.float32)
     w = jax.random.normal(jax.random.key(7), (D, K), jnp.float32)
     for leg in ("fake_comm", "fake_compute"):
-        out = shard_map(
+        out = jax.jit(shard_map(
             lambda a, b: CM.all_gather_matmul(a, b, "r", gather_axis=1,
                                               **{leg: True}),
             mesh=mesh, in_specs=(P(None, "r", None), P()),
-            out_specs=P(), check_vma=False)(x, w)
+            out_specs=P(), check_vma=False))(x, w)
         assert out.shape == (MB, 4 * S_LOC, K), leg
         assert np.all(np.isfinite(np.asarray(out))), leg
     # 1-rank axis degenerates to the plain dot exactly

@@ -273,17 +273,34 @@ def test_loss_and_every_gradient_leaf_against_the_reference(
     each layer recomputed: the loss and every leaf's gradient.  At the
     published 24 layers (attention at 2, 6, 10, 14, 18, 21; two leading
     dense layers) with a share held (the reference is given the same
-    share) the loss alone: the pattern adds no kind of leaf to the
-    cut's, and the backward of 24 unrolled layers is half a minute of
-    compiling here.  Every norm's weight away from one."""
+    share): the pattern and the two kinds of FFN on the configuration,
+    the routing's and the weights' shapes from ``jax.eval_shape`` of
+    the 24 layers, and the loss against the reference's through the
+    first eight (both dense layers, attention at 2 and 6, six expert
+    layers): the pattern adds no kind of leaf to the cut's, and 24
+    unrolled layers compiled twice was most of this file's time.
+    Every norm's weight away from one."""
     arch = arch_of(*held, layer_types=types, num_hidden_layers=len(types),
                    num_dense_layers=dense)
     assert [i for i, k in enumerate(arch["layer_kinds"]) if k == "gated"] \
         == ([1] if types is CUT else [2, 6, 10, 14, 18, 21])
-    params, toks = moved(weights.make_params(arch, 2**31 + 5)), tokens()
+    toks = tokens()
     cfg = config(arch, remat=remat, loss_row_block=rows)
     assert cfg.ffn_kinds == ("dense",) * dense + ("moe",) * (len(types)
                                                              - dense)
+    if not leaves_too:
+        shapes = jax.eval_shape(lambda: weights.make_params(arch, 0))
+        assert (shapes["gated"]["wq"].shape[0],
+                shapes["moe"]["w_gate"].shape[:2]) == (6, (22, 8))
+        loss, routing = jax.eval_shape(
+            lambda p: hybrid.loss_and_routing(p, toks, cfg), shapes)
+        assert loss.shape == () and routing["choices"].shape \
+            == (22, SEQ, TOP_K)
+        types = types[:8]
+        arch = arch_of(*held, layer_types=types, num_hidden_layers=8,
+                       num_dense_layers=dense)
+        cfg = config(arch, remat=remat, loss_row_block=rows)
+    params = moved(weights.make_params(arch, 2**31 + 5))
     both = jax.value_and_grad if leaves_too else (lambda f, **kw: f)
     with jax.default_matmul_precision("highest"):
         want = jax.jit(both(lambda p: ref.loss_fn(p, toks, arch)))(params)

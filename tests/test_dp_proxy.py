@@ -11,7 +11,8 @@ from dlnetbench_tpu.metrics.emit import emit_result, result_to_record
 from dlnetbench_tpu.metrics.parser import get_metrics_dataframe, load_records
 from dlnetbench_tpu.parallel.mesh import make_flat_mesh
 from dlnetbench_tpu.proxies import dp as dp_proxy
-from dlnetbench_tpu.proxies.base import ProxyConfig, estimate_runs, run_proxy
+from dlnetbench_tpu.proxies.base import (ProxyConfig, StepBundle,
+                                         estimate_runs, run_proxy)
 
 TINY = dict(size_scale=1e-5, time_scale=2e-4)
 
@@ -166,45 +167,53 @@ def test_cli_buffer_dtype_stats(eight_devices, tmp_path):
     assert [b // 2 for b in f32] == list(bf16)
 
 
-def test_barrier_time_uses_matched_compute_samples():
-    """VERDICT r1 #6: barrier_time[i] must be full[i] - compute[i] with an
-    ADJACENT (A/B-interleaved) compute sample, not full[i] minus an
-    averaged compute time — drifting per-run durations would otherwise
-    leak compute variance into the exposed-comm signal."""
-    import time as _time
-    from dlnetbench_tpu.proxies.base import ProxyConfig, StepBundle, run_proxy
-
-    # Call counts include one warmup (full) / compile (compute) call each,
-    # so measured pairs are (20, 18), (30, 28), (40, 38) ms: matched
-    # subtraction gives ~2 ms for every run, while subtracting the MEAN
-    # compute (28 ms) would give ~[0, 2, 12] ms
+def _barrier_samples_ms(clock) -> list:
+    """``barrier_time`` of three runs whose full and compute legs both
+    drift by 10 ms a call, read on ``clock``.  Call counts include one
+    warmup (full) / compile (compute) call each, so measured pairs are
+    (20, 18), (30, 28), (40, 38) ms: matched subtraction gives 2 ms for
+    every run, while subtracting the MEAN compute (28 ms) would give
+    [0, 2, 12] ms."""
     calls = {"full": 0, "comp": 0}
 
     def full():
-        _time.sleep(0.010 + 0.010 * calls["full"])
+        clock.sleep(0.010 + 0.010 * calls["full"])
         calls["full"] += 1
 
     def compute():
-        _time.sleep(0.008 + 0.010 * calls["comp"])
+        clock.sleep(0.008 + 0.010 * calls["comp"])
         calls["comp"] += 1
 
     bundle = StepBundle(full=full, compute=compute, comm=None,
                         global_meta={"proxy": "t", "world_size": 1})
     cfg = ProxyConfig(warmup=1, runs=3, measure_energy=False)
-    res = run_proxy("t", bundle, cfg)
-    barrier_ms = [t / 1000 for t in res.timers_us["barrier_time"]]
+    res = run_proxy("t", bundle, cfg, clock=clock.perf_counter)
+    return [t / 1000 for t in res.timers_us["barrier_time"]]
+
+
+def test_barrier_time_uses_matched_compute_samples(owned_clock):
+    """VERDICT r1 #6: barrier_time[i] must be full[i] - compute[i] with an
+    ADJACENT (A/B-interleaved) compute sample, not full[i] minus an
+    averaged compute time — drifting per-run durations would otherwise
+    leak compute variance into the exposed-comm signal.  On a clock only
+    the two legs advance, the matched differences are the 2 ms exactly."""
+    assert _barrier_samples_ms(owned_clock) == pytest.approx([2.0] * 3)
+
+
+@pytest.mark.slow
+def test_barrier_time_uses_matched_compute_samples_by_the_wall_clock():
+    """The same legs asleep on the host's clock.  The mean-subtraction
+    bug's signature is the SPREAD ([0, 2, 12]), so the top sample and
+    the median carry the guard; a single low sample is tolerated (a
+    sleep pair can inflate unevenly under load)."""
+    import statistics
+    import time
+    barrier_ms = _barrier_samples_ms(time)
     assert len(barrier_ms) == 3
-    # The mean-subtraction bug's signature is the SPREAD ([0, 2, 12]:
-    # the per-run drift leaks in, blowing the top sample far past the
-    # matched ~2 ms), so the top sample and the median carry the guard.
-    # A single low sample is tolerated: under whole-suite host load a
-    # sleep pair can inflate unevenly and one matched difference clamps
-    # to ~0 (observed flake [0.0, 2.0, 2.8] on the loaded 2-core host).
     assert max(barrier_ms) < 6.0, (
         f"barrier_time {barrier_ms} — matched samples give ~2 ms each; "
         "a spread like [0, 2, 12] means a mean-compute subtraction")
-    import statistics as _stats
-    assert 1.0 < _stats.median(barrier_ms) < 6.0, (
+    assert 1.0 < statistics.median(barrier_ms) < 6.0, (
         f"barrier_time {barrier_ms} — matched samples give ~2 ms each")
     assert sum(1 for b in barrier_ms if b <= 1.0) <= 1, (
         f"barrier_time {barrier_ms} — more than one collapsed sample is "

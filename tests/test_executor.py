@@ -201,7 +201,8 @@ def test_persistent_cache_placement(tmp_path, monkeypatch, eight_devices):
              "jax_persistent_cache_min_entry_size_bytes")
     before = {k: getattr(jax.config, k) for k in knobs}
     try:
-        # conftest turns the cache off for the session
+        # conftest points the session's cache at a temporary directory
+        # through the variable; without it the fixed path is the place
         jax.config.update("jax_enable_compilation_cache", True)
         monkeypatch.delenv(executor.ENV_CACHE_DIR, raising=False)
         repo = Path(__file__).resolve().parent.parent

@@ -4,6 +4,7 @@ policies around the dp proxy on the virtual mesh, record provenance,
 and the analysis layer's straggler/recovery columns."""
 from __future__ import annotations
 
+import dataclasses
 import json
 
 import pytest
@@ -135,18 +136,24 @@ def proxy_cfg():
                        measure_energy=False)
 
 
-def test_straggler_delay_rides_the_runtime_samples(eight_devices, proxy_cfg):
-    """An injected per-step delay must inflate the timed runtime (the
-    sleep lands INSIDE the chain) and be accounted in the
-    fault_delay_us timer."""
-    import dataclasses
-
-    from dlnetbench_tpu.faults.policy import run_faulted
-
-    cfg = dataclasses.replace(proxy_cfg, runs=4)
+def _straggler_plan_and_config(proxy_cfg):
     plan = FaultPlan(events=[FaultEvent(kind="delay", ranks=[1],
                                         iteration=3,
                                         magnitude_us=20000.0)]).validate()
+    return plan, dataclasses.replace(proxy_cfg, runs=4)
+
+
+def test_straggler_delay_rides_the_runtime_samples(eight_devices, proxy_cfg,
+                                                   owned_clock):
+    """An injected per-step delay must inflate the timed runtime (the
+    sleep lands INSIDE the chain) and be accounted in the
+    fault_delay_us timer.  The samples are read on a clock that only
+    the injector's sleep advances: a faulted run is the 20 ms exactly,
+    a clean one nothing."""
+    from dlnetbench_tpu.faults.policy import run_faulted
+    from dlnetbench_tpu.proxies.base import run_proxy
+
+    plan, cfg = _straggler_plan_and_config(proxy_cfg)
     bundle = _dp_bundle(cfg, eight_devices)
     res = run_faulted("dp", bundle, cfg, plan)
     g = res.global_meta
@@ -157,11 +164,28 @@ def test_straggler_delay_rides_the_runtime_samples(eight_devices, proxy_cfg):
     assert len(fd) == cfg.runs
     # window starts at step 3 = measured run 2 (after the 1-step warmup)
     assert fd[0] == fd[1] == 0.0 and fd[2] >= 19999 and fd[3] >= 19999
-    # the faulted samples carry the sleep over the IN-RECORD clean
-    # baseline (runs 0-1, adjacent in time — cross-run medians would be
-    # at the mercy of host drift)
+    # what ``run_faulted`` runs under this plan, with the injector's
+    # sleep and the samples on the test's own clock
+    injector = FaultInjector(plan, world=8, sleep=owned_clock.sleep)
+    res = run_proxy("dp", bundle,
+                    dataclasses.replace(cfg, fault_injector=injector),
+                    clock=owned_clock.perf_counter)
+    assert res.timers_us["runtimes"] == pytest.approx([0, 0, 20000, 20000])
+    assert res.timers_us["fault_delay_us"] == [0, 0, 20000, 20000]
+
+
+@pytest.mark.slow
+def test_straggler_delay_rides_the_runtime_samples_by_the_wall_clock(
+        eight_devices, proxy_cfg):
+    """The faulted samples carry the sleep over the IN-RECORD clean
+    baseline (runs 0-1, adjacent in time: cross-run medians would be at
+    the mercy of host drift)."""
     import statistics
-    rt = res.timers_us["runtimes"]
+
+    from dlnetbench_tpu.faults.policy import run_faulted
+    plan, cfg = _straggler_plan_and_config(proxy_cfg)
+    rt = run_faulted("dp", _dp_bundle(cfg, eight_devices), cfg,
+                     plan).timers_us["runtimes"]
     assert (statistics.median(rt[2:]) - statistics.median(rt[:2])
             >= 15000)
 

@@ -55,8 +55,8 @@ def test_gradients_match_reference(b, s, hq, hkv, dh, causal):
     def loss_flash(q, k, v):
         return jnp.sum(flash_attention(q, k, v, causal, 128, 128) * cot)
 
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    g_fl = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
+    g_fl = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
     for a, b_ in zip(g_ref, g_fl):
         assert jnp.max(jnp.abs(a - b_)) < 5e-4
 
@@ -80,11 +80,11 @@ def test_two_widths_forward_and_gradients_match_reference(hq, hkv, dqk,
     got = flash_attention(q, k, v, True, 128, 128)
     assert got.shape == want.shape == (1, 256, hq, dv)
     assert jnp.max(jnp.abs(got - want)) < 2e-5
-    g_ref = jax.grad(lambda *a: jnp.sum(
-        xla_attention(*a, causal=True) * cot), argnums=(0, 1, 2))(q, k, v)
-    g_fl = jax.grad(lambda *a: jnp.sum(
+    g_ref = jax.jit(jax.grad(lambda *a: jnp.sum(
+        xla_attention(*a, causal=True) * cot), argnums=(0, 1, 2)))(q, k, v)
+    g_fl = jax.jit(jax.grad(lambda *a: jnp.sum(
         flash_attention(*a, True, 128, 128) * cot),
-        argnums=(0, 1, 2))(q, k, v)
+        argnums=(0, 1, 2)))(q, k, v)
     for a, b_ in zip(g_ref, g_fl):
         assert a.shape == b_.shape
         assert jnp.max(jnp.abs(a - b_)) < 5e-4
@@ -348,16 +348,25 @@ def _backward(case, monkeypatch=None):
     return grads, marks, (q, k, v, do, mask)
 
 
+@pytest.fixture(scope="module")
+def resident_backward():
+    """``_backward`` of a named case on the resident path, run once a
+    module: the comparison with the two kernels and the one with the
+    reference read the same gradients."""
+    return functools.cache(lambda name: _backward(RESIDENT_CASES[name]))
+
+
 @longcontext
 @pytest.mark.parametrize("name", sorted(RESIDENT_CASES))
-def test_resident_dq_matches_the_two_kernels(name, monkeypatch):
+def test_resident_dq_matches_the_two_kernels(name, monkeypatch,
+                                             resident_backward):
     """The dk/dv kernel with a head's dq resident against dkv + dq: dq,
     dk and dv bit-equal where both run the same blocks (key blocks
     arrive in the dq kernel's order), else within this file's
     tolerance; each path marks itself once."""
     case = RESIDENT_CASES[name]
     b, s, hq, hkv, dqk, dv, mask, dq_blocks, dkv_blocks = case
-    one, one_marks, _ = _backward(case)
+    one, one_marks, _ = resident_backward(name)
     two, two_marks, _ = _backward(case, monkeypatch)
     mark = {"dq_resident_bytes": s * fa._padded(dqk) * (4 + 2 * 4),
             "block_q": dkv_blocks[0], "block_k": dkv_blocks[1]}
@@ -374,16 +383,16 @@ def test_resident_dq_matches_the_two_kernels(name, monkeypatch):
 @longcontext
 @pytest.mark.parametrize("name", ["causal", "window", "documents_both_ways",
                                   "scores192_values128", "bk_over_bq"])
-def test_resident_dq_matches_reference(name):
+def test_resident_dq_matches_reference(name, resident_backward):
     """And against the einsum reference under the same mask."""
-    (dq, dk, dv), _, (q, k, v, do, mask) = _backward(RESIDENT_CASES[name])
+    (dq, dk, dv), _, (q, k, v, do, mask) = resident_backward(name)
 
     def ref(q, k, v):
         if isinstance(mask, bool):
             return xla_attention(q, k, v, causal=mask)
         return _masked_ref(q, k, v, mask)
-    _, vjp = jax.vjp(ref, q, k, v)
-    for a, c in zip(vjp(do), (dq, dk, dv)):
+    want = jax.jit(lambda *x: jax.vjp(ref, *x[:3])[1](x[3]))(q, k, v, do)
+    for a, c in zip(want, (dq, dk, dv)):
         assert jnp.max(jnp.abs(a - c)) < 5e-4
 
 

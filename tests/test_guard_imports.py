@@ -9,6 +9,9 @@
 3. The package's layers import downward only: ``utils`` -> ``core`` /
    ``metrics`` / ``parallel`` -> ``ops`` -> ``models`` -> ``serving``.
    What still points up is listed here with its reason.
+4. Only the ``topo`` fixture of ``tests/chip_compile_support.py``
+   describes a TPU topology: that call loads the TPU's library, and
+   every xdist worker imports every test file.
 """
 from __future__ import annotations
 
@@ -105,6 +108,34 @@ def test_lower_layers_do_not_import_upward(package):
     assert listed - found == set(), (
         f"exceptions that match no import any more, delete them: "
         f"{sorted(listed - found)}")
+
+
+def _names_topology_desc(node) -> bool:
+    return "get_topology_desc" in (
+        getattr(node, "attr", None), getattr(node, "id", None),
+        *(a.name for a in getattr(node, "names", ())
+          if isinstance(node, ast.ImportFrom)))
+
+
+def test_no_tier1_file_describes_a_topology_outside_its_fixture():
+    """``get_topology_desc`` loads the TPU's library into the process.
+    Named at a module's top level it would load it into every worker
+    that imports the file; named inside the one fixture, only a case
+    that asks for ``topo`` does."""
+    found = set()
+    for path in (REPO / "tests").rglob("*.py"):
+        tree = ast.parse(path.read_text())
+        inside = {id(n): fn for fn in ast.walk(tree)
+                  if isinstance(fn, ast.FunctionDef)
+                  and any("fixture" in ast.unparse(d)
+                          for d in fn.decorator_list)
+                  for n in ast.walk(fn)}
+        for node in ast.walk(tree):
+            if _names_topology_desc(node):
+                fn = inside.get(id(node))
+                found.add((path.relative_to(REPO).as_posix(),
+                           fn.name if fn else None))
+    assert found == {("tests/chip_compile_support.py", "topo")}
 
 
 def test_collection_is_clean():

@@ -39,8 +39,8 @@ def case():
     params = weights_hybrid.make_params(arch, 2**31 + 5)
     tokens = jax.random.randint(jax.random.key(1), (2, 65), 0, 256)
     with jax.default_matmul_precision("highest"):
-        want = jax.value_and_grad(
-            lambda p: ref.loss_fn(p, tokens, arch))(params)
+        want = jax.jit(jax.value_and_grad(
+            lambda p: ref.loss_fn(p, tokens, arch)))(params)
     return arch, params, tokens, want
 
 
@@ -60,7 +60,8 @@ def test_benchmark_weights_follow_the_programs_layout():
     arch = weights_hybrid.arch_of(CONFIG)
     assert weights_hybrid.shapes(arch) == hybrid.param_shapes(config())
     made = weights_hybrid.make_params(arch, 3)
-    own = hybrid.init_params(jax.random.key(3), config())
+    own = jax.eval_shape(
+        lambda: hybrid.init_params(jax.random.key(3), config()))
     assert jax.tree.map(lambda a: (a.shape, a.dtype), made) \
         == jax.tree.map(lambda a: (a.shape, a.dtype), own)
 
@@ -92,10 +93,11 @@ def test_full_layers_keys_and_values_hold_every_cross_layers_part(case):
     cfg = config()
     real = hybrid.diff_attention
 
-    def grad_with_cut(cut_layers):
+    @jax.jit        # one build: which layers are cut is an argument
+    def grad_with_cut(is_cut):
         def cut(cfg_, y, p, kv, li, window):
-            if li in cut_layers:
-                kv = jax.lax.stop_gradient(kv)
+            kv = jax.tree.map(lambda a: jnp.where(
+                is_cut[li], jax.lax.stop_gradient(a), a), kv)
             return real(cfg_, y, p, kv, li, window)
         hybrid.diff_attention = cut
         try:
@@ -106,7 +108,9 @@ def test_full_layers_keys_and_values_hold_every_cross_layers_part(case):
             hybrid.diff_attention = real
     cross = [li for li, k in enumerate(KINDS) if k == "cross"]
     assert cross == [7, 9]
-    cut7, cut9, both = (grad_with_cut(c) for c in ({7}, {9}, {7, 9}))
+    cut7, cut9, both = (
+        grad_with_cut(jnp.isin(jnp.arange(len(KINDS)), jnp.array(c)))
+        for c in ([7], [9], [7, 9]))
     full = cfg.index_in_group(KINDS.index("full"))
     for leaf in ("wk", "wv"):
         g, g7, g9, g0 = (t["attn"][leaf][full]

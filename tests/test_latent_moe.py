@@ -64,7 +64,8 @@ def test_benchmark_weights_follow_the_programs_layout():
         == {k: shape for k, (shape, _)
             in hybrid.param_shapes(config(arch)).items()}
     made = weights.make_params(arch, 3)
-    own = hybrid.init_params(jax.random.key(3), config(arch))
+    own = jax.eval_shape(
+        lambda: hybrid.init_params(jax.random.key(3), config(arch)))
     assert jax.tree.map(lambda a: (a.shape, a.dtype), made) \
         == jax.tree.map(lambda a: (a.shape, a.dtype), own)
     assert float(jnp.abs(made["moe"]["router_bias"]).max()) > 0
@@ -80,8 +81,8 @@ def test_loss_and_every_gradient_leaf_against_the_reference(
     params, toks = weights.make_params(arch, 2**31 + 5), tokens()
     cfg = config(arch, remat=remat, loss_row_block=rows)
     with jax.default_matmul_precision("highest"):
-        want_loss, want = jax.value_and_grad(
-            lambda p: ref.loss_fn(p, toks, arch))(params)
+        want_loss, want = jax.jit(jax.value_and_grad(
+            lambda p: ref.loss_fn(p, toks, arch)))(params)
         (loss, routing), grad = jax.jit(jax.value_and_grad(
             lambda p: hybrid.loss_and_routing(p, toks, cfg),
             has_aux=True))(params)

@@ -82,8 +82,9 @@ def test_moe_gather_gradients_match_dense_at_full_capacity(impl):
     gather_cfg = dataclasses.replace(
         cfg, moe_impl=impl,
         moe_capacity_factor=cfg.num_experts / cfg.top_k)
-    want = jax.value_and_grad(tfm.loss_fn)(params, tokens, cfg)
-    got = jax.value_and_grad(tfm.loss_fn)(params, tokens, gather_cfg)
+    want, got = (jax.jit(jax.value_and_grad(
+        lambda p, c=c: tfm.loss_fn(p, tokens, c)))(params)
+        for c in (cfg, gather_cfg))
     assert abs(float(got[0]) - float(want[0])) < 1e-5
     for (path, a), b in zip(jax.tree.leaves_with_path(got[1]),
                             jax.tree.leaves(want[1])):
@@ -159,10 +160,11 @@ def test_vit_forward_and_grad():
                             "dtype": "float32"})
     params = vitm.init_params(jax.random.key(0), cfg)
     images = jax.random.normal(jax.random.key(1), (2, 32, 32, 3))
-    logits = vitm.forward(params, images, cfg)
+    logits = jax.jit(lambda p: vitm.forward(p, images, cfg))(params)
     assert logits.shape == (2, 10)
     labels = jnp.array([1, 3])
-    g = jax.grad(vitm.loss_fn)(params, images, labels, cfg)
+    g = jax.jit(jax.grad(
+        lambda p: vitm.loss_fn(p, images, labels, cfg)))(params)
     leaves = jax.tree.leaves(g)
     assert all(np.all(np.isfinite(np.asarray(x, dtype=np.float32)))
                for x in leaves)
@@ -189,7 +191,8 @@ def test_remat_agrees_with_no_remat():
     tokens = jax.random.randint(jax.random.key(1), (2, 17), 0, 64)
 
     def lg(cfg):
-        return jax.value_and_grad(tfm.loss_fn)(params, tokens, cfg)
+        return jax.jit(jax.value_and_grad(
+            lambda p: tfm.loss_fn(p, tokens, cfg)))(params)
 
     l0, g0 = lg(cfg0)
     for scan_layers in (True, False):

@@ -95,8 +95,8 @@ def test_plan_dispatch_and_combine_match_onehot(cf, path):
         xe, disp, gate = _onehot_dispatch(x, wr, 4, 2, cf, **kw)
         return jnp.sum(jnp.sin(_onehot_combine(jnp.tanh(xe) * eo, disp,
                                                gate)))
-    got = jax.value_and_grad(new, argnums=(0, 1, 2))(x, wr, eo)
-    want = jax.value_and_grad(old, argnums=(0, 1, 2))(x, wr, eo)
+    got = jax.jit(jax.value_and_grad(new, argnums=(0, 1, 2)))(x, wr, eo)
+    want = jax.jit(jax.value_and_grad(old, argnums=(0, 1, 2)))(x, wr, eo)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         scale = max(1.0, float(jnp.max(jnp.abs(b))))
         assert float(jnp.max(jnp.abs(a - b))) <= 1e-6 * scale
@@ -379,8 +379,8 @@ def test_grouped_ffn_grads_match_reference(counts, h, blocks):
     def ref(x_, a, b, c):
         return jnp.sum(_ffn_ref(x_, a, b, c, live) * r)
 
-    g1 = jax.grad(loss, argnums=(0, 1, 2, 3))(x, wg, wu, wd)
-    g2 = jax.grad(ref, argnums=(0, 1, 2, 3))(x, wg, wu, wd)
+    g1 = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3)))(x, wg, wu, wd)
+    g2 = jax.jit(jax.grad(ref, argnums=(0, 1, 2, 3)))(x, wg, wu, wd)
     for a, b in zip(g1, g2):
         assert float(jnp.max(jnp.abs(a - b))) < 1e-5
     if live is not None:
@@ -435,12 +435,43 @@ _BWD_KERNELS = ["grouped_mm_bwd_dh", "grouped_mm_bwd_dx",
                 "grouped_mm_bwd_dw", "grouped_mm_bwd_dw"]
 
 
+@pytest.fixture(scope="module")
+def counted_backward_grads():
+    """``grads(h, blocks)(x, wg, wu, wd, cnt, live, r)``: the four
+    gradients under ``backward="counted"``, under the einsum backward
+    and of the einsum reference, jitted once a width and tiling.  The
+    counts are an argument, so the grid's count cases share the build
+    (interpret-mode kernels traced and compiled once, not a case)."""
+    built = {}
+
+    def grads(h, blocks):
+        key = (h, tuple(sorted(blocks.items())))
+        if key not in built:
+            def loss(backward):
+                return lambda x_, a, b, c, cnt, r: jnp.sum(gm.grouped_ffn(
+                    x_, a, b, c, counts=cnt, backward=backward, **blocks)
+                    * r)
+
+            def ref(x_, a, b, c, live, r):
+                return jnp.sum(_ffn_ref(x_, a, b, c, live) * r)
+
+            def all_three(x, wg, wu, wd, cnt, live, r):
+                w = (x, wg, wu, wd)
+                return (jax.grad(loss("counted"), (0, 1, 2, 3))(*w, cnt, r),
+                        jax.grad(loss("einsum"), (0, 1, 2, 3))(*w, cnt, r),
+                        jax.grad(ref, (0, 1, 2, 3))(*w, live, r))
+            built[key] = jax.jit(all_three)
+        return built[key]
+    return grads
+
+
 @pytest.mark.parametrize("blocks", [
     _GM_BLOCKS, dict(block_c=4, block_n=8, block_k=8), dict(block_c=4)],
     ids=["pow2", "nk_gt_1", "whole_k"])
 @pytest.mark.parametrize("h", [48, 88])
 @pytest.mark.parametrize("counts", _BWD_COUNTS)
-def test_counted_backward_matches_reference_and_einsums(counts, h, blocks):
+def test_counted_backward_matches_reference_and_einsums(
+        counts, h, blocks, counted_backward_grads):
     """``backward="counted"``: all four gradients against the einsum
     reference and against the einsum backward, with x and the
     cotangent nonzero past every count.  A block is live in the
@@ -452,17 +483,8 @@ def test_counted_backward_matches_reference_and_einsums(counts, h, blocks):
     r = jax.random.normal(jax.random.key(7), x.shape, _F32)
     cnt = jnp.array(_BWD_COUNTS[counts], jnp.int32)
     live = ((jnp.arange(16)[None, :] // 4 * 4) < cnt[:, None]).astype(_F32)
-
-    def loss(backward):
-        return lambda x_, a, b, c: jnp.sum(gm.grouped_ffn(
-            x_, a, b, c, counts=cnt, backward=backward, **blocks) * r)
-
-    def ref(x_, a, b, c):
-        return jnp.sum(_ffn_ref(x_, a, b, c, live) * r)
-
-    got = jax.grad(loss("counted"), argnums=(0, 1, 2, 3))(x, wg, wu, wd)
-    was = jax.grad(loss("einsum"), argnums=(0, 1, 2, 3))(x, wg, wu, wd)
-    want = jax.grad(ref, argnums=(0, 1, 2, 3))(x, wg, wu, wd)
+    got, was, want = counted_backward_grads(h, blocks)(
+        x, wg, wu, wd, cnt, live, r)
     for a, b, c in zip(got, was, want):
         assert float(jnp.max(jnp.abs(a - c))) < 1e-5
         assert float(jnp.max(jnp.abs(a - b))) < 1e-5

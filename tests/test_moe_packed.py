@@ -287,14 +287,25 @@ def weights(shape, seed, dtype=_F32):
             * 0.2).astype(dtype)
 
 
+# the kernels under test, each jitted once a module: the counts are an
+# argument, so the count cases of a test share one build of its
+# interpret-mode kernel (``ROW_BLOCK`` is ``BC`` in every case here)
+grouped_mm = jax.jit(gm.grouped_matmul, static_argnames=(
+    "bound", "block_n", "block_k"))
+bwd_rows = jax.jit(gm._bwd_rows, static_argnums=3, static_argnames=(
+    "name", "act", "bound"))
+bwd_dw = jax.jit(gm._bwd_dw, static_argnums=(3, 4), static_argnames=(
+    "name", "bound"))
+
+
 @pytest.mark.parametrize("blocks", [{}, {"block_n": 128, "block_k": 16}],
                          ids=["whole_k", "k_in_blocks"])
 @pytest.mark.parametrize("name", sorted(COUNTS))
 def test_packed_grouped_mm_against_an_einsum_over_the_live_rows(name, blocks):
     x, xp, counts, start = packed_case(COUNTS[name])
     w = weights((E, KD, N), 1)
-    out = gm.grouped_matmul(x, w, counts=counts, bound=C, **blocks)
-    pad = gm.grouped_matmul(xp, w, counts=counts, **blocks)
+    out = grouped_mm(x, w, counts=counts, bound=C, **blocks)
+    pad = grouped_mm(xp, w, counts=counts, **blocks)
     assert out.shape == (1, x.shape[1], N)
     for i, got, same in live(out, pad, counts, start):
         n = got.shape[0]
@@ -317,11 +328,11 @@ def test_packed_bwd_dh_against_an_einsum_over_the_live_rows(name, act):
     g, gp, _, _ = packed_case(COUNTS[name], 3)
     u, up, _, _ = packed_case(COUNTS[name], 4)
     w_down = weights((E, KD, KD), 5)            # [E, f, d], f = d = KD
-    got = gm._bwd_rows((dy,), (w_down,), counts, (BC, None, None),
-                       swiglu=(g, u), name="grouped_mm_bwd_dh", act=act,
-                       bound=C)
-    pad = gm._bwd_rows((dyp,), (w_down,), counts, (BC, None, None),
-                       swiglu=(gp, up), name="grouped_mm_bwd_dh", act=act)
+    got = bwd_rows((dy,), (w_down,), counts, (BC, None, None),
+                   swiglu=(g, u), name="grouped_mm_bwd_dh", act=act,
+                   bound=C)
+    pad = bwd_rows((dyp,), (w_down,), counts, (BC, None, None),
+                   swiglu=(gp, up), name="grouped_mm_bwd_dh", act=act)
     for i in range(E):
         n = int(counts[i])
         dh = jnp.einsum("cd,fd->cf", dyp[i, :n], w_down[i],
@@ -340,10 +351,10 @@ def test_packed_bwd_dx_against_an_einsum_over_the_live_rows(name):
     dg, dgp, counts, start = packed_case(COUNTS[name], 6)
     du, dup, _, _ = packed_case(COUNTS[name], 7)
     w_gate, w_up = weights((E, N, KD), 8), weights((E, N, KD), 9)
-    got = gm._bwd_rows((dg, du), (w_gate, w_up), counts, (BC, None, None),
-                       name="grouped_mm_bwd_dx", bound=C)
-    pad = gm._bwd_rows((dgp, dup), (w_gate, w_up), counts, (BC, None, None),
-                       name="grouped_mm_bwd_dx")
+    got = bwd_rows((dg, du), (w_gate, w_up), counts, (BC, None, None),
+                   name="grouped_mm_bwd_dx", bound=C)
+    pad = bwd_rows((dgp, dup), (w_gate, w_up), counts, (BC, None, None),
+                   name="grouped_mm_bwd_dx")
     assert got.shape == (1, dg.shape[1], N)
     for i, rows, same in live(got, pad, counts, start):
         n = rows.shape[0]
@@ -361,10 +372,10 @@ def test_packed_bwd_dw_against_an_einsum_over_the_live_rows(name):
     an expert with no row writing zeros."""
     x, xp, counts, start = packed_case(COUNTS[name], 10)
     b = [packed_case(COUNTS[name], 11 + j) for j in range(2)]
-    got = gm._bwd_dw(x, tuple(p[0] for p in b), counts, (BC, None, None),
-                     (_F32, _F32), name="grouped_mm_bwd_dw", bound=C)
-    pad = gm._bwd_dw(xp, tuple(p[1] for p in b), counts, (BC, None, None),
-                     (_F32, _F32), name="grouped_mm_bwd_dw")
+    got = bwd_dw(x, tuple(p[0] for p in b), counts, (BC, None, None),
+                 (_F32, _F32), name="grouped_mm_bwd_dw", bound=C)
+    pad = bwd_dw(xp, tuple(p[1] for p in b), counts, (BC, None, None),
+                 (_F32, _F32), name="grouped_mm_bwd_dw")
     for dw, same, (_, bp, _, _) in zip(got, pad, b):
         assert dw.shape == (E, KD, KD)
         want = jnp.einsum("eck,ecn->ekn", xp, bp, precision="highest")

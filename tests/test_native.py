@@ -295,24 +295,54 @@ def test_loop_mode_runs_forever(native_bin):
         subprocess.run(cmd, capture_output=True, timeout=3)
 
 
+_BUBBLE_GRIDS = ((2, 8), (4, 4))     # (S, M) at fixed S * M
+
+
+def _bubble_records(native_bin, schedule, *extra) -> dict:
+    """{(S, M): the record of hybrid_2d at that grid}."""
+    return {(S, M): run_proxy(native_bin, "hybrid_2d", "--num_stages", S,
+                              "--num_microbatches", M, "--dp", 1,
+                              "--schedule", schedule, *extra, world=S)
+            for S, M in _BUBBLE_GRIDS}
+
+
 @pytest.mark.parametrize("schedule", [
     "gpipe",  # the default-lane bubble representative
     pytest.param("1f1b", marks=[pytest.mark.slow, pytest.mark.native_slow]),
 ])
 def test_native_pipeline_bubble(native_bin, schedule):
-    """The native engine realizes the GPipe fill/drain bubble through its
-    blocking rendezvous send/recv chain (reference hybrid_2d.cpp:106-133):
-    at fixed S*M, runtime scales with (M+S-1)/(S*M), not M/(S*M).
-    S=2,M=8 -> 9/16 model-time units; S=4,M=4 -> 7/16; expected ratio
-    ~7/9 = 0.78, vs ~0.5 if stages never waited for upstream compute."""
-    times = {}
-    for S, M in ((2, 8), (4, 4)):
-        rec = run_proxy(native_bin, "hybrid_2d", "--num_stages", S,
-                        "--num_microbatches", M, "--dp", 1,
-                        "--schedule", schedule, "--time_scale", "0.05",
-                        "--runs", 3, world=S)
-        assert rec["global"]["ticks_per_direction"] == M + S - 1
-        times[S] = min(rec["ranks"][0]["runtimes"])
+    """The native engine's pipeline clock counts the GPipe fill/drain
+    bubble of its blocking rendezvous send/recv chain (reference
+    hybrid_2d.cpp:106-133): at fixed S*M an iteration spans (M+S-1)
+    slots a direction, not M.  S=2,M=8 -> 9/16 of the S*M model-time
+    units; S=4,M=4 -> 7/16; ratio 7/9, vs 1/2 if stages never waited for
+    upstream compute.  On the record's own slot counts: a ratio of
+    measured runtimes is the ``slow`` lane's
+    (``test_native_pipeline_bubble_by_the_wall_clock``)."""
+    share = {}
+    for (S, M), rec in _bubble_records(native_bin, schedule).items():
+        g = rec["global"]
+        assert (g["num_stages"], g["num_microbatches"]) == (S, M)
+        assert g["ticks_per_direction"] == M + S - 1
+        # one unit a forward slot, two a backward (the stat model)
+        assert g["ticks_total"] == pytest.approx(3 * (M + S - 1))
+        assert len(rec["ranks"]) == S
+        share[S] = g["ticks_total"] / (3 * S * M)
+    assert share == pytest.approx({2: 9 / 16, 4: 7 / 16})
+    assert share[4] / share[2] == pytest.approx(7 / 9)
+
+
+@pytest.mark.slow
+@pytest.mark.native_slow
+@pytest.mark.parametrize("schedule", ["gpipe", "1f1b"])
+def test_native_pipeline_bubble_by_the_wall_clock(native_bin, schedule):
+    """The engine REALIZES that clock: measured runtime scales with
+    (M+S-1)/(S*M); expected ratio ~7/9 = 0.78, vs ~0.5 if stages never
+    waited for upstream compute."""
+    recs = _bubble_records(native_bin, schedule, "--time_scale", "0.05",
+                           "--runs", 3)
+    times = {S: min(rec["ranks"][0]["runtimes"])
+             for (S, _), rec in recs.items()}
     ratio = times[4] / times[2]
     assert 0.62 < ratio < 0.95, (
         f"{schedule}: t(S=4)/t(S=2) = {ratio:.3f}; expected ~0.78 "

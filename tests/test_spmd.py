@@ -56,7 +56,14 @@ def test_spmd_int8_mlp_step_runs_and_learns(eight_devices):
     assert losses[0] == pytest.approx(float(l_m), rel=0.05)
 
 
-def test_spmd_matches_dataparallel_only(eight_devices):
+@pytest.fixture(scope="module")
+def single_device_step():
+    """The one-device reference step at lossless EP capacity, built once
+    for the cases that hold a mesh's step to it."""
+    return spmd.build(1, spmd.SpmdConfig(capacity_factor=8.0))[2]
+
+
+def test_spmd_matches_dataparallel_only(eight_devices, single_device_step):
     """pp=tp=1 (pure dp) must equal full dp x pp x tp on the same data to
     within numerical tolerance — the parallelism must not change the math.
     Capacity is set lossless (cap >= T*k): with finite capacity the EP
@@ -64,9 +71,8 @@ def test_spmd_matches_dataparallel_only(eight_devices):
     so only the no-drop regime is bitwise-comparable across tp."""
     cfg = spmd.SpmdConfig(capacity_factor=8.0)
     _, _, step8, params, tokens = spmd.build(8, cfg)
-    _, _, step1, _, _ = spmd.build(1, cfg)
     p8, l8 = step8(params, tokens)
-    p1, l1 = step1(params, tokens)
+    p1, l1 = single_device_step(params, tokens)
     assert float(l8) == pytest.approx(float(l1), rel=2e-3)
     # spot-check a parameter after one update
     d8 = np.asarray(p8["layers"]["wq"], dtype=np.float32)
@@ -75,15 +81,15 @@ def test_spmd_matches_dataparallel_only(eight_devices):
 
 
 @pytest.mark.parametrize("sp_mode", ["ring", "ulysses"])
-def test_spmd_sequence_parallel_modes_match(eight_devices, sp_mode):
+def test_spmd_sequence_parallel_modes_match(eight_devices, sp_mode,
+                                            single_device_step):
     """ring / ulysses attention (ops/sequence_parallel.py) must produce the
     same training step as megatron SP and as the single-device reference
     (lossless EP capacity, see test_spmd_matches_dataparallel_only)."""
     cfg = spmd.SpmdConfig(capacity_factor=8.0, sp_mode=sp_mode)
     _, _, step8, params, tokens = spmd.build(8, cfg)
-    _, _, step1, _, _ = spmd.build(1, spmd.SpmdConfig(capacity_factor=8.0))
     p8, l8 = step8(params, tokens)
-    p1, l1 = step1(params, tokens)
+    p1, l1 = single_device_step(params, tokens)
     assert float(l8) == pytest.approx(float(l1), rel=2e-3)
     d8 = np.asarray(p8["layers"]["wq"], dtype=np.float32)
     d1 = np.asarray(p1["layers"]["wq"], dtype=np.float32)

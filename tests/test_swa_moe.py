@@ -394,22 +394,38 @@ def test_loss_and_every_gradient_leaf_against_the_reference(
     """The whole model at the cell's pattern (two periods ``[full,
     window, window, window]``, 16 of 64 experts held, each layer
     recomputed, head and loss in row blocks): the loss and every leaf's
-    gradient.  At the published 52 layers (full at 0, 4, ..., 48) the
-    loss alone: the pattern adds no kind of leaf to the cut's, and the
-    backward of 52 unrolled layers is a minute of compiling here (and
-    the 52 expert layers are traced once there, as one jitted function
-    that the step calls: tracing 156 kernels in interpret mode is a
-    quarter of a minute)."""
-    if not leaves_too:
-        monkeypatch.setattr(hybrid, "moe_held", jax.jit(
-            moe.moe_held, static_argnums=(5,), static_argnames=(
-                "held", "slots", "scoring", "scale", "activation")))
+    gradient.  At the published 52 layers (full at 0, 4, ..., 48),
+    another share held, nothing recomputed and the loss over whole
+    logits: the pattern and the kinds' counts on the configuration, the
+    routing's and the weights' shapes from ``jax.eval_shape`` of the 52
+    layers, and the loss against the reference's through the first
+    three periods.  (The pattern repeats every four layers and adds no
+    kind of leaf to the cut's; compiling 52 unrolled layers for both
+    sides was 20 s here for the same comparison 13 times over.)"""
     arch = arch_of(*held, layout=layout)
     assert [i for i, k in enumerate(arch["layer_kinds"]) if k == "nope"] \
         == list(range(0, len(layout), 4))
-    params, toks = moved(weights.make_params(arch, 2**31 + 5)), tokens()
+    toks = tokens()
     cfg = config(arch, remat=remat, loss_row_block=rows)
     assert cfg.ffn_kinds == ("moe",) * len(layout) and not cfg.tied_head
+    if not leaves_too:
+        # the expert layers are traced once, as one jitted function
+        # that the step calls (156 kernels in interpret mode otherwise)
+        monkeypatch.setattr(hybrid, "moe_held", jax.jit(
+            moe.moe_held, static_argnums=(5,), static_argnames=(
+                "held", "slots", "scoring", "scale", "activation")))
+        assert len(layout) == 52 and cfg.layer_kinds.count("nope") == 13
+        shapes = jax.eval_shape(lambda: weights.make_params(arch, 0))
+        assert shapes["gated"]["wq"].shape[0] == shapes["moe"][
+            "w_gate"].shape[0] == 52
+        loss, routing = jax.eval_shape(
+            lambda p: hybrid.loss_and_routing(p, toks, cfg), shapes)
+        assert loss.shape == () and routing["choices"].shape \
+            == (52, SEQ, TOP_K)
+        layout = layout[:12]
+        arch = arch_of(*held, layout=layout)
+        cfg = config(arch, remat=remat, loss_row_block=rows)
+    params = moved(weights.make_params(arch, 2**31 + 5))
     both = jax.value_and_grad if leaves_too else (lambda f, **kw: f)
     with jax.default_matmul_precision("highest"):
         want = jax.jit(both(lambda p: ref.loss_fn(p, toks, arch)))(params)
