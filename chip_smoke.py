@@ -137,6 +137,17 @@ class Sizes:
         head_dim=128, sliding_window_size=256, moe_ffn_hidden_size=128,
         vocab_size=512))
     w_seq: int = 1024
+    # headgate_moe: the published 128 lanes a head, window layers in
+    # groups of nine and full layers in groups of six over one key/value
+    # head, a leading dense layer and the period [window, window,
+    # window, full], a quarter of the experts held
+    g_shape: dict = dataclasses.field(default_factory=lambda: dict(
+        hidden_size=256, num_attention_heads=6, num_key_value_heads=1,
+        head_dim=128, sliding_window=256, intermediate_size=512,
+        moe_intermediate_size=128, shared_expert_intermediate_size=128,
+        vocab_size=512))
+    g_window_heads: int = 9
+    g_seq: int = 1024
     # four chips
     c4_fsdp_model: str = "llama3_8b_16_bfloat16"
     c4_fsdp_scale: float = 0.125
@@ -190,6 +201,11 @@ TINY = Sizes(
                  num_key_value_heads=1, head_dim=16, sliding_window_size=32,
                  moe_ffn_hidden_size=32, vocab_size=256),
     w_seq=128,
+    g_shape=dict(hidden_size=64, num_attention_heads=6,
+                 num_key_value_heads=1, head_dim=16, sliding_window=32,
+                 intermediate_size=128, moe_intermediate_size=32,
+                 shared_expert_intermediate_size=32, vocab_size=256),
+    g_seq=128,
     c4_fsdp_scale=1e-5, c4_h3d_scale=1e-5, c4_time_scale=1e-4, c4_batch=4,
     c4_seq=128,
 )
@@ -1254,6 +1270,96 @@ def phase_swa_moe(sz: Sizes) -> dict:
                       "bound; window, full and expert kernels compiled in"}
 
 
+# ---------------------------------------------------- phase: headgate_moe
+
+def phase_headgate_moe(sz: Sizes) -> dict:
+    """The head-gated window-and-full attention expert decoder
+    (models/hybrid.py: a ``gated`` layer with a dense MLP, three ``swa``
+    layers at nine query heads over one key/value head and a ``gated``
+    layer at six in stacks of their own, RoPE on every lane of a window
+    layer and YaRN on half the lanes of a full one, one sigmoid gate a
+    head, softmax-routed experts times 2.5 of which 2 of the router's 8
+    are held beside a plain shared one, the head untied) through the
+    same step builder and executor as ``phase_train``, the dense and the
+    block-sparse attention kernels forced, against the benchmark's plain
+    float32 reference on the same seeded weights."""
+    from benchmarks import reference_headgate_moe, weights_headgate_moe
+    from benchmarks.runners import train_headgate_moe
+    from dlnetbench_tpu.core import executor
+    from dlnetbench_tpu.models import bench_step
+
+    kinds = ["full_attention"] + ["sliding_attention"] * 3 \
+        + ["full_attention"]
+    full = sz.g_shape["num_attention_heads"]
+    config = {
+        **sz.g_shape, "num_hidden_layers": 5, "layer_types": kinds,
+        "mlp_layer_types": ["dense"] + ["sparse"] * 4,
+        "mlp_only_layers": [0], "gating_types": ["per_head"] * 5,
+        "gating": "per-head",
+        "num_attention_heads_per_layer": [
+            full if k == "full_attention" else sz.g_window_heads
+            for k in kinds],
+        "num_experts": 2, "published": {"num_experts": 8},
+        "num_experts_per_tok": 3, "norm_topk_prob": True,
+        "decoder_sparse_step": 1, "moe_routed_scaling_factor": 2.5,
+        "moe_router_logit_softcapping": 0,
+        "moe_apply_router_weight_on_input": False,
+        "attention_bias": False, "rms_norm_eps": 1e-6,
+        "rope_parameters": {
+            # the original positions inside the sequence, so that the
+            # ramp and the factor both act on it
+            "full_attention": {
+                "rope_theta": 500000, "rope_type": "yarn", "factor": 8,
+                "original_max_position_embeddings": sz.g_seq // 4,
+                "beta_slow": 1, "beta_fast": 32,
+                "attention_factor": 1.4852030263919618,
+                "partial_rotary_factor": 0.5},
+            "sliding_attention": {"rope_type": "default",
+                                  "rope_theta": 10000,
+                                  "partial_rotary_factor": 1}},
+        "tie_word_embeddings": False, "torch_dtype": sz.dtype,
+        "assumed": {"first_held_expert": 2}}
+    arch = weights_headgate_moe.arch_of(config)
+    slots = 2 * sz.g_seq        # every row of the batch: no bound to reach
+    cfg = train_headgate_moe.config_of(
+        arch, sz.g_seq, slots, remat=True, attention_impl="flash",
+        loss_row_block=sz.g_seq)
+
+    def make_params():
+        return weights_headgate_moe.make_params(arch, sz.seed)
+    tokens = weights_headgate_moe.make_token_pool(
+        sz.seed, 1, 2, sz.g_seq + 1, arch["vocab_size"])[0]
+    want = reference_headgate_moe.sgd_steps(
+        make_params, [tokens] * sz.h_k, arch, sz.h_lr)
+    prog = executor.CompiledProgram(executor.Program(
+        fn=bench_step.make_train_k(cfg, sz.h_k, sz.h_lr),
+        args=(make_params(), tokens),
+        donate_argnums=bench_step.DONATE_ARGNUMS))
+    kernels = prog.as_text().count("tpu_custom_call")
+    if on_tpu():
+        # the attention kernels of either kind, three grouped matmuls
+        # and the counted backward's four a layer, each at least once
+        require(kernels >= 13, f"compiled head-gated step holds "
+                               f"{kernels} tpu_custom_call")
+    got, routing, gap = expert_step_checks("head-gated", prog, want, sz.h_k)
+    return {"shapes": {**sz.g_shape, "window_heads": sz.g_window_heads,
+                       "seq": sz.g_seq, "batch": 2,
+                       "layers": list(arch["layer_kinds"]), "experts": 8,
+                       "held": list(arch["held"]), "top_k": 3,
+                       "slots": slots, "steps": sz.h_k, "lr": sz.h_lr},
+            "tpu_custom_calls": kernels,
+            "memory_analysis": prog.memory_analysis,
+            "losses": [round(v, 4) for v in got],
+            "float32_losses": [round(v, 4) for v in want["losses"]],
+            "rows_routed_to_held": int(routing["routed"][0]),
+            "selection_gap": gap,
+            "checks": "first loss, the loss's fall and the selections "
+                      "within tolerance of "
+                      "benchmarks/reference_headgate_moe.py; no row past "
+                      "the bound; window, full and expert kernels "
+                      "compiled in"}
+
+
 # ------------------------------------------------------ four-chip phases
 
 def all_device_ids() -> set:
@@ -1464,7 +1570,8 @@ ONE_CHIP = (("kernels", phase_kernels), ("train", phase_train),
             ("latent_moe", phase_latent_moe),
             ("linear_moe", phase_linear_moe),
             ("conv_moe", phase_conv_moe),
-            ("swa_moe", phase_swa_moe))
+            ("swa_moe", phase_swa_moe),
+            ("headgate_moe", phase_headgate_moe))
 FOUR_CHIPS = (("mesh_proxies", phase_mesh_proxies), ("spmd", phase_spmd),
               ("kv_shard", phase_kv_shard))
 

@@ -92,9 +92,11 @@ class ModelCard:
     # lanes (0 => embed_dim / num_heads), RoPE on the first rope_dim
     attn_head_dim: int = 0
     rope_dim: int = 0
-    attn_output_gate: bool = True   # false: the heads' output ungated,
-                                    # the query projection without lanes
-                                    # for a gate
+    attn_output_gate: bool | str = True  # false: the heads' output
+                                    # ungated, the query projection
+                                    # without lanes for a gate; "head":
+                                    # one sigmoid gate a head from a
+                                    # projection [D, H] of its own
     attn_head_norm: bool = True     # false: queries and keys not normed
                                     # a head ("swa" and "nope" layers are
                                     # "gated" ones with a window and RoPE,
@@ -108,6 +110,16 @@ class ModelCard:
     # gated short convolution (a "conv" layer): taps of its depthwise
     # causal conv over the model's width
     short_conv: int = 0
+    # what a "swa" layer has of its own, 0 => the "gated" layer's: its
+    # query heads (over the same key/value heads), its RoPE base and the
+    # leading lanes RoPE turns
+    window_heads: int = 0
+    window_rope_theta: float = 0.0
+    window_rope_dim: int = 0
+    # YaRN on a "gated" layer's turned lanes (models/layers.rope_freqs):
+    # (factor, original positions, beta_fast, beta_slow, the factor on
+    # cos and sin); () => plain RoPE
+    rope_yarn: tuple = ()
 
     # ------------------------------------------------------------------ #
     @property
@@ -177,8 +189,12 @@ class ModelCard:
                     + self.linear_value_dim + vz * d)
         if kind in ("gated", "swa", "nope"):
             dh = self.attn_head_dim or self.head_dim
-            dq, dkv = self.num_heads * dh, self.kv_heads * dh
-            return (d * (1 + self.attn_output_gate) * dq + 2 * d * dkv
+            heads = (self.window_heads if kind == "swa"
+                     and self.window_heads else self.num_heads)
+            dq, dkv = heads * dh, self.kv_heads * dh
+            gate = {True: d * dq, "head": d * heads}.get(
+                self.attn_output_gate, 0)
+            return (d * dq + gate + 2 * d * dkv
                     + 2 * dh * self.attn_head_norm + dq * d)
         if kind == "conv":
             return d * 3 * d + self.short_conv * d + d * d
@@ -250,8 +266,9 @@ def _parse_card(name: str, raw: dict) -> ModelCard:
         moe = MoEParams(**raw["moe_params"])
     known = {f.name for f in dataclasses.fields(ModelCard)}
     kwargs = {k: v for k, v in raw.items() if k in known and k != "moe_params"}
-    if "layer_kinds" in kwargs:
-        kwargs["layer_kinds"] = tuple(kwargs["layer_kinds"])
+    for key in ("layer_kinds", "rope_yarn"):
+        if key in kwargs:
+            kwargs[key] = tuple(kwargs[key])
     return ModelCard(name=name, moe_params=moe, **kwargs)
 
 

@@ -7,7 +7,7 @@ for this framework: what every downstream layer consumes is the
 a HF model" is a card, not a cache of safetensors.  This module maps a HF
 config (``model_type`` gpt2 / llama / mistral / mixtral / phi4flash /
 deepseek_v3 as Kimi-VL and Moonlight state it / qwen3_next / lfm2_moe /
-smallthinker / vit) onto
+smallthinker / laguna / vit) onto
 ``ModelCard`` fields and writes the card JSON.
 
 Offline-first: hub access is attempted only when requested and is never
@@ -111,6 +111,9 @@ def card_from_hf_config(name: str, cfg: Mapping[str, Any] | Any) -> ModelCard:
 
     if mt == "smallthinker" or (not mt and "moe_num_primary_experts" in cfg):
         return _swa_moe_card(name, cfg)
+
+    if mt == "laguna":
+        return _headgate_moe_card(name, cfg)
 
     if mt == "vit":
         image = int(cfg["image_size"])
@@ -408,6 +411,137 @@ def _swa_moe_card(name: str, cfg: Mapping[str, Any]) -> ModelCard:
             expert_ff_dim=int(cfg["moe_ffn_hidden_size"]),
             early_router=True,
             activation="relu",
+        ),
+    )
+
+
+_LAGUNA_KIND = {"full_attention": "gated", "sliding_attention": "swa"}
+
+
+def _headgate_moe_card(name: str, cfg: Mapping[str, Any]) -> ModelCard:
+    """``model_type: "laguna"``: grouped-query softmax attention with no
+    norm a head, a window (``sliding_attention``: ``swa``) or every
+    earlier key (``full_attention``: ``gated``) by ``layer_types``, the
+    two kinds at query head counts of their own
+    (``num_attention_heads_per_layer``) over the same key/value heads
+    and with RoPE of their own (``rope_parameters`` by kind: base,
+    ``partial_rotary_factor``, plain or YaRN), one sigmoid gate a head
+    (``gating: "per-head"``), dense FFNs where ``mlp_layer_types`` /
+    ``mlp_only_layers`` say so (leading layers only) and softmax-routed
+    experts times ``moe_routed_scaling_factor`` beside one plain shared
+    expert elsewhere, an untied head.  Refused, with the layer's number
+    where it is a layer's: a per-layer list of another length than
+    ``num_hidden_layers``, a head count the key/value heads do not
+    divide or that differs within a kind, a dense layer after an expert
+    layer, a gate that is not a head's, YaRN on a window layer or
+    another scaling anywhere, a router with a soft cap, unnormalised
+    weights, the router's weight on an expert's input."""
+    layers = int(cfg["num_hidden_layers"])
+    kv = int(cfg["num_key_value_heads"])
+    kinds = list(cfg["layer_types"])
+    per_layer = {"layer_types": kinds,
+                 "num_attention_heads_per_layer":
+                     list(cfg["num_attention_heads_per_layer"])}
+    for key in ("mlp_layer_types", "gating_types"):
+        if key in cfg:
+            per_layer[key] = list(cfg[key])
+    for key, values in per_layer.items():
+        if len(values) != layers:
+            raise ValueError(f"{name}: {len(values)} entries of {key} for "
+                             f"{layers} layers")
+    heads: dict = {}
+    for li, (kind, h) in enumerate(zip(
+            kinds, per_layer["num_attention_heads_per_layer"])):
+        if kind not in _LAGUNA_KIND:
+            raise ValueError(f"{name}: layer {li} is a {kind!r} layer; "
+                             f"this import has {sorted(_LAGUNA_KIND)}")
+        if h % kv:
+            raise ValueError(f"{name}: layer {li} has {h} query heads, "
+                             f"which {kv} key/value heads do not divide")
+        if heads.setdefault(kind, h) != h:
+            raise ValueError(f"{name}: layer {li} has {h} query heads "
+                             f"where earlier {kind} layers have "
+                             f"{heads[kind]}; a kind has one head count")
+    dense = per_layer.get("mlp_layer_types") or [
+        "dense" if li in cfg.get("mlp_only_layers", ()) else "sparse"
+        for li in range(layers)]
+    first_dense = dense.index("sparse") if "sparse" in dense else layers
+    late = [li for li, f in enumerate(dense)
+            if f != ("dense" if li < first_dense else "sparse")]
+    if late:
+        raise ValueError(f"{name}: layer {late[0]} has a {dense[late[0]]!r} "
+                         f"FFN after an expert layer; dense layers lead")
+    gates = set(per_layer.get("gating_types", ())) | {
+        str(cfg.get("gating", "per-head")).replace("-", "_")}
+    rope = cfg["rope_parameters"]
+    full, window = rope["full_attention"], rope["sliding_attention"]
+    unsupported = {k: v for k, v, ok in (
+        ("gating", sorted(gates), gates == {"per_head"}),
+        ("sliding_attention.rope_type", window.get("rope_type"),
+         window.get("rope_type", "default") == "default"),
+        ("full_attention.rope_type", full.get("rope_type"),
+         full.get("rope_type", "default") in ("default", "yarn")),
+        ("moe_router_logit_softcapping",
+         cfg.get("moe_router_logit_softcapping"),
+         not cfg.get("moe_router_logit_softcapping")),
+        ("norm_topk_prob", cfg.get("norm_topk_prob"),
+         cfg.get("norm_topk_prob", True)),
+        ("moe_apply_router_weight_on_input",
+         cfg.get("moe_apply_router_weight_on_input"),
+         not cfg.get("moe_apply_router_weight_on_input")),
+        ("attention_bias", cfg.get("attention_bias"),
+         not cfg.get("attention_bias"))) if not ok}
+    if unsupported:
+        raise ValueError(f"{name}: head-gated import has no {unsupported}")
+    dh = int(cfg.get("head_dim")
+             or cfg["hidden_size"] // int(cfg["num_attention_heads"]))
+    yarn = ()
+    if full.get("rope_type") == "yarn":
+        yarn = (float(full["factor"]),
+                float(full["original_max_position_embeddings"]),
+                float(full.get("beta_fast", 32)),
+                float(full.get("beta_slow", 1)),
+                float(full["attention_factor"]))
+    full_heads = heads.get("full_attention",
+                           int(cfg["num_attention_heads"]))
+    width = int(cfg["moe_intermediate_size"])
+    shared = int(cfg.get("shared_expert_intermediate_size", 0))
+    if shared % width:
+        raise ValueError(f"{name}: a shared expert of {shared} beside "
+                         f"experts of {width}")
+    return ModelCard(
+        name=name,
+        embed_dim=int(cfg["hidden_size"]),
+        num_heads=full_heads,
+        num_kv_heads=kv,
+        ff_dim=int(cfg["intermediate_size"]),
+        seq_len=int(cfg["max_position_embeddings"]),
+        num_decoder_blocks=layers,
+        vocab_size=int(cfg["vocab_size"]),
+        gated_mlp=True,
+        tied_embeddings=bool(cfg.get("tie_word_embeddings", False)),
+        layer_kinds=tuple(_LAGUNA_KIND[k] for k in kinds),
+        sliding_window=int(cfg["sliding_window"]),
+        attn_head_dim=dh,
+        attn_output_gate="head",
+        attn_head_norm=False,
+        rope_theta=float(full["rope_theta"]),
+        rope_dim=int(dh * float(full.get("partial_rotary_factor", 1))),
+        rope_yarn=yarn,
+        window_heads=heads.get("sliding_attention", full_heads),
+        window_rope_theta=float(window["rope_theta"]),
+        window_rope_dim=int(
+            dh * float(window.get("partial_rotary_factor", 1))),
+        rms_norm=True,
+        norm_eps=float(cfg.get("rms_norm_eps", 1e-6)),
+        moe_params=MoEParams(
+            num_experts=int(cfg["num_experts"]),
+            num_experts_per_tok=int(cfg["num_experts_per_tok"]),
+            scoring="softmax",
+            routed_scale=float(cfg.get("moe_routed_scaling_factor", 1.0)),
+            shared_experts=shared // width,
+            expert_ff_dim=width,
+            first_dense_layers=first_dense,
         ),
     )
 

@@ -17,7 +17,13 @@ shared one, the head tied under RMSNorm) and the window-and-full
 attention expert models of the SmallThinker family (``swa`` layers with
 a ``nope`` layer every fourth, no norm a head, a router that reads the
 layer's input before attention, ReLU-gated experts and no shared one,
-an untied head under RMSNorm).
+an untied head under RMSNorm) and the head-gated window-and-full
+attention expert models of the Laguna family (``swa`` layers with a
+``gated`` layer every fourth, the two kinds at head counts, RoPE bases
+and turned lanes of their own, YaRN on the full layers', one sigmoid
+gate a head from a projection of its own, a leading dense layer, then
+softmax-routed experts times ``routed_scale`` beside a plain shared
+one).
 
 ``HybridConfig.layer_kinds`` names each layer's mixer, one of ``KINDS``:
 
@@ -49,11 +55,19 @@ an untied head under RMSNorm).
   output before the output projection.  Where the card states no gate
   (``attn_gate`` false) the projection carries the queries alone and
   the heads' output goes to the output projection as it is: grouped
-  softmax attention with a norm a head.
+  softmax attention with a norm a head.  Where the gate is one scalar
+  a head (``attn_gate`` "head") a projection of its own, ``wg`` [D, H],
+  reads the layer's normed input and ``sigmoid`` of it multiplies each
+  head's output.  ``rope_yarn`` blends the turned lanes' frequencies
+  and scales cos and sin (``layers.rope_freqs``).
 * ``swa`` / ``nope`` — the ``gated`` layer's grouped softmax attention
   (its weights, its function) with the layer's own mask and positions:
   ``swa`` sees the last ``attention_window`` keys and RoPE turns its
   heads, ``nope`` sees every earlier key and has no position at all.
+  A ``swa`` layer's query heads, RoPE base and turned lanes are its own
+  where the card states them (``window_heads``, ``window_rope_theta``,
+  ``window_rope_dim``); with a head count of their own the ``swa``
+  layers are a stack of their own, ``swa``, beside ``gated``.
 * ``conv``   — gated short convolution: one projection to three streams
   ``[b | c | u]`` of the model's width, ``c * conv(b * u)`` with a
   depthwise causal convolution of ``short_conv`` taps over time, no
@@ -105,14 +119,18 @@ _F32 = jnp.float32
 KINDS = ("mamba", "window", "full", "gmu", "cross", "mla", "gdn", "gated",
          "conv", "swa", "nope")
 FFN_KINDS = ("dense", "moe")
-# which stack of parameters a layer's mixer reads
-GROUP_OF = {"mamba": "mamba", "window": "attn", "full": "attn",
-            "gmu": "gmu", "cross": "cross", "mla": "mla", "gdn": "gdn",
-            "gated": "gated", "conv": "conv", "swa": "gated",
-            "nope": "gated"}
-# the inner scope of a "swa" or "nope" layer's kernel call
-# (``spans.SCOPES``); a "gated" layer's wears ``attn`` alone
-_ATTN_SCOPE = {"swa": "attn.window", "nope": "attn.full"}
+# what a layer's kind reads: the stack of parameters its mixer's weights
+# lie in, and the inner scope (``spans.SCOPES``) its kernel call wears
+# inside its layer's (None: the layer's alone).  What a configuration
+# changes of this is in ``HybridConfig.group_of`` and ``attn_scope``
+_READS = {"mamba": ("mamba", None), "window": ("attn", None),
+          "full": ("attn", None), "gmu": ("gmu", None),
+          "cross": ("cross", None), "mla": ("mla", None),
+          "gdn": ("gdn", None), "gated": ("gated", None),
+          "conv": ("conv", None), "swa": ("gated", "attn.window"),
+          "nope": ("gated", "attn.full")}
+# the kinds ``gated_mixer`` computes, whichever stack holds their weights
+_GATED_KINDS = frozenset(k for k, (g, _) in _READS.items() if g == "gated")
 # what a step with expert layers returns beside its loss
 # (``models/moe.moe_held``): three counters over its expert layers (the
 # rows routed to held experts and the rows past the bound summed, the
@@ -171,8 +189,20 @@ class HybridConfig:
     gdn_key_dim: int = 0            # lanes of a key head
     gdn_value_dim: int = 0          # lanes of a value head
     gdn_conv: int = 4               # taps of the depthwise causal conv
-    attn_gate: bool = True          # a "gated" layer's output gate
+    attn_gate: bool | str = True    # a "gated" layer's output gate: of
+                                    # the head's width (``wq`` carries
+                                    # it), "head": one scalar a head
+                                    # (``wg``), False: none
     head_norm: bool = True          # ... and its norm a head on q and k
+    # what a "swa" layer has of its own: 0 = the "gated" layer's
+    window_heads: int = 0           # query heads (then a stack "swa")
+    window_rope_theta: float = 0.0
+    window_rope_dim: int = 0
+    rope_yarn: tuple = ()           # YaRN on a "gated" layer's turned
+                                    # lanes (``layers.rope_freqs``):
+                                    # (factor, original positions,
+                                    # beta_fast, beta_slow, the factor
+                                    # on cos and sin); () = plain RoPE
     short_conv: int = 3             # taps of a "conv" layer's convolution
     rule_impl: str = "auto"         # ops.gated_delta_rule: auto|pallas|xla
     # the FFN of each layer; () = every layer dense
@@ -227,11 +257,11 @@ class HybridConfig:
                              "gdn_value_heads, gdn_key_dim and "
                              "gdn_value_dim")
         if self.early_router and any(
-                f == "moe" and GROUP_OF[k] != "gated"
+                f == "moe" and k not in _GATED_KINDS
                 for k, f in zip(kinds, ffn)):
             raise ValueError("early_router: only a gated, swa or nope "
                              "layer hands its router the mixer's input")
-        if set(kinds) & {"gated", "swa", "nope"} and (
+        if set(kinds) & _GATED_KINDS and (
                 self.num_heads % self.num_kv_heads
                                  or self.rope_dim % 2
                                  or self.rope_dim > self.head_dim):
@@ -239,6 +269,17 @@ class HybridConfig:
                              "num_kv_heads dividing "
                              "num_heads and an even rope_dim within the "
                              "head")
+        object.__setattr__(self, "rope_yarn", tuple(self.rope_yarn))
+        if "swa" in kinds and (self.window_heads % self.num_kv_heads
+                               or self.window_rope_dim % 2
+                               or self.window_rope_dim > self.head_dim):
+            raise ValueError("swa layers need num_kv_heads dividing "
+                             "window_heads and an even window_rope_dim "
+                             "within the head")
+        if (self.attn_gate not in (False, True, "head")
+                or len(self.rope_yarn) not in (0, 5)):
+            raise ValueError("attn_gate is False, True or 'head'; "
+                             "rope_yarn five numbers")
         if set(kinds) & {"window", "full", "cross"} and (
                 self.num_heads % 2 or self.num_kv_heads % 2
                 or self.num_heads % self.num_kv_heads):
@@ -269,6 +310,10 @@ class HybridConfig:
                   "attn_gate": card.attn_output_gate,
                   "head_norm": card.attn_head_norm,
                   "rope_dim": card.rope_dim,
+                  "window_heads": card.window_heads,
+                  "window_rope_theta": card.window_rope_theta,
+                  "window_rope_dim": card.window_rope_dim,
+                  "rope_yarn": card.rope_yarn,
                   "gdn_key_heads": card.linear_key_heads,
                   "gdn_value_heads": card.linear_value_heads,
                   "gdn_key_dim": card.linear_key_dim,
@@ -330,10 +375,39 @@ class HybridConfig:
         kinds = self.layer_kinds
         return len(kinds) - 1 - kinds[::-1].index("mamba")
 
+    def heads_of(self, kind: str) -> int:
+        """Query heads of a ``kind`` attention layer."""
+        return (self.window_heads if kind == "swa" and self.window_heads
+                else self.num_heads)
+
+    def group_of(self, kind: str) -> str:
+        """The stack a ``kind`` layer's mixer reads: ``_READS``'s, and
+        ``swa`` where the window layers' head count is their own."""
+        own = kind == "swa" and self.heads_of(kind) != self.num_heads
+        return "swa" if own else _READS[kind][0]
+
+    def attn_scope(self, kind: str):
+        """The inner scope of a ``kind`` layer's kernel call: a
+        ``gated`` layer beside ``swa`` layers wears ``attn.full``, so
+        that the two kinds' time can be read apart."""
+        if kind == "gated" and "swa" in self.layer_kinds:
+            return "attn.full"
+        return _READS[kind][1]
+
+    def rope_of(self, kind: str) -> tuple:
+        """(theta, turned lanes, YaRN's numbers or None) of a ``gated``
+        or ``swa`` layer's RoPE."""
+        if kind == "swa":
+            return (self.window_rope_theta or self.rope_theta,
+                    self.window_rope_dim or self.rope_dim or self.head_dim,
+                    None)
+        return (self.rope_theta, self.rope_dim or self.head_dim,
+                self.rope_yarn or None)
+
     def index_in_group(self, li: int) -> int:
-        group = GROUP_OF[self.layer_kinds[li]]
+        group = self.group_of(self.layer_kinds[li])
         return sum(1 for k in self.layer_kinds[:li]
-                   if GROUP_OF[k] == group)
+                   if self.group_of(k) == group)
 
     def index_in_ffn(self, li: int) -> int:
         """Layer ``li``'s place among the layers with its kind of FFN
@@ -345,9 +419,10 @@ class HybridConfig:
         return "moe" in self.ffn_kinds
 
     def group_sizes(self) -> dict:
-        out = {g: 0 for g in set(GROUP_OF.values())}
+        out = {g: 0 for g, _ in _READS.values()}
         for k in self.layer_kinds:
-            out[GROUP_OF[k]] += 1
+            g = self.group_of(k)
+            out[g] = out.get(g, 0) + 1
         return out
 
 
@@ -456,16 +531,20 @@ def param_shapes(cfg: HybridConfig) -> dict:
             "gdn/dt_bias": ((m, hv), "ones"),
             "gdn/o_norm": ((m, cfg.gdn_value_dim), "ones"),
             "gdn/w_out": ((m, vz, d), 1.0 / math.sqrt(vz))})
-    if (m := sizes["gated"]):
-        h, hkv = cfg.num_heads, cfg.num_kv_heads
+    for g, h in (("gated", cfg.num_heads), ("swa", cfg.window_heads)):
+        if not (m := sizes.get(g, 0)):
+            continue
+        hkv, wide = cfg.num_kv_heads, cfg.attn_gate is True
         out.update({
-            "gated/wq": ((m, d, (1 + cfg.attn_gate) * h * dh), s_d),
-            "gated/wk": ((m, d, hkv * dh), s_d),
-            "gated/wv": ((m, d, hkv * dh), s_d),
-            "gated/wo": ((m, h * dh, d), 1.0 / math.sqrt(h * dh))})
+            f"{g}/wq": ((m, d, (1 + wide) * h * dh), s_d),
+            f"{g}/wk": ((m, d, hkv * dh), s_d),
+            f"{g}/wv": ((m, d, hkv * dh), s_d),
+            f"{g}/wo": ((m, h * dh, d), 1.0 / math.sqrt(h * dh))})
+        if cfg.attn_gate == "head":
+            out[f"{g}/wg"] = ((m, d, h), s_d)
         if cfg.head_norm:
-            out.update({"gated/q_norm": ((m, dh), unit),
-                        "gated/k_norm": ((m, dh), unit)})
+            out.update({f"{g}/q_norm": ((m, dh), unit),
+                        f"{g}/k_norm": ((m, dh), unit)})
     if (m := sizes["conv"]):
         out.update({
             "conv/w_in": ((m, d, 3 * d), s_d),
@@ -748,15 +827,16 @@ def gdn_mixer(cfg: HybridConfig, y, p):
 def gated_mixer(cfg: HybridConfig, y, p, kind: str = "gated"):
     """Softmax attention with grouped keys and values; a norm a head on
     queries and keys and an output gate where the card states them.
-    The layer's ``kind`` gives its mask and its positions: ``gated``
-    and ``nope`` see every earlier key, ``swa`` the last
-    ``attention_window`` (the block-sparse kernels at
-    ``_splash_block``'s blocks); RoPE turns the first ``rope_dim``
-    lanes of ``gated`` and ``swa`` heads and none of ``nope``'s."""
+    The layer's ``kind`` gives its mask, its positions and its query
+    heads (``cfg.heads_of``): ``gated`` and ``nope`` see every earlier
+    key, ``swa`` the last ``attention_window`` (the block-sparse
+    kernels at ``_splash_block``'s blocks); RoPE turns the first lanes
+    of ``gated`` and ``swa`` heads as ``cfg.rope_of`` says and none of
+    ``nope``'s."""
     b, s, _ = y.shape
-    h, hkv, dh, dr = (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
-                      cfg.rope_dim or cfg.head_dim)
-    qg = jnp.dot(y, p["wq"]).reshape(b, s, h, (1 + cfg.attn_gate) * dh)
+    h, hkv, dh = cfg.heads_of(kind), cfg.num_kv_heads, cfg.head_dim
+    wide = cfg.attn_gate is True
+    qg = jnp.dot(y, p["wq"]).reshape(b, s, h, (1 + wide) * dh)
     q = qg[..., :dh]
     k = jnp.dot(y, p["wk"]).reshape(b, s, hkv, dh)
     v = jnp.dot(y, p["wv"]).reshape(b, s, hkv, dh)
@@ -765,11 +845,12 @@ def gated_mixer(cfg: HybridConfig, y, p, kind: str = "gated"):
                       cfg.norm_eps).astype(y.dtype)
         k = L.rmsnorm(k, _norm_scale(cfg, p["k_norm"]),
                       cfg.norm_eps).astype(y.dtype)
-    inner = _ATTN_SCOPE.get(kind)
+    inner = cfg.attn_scope(kind)
     with scope(inner) if inner else contextlib.nullcontext():
         if kind != "nope":
+            theta, dr, yarn = cfg.rope_of(kind)
             q_rope, k_rope = L.rope(q[..., :dr], k[..., :dr], jnp.arange(s),
-                                    cfg.rope_theta)
+                                    theta, yarn)
             q = jnp.concatenate([q_rope, q[..., dr:]], axis=-1)
             k = jnp.concatenate([k_rope, k[..., dr:]], axis=-1)
         mask, block = None, None
@@ -778,8 +859,13 @@ def gated_mixer(cfg: HybridConfig, y, p, kind: str = "gated"):
             block = _splash_block(cfg, s)
         o = ops.attention(q, k, v, causal=True, impl=cfg.attention_impl,
                           mask=mask, block_q=block, block_k=block)
-    if cfg.attn_gate:
+    if wide:
         o = o * jax.nn.sigmoid(qg[..., dh:].astype(_F32)).astype(y.dtype)
+    elif cfg.attn_gate:
+        with scope("attn.gate"):
+            g = jax.nn.sigmoid(jnp.dot(y, p["wg"],
+                                       preferred_element_type=_F32))
+            o = o * g[..., None].astype(y.dtype)
     return jnp.dot(o.reshape(b, s, h * dh), p["wo"])
 
 
@@ -832,7 +918,7 @@ def _layer(cfg: HybridConfig, li: int, x, bp, mp, fp, memory, kv):
     elif kind == "mla":
         with scope("attn"):
             x = x + mla_mixer(cfg, _norm(cfg, x, bp, "norm1"), mp)
-    elif GROUP_OF[kind] == "gated":
+    elif kind in _GATED_KINDS:
         with scope("attn"):
             y = _norm(cfg, x, bp, "norm1")
             # a "gated" layer keeps the call of three arguments that
@@ -884,7 +970,7 @@ def _forward(params: dict, tokens, cfg: HybridConfig):
         gi, fi = cfg.index_in_group(li), cfg.index_in_ffn(li)
         block = params["block"]
         bp = {k: a[li] for k, a in block.items() if k not in _MLP}
-        mp = jax.tree.map(lambda a: a[gi], params[GROUP_OF[kind]])
+        mp = jax.tree.map(lambda a: a[gi], params[cfg.group_of(kind)])
         if cfg.ffn_kinds[li] == "moe":
             fp = jax.tree.map(lambda a: a[fi], params["moe"])
         else:

@@ -9,10 +9,12 @@ matters (norm statistics, softmax, router logits); matmuls request float32
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from dlnetbench_tpu.metrics.spans import mark, scope
 from dlnetbench_tpu.ops import fp8 as qf8
@@ -75,13 +77,44 @@ def layernorm(x, scale, bias, eps=1e-5):
     return y.astype(x.dtype) * scale + bias
 
 
-def rope(q, k, positions, theta=10000.0):
-    """Rotary embeddings; q/k: [..., S, H, Dh], positions: [S]."""
+def rope_freqs(theta: float, lanes: int, yarn):
+    """(inverse frequencies [lanes / 2], the factor on cos and sin) of
+    RoPE under YaRN as ``transformers`` computes it, in float32 on the
+    host.  ``yarn`` = (factor, original positions, beta_fast, beta_slow,
+    attention factor): pair ``i`` turns at ``theta^(-2i/lanes)`` times
+    ``(1 - ramp_i) + ramp_i / factor``, ``ramp`` rising from 0 at pair
+    ``low`` to 1 at pair ``high``, the pairs that make ``beta_fast`` and
+    ``beta_slow`` turns over the original positions (floor and
+    ceiling, within the lanes)."""
+    factor, original, beta_fast, beta_slow, attention_factor = yarn
+
+    def pair_of(turns):
+        return (lanes * math.log(original / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), lanes - 1)
+    high = high + 0.001 if high == low else high
+    i = np.arange(lanes // 2, dtype=np.float32)
+    ramp = np.clip((i - low) / np.float32(high - low), 0.0, 1.0)
+    base = np.float32(theta) ** (-2.0 * i / np.float32(lanes))
+    inv_freq = base * ((1.0 - ramp) + ramp / np.float32(factor))
+    return inv_freq.astype(np.float32), float(attention_factor)
+
+
+def rope(q, k, positions, theta=10000.0, yarn=None):
+    """Rotary embeddings; q/k: [..., S, H, Dh], positions: [S].  ``yarn``
+    = ``rope_freqs``'s numbers, or None for plain RoPE."""
     dh = q.shape[-1]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, dh, 2, dtype=_F32) / dh))
+    if yarn is None:
+        inv_freq = 1.0 / (theta ** (jnp.arange(0, dh, 2, dtype=_F32) / dh))
+        factor = 1.0
+    else:
+        inv_freq, factor = rope_freqs(theta, dh, yarn)
     angles = positions.astype(_F32)[:, None] * inv_freq[None, :]  # [S, Dh/2]
     cos = jnp.cos(angles)[None, :, None, :]
     sin = jnp.sin(angles)[None, :, None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
 
     def rot(x):
         x1, x2 = jnp.split(x.astype(_F32), 2, axis=-1)
@@ -159,7 +192,7 @@ def moe_router(x, w_router, top_k: int, *, scoring: str = "softmax",
     model's card states (``MoEParams.scoring``):
 
     * ``"softmax"``: the top-k of the logits, softmax over the selected
-      (Mixtral convention).
+      (Mixtral convention), times ``scale`` where a card states one.
     * ``"sigmoid"``: scores ``s = sigmoid(logits)``; the top-k of
       ``s + bias``, ``bias`` [E] a per-expert correction that steers
       the selection only and has no gradient; the weights are ``s``
@@ -169,7 +202,8 @@ def moe_router(x, w_router, top_k: int, *, scoring: str = "softmax",
         logits = router_logits(x, w_router)
         if scoring == "softmax":
             top_vals, top_idx = jax.lax.top_k(logits, top_k)
-            return jax.nn.softmax(top_vals, axis=-1), top_idx
+            w = jax.nn.softmax(top_vals, axis=-1)
+            return (w if scale == 1.0 else w * scale), top_idx
         if scoring != "sigmoid":
             raise ValueError(f"moe_router: unknown scoring {scoring!r} "
                              f"(softmax | sigmoid)")

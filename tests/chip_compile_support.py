@@ -116,6 +116,8 @@ CELL_BUILD = {
                               {"attention_impl": "flash"}, {}),
     "smallthinker_21b_a3b_train_s16k": ("weights_swa_moe", "train_swa_moe",
                                         {"attention_impl": "flash"}, {}),
+    "laguna_s21_train_s16k": ("weights_headgate_moe", "train_headgate_moe",
+                              {"attention_impl": "flash"}, {}),
 }
 
 

@@ -75,7 +75,7 @@ def test_splash_forward_backward(for_chip, mask):
     assert kernels_in(text) == 2
 
 
-# the seven cells' attention, as their models call ``ops.attention``:
+# the eight cells' attention, as their models call ``ops.attention``:
 # q's shape, key/value heads, value lanes, window, explicit blocks
 CELL_ATTENTION = {
     "minerva7b_train": ((2, 6144, 32, 128), 8, 128, None, None),
@@ -91,6 +91,10 @@ CELL_ATTENTION = {
                                                4096, 2048),
     "smallthinker_21b_a3b_train_s16k.full": ((1, 16384, 28, 128), 4, 128,
                                              None, None),
+    # groups of nine through the block-sparse kernels in blocks of 512,
+    # groups of six through the dense ones
+    "laguna_s21_train_s16k.window": ((1, 16384, 72, 128), 8, 128, 512, 512),
+    "laguna_s21_train_s16k.full": ((1, 16384, 48, 128), 8, 128, None, None),
 }
 
 

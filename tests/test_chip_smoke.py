@@ -39,10 +39,11 @@ def _lines(capsys) -> list[dict]:
 
 
 # the one-chip phases in two cases, the second in
-# tests/test_chip_smoke_hybrids.py: all ten in one case were the suite's
+# tests/test_chip_smoke_hybrids.py: all eleven in one case would be (ten were) the suite's
 # longest (227 s of a worker under ``--dist loadfile``)
 MAIN_PATHS = ("kernels", "train", "proxy", "serve", "moe")
-HYBRIDS = ("hybrid", "latent_moe", "linear_moe", "conv_moe", "swa_moe")
+HYBRIDS = ("hybrid", "latent_moe", "linear_moe", "conv_moe", "swa_moe",
+           "headgate_moe")
 
 
 def check_tiny_phases(smoke_out, capsys, argv, phases):

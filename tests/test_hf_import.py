@@ -314,3 +314,121 @@ def test_swa_moe_import_refuses_what_no_layer_computes(over, match):
     router without its softmax, unnormalised weights and scaled RoPE."""
     with pytest.raises(ValueError, match=match):
         hf_import.card_from_hf_config("x", {**_smallthinker_row(), **over})
+
+
+def _laguna_row():
+    """The keys of the catalog's row for Laguna-S-2.1 (``config.json``
+    as published)."""
+    period = ["full_attention"] + ["sliding_attention"] * 3
+    return {
+        "model_type": "laguna", "vocab_size": 100352, "hidden_size": 3072,
+        "intermediate_size": 12288, "num_hidden_layers": 48,
+        "num_attention_heads": 48, "num_key_value_heads": 8,
+        "head_dim": 128, "max_position_embeddings": 1048576,
+        "attention_bias": False, "rms_norm_eps": 1e-06, "num_experts": 256,
+        "num_experts_per_tok": 10, "moe_intermediate_size": 1024,
+        "shared_expert_intermediate_size": 1024, "norm_topk_prob": True,
+        "decoder_sparse_step": 1, "mlp_only_layers": [0],
+        "tie_word_embeddings": False, "gating": "per-head",
+        "sliding_window": 512,
+        "rope_parameters": {
+            "full_attention": {
+                "rope_theta": 500000, "rope_type": "yarn", "factor": 128,
+                "original_max_position_embeddings": 8192, "beta_slow": 1,
+                "beta_fast": 32, "attention_factor": 1.4852030263919618,
+                "partial_rotary_factor": 0.5},
+            "sliding_attention": {"rope_type": "default",
+                                  "rope_theta": 10000,
+                                  "partial_rotary_factor": 1}},
+        "layer_types": period * 12,
+        "moe_apply_router_weight_on_input": False,
+        "mlp_layer_types": ["dense"] + ["sparse"] * 47,
+        "gating_types": ["per_head"] * 48,
+        "moe_routed_scaling_factor": 2.5,
+        "num_attention_heads_per_layer": [48, 72, 72, 72] * 12,
+        "moe_router_logit_softcapping": 0}
+
+
+def test_headgate_moe_card_states_the_head_counts_the_gate_and_the_ropes():
+    card = hf_import.card_from_hf_config("laguna_s_2_1", _laguna_row())
+    assert card == load_model_card("laguna_s_2_1")
+    kinds = card.layer_kinds
+    assert len(kinds) == 48 and kinds[:5] == ("gated", "swa", "swa", "swa",
+                                              "gated")
+    assert [i for i, k in enumerate(kinds) if k == "gated"] \
+        == list(range(0, 48, 4))
+    assert (card.embed_dim, card.num_heads, card.window_heads,
+            card.kv_heads, card.attn_head_dim, card.sliding_window,
+            card.ff_dim, card.norm_eps, card.vocab_size, card.seq_len) == (
+        3072, 48, 72, 8, 128, 512, 12288, 1e-6, 100352, 1048576)
+    assert card.attn_output_gate == "head" and not card.attn_head_norm
+    assert card.rms_norm and not card.tied_embeddings
+    assert (card.rope_theta, card.rope_dim, card.window_rope_theta,
+            card.window_rope_dim) == (5e5, 64, 1e4, 128)
+    assert card.rope_yarn == (128.0, 8192.0, 32.0, 1.0, 1.4852030263919618)
+    moe = card.moe_params
+    assert (moe.num_experts, moe.num_experts_per_tok, moe.expert_ff_dim,
+            moe.scoring, moe.routed_scale, moe.shared_experts,
+            moe.shared_gate, moe.first_dense_layers, moe.early_router,
+            moe.activation) == (
+        256, 10, 1024, "softmax", 2.5, 1, False, 1, False, "silu")
+    # the published "~118B"
+    assert card.num_params() == pytest.approx(117.56e9, rel=1e-4)
+    raw = hf_import.card_to_json(card)
+    assert raw["attn_output_gate"] == "head" and raw["window_heads"] == 72
+    assert raw["rope_yarn"] == [128.0, 8192.0, 32.0, 1.0,
+                                1.4852030263919618]
+    assert raw["moe_params"] == {
+        "num_experts": 256, "num_experts_per_tok": 10, "routed_scale": 2.5,
+        "shared_experts": 1, "expert_ff_dim": 1024,
+        "first_dense_layers": 1}
+    # without the per-layer FFN list, mlp_only_layers says the same
+    row = {k: v for k, v in _laguna_row().items()
+           if k not in ("mlp_layer_types", "gating_types")}
+    assert hf_import.card_from_hf_config("laguna_s_2_1", row) == card
+    # plain RoPE on the full layers is a card without YaRN's numbers
+    rope = _laguna_row()["rope_parameters"]
+    plain = {**rope, "full_attention": {"rope_type": "default",
+                                        "rope_theta": 500000}}
+    other = hf_import.card_from_hf_config(
+        "x", {**_laguna_row(), "rope_parameters": plain})
+    assert other.rope_yarn == () and other.rope_dim == 128
+
+
+def _rope_with(kind, **over):
+    rope = _laguna_row()["rope_parameters"]
+    return {"rope_parameters": {**rope, kind: {**rope[kind], **over}}}
+
+
+@pytest.mark.parametrize("over,match", [
+    ({"layer_types": ["full_attention"] * 47},
+     "47 entries of layer_types for 48 layers"),
+    ({"num_attention_heads_per_layer": [48, 72, 72, 72] * 11},
+     "44 entries of num_attention_heads_per_layer"),
+    ({"mlp_layer_types": ["dense"]}, "1 entries of mlp_layer_types"),
+    ({"num_attention_heads_per_layer": [48, 72, 70, 72] + [48, 72, 72, 72]
+      * 11}, "layer 2 has 70 query heads, which 8"),
+    ({"num_attention_heads_per_layer": [48, 72, 72, 72, 48, 64, 72, 72]
+      + [48, 72, 72, 72] * 10}, "layer 5 has 64 query heads where earlier"),
+    ({"layer_types": ["chunked_attention"] + ["sliding_attention"] * 47},
+     "layer 0 is a 'chunked_attention' layer"),
+    ({"mlp_layer_types": ["dense", "sparse", "dense"] + ["sparse"] * 45},
+     "layer 2 has a 'dense' FFN after an expert layer"),
+    ({"gating": "per-lane"}, "gating"),
+    (_rope_with("sliding_attention", rope_type="yarn"),
+     "sliding_attention.rope_type"),
+    (_rope_with("full_attention", rope_type="llama3"),
+     "full_attention.rope_type"),
+    ({"moe_router_logit_softcapping": 30.0}, "softcapping"),
+    ({"norm_topk_prob": False}, "norm_topk_prob"),
+    ({"moe_apply_router_weight_on_input": True}, "weight_on_input"),
+    ({"shared_expert_intermediate_size": 1536}, "a shared expert of 1536")])
+def test_headgate_moe_import_refuses_what_no_layer_computes(over, match):
+    """A per-layer list of another length than the layers, a head count
+    the key/value heads do not divide or that differs within a kind
+    (with the layer's number), an unknown kind of layer, a dense layer
+    after an expert layer, another gate, scaled RoPE that no layer
+    turns by, a capped router, unnormalised weights, the weight on an
+    expert's input."""
+    with pytest.raises(ValueError, match=match):
+        hf_import.card_from_hf_config("x", {**_laguna_row(), **over})

@@ -140,7 +140,7 @@ def test_gmu_reads_the_last_mamba_layers_scan_output(case):
     for li in range(5):
         bp = jax.tree.map(lambda a: a[li], params["block"])
         mp = jax.tree.map(lambda a: a[cfg.index_in_group(li)],
-                          params[hybrid.GROUP_OF[KINDS[li]]])
+                          params[cfg.group_of(KINDS[li])])
         x, handed, _ = hybrid._layer(cfg, li, x, bp, mp, bp, None, None)
     assert jnp.allclose(seen["memory"], handed, rtol=1e-5, atol=1e-6)
 

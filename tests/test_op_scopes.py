@@ -141,6 +141,10 @@ def test_moe_dispatch_and_combine_gather_rows_and_multiply_nothing(steps):
     # a window or a full layer's kernel call inside its attention layer
     ("jit(f)/jvp(attn)/attn.window/pallas_call", "attn.window"),
     ("jit(f)/transpose(jvp(attn))/attn.full/reduce_sum", "attn.full"),
+    # the gate a head, beside the kernel call inside the same layer
+    ("jit(f)/jvp(attn)/attn.gate/logistic", "attn.gate"),
+    ("jit(f)/transpose(jvp(attn))/checkpoint/attn/attn.gate/dot_general",
+     "attn.gate"),
     ("jit(attn)/add", None),    # a function's name is not a scope
     ("jit(f)/attention/add", None),
     ("", None)])
@@ -349,9 +353,10 @@ _LOOP_ATTRS = re.compile(r", condition=%?[\w.\-]+, body=%?[\w.\-]+")
     ("train_latent_moe", "LatentMoeCell", "kimivl_a3b_train_s8k"),
     ("train_linear_moe", "LinearMoeCell", "qwen3next_a3b_train_s16k"),
     ("train_conv_moe", "ConvMoeCell", "lfm2_8b_a1b_train_s8k"),
-    ("train_swa_moe", "SwaMoeCell", "smallthinker_21b_a3b_train_s16k")])
+    ("train_swa_moe", "SwaMoeCell", "smallthinker_21b_a3b_train_s16k"),
+    ("train_headgate_moe", "HeadgateMoeCell", "laguna_s21_train_s16k")])
 def test_the_loop_rule_moves_only_what_had_no_scope(runner, cls, name):
-    """The five ``models/hybrid.py`` cells' steps at their rehearsal
+    """The six ``models/hybrid.py`` cells' steps at their rehearsal
     sizes, the table with the loop rule against the table without it
     (the same text with the loops' ``condition=`` and ``body=`` taken
     off, which is what ``hlo_op_scopes`` read before it knew loops):
@@ -398,7 +403,7 @@ def test_the_loop_rule_moves_only_what_had_no_scope(runner, cls, name):
 def test_scope_refuses_a_name_outside_the_vocabulary():
     with pytest.raises(ValueError, match="spans.SCOPES"):
         spans.scope("attention")
-    assert len(set(spans.SCOPES)) == len(spans.SCOPES) == 19
+    assert len(set(spans.SCOPES)) == len(spans.SCOPES) == 20
     assert spans.OTHER_SCOPE not in spans.SCOPES
 
 
