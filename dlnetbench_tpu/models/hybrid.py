@@ -737,8 +737,11 @@ def project_kv(cfg: HybridConfig, y, p):
 
 
 def _splash_block(cfg: HybridConfig, s: int):
-    """Blocks no wider than the window, so that the block-sparse
-    kernels skip what lies outside it."""
+    """Blocks no wider than the window, so that a row block's visit
+    range is the band's two or three blocks and the tiles hold little
+    that lies outside it.  The kernels' grid is as long as that range
+    (``BlockMask.q_visits``), so a smaller block costs the steps of its
+    own tiles and no longer ``S // block`` of them a row."""
     limit = max(cfg.attention_window, 128)
     return next((b for b in _SPLASH_BLOCKS if b <= limit and s % b == 0),
                 None)

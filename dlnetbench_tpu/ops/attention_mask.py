@@ -22,7 +22,8 @@ attention (ops/sequence_parallel.py) and the serving prefill
   this layer exists to avoid.
 * ``BlockMask`` — per (q-block, kv-block) verdicts {skip, full,
   partial} precomputed on host from the intervals, plus the transposed
-  (per-kv-block) visit ranges the dk/dv kernel grid needs and the
+  (per-kv-block) visit ranges the dk/dv kernel needs, the widest visit
+  range each way (the length of the kernels' minor grid axis) and the
   ``sparsity_fraction`` stat the bench/record layer stamps.
 * ``ring_hop_work`` — the same verdict at ring-hop granularity: an
   [n, n] table saying whether shard ``me``'s queries see shard
@@ -211,10 +212,21 @@ class BlockMask:
     everything the splash kernels prefetch, as host numpy int32:
 
     q_first_k/q_last_k   [nq]  kv-block visit range per q block (the
-                               fwd/dq grid bounds; blocks outside issue
-                               no DMA and no MXU work)
+                               fwd/dq kernels walk it; blocks outside
+                               issue no DMA and no MXU work)
     kv_first_q/kv_last_q [nk]  q-block visit range per kv block (the
-                               dk/dv grid, whose minor axis walks q)
+                               dk/dv kernel, whose minor axis walks q)
+    q_visits / kv_visits int   the widest of each: the most kv blocks a
+                               q block visits, the most q blocks a kv
+                               block does.  The kernels' minor grid axis
+                               is this long and step r of row i names
+                               block first[i] + r, so a band costs its
+                               own width in grid steps and not S / block
+                               (a step that does nothing still costs a
+                               step: 0.17 us of the forward kernel and
+                               0.33 of the dk/dv kernel on the v5e, for
+                               69 192 of 73 728 steps at window 512 in
+                               blocks of 512, S = 16 384, 72 heads)
     blk_lo_max/blk_hi_min [nq] max(lo)/min(hi) over the block's rows —
                                a kv block j is FULL for q block i iff
                                blk_lo_max[i] <= j*bk and
@@ -231,6 +243,8 @@ class BlockMask:
     q_last_k: np.ndarray
     kv_first_q: np.ndarray
     kv_last_q: np.ndarray
+    q_visits: int
+    kv_visits: int
     blk_lo_max: np.ndarray
     blk_hi_min: np.ndarray
     lo: np.ndarray
@@ -243,6 +257,12 @@ class BlockMask:
     @property
     def nk(self) -> int:
         return self.seq_len // self.block_k
+
+    @property
+    def visited(self) -> int:
+        """The (q block, kv block) pairs that are not SKIP: what a grid
+        over this mask has to do, whichever axis is minor."""
+        return int((self.q_last_k - self.q_first_k + 1).sum())
 
     def verdicts(self) -> np.ndarray:
         """[nq, nk] uint8 verdict table (SKIP/PARTIAL/FULL) — derived
@@ -308,6 +328,8 @@ def block_mask(spec: MaskSpec, s: int, block_q: int,
         spec=spec, seq_len=s, block_q=block_q, block_k=block_k,
         q_first_k=q_first_k, q_last_k=q_last_k,
         kv_first_q=kv_first_q, kv_last_q=kv_last_q,
+        q_visits=int((q_last_k - q_first_k).max()) + 1,
+        kv_visits=int((kv_last_q - kv_first_q).max()) + 1,
         blk_lo_max=lo_b.max(axis=1).astype(np.int32),
         blk_hi_min=hi_b.min(axis=1).astype(np.int32),
         lo=lo.astype(np.int32), hi=hi.astype(np.int32))

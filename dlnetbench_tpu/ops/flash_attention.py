@@ -34,6 +34,13 @@ Design (TPU-first, not a port — the reference has no kernels at all):
   widened to the keys' width.  On the MXU a contraction runs in passes
   of 128, so 192 padded to 256 costs the two passes that 192 costs.
   The splash kernels keep one width.
+* The block-sparse (splash) kernels further down are the same bodies
+  under a host-built ``BlockMask``.  Their grid is (batch, q_head,
+  row block, step of the row's visit range): the minor axis is as long
+  as the mask's widest visit range, not ``S // block``, and step ``r``
+  of row ``i`` names block ``first[i] + r``.  A grid step that does
+  nothing still costs a step, and under a narrow band nearly all of an
+  ``S // block`` axis does nothing.
 
 On non-TPU backends the same kernels run under ``interpret=True`` so the
 whole path is unit-testable on the CPU mesh (tests/test_flash_attention.py
@@ -42,6 +49,7 @@ checks fwd+grad against the einsum reference in ops/xla_attention.py).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -704,10 +712,16 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 # The masked generalization of the kernels above (ISSUE 10): a host-
 # precomputed BlockMask (ops/attention_mask.py) drives the grid through
 # scalar-prefetch arrays —
-#   * SKIP blocks issue no MXU work (``pl.when`` off) and no DMA (the
-#     BlockSpec index maps clamp into the visit range, so out-of-range
-#     grid steps revisit the previous block and copy nothing — the same
-#     trick the causal kernels use for the fully-masked tail),
+#   * the minor grid axis is as long as the mask's widest visit range
+#     (``BlockMask.q_visits`` / ``kv_visits``), and step r of row i
+#     names block first[i] + r: SKIP blocks outside a row's range have
+#     no grid step at all.  A row with fewer visits than the widest
+#     (the first rows of a window, a short document) spends the steps
+#     left over on nothing: no MXU work (``pl.when`` off) and no DMA
+#     (the index maps clamp to the row's last block, and a same-index
+#     revisit copies nothing — the trick the causal kernels use for the
+#     fully-masked tail).  Under the plain-causal spec the widest row
+#     visits every block and the grid is the dense kernels',
 #   * FULL blocks skip the in-register mask apply,
 #   * PARTIAL blocks mask against the row intervals [lo[q], hi[q]]
 #     (two compares — causal, window and segment semantics all reduce
@@ -730,6 +744,21 @@ def _row_i32(arr, s: int):
                             (_SUBLANES, s))
 
 
+def _visited(first_ref, last_ref, row, r):
+    """The block that step ``r`` of ``row``'s visit range names, for an
+    index map.  A row with fewer visits than the widest revisits its
+    last block on the steps left over, which copies nothing."""
+    return jnp.minimum(first_ref[row] + r, last_ref[row])
+
+
+def _mark_grid(kernel: str, grid, bm):
+    """How much of a block-sparse call's grid does work, a fact of the
+    traced program like ``flash.bwd``: ``steps`` the grid's product,
+    ``live`` the steps whose (row, block) pair the mask visits."""
+    spans.mark("flash.grid", kernel=kernel, steps=math.prod(grid),
+               live=grid[0] * grid[1] * bm.visited)
+
+
 def _interval_mask(s, lo, hi, j, block_q: int, block_k: int):
     """Mask score block ``s`` against the row intervals: key column k
     allowed iff lo[q] <= k <= hi[q].  ``lo``/``hi``: [bq] int32 (this
@@ -745,16 +774,17 @@ def _splash_fwd_kernel(first_ref, last_ref, lomax_ref, himin_ref,
                        o_ref, lse_ref, acc_ref, m_ref, l_ref,
                        *, scale: float, block_q: int, block_k: int):
     i = pl.program_id(2)      # q block
-    j = pl.program_id(3)      # kv block
-    fj, lj = first_ref[i], last_ref[i]
+    r = pl.program_id(3)      # step of its visit range
+    lj = last_ref[i]
+    j = first_ref[i] + r      # kv block
 
-    @pl.when(j == fj)
+    @pl.when(r == 0)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    work = (j >= fj) & (j <= lj)
+    work = j <= lj
     full = ((lomax_ref[i] <= j * block_k)
             & (himin_ref[i] >= (j + 1) * block_k - 1))
 
@@ -803,13 +833,11 @@ def _splash_fwd(q, k, v, spec, *, block_q: int, block_k: int):
     bm = amask.block_mask(spec, s, block_q, block_k)
 
     qt, kt, vt = (_to_bsf(x, dh_p) for x in (q, k, v))
-    nq, nk = s // block_q, s // block_k
+    grid = (b, hq, bm.nq, bm.q_visits)
+    _mark_grid("flash_fwd", grid, bm)
 
-    def kv_index(bi, h, i, j, first_ref, last_ref, lomax_ref, himin_ref):
-        # clamp into the visit range: out-of-range steps revisit the
-        # nearest visited block, so skipped KV copies no bytes
-        j = jnp.clip(j, first_ref[i], last_ref[i])
-        return (bi, j, h // group)
+    def kv_index(bi, h, i, r, first_ref, last_ref, lomax_ref, himin_ref):
+        return (bi, _visited(first_ref, last_ref, i, r), h // group)
 
     def q_index(bi, h, i, j, *_refs):
         return (bi, i, h)
@@ -819,7 +847,7 @@ def _splash_fwd(q, k, v, spec, *, block_q: int, block_k: int):
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(b, hq, nq, nk),
+        grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, dh_p), q_index),
             pl.BlockSpec((1, block_k, dh_p), kv_index),
@@ -859,14 +887,15 @@ def _splash_dq_kernel(first_ref, last_ref, lomax_ref, himin_ref,
                       lo_ref, hi_ref, dq_ref, dq_acc,
                       *, scale: float, block_q: int, block_k: int):
     i = pl.program_id(2)
-    j = pl.program_id(3)
-    fj, lj = first_ref[i], last_ref[i]
+    r = pl.program_id(3)
+    lj = last_ref[i]
+    j = first_ref[i] + r
 
-    @pl.when(j == fj)
+    @pl.when(r == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    work = (j >= fj) & (j <= lj)
+    work = j <= lj
     full = ((lomax_ref[i] <= j * block_k)
             & (himin_ref[i] >= (j + 1) * block_k - 1))
 
@@ -901,18 +930,21 @@ def _splash_dkv_kernel(firsti_ref, lasti_ref, lomax_ref, himin_ref,
                        scale: float, block_q: int, block_k: int):
     dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc = _dkv_refs(refs)
     j = pl.program_id(2)      # kv block (outer)
-    i = pl.program_id(3)      # q block (inner / minor)
+    r = pl.program_id(3)      # step of its visit range (inner / minor)
     fi, li = firsti_ref[j], lasti_ref[j]
+    work = fi + r <= li
+    # q block; past the range the last one again, as the index maps
+    # have it, so that the FULL test reads inside its arrays
+    i = _visited(firsti_ref, lasti_ref, j, r)
 
-    @pl.when(i == fi)
+    @pl.when(r == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     if dq_acc is not None:
-        _dq_resident_init(dq_acc, j, i)
+        _dq_resident_init(dq_acc, j, r)
 
-    work = (i >= fi) & (i <= li)
     full = ((lomax_ref[i] <= j * block_k)
             & (himin_ref[i] >= (j + 1) * block_k - 1))
 
@@ -943,13 +975,13 @@ def _splash_dkv_kernel(firsti_ref, lasti_ref, lomax_ref, himin_ref,
     pl.when(work & full)(lambda: _step(False))
     pl.when(work & ~full)(lambda: _step(True))
 
-    @pl.when(i == li)
+    @pl.when(r == li - fi)
     def _emit():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
     if dq_acc is not None:
-        _dq_resident_emit(dq_ref, dq_acc, j, i)
+        _dq_resident_emit(dq_ref, dq_acc, j, r)
 
 
 def _splash_dq_call(qt, kt, vt, dot, lse, dcap, spec, *, scale: float,
@@ -960,10 +992,11 @@ def _splash_dq_call(qt, kt, vt, dot, lse, dcap, spec, *, scale: float,
     hq = lse.shape[1]
     dh_p = _LANES
     bm_dq = amask.block_mask(spec, s, block_q, block_k)
+    grid = (b, hq, bm_dq.nq, bm_dq.q_visits)
+    _mark_grid("flash_bwd_dq", grid, bm_dq)
 
-    def kv_index(bi, h, i, j, first_ref, last_ref, *_r):
-        j = jnp.clip(j, first_ref[i], last_ref[i])
-        return (bi, j, h // group)
+    def kv_index(bi, h, i, r, first_ref, last_ref, *_r):
+        return (bi, _visited(first_ref, last_ref, i, r), h // group)
 
     def q_index(bi, h, i, j, *_r):
         return (bi, i, h)
@@ -976,7 +1009,7 @@ def _splash_dq_call(qt, kt, vt, dot, lse, dcap, spec, *, scale: float,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(b, hq, s // block_q, s // block_k),
+        grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, dh_p), q_index),
             pl.BlockSpec((1, block_k, dh_p), kv_index),
@@ -1028,15 +1061,14 @@ def _splash_bwd_impl(q, k, v, out, lse, do, spec, *,
                             (b, hq, _SUBLANES, s))
 
     # dk/dv kernel: transposed visit ranges (per-kv-block q range) at
-    # its own block shape; the minor grid axis walks q blocks
+    # its own block shape; the minor grid axis walks a kv block's q
+    # blocks
     bm_t = amask.block_mask(spec, s, bq_dkv, bk_dkv)
-    nq_t, nk_t = s // bq_dkv, s // bk_dkv
+    grid_t = (b, hq, bm_t.nk, bm_t.kv_visits)
+    _mark_grid("flash_bwd_dkv", grid_t, bm_t)
 
-    def i_clamped(j, i, firsti_ref, lasti_ref):
-        return jnp.clip(i, firsti_ref[j], lasti_ref[j])
-
-    def q_index_t(bi, h, j, i, firsti_ref, lasti_ref, *_r):
-        return (bi, i_clamped(j, i, firsti_ref, lasti_ref), h)
+    def q_index_t(bi, h, j, r, firsti_ref, lasti_ref, *_r):
+        return (bi, _visited(firsti_ref, lasti_ref, j, r), h)
 
     def kv_index_t(bi, h, j, i, *_r):
         return (bi, j, h // group)
@@ -1044,16 +1076,16 @@ def _splash_bwd_impl(q, k, v, out, lse, do, spec, *,
     def kv_out_t(bi, h, j, i, *_r):
         return (bi, j, h)
 
-    def row_index_t(bi, h, j, i, firsti_ref, lasti_ref, *_r):
-        return (bi, h, 0, i_clamped(j, i, firsti_ref, lasti_ref))
+    def row_index_t(bi, h, j, r, firsti_ref, lasti_ref, *_r):
+        return (bi, h, 0, _visited(firsti_ref, lasti_ref, j, r))
 
-    def mrow_index_t(bi, h, j, i, firsti_ref, lasti_ref, *_r):
-        return (0, i_clamped(j, i, firsti_ref, lasti_ref))
+    def mrow_index_t(bi, h, j, r, firsti_ref, lasti_ref, *_r):
+        return (0, _visited(firsti_ref, lasti_ref, j, r))
 
     dq_spec, dq_shape, dq_scratch = _dq_resident_parts(q, dh_p, fused)
     grid_spec_t = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(b, hq, nk_t, nq_t),
+        grid=grid_t,
         in_specs=[
             pl.BlockSpec((1, bq_dkv, dh_p), q_index_t),
             pl.BlockSpec((1, bk_dkv, dh_p), kv_index_t),

@@ -51,6 +51,30 @@ def test_verdicts_match_brute_force(spec, s, bq, bk):
 
 
 @pytest.mark.parametrize("spec", SPECS)
+@pytest.mark.parametrize("s,bq,bk", [(128, 16, 16), (128, 32, 16),
+                                     (96, 16, 32)])
+def test_widest_visit_ranges_match_verdicts(spec, s, bq, bk):
+    """The two integers the kernels' minor grid axes are as long as:
+    the row and the column maxima of ``verdicts()``'s non-SKIP counts
+    (held to the brute force above), and their sum, which is the same
+    set counted either way."""
+    bm = am.block_mask(spec, s, bq, bk)
+    live = bm.verdicts() != am.SKIP
+    assert bm.q_visits == live.sum(axis=1).max()
+    assert bm.kv_visits == live.sum(axis=0).max()
+    assert bm.visited == live.sum() \
+        == (bm.kv_last_q - bm.kv_first_q + 1).sum()
+    assert type(bm.q_visits) is type(bm.kv_visits) is int
+    if spec.is_plain_causal:    # the widest row visits every block
+        assert (bm.q_visits, bm.kv_visits) == (bm.nk, bm.nq)
+    elif spec.window:           # a band: its width, whatever S is
+        assert bm.q_visits < bm.nk and bm.kv_visits < bm.nq
+        wide = am.block_mask(spec, 4 * s, bq, bk)
+        assert (wide.q_visits, wide.kv_visits) == (bm.q_visits,
+                                                   bm.kv_visits)
+
+
+@pytest.mark.parametrize("spec", SPECS)
 def test_allowed_predicate_matches_dense(spec):
     """The traceable predicate (ring hops, serving prefill) is the same
     semantics as the dense builder."""

@@ -6,6 +6,7 @@ The einsum implementation is the ground truth; tolerances are fp32-tight.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import re
 
@@ -180,18 +181,50 @@ def test_splash_causal_bit_identical_to_flash():
         assert jnp.all(a_ == b_)
 
 
+# masks whose widest visit range is shorter than the row of blocks and
+# whose rows differ in theirs, so that the kernels' minor grid axis is
+# the band's and some rows leave steps of it over: a window of three
+# blocks under groups of three, documents under a window, a window that
+# is no multiple of the block, documents that look both ways
+BAND_W384 = am.MaskSpec(causal=True, window=384)
+BAND_DOCS = am.MaskSpec(causal=True, window=100, seg_avg=150, seg_seed=5)
+BAND_W200 = am.MaskSpec(causal=True, window=200)
+
+MASKED_CASES = {
+    # id: (spec, b, s, hq, hkv, (block_q, block_k))
+    **{spec.label(): (spec, 2, 256, 4, 2, (64, 64)) for spec in MASK_SPECS},
+    "band_window384_groups_of_3": (BAND_W384, 1, 1024, 6, 2, (128, 128)),
+    "band_documents_window": (BAND_DOCS, 2, 512, 4, 2, (64, 64)),
+    "band_window200_off_block": (BAND_W200, 1, 512, 4, 1, (64, 64)),
+    "band_window200_bk_over_bq": (BAND_W200, 1, 512, 2, 2, (64, 128)),
+    "band_documents_both_ways": (MASK_SPECS[2], 1, 512, 2, 2, (64, 64)),
+}
+
+
+def _assert_ragged_band(spec, s, bq, bk):
+    """The mask's grids are shorter than ``s // block`` both ways, and
+    not every row fills them."""
+    bm = am.block_mask(spec, s, bq, bk)
+    assert bm.q_visits < bm.nk and bm.kv_visits < bm.nq
+    assert len(set((bm.q_last_k - bm.q_first_k).tolist())) > 1
+    assert len(set((bm.kv_last_q - bm.kv_first_q).tolist())) > 1
+
+
 @longcontext
-@pytest.mark.parametrize("spec", MASK_SPECS)
-def test_splash_masked_matches_dense_reference(spec):
+@pytest.mark.parametrize("name", sorted(MASKED_CASES))
+def test_splash_masked_matches_dense_reference(name):
     """Window / segment / intersection specs vs the dense reference
     applying the SAME mask (fwd <= 1e-5; grads via jax.vjp)."""
-    q, k, v = _make_qkv(jax.random.key(8), 2, 256, 4, 2, 128)
+    spec, b, s, hq, hkv, (bq, bk) = MASKED_CASES[name]
+    if name.startswith("band_"):
+        _assert_ragged_band(spec, s, bq, bk)
+    q, k, v = _make_qkv(jax.random.key(8), b, s, hq, hkv, 128)
     want = _masked_ref(q, k, v, spec)
-    got = splash_attention(q, k, v, spec, 64, 64)
+    got = splash_attention(q, k, v, spec, bq, bk)
     assert jnp.max(jnp.abs(got - want)) < 1e-5
     cot = jax.random.normal(jax.random.key(9), q.shape, q.dtype)
     _, vjp_ref = jax.vjp(lambda *xs: _masked_ref(*xs, spec), q, k, v)
-    _, vjp_spl = jax.vjp(lambda *xs: splash_attention(*xs, spec, 64, 64),
+    _, vjp_spl = jax.vjp(lambda *xs: splash_attention(*xs, spec, bq, bk),
                          q, k, v)
     for a_, b_ in zip(vjp_ref(cot), vjp_spl(cot)):
         assert jnp.max(jnp.abs(a_ - b_)) < 1e-4
@@ -314,6 +347,13 @@ RESIDENT_CASES = {
     "other_blocks": (1, 256, 2, 2, 128, 128, True, (128, 128), (64, 128)),
     "other_blocks_window": (1, 256, 2, 2, 128, 128, WINDOW,
                             (128, 64), (64, 64)),
+    # the ragged bands of ``MASKED_CASES``: the grids' minor axes are
+    # the band's, 4 of 8 steps and under
+    **{name: (b, s, hq, hkv, 128, 128, spec, blocks, blocks)
+       for name, (spec, b, s, hq, hkv, blocks) in MASKED_CASES.items()
+       if name.startswith("band_")},
+    "band_window200_other_blocks": (1, 512, 2, 2, 128, 128, BAND_W200,
+                                    (128, 64), (64, 64)),
 }
 
 
@@ -381,8 +421,10 @@ def test_resident_dq_matches_the_two_kernels(name, monkeypatch,
 
 
 @longcontext
-@pytest.mark.parametrize("name", ["causal", "window", "documents_both_ways",
-                                  "scores192_values128", "bk_over_bq"])
+@pytest.mark.parametrize("name", [
+    "causal", "window", "documents_both_ways", "scores192_values128",
+    "bk_over_bq", "band_window384_groups_of_3", "band_documents_window",
+    "band_window200_off_block", "band_window200_bk_over_bq"])
 def test_resident_dq_matches_reference(name, resident_backward):
     """And against the einsum reference under the same mask."""
     (dq, dk, dv), _, (q, k, v, do, mask) = resident_backward(name)
@@ -480,21 +522,31 @@ def test_past_the_rule_the_callers_blocks_stand():
         (False, (2048, 2048))
 
 
-def test_the_window_layers_backward_runs_at_the_fitted_blocks():
-    """``smallthinker_21b_a3b_train_s16k``'s window layers hand
-    ``ops.attention`` blocks of 2048 for both directions: the forward
-    and the backward's grid as traced."""
+@pytest.mark.parametrize("shape,hkv,window,block,fwd,dkv", [
+    # smallthinker_21b_a3b_train_s16k: blocks of 2048 for both
+    # directions, the backward fitted to 1024 beside a head's dq; a row
+    # block sees the diagonal and two blocks before it, a key block of
+    # 1024 is seen from five
+    ((1, 16384, 28, 128), 4, 4096, 2048, "1, 28, 8, 3", "1, 28, 16, 5"),
+    # laguna_s21_train_s16k: a window of one block, two visits each way
+    ((1, 16384, 72, 128), 8, 512, 512, "1, 72, 32, 2", "1, 72, 32, 2"),
+], ids=["smallthinker", "laguna"])
+def test_the_window_layers_backward_runs_at_the_fitted_blocks(
+        shape, hkv, window, block, fwd, dkv):
+    """The window layers' grids as traced from ``ops.attention`` at the
+    blocks the models hand it: the forward's and the backward's (at the
+    fitted blocks), each minor axis the mask's widest visit range and
+    not ``S // block``."""
     from dlnetbench_tpu import ops
-    shape, kv = (1, 16384, 28, 128), (1, 16384, 4, 128)
-    spec = am.MaskSpec(causal=True, window=4096)
+    kv = shape[:2] + (hkv, shape[3])
+    spec = am.MaskSpec(causal=True, window=window)
     text = str(jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(ops.attention(
-        q, k, v, causal=True, impl="flash", mask=spec, block_q=2048,
-        block_k=2048).astype(jnp.float32)), argnums=(0, 1, 2)))(
+        q, k, v, causal=True, impl="flash", mask=spec, block_q=block,
+        block_k=block).astype(jnp.float32)), argnums=(0, 1, 2)))(
         *[jax.ShapeDtypeStruct(x, jnp.bfloat16) for x in (shape, kv, kv)]))
     grids = {name: grid for grid, name in re.findall(
         r"grid=\((.*?)\).*?name=(flash_\w+)", text, re.S)}
-    assert grids == {"flash_fwd": "1, 28, 8, 8",
-                     "flash_bwd_dkv": "1, 28, 16, 16"}
+    assert grids == {"flash_fwd": fwd, "flash_bwd_dkv": dkv}
 
 
 def test_resident_dq_without_a_tracer_marks_nothing():
@@ -502,4 +554,117 @@ def test_resident_dq_without_a_tracer_marks_nothing():
     assert fa._dq_resident(jax.ShapeDtypeStruct((1, 256, 2, 128),
                                                 jnp.float32), 128, 64, 64,
                            fit=True) == (True, 64, 64)
+    assert spans.current() is None
+
+
+# ------------------------------------------- the grid follows the mask
+# The block-sparse kernels' minor grid axis is as long as the mask's
+# widest visit range, and step r of row i names block first[i] + r.
+# The grid that was there before, ``S // block`` long with the steps
+# outside a row's range doing nothing, is the same kernels under a mask
+# whose widest visit is every block: the same tiles in the same order,
+# so the same floats.
+
+def _full_grid_masks(monkeypatch):
+    """``block_mask`` with both widest visits at ``S // block``."""
+    compact = am.block_mask
+
+    def full(*args):
+        bm = compact(*args)
+        return dataclasses.replace(bm, q_visits=bm.nk, kv_visits=bm.nq)
+    monkeypatch.setattr(fa.amask, "block_mask", full)
+
+
+def _masked_call(name, dtype):
+    """out, lse, dq, dk, dv of a ``MASKED_CASES`` call at its blocks,
+    and its ``flash.grid`` marks by kernel."""
+    spec, b, s, hq, hkv, (bq, bk) = MASKED_CASES[name]
+    q, k, v = _make_qkv(jax.random.key(31), b, s, hq, hkv, 128, dtype)
+    do = jax.random.normal(jax.random.key(32), q.shape, dtype)
+    tracer = spans.enable()
+    try:
+        out, lse = fa._splash_fwd(q, k, v, spec, block_q=bq, block_k=bk)
+        grads = fa._splash_bwd_impl(q, k, v, out, lse, do, spec,
+                                    block_q=bq, block_k=bk,
+                                    consult_db=False)
+    finally:
+        spans.disable()
+    marks = {s_["attrs"]["kernel"]: s_["attrs"]
+             for s_ in tracer.export()["spans"] if s_["name"] == "flash.grid"}
+    return (out, lse, *grads), marks
+
+
+@longcontext
+@pytest.mark.parametrize("two_kernels", [False, True],
+                         ids=["fused", "two_kernels"])
+@pytest.mark.parametrize("name,dtype", [
+    ("band_window384_groups_of_3", jnp.float32),
+    ("band_window384_groups_of_3", jnp.bfloat16),
+    ("band_documents_window", jnp.float32),
+    ("band_window200_bk_over_bq", jnp.bfloat16),
+    ("causal&seg(avg=50,seed=3)", jnp.float32),
+    ("band_documents_both_ways", jnp.bfloat16),
+])
+def test_compact_grid_gives_the_full_grids_floats(name, dtype, two_kernels,
+                                                  monkeypatch):
+    """Outputs, lse and all three gradients at fixed blocks, bit for
+    bit, on the band's grid and on the ``S // block`` one; and the
+    band's grid is the shorter by the factor the marks give."""
+    if two_kernels:
+        monkeypatch.setattr(fa, "_DQ_RESIDENT_SHARE", 0.0)
+    compact, marks = _masked_call(name, dtype)
+    _full_grid_masks(monkeypatch)
+    full, full_marks = _masked_call(name, dtype)
+    kernels = ["flash_fwd", "flash_bwd_dkv"] + ["flash_bwd_dq"] * two_kernels
+    assert sorted(marks) == sorted(full_marks) == sorted(kernels)
+    spec, b, s, hq, _, (bq, bk) = MASKED_CASES[name]
+    for kernel in kernels:
+        assert full_marks[kernel]["steps"] == b * hq * (s // bq) * (s // bk)
+        assert marks[kernel]["live"] == full_marks[kernel]["live"]
+        assert marks[kernel]["steps"] < full_marks[kernel]["steps"]
+    for a, c, what in zip(compact, full, ("out", "lse", "dq", "dk", "dv")):
+        assert a.dtype == c.dtype and jnp.array_equal(a, c), what
+
+
+@longcontext
+@pytest.mark.parametrize("two_kernels", [False, True],
+                         ids=["fused", "two_kernels"])
+def test_each_block_sparse_call_marks_its_grid(two_kernels, monkeypatch):
+    """``flash.grid`` once a traced site: the grid's product and the
+    steps that visit a block, from the mask at that kernel's blocks.
+    A window of 384 in blocks of 128 at S = 1024: eight rows of at most
+    four visits, 1 + 2 + 3 + 5 x 4 = 26 of the 32 steps live."""
+    if two_kernels:
+        monkeypatch.setattr(fa, "_DQ_RESIDENT_SHARE", 0.0)
+    _, marks = _masked_call("band_window384_groups_of_3", jnp.float32)
+    grid = {"steps": 6 * 8 * 4, "live": 6 * 26}
+    want = {"flash_fwd": grid, "flash_bwd_dkv": grid}
+    if two_kernels:
+        want["flash_bwd_dq"] = grid
+    assert marks == {k: {"kernel": k, **g} for k, g in want.items()}
+    bm = am.block_mask(BAND_W384, 1024, 128, 128)
+    assert (bm.q_visits, bm.kv_visits, bm.visited) == (4, 4, 26)
+
+
+def test_the_plain_causal_grid_is_the_dense_kernels():
+    """Where the widest row visits every block the grid is ``S // block``
+    long to the last index (and ``test_splash_causal_bit_identical_to_
+    flash`` holds the floats)."""
+    def grids(fn):
+        x = jax.ShapeDtypeStruct((1, 512, 2, 128), jnp.float32)
+        text = str(jax.make_jaxpr(jax.grad(
+            lambda q, k, v: jnp.sum(fn(q, k, v)), argnums=(0, 1, 2)))(x, x, x))
+        return re.findall(r"grid=\((.*?)\)", text)
+    dense = grids(lambda *a: flash_attention(*a, True, 128, 128))
+    assert dense == ["1, 2, 4, 4"] * 2
+    assert grids(lambda *a: splash_attention(
+        *a, am.MaskSpec(causal=True), 128, 128)) == dense
+
+
+def test_a_block_sparse_call_without_a_tracer_marks_nothing():
+    assert not spans.is_enabled()
+    x = jnp.ones((1, 256, 1, 128), jnp.float32)
+    out, lse = fa._splash_fwd(x, x, x, WINDOW, block_q=64, block_k=64)
+    fa._splash_bwd_impl(x, x, x, out, lse, x, WINDOW, block_q=64,
+                        block_k=64, consult_db=False)
     assert spans.current() is None
