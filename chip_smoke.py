@@ -130,6 +130,19 @@ class Sizes:
         hidden_size=256, num_attention_heads=4, num_key_value_heads=1,
         intermediate_size=512, moe_intermediate_size=128, vocab_size=512))
     v_seq: int = 1024
+    # sparse_linear_ops: the two ops of the sparse-and-linear hybrid at
+    # its cell's shapes (32 query heads over 2 of 128 lanes, 16384
+    # tokens, the published selection); the sparse gradients against a
+    # dense reference at a length one chip holds one group of
+    o_seq: int = 16384
+    o_heads: int = 32
+    o_kv_heads: int = 2
+    o_head_dim: int = 128
+    o_sizes: tuple = (32, 16, 64, 64, 2048, 1, 8192)
+    o_rule_seq: int = 1024
+    o_grad_seq: int = 4096
+    o_grad_sizes: tuple = (32, 16, 64, 16, 512, 1, 2048)
+    o_rows: int = 256
     # swa_moe: the published 128 lanes a head in groups of seven, one
     # period [full, window, window, window], a quarter of the experts held
     w_shape: dict = dataclasses.field(default_factory=lambda: dict(
@@ -165,6 +178,9 @@ FULL = Sizes()
 _TINY_SHAPE = dict(embed_dim=64, num_heads=8, num_kv_heads=4, ff_dim=128,
                    vocab_size=256)
 TINY = Sizes(
+    o_seq=256, o_heads=8, o_kv_heads=2, o_head_dim=16,
+    o_sizes=(8, 4, 16, 4, 32, 1, 64), o_rule_seq=96, o_grad_seq=128,
+    o_grad_sizes=(8, 4, 16, 4, 32, 1, 64), o_rows=32,
     dtype="float32",
     k_tokens=128, k_embed=128, k_ff=256, k_seq=128, k_heads=4,
     k_kv_heads=2, k_head_dim=32, k_experts=4, k_capacity=32,
@@ -1360,6 +1376,132 @@ def phase_headgate_moe(sz: Sizes) -> dict:
                       "compiled in"}
 
 
+def phase_sparse_linear_ops(sz: Sizes) -> dict:
+    """The two ops of the sparse-and-linear hybrid at its cell's shapes
+    (``ops/lightning_attention.py``, ``ops/sparse_attention.py``), the
+    Pallas kernels forced.  The lightning rule: the kernel pair against
+    the same chunks as a ``lax.scan`` at the cell's length, output and
+    three gradients, and against the token recurrence at a length whose
+    states fit.  The sparse layer: the selection at the cell's length
+    (every token's list holds block 0 and its own block and as many
+    blocks as exist, up to ``topk``), the forward kernel there against
+    dense masked softmax on sampled query rows, and all three gradients
+    of one group against the dense reference at a length it fits."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from dlnetbench_tpu.ops import lightning_attention as la
+    from dlnetbench_tpu.ops import sparse_attention as sa
+
+    f32, dt = jnp.float32, jnp.dtype(sz.dtype)
+    s, h, hkv, d = sz.o_seq, sz.o_heads, sz.o_kv_heads, sz.o_head_dim
+    keys = jax.random.split(jax.random.key(sz.seed), 8)
+    checks: dict = {}
+
+    def draw(key, *shape):
+        return jax.random.normal(key, shape, f32).astype(dt)
+
+    # --- the lightning rule
+    decay = la.head_log_decay(h, 1, 32)
+    scale = d ** -0.5
+    q, k, v, w = (draw(kk, 1, s, h, d) for kk in keys[:4])
+
+    def rule(impl, fn=la.lightning_attention):
+        def loss(q, k, v):
+            args = (impl,) if impl else ()
+            o = fn(q, k, v, decay, scale, *args)
+            return jnp.sum(o.astype(f32) * w[:, :q.shape[1]].astype(f32)), o
+        return jax.jit(jax.value_and_grad(loss, (0, 1, 2), has_aux=True))
+    (_, o_k), g_k = rule("pallas")(q, k, v)
+    (_, o_x), g_x = rule("xla")(q, k, v)
+    close(checks, "lightning o, kernels vs scan", o_k, o_x, 1e-2)
+    for name, a, b in zip(("dq", "dk", "dv"), g_k, g_x):
+        close(checks, f"lightning {name}, kernels vs scan", a, b, 1e-2)
+    short = tuple(t[:, :sz.o_rule_seq] for t in (q, k, v))
+    (_, o_k), g_k = rule("pallas")(*short)
+    (_, o_r), g_r = rule(None, la.reference_rule)(
+        *(t.astype(f32) for t in short))
+    close(checks, "lightning o vs recurrence", o_k, o_r, 3e-2)
+    for name, a, b in zip(("dq", "dk", "dv"), g_k, g_r):
+        close(checks, f"lightning {name} vs recurrence", a, b, 3e-2)
+
+    # --- the selection and the sparse forward at the cell's length
+    sizes = sa.SparseSizes(*sz.o_sizes)
+    q = draw(keys[4], 1, s, h, d)
+    k, v = (draw(kk, 1, s, hkv, d) for kk in keys[5:7])
+    blocks = jax.jit(lambda q, k: sa.select_blocks(q, k, sizes))(q, k)
+    lists = np.asarray(blocks)[0]                       # [S, Hkv, topk]
+    own = np.arange(s) // sizes.block_size
+    require(bool(((lists == 0).any(-1)).all()
+                 and (lists == own[:, None, None]).any(-1).all()),
+            "a list without block 0 or the token's own block")
+    require(bool(((lists >= 0).sum(-1)
+                  == np.minimum(own + 1, sizes.topk)[:, None]).all()
+                 and (lists <= own[:, None, None]).all()),
+            "a list of another length than the visible blocks allow, or "
+            "a block after the token")
+    out, counted = jax.jit(lambda q, k, v, b: (
+        lambda vis: (sa.block_sparse_attention(q, k, v, vis),
+                     sa.counters(b, vis)))(
+        sa.plan_visits(b, sizes.block_size, q.dtype)))(q, k, v, blocks)
+    rows = np.arange(sz.o_rows) * (s // sz.o_rows) + s // sz.o_rows - 1
+
+    @jax.jit
+    def sampled(q, k, v, blocks):
+        """Dense masked softmax of the sampled query rows."""
+        member = sa.membership(blocks[:, rows], s // sizes.block_size,
+                               f32)[0]                  # [Hkv, n, nblk]
+        seen = (jnp.repeat(member, sizes.block_size, -1) > 0) \
+            & (jnp.arange(s)[None, None, :] <= rows[None, :, None])
+        qg = q[0, rows].astype(f32).reshape(len(rows), hkv, h // hkv, d)
+        sc = jnp.einsum("ngqd,kgd->gnqk", qg, k[0].astype(f32),
+                        precision="highest") * scale
+        pr = jax.nn.softmax(jnp.where(seen[:, :, None, :], sc, -jnp.inf),
+                            axis=-1)
+        o = jnp.einsum("gnqk,kgd->ngqd", pr, v[0].astype(f32),
+                       precision="highest")
+        return o.reshape(len(rows), h, d)
+    close(checks, "sparse o on sampled rows vs dense masked softmax",
+          out[0, rows], sampled(q, k, v, blocks), 2e-2)
+    counted = jax.device_get(counted)
+
+    # --- the sparse gradients, one group, at a length a reference fits
+    gs, gsizes = sz.o_grad_seq, sa.SparseSizes(*sz.o_grad_sizes)
+    group = h // hkv
+    q, w = (draw(kk, 1, gs, group, d) for kk in (keys[4], keys[7]))
+    k, v = (draw(kk, 1, gs, 1, d) for kk in keys[5:7])
+    blocks = sa.select_blocks(q, k, gsizes)
+
+    def attn(fn):
+        def loss(q, k, v):
+            o = fn(q, k, v, blocks, gsizes.block_size)
+            return jnp.sum(o.astype(f32) * w.astype(f32)), o
+        return jax.jit(jax.value_and_grad(loss, (0, 1, 2), has_aux=True))
+    (_, o_k), g_k = attn(lambda q, k, v, lists, block: (
+        sa.block_sparse_attention(q, k, v, sa.plan_visits(
+            lists, block, q.dtype))))(q, k, v)
+    (_, o_r), g_r = attn(sa.reference_attention)(
+        *(t.astype(f32) for t in (q, k, v)))
+    close(checks, "sparse o, one group vs dense reference", o_k, o_r, 2e-2)
+    for name, a, b in zip(("dq", "dk", "dv"), g_k, g_r):
+        close(checks, f"sparse {name}, one group vs dense reference", a, b,
+              3e-2)
+    return {"shapes": {"seq": s, "heads": h, "kv_heads": hkv,
+                       "head_dim": d, "sizes": list(sz.o_sizes),
+                       "rule_seq": sz.o_rule_seq, "grad_seq": gs,
+                       "grad_sizes": list(sz.o_grad_sizes),
+                       "sampled_rows": sz.o_rows},
+            "selected": int(counted["selected"]),
+            "visited": int(counted["visited"]),
+            "kernel_checks": checks,
+            "checks": "lightning kernels against the scan of chunks and "
+                      "the token recurrence; every list holds block 0, "
+                      "the token's own block and min(visible, topk) "
+                      "blocks; sparse forward against dense masked "
+                      "softmax on sampled rows; sparse gradients of one "
+                      "group against the dense reference"}
+
+
 # ------------------------------------------------------ four-chip phases
 
 def all_device_ids() -> set:
@@ -1571,7 +1713,8 @@ ONE_CHIP = (("kernels", phase_kernels), ("train", phase_train),
             ("linear_moe", phase_linear_moe),
             ("conv_moe", phase_conv_moe),
             ("swa_moe", phase_swa_moe),
-            ("headgate_moe", phase_headgate_moe))
+            ("headgate_moe", phase_headgate_moe),
+            ("sparse_linear_ops", phase_sparse_linear_ops))
 FOUR_CHIPS = (("mesh_proxies", phase_mesh_proxies), ("spmd", phase_spmd),
               ("kv_shard", phase_kv_shard))
 

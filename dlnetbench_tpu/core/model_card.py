@@ -66,8 +66,8 @@ class ModelCard:
     patch_size: int = 0             # ViT
     num_classes: int = 0            # ViT head
     # a per-layer pattern of mixers (models/hybrid.py KINDS: mamba,
-    # window, full, gmu, cross, mla, gdn, gated, conv, swa, nope), one
-    # name a decoder block; () => every
+    # window, full, gmu, cross, mla, gdn, gated, conv, swa, nope, sparse,
+    # lightning), one name a decoder block; () => every
     # block is the transformer's one kind (models/transformer.py)
     layer_kinds: tuple = ()
     sliding_window: int = 0         # keys a "window" or "swa" layer attends
@@ -120,6 +120,22 @@ class ModelCard:
     # (factor, original positions, beta_fast, beta_slow, the factor on
     # cos and sin); () => plain RoPE
     rope_yarn: tuple = ()
+    # MiniCPM's three scalars, 1.0 => none: the embedding times
+    # embed_scale (scale_emb), each branch times residual_scale before
+    # it is added (scale_depth / sqrt(published depth)), the final
+    # normed stream times logit_scale (dim_model_base / hidden_size)
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
+    # a "sparse" layer's selection (ops/sparse_attention.SparseSizes):
+    # (kernel_size, kernel_stride, block_size, topk, window_size,
+    # init_blocks, dense_len)
+    sparse_attention: tuple = ()
+    # a "lightning" layer's heads are linear_key_heads of linear_key_dim
+    # lanes (values linear_value_dim); its decay reads the layer's index
+    # among published_layers (0 => num_decoder_blocks: a cut of a model
+    # keeps the published depth here)
+    published_layers: int = 0
 
     # ------------------------------------------------------------------ #
     @property
@@ -187,7 +203,12 @@ class ModelCard:
             return (d * (2 * qk + 2 * vz) + d * 2 * hv
                     + self.linear_conv * (2 * qk + vz) + 2 * hv
                     + self.linear_value_dim + vz * d)
-        if kind in ("gated", "swa", "nope"):
+        if kind == "lightning":
+            qk = self.linear_key_heads * self.linear_key_dim
+            vz = self.linear_value_heads * self.linear_value_dim
+            return (d * (2 * qk + 2 * vz) + 2 * self.linear_key_dim
+                    + self.linear_value_dim + vz * d)
+        if kind in ("gated", "swa", "nope", "sparse"):
             dh = self.attn_head_dim or self.head_dim
             heads = (self.window_heads if kind == "swa"
                      and self.window_heads else self.num_heads)
@@ -266,7 +287,7 @@ def _parse_card(name: str, raw: dict) -> ModelCard:
         moe = MoEParams(**raw["moe_params"])
     known = {f.name for f in dataclasses.fields(ModelCard)}
     kwargs = {k: v for k, v in raw.items() if k in known and k != "moe_params"}
-    for key in ("layer_kinds", "rope_yarn"):
+    for key in ("layer_kinds", "rope_yarn", "sparse_attention"):
         if key in kwargs:
             kwargs[key] = tuple(kwargs[key])
     return ModelCard(name=name, moe_params=moe, **kwargs)

@@ -7,7 +7,7 @@ for this framework: what every downstream layer consumes is the
 a HF model" is a card, not a cache of safetensors.  This module maps a HF
 config (``model_type`` gpt2 / llama / mistral / mixtral / phi4flash /
 deepseek_v3 as Kimi-VL and Moonlight state it / qwen3_next / lfm2_moe /
-smallthinker / laguna / vit) onto
+smallthinker / laguna / minicpm_sala / vit) onto
 ``ModelCard`` fields and writes the card JSON.
 
 Offline-first: hub access is attempted only when requested and is never
@@ -24,6 +24,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import math
 import sys
 from pathlib import Path
 from typing import Any, Mapping
@@ -114,6 +115,9 @@ def card_from_hf_config(name: str, cfg: Mapping[str, Any] | Any) -> ModelCard:
 
     if mt == "laguna":
         return _headgate_moe_card(name, cfg)
+
+    if mt == "minicpm_sala":
+        return _sparse_linear_card(name, cfg)
 
     if mt == "vit":
         image = int(cfg["image_size"])
@@ -543,6 +547,98 @@ def _headgate_moe_card(name: str, cfg: Mapping[str, Any]) -> ModelCard:
             expert_ff_dim=width,
             first_dense_layers=first_dense,
         ),
+    )
+
+
+_SALA_KIND = {"minicpm4": "sparse", "lightning-attn": "lightning"}
+# MiniCPM4-8B's published ``sparse_config``, in
+# ``ops/sparse_attention.SparseSizes``' order: the MiniCPM-SALA config
+# names the same ``minicpm4`` mixer and does not repeat its sizes
+_MINICPM4_SPARSE = {"kernel_size": 32, "kernel_stride": 16, "block_size": 64,
+                    "topk": 64, "window_size": 2048, "init_blocks": 1,
+                    "dense_len": 8192}
+
+
+def _sparse_linear_card(name: str, cfg: Mapping[str, Any]) -> ModelCard:
+    """``model_type: "minicpm_sala"``: by ``mixer_types`` a ``minicpm4``
+    layer (``sparse``: grouped softmax attention without position over
+    the key blocks a token selects, a norm a head, a gate of the head's
+    width) or a ``lightning-attn`` layer (``lightning``: linear
+    attention with a constant decay a head, RoPE, a norm a head on
+    queries, keys and the output, a gate), dense SwiGLUs, an untied
+    head, and MiniCPM's three scalars (``scale_emb``; ``scale_depth /
+    sqrt(num_hidden_layers)``; ``dim_model_base / hidden_size``).  The
+    selection's sizes are the config's ``sparse_config`` where it has
+    one, else MiniCPM4's.  Refused: a list of another length than
+    ``num_hidden_layers``, an unknown mixer (by its layer's number),
+    lightning keys and values at another head count than its queries,
+    another ``lightning_scale``, RoPE on a sparse layer, a layer without
+    its norms or gates, a bias."""
+    layers = int(cfg["num_hidden_layers"])
+    mixers = list(cfg["mixer_types"])
+    if len(mixers) != layers:
+        raise ValueError(f"{name}: {len(mixers)} entries of mixer_types "
+                         f"for {layers} layers")
+    for li, m in enumerate(mixers):
+        if m not in _SALA_KIND:
+            raise ValueError(f"{name}: layer {li} is a {m!r} mixer; this "
+                             f"import has {sorted(_SALA_KIND)}")
+    nh = int(cfg["lightning_nh"])
+    unsupported = {k: v for k, v, ok in (
+        ("lightning_nkv", cfg.get("lightning_nkv"),
+         int(cfg.get("lightning_nkv", nh)) == nh),
+        ("lightning_scale", cfg.get("lightning_scale"),
+         cfg.get("lightning_scale", "1/sqrt(d)") == "1/sqrt(d)"),
+        ("lightning_use_rope", cfg.get("lightning_use_rope"),
+         cfg.get("lightning_use_rope", True)),
+        ("attn_use_rope", cfg.get("attn_use_rope"),
+         not cfg.get("attn_use_rope", False)),
+        ("qk_norm", cfg.get("qk_norm"), cfg.get("qk_norm", True)),
+        ("use_output_norm", cfg.get("use_output_norm"),
+         cfg.get("use_output_norm", True)),
+        ("use_output_gate", cfg.get("use_output_gate"),
+         cfg.get("use_output_gate", True)),
+        ("attn_use_output_gate", cfg.get("attn_use_output_gate"),
+         cfg.get("attn_use_output_gate", True)),
+        ("hidden_act", cfg.get("hidden_act"),
+         cfg.get("hidden_act", "silu") == "silu"),
+        ("attention_bias", cfg.get("attention_bias"),
+         not cfg.get("attention_bias"))) if not ok}
+    if unsupported:
+        raise ValueError(f"{name}: sparse-and-linear import has no "
+                         f"{unsupported}")
+    hidden = int(cfg["hidden_size"])
+    heads = int(cfg["num_attention_heads"])
+    sizes = {**_MINICPM4_SPARSE, **cfg.get("sparse_config", {})}
+    dh = int(cfg["lightning_head_dim"])
+    return ModelCard(
+        name=name,
+        embed_dim=hidden,
+        num_heads=heads,
+        num_kv_heads=int(cfg.get("num_key_value_heads") or heads),
+        ff_dim=int(cfg["intermediate_size"]),
+        seq_len=int(cfg["max_position_embeddings"]),
+        num_decoder_blocks=layers,
+        vocab_size=int(cfg["vocab_size"]),
+        gated_mlp=True,
+        tied_embeddings=bool(cfg.get("tie_word_embeddings", False)),
+        layer_kinds=tuple(_SALA_KIND[m] for m in mixers),
+        attn_head_dim=int(cfg.get("head_dim") or hidden // heads),
+        attn_output_gate=True,
+        attn_head_norm=True,
+        rope_theta=float(cfg.get("rope_theta", 10000.0)),
+        rms_norm=True,
+        norm_eps=float(cfg.get("rms_norm_eps", 1e-6)),
+        linear_key_heads=nh,
+        linear_value_heads=nh,
+        linear_key_dim=dh,
+        linear_value_dim=dh,
+        embed_scale=float(cfg.get("scale_emb", 1.0)),
+        residual_scale=float(cfg.get("scale_depth", 1.0))
+        / math.sqrt(layers),
+        logit_scale=float(cfg.get("dim_model_base", hidden)) / hidden,
+        sparse_attention=tuple(int(sizes[k]) for k in _MINICPM4_SPARSE),
+        published_layers=layers,
     )
 
 

@@ -51,7 +51,8 @@ from pathlib import Path
 # The model step's layers, by name.  Declared here once: the models wear
 # them through ``scope()``, the executor's op->scope table and the
 # benchmark's per-layer readers take them from here.
-SCOPES = ("embed", "attn", "attn.window", "attn.full", "attn.gate", "mlp",
+SCOPES = ("embed", "attn", "attn.window", "attn.full", "attn.gate",
+          "attn.select", "attn.sparse", "mlp",
           "moe.router", "moe.dispatch", "moe.experts", "moe.combine",
           "moe.shared", "ssm", "ssm.scan", "gmu", "linattn", "linattn.rule",
           "conv", "conv.gate", "head_loss", "optimizer")

@@ -74,7 +74,8 @@ def make_train_k(cfg, k: int, lr: float = 1e-3):
     ``(params, losses[k])``; for a model with expert layers
     ``(params, (losses[k], routing))``, each entry of ``routing``
     (``models/hybrid.ROUTING``: three counters and the selections)
-    with a leading [k].
+    with a leading [k]; a model whose sparse attention layers select
+    returns their ``hybrid.SELECTION`` in the same dict.
 
     ``lr`` is the SGD step: at the bench's 1e-3 the bf16 weights barely
     move (the headline measures time); a caller that wants to see the
@@ -85,7 +86,7 @@ def make_train_k(cfg, k: int, lr: float = 1e-3):
 
     # the one step builder for every model family: a config names its
     # model by its type
-    counts = isinstance(cfg, hybrid.HybridConfig) and cfg.has_experts
+    counts = isinstance(cfg, hybrid.HybridConfig) and cfg.returns_aux
     loss_fn = (hybrid.loss_and_routing if counts
                else hybrid.loss_fn if isinstance(cfg, hybrid.HybridConfig)
                else tfm.loss_fn)

@@ -23,7 +23,10 @@ attention expert models of the Laguna family (``swa`` layers with a
 and turned lanes of their own, YaRN on the full layers', one sigmoid
 gate a head from a projection of its own, a leading dense layer, then
 softmax-routed experts times ``routed_scale`` beside a plain shared
-one).
+one) and the sparse-and-linear hybrids of the MiniCPM-SALA family
+(``lightning`` layers with a ``sparse`` layer about every fourth, dense
+SwiGLUs, an untied head, and MiniCPM's three scalars on the residual
+path: below).
 
 ``HybridConfig.layer_kinds`` names each layer's mixer, one of ``KINDS``:
 
@@ -72,8 +75,29 @@ one).
   ``[b | c | u]`` of the model's width, ``c * conv(b * u)`` with a
   depthwise causal convolution of ``short_conv`` taps over time, no
   activation and no bias, and an output projection.
+* ``sparse`` — the ``gated`` layer's projections, norm a head and gate
+  of a head's width with no position, over the key blocks each token
+  chooses for itself (InfLLM-V2; ``sparse_sizes``,
+  ``ops.sparse_attention``): a sequence longer than the sizes'
+  ``dense_len`` selects (``select_blocks``, no gradient, once a step:
+  the checkpoint keeps the lists) and attends its own blocks
+  (``block_sparse_attention``); at or under it the layer is a ``nope``
+  layer with a gate, bit for bit.
+* ``lightning`` — linear attention with a constant decay a head
+  (``ops.lightning_attention``): projections to queries, keys and
+  values at ``gdn_key_heads`` heads, a norm a head on queries and keys,
+  RoPE on every lane, ``S_t = lambda_h S_{t-1} + k_t^T v_t`` with
+  ``lambda_h`` from the head's slope and the layer's place among
+  ``lightning_depth`` layers (a constant, not a leaf), an RMSNorm over
+  each head's output (``o_norm``) times ``sigmoid`` of a gate projected
+  from the layer's normed input, and an output projection.
 
-Every layer is ``x += mixer(norm(x)); x += ffn(norm(x))``.
+Every layer is ``x += c mixer(norm(x)); x += c ffn(norm(x))`` with
+``c = residual_scale``; the embedding is times ``embed_scale`` and the
+final normed stream times ``logit_scale`` before the head.  The three
+are 1.0 (and then no operation of the program) everywhere but in the
+MiniCPM family, whose ``scale_emb``, ``scale_depth / sqrt(published
+depth)`` and ``dim_model_base / hidden_size`` they are.
 ``ffn_kinds`` names each layer's FFN: ``dense`` (SwiGLU at ``ff_dim``)
 or ``moe``: ``sum_i w_i expert_i(y)`` over the token's top-k of
 ``num_experts`` routed experts, of which this chip holds
@@ -116,14 +140,17 @@ from dlnetbench_tpu.core.model_card import ModelCard
 from dlnetbench_tpu.metrics.spans import mark, scope
 from dlnetbench_tpu.models import layers as L
 from dlnetbench_tpu.models.moe import moe_held
+from dlnetbench_tpu.ops import sparse_attention as sparse
 from dlnetbench_tpu.ops.attention_mask import MaskSpec
 from dlnetbench_tpu.ops.flash_attention import KEPT_NAMES
 from dlnetbench_tpu.ops.gated_delta_rule import gated_delta_rule
+from dlnetbench_tpu.ops.lightning_attention import (head_log_decay,
+                                                    lightning_attention)
 from dlnetbench_tpu.ops.selective_scan import selective_scan
 
 _F32 = jnp.float32
 KINDS = ("mamba", "window", "full", "gmu", "cross", "mla", "gdn", "gated",
-         "conv", "swa", "nope")
+         "conv", "sparse", "lightning", "swa", "nope")
 FFN_KINDS = ("dense", "moe")
 # what a layer's kind reads: the stack of parameters its mixer's weights
 # lie in, and the inner scope (``spans.SCOPES``) its kernel call wears
@@ -134,7 +161,8 @@ _READS = {"mamba": ("mamba", None), "window": ("attn", None),
           "cross": ("cross", None), "mla": ("mla", None),
           "gdn": ("gdn", None), "gated": ("gated", None),
           "conv": ("conv", None), "swa": ("gated", "attn.window"),
-          "nope": ("gated", "attn.full")}
+          "nope": ("gated", "attn.full"), "sparse": ("gated", "attn.sparse"),
+          "lightning": ("lightning", "linattn.rule")}
 # the kinds ``gated_mixer`` computes, whichever stack holds their weights
 _GATED_KINDS = frozenset(k for k, (g, _) in _READS.items() if g == "gated")
 # what a step with expert layers returns beside its loss
@@ -143,6 +171,10 @@ _GATED_KINDS = frozenset(k for k, (g, _) in _READS.items() if g == "gated")
 # largest load of one expert) and every layer's selection
 COUNTERS = ("routed", "max_load", "past_bound")
 ROUTING = (*COUNTERS, "choices")
+# what a step whose sparse layers select returns beside its loss: every
+# such layer's lists stacked [sparse layers, B, S, Hkv, topk] and two
+# counters summed over them (``ops.sparse_attention.counters``)
+SELECTION = ("blocks", "selected", "visited")
 # leaves kept in float32 whatever the model's dtype (the family's
 # convention: the recurrence's own parameters, lambdas and norms)
 F32_LEAVES = frozenset({
@@ -228,6 +260,18 @@ class HybridConfig:
                                     # this chip holds; () = all of them
     moe_slots: int = 0              # rows a held expert's buffer has: a
                                     # bound the load is not to reach
+    # MiniCPM's three scalars; 1.0 = none, and no operation
+    embed_scale: float = 1.0        # the embedding's rows times this
+    residual_scale: float = 1.0     # a branch times this before it is added
+    logit_scale: float = 1.0        # the final normed stream times this
+    # a "sparse" layer's selection (``ops.sparse_attention.SparseSizes``:
+    # kernel_size, kernel_stride, block_size, topk, window_size,
+    # init_blocks, dense_len)
+    sparse_sizes: tuple = ()
+    # a "lightning" layer's heads are ``gdn_key_heads`` of ``gdn_key_dim``
+    # lanes (values ``gdn_value_dim``); its decay reads the layer's index
+    # among this many layers (0 = the model's own depth)
+    lightning_depth: int = 0
 
     def __post_init__(self):
         kinds = tuple(self.layer_kinds)
@@ -278,6 +322,20 @@ class HybridConfig:
                              "num_heads and an even rope_dim within the "
                              "head")
         object.__setattr__(self, "rope_yarn", tuple(self.rope_yarn))
+        object.__setattr__(self, "sparse_sizes", tuple(self.sparse_sizes))
+        if "sparse" in kinds:
+            if len(self.sparse_sizes) != 7:
+                raise ValueError("sparse layers need the seven "
+                                 "sparse_sizes")
+            if self.has_selection:
+                sparse.SparseSizes(*self.sparse_sizes).check(self.seq_len)
+        if "lightning" in kinds and not (
+                self.gdn_key_heads and self.gdn_key_dim
+                and self.gdn_key_dim % 2 == 0 and self.gdn_value_dim
+                and self.gdn_value_heads == self.gdn_key_heads):
+            raise ValueError("lightning layers need as many gdn_key_heads "
+                             "as gdn_value_heads, an even gdn_key_dim and "
+                             "gdn_value_dim")
         if "swa" in kinds and (self.window_heads % self.num_kv_heads
                                or self.window_rope_dim % 2
                                or self.window_rope_dim > self.head_dim):
@@ -322,6 +380,11 @@ class HybridConfig:
                   "window_rope_theta": card.window_rope_theta,
                   "window_rope_dim": card.window_rope_dim,
                   "rope_yarn": card.rope_yarn,
+                  "embed_scale": card.embed_scale,
+                  "residual_scale": card.residual_scale,
+                  "logit_scale": card.logit_scale,
+                  "sparse_sizes": card.sparse_attention,
+                  "lightning_depth": card.published_layers,
                   "gdn_key_heads": card.linear_key_heads,
                   "gdn_value_heads": card.linear_value_heads,
                   "gdn_key_dim": card.linear_key_dim,
@@ -425,6 +488,18 @@ class HybridConfig:
     @property
     def has_experts(self) -> bool:
         return "moe" in self.ffn_kinds
+
+    @property
+    def has_selection(self) -> bool:
+        """A ``sparse`` layer selects: the sequence is longer than its
+        ``dense_len``."""
+        return ("sparse" in self.layer_kinds
+                and self.seq_len > self.sparse_sizes[6])
+
+    @property
+    def returns_aux(self) -> bool:
+        """The step returns ``ROUTING`` / ``SELECTION`` beside its loss."""
+        return self.has_experts or self.has_selection
 
     def group_sizes(self) -> dict:
         out = {g: 0 for g, _ in _READS.values()}
@@ -553,6 +628,18 @@ def param_shapes(cfg: HybridConfig) -> dict:
         if cfg.head_norm:
             out.update({f"{g}/q_norm": ((m, dh), unit),
                         f"{g}/k_norm": ((m, dh), unit)})
+    if (m := sizes["lightning"]):
+        h = cfg.gdn_key_heads
+        qk, vz = h * cfg.gdn_key_dim, h * cfg.gdn_value_dim
+        out.update({
+            "lightning/wq": ((m, d, qk), s_d),
+            "lightning/wk": ((m, d, qk), s_d),
+            "lightning/wv": ((m, d, vz), s_d),
+            "lightning/wz": ((m, d, vz), s_d),
+            "lightning/q_norm": ((m, cfg.gdn_key_dim), unit),
+            "lightning/k_norm": ((m, cfg.gdn_key_dim), unit),
+            "lightning/o_norm": ((m, cfg.gdn_value_dim), unit),
+            "lightning/wo": ((m, vz, d), 1.0 / math.sqrt(vz))})
     if (m := sizes["conv"]):
         out.update({
             "conv/w_in": ((m, d, 3 * d), s_d),
@@ -835,15 +922,10 @@ def gdn_mixer(cfg: HybridConfig, y, p):
     return jnp.dot((o * _silu(z)).reshape(b, s, nv), p["w_out"])
 
 
-def gated_mixer(cfg: HybridConfig, y, p, kind: str = "gated"):
-    """Softmax attention with grouped keys and values; a norm a head on
-    queries and keys and an output gate where the card states them.
-    The layer's ``kind`` gives its mask, its positions and its query
-    heads (``cfg.heads_of``): ``gated`` and ``nope`` see every earlier
-    key, ``swa`` the last ``attention_window`` (the block-sparse
-    kernels at ``_splash_block``'s blocks); RoPE turns the first lanes
-    of ``gated`` and ``swa`` heads as ``cfg.rope_of`` says and none of
-    ``nope``'s."""
+def _gated_qkv(cfg: HybridConfig, y, p, kind: str):
+    """A ``gated_mixer`` layer's projections: (the query projection's
+    output [B, S, H, dh or 2 dh] with the wide gate's lanes, q, k, v),
+    queries and keys normed a head where the card says so."""
     b, s, _ = y.shape
     h, hkv, dh = cfg.heads_of(kind), cfg.num_kv_heads, cfg.head_dim
     wide = cfg.attn_gate is True
@@ -856,9 +938,38 @@ def gated_mixer(cfg: HybridConfig, y, p, kind: str = "gated"):
                       cfg.norm_eps).astype(y.dtype)
         k = L.rmsnorm(k, _norm_scale(cfg, p["k_norm"]),
                       cfg.norm_eps).astype(y.dtype)
+    return qg, q, k, v
+
+
+def _gated_out(cfg: HybridConfig, y, p, qg, o):
+    """The heads' output ``o`` [B, S, H, dh] under the layer's gate,
+    through the output projection."""
+    b, s, h, dh = o.shape
+    if cfg.attn_gate is True:
+        o = o * jax.nn.sigmoid(qg[..., dh:].astype(_F32)).astype(y.dtype)
+    elif cfg.attn_gate:
+        with scope("attn.gate"):
+            g = jax.nn.sigmoid(jnp.dot(y, p["wg"],
+                                       preferred_element_type=_F32))
+            o = o * g[..., None].astype(y.dtype)
+    return jnp.dot(o.reshape(b, s, h * dh), p["wo"])
+
+
+def gated_mixer(cfg: HybridConfig, y, p, kind: str = "gated"):
+    """Softmax attention with grouped keys and values; a norm a head on
+    queries and keys and an output gate where the card states them.
+    The layer's ``kind`` gives its mask, its positions and its query
+    heads (``cfg.heads_of``): ``gated``, ``nope`` and ``sparse`` (at a
+    sequence that does not select: ``sparse_mixer``) see every earlier
+    key, ``swa`` the last ``attention_window`` (the block-sparse
+    kernels at ``_splash_block``'s blocks); RoPE turns the first lanes
+    of ``gated`` and ``swa`` heads as ``cfg.rope_of`` says and none of
+    ``nope``'s or ``sparse``'s."""
+    s = y.shape[1]
+    qg, q, k, v = _gated_qkv(cfg, y, p, kind)
     inner = cfg.attn_scope(kind)
     with scope(inner) if inner else contextlib.nullcontext():
-        if kind != "nope":
+        if kind not in ("nope", "sparse"):
             theta, dr, yarn = cfg.rope_of(kind)
             q_rope, k_rope = L.rope(q[..., :dr], k[..., :dr], jnp.arange(s),
                                     theta, yarn)
@@ -870,14 +981,60 @@ def gated_mixer(cfg: HybridConfig, y, p, kind: str = "gated"):
             block = _splash_block(cfg, s)
         o = ops.attention(q, k, v, causal=True, impl=cfg.attention_impl,
                           mask=mask, block_q=block, block_k=block)
-    if wide:
-        o = o * jax.nn.sigmoid(qg[..., dh:].astype(_F32)).astype(y.dtype)
-    elif cfg.attn_gate:
-        with scope("attn.gate"):
-            g = jax.nn.sigmoid(jnp.dot(y, p["wg"],
-                                       preferred_element_type=_F32))
-            o = o * g[..., None].astype(y.dtype)
-    return jnp.dot(o.reshape(b, s, h * dh), p["wo"])
+    return _gated_out(cfg, y, p, qg, o)
+
+
+def sparse_mixer(cfg: HybridConfig, y, p):
+    """(out, the layer's ``SELECTION`` or None).  A sequence longer than
+    ``dense_len``: each token's blocks from its own scores
+    (``attn.select``; integers, no gradient), then attention over them
+    (``attn.sparse``).  At or under it ``gated_mixer``'s every earlier
+    key: nothing is selected."""
+    if not cfg.has_selection:
+        return gated_mixer(cfg, y, p, "sparse"), None
+    sizes = sparse.SparseSizes(*cfg.sparse_sizes)
+    qg, q, k, v = _gated_qkv(cfg, y, p, "sparse")
+    with scope("attn.select"):
+        blocks = sparse.select_blocks(q, k, sizes)
+    with scope("attn.sparse"):
+        visits = sparse.plan_visits(blocks, sizes.block_size, q.dtype)
+        o = sparse.block_sparse_attention(q, k, v, visits)
+    return _gated_out(cfg, y, p, qg, o), {
+        "blocks": blocks, **sparse.counters(blocks, visits)}
+
+
+def lightning_mixer(cfg: HybridConfig, y, p, li: int):
+    """Lightning attention of layer ``li``: a norm a head on queries and
+    keys, RoPE on every lane, the rule with the layer's constant decay
+    (float32), a norm over each head's output, a sigmoid gate from a
+    projection of its own."""
+    b, s, _ = y.shape
+    h, dk, dv = cfg.gdn_key_heads, cfg.gdn_key_dim, cfg.gdn_value_dim
+    q = jnp.dot(y, p["wq"]).reshape(b, s, h, dk)
+    k = jnp.dot(y, p["wk"]).reshape(b, s, h, dk)
+    v = jnp.dot(y, p["wv"]).reshape(b, s, h, dv)
+    q = L.rmsnorm(q, _norm_scale(cfg, p["q_norm"]),
+                  cfg.norm_eps).astype(y.dtype)
+    k = L.rmsnorm(k, _norm_scale(cfg, p["k_norm"]),
+                  cfg.norm_eps).astype(y.dtype)
+    q, k = L.rope(q, k, jnp.arange(s), cfg.rope_theta)
+    decay = head_log_decay(h, li, cfg.lightning_depth or cfg.num_layers)
+    with scope("linattn.rule"):
+        o = lightning_attention(q, k, v, decay, 1.0 / math.sqrt(dk),
+                                cfg.rule_impl)
+    o = L.rmsnorm(o, _norm_scale(cfg, p["o_norm"]),
+                  cfg.norm_eps).astype(y.dtype)
+    z = jnp.dot(y, p["wz"]).reshape(b, s, h, dv)
+    o = o * jax.nn.sigmoid(z.astype(_F32)).astype(y.dtype)
+    return jnp.dot(o.reshape(b, s, h * dv), p["wo"])
+
+
+def _add(cfg: HybridConfig, x, out):
+    """The residual stream after a branch: ``x + c out``, ``c`` the
+    configuration's ``residual_scale`` (1.0: the plain sum)."""
+    if cfg.residual_scale == 1.0:
+        return x + out
+    return x + (out.astype(_F32) * cfg.residual_scale).astype(x.dtype)
 
 
 def expert_ffn(cfg: HybridConfig, x, norm, fp, router_in=None):
@@ -907,44 +1064,54 @@ def expert_ffn(cfg: HybridConfig, x, norm, fp, router_in=None):
                 )[..., None].astype(y.dtype)
             out = out + shared
     with scope("moe.combine"):
-        return x + out, routing
+        return _add(cfg, x, out), routing
 
 
 def _layer(cfg: HybridConfig, li: int, x, bp, mp, fp, memory, kv):
     """Layer ``li``: ``bp`` its norms, ``mp`` its mixer's weights,
-    ``fp`` its FFN's.  Returns (x, handed, routing): ``handed`` is the
+    ``fp`` its FFN's.  Returns (x, handed, aux): ``handed`` is the
     memory (the memory layer), (k1, k2, V) (the full layer) or None;
-    ``routing`` an expert layer's, else None."""
+    ``aux`` an expert layer's ``ROUTING`` and a selecting sparse
+    layer's ``SELECTION`` in one dict, else None."""
     kind = cfg.layer_kinds[li]
-    handed = router_in = None
+    handed = router_in = selection = None
     if kind == "mamba":
         with scope("ssm"):
             out, s = mamba_mixer(cfg, _norm(cfg, x, bp, "norm1"), mp)
-            x = x + out
+            x = _add(cfg, x, out)
         if li == cfg.memory_layer:
             handed = s
     elif kind == "gmu":
         with scope("gmu"):
-            x = x + gmu_mixer(_norm(cfg, x, bp, "norm1"), memory, mp)
+            x = _add(cfg, x, gmu_mixer(_norm(cfg, x, bp, "norm1"), memory,
+                                       mp))
     elif kind == "mla":
         with scope("attn"):
-            x = x + mla_mixer(cfg, _norm(cfg, x, bp, "norm1"), mp)
+            x = _add(cfg, x, mla_mixer(cfg, _norm(cfg, x, bp, "norm1"), mp))
     elif kind in _GATED_KINDS:
         with scope("attn"):
             y = _norm(cfg, x, bp, "norm1")
             # a "gated" layer keeps the call of three arguments that
             # the benchmark's planted fault wraps
             # (benchmarks/runners/train_conv_moe._no_qk_norm)
-            x = x + (gated_mixer(cfg, y, mp) if kind == "gated"
-                     else gated_mixer(cfg, y, mp, kind))
+            if kind == "sparse":
+                out, selection = sparse_mixer(cfg, y, mp)
+            else:
+                out = (gated_mixer(cfg, y, mp) if kind == "gated"
+                       else gated_mixer(cfg, y, mp, kind))
+            x = _add(cfg, x, out)
         if cfg.early_router:
             router_in = y
     elif kind == "gdn":
         with scope("linattn"):
-            x = x + gdn_mixer(cfg, _norm(cfg, x, bp, "norm1"), mp)
+            x = _add(cfg, x, gdn_mixer(cfg, _norm(cfg, x, bp, "norm1"), mp))
+    elif kind == "lightning":
+        with scope("linattn"):
+            x = _add(cfg, x, lightning_mixer(
+                cfg, _norm(cfg, x, bp, "norm1"), mp, li))
     elif kind == "conv":
         with scope("conv"):
-            x = x + conv_mixer(_norm(cfg, x, bp, "norm1"), mp)
+            x = _add(cfg, x, conv_mixer(_norm(cfg, x, bp, "norm1"), mp))
     else:
         with scope("attn"):
             y = _norm(cfg, x, bp, "norm1")
@@ -952,14 +1119,15 @@ def _layer(cfg: HybridConfig, li: int, x, bp, mp, fp, memory, kv):
                 kv = project_kv(cfg, y, mp)
             if kind == "full":
                 handed = kv
-            x = x + diff_attention(cfg, y, mp, kv, li, kind == "window")
+            x = _add(cfg, x, diff_attention(cfg, y, mp, kv, li,
+                                            kind == "window"))
     if cfg.ffn_kinds[li] == "moe":
         x, routing = expert_ffn(cfg, x, bp, fp, router_in)
-        return x, handed, routing
+        return x, handed, {**routing, **(selection or {})}
     with scope("mlp"):
         y = _norm(cfg, x, bp, "norm2")
-        x = x + L.swiglu(y, fp["w_gate"], fp["w_up"], fp["w_down"])
-    return x, handed, None
+        x = _add(cfg, x, L.swiglu(y, fp["w_gate"], fp["w_up"], fp["w_down"]))
+    return x, handed, selection
 
 
 _MLP = ("w_gate", "w_up", "w_down")
@@ -967,13 +1135,16 @@ _MLP = ("w_gate", "w_up", "w_down")
 
 def _keeping(li: int, kind: str):
     """The checkpoint policy of layer ``li``: an attention kernel's
-    output and lse (``KEPT_NAMES``) are saved for the backward and
-    nothing else is, so the layer's recomputation has no use for the
-    kernel's forward call and drops it; a layer without such a kernel
-    saves nothing.  Each value it saves is a fact of the traced program,
+    output and lse (``KEPT_NAMES``) and a sparse layer's selection
+    with what the kernels read of it (``sparse.BLOCKS_NAME``) are saved
+    for the backward and nothing else is, so the layer's recomputation
+    has no use for the kernel's forward call, nor for the selection and
+    its plan of visits, and drops them; a layer
+    without such a kernel saves nothing.  Each value it saves is a fact of the traced program,
     marked ``remat.kept`` (``spans.mark``: on the build's ``compile``
     span under a tracer, nothing without one)."""
-    named = jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)
+    named = jax.checkpoint_policies.save_only_these_names(
+        *KEPT_NAMES, sparse.BLOCKS_NAME)
 
     def policy(prim, *avals, **params):
         keep = named(prim, *avals, **params)
@@ -988,12 +1159,15 @@ def _keeping(li: int, kind: str):
 def _forward(params: dict, tokens, cfg: HybridConfig):
     """tokens [B, S] -> (the last layer's output [B, S, D] before the
     final norm, which ``loss_and_routing`` goes on from; the expert
-    layers' ``ROUTING``, ``choices`` stacked [expert layers, T, k], or
-    {} without expert layers)."""
+    layers' ``ROUTING``, ``choices`` stacked [expert layers, T, k], and
+    the selecting sparse layers' ``SELECTION``, ``blocks`` stacked
+    [sparse layers, B, S, Hkv, topk], or {} without either)."""
     with scope("embed"):
         x = params["embed"][tokens]
+        if cfg.embed_scale != 1.0:
+            x = (x.astype(_F32) * cfg.embed_scale).astype(x.dtype)
     memory = kv = None
-    routed = []
+    auxes = []
     for li, kind in enumerate(cfg.layer_kinds):
         layer = _layer
         if cfg.remat:
@@ -1007,19 +1181,27 @@ def _forward(params: dict, tokens, cfg: HybridConfig):
             fp = jax.tree.map(lambda a: a[fi], params["moe"])
         else:
             fp = {k: block[k][fi] for k in _MLP}
-        x, handed, routing = layer(cfg, li, x, bp, mp, fp, memory, kv)
+        x, handed, aux = layer(cfg, li, x, bp, mp, fp, memory, kv)
         if kind == "mamba" and handed is not None:
             memory = handed
         elif kind == "full":
             kv = handed
-        if routing is not None:
-            routed.append(routing)
-    if not routed:
-        return x, {}
-    stacked = {k: jnp.stack([r[k] for r in routed]) for k in ROUTING}
-    return x, {**stacked, "routed": jnp.sum(stacked["routed"]),
+        if aux:
+            auxes.append(aux)
+    out = {}
+    routed = [a for a in auxes if "choices" in a]
+    if routed:
+        stacked = {k: jnp.stack([r[k] for r in routed]) for k in ROUTING}
+        out = {**stacked, "routed": jnp.sum(stacked["routed"]),
                "max_load": jnp.max(stacked["max_load"]),
                "past_bound": jnp.sum(stacked["past_bound"])}
+    selected = [a for a in auxes if "blocks" in a]
+    if selected:
+        out.update(
+            blocks=jnp.stack([a["blocks"] for a in selected]),
+            selected=sum(a["selected"] for a in selected),
+            visited=sum(a["visited"] for a in selected))
+    return x, out
 
 
 def forward(params: dict, tokens, cfg: HybridConfig):
@@ -1030,7 +1212,7 @@ def forward(params: dict, tokens, cfg: HybridConfig):
 
 def loss_and_routing(params: dict, tokens, cfg: HybridConfig):
     """(next-token cross-entropy on a [B, S+1] token batch, the expert
-    layers' ``ROUTING``).  Where the rows are a multiple of
+    layers' ``ROUTING`` and the sparse layers' ``SELECTION``).  Where the rows are a multiple of
     ``cfg.loss_row_block`` the head and the loss run block by block and
     each block leaves its gradients behind
     (``layers.blocked_head_cross_entropy``); the final norm is taken
@@ -1041,6 +1223,8 @@ def loss_and_routing(params: dict, tokens, cfg: HybridConfig):
     table = params["embed" if cfg.tied_head else "head"]
     with scope("head_loss"):
         x = _norm(cfg, x, params, "final_norm")
+        if cfg.logit_scale != 1.0:
+            x = (x.astype(_F32) * cfg.logit_scale).astype(x.dtype)
         rows, block = x.shape[0] * x.shape[1], cfg.loss_row_block
         if not block or rows <= block or rows % block:
             return L.cross_entropy(jnp.dot(x, table.T), targets), routing

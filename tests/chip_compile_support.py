@@ -118,6 +118,9 @@ CELL_BUILD = {
                                         {"attention_impl": "flash"}, {}),
     "laguna_s21_train_s16k": ("weights_headgate_moe", "train_headgate_moe",
                               {"attention_impl": "flash"}, {}),
+    "minicpm_sala_train_s16k": (
+        "weights_sparse_linear", "train_sparse_linear",
+        {"attention_impl": "flash", "rule_impl": "pallas"}, {}),
 }
 
 

@@ -118,7 +118,7 @@ _KEYED_BY_KIND = ("test_scope_dump_reads_through_the_harness",
 _NOT_IN_THE_TABLE = ("kimivl_a3b_train_s8k", "qwen3next_a3b_train_s16k",
                      "lfm2_8b_a1b_train_s8k",
                      "smallthinker_21b_a3b_train_s16k",
-                     "laguna_s21_train_s16k")
+                     "laguna_s21_train_s16k", "minicpm_sala_train_s16k")
 
 
 # One case of tests/benchmarks/test_bench_qwen3next.py holds its cell to

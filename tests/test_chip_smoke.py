@@ -43,7 +43,7 @@ def _lines(capsys) -> list[dict]:
 # longest (227 s of a worker under ``--dist loadfile``)
 MAIN_PATHS = ("kernels", "train", "proxy", "serve", "moe")
 HYBRIDS = ("hybrid", "latent_moe", "linear_moe", "conv_moe", "swa_moe",
-           "headgate_moe")
+           "headgate_moe", "sparse_linear_ops")
 
 
 def check_tiny_phases(smoke_out, capsys, argv, phases):

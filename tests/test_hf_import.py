@@ -432,3 +432,78 @@ def test_headgate_moe_import_refuses_what_no_layer_computes(over, match):
     expert's input."""
     with pytest.raises(ValueError, match=match):
         hf_import.card_from_hf_config("x", {**_laguna_row(), **over})
+
+
+def _minicpm_sala_row():
+    """The keys of the catalog's row for MiniCPM-SALA (``config.json`` as
+    published)."""
+    sparse_at = {0, 9, 16, 17, 22, 29, 30, 31}
+    return {
+        "attention_bias": False, "attn_use_rope": False, "head_dim": 128,
+        "hidden_act": "silu", "hidden_size": 4096,
+        "intermediate_size": 16384, "lightning_head_dim": 128,
+        "lightning_nh": 32, "lightning_nkv": 32,
+        "lightning_scale": "1/sqrt(d)", "lightning_use_rope": True,
+        "max_position_embeddings": 524288, "model_type": "minicpm_sala",
+        "mixer_types": ["minicpm4" if li in sparse_at else "lightning-attn"
+                        for li in range(32)],
+        "num_attention_heads": 32, "num_hidden_layers": 32,
+        "num_key_value_heads": 2, "qk_norm": True, "rand_init": False,
+        "rms_norm_eps": 1e-06, "vocab_size": 73448, "rope_theta": 10000,
+        "scale_emb": 12, "scale_depth": 1.4, "mup_denominator": 32,
+        "dim_model_base": 256, "tie_word_embeddings": False,
+        "use_output_gate": True, "use_output_norm": True,
+        "attn_use_output_gate": True}
+
+
+def test_sparse_linear_card_states_the_mixers_the_scalars_and_the_sizes():
+    import math
+    card = hf_import.card_from_hf_config("minicpm_sala", _minicpm_sala_row())
+    assert card == load_model_card("minicpm_sala")
+    kinds = card.layer_kinds
+    assert len(kinds) == 32
+    assert [i for i, k in enumerate(kinds) if k == "sparse"] \
+        == [0, 9, 16, 17, 22, 29, 30, 31]
+    assert set(kinds) == {"sparse", "lightning"}
+    assert (card.embed_dim, card.num_heads, card.kv_heads,
+            card.attn_head_dim, card.ff_dim, card.vocab_size) \
+        == (4096, 32, 2, 128, 16384, 73448)
+    assert (card.linear_key_heads, card.linear_value_heads,
+            card.linear_key_dim, card.linear_value_dim) == (32, 32, 128, 128)
+    assert card.embed_scale == 12.0 and card.logit_scale == 256 / 4096
+    assert card.residual_scale == pytest.approx(1.4 / math.sqrt(32))
+    assert card.published_layers == 32
+    # MiniCPM4's sparse_config where the row gives none; the row's own
+    # where it does
+    assert card.sparse_attention == (32, 16, 64, 64, 2048, 1, 8192)
+    own = hf_import.card_from_hf_config("x", {
+        **_minicpm_sala_row(), "sparse_config": {"topk": 96,
+                                                 "dense_len": 4096}})
+    assert own.sparse_attention == (32, 16, 64, 96, 2048, 1, 4096)
+    assert (card.attn_output_gate, card.attn_head_norm, card.rms_norm,
+            card.tied_embeddings, card.norm_eps) \
+        == (True, True, True, False, 1e-6)
+    assert card.num_params() == pytest.approx(9.48e9, rel=5e-3)
+    assert hf_import.card_to_json(card)["sparse_attention"] \
+        == [32, 16, 64, 64, 2048, 1, 8192]
+
+
+@pytest.mark.parametrize("over,match", [
+    ({"mixer_types": ["minicpm4"] * 31}, "31 entries of mixer_types"),
+    ({"mixer_types": ["minicpm4"] * 5 + ["mamba2"] + ["minicpm4"] * 26},
+     "layer 5 is a 'mamba2' mixer"),
+    ({"lightning_nkv": 8}, "lightning_nkv"),
+    ({"lightning_scale": "1/d"}, "lightning_scale"),
+    ({"attn_use_rope": True}, "attn_use_rope"),
+    ({"lightning_use_rope": False}, "lightning_use_rope"),
+    ({"qk_norm": False}, "qk_norm"),
+    ({"use_output_norm": False}, "use_output_norm"),
+    ({"attn_use_output_gate": False}, "attn_use_output_gate"),
+    ({"hidden_act": "gelu"}, "hidden_act"),
+    ({"attention_bias": True}, "attention_bias")])
+def test_sparse_linear_import_refuses_what_no_layer_computes(over, match):
+    """An unknown mixer is refused by its layer's number; so are a list
+    of another length, grouped lightning keys, another scale, RoPE on a
+    sparse layer and a layer without its norms or gates."""
+    with pytest.raises(ValueError, match=match):
+        hf_import.card_from_hf_config("x", {**_minicpm_sala_row(), **over})

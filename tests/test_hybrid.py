@@ -234,7 +234,7 @@ def test_card_states_the_pattern_and_config_follows_it():
     assert (cfg.head_dim, cfg.dt_rank, cfg.ssm_inner) == (64, 160, 5120)
     assert cfg.group_sizes() == {"mamba": 9, "attn": 9, "gmu": 7,
                                  "cross": 7, "mla": 0, "gdn": 0,
-                                 "gated": 0, "conv": 0}
+                                 "gated": 0, "conv": 0, "lightning": 0}
     with pytest.raises(ValueError, match="a mamba layer before"):
         hybrid.HybridConfig.from_card(card, layer_kinds=("gmu", "mamba"))
     with pytest.raises(ValueError, match="exactly one full"):

@@ -403,7 +403,7 @@ def test_the_loop_rule_moves_only_what_had_no_scope(runner, cls, name):
 def test_scope_refuses_a_name_outside_the_vocabulary():
     with pytest.raises(ValueError, match="spans.SCOPES"):
         spans.scope("attention")
-    assert len(set(spans.SCOPES)) == len(spans.SCOPES) == 20
+    assert len(set(spans.SCOPES)) == len(spans.SCOPES) == 22
     assert spans.OTHER_SCOPE not in spans.SCOPES
 
 
