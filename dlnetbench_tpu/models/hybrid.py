@@ -115,8 +115,11 @@ experts), layers are unrolled as the bench recipe unrolls them, and
 its input, all but its attention kernel's forward call, whose output and
 lse the checkpoint keeps (``_keeping``: ``[B, S, Hq, Dv]`` in the
 model's dtype and ``[B, Hq, 8, S]`` float32 a call, where a head of
-the output is one lane tile, ``ops.flash_attention._kept``; a layer
-without such a kernel keeps nothing).
+the output is one lane tile, ``ops.flash_attention._kept``), and all but
+a ``gdn`` layer's rule, whose output, chunk states and chunk matrices it
+keeps (``ops.gated_delta_rule.KEPT_NAMES``: ``[B, S, H, dv]`` and
+``[B, H, S / C, dk, dv]`` in the model's dtype, ``[B, H, S / C, C, C]``
+float32); a layer without such a kernel keeps nothing.
 
 Differential attention runs over the kernels ``ops.attention`` already
 has: heads are paired by adjacent index, ``(q1, k1)`` and ``(q2, k2)``
@@ -143,7 +146,8 @@ from dlnetbench_tpu.models.moe import moe_held
 from dlnetbench_tpu.ops import sparse_attention as sparse
 from dlnetbench_tpu.ops.attention_mask import MaskSpec
 from dlnetbench_tpu.ops.flash_attention import KEPT_NAMES
-from dlnetbench_tpu.ops.gated_delta_rule import gated_delta_rule
+from dlnetbench_tpu.ops.gated_delta_rule import (
+    KEPT_NAMES as RULE_KEPT_NAMES, gated_delta_rule)
 from dlnetbench_tpu.ops.lightning_attention import (head_log_decay,
                                                     lightning_attention)
 from dlnetbench_tpu.ops.selective_scan import selective_scan
@@ -1131,20 +1135,22 @@ def _layer(cfg: HybridConfig, li: int, x, bp, mp, fp, memory, kv):
 
 
 _MLP = ("w_gate", "w_up", "w_down")
+# the names a checkpointed layer keeps: an attention kernel's output and
+# lse, the gated delta rule's output, chunk states and chunk matrices,
+# and a sparse layer's selection with what the kernels read of it
+_KEPT = (*KEPT_NAMES, *RULE_KEPT_NAMES, sparse.BLOCKS_NAME)
 
 
 def _keeping(li: int, kind: str):
-    """The checkpoint policy of layer ``li``: an attention kernel's
-    output and lse (``KEPT_NAMES``) and a sparse layer's selection
-    with what the kernels read of it (``sparse.BLOCKS_NAME``) are saved
-    for the backward and nothing else is, so the layer's recomputation
-    has no use for the kernel's forward call, nor for the selection and
-    its plan of visits, and drops them; a layer
-    without such a kernel saves nothing.  Each value it saves is a fact of the traced program,
-    marked ``remat.kept`` (``spans.mark``: on the build's ``compile``
-    span under a tracer, nothing without one)."""
-    named = jax.checkpoint_policies.save_only_these_names(
-        *KEPT_NAMES, sparse.BLOCKS_NAME)
+    """The checkpoint policy of layer ``li``: the values its kernels
+    have named (``_KEPT``) are saved for the backward and nothing else
+    is, so the layer's recomputation has no use for the kernel's forward
+    call, nor for the selection and its plan of visits, and drops them;
+    a layer without such a kernel saves nothing.  Each value it saves is
+    a fact of the traced program, marked ``remat.kept`` (``spans.mark``:
+    on the build's ``compile`` span under a tracer, nothing without
+    one)."""
+    named = jax.checkpoint_policies.save_only_these_names(*_KEPT)
 
     def policy(prim, *avals, **params):
         keep = named(prim, *avals, **params)

@@ -76,6 +76,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -493,6 +494,15 @@ def _xla_bwd(q, k, v, cum, bt, s0, x, do, chunk: int):
 
 # ------------------------------------------------------------ public op
 
+# The names of a forward's three arrays as its backward and its caller
+# read them (``jax.ad_checkpoint.checkpoint_name``): ``o``, the states
+# entering the chunks and the chunks' ``X``.  A ``jax.checkpoint`` whose
+# policy saves these names keeps all three, and the forward sweep is dead
+# in its recomputation; anywhere else a name is the identity (as
+# ``flash_attention.KEPT_NAMES``).
+KEPT_NAMES = ("rule_out", "rule_states", "rule_chunks")
+
+
 def _pad_time(x, pad: int):
     """A padded token has k = 0, beta = 0, g = 0: the state passes
     through it unchanged."""
@@ -534,7 +544,9 @@ def _vjp_fwd(q, k, v, g, beta, impl, chunk):
         o, s0, x = _pallas_fwd(*xs, chunk, hb)
     else:
         o, s0, x = _xla_fwd(*xs, chunk)
-    return o[:, :q.shape[1]], (q, k, v, g, beta, s0, x)
+    o, s0, x = (checkpoint_name(a, name) for a, name in zip(
+        (o[:, :q.shape[1]], s0, x), KEPT_NAMES))
+    return o, (q, k, v, g, beta, s0, x)
 
 
 def _vjp_bwd(impl, chunk, res, do):

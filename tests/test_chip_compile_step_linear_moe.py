@@ -13,7 +13,9 @@ def test_linear_moe_train_step_at_the_cell_shapes_compiles_for_the_chip(
     cell's own files, widths, sequence, bound and compiler options, the
     sweeps that "auto" takes on the chip), cut to one layer of each kind
     so that it compiles in a minute: the kernels by name (the rule's
-    forward kernel twice a linear layer and its backward once, three
+    forward kernel once a linear layer and its backward once: the
+    checkpoint keeps the forward's three arrays,
+    ``ops/gated_delta_rule.KEPT_NAMES``; three
     attention kernels a full layer (the forward twice: at 256 lanes a
     head the checkpoint does not keep the kernel's output yet,
     ``ops/flash_attention._kept``), six grouped matmuls a layer and the
@@ -21,19 +23,27 @@ def test_linear_moe_train_step_at_the_cell_shapes_compiles_for_the_chip(
     loop but the head's (none around the rule: all heads go through one
     call), no chunk matrix but what the rule's kernels write, the state
     donated, and the temporaries under what let 32 held experts keep
-    the 13.0 GB rule (the whole step's count is in the configuration
-    file)."""
+    the 13.0 GB rule.  The whole step's count in the configuration file
+    is PR 32's (2 x 2.341 + 5.912 = 10.59 GB); ISSUE 51 reckoned the
+    three kept layers at 537 MB each on top of it, 2 x 2.341 + 5.912 +
+    1.61 = 12.20 GB; the compiler, asked here for the whole cell (PR 51,
+    a count and no chip run), reads temporaries of 5.312 GB with the
+    rule kept and 5.475 with it recomputed, 2 x 2.341 + 5.312 = 9.99 GB
+    against 10.16: the recomputation's forward held more beside the
+    backward than the three arrays a layer that now live from the
+    forward on (cut to one layer of each kind: 3.38 against 4.71)."""
     from dlnetbench_tpu.core import executor
     step, cell, arch = cell_step("qwen3next_a3b_train_s16k", one_chip)
     assert arch["layer_kinds"] == ("gdn", "gated")
     cfg = cell_program("qwen3next_a3b_train_s16k")[2]
     mem = step.memory_analysis
     assert mem["alias"] > 0.99 * mem["argument"]   # the state is donated
-    assert mem["temp"] <= 5.5e9        # 5.39 GB read, PR 33 (5.52, PR 32)
+    # 3.38 GB read, PR 51 (4.71 with the rule recomputed; 5.39, PR 33)
+    assert mem["temp"] <= 3.6e9
     text = step.as_text()
     names = kernel_instructions(text)
     assert sorted(re_sub_number(k) for k in names) == sorted(
-        ["gdr_fwd"] * 2 + ["gdr_bwd"] + ["flash_fwd"] * 2
+        ["gdr_fwd", "gdr_bwd"] + ["flash_fwd"] * 2
         + ["flash_bwd_dkv"] + ["grouped_mm"] * 12
         + [*EXPERTS_BWD, "grouped_mm_bwd_dw"] * 2)
     table = executor.hlo_op_scopes(text)
