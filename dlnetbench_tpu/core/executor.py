@@ -102,10 +102,11 @@ def enable_persistent_cache() -> str:
 
 
 # --------------------------------------------------------------------
-# From compiled HLO text to {instruction name: scope}: the join between
-# what a device trace prints first in each event's name (``%fusion.54 =
-# ...``) and the ``jax.named_scope`` the model wore where that work was
-# written (``spans.SCOPES``).
+# From compiled HLO text to {instruction name: scope} and {instruction
+# name: phase}: the join between what a device trace prints first in
+# each event's name (``%fusion.54 = ...``) and the ``jax.named_scope``
+# the model wore where that work was written (``spans.SCOPES``), and the
+# pass of the step that runs it (``spans.PHASES``).
 
 _HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
 _HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$")
@@ -118,26 +119,151 @@ _HLO_BRANCHES = re.compile(
     r"|true_computation=(\S+), false_computation=(\S+))")
 _HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
 _HLO_TRANSFORM = re.compile(r"(\w+)\((.*)\)")
+_REMATTED = "rematted_computation"
+
+
+def _path_parts(op_name: str):
+    """The parts of an ``op_name`` path from the innermost out, each as
+    (the transforms around it, what they wrap): ``transpose(jvp(attn))``
+    is ``(["transpose", "jvp"], "attn")``, the scope transformed;
+    ``jit(attn)`` is a function that happens to be called so and wraps
+    nothing.  The path is split at ``/`` alone: where the compiler
+    joined two paths with ``;``, the part that holds the ``;`` is
+    neither's."""
+    for part in reversed(op_name.split("/")):
+        transforms = []
+        while (m := _HLO_TRANSFORM.fullmatch(part)) and m.group(1) != "jit":
+            transforms.append(m.group(1))
+            part = m.group(2)
+        yield transforms, part
 
 
 def scope_of_op_name(op_name: str, vocabulary=spans.SCOPES) -> str | None:
     """The innermost vocabulary scope of an ``op_name`` path, with the
     transforms peeled off (``jit(f)/transpose(jvp(attn))/dot_general``
-    -> ``attn``), so that forward and backward of a layer land together;
-    None where the path holds none."""
-    for part in reversed(op_name.split("/")):
-        # jvp(attn), transpose(jvp(attn)) are the scope transformed;
-        # jit(attn) is a function that happens to be called so
-        while (m := _HLO_TRANSFORM.fullmatch(part)) and m.group(1) != "jit":
-            part = m.group(2)
+    -> ``attn``), so that forward and backward of a layer land together
+    (``phase_of_op_name`` tells them apart); None where the path holds
+    none."""
+    for _, part in _path_parts(op_name):
         if part in vocabulary:
             return part
     return None
 
 
+def phase_of_op_name(op_name: str) -> str | None:
+    """The pass of a train step an ``op_name`` path was traced in, one
+    of ``spans.PHASES``: ``recompute`` where the path holds a
+    ``rematted_computation`` part (``jax.checkpoint``'s forward run
+    again inside the backward pass); else ``backward`` where a part is
+    a ``transpose(...)``; else ``forward`` where a part is a
+    ``jvp(...)``; else None (the optimizer's updates, whatever was not
+    differentiated).  It is what the path says: a ``custom_vjp`` whose
+    forward rule also makes gradients (the fused head) is ``forward``
+    whole."""
+    found = None
+    for transforms, part in _path_parts(op_name):
+        if part == _REMATTED:
+            return "recompute"
+        if "transpose" in transforms:
+            found = "backward"
+        elif "jvp" in transforms and found is None:
+            found = "forward"
+    return found
+
+
 def hlo_module_name(hlo_text: str) -> str:
     m = re.match(r"HloModule\s+([\w.\-]+)", hlo_text)
     return m.group(1) if m else ""
+
+
+@dataclasses.dataclass
+class _HloText:
+    """What a table needs of a compiled module's text, from one pass
+    over it."""
+    op_name: dict[str, str | None]  # instruction -> its op_name, in
+    #                                 the text's order
+    where: dict[str, str | None]    # instruction -> its computation
+    matmuls: set[str]               # the dots and convolutions
+    fusions: dict[str, str]         # fusion instruction -> callee
+    loop_of: dict[str, str]         # body or condition -> its while,
+    #                                 a branch -> its conditional
+    branches: dict[str, list]       # conditional -> its branches
+
+
+def _read_hlo(hlo_text: str) -> _HloText:
+    text = _HloText({}, {}, set(), {}, {}, {})
+    comp = None
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if m is None:
+            c = _HLO_COMPUTATION.match(line)
+            if c is not None:
+                comp = c.group(1)
+            continue
+        inst = m.group(1)
+        head, _, meta = line.partition(", metadata={")
+        name = _HLO_OP_NAME.search(meta)
+        text.op_name[inst] = name.group(1) if name else None
+        text.where[inst] = comp
+        loop = _HLO_LOOP.search(head)
+        if loop:
+            text.loop_of.update(dict.fromkeys(loop.groups(), inst))
+        cond = _HLO_BRANCHES.search(head)
+        if cond:
+            names = re.findall(r"[\w.\-]+", " ".join(filter(None,
+                                                            cond.groups())))
+            text.branches[inst] = names
+            text.loop_of.update(dict.fromkeys(names, inst))
+        op = _HLO_OPCODE.search(head)
+        kind = op.group(1) if op else None
+        if kind == "fusion":
+            callee = _HLO_CALLS.search(head)
+            if callee:
+                text.fusions[inst] = callee.group(1)
+        elif kind is not None:
+            text.matmuls.add(inst)
+    return text
+
+
+def _labelled(text: _HloText, label, default: str) -> dict[str, str]:
+    """{instruction name: ``label(its op_name)``} under the rules
+    ``hlo_op_scopes`` states; ``default`` where they find none."""
+    read: dict[str, str | None] = {}    # op_name -> its label
+    own: dict[str, str | None] = {}     # instruction -> label or None
+    matmul: dict[str, str] = {}         # computation -> its dot's label
+    root: dict[str, str] = {}           # computation -> its root's label
+    for inst, name in text.op_name.items():
+        if name is not None and name not in read:
+            read[name] = label(name)
+        found = own[inst] = read.get(name)
+        comp = text.where[inst]
+        if found and comp is not None:
+            if inst in text.matmuls:
+                matmul.setdefault(comp, found)
+            # text order is a topological order and the root comes last
+            root[comp] = found
+    for inst, callee in text.fusions.items():
+        own[inst] = matmul.get(callee) or root.get(callee) or own[inst]
+    for inst, names in text.branches.items():
+        own[inst] = own[inst] or next(
+            (root[b] for b in names if b in root), None)
+
+    def resolved(inst):
+        """``inst``'s label, or that of the innermost loop around it
+        that has one."""
+        while inst is not None and own[inst] is None:
+            inst = text.loop_of.get(text.where[inst])
+        return own[inst] if inst is not None else None
+    return {k: resolved(k) or default for k in own}
+
+
+def _scopes(text: _HloText, vocabulary) -> dict[str, str]:
+    return _labelled(text, lambda name: scope_of_op_name(name, vocabulary),
+                     spans.OTHER_SCOPE)
+
+
+def _phases(text: _HloText) -> dict[str, str]:
+    return _labelled(text, phase_of_op_name, spans.NO_PHASE)
 
 
 def hlo_op_scopes(hlo_text: str, vocabulary=spans.SCOPES) -> dict[str, str]:
@@ -160,60 +286,26 @@ def hlo_op_scopes(hlo_text: str, vocabulary=spans.SCOPES) -> dict[str, str]:
     that the compiler rebuilt (an operation moved into or out of every
     branch) has lost its ``op_name`` and takes its branches' scope.
     ``spans.OTHER_SCOPE`` where there is none."""
-    own: dict[str, str | None] = {}     # instruction -> scope or None
-    fusions: dict[str, str] = {}        # fusion instruction -> callee
-    matmul: dict[str, str] = {}         # computation -> its dot's scope
-    root: dict[str, str] = {}           # computation -> its root's scope
-    where: dict[str, str] = {}          # instruction -> its computation
-    loop_of: dict[str, str] = {}        # body or condition -> its while,
-    #                                     a branch -> its conditional
-    branches: dict[str, list] = {}      # conditional -> its branches
-    comp = None
-    for line in hlo_text.splitlines():
-        m = _HLO_INSTRUCTION.match(line)
-        if m is None:
-            c = _HLO_COMPUTATION.match(line)
-            if c is not None:
-                comp = c.group(1)
-            continue
-        head, _, meta = line.partition(", metadata={")
-        name = _HLO_OP_NAME.search(meta)
-        scope = scope_of_op_name(name.group(1), vocabulary) if name else None
-        own[m.group(1)] = scope
-        where[m.group(1)] = comp
-        loop = _HLO_LOOP.search(head)
-        if loop:
-            loop_of.update(dict.fromkeys(loop.groups(), m.group(1)))
-        cond = _HLO_BRANCHES.search(head)
-        if cond:
-            names = re.findall(r"[\w.\-]+", " ".join(filter(None,
-                                                            cond.groups())))
-            branches[m.group(1)] = names
-            loop_of.update(dict.fromkeys(names, m.group(1)))
-        op = _HLO_OPCODE.search(head)
-        kind = op.group(1) if op else None
-        if kind == "fusion":
-            callee = _HLO_CALLS.search(head)
-            if callee:
-                fusions[m.group(1)] = callee.group(1)
-        elif kind in ("dot", "convolution") and scope and comp is not None:
-            matmul.setdefault(comp, scope)
-        if scope and comp is not None:
-            # text order is a topological order and the root comes last
-            root[comp] = scope
-    for inst, callee in fusions.items():
-        own[inst] = matmul.get(callee) or root.get(callee) or own[inst]
-    for inst, names in branches.items():
-        own[inst] = own[inst] or next(
-            (root[b] for b in names if b in root), None)
+    return _scopes(_read_hlo(hlo_text), vocabulary)
 
-    def scoped(inst):
-        """``inst``'s scope, or that of the innermost loop around it
-        that has one."""
-        while inst is not None and own[inst] is None:
-            inst = loop_of.get(where[inst])
-        return own[inst] if inst is not None else None
-    return {k: scoped(k) or spans.OTHER_SCOPE for k in own}
+
+def hlo_op_phases(hlo_text: str) -> dict[str, str]:
+    """{instruction name: phase} of every instruction of every
+    computation of a compiled module's text: ``phase_of_op_name`` under
+    the rules ``hlo_op_scopes`` fixes for scopes.  A fusion takes the
+    phase of the ``dot``/``convolution`` it calls, else of its callee's
+    root, else its own (a recomputed product fused into a backward
+    matmul is ``backward``); a custom call keeps its own; what has none
+    inside a loop's body or condition or a conditional's branches takes
+    the loop's.  ``spans.NO_PHASE`` where there is none."""
+    return _phases(_read_hlo(hlo_text))
+
+
+def hlo_op_tables(hlo_text: str) -> tuple[dict[str, str], dict[str, str]]:
+    """``(hlo_op_scopes, hlo_op_phases)`` of a text from one pass over
+    it."""
+    text = _read_hlo(hlo_text)
+    return _scopes(text, spans.SCOPES), _phases(text)
 
 
 # --------------------------------------------------------------------
@@ -263,11 +355,12 @@ def _listen() -> None:
 class _Compiled:
     """What ``CompiledProgram`` and ``CompiledStep`` share: the build
     and its record, XLA's analyses of the executable, its text, and the
-    op->scope table made from that text — computed when asked and kept,
-    never at build when tracing is off."""
+    op->scope and op->phase tables made from that text — computed when
+    asked and kept, never at build when tracing is off."""
 
     _compiled = None
     _op_scopes = None
+    _op_phases = None
     stats: dict
 
     def _build(self, fn: Callable, args, donate: tuple,
@@ -288,8 +381,9 @@ class _Compiled:
         ``cache_retrieval_s`` and ``backend_compile_s`` as jax's own
         events give them, ``code_bytes`` (the executable's generated
         code, None where the backend does not say), ``op_scopes_s``
-        (the executable's text and the op->scope table from it: what
-        tracing itself costs a build, 0.0 with no tracer) and
+        (the executable's text and the op->scope and op->phase tables
+        from it: what tracing itself costs a build, 0.0 with no tracer)
+        and
         ``analyses_s`` (XLA's cost analysis, after the build).  With a
         tracer on, the ``compile`` span wears the phases, the cache's
         verdict and the code's size as attrs: a train run's spans are
@@ -392,16 +486,33 @@ class _Compiled:
         executable and the old table, with no warning.  Clear the cache
         directory (``enable_persistent_cache``) after such an edit."""
         if self._op_scopes is None:
-            self._op_scopes = hlo_op_scopes(self.as_text())
+            self._read_tables(self.as_text())
         return self._op_scopes
 
+    def op_phases(self) -> dict[str, str]:
+        """{instruction name: ``spans.PHASES`` name or "none"} of the
+        compiled program (``hlo_op_phases``), made with ``op_scopes``
+        from one reading of the text.
+
+        Read from the executable's own text, as ``op_scopes`` is: an
+        executable loaded from the persistent cache gives the phases it
+        was compiled with, and an edit that only moves a
+        ``jax.checkpoint`` or what its policy keeps may find the old
+        executable and the old table, with no warning."""
+        if self._op_phases is None:
+            self._read_tables(self.as_text())
+        return self._op_phases
+
+    def _read_tables(self, text: str) -> None:
+        self._op_scopes, self._op_phases = hlo_op_tables(text)
+
     def _register_op_scopes(self) -> None:
-        """Hand the table to the current tracer, keyed by the module's
+        """Hand both tables to the current tracer, keyed by the module's
         name as a device trace prints it."""
         text = self.as_text()
-        self._op_scopes = hlo_op_scopes(text)
-        spans.current().register_op_scopes(hlo_module_name(text),
-                                           self._op_scopes)
+        self._read_tables(text)
+        spans.current().register_op_scopes(
+            hlo_module_name(text), self._op_scopes, self._op_phases)
 
 
 @dataclasses.dataclass

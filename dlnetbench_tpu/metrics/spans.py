@@ -31,8 +31,9 @@ side the same artifact-grade story:
   the device can then be put down to the span that covers it.
 * ``SCOPES`` is the fixed vocabulary of ``jax.named_scope`` names the
   model step wears (``scope(name)``); ``core/executor.py`` maps each
-  compiled instruction back to one of them (``op_scopes``), and a
-  tracer keeps the tables of the steps compiled while it was enabled
+  compiled instruction back to one of them (``op_scopes``) and to the
+  pass that runs it (``op_phases``: one of ``PHASES``), and a tracer
+  keeps the tables of the steps compiled while it was enabled
   (``Tracer.export``).
 
 The tracer is deliberately NOT a per-collective measurement channel —
@@ -57,6 +58,11 @@ SCOPES = ("embed", "attn", "attn.window", "attn.full", "attn.gate",
           "moe.shared", "ssm", "ssm.scan", "gmu", "linattn", "linattn.rule",
           "conv", "conv.gate", "head_loss", "optimizer")
 OTHER_SCOPE = "other"   # an instruction under none of them
+# The passes of a train step an instruction can belong to: the forward
+# pass, a checkpointed layer's forward run again inside the backward
+# pass, and the backward pass itself.
+PHASES = ("forward", "recompute", "backward")
+NO_PHASE = "none"       # an instruction of none of them (the optimizer)
 
 
 def scope(name: str):
@@ -136,6 +142,8 @@ class Tracer:
         # module name -> {instruction name: scope} of the steps compiled
         # while this tracer was current (core/executor.py registers them)
         self.op_scopes: dict[str, dict[str, str]] = {}
+        # module name -> {instruction name: phase} of the same steps
+        self.op_phases: dict[str, dict[str, str]] = {}
         self._lock = threading.Lock()
         # tid -> stack of OPEN spans, readable from other threads: the
         # watchdog's stall handler fires on a Timer thread and must see
@@ -196,17 +204,22 @@ class Tracer:
         now = time.perf_counter()
         self._record(name, now, now, 0, attrs)
 
-    def register_op_scopes(self, module: str, table: dict) -> None:
+    def register_op_scopes(self, module: str, table: dict,
+                           phases: dict | None = None) -> None:
         with self._lock:
             self.op_scopes[module] = table
+            if phases is not None:
+                self.op_phases[module] = phases
 
     def export(self) -> dict:
         """What a run's reader takes at its end, as plain data: the
-        finished spans and the op->scope tables."""
+        finished spans, the op->scope tables and the op->phase tables."""
         with self._lock:
             return {"spans": [dict(s) for s in self.spans],
                     "op_scopes": {m: dict(t)
-                                  for m, t in self.op_scopes.items()}}
+                                  for m, t in self.op_scopes.items()},
+                    "op_phases": {m: dict(t)
+                                  for m, t in self.op_phases.items()}}
 
 
 # Module-level current tracer.  ``None`` means disabled — the common

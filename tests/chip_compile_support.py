@@ -194,6 +194,17 @@ def kernel_instructions(text: str) -> list:
             and (m := executor._HLO_INSTRUCTION.match(line))]
 
 
+def phases_of_kernels(step) -> dict:
+    """{Pallas kernel's name without its number: {phase: how many of
+    its calls}} by the compiled step's own table of phases."""
+    phases = step.op_phases()
+    found: dict = {}
+    for inst in kernel_instructions(step.as_text()):
+        row = found.setdefault(re_sub_number(inst), {})
+        row[phases[inst]] = row.get(phases[inst], 0) + 1
+    return found
+
+
 def scopes_by_opcode(text: str, opcodes: str, keep) -> dict:
     """{opcode: the scopes its instructions lie under}, over the
     instructions of ``text`` whose opcode is one of ``opcodes`` (a

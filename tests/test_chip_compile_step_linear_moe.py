@@ -2,13 +2,20 @@
 compiles for the chip (see ``chip_compile_support.cell_step``)."""
 from __future__ import annotations
 
+import pytest
 from chip_compile_support import (
     EXPERTS_BWD, cell_program, cell_step, chunk_arrays,
-    kernel_instructions, re_sub_number)
+    kernel_instructions, phases_of_kernels, re_sub_number)
+
+
+@pytest.fixture(scope="module")
+def built(one_chip, no_persistent_cache):
+    """One compile for the file's cases."""
+    return cell_step("qwen3next_a3b_train_s16k", one_chip)
 
 
 def test_linear_moe_train_step_at_the_cell_shapes_compiles_for_the_chip(
-        one_chip, no_persistent_cache):
+        built):
     """``qwen3next_a3b_train_s16k``'s step as the runner builds it (the
     cell's own files, widths, sequence, bound and compiler options, the
     sweeps that "auto" takes on the chip), cut to one layer of each kind
@@ -33,7 +40,7 @@ def test_linear_moe_train_step_at_the_cell_shapes_compiles_for_the_chip(
     backward than the three arrays a layer that now live from the
     forward on (cut to one layer of each kind: 3.38 against 4.71)."""
     from dlnetbench_tpu.core import executor
-    step, cell, arch = cell_step("qwen3next_a3b_train_s16k", one_chip)
+    step, cell, arch = built
     assert arch["layer_kinds"] == ("gdn", "gated")
     cfg = cell_program("qwen3next_a3b_train_s16k")[2]
     mem = step.memory_analysis
@@ -53,3 +60,54 @@ def test_linear_moe_train_step_at_the_cell_shapes_compiles_for_the_chip(
     made = chunk_arrays(text, cfg.gdn_value_heads,
                         cell.traffic["seq_len"] // 128)
     assert set().union(*made.values()) <= {"get-tuple-element", "bitcast"}
+
+
+def test_the_kept_rule_runs_forward_once_and_the_unkept_attention_twice(
+        built):
+    """The compiled step's table of phases (``executor.hlo_op_phases``,
+    on the text the chip's compiler wrote): the ``gdn`` layer's
+    checkpoint keeps the rule's three arrays, so its ``gdr_fwd`` is
+    ``forward`` and there is none under ``recompute``; the ``gated``
+    layer's does not keep the attention kernel's output at 256 lanes
+    (ROADMAP S7), so ``flash_fwd`` stands once in each; every backward
+    kernel is ``backward``, and each layer's three expert matmuls run
+    forward and again."""
+    step, _, _ = built
+    assert phases_of_kernels(step) == {
+        "gdr_fwd": {"forward": 1},
+        "gdr_bwd": {"backward": 1},
+        "flash_fwd": {"forward": 1, "recompute": 1},
+        "flash_bwd_dkv": {"backward": 1},
+        "grouped_mm": {"forward": 6, "recompute": 6},
+        "grouped_mm_bwd_dh": {"backward": 2},
+        "grouped_mm_bwd_dx": {"backward": 2},
+        "grouped_mm_bwd_dw": {"backward": 4}}
+
+
+def test_every_phase_and_scope_of_the_step_is_in_its_tables(built):
+    """Both tables from one reading of the text hold the same
+    instructions; the head's loop and what the compiler put inside it
+    are ``forward`` (the fused head makes its gradients in its forward
+    rule), the update has no phase."""
+    from dlnetbench_tpu.core import executor
+    from dlnetbench_tpu.metrics import spans
+    step, _, _ = built
+    scopes, phases = step.op_scopes(), step.op_phases()
+    assert set(scopes) == set(phases)
+    assert set(phases.values()) == {*spans.PHASES, spans.NO_PHASE}
+    loops = [m.group(1) for line in step.as_text().splitlines()
+             if " while(" in line
+             and (m := executor._HLO_INSTRUCTION.match(line))]
+    assert [(scopes[w], phases[w]) for w in loops] \
+        == [("head_loss", "forward")]
+    assert {phases[i] for i in scopes if scopes[i] == "head_loss"} \
+        == {"forward", "backward"}
+    by_scope = {}
+    for inst, scope in scopes.items():
+        by_scope.setdefault(scope, set()).add(phases[inst])
+    for scope in ("linattn", "attn", "moe.router", "moe.experts",
+                  "moe.shared"):
+        assert by_scope[scope] == set(spans.PHASES), scope
+    assert by_scope["linattn.rule"] == {"forward", "backward"}
+    assert spans.NO_PHASE in by_scope["optimizer"]
+    assert "recompute" not in by_scope["optimizer"] | by_scope["embed"]

@@ -2,13 +2,19 @@
 the chip and fits it (see ``chip_compile_support.cell_step``)."""
 from __future__ import annotations
 
+import pytest
 from chip_compile_support import (
     EXPERTS_BWD, cell_program, cell_step, kernel_instructions,
-    re_sub_number)
+    phases_of_kernels, re_sub_number)
 
 
-def test_swa_moe_train_step_at_the_cell_shapes_fits_the_chip(
-        one_chip, no_persistent_cache):
+@pytest.fixture(scope="module")
+def built(one_chip, no_persistent_cache):
+    """One compile for the file's cases."""
+    return cell_step("smallthinker_21b_a3b_train_s16k", one_chip)
+
+
+def test_swa_moe_train_step_at_the_cell_shapes_fits_the_chip(built):
     """``smallthinker_21b_a3b_train_s16k``'s whole step (the cell's own
     files and compiler options, as the runner builds it): the rule of
     the configuration file, twice the arguments plus the temporaries at
@@ -27,7 +33,7 @@ def test_swa_moe_train_step_at_the_cell_shapes_fits_the_chip(
     assert hybrid._splash_block(cfg, cell.traffic["seq_len"]) == 2048
     assert (cfg.num_heads, cfg.num_kv_heads, cfg.attention_window) \
         == (28, 4, 4096)
-    step, cell, arch = cell_step("smallthinker_21b_a3b_train_s16k", one_chip)
+    step, cell, arch = built
     mem = step.memory_analysis
     assert 2 * mem["argument"] + mem["temp"] <= 14.0e9
     assert mem["alias"] > 0.99 * mem["argument"]   # the state is donated
@@ -48,3 +54,37 @@ def test_swa_moe_train_step_at_the_cell_shapes_fits_the_chip(
         == {"attn.window": 6 * 2, "attn.full": 2 * 2}
     assert {"attn", "moe.router", "moe.dispatch", "moe.experts",
             "moe.combine", "head_loss"} <= set(table.values())
+
+
+def test_kept_attention_runs_forward_once_and_the_experts_twice(built):
+    """The compiled step's table of phases: every layer's checkpoint
+    keeps its attention kernel's output and lse, so all eight
+    ``flash_fwd`` are ``forward`` and none is ``recompute``; every
+    ``flash_bwd_dkv`` is ``backward``; each layer's three expert
+    matmuls run forward and again under ``recompute`` (ROADMAP S7)."""
+    step, _, arch = built
+    layers = arch["num_layers"]
+    assert phases_of_kernels(step) == {
+        "flash_fwd": {"forward": layers},
+        "flash_bwd_dkv": {"backward": layers},
+        "grouped_mm": {"forward": 3 * layers, "recompute": 3 * layers},
+        "grouped_mm_bwd_dh": {"backward": layers},
+        "grouped_mm_bwd_dx": {"backward": layers},
+        "grouped_mm_bwd_dw": {"backward": 2 * layers}}
+
+
+def test_the_steps_scopes_by_phase(built):
+    from dlnetbench_tpu.metrics import spans
+    step, _, _ = built
+    scopes, phases = step.op_scopes(), step.op_phases()
+    assert set(scopes) == set(phases)
+    by_scope = {}
+    for inst, scope in scopes.items():
+        by_scope.setdefault(scope, set()).add(phases[inst])
+    # a window layer's RoPE stands between its projections and the
+    # kernel and is run again with them; the kernel is not (above)
+    for scope in ("attn", "attn.window", "moe.router", "moe.experts"):
+        assert by_scope[scope] == set(spans.PHASES), scope
+    assert {"forward", "backward"} <= by_scope["attn.full"]
+    assert by_scope["head_loss"] == {"forward", "backward"}
+    assert spans.NO_PHASE in by_scope["optimizer"]

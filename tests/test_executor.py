@@ -287,8 +287,24 @@ def _close(a_s, b_s):
     return abs(a_s - b_s) <= max(0.05 * b_s, 0.020)
 
 
+@pytest.fixture
+def cache_off():
+    """No persistent cache while the case runs.  conftest's thresholds
+    keep a program this small out of the session's cache, but a case of
+    another file that ran in this process may have zeroed them
+    (``cli.main`` calls ``enable_persistent_cache``), so the premise of
+    ``cache == "off"`` is set here and not left to the order of files."""
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
 @pytest.mark.parametrize("kind", KINDS)
-def test_build_record_with_no_tracer(kind, eight_devices):
+def test_build_record_with_no_tracer(kind, eight_devices, cache_off):
     from dlnetbench_tpu.metrics import spans
     from dlnetbench_tpu.utils.timing import process_age_s
     assert not spans.is_enabled()
@@ -302,7 +318,7 @@ def test_build_record_with_no_tracer(kind, eight_devices):
     assert 0 < rec["began_at_s"] <= process_age_s()
     assert min(rec["trace_s"], rec["lower_s"], rec["executable_s"]) > 0
     assert rec["op_scopes_s"] == 0.0                # no tracer, no table
-    assert rec["cache"] == "off"                    # conftest: cache off
+    assert rec["cache"] == "off"                    # ``cache_off``
     assert rec["cache_retrieval_s"] == 0.0
     assert 0 < rec["backend_compile_s"] <= rec["executable_s"]
     assert rec["code_bytes"] == \
