@@ -20,8 +20,12 @@ window_size, init_blocks, dense_len)``, for token ``t`` and group ``g``:
   (e) the first ``init_blocks`` blocks and the ``window_size / block``
       blocks ending at ``t``'s own score +inf;
   (f) the selection is the ``topk`` best of blocks ``0 ... t // block``,
-      ties to the lower index, all of them where fewer exist, the list
-      padded with ``-1``.
+      best first, ties to the lower index, all of them where fewer
+      exist, the list padded with ``-1``.  A block's place in the list
+      is a count, ``#{j : score[j] > score[b], or equal with j < b}``,
+      and the list's entry ``p`` the block whose place is ``p``
+      (``_best_blocks``): ``lax.top_k``'s answer to the integer, with
+      no sort.
 
 The selection is integers: ``q`` and ``k`` enter it under
 ``stop_gradient``.  The scores run in blocks of query rows
@@ -34,7 +38,13 @@ list serves the group's ``G`` heads.  A key outside a token's own
 selection adds nothing to that token, in the forward or in any of the
 three gradients.
 
-How it runs.  ``plan_visits`` makes, ONCE a call, all that the kernels
+How it runs.  The top is counted because a ``top_k`` of 256 lowers, on
+this chip, to a key/payload sort of every row: 1.56 ms a row block of
+512, 32 of them a step at 16k, 50 of the selection's 53 ms, where the
+two counting passes take 5.  The all-pairs compare ``[rows, Hkv, nblk,
+nblk]`` fuses into its sum and the places' compare ``[rows, Hkv, topk,
+nblk]`` into its max: neither is written to HBM.
+``plan_visits`` makes, ONCE a call, all that the kernels
 read of the lists (``Visits``): a membership matrix ``[B, Hkv, S, S /
 block]`` (0/1 in the inputs' dtype, 8 MB a group at 16k) and, from it,
 the tiles ``(Tq tokens) x (Tk keys)`` that hold any selected (token,
@@ -161,6 +171,23 @@ def _block_scores(q_rows, t_rows, kc, sizes: SparseSizes):
     return jnp.where((blk <= own)[None, :, None, :], score, -jnp.inf)
 
 
+def _best_blocks(score, take: int):
+    """[..., nblk] float32 scores -> int32 [..., take]: the blocks of the
+    ``take`` best scores, best first, ties to the lower index, ``NONE``
+    where the score at that place is -inf.  ``lax.top_k``'s lists without
+    its sort: a block's place is the count of the blocks ahead of it."""
+    nblk = score.shape[-1]
+    iota = jax.lax.broadcasted_iota
+    theirs, ours = score[..., :, None], score[..., None, :]
+    lower = iota(jnp.int32, (nblk, nblk), 0) < iota(jnp.int32, (nblk, nblk), 1)
+    ahead = jnp.where(lower, theirs >= ours, theirs > ours)
+    place = jnp.sum(ahead, -2, dtype=jnp.int32)             # [..., nblk]
+    here = ((place[..., None, :] == iota(jnp.int32, (take, nblk), 0))
+            & (ours > -jnp.inf))
+    return jnp.max(jnp.where(here, iota(jnp.int32, (take, nblk), 1), NONE),
+                   -1)
+
+
 def select_blocks(q, k, sizes: SparseSizes):
     """``int32 [B, S, Hkv, topk]``: each token's and key/value head's
     blocks, best first, ``NONE`` where fewer exist (the module's
@@ -177,9 +204,7 @@ def select_blocks(q, k, sizes: SparseSizes):
     tr = jnp.arange(s).reshape(s // rows, rows)
 
     def one(xs):
-        score = _block_scores(*xs, kc, sizes)
-        top, idx = jax.lax.top_k(score, take)
-        return jnp.where(top > -jnp.inf, idx, NONE).astype(jnp.int32)
+        return _best_blocks(_block_scores(*xs, kc, sizes), take)
     out = jax.lax.map(one, (qr, tr)).swapaxes(0, 1).reshape(b, s, hkv, take)
     if take < sizes.topk:
         out = jnp.pad(out, ((0, 0),) * 3 + ((0, sizes.topk - take),),

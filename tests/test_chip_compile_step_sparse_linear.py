@@ -20,7 +20,8 @@ def test_sparse_linear_train_step_at_the_cell_shapes_fits_the_chip(
     same); ONE sparse forward kernel and the two backward ones for the
     one sparse layer (the layer is recomputed, but its checkpoint keeps
     the kernel's output, its lse and the lists, so neither the forward
-    kernel nor the selection runs again: one top-64 sort); the lightning
+    kernel nor the selection runs again, and the selection ranks its
+    scores by counting: no sort under its scope); the lightning
     rule's forward twice and its backward once a lightning layer; each
     under its scope; the state donated."""
     from dlnetbench_tpu.core import executor
@@ -59,5 +60,8 @@ def test_sparse_linear_train_step_at_the_cell_shapes_fits_the_chip(
                          "sparse_bwd_dkv": "attn.sparse",
                          "lightning_fwd": "linattn.rule",
                          "lightning_bwd": "linattn.rule"}
+    # the sparse layer's sorts are the visit lists' (``plan_visits``)
+    sorts = {s for i, s in table.items() if re_sub_number(i) == "sort"}
+    assert "attn.sparse" in sorts and "attn.select" not in sorts
     assert {"attn", "attn.select", "attn.sparse", "linattn", "linattn.rule",
             "mlp", "head_loss", "embed", "optimizer"} <= set(table.values())

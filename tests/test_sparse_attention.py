@@ -1,6 +1,7 @@
 """``ops/sparse_attention.py``: the selection against the benchmark's
-float32 reference and by hand, the kernels (interpret mode) against dense
-masked softmax on the same lists."""
+float32 reference and by hand, its counted ranking against ``lax.top_k``,
+the kernels (interpret mode) against dense masked softmax on the same
+lists."""
 from __future__ import annotations
 
 import contextlib
@@ -95,6 +96,69 @@ def test_ties_go_to_the_lower_index():
     blocks = np.asarray(sa.select_blocks(q, jnp.ones_like(k), SIZES))
     own = np.arange(S) // SIZES.block_size
     assert (blocks[:, own >= 4][..., 3] == 1).all()
+
+
+def drawn_scores(case: str):
+    """float32 [2, 64, 2, 64] block scores as ``_block_scores`` can make
+    them (sums of shares: no -0.0), row ``t`` a token of block ``t``."""
+    rng = np.random.default_rng(5)
+    x = rng.normal(size=(2, 64, 2, 64)).astype(np.float32)
+    own = np.arange(64)[None, :, None, None]
+    blk = np.arange(64)[None, None, None, :]
+    if case == "ties":          # eight values or so a row of 64
+        x = np.round(x, 0) + 0.0
+    if case == "equal":
+        x = np.full_like(x, 0.25)
+    if case == "forced":        # step (e): two first blocks, a window of 4
+        x = np.where((blk < 2) | (blk > own - 4), np.inf, x)
+    if case in ("short", "forced"):     # the causal cut: t + 1 visible
+        x = np.where(blk <= own, x, -np.inf)
+    return jnp.asarray(x, F32)
+
+
+@pytest.mark.parametrize("take", [16, 64])
+@pytest.mark.parametrize("case", ["random", "ties", "equal", "short",
+                                  "forced"])
+def test_the_counted_ranking_gives_top_ks_lists(case, take):
+    """Best first, ties to the lower index, the +inf blocks first in
+    index order, ``NONE`` where fewer than ``take`` are visible: to the
+    integer what ``lax.top_k`` answers."""
+    score = drawn_scores(case)
+    top, idx = jax.lax.top_k(score, take)
+    want = np.asarray(jnp.where(top > -jnp.inf, idx, sa.NONE))
+    got = jax.jit(sa._best_blocks, static_argnums=1)(score, take)
+    assert got.dtype == jnp.int32
+    assert np.array_equal(np.asarray(got), want)
+    if case in ("short", "forced"):
+        assert (want[:, :take - 1, :, -1] == sa.NONE).all()
+    if case == "ties":
+        assert (np.diff(np.sort(np.asarray(score), -1), axis=-1)
+                == 0).mean() > 0.8
+
+
+def primitives(jaxpr):
+    """The names of a jaxpr's equations, those of its sub-jaxprs (a
+    ``scan``'s body, a ``pjit``'s) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from primitives(sub)
+
+
+def test_the_selection_sorts_nothing():
+    """A ``top_k`` of 256 is a full sort of every row on the chip: the
+    selection's program holds neither (``plan_visits``'s ``argsort`` of
+    a row of tiles is another matter, and is there)."""
+    q, k, _, _ = draws()
+    with sized(_SELECT_ROWS=64):
+        names = set(primitives(jax.make_jaxpr(
+            lambda q, k: sa.select_blocks(q, k, SIZES))(q, k).jaxpr))
+    assert "scan" in names and "reduce_max" in names    # the walk went in
+    assert not names & {"sort", "top_k", "approx_top_k"}
+    visits = set(primitives(jax.make_jaxpr(
+        lambda b: sa.plan_visits(b, SIZES.block_size, F32))(
+            jnp.zeros((B, S, HKV, SIZES.topk), jnp.int32)).jaxpr))
+    assert "sort" in visits
 
 
 def test_the_selection_takes_no_gradient_and_is_made_in_row_blocks(chosen):

@@ -102,26 +102,37 @@ def test_loss_selection_and_every_gradient_leaf_against_the_reference(
     assert int(picked["selected"]) <= int(picked["visited"])
 
 
+def selections(jaxpr: str) -> int:
+    """How often a program ranks block scores: a ranking ends in the
+    step's only int32 ``reduce_max`` (``sparse_attention._best_blocks``:
+    the block at each place of the list)."""
+    return len(re.findall(r"i32\[[\d,]*\] = reduce_max", jaxpr))
+
+
 def test_a_checkpoint_keeps_the_lists_and_selects_once_a_step(arch):
-    """The step's program sorts for the top blocks once a sparse layer:
+    """The step's program ranks its block scores once a sparse layer:
     the backward's recomputation reads the kept lists (and the kept
     output and lse: one forward kernel)."""
     cfg = config(arch, remat=True)
     p, t = weights.make_params(arch, 7), tokens(arch)
     step = jax.jit(bench_step.make_train_k(cfg, 1, 0.1))
     jaxpr = str(jax.make_jaxpr(step)(p, t))
-    assert len(re.findall(r"\btop_k\b", jaxpr)) == 1
+    assert selections(jaxpr) == 1
     # nor is the plan of visits made again: its two sorts (the visited
     # tiles by row tile and by key tile), once
     assert jaxpr.count("jit[name=argsort") == 2
     assert jaxpr.count("name=sparse_fwd") == 1
     assert jaxpr.count("name=sparse_bwd_dq") == 1
     assert jaxpr.count("name=sparse_bwd_dkv") == 1
-    # without the policy's name the recomputation would select again
+    # without the policy's names the recomputation selects again
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(hybrid, "_KEPT", ())
+        assert selections(str(jax.make_jaxpr(jax.jit(
+            bench_step.make_train_k(cfg, 1, 0.1)))(p, t))) == 2
     plain = with_(cfg, remat=False)
     jaxpr = str(jax.make_jaxpr(jax.jit(
         bench_step.make_train_k(plain, 1, 0.1)))(p, t))
-    assert len(re.findall(r"\btop_k\b", jaxpr)) == 1
+    assert selections(jaxpr) == 1
     from dlnetbench_tpu.metrics import spans
     jax.clear_caches()      # a cached trace marks nothing
     tracer = spans.enable()
